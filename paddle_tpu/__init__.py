@@ -12,34 +12,6 @@ analysis), designed jax/XLA/Pallas/pjit-first rather than ported:
   * ProcessGroupNCCL/TCPStore ≙ jax.distributed + XLA collectives over ICI/DCN
 """
 
-# jax-version compat: the tree is written against the stable jax surface
-# (jax.shard_map, jax.enable_x64); on older jax those still live under
-# jax.experimental.  Install top-level aliases BEFORE any submodule import
-# so every call site (and subprocess that imports paddle_tpu) sees one API.
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(*args, **kw):
-        # newer jax renamed check_rep -> check_vma
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map(*args, **kw)
-
-    _jax.shard_map = _shard_map_compat
-if not hasattr(_jax, "enable_x64"):
-    from jax.experimental import enable_x64 as _enable_x64
-
-    _jax.enable_x64 = _enable_x64
-if not hasattr(_jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        # core.axis_frame(name) returns the bound axis size on older jax
-        size = _jax.core.axis_frame(axis_name)
-        return getattr(size, "size", size)
-
-    _jax.lax.axis_size = _axis_size
-
 from . import (amp, distributed, flags, framework, hapi, inference, io,
                jit, metric, nn, observability, optimizer, profiler, static,
                tensor, utils)

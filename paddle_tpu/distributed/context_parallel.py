@@ -67,18 +67,13 @@ def context_parallel_attention(q, k, v, causal: bool = True,
     lse_spec = P(b_spec, h_spec, axis)
     seg_spec = P(b_spec, axis)
 
-    # jax without varying-manual-axes typing (no jax.typeof/lax.pcast)
-    # cannot type the ring's lax.switch branches consistently — its
-    # replication checker false-positives on the backward pass; disable
-    # the check there (newer jax keeps it, satisfied via pcast)
-    kw = {} if hasattr(jax, "typeof") else {"check_vma": False}
     if segment_ids is None:
         fn = jax.shard_map(
             lambda q_, k_, v_: shard_fn(q_, k_, v_, axis, causal=causal,
                                         scale=scale),
             mesh=m,
             in_specs=(qkv_spec, qkv_spec, qkv_spec),
-            out_specs=(qkv_spec, lse_spec), **kw)
+            out_specs=(qkv_spec, lse_spec))
         out, _ = fn(q, k, v)
     else:
         fn = jax.shard_map(
@@ -86,6 +81,6 @@ def context_parallel_attention(q, k, v, causal: bool = True,
                                             scale=scale, segment_ids=s_),
             mesh=m,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),
-            out_specs=(qkv_spec, lse_spec), **kw)
+            out_specs=(qkv_spec, lse_spec))
         out, _ = fn(q, k, v, segment_ids)
     return out

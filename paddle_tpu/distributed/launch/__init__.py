@@ -71,6 +71,14 @@ class _Worker:
 def _spawn(cmd: Sequence[str], cfg: LaunchConfig, coordinator: str,
            restart_num: int, nprocs: Optional[int] = None) -> List[_Worker]:
     nprocs = nprocs if nprocs is not None else cfg.nprocs
+    if cfg.backend == "tpu" and nprocs > 1:
+        raise ValueError(
+            f"backend='tpu' with nprocs={nprocs}: a chip belongs to one "
+            f"process at a time and a jax process takes every chip of its "
+            f"host, so {nprocs} local workers would fail or hang on each "
+            f"other's chips.  Run one process per host (nprocs=1 here, one "
+            f"launcher per host with --master), or backend='cpu' for "
+            f"multi-process emulation on one machine.")
     workers = []
     for rank in range(nprocs):
         env = dict(os.environ)

@@ -47,8 +47,6 @@ from typing import List
 import jax
 
 from ..static_analysis.core import (CANONICAL as _CANONICAL,
-                                    install_rep_rule_fallbacks
-                                    as _install_rep_rule_fallbacks,
                                     sub_jaxprs as _sub_jaxprs)
 from ..static_analysis.mesh_rules import (COLLECTIVE_PRIMS
                                           as _COLLECTIVE_PRIMS,
@@ -62,11 +60,6 @@ __all__ = ["CollectiveOrderError", "collective_schedule",
 
 class CollectiveOrderError(RuntimeError):
     """A collective schedule that can diverge across ranks."""
-
-
-# imported for effect at this module's historical call point (idempotent;
-# static_analysis.core also installs at its own import)
-_install_rep_rule_fallbacks()
 
 
 def collective_schedule(fn, *args, **kwargs):

@@ -92,34 +92,49 @@ class HardwareProfile:
                 "host_gbps": self.host_gbps}
 
 
-# v5e numbers are seeded from the committed BENCH_DECODE.json
-# ``llama_940m_serving.conventions`` block (197e12 peak bf16 FLOP/s,
-# 675 GB/s *measured* HBM stream); ICI has no committed measurement yet,
-# so the datasheet-nominal 1600 Gbit/s = 200 GB/s per chip stands in
-# until a TPU re-run lands one (BASELINE.md records the provenance).
+# THE peaks table.  v5e: the published figures of one chip (Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s =
+# 200 GB/s chip-to-chip interconnect).  host_gbps has no published figure:
+# PCIe Gen3 x16 nominal (16 GB/s) stands in for the host DMA link.
 # cpu_smoke is deliberately tiny and round: tier-1 exercises the model's
 # arithmetic and determinism on CPU, where absolute milliseconds are
 # meaningless and only ratios/bounds are gated.
 PROFILES: Dict[str, HardwareProfile] = {
-    # host_gbps: no committed measurement either — PCIe Gen3 x16
-    # nominal (16 GB/s) stands in for the v5e host DMA link; cpu_smoke
-    # again only needs a stable, deliberately-small value
     "v5e": HardwareProfile("v5e", peak_bf16_flops=197e12,
-                           hbm_gbps=675.0, ici_gbps=200.0,
+                           hbm_gbps=819.0, ici_gbps=200.0,
                            host_gbps=16.0),
     "cpu_smoke": HardwareProfile("cpu_smoke", peak_bf16_flops=5e10,
                                  hbm_gbps=20.0, ici_gbps=2.0,
                                  host_gbps=4.0),
 }
 
+# jax ``device_kind`` -> profile.  A TPU that is not here is an error, not
+# a default: peaks assumed for a device nobody looked up are how a roofline
+# share ends up over 1.
+DEVICE_KINDS: Dict[str, str] = {"TPU v5 lite": "v5e"}
+
+
+def profile_for_device(device) -> HardwareProfile:
+    """The peaks of one jax device, by its ``device_kind``."""
+    try:
+        return PROFILES[DEVICE_KINDS[device.device_kind]]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r}; "
+            f"known: {sorted(DEVICE_KINDS)} (add it to "
+            f"observability/costmodel.py with its source)") from None
+
 
 def resolve_profile(name: Optional[str] = None) -> HardwareProfile:
     """Resolve a profile name (default FLAGS_perf_model_profile):
-    ``auto`` picks ``v5e`` on a TPU backend, ``cpu_smoke`` elsewhere."""
+    ``auto`` looks the first device up by kind on a TPU backend and picks
+    ``cpu_smoke`` elsewhere."""
     name = str(name or _flags.flag("perf_model_profile"))
     if name == "auto":
         import jax
-        name = "v5e" if jax.default_backend() == "tpu" else "cpu_smoke"
+        if jax.default_backend() == "tpu":
+            return profile_for_device(jax.devices()[0])
+        name = "cpu_smoke"
     try:
         return PROFILES[name]
     except KeyError:

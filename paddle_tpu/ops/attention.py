@@ -222,13 +222,11 @@ def _mesh_sharded_trace() -> bool:
         return False
     if not any(mesh.shape[a] > 1 for a in mesh.axis_names):
         return False
-    try:                       # per-shard (shard_map/pmap) trace: exempt
-        from jax._src.core import nonempty_axis_env
-        if nonempty_axis_env():
-            return False
-    except ImportError:        # future jax: fail toward the safe gate
-        pass
-    return True
+    # per-shard (shard_map/pmap) trace: exempt.  jax 0.9.0 has no public
+    # spelling of "is a named axis bound" besides jax.core's
+    # nonempty_axis_env_DO_NOT_USE alias of this function.
+    from jax._src.core import nonempty_axis_env
+    return not nonempty_axis_env()
 
 
 def decode_shape_gate(s, hq, hkv, d, kv_len, paged_block_len=None):

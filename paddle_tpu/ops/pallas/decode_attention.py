@@ -95,7 +95,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 from . import limits as _limits
 
 NEG_INF = -1e30
@@ -117,15 +116,17 @@ def _pick_block_kv(kv_len: int, cap: int) -> int:
     return 0
 
 
-def _kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs, scale, s, g,
-            hkv, d, bq, tile_p, bk, chunks, quantized):
+def _kernel(pos_ref, bt_ref, *refs, scale, s, g, hkv, d, bq, tile_p, bk,
+            chunks, n_cols, quantized):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
-        # block-table-indexed operands (same index map, same dead-tail
-        # clamp, same DMA elision) — one (1, hkv) f32 row per KV chunk
-        ks_ref, vs_ref, o_ref, acc_sc, m_sc, l_sc = refs
+        # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
+        # already gathered per row by the wrapper, so the body reads one
+        # scalar per (row, chunk, head) and nothing scale-sized is ever
+        # blocked through VMEM (a (1, hkv) block does not tile on a TPU)
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc = refs
     else:
-        o_ref, acc_sc, m_sc, l_sc = refs
+        q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc = refs
     del bt_ref  # consumed by the index maps, not the body
     bi = pl.program_id(0)
     qi = pl.program_id(1)
@@ -165,8 +166,9 @@ def _kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, *refs, scale, s, g,
                 # per-element dequant multiply on the chunk
                 kh = kh.astype(qh.dtype)
                 vh = vh.astype(qh.dtype)
-                k_s = scale * ks_ref[0, h]
-                v_s = vs_ref[0, h]
+                sc_at = (bi * n_cols + ki) * hkv + h
+                k_s = scale * ks_ref[sc_at]
+                v_s = vs_ref[sc_at]
             else:
                 k_s = scale
             sc = jax.lax.dot_general(
@@ -252,8 +254,13 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         k2 = k_cache.reshape(n_pool, bk, hkv * d)
         v2 = v_cache.reshape(n_pool, bk, hkv * d)
         if quantized:
-            ks2 = jnp.asarray(k_scale, jnp.float32).reshape(n_pool, hkv)
-            vs2 = jnp.asarray(v_scale, jnp.float32).reshape(n_pool, hkv)
+            # gather each row's scale rows through its block table here,
+            # so the SMEM tables scale with the batch geometry (like the
+            # block table itself), not with the pool
+            ks2 = jnp.take(jnp.asarray(k_scale, jnp.float32), bt, axis=0,
+                           mode="clip")
+            vs2 = jnp.take(jnp.asarray(v_scale, jnp.float32), bt, axis=0,
+                           mode="clip")
     else:
         _, kv_len, hkv, _ = k_cache.shape
     if hq % hkv or hkv == 0:
@@ -309,11 +316,13 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         k2 = k_cache.reshape(b * full, bk, hkv * d)
         v2 = v_cache.reshape(b * full, bk, hkv * d)
         if quantized:
-            ks2 = jnp.asarray(k_scale, jnp.float32).reshape(
-                b * full, hkv)
-            vs2 = jnp.asarray(v_scale, jnp.float32).reshape(
-                b * full, hkv)
-    chunks = kv_len // bk
+            ks2 = jnp.asarray(k_scale, jnp.float32)
+            vs2 = jnp.asarray(v_scale, jnp.float32)
+    n_cols = chunks = kv_len // bk
+    if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
+        raise NotImplementedError(
+            f"int8 scale table {b}x{n_cols}x{hkv} > "
+            f"{_limits.MAX_SCALE_TABLE} SMEM entries")
     if live_len is not None:
         chunks = max(1, min(chunks, -(-int(live_len) // bk)))
     tile_p = max(8, -(-(bq * g) // 8) * 8)  # sublane-pad each q tile
@@ -350,12 +359,13 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
 
     kernel = functools.partial(
         _kernel, scale=float(scale), s=s, g=g, hkv=hkv, d=d, bq=bq,
-        tile_p=tile_p, bk=bk, chunks=chunks, quantized=quantized)
+        tile_p=tile_p, bk=bk, chunks=chunks, n_cols=n_cols,
+        quantized=quantized)
 
-    def q_idx(bi, qi, ki, pos_ref, bt_ref):
+    def q_idx(bi, qi, ki, pos_ref, bt_ref, *_):
         return (bi, 0, qi, 0)
 
-    def kv_idx(bi, qi, ki, pos_ref, bt_ref):
+    def kv_idx(bi, qi, ki, pos_ref, bt_ref, *_):
         # clamp the LOGICAL chunk index to this q tile's last live block,
         # then dereference the block table: dead-tail chunks re-map to the
         # same physical block as the previous grid step → Pallas elides
@@ -369,29 +379,20 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         last = (pos_ref[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
         return (bt_ref[bi, jnp.minimum(ki, last)], 0, 0)
 
-    def sc_idx(bi, qi, ki, pos_ref, bt_ref):
-        # the scale rows ride the same table dereference (and the same
-        # dead-tail clamp) as the KV chunks they dequantize
-        last = (pos_ref[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bt_ref[bi, jnp.minimum(ki, last)], 0)
-
-    in_specs = [
-        pl.BlockSpec((1, hkv, tile_p, d), q_idx),
-        pl.BlockSpec((1, bk, hkv * d), kv_idx),
-        pl.BlockSpec((1, bk, hkv * d), kv_idx),
-    ]
-    operands = (pos_arr, bt, qg, k2, v2)
+    scalars = (pos_arr, bt)
     if quantized:
-        in_specs += [pl.BlockSpec((1, hkv), sc_idx),
-                     pl.BlockSpec((1, hkv), sc_idx)]
-        operands += (ks2, vs2)
+        scalars += (ks2.reshape(-1), vs2.reshape(-1))
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(b, nq, chunks),
-            in_specs=in_specs,
+            in_specs=[
+                pl.BlockSpec((1, hkv, tile_p, d), q_idx),
+                pl.BlockSpec((1, bk, hkv * d), kv_idx),
+                pl.BlockSpec((1, bk, hkv * d), kv_idx),
+            ],
             out_specs=pl.BlockSpec((1, hkv, tile_p, d), q_idx),
             scratch_shapes=[
                 pltpu.VMEM((hkv, tile_p, d), jnp.float32),
@@ -400,10 +401,10 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, nq * tile_p, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(*scalars, qg, k2, v2)
     out = out.reshape(b, hkv, nq, tile_p, d)[:, :, :, :bq * g]
     out = out.reshape(b, hkv, nq * bq * g, d)[:, :, :rows]
     out = out.reshape(b, hkv, s, g, d).transpose(0, 2, 1, 3, 4)

@@ -28,7 +28,11 @@ The values themselves are TPU architecture facts, not tunables:
   * ``MAX_HEAD_DIM`` — two lane tiles; larger heads blow the per-head
     VMEM scratch budget of the decode kernels;
   * ``MAX_GEMM_ROWS`` — the int8 weight-only matmul is decode-shaped
-    (batch·seq rows stay tiny); training-size GEMMs belong to XLA.
+    (batch·seq rows stay tiny); training-size GEMMs belong to XLA;
+  * ``MAX_SCALE_TABLE`` — entries (rows · chunks · kv-heads) in ONE of
+    the int8 decode kernel's two f32 scale tables.  They ride in SMEM
+    as scalar prefetch beside the block table; Mosaic for a v5e took
+    65,536-entry tables and refused 131,072 (AOT compile, PR 21).
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ MAX_Q_ROWS = 64      # flash-decode per-tile s·G row cap
 MAX_Q_LEN = 2048     # q longer than any prefill chunk => flash kernel
 MAX_HEAD_DIM = 256   # decode-attention head_dim ceiling (2 lane tiles)
 MAX_GEMM_ROWS = 256  # int8_matmul row ceiling (decode-shaped GEMMs)
+MAX_SCALE_TABLE = 65536  # int8 decode: per-table SMEM scale entries
 
 # second-minor register-tile height by dtype name (jnp dtype .name)
 SUBLANES = {

@@ -62,11 +62,7 @@ def merge_attention(out_a, lse_a, out_b, lse_b):
 def _as_varying(x, like, axis_name):
     """Mark a constant as varying over every mesh axis that ``like`` varies
     over (plus ``axis_name``) — lax.switch branches and scan carries must
-    agree on varying-axes types.  On jax versions without varying-manual-
-    axes typing (no ``jax.typeof``/``lax.pcast``) this is a no-op: those
-    versions don't distinguish the types either."""
-    if not hasattr(jax, "typeof") or not hasattr(lax, "pcast"):
-        return x
+    agree on varying-axes types."""
     want = frozenset(getattr(jax.typeof(like), "vma", frozenset())) \
         | {axis_name}
     have = frozenset(getattr(jax.typeof(x), "vma", frozenset()))

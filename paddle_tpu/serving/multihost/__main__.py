@@ -85,7 +85,7 @@ def _spawn_worker(name: str, store_host: str, store_port: int
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [repo_root] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"      # the selfcheck is a CPU tool (below)
     return subprocess.Popen(
         [sys.executable, "-m", "paddle_tpu.serving.multihost", "--worker",
          "--name", name, "--store-host", store_host,
@@ -94,6 +94,15 @@ def _spawn_worker(name: str, store_host: str, store_port: int
 
 
 def _selfcheck(args: argparse.Namespace) -> int:
+    # A CPU tool: it checks rendezvous, framing, failover and token parity
+    # between this process's reference engine and two worker processes, so
+    # all three must compute on the same backend — and three processes
+    # cannot share one chip.  Pinned here and in _spawn_worker.
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    print(f"[selfcheck] backend pinned: {jax.default_backend()} "
+          f"(parent and workers)", flush=True)
+
     from paddle_tpu import observability as obs
     from .plane import MultiHostRouter
     from .transport import (SocketTransport, StoreClient, StoreServer,

@@ -5,18 +5,14 @@ Paddle's NCCL watchdog + StreamSafeCUDAAllocator as "XLA's checker + a
 shard_map collective-order lint of our own".  The collective lint
 (distributed/lint.py) was the first such rule; this module is the
 machinery it and every later rule share, factored out so there is ONE
-version-compat surface for jax's primitive renames, ONE sub-jaxpr
-discovery convention, and ONE structured :class:`Finding` shape:
+table of jax's primitive aliases, ONE sub-jaxpr discovery convention, and ONE structured :class:`Finding` shape:
 
   * :func:`sub_jaxprs` / :func:`iter_eqns` — duck-typed discovery and
     recursive walking of the jaxprs hiding in eqn params (pjit bodies,
     scan/cond/while branches, shard_map, remat, custom_* rules);
-  * :data:`CANONICAL` / :func:`canonical_name` — the jax-rename-tolerant
-    primitive-name mapping (``psum``/``psum2``/``psum_invariant`` are one
-    collective across jax releases);
-  * :func:`install_rep_rule_fallbacks` — the 0.4.x shard_map rep-checker
-    shims without which linting a while_loop under shard_map explodes
-    before any walk starts;
+  * :data:`CANONICAL` / :func:`canonical_name` — the primitive-name
+    mapping (``psum``/``psum_invariant`` are one collective, traced with
+    and without shard_map's vma check);
   * :func:`trace_for_lint` — one abstract trace of a python function
     into a :class:`LintContext` (closed jaxpr + flat labelled inputs +
     donation marks), the input every graph-lint rule consumes.
@@ -34,7 +30,7 @@ import jax
 
 __all__ = ["Finding", "GraphLintError", "GraphLintWarning", "CANONICAL",
            "canonical_name", "sub_jaxprs", "iter_eqns", "aval_bytes",
-           "install_rep_rule_fallbacks", "FlatInput", "LintContext",
+           "FlatInput", "LintContext",
            "trace_for_lint", "MeshInfo", "canon_spec", "spec_axes",
            "sharded_bytes", "EqnRecord", "propagate_shardings",
            "MeshLintContext", "trace_for_mesh_lint"]
@@ -80,58 +76,20 @@ class Finding:
                 f"{self.path or '<signature>'}: {self.message}{b}")
 
 
-# version-specific primitive name -> the canonical name schedules report
-# (and tests pin): jax renames collectives across releases — lax.psum
-# traces as "psum2" under the 0.4.x shard_map rewrite and as
-# "psum_invariant" under the vma type system (jax >= 0.8) — so analyzers
-# match through this table instead of pinning one release's strings.
+# primitive name -> the canonical name schedules report (and tests pin):
+# lax.psum traces as "psum_invariant" under shard_map's vma type system
+# and as "psum" with check_vma=False — one collective, so analyzers match
+# through this table.
 CANONICAL: Dict[str, str] = {
     "psum": "psum_invariant",
-    "psum2": "psum_invariant",
     "psum_invariant": "psum_invariant",
     "all_gather_invariant": "all_gather",
 }
 
 
 def canonical_name(name: str) -> str:
-    """Canonical primitive name across jax releases."""
+    """Canonical primitive name (see :data:`CANONICAL`)."""
     return CANONICAL.get(name, name)
-
-
-def install_rep_rule_fallbacks() -> None:
-    """jax 0.4.x's shard_map rep-checker has no rule for ``while`` (and
-    raises NotImplementedError at trace time), so linting a while_loop
-    under shard_map — the exact pattern the collective lint exists to
-    inspect — would explode before the walk even starts.  Register a
-    conservative fallback (outputs replicated over NO axes: never claims
-    a replication it can't prove, so it is sound for any out_specs that
-    mention every mesh axis) for the control-flow primitives the checker
-    is missing.  vma-era jax (>= 0.8) has real rules and is left
-    untouched.  Idempotent."""
-    try:
-        from jax.experimental import shard_map as _sm
-        rules = getattr(_sm, "_check_rules", None)
-        if rules is None:
-            return
-        import jax.extend.core as _core  # noqa: F401  (presence probe)
-        from jax import lax as _lax
-        for prim_name in ("while_p",):
-            prim = getattr(_lax, prim_name, None)
-            if prim is None:
-                from jax._src.lax import control_flow as _cf
-                prim = getattr(_cf, prim_name, None)
-            if prim is not None and prim not in rules:
-                rules[prim] = lambda mesh, *in_rep, **params: set()
-                # the efficient-transpose rewrite trace keeps a second
-                # rule table; "bind unchanged, rep from the check rule"
-                # is the registered no-op there
-                if hasattr(_sm, "register_norewrite"):
-                    _sm.register_norewrite(prim)
-    except Exception:       # pragma: no cover - newer jax needs nothing
-        pass
-
-
-install_rep_rule_fallbacks()
 
 
 def sub_jaxprs(eqn) -> List[Tuple[str, Any]]:

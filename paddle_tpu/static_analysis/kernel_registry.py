@@ -3,7 +3,7 @@
 The graph-lint layer (ISSUE 6/8) stops at the jaxpr: a ``pallas_call``
 is one opaque eqn, so the kernels the serving stack rides — the q-tiled
 flash-decode kernel with scalar-prefetch-clamped index maps, the paged
-block-table dereference, the int8 scale operands — were validated only
+block-table dereference, the int8 scale tables — were validated only
 by running them.  This module re-expresses each kernel's GEOMETRY as a
 :class:`KernelSpec`: the grid, every BlockSpec's block shape and index
 map (rewritten over closed integer intervals, :class:`Iv`), the
@@ -114,6 +114,7 @@ class ScalarOperand:
     shape: Tuple[int, ...]
     lo: int
     hi: int
+    kv_stream: bool = False   # counts toward kv_streamed_bytes (4 B each)
 
 
 class ScalarEnv:
@@ -241,7 +242,7 @@ def streamed_bytes(spec: KernelSpec) -> int:
 
 
 def kv_streamed_bytes(spec: KernelSpec) -> int:
-    """Cache-side streamed bytes only (KV blocks + their scale rows) —
+    """Cache-side streamed bytes only (KV blocks + their scale tables) —
     the quantity the committed int8_serving <=0.55x claim bounds."""
     total = 0
     grid_n = _grid_size(spec)
@@ -250,6 +251,12 @@ def kv_streamed_bytes(spec: KernelSpec) -> int:
             continue
         n = grid_n if op.fetches is None else int(op.fetches)
         total += n * op.block_bytes()
+    for sc in spec.scalars:     # prefetched whole, once per call
+        if sc.kv_stream:
+            n = 4
+            for d in sc.shape:
+                n *= int(d)
+            total += n
     return total
 
 
@@ -284,6 +291,7 @@ def decode_kernel_rejects(b: int, s: int, hq: int, hkv: int, d: int,
             if bk * ng != kv_len or bk % _limits.LANES:
                 return (f"int8 scale granule {kv_len}/{ng} is not a "
                         f"128-aligned divisor of the cache length")
+            n_cols = ng
         else:
             from ..ops.pallas.decode_attention import _pick_block_kv
             if block_kv is None:
@@ -292,6 +300,11 @@ def decode_kernel_rejects(b: int, s: int, hq: int, hkv: int, d: int,
             if not _pick_block_kv(kv_len, int(block_kv)):
                 return (f"max_length {kv_len} has no 128-aligned chunk "
                         f"divisor <= {block_kv}")
+    elif quantized:
+        n_cols = kv_len // paged_block_len
+    if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
+        return (f"int8 scale table {b}x{n_cols}x{hkv} > "
+                f"{_limits.MAX_SCALE_TABLE} SMEM entries")
     return None
 
 
@@ -311,7 +324,8 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     is the identity-table view ``(b*chunks, bk, hkv*d)``).  Paged: pass
     ``block_len`` + ``max_blocks`` (+ ``num_blocks``, default the
     serving engine's ``num_slots*max_blocks + 1`` null-block pool).
-    ``quantized`` adds the two f32 scale operands; contiguous int8 pins
+    ``quantized`` adds the two f32 scale tables as scalar-prefetch
+    operands; contiguous int8 pins
     the KV chunk to the scale granule (``n_granules`` — the
     init_kv_cache layout).  Alignment/granule violations are RECORDED
     in ``dims`` for the rules to flag (the kernel would raise at call
@@ -383,6 +397,14 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         # null-filled (block 0) — live rows must never dereference them
         ScalarOperand("bt", (b, chunks), 0, max(0, n_pool - 1)),
     )
+    if quantized:
+        # the f32 scale tables, gathered per row by the wrapper, ride in
+        # SMEM beside the block table (their VALUES never feed an index
+        # map, so the declared range is vacuous)
+        scalars += (ScalarOperand("k_scale", (b * chunks * hkv,), 0, 0,
+                                  kv_stream=True),
+                    ScalarOperand("v_scale", (b * chunks * hkv,), 0, 0,
+                                  kv_stream=True))
 
     def expected_last(p: int, q: int) -> int:
         # last chunk holding a key visible to ANY row of q tile q at
@@ -400,14 +422,6 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         col = iv_min(ki, last)
         blk = sc.lookup("bt", bi, col)
         return (blk, Iv.const(0), Iv.const(0))
-
-    def sc_idx(grid_ivs, sc):
-        bi, qi, ki = grid_ivs
-        pos = sc.lookup("pos", bi)
-        last = (pos + iv_min((qi + 1) * bq, Iv.const(s)) - 1) // bk
-        col = iv_min(ki, last)
-        blk = sc.lookup("bt", bi, col)
-        return (blk, Iv.const(0))
 
     clamp = ClampCheck(table="bt", pin_scalar="pos", pin_axis=1,
                        expected=expected_last)
@@ -429,15 +443,6 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         BlockOperand("v", kv_block, kv_array, kv_dtype, kv_idx,
                      fetches=kv_fetches, kv_stream=True, clamp=clamp),
     ]
-    if quantized:
-        operands += [
-            BlockOperand("k_scale", (1, hkv), (n_pool, hkv), "float32",
-                         sc_idx, fetches=kv_fetches, kv_stream=True,
-                         clamp=clamp),
-            BlockOperand("v_scale", (1, hkv), (n_pool, hkv), "float32",
-                         sc_idx, fetches=kv_fetches, kv_stream=True,
-                         clamp=clamp),
-        ]
     operands.append(
         BlockOperand("out", q_block, q_array, q_dtype, q_idx,
                      sublane_padded=True, fetches=q_fetches))

@@ -233,10 +233,10 @@ class KernelAlignRule(KernelRule):
                 sub_b = op.block_shape[-2]
                 sub_a = op.array_shape[-2]
                 sl = _limits.sublanes(op.dtype)
-                # a 1-row block (the int8 scale rows) is a degenerate
-                # tile Mosaic pads internally; the lint targets
-                # multi-row blocks that straddle sublane tiles
-                if sub_b > 1 and sub_b % sl and sub_b != sub_a:
+                # Mosaic refuses a second-minor block dim that is
+                # neither the array's nor sublane-aligned — one row of
+                # a taller array included
+                if sub_b % sl and sub_b != sub_a:
                     out.append(core.Finding(
                         rule=self.name, severity=self.severity,
                         path=spec.path,

@@ -47,11 +47,9 @@ __all__ = ["COLLECTIVE_PRIMS", "collective_sig", "walk_collectives",
            "comm_report", "estimate_peak_hbm"]
 
 
-# primitive names that lower to cross-replica communication.  jax renames
-# these across versions — matching goes through the shared core.CANONICAL
-# table instead of pinning one release's strings.  The replication
-# *casts* ("pbroadcast" on 0.4.x, "pvary" on vma jax) move no data and
-# are deliberately absent.
+# primitive names that lower to cross-replica communication; aliases of
+# one collective match through the shared core.CANONICAL table.  The
+# replication *cast* ("pvary") moves no data and is deliberately absent.
 COLLECTIVE_PRIMS = {
     "psum", "psum_invariant", "pmax", "pmin", "all_gather",
     "all_to_all", "ppermute", "reduce_scatter", "psum_scatter", "pgather",

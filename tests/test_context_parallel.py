@@ -26,12 +26,6 @@ def _sep_mesh(p):
     return Mesh(np.asarray(jax.devices()[:p]), ("sep",))
 
 
-# jax without varying-manual-axes typing (no jax.typeof) false-positives
-# its replication check on the ring BACKWARD's cond branches; same guard
-# as distributed/context_parallel.py
-_SM_KW = {} if hasattr(jax, "typeof") else {"check_vma": False}
-
-
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("hkv", [4, 2])
 def test_ring_matches_full(causal, hkv):
@@ -65,7 +59,7 @@ def test_ring_grads_match_full():
         lambda q_, k_, v_: ring_attention_shard(q_, k_, v_, "sep",
                                                 causal=True)[0],
         mesh=mesh, in_specs=(P(None, "sep"),) * 3,
-        out_specs=P(None, "sep"), **_SM_KW)
+        out_specs=P(None, "sep"))
 
     def loss_ring(q, k, v):
         return jnp.sum(ring(q, k, v) * w)
@@ -214,7 +208,7 @@ def test_ring_varlen_grads_match_packed_oracle():
         lambda q_, k_, v_, s_: ring_attention_shard(
             q_, k_, v_, "sep", causal=True, segment_ids=s_)[0],
         mesh=mesh, in_specs=(P(None, "sep"),) * 3 + (P(None, "sep"),),
-        out_specs=P(None, "sep"), **_SM_KW)
+        out_specs=P(None, "sep"))
 
     gr = jax.grad(lambda q_, k_, v_: jnp.sum(ring(q_, k_, v_, seg) * w),
                   argnums=(0, 1, 2))(q, k, v)

@@ -9,7 +9,6 @@ No engines, no compiles.
 """
 
 import json
-import os
 
 import pytest
 
@@ -18,7 +17,6 @@ from paddle_tpu.models import tiny_llama_config
 from paddle_tpu.observability import costmodel as cm
 from paddle_tpu.observability.metrics import MetricsRegistry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # -- profiles ----------------------------------------------------------------
@@ -27,7 +25,7 @@ def test_profiles_and_resolution():
     assert {"v5e", "cpu_smoke"} <= set(cm.PROFILES)
     v5e = cm.resolve_profile("v5e")
     assert v5e.peak_bf16_flops == 197e12
-    assert v5e.hbm_bps == 675.0 * 1e9
+    assert v5e.hbm_bps == 819.0 * 1e9      # published, not the July chain
     # the test backend is CPU, so 'auto' (and the flag default) must
     # pick the smoke profile — tier-1 never pretends to be a v5e
     assert cm.resolve_profile("auto").name == "cpu_smoke"
@@ -35,11 +33,19 @@ def test_profiles_and_resolution():
     with pytest.raises(ValueError, match="unknown hardware profile"):
         cm.resolve_profile("v9000")
 
+    class Dev:                      # a jax device, as far as the table looks
+        device_kind = "TPU v5 lite"
+
+    assert cm.profile_for_device(Dev) is v5e
+    Dev.device_kind = "TPU v9000"
+    with pytest.raises(ValueError, match="no published peaks.*v9000"):
+        cm.profile_for_device(Dev)
+
 
 def test_profile_as_dict_round_trips():
     d = cm.PROFILES["v5e"].as_dict()
     assert d == {"name": "v5e", "peak_bf16_flops": 197e12,
-                 "hbm_gbps": 675.0, "ici_gbps": 200.0,
+                 "hbm_gbps": 819.0, "ici_gbps": 200.0,
                  "host_gbps": 16.0}
     assert cm.HardwareProfile(**d) == cm.PROFILES["v5e"]
 
@@ -120,11 +126,11 @@ def test_depth_bucketing_and_memoization():
 
 # -- dtype-aware KV cost -----------------------------------------------------
 
-def test_kv_bytes_per_token_matches_committed_int8_ratio():
-    """The model's per-token KV cost must reproduce the committed
-    ``per_step_streamed_cache_bytes.ratio`` BENCH row exactly — the
-    int8 predicted kv-stream term shrinks by the same factor the pool
-    accounting measured (ISSUE 15 acceptance)."""
+def test_kv_bytes_per_token_matches_pool_accounting_int8_ratio():
+    """The model's per-token KV cost must reproduce the pool accounting's
+    int8/full streamed-bytes ratio exactly — 0.254 on the f32 tiny config
+    at block_len 16: a quarter the payload plus one f32 scale per kv head
+    per block (ISSUE 15 acceptance)."""
     c = tiny_llama_config()
     full = cm.kv_bytes_per_token(c, "bf16")
     int8 = cm.kv_bytes_per_token(c, "int8", block_len=16)
@@ -135,10 +141,8 @@ def test_kv_bytes_per_token_matches_committed_int8_ratio():
     assert int8 < full
     # 'mixed' keeps the device pool at native precision
     assert cm.kv_bytes_per_token(c, "mixed") == full
-    with open(os.path.join(REPO, "BENCH_DECODE.json")) as f:
-        committed = json.load(f)["cpu_plumbing_smoke"]["int8_serving"][
-            "per_step_streamed_cache_bytes"]["ratio"]
-    assert round(int8 / full, 3) == committed
+    assert round(int8 / full, 3) == round(0.25 + 4 / (16 * c.head_dim * 4),
+                                          3) == 0.254
 
 
 # -- attribution: signature determinism + reset ------------------------------

@@ -199,14 +199,26 @@ def test_align_block_divisibility_offender():
     assert "block 3 does not tile array dim 10" in msgs
 
 
-def test_align_scale_rows_are_exempt():
-    """1-row f32 scale blocks are degenerate tiles Mosaic pads — the
-    sublane lint must not flag them (regression for the int8 specs)."""
+def test_align_scale_tables_ride_scalar_prefetch():
+    """Mosaic refuses a (1, hkv) block of a (num_blocks, hkv) array —
+    the int8 kernel's first form (PR 13) never lowered for a TPU.  The
+    scale tables are scalar-prefetch operands now, and the sublane lint
+    flags the 1-row block it used to exempt."""
     spec = next(s for s in kr.registered_kernel_specs()
                 if s.dims.get("quantized") and s.dims.get("paged"))
-    scale_ops = [o for o in spec.operands if "scale" in o.name]
-    assert scale_ops, "int8 paged spec must carry scale operands"
+    assert not [o for o in spec.operands if "scale" in o.name]
+    assert {"k_scale", "v_scale"} <= {s.name for s in spec.scalars}
     assert krl.KernelAlignRule().run(spec) == []
+
+    def idx(grid, env):
+        (i,) = grid
+        return (i, kr.iv(0))
+
+    row = kr.BlockOperand("k_scale", (1, 8), (513, 8), "float32", idx)
+    bad = kr.KernelSpec(op="mini_align", variant="scale-row", grid=(513,),
+                        operands=(row,))
+    msgs = " | ".join(f.message for f in krl.KernelAlignRule().run(bad))
+    assert "second-minor block dim 1" in msgs
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,15 @@ def _read_logs(log_dir):
     return out
 
 
+def test_tpu_backend_refuses_several_local_workers():
+    """A chip belongs to one process: nprocs > 1 under backend='tpu'
+    would start workers that each claim every local chip.  Refused before
+    anything is spawned, with what to set instead."""
+    with pytest.raises(ValueError, match="one process per host.*cpu"):
+        elastic_run([sys.executable, "-c", "raise SystemExit(3)"],
+                    LaunchConfig(nprocs=2))
+
+
 @pytest.mark.timeout(300)
 def test_two_process_allreduce_and_checkpoint(tmp_path):
     log_dir = str(tmp_path / "logs")

@@ -53,7 +53,7 @@ def test_mesh_info_accepts_string_dict_mesh_and_abstract_mesh():
     assert sa.MeshInfo.of("mp2dp4").as_dict() == {"mp": 2, "dp": 4}
     assert sa.MeshInfo.of({"dp": 2, "mp": 2}).size("mp") == 2
     assert sa.MeshInfo.of(_mesh22()).as_dict() == {"dp": 2, "mp": 2}
-    am = jax.sharding.AbstractMesh((("dp", 2), ("mp", 2)))
+    am = jax.sharding.AbstractMesh((2, 2), ("dp", "mp"))
     assert sa.MeshInfo.of(am).as_dict() == {"dp": 2, "mp": 2}
     with pytest.raises(ValueError, match="mp2dp2"):
         sa.MeshInfo.of("mp2dp2!")

@@ -2,15 +2,15 @@
 
 Two halves under test: the calibrate-then-monitor EwmaDetector (skip /
 warmup semantics, one-sided vs two-sided bands, anomaly counting,
-reset) and the ``bench.py --check-history`` offline gate — green on the
-committed artifacts, red on synthetically-regressed copies (the ISSUE
-15 acceptance unit test), and the CLI exit-code mapping.
+reset) and the ``bench.py --check-history`` offline gate — green on a
+minimal trajectory written to ``tmp_path``, red on synthetically-regressed
+copies of it (the ISSUE 15 acceptance unit test), and the CLI exit-code
+mapping.
 """
 
 import glob
 import json
 import os
-import shutil
 import sys
 
 import pytest
@@ -68,22 +68,54 @@ def test_two_sided_catches_underprediction_and_reset():
 
 # -- committed-history gate --------------------------------------------------
 
-def test_check_history_green_on_committed_repo():
+def _write_artifacts(tmp):
+    """A minimal committed trajectory: two training-bench records and one
+    serving artifact holding exactly the keys the cases below mutate (the
+    shapes ``bench.py`` writes; the values are the July-record ones)."""
+    for n, mfu in ((1, 0.6359), (2, 0.6588)):
+        with open(os.path.join(tmp, f"BENCH_r{n:02d}.json"), "w") as f:
+            json.dump({"n": n, "parsed": {
+                "metric": "mfu_llama3_arch_940m", "value": mfu}}, f)
+    decode = {
+        "cpu_plumbing_smoke": {
+            "serving": {"step_traces": 1},
+            "int8_serving": {
+                "per_step_streamed_cache_bytes": {"ratio": 0.254},
+                "capacity_at_equal_pool_bytes": {"capacity_ratio": 1.97},
+                "deterministic_replay": True},
+            "perf_model": {"drift_findings": 0,
+                           "kv_ratio_consistent": True},
+            "spec_model": {
+                "model_beats_ngram_on_novel": True,
+                "novel_text": {"greedy_parity": True},
+                "repetition_heavy": {"greedy_parity": True},
+                "deterministic_replay": True, "lint_findings": 0,
+                "mesh_paths": [
+                    {"chosen_path": "pallas_decode_shard_map"}]}},
+        "llama_940m_serving": {"decode": [
+            {"batch": 1, "max_length": 2048,
+             "tokens_per_sec_per_chip": 385.9,
+             "of_weight_stream_bound": 1.074},
+            {"batch": 8, "max_length": 8192,
+             "tokens_per_sec_per_chip": 1874.0,
+             "of_weight_stream_bound": 0.652}]}}
+    with open(os.path.join(tmp, "BENCH_DECODE.json"), "w") as f:
+        json.dump(decode, f)
+    return str(tmp)
+
+
+def test_check_history_green_on_committed_repo(tmp_path):
+    # the checkout itself: green, with whatever it does not carry skipped
     r = check_history()
+    assert r["ok"] is True and r["root"] == REPO
+    # a full trajectory: every gate present and green
+    r = check_history(_write_artifacts(tmp_path))
     assert r["ok"] is True
-    assert r["root"] == REPO
     names = {c["name"] for c in r["checks"]}
     assert {"bench_r_mfu_trajectory", "int8_streamed_bytes_ratio",
             "step_traces_budget", "decode_head_tok_s",
             "perf_model_row", "spec_model_row"} <= names
-    assert all(c["ok"] is not False for c in r["checks"])
-
-
-def _copy_artifacts(tmp):
-    for f in glob.glob(os.path.join(REPO, "BENCH_r*.json")):
-        shutil.copy(f, tmp)
-    shutil.copy(os.path.join(REPO, "BENCH_DECODE.json"), tmp)
-    return str(tmp)
+    assert all(c["ok"] is True for c in r["checks"])
 
 
 def _edit(path, fn):
@@ -95,7 +127,7 @@ def _edit(path, fn):
 
 
 def test_synthetic_mfu_regression_fails(tmp_path):
-    root = _copy_artifacts(tmp_path)
+    root = _write_artifacts(tmp_path)
     latest = sorted(glob.glob(os.path.join(root, "BENCH_r*.json")))[-1]
     _edit(latest, lambda b: b["parsed"].update(
         value=b["parsed"]["value"] * 0.5))
@@ -106,7 +138,7 @@ def test_synthetic_mfu_regression_fails(tmp_path):
 
 
 def test_synthetic_int8_ratio_regression_fails(tmp_path):
-    root = _copy_artifacts(tmp_path)
+    root = _write_artifacts(tmp_path)
 
     def fatten(b):
         b["cpu_plumbing_smoke"]["int8_serving"][
@@ -120,7 +152,7 @@ def test_synthetic_int8_ratio_regression_fails(tmp_path):
 
 
 def test_synthetic_retrace_regression_fails(tmp_path):
-    root = _copy_artifacts(tmp_path)
+    root = _write_artifacts(tmp_path)
 
     def retrace(b):
         b["cpu_plumbing_smoke"]["serving"]["step_traces"] = 3
@@ -133,7 +165,7 @@ def test_synthetic_retrace_regression_fails(tmp_path):
 
 
 def test_synthetic_spec_model_regression_fails(tmp_path):
-    root = _copy_artifacts(tmp_path)
+    root = _write_artifacts(tmp_path)
 
     def lose_the_win(b):
         row = b["cpu_plumbing_smoke"]["spec_model"]
@@ -147,7 +179,7 @@ def test_synthetic_spec_model_regression_fails(tmp_path):
 
 
 def test_synthetic_spec_model_mesh_demotion_fails(tmp_path):
-    root = _copy_artifacts(tmp_path)
+    root = _write_artifacts(tmp_path)
 
     def demote(b):
         for row in b["cpu_plumbing_smoke"]["spec_model"]["mesh_paths"]:
@@ -166,8 +198,9 @@ def test_missing_artifacts_skip_rather_than_fail(tmp_path):
     assert any(c["ok"] is None for c in r["checks"])
 
 
-def test_tolerance_overrides_apply():
-    r = check_history(tolerances={"decode_head_tok_s_floor": 1e9})
+def test_tolerance_overrides_apply(tmp_path):
+    r = check_history(_write_artifacts(tmp_path),
+                      tolerances={"decode_head_tok_s_floor": 1e9})
     assert r["ok"] is False
     bad = {c["name"]: c["ok"] for c in r["checks"]}
     assert bad["decode_head_tok_s"] is False
@@ -177,14 +210,15 @@ def test_tolerance_overrides_apply():
 
 # -- CLI exit mapping --------------------------------------------------------
 
-def test_bench_check_history_cli_exit_codes(monkeypatch, capsys):
-    """``bench.py --check-history`` exits 0 on the committed trajectory
-    and non-zero once a tracked metric regresses past tolerance."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
+def test_bench_check_history_cli_exit_codes(monkeypatch, capsys, tmp_path):
+    """``bench.py --check-history`` exits 0 on a green trajectory and
+    non-zero once a tracked metric regresses past tolerance."""
+    import functools
+
+    import bench
+    from paddle_tpu.observability import regression
+    monkeypatch.setattr(regression, "check_history", functools.partial(
+        check_history, _write_artifacts(tmp_path)))
     monkeypatch.setattr(sys, "argv", ["bench.py", "--check-history"])
     with pytest.raises(SystemExit) as e:
         bench.main()
@@ -193,7 +227,6 @@ def test_bench_check_history_cli_exit_codes(monkeypatch, capsys):
 
     # regress a committed floor past the committed value: same CLI,
     # same artifacts, non-zero exit
-    from paddle_tpu.observability import regression
     monkeypatch.setitem(regression.HISTORY_TOLERANCES,
                         "decode_head_tok_s_floor", 1e9)
     with pytest.raises(SystemExit) as e:
