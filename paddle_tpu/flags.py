@@ -319,9 +319,11 @@ DEFINE("retrace_watchdog", "warn",
        "RetraceWarning per violation), 'off' (count only).  The count "
        "always lands in the jit.traces registry counter")
 DEFINE("observability_spans", True,
-       "record host spans (serving tick/prefill/decode, RecordEvent "
-       "scopes) into the default SpanTracer for Chrome-trace/Perfetto "
-       "export; off leaves span() calls as no-ops")
+       "record host spans (the serving tick's phases, prefill waves, "
+       "RecordEvent scopes) into the default SpanTracer for "
+       "Chrome-trace/Perfetto export and, as jax.profiler "
+       "TraceAnnotations, into a running profiler trace; off leaves "
+       "span() calls as no-ops in both")
 DEFINE("trace_buffer_events", 100000,
        "span-tracer ring-buffer capacity: a long-running server keeps "
        "the most recent window of host spans and counts the rest as "

@@ -361,11 +361,16 @@ class LlamaAttention(Layer):
                     block_tables=block_tables,
                     k_scale=sc[idx, 0], v_scale=sc[idx, 1])
                 return matmul(out.reshape(b, s, -1), self.o_proj), cache
-            cache = cache.at[idx, 0, phys, off].set(k.astype(cache.dtype))
-            cache = cache.at[idx, 1, phys, off].set(v.astype(cache.dtype))
-            cache = constrain(cache, None, None, None, None, "mp", None)
-            out = cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
-                                          pos, block_tables=block_tables)
+            with jax.named_scope("kv_write"):
+                cache = cache.at[idx, 0, phys, off].set(
+                    k.astype(cache.dtype))
+                cache = cache.at[idx, 1, phys, off].set(
+                    v.astype(cache.dtype))
+                cache = constrain(cache, None, None, None, None, "mp", None)
+            with jax.named_scope("kv_slice"):
+                k_pool, v_pool = cache[idx, 0], cache[idx, 1]
+            out = cached_decode_attention(q, k_pool, v_pool, pos,
+                                          block_tables=block_tables)
             return matmul(out.reshape(b, s, -1), self.o_proj), cache
         if quantized:
             sc = cache["scale"]
@@ -461,11 +466,15 @@ class LlamaDecoderLayer(Layer):
 
     def decode(self, x, rope_cache, pos, cache, idx: int,
                block_tables=None):
-        a, cache = self.self_attn.decode(
-            self.input_layernorm(x), rope_cache, pos, cache, idx,
-            block_tables=block_tables)
-        x = x + a
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # named for the device trace (the scopes reach every op's
+        # ``op_name``; the engine's program_part names the kernel)
+        with jax.named_scope("attn"):
+            a, cache = self.self_attn.decode(
+                self.input_layernorm(x), rope_cache, pos, cache, idx,
+                block_tables=block_tables)
+            x = x + a
+        with jax.named_scope("ffn"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
         return x, cache
 
 
@@ -591,7 +600,8 @@ class LlamaForCausalLM(Layer):
         selects."""
         hidden, cache = self.model.decode(input_ids, cache, pos,
                                           block_tables=block_tables)
-        return self.logits(hidden), cache
+        with jax.named_scope("lm_head"):
+            return self.logits(hidden), cache
 
     def generate(self, input_ids, max_new_tokens: int = 32, **kw):
         """Greedy/sampled generation with the pre-allocated KV cache

@@ -12,8 +12,12 @@ hardware allows") requires as a *layer*, not per-module counters:
     all report here — ``observability.snapshot()`` after a serving
     trace is the whole story in one dict;
   * :mod:`.tracing` — a host-side span tracer with Chrome-trace /
-    Perfetto JSON export, composed with ``profiler.RecordEvent`` so the
-    same labelled regions appear against XLA device traces;
+    Perfetto JSON export; every span is also a
+    ``jax.profiler.TraceAnnotation``, so the same labelled regions
+    appear in an XLA device trace (``profiler.RecordEvent`` is a thin
+    wrapper over it);
+  * :mod:`.clock` — the one ``perf_counter`` origin spans and request
+    events are stamped against, and the conversions to it;
   * :mod:`.request_log` — per-request lifecycle timelines (submitted →
     admitted → prefill → first token → retired) keyed by a uid minted
     at ``submit()`` and threaded router → replica → engine → slot, with
@@ -34,6 +38,7 @@ millisecond histograms carry the ``_ms`` suffix; per-instance series are
 distinguished by labels (``engine="0"``, ``pool="1"``), never by name.
 """
 
+from . import clock
 from .costmodel import (CostModel, HardwareProfile, PROFILES,
                         TickAttribution, kv_bytes_per_token, perf_signature,
                         resolve_profile)
@@ -51,8 +56,8 @@ from .metrics import reset as _reset_metrics
 from .regression import EwmaDetector, HISTORY_TOLERANCES, check_history
 from .regression import reset as _reset_regression
 from .request_log import RequestLog, get_request_log
-from .tracing import (SpanTracer, counter, export_chrome_trace, get_tracer,
-                      instant, span)
+from .tracing import (SpanTracer, export_chrome_trace, get_tracer, instant,
+                      span)
 from .watchdog import (RetraceError, RetraceWarning, TrackedFunction,
                        track_retraces)
 
@@ -60,8 +65,8 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "LATENCY_BUCKETS_MS", "SNAPSHOT_SCHEMA_VERSION", "default_registry",
     "snapshot", "prometheus_text", "reset",
-    "SpanTracer", "get_tracer", "span", "instant", "counter",
-    "export_chrome_trace",
+    "SpanTracer", "get_tracer", "span", "instant", "export_chrome_trace",
+    "clock",
     "RequestLog", "get_request_log",
     "RetraceError", "RetraceWarning", "TrackedFunction", "track_retraces",
     "HardwareProfile", "PROFILES", "resolve_profile", "CostModel",

@@ -39,7 +39,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .. import flags as _flags
 from . import metrics as _metrics
-from . import tracing as _tracing
 from .regression import EwmaDetector
 
 __all__ = [
@@ -388,10 +387,6 @@ class TickAttribution:
         for kind, v in (("tick_ms", float(measured_ms)), ("ratio", ratio)):
             if self._stream_det[kind].observe(v):
                 self._anom.labels(engine=self._eid, kind=kind).inc()
-        tracer = _tracing.get_tracer()
-        tracer.counter("serving.tick_model",
-                       predicted_ms=pred["predicted_ms"],
-                       measured_ms=float(measured_ms))
         return pred
 
     def on_ttft(self, ms: float) -> None:

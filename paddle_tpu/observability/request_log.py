@@ -16,10 +16,10 @@ preemption-relevant wait), and — under the preemptive scheduler —
 ``preempted`` → (``swapped_out`` → ``swapped_in``)? → ``resumed``
 mid-decode cycles (any number of them per request) and a terminal
 ``retired`` with ``violation="cancelled"`` when ``cancel(rid)`` pulls
-the request mid-flight.  Each event also mirrors into the span
-tracer as a ``request.<name>`` instant with the uid as correlation arg,
-so the per-request story lines up against the host span timeline in one
-Perfetto load.
+the request mid-flight.  Event stamps (``t_ms``) run on the span
+tracer's clock — one origin, :mod:`.clock` — so the per-request story
+lines up against the host span timeline exactly
+(``clock.event_ms_to_perf_counter``).
 
 Three read surfaces:
 
@@ -54,6 +54,8 @@ import threading
 import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
+
+from . import clock as _clock
 
 __all__ = ["RequestLog", "get_request_log"]
 
@@ -90,12 +92,14 @@ class RequestLog:
             OrderedDict()
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        # the clock seam: event timestamps read (self._clock() - _t0).
-        # Simulated fleets swap both for a virtual clock so timelines
-        # (and, through transport._default_clock_ms, RPC stitching)
-        # replay byte-deterministically.
+        # the clock seam: event timestamps read (self._clock() - _t0),
+        # by default perf_counter against the layer's one origin
+        # (clock.py), which the span tracer shares.  Simulated fleets
+        # swap both for a virtual clock so timelines (and, through
+        # transport._default_clock_ms, RPC stitching) replay
+        # byte-deterministically.
         self._clock = time.perf_counter
-        self._t0 = time.perf_counter()
+        self._t0 = _clock.origin_s()
 
     # -- recording ---------------------------------------------------------
 
@@ -122,11 +126,9 @@ class RequestLog:
 
     def event(self, uid: int, name: str, t_ms: Optional[float] = None,
               **attrs: Any) -> None:
-        """Append one lifecycle event and mirror it into the span
-        tracer as a ``request.<name>`` instant with ``uid`` as the
-        correlation arg.  ``t_ms`` overrides the stamp — how a plane
-        merges a worker's shipped events at their clock-stitched plane
-        time instead of their arrival time."""
+        """Append one lifecycle event.  ``t_ms`` overrides the stamp —
+        how a plane merges a worker's shipped events at their
+        clock-stitched plane time instead of their arrival time."""
         if t_ms is None:
             t_ms = self.now_ms()
         ev = {"name": name, "t_ms": float(t_ms), "attrs": dict(attrs)}
@@ -138,9 +140,6 @@ class RequestLog:
                     self.dropped += 1
                 rec = self._records[uid] = []
             rec.append(ev)
-        from .tracing import get_tracer
-        get_tracer().instant(f"request.{name}", cat="request", uid=uid,
-                             **attrs)
 
     # -- readout -----------------------------------------------------------
 

@@ -8,17 +8,25 @@ chrome://tracing and https://ui.perfetto.dev load directly.  Nesting
 needs no parent pointers: Perfetto stacks events on one tid by ts/dur
 containment, which the context-manager discipline guarantees.
 
-Composition with device traces: :class:`paddle_tpu.profiler.RecordEvent`
-emits BOTH a ``jax.profiler.TraceAnnotation`` (so the span shows up
-inside the XLA/XPlane device dump) and a host span here — the same
-labelled region appears in the device timeline and in this exporter's
-host timeline, which is what lets queue-wait and dispatch gaps be read
-against kernel activity.
+One span call, two sinks: ``start``/``finish``/``span`` also enter and
+leave a ``jax.profiler.TraceAnnotation`` of the same name (the span's
+args as its metadata), so while a ``jax.profiler`` trace runs the same
+labelled region is in the ``.xplane.pb`` the device events are in — on
+the profiler's own clock, next to the kernels — and in this ring.  That
+is what lets an idle gap of the device be attributed to the host phase
+it fell in.  :class:`paddle_tpu.profiler.RecordEvent` is a thin wrapper
+over it.  ``enabled=False`` (``FLAGS_observability_spans`` off) turns
+both sinks into no-ops.
 
-Cost discipline: recording one span is two ``perf_counter_ns`` calls and
-one deque append under a lock — O(1) host work, no device syncs.  The
-buffer is a ring (``FLAGS_trace_buffer_events``): a long-running server
-keeps the most recent window and counts what it dropped.
+One clock: ``ts`` is microseconds of ``time.perf_counter`` since the
+origin in :mod:`.clock`, which the request log shares
+(``clock.span_ts_to_perf_counter`` and friends convert exactly).
+
+Cost discipline: recording one span is two ``perf_counter_ns`` calls, one
+deque append under a lock and one ``TraceAnnotation`` (a flag test while
+no profiler runs) — O(1) host work, no device syncs.  The buffer is a
+ring (``FLAGS_trace_buffer_events``): a long-running server keeps the
+most recent window and counts what it dropped.
 """
 
 from __future__ import annotations
@@ -32,20 +40,25 @@ from typing import Any, Dict, List, Optional
 
 from collections import deque
 
-__all__ = ["SpanTracer", "get_tracer", "span", "instant", "counter",
+import jax.profiler as _jax_profiler
+
+from . import clock as _clock
+
+__all__ = ["SpanTracer", "get_tracer", "span", "instant",
            "export_chrome_trace"]
 
 
 class _OpenSpan:
-    __slots__ = ("name", "cat", "args", "ts", "tid")
+    __slots__ = ("name", "cat", "args", "ts", "tid", "ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any],
-                 ts: float, tid: int):
+                 ts: float, tid: int, ann: Any):
         self.name = name
         self.cat = cat
         self.args = args
         self.ts = ts
         self.tid = tid
+        self.ann = ann
 
 
 class SpanTracer:
@@ -69,11 +82,11 @@ class SpanTracer:
         self._events: "deque[Dict[str, Any]]" = deque()
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        # one wall-clock origin per tracer so every span shares a timebase
-        self._t0 = time.perf_counter_ns()
 
-    def _now_us(self) -> float:
-        return (time.perf_counter_ns() - self._t0) / 1e3
+    @staticmethod
+    def _now_us() -> float:
+        # every tracer and the request log share clock.ORIGIN_NS
+        return (time.perf_counter_ns() - _clock.ORIGIN_NS) / 1e3
 
     # -- recording ---------------------------------------------------------
 
@@ -81,14 +94,20 @@ class SpanTracer:
               **args: Any) -> Optional[_OpenSpan]:
         if not self.enabled:
             return None
+        ann = _jax_profiler.TraceAnnotation(name, **args)
+        ann.__enter__()
         return _OpenSpan(name, cat, args, self._now_us(),
-                         threading.get_ident())
+                         threading.get_ident(), ann)
 
     def finish(self, span: Optional[_OpenSpan]) -> None:
-        if span is None or not self.enabled:
+        if span is None:
+            return
+        now = self._now_us()
+        span.ann.__exit__(None, None, None)
+        if not self.enabled:
             return
         ev = {"name": span.name, "cat": span.cat, "ph": "X",
-              "ts": span.ts, "dur": self._now_us() - span.ts,
+              "ts": span.ts, "dur": now - span.ts,
               "pid": self._pid, "tid": span.tid}
         if span.args:
             ev["args"] = span.args
@@ -111,21 +130,6 @@ class SpanTracer:
               "tid": threading.get_ident()}
         if args:
             ev["args"] = args
-        self._append(ev)
-
-    def counter(self, name: str, cat: str = "host",
-                **values: Any) -> None:
-        """Chrome-trace counter sample (ph "C"): one numeric series per
-        kwarg, rendered as stacked counter tracks in Perfetto.  The
-        cost model emits ``serving.tick_model`` predicted/measured
-        samples here every tick, riding next to the ``serving.step``
-        spans."""
-        if not self.enabled:
-            return
-        ev = {"name": name, "cat": cat, "ph": "C",
-              "ts": self._now_us(), "pid": self._pid,
-              "tid": threading.get_ident(),
-              "args": {k: float(v) for k, v in values.items()}}
         self._append(ev)
 
     def _append(self, ev: Dict[str, Any]) -> None:
@@ -218,10 +222,6 @@ def span(name: str, cat: str = "host", **args: Any):
 
 def instant(name: str, cat: str = "host", **args: Any) -> None:
     get_tracer().instant(name, cat, **args)
-
-
-def counter(name: str, cat: str = "host", **values: Any) -> None:
-    get_tracer().counter(name, cat, **values)
 
 
 def export_chrome_trace(path: Optional[str] = None) -> Dict[str, Any]:

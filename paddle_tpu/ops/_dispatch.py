@@ -39,6 +39,39 @@ def kernel_path_hint(op: str):
         _HINT.op = prev
 
 
+@contextlib.contextmanager
+def program_part(program: str, part: str):
+    """Name one part of a jitted program for the device trace: a
+    ``jax.named_scope(part)`` over everything traced inside, and the
+    prefix ``<program>_<part>`` on the name of every Pallas kernel built
+    inside (see :func:`kernel_name`).
+
+    XLA names a Mosaic custom call's HLO instruction after the innermost
+    name-stack entry around the ``pallas_call`` — without a ``name`` that
+    is the jitted function itself, which is why a profile used to show
+    the serving step's decode rows and its prompt chunk as one kernel
+    ``_mixed_step_impl_paged``.  With the prefix the trace says which
+    program and which part of it a kernel call served:
+    ``_step_impl_decode_rows_flash_decode`` against
+    ``_step_impl_prompt_chunk_flash_decode``.  Trace-time only, like
+    :func:`kernel_path_hint`; thread-local for the same reason."""
+    prev = getattr(_HINT, "kernel_prefix", None)
+    _HINT.kernel_prefix = f"{program}_{part}"
+    try:
+        with jax.named_scope(part):
+            yield
+    finally:
+        _HINT.kernel_prefix = prev
+
+
+def kernel_name(base: str) -> str:
+    """The ``name=`` of a ``pallas_call`` being built: ``base`` (the
+    kernel's own name), led by the innermost :func:`program_part`'s
+    ``<program>_<part>_`` when one is open."""
+    prefix = getattr(_HINT, "kernel_prefix", None)
+    return f"{prefix}_{base}" if prefix else base
+
+
 def kernel_path_op(default: str) -> str:
     """The op label a dispatch site should count under: the innermost
     active :func:`kernel_path_hint`, or ``default``."""

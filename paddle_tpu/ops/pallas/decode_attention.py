@@ -251,8 +251,9 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         bt = jnp.asarray(block_tables, jnp.int32)
         kv_len = bt.shape[1] * bk
         # pool layout: one physical block == one KV chunk == one DMA
-        k2 = k_cache.reshape(n_pool, bk, hkv * d)
-        v2 = v_cache.reshape(n_pool, bk, hkv * d)
+        with jax.named_scope("kv_relayout"):
+            k2 = k_cache.reshape(n_pool, bk, hkv * d)
+            v2 = v_cache.reshape(n_pool, bk, hkv * d)
         if quantized:
             # gather each row's scale rows through its block table here,
             # so the SMEM tables scale with the batch geometry (like the
@@ -404,6 +405,7 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name=_disp.kernel_name("flash_decode"),
     )(*scalars, qg, k2, v2)
     out = out.reshape(b, hkv, nq, tile_p, d)[:, :, :, :bq * g]
     out = out.reshape(b, hkv, nq * bq * g, d)[:, :, :rows]

@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 from . import limits as _limits
+from .._dispatch import kernel_name
 
 _LANES = _limits.LANES  # VPU lane width: m/l scratch rows padded to this
 
@@ -240,6 +241,7 @@ def _fwd(q, k, v, seg_q=None, seg_kv=None, scale: float = 1.0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=kernel_name("flash_attn_fwd"),
     )(*args)
     return out, lse  # lse lane-broadcast (b, hq, sq, _LANES); callers slice
 
@@ -397,6 +399,7 @@ def _bwd(scale, causal, interpret, res, grads):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=kernel_name("flash_attn_bwd_dq"),
     )(q, k, v, do, lse4, delta4, *seg_args)
 
     dkv_kernel = functools.partial(
@@ -433,6 +436,7 @@ def _bwd(scale, causal, interpret, res, grads):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name=kernel_name("flash_attn_bwd_dkv"),
     )(q, k, v, do, lse4, delta4, *seg_args)
     if g > 1:
         dk = dk.reshape(b, hkv, g, skv, d).sum(axis=2).astype(k.dtype)

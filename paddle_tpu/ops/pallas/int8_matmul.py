@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import limits as _limits
+from .._dispatch import kernel_name
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps: int):
@@ -110,5 +111,6 @@ def int8_matmul_pallas(x, w8, scale, block_k: int = 0, block_n: int = 0,
         out_shape=jax.ShapeDtypeStruct((rows_p, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((rows_p, bn), jnp.float32)],
         interpret=interpret,
+        name=kernel_name("int8_matmul"),
     )(x2, w8, scale.reshape(1, n))
     return out[:rows].reshape(x.shape[:-1] + (n,))

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .._dispatch import kernel_name
+
 
 def _kernel(x_ref, w_ref, o_ref, *, epsilon: float):
     xf = x_ref[...].astype(jnp.float32)
@@ -92,5 +94,6 @@ def rms_norm_pallas(x, weight=None, epsilon: float = 1e-6,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name=kernel_name("rms_norm"),
     )(*args)
     return out.reshape(x.shape)

@@ -80,25 +80,22 @@ class RecordEvent:
     """User-scope annotation visible in the trace (parity:
     paddle.profiler.RecordEvent; ≙ jax.profiler.TraceAnnotation).
 
-    Emits the scope TWICE so host and device views line up: as a jax
-    TraceAnnotation (shows up inside the XLA/XPlane device dump) and as
-    a host span in ``paddle_tpu.observability``'s tracer (shows up in
-    the Chrome-trace/Perfetto export next to the serving scheduler's
-    spans) — the same labelled region in both timelines."""
+    A thin wrapper over ``paddle_tpu.observability``'s span tracer, the
+    one mechanism the serving engine's spans use too: one call records
+    the scope as a host span (Chrome-trace/Perfetto export) AND enters a
+    jax TraceAnnotation of the same name (the XLA/XPlane device dump) —
+    the same labelled region in both timelines."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = jax.profiler.TraceAnnotation(name)
         self._span = None
 
     def begin(self):
         from .. import observability
         self._span = observability.get_tracer().start(self.name, cat="user")
-        self._ann.__enter__()
 
     def end(self):
         from .. import observability
-        self._ann.__exit__(None, None, None)
         observability.get_tracer().finish(self._span)
         self._span = None
 

@@ -161,7 +161,38 @@ from ..ops import _dispatch as _disp
 from .drafter import DraftModelDrafter, NgramDrafter
 from .kv_cache import BlockManager, init_paged_kv_cache
 
-__all__ = ["ServingEngine", "SamplingParams", "Request"]
+__all__ = ["ServingEngine", "SamplingParams", "Request", "TICK_PHASES"]
+
+#: The phases of one scheduler tick: the names of the non-overlapping
+#: child spans that tile ``serving.step`` in every step body and in both
+#: prefill waves (a wave's phases nest inside ``serving.admit`` >
+#: ``serving.prefill``).  Whoever attributes host time or a device idle
+#: gap to the tick reads these names (benchmark/harness/engine_spans.py):
+#:
+#: - ``serving.admit``: queue scan, pool reservation, swap resumes, wave
+#:   building (``_admit*``);
+#: - ``serving.grow``: block-table growth and COW over the slots,
+#:   ``ensure_capacity``, ``_flush_fresh_scales`` (paged only);
+#: - ``serving.build_inputs``: the chunk operand's assembly and the
+#:   host->device uploads (spec mode: also the draft, which builds the
+#:   verify window, in a span of its own before ``serving.grow``);
+#: - ``serving.dispatch``: the call of the step / prefill program, which
+#:   returns when the program is enqueued;
+#: - ``serving.readback``: the token fetch, the tick's one sync;
+#: - ``serving.advance``: cost-model stamp, per-slot advance, chunk
+#:   accounting, retirement, queued demotions.
+TICK_PHASES = ("serving.admit", "serving.grow", "serving.build_inputs",
+               "serving.dispatch", "serving.readback", "serving.advance")
+_ADMIT, _GROW, _BUILD, _DISPATCH, _READBACK, _ADVANCE = TICK_PHASES
+
+# The two families of device program, as ``ops._dispatch.program_part``
+# leads the names of the Pallas kernels built inside their parts
+# (``_step_impl_decode_rows_flash_decode``, ...).  The spelling is the
+# one the device trace had before kernels carried a name, when XLA named
+# a kernel after the jitted ``_*step_impl*`` / ``_prefill_impl*``
+# function it sat in: the benchmark's accepted readers find the step
+# programs' kernels by ``_\w*step_impl\w*`` on that name.
+_STEP, _PREFILL = "_step_impl", "_prefill_impl"
 
 # engine instances share the default registry; the ``engine`` label keeps
 # their series (and retrace budgets) independent
@@ -1201,11 +1232,13 @@ class ServingEngine:
                    temps, topk, topp, key):
         """One decode step for ALL slots: row i holds request state at
         position ``positions[i]``.  Compiled exactly once."""
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_STEP, "decode_rows"), \
+                bind_params(self._bind, self._prepare(params)):
             logits, cache = self.model.decode_step(
                 tokens[:, None], cache, positions)
-        nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-        nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
+            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
         return nxt, cache
 
     def _prefill_impl(self, params, cache, ids, plens, slot_ids,
@@ -1220,10 +1253,12 @@ class ServingEngine:
         nb = ids.shape[0]
         sub = init_kv_cache(self.config, nb, self.max_length,
                             quantized=self.quantized)
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_PREFILL, "wave_rows"), \
+                bind_params(self._bind, self._prepare(params)):
             logits, sub = self.model.decode_step(ids, sub, 0)
-        last = logits[jnp.arange(nb), plens - 1]           # (nb, vocab)
-        tok = sample_tokens(last, key, temps, topk, topp)
+        with jax.named_scope("sample"):
+            last = logits[jnp.arange(nb), plens - 1]       # (nb, vocab)
+            tok = sample_tokens(last, key, temps, topk, topp)
         # leaf-wise slot scatter (the int8 cache is a {kv, scale} pytree
         # with batch at axis 2 in both leaves; the fresh sub-cache's zero
         # scales reset the reused rows' quantization state for free)
@@ -1238,11 +1273,13 @@ class ServingEngine:
         rides along as a traced input, so allocation changes (slots
         deepening into fresh blocks, prefix adoptions, evictions) reach
         the device as data.  Compiled exactly once."""
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_STEP, "decode_rows"), \
+                bind_params(self._bind, self._prepare(params)):
             logits, cache = self.model.decode_step(
                 tokens[:, None], cache, positions, block_tables=tables)
-        nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-        nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
+            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
         return nxt, cache
 
     def _prefill_impl_paged(self, params, cache, ids, prefix_lens,
@@ -1258,11 +1295,13 @@ class ServingEngine:
         token samples from the logits at each row's last REAL suffix
         position.  One compilation per padded suffix-bucket length."""
         nb = ids.shape[0]
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_PREFILL, "wave_rows"), \
+                bind_params(self._bind, self._prepare(params)):
             logits, cache = self.model.decode_step(
                 ids, cache, prefix_lens, block_tables=tables)
-        last = logits[jnp.arange(nb), suffix_lens - 1]     # (nb, vocab)
-        tok = sample_tokens(last, key, temps, topk, topp)
+        with jax.named_scope("sample"):
+            last = logits[jnp.arange(nb), suffix_lens - 1]  # (nb, vocab)
+            tok = sample_tokens(last, key, temps, topk, topp)
         return tok, cache
 
     def _mixed_step_impl(self, params, cache, tokens, positions, slot_mask,
@@ -1292,19 +1331,23 @@ class ServingEngine:
         request's FIRST token when this chunk completes the prompt; the
         host discards it otherwise."""
         prep = self._prepare(params)
-        with bind_params(self._bind, prep):
+        with _disp.program_part(_STEP, "decode_rows"), \
+                bind_params(self._bind, prep):
             logits, cache = self.model.decode_step(
                 tokens[:, None], cache, positions)
-        nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-        nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        row = _slot_row(cache, cslot)
-        with bind_params(self._bind, prep):
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
+            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        with _disp.program_part(_STEP, "prompt_chunk"), \
+                bind_params(self._bind, prep):
+            row = _slot_row(cache, cslot)
             clogits, row = self.model.decode_step(
                 cids, row, cpos[None])          # (1,) per-row position
-        ctok = sample_tokens(clogits[0, clen - 1][None],
-                             jax.random.fold_in(key, 1),
-                             ctemp, ctopk, ctopp)[0]
-        cache = _slot_row_update(cache, row, cslot)
+            cache = _slot_row_update(cache, row, cslot)
+        with jax.named_scope("sample_chunk"):
+            ctok = sample_tokens(clogits[0, clen - 1][None],
+                                 jax.random.fold_in(key, 1),
+                                 ctemp, ctopk, ctopp)[0]
         return nxt, ctok, cache
 
     def _mixed_step_impl_paged(self, params, cache, tokens, positions,
@@ -1318,17 +1361,21 @@ class ServingEngine:
         and a chunk-free tick passes the all-null table itself.  No
         row slicing — the pool IS the cache for both parts."""
         prep = self._prepare(params)
-        with bind_params(self._bind, prep):
+        with _disp.program_part(_STEP, "decode_rows"), \
+                bind_params(self._bind, prep):
             logits, cache = self.model.decode_step(
                 tokens[:, None], cache, positions, block_tables=tables)
-        nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-        nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        with bind_params(self._bind, prep):
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
+            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        with _disp.program_part(_STEP, "prompt_chunk"), \
+                bind_params(self._bind, prep):
             clogits, cache = self.model.decode_step(
                 cids, cache, cpos[None], block_tables=ctable)
-        ctok = sample_tokens(clogits[0, clen - 1][None],
-                             jax.random.fold_in(key, 1),
-                             ctemp, ctopk, ctopp)[0]
+        with jax.named_scope("sample_chunk"):
+            ctok = sample_tokens(clogits[0, clen - 1][None],
+                                 jax.random.fold_in(key, 1),
+                                 ctemp, ctopk, ctopp)[0]
         return nxt, ctok, cache
 
     # -- jitted device programs: speculative decoding ----------------------
@@ -1350,13 +1397,15 @@ class ServingEngine:
         is distributed exactly as plain sampling.  The
         kernel_path_hint relabels this trace's dispatch counts as
         ``op="spec_verify"``."""
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_STEP, "verify_rows"), \
+                bind_params(self._bind, self._prepare(params)):
             with _disp.kernel_path_hint("spec_verify"):
                 logits, cache = self.model.decode_step(
                     tokens, cache, positions, block_tables=block_tables)
-        out, n_acc = accept_draft_tokens(
-            logits, tokens[:, 1:], draft_ok, key, temps, topk, topp,
-            pad_token_id=self.pad_token_id, draft_probs=draft_probs)
+        with jax.named_scope("accept"):
+            out, n_acc = accept_draft_tokens(
+                logits, tokens[:, 1:], draft_ok, key, temps, topk, topp,
+                pad_token_id=self.pad_token_id, draft_probs=draft_probs)
         return out, n_acc, cache
 
     def _spec_step_impl(self, params, cache, tokens, positions, slot_mask,
@@ -1407,13 +1456,15 @@ class ServingEngine:
             temps, topk, topp, key)
         out = jnp.where(slot_mask[:, None], out,
                         jnp.int32(self.pad_token_id))
-        row = _slot_row(cache, cslot)
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_STEP, "prompt_chunk"), \
+                bind_params(self._bind, self._prepare(params)):
+            row = _slot_row(cache, cslot)
             clogits, row = self.model.decode_step(cids, row, cpos[None])
-        ctok = sample_tokens(clogits[0, clen - 1][None],
-                             jax.random.fold_in(key, 1),
-                             ctemp, ctopk, ctopp)[0]
-        cache = _slot_row_update(cache, row, cslot)
+            cache = _slot_row_update(cache, row, cslot)
+        with jax.named_scope("sample_chunk"):
+            ctok = sample_tokens(clogits[0, clen - 1][None],
+                                 jax.random.fold_in(key, 1),
+                                 ctemp, ctopk, ctopp)[0]
         return out, n_acc, ctok, cache
 
     def _spec_mixed_step_impl_paged(self, params, cache, tokens,
@@ -1428,12 +1479,14 @@ class ServingEngine:
             temps, topk, topp, key, block_tables=tables)
         out = jnp.where(slot_mask[:, None], out,
                         jnp.int32(self.pad_token_id))
-        with bind_params(self._bind, self._prepare(params)):
+        with _disp.program_part(_STEP, "prompt_chunk"), \
+                bind_params(self._bind, self._prepare(params)):
             clogits, cache = self.model.decode_step(
                 cids, cache, cpos[None], block_tables=ctable)
-        ctok = sample_tokens(clogits[0, clen - 1][None],
-                             jax.random.fold_in(key, 1),
-                             ctemp, ctopk, ctopp)[0]
+        with jax.named_scope("sample_chunk"):
+            ctok = sample_tokens(clogits[0, clen - 1][None],
+                                 jax.random.fold_in(key, 1),
+                                 ctemp, ctopk, ctopp)[0]
         return out, n_acc, ctok, cache
 
     # -- public API --------------------------------------------------------
@@ -2105,39 +2158,43 @@ class ServingEngine:
         self._tracer.instant("serving.cancelled", rid=req.request_id)
 
     def _step_inner(self) -> List[int]:
-        finished = self._admit()
-        occ = int(self._active.sum())
-        self._set_occupancy(occ)
+        span = self._tracer.span
+        with span(_ADMIT):
+            finished = self._admit()
+            occ = int(self._active.sum())
+            self._set_occupancy(occ)
         if not occ:
             return finished
         self._ticks += 1
-        key = jax.random.fold_in(self._base_key, self._ticks)
         t0 = self._clock()
-        with self._tracer.span("serving.decode", slots=occ):
+        with span("serving.decode", slots=occ):
             if self.paged:
-                for i, slot in enumerate(self._slots):
-                    if slot is None:
-                        continue
-                    # this tick writes K/V at positions[i]
-                    self._grow_row_for_writes(i, int(self._positions[i]))
-                self._flush_fresh_scales()
+                with span(_GROW):
+                    for i, slot in enumerate(self._slots):
+                        if slot is None:
+                            continue
+                        # this tick writes K/V at positions[i]
+                        self._grow_row_for_writes(
+                            i, int(self._positions[i]))
+                    self._flush_fresh_scales()
+            with span(_BUILD):
+                tables = ((jnp.asarray(self._tables),) if self.paged
+                          else ())
+                args = (jnp.asarray(self._tokens),
+                        jnp.asarray(self._positions), *tables,
+                        jnp.asarray(self._active), jnp.asarray(self._temps),
+                        jnp.asarray(self._topk), jnp.asarray(self._topp),
+                        jax.random.fold_in(self._base_key, self._ticks))
+            with span(_DISPATCH):
                 nxt, self._cache = self._step_fn(
-                    self._params, self._cache,
-                    jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                    jnp.asarray(self._tables), jnp.asarray(self._active),
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), key)
-            else:
-                nxt, self._cache = self._step_fn(
-                    self._params, self._cache,
-                    jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                    jnp.asarray(self._active), jnp.asarray(self._temps),
-                    jnp.asarray(self._topk), jnp.asarray(self._topp), key)
-            nxt = np.asarray(nxt)        # the tick's one host sync
+                    self._params, self._cache, *args)
+            with span(_READBACK):
+                nxt = np.asarray(nxt)    # the tick's one host sync
         now = self._clock()
-        self._m_step_ms.observe((now - t0) * 1e3)
-        self._perf_tick((now - t0) * 1e3, occ)
-        finished.extend(self._advance_decode(nxt, now))
+        with span(_ADVANCE):
+            self._m_step_ms.observe((now - t0) * 1e3)
+            self._perf_tick((now - t0) * 1e3, occ)
+            finished.extend(self._advance_decode(nxt, now))
         return finished
 
     def _advance_decode(self, nxt: np.ndarray, now: float) -> List[int]:
@@ -2236,52 +2293,57 @@ class ServingEngine:
         ONE verify step over every slot's (k+1)-token window.  Each row
         commits 1..k+1 tokens; the weight stream — the b=1 bound
         BENCH_DECODE.json proves — is paid once either way."""
-        finished = self._admit()
-        occ = int(self._active.sum())
-        self._set_occupancy(occ)
+        span = self._tracer.span
+        with span(_ADMIT):
+            finished = self._admit()
+            occ = int(self._active.sum())
+            self._set_occupancy(occ)
         if not occ:
             return finished
-        with self._tracer.span("serving.draft"):
+        # the draft builds the verify window, and growth below needs its
+        # real span: an input-building phase of its own, before the grow
+        with span(_BUILD), span("serving.draft"):
             drafts, draft_ok, draft_probs = self._propose_drafts()
-        window = np.concatenate([self._tokens[:, None], drafts], axis=1)
+            window = np.concatenate([self._tokens[:, None], drafts],
+                                    axis=1)
         self._ticks += 1
-        key = jax.random.fold_in(self._base_key, self._ticks)
         t0 = self._clock()
-        with self._tracer.span("serving.verify", slots=occ,
-                               drafted=int(draft_ok.sum())):
+        with span("serving.verify", slots=occ,
+                  drafted=int(draft_ok.sum())):
             if self.paged:
-                for i, slot in enumerate(self._slots):
-                    if slot is None:
-                        continue
-                    # grow/privatise over the row's REAL draft span only:
-                    # pad-column writes past the chain steer to the null
-                    # block, so no block is ever allocated for a draft
-                    # that was never proposed
-                    self._grow_row_for_writes(
-                        i, int(self._positions[i])
-                        + int(draft_ok[i].sum()))
-                self._flush_fresh_scales()
+                with span(_GROW):
+                    for i, slot in enumerate(self._slots):
+                        if slot is None:
+                            continue
+                        # grow/privatise over the row's REAL draft span
+                        # only: pad-column writes past the chain steer to
+                        # the null block, so no block is ever allocated
+                        # for a draft that was never proposed
+                        self._grow_row_for_writes(
+                            i, int(self._positions[i])
+                            + int(draft_ok[i].sum()))
+                    self._flush_fresh_scales()
+            with span(_BUILD):
+                tables = ((jnp.asarray(self._tables),) if self.paged
+                          else ())
+                args = (jnp.asarray(window), jnp.asarray(self._positions),
+                        *tables, jnp.asarray(self._active),
+                        jnp.asarray(draft_ok), jnp.asarray(draft_probs),
+                        jnp.asarray(self._temps), jnp.asarray(self._topk),
+                        jnp.asarray(self._topp),
+                        jax.random.fold_in(self._base_key, self._ticks))
+            with span(_DISPATCH):
                 out, n_acc, self._cache = self._step_fn(
-                    self._params, self._cache, jnp.asarray(window),
-                    jnp.asarray(self._positions), jnp.asarray(self._tables),
-                    jnp.asarray(self._active), jnp.asarray(draft_ok),
-                    jnp.asarray(draft_probs),
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), key)
-            else:
-                out, n_acc, self._cache = self._step_fn(
-                    self._params, self._cache, jnp.asarray(window),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._active), jnp.asarray(draft_ok),
-                    jnp.asarray(draft_probs),
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), key)
-            out, n_acc = jax.device_get((out, n_acc))  # the one host sync
+                    self._params, self._cache, *args)
+            with span(_READBACK):
+                # the one host sync
+                out, n_acc = jax.device_get((out, n_acc))
         now = self._clock()
-        self._m_step_ms.observe((now - t0) * 1e3)
-        self._perf_tick((now - t0) * 1e3, occ)
-        finished.extend(self._advance_decode_spec(
-            np.asarray(out), np.asarray(n_acc), draft_ok, now))
+        with span(_ADVANCE):
+            self._m_step_ms.observe((now - t0) * 1e3)
+            self._perf_tick((now - t0) * 1e3, occ)
+            finished.extend(self._advance_decode_spec(
+                np.asarray(out), np.asarray(n_acc), draft_ok, now))
         return finished
 
     def _advance_decode_spec(self, out: np.ndarray, n_acc: np.ndarray,
@@ -2359,33 +2421,26 @@ class ServingEngine:
         ``prefill_chunk``-token slice of the admitted prompt.  A long
         prompt therefore costs a bounded latency bump per tick instead
         of stalling every in-flight decode for its whole prefill."""
-        finished = self._admit_chunked()
-        occ = int(self._active.sum())
-        self._set_occupancy(occ)
-        pf = self._prefill
-        self._m_chunk_queue.observe(self._pending_chunks())
-        # decode-priority policy: while decodes are active, pending
-        # chunks run on alternate ticks only (odd _ticks), halving the
-        # prompt-ingest rate to shave the mixed-step TPOT bump
-        do_chunk = pf is not None and (
-            self._chunk_policy == "prefill" or occ == 0
-            or self._ticks % 2 == 1)
+        span = self._tracer.span
+        with span(_ADMIT):
+            finished = self._admit_chunked()
+            occ = int(self._active.sum())
+            self._set_occupancy(occ)
+            pf = self._prefill
+            self._m_chunk_queue.observe(self._pending_chunks())
+            # decode-priority policy: while decodes are active, pending
+            # chunks run on alternate ticks only (odd _ticks), halving the
+            # prompt-ingest rate to shave the mixed-step TPOT bump
+            do_chunk = pf is not None and (
+                self._chunk_policy == "prefill" or occ == 0
+                or self._ticks % 2 == 1)
         if not occ and not do_chunk:
             return finished
         self._ticks += 1
-        key = jax.random.fold_in(self._base_key, self._ticks)
         ch = self.prefill_chunk
-        cids = np.full((1, ch), self.pad_token_id, np.int32)
-        ctemp = np.zeros((1,), np.float32)
-        ctopk = np.zeros((1,), np.int32)
-        ctopp = np.ones((1,), np.float32)
         if do_chunk:
             clen = min(ch, pf.req.prompt.size - pf.cursor)
-            cids[0, :clen] = pf.req.prompt[pf.cursor:pf.cursor + clen]
             cpos, cslot = pf.cursor, pf.slot
-            sp = pf.req.sampling
-            ctemp[0], ctopk[0], ctopp[0] = (sp.temperature, sp.top_k,
-                                            sp.top_p)
         else:
             # chunk-free tick, same compiled program: contiguous writes
             # drop past max_length, paged writes land in the null block
@@ -2395,90 +2450,97 @@ class ServingEngine:
             # spec × chunked: the decode half becomes the verify window.
             # A prefilling slot is inactive until its cursor completes,
             # so its spec window is suspended by construction.
-            with self._tracer.span("serving.draft"):
+            with span(_BUILD), span("serving.draft"):
                 drafts, draft_ok, draft_probs = self._propose_drafts()
-            window = np.concatenate([self._tokens[:, None], drafts],
-                                    axis=1)
+                window = np.concatenate([self._tokens[:, None], drafts],
+                                        axis=1)
         t0 = self._clock()
-        chunk_span = (self._tracer.span("serving.chunk", slot=cslot,
-                                        start=cpos, tokens=clen)
+        chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
+                           tokens=clen)
                       if do_chunk else contextlib.nullcontext())
-        decode_span = self._tracer.span(
+        decode_span = span(
             "serving.verify" if self.spec else "serving.decode",
             slots=occ)
         with decode_span, chunk_span:
             if self.paged:
-                for i, slot in enumerate(self._slots):
-                    if slot is None:
-                        continue
-                    last = int(self._positions[i])
-                    if self.spec:
-                        last += int(draft_ok[i].sum())
-                    self._grow_row_for_writes(i, last)
+                with span(_GROW):
+                    for i, slot in enumerate(self._slots):
+                        if slot is None:
+                            continue
+                        last = int(self._positions[i])
+                        if self.spec:
+                            last += int(draft_ok[i].sum())
+                        self._grow_row_for_writes(i, last)
+                    if do_chunk:
+                        # grow the chain to cover this chunk's real
+                        # tokens; pad-tail positions fall past the chain
+                        # and steer to the null block (the admission
+                        # reservation makes the growth infallible)
+                        self.kv.ensure_capacity(cslot, cpos + clen - 1)
+                        ctable = self.kv.table_row(cslot,
+                                                   self.max_blocks)[None]
+                    else:
+                        ctable = np.zeros((1, self.max_blocks), np.int32)
+                    self._flush_fresh_scales()
+            with span(_BUILD):
+                # the chunk operand
+                cids = np.full((1, ch), self.pad_token_id, np.int32)
+                ctemp = np.zeros((1,), np.float32)
+                ctopk = np.zeros((1,), np.int32)
+                ctopp = np.ones((1,), np.float32)
                 if do_chunk:
-                    # grow the chain to cover this chunk's real tokens;
-                    # pad-tail positions fall past the chain and steer to
-                    # the null block (the admission reservation makes the
-                    # growth infallible)
-                    self.kv.ensure_capacity(cslot, cpos + clen - 1)
-                    ctable = self.kv.table_row(cslot,
-                                               self.max_blocks)[None]
+                    cids[0, :clen] = pf.req.prompt[
+                        pf.cursor:pf.cursor + clen]
+                    sp = pf.req.sampling
+                    ctemp[0], ctopk[0], ctopp[0] = (
+                        sp.temperature, sp.top_k, sp.top_p)
+                if self.paged:
+                    pos = self._positions
+                    tables = (jnp.asarray(self._tables),)
+                    cdst = jnp.asarray(ctable)
                 else:
-                    ctable = np.zeros((1, self.max_blocks), np.int32)
-                self._flush_fresh_scales()
-                head = ((jnp.asarray(window), jnp.asarray(self._positions),
-                         jnp.asarray(self._tables),
-                         jnp.asarray(self._active), jnp.asarray(draft_ok),
-                         jnp.asarray(draft_probs))
-                        if self.spec else
-                        (jnp.asarray(self._tokens),
-                         jnp.asarray(self._positions),
-                         jnp.asarray(self._tables),
-                         jnp.asarray(self._active)))
-                res = self._step_fn(
-                    self._params, self._cache, *head,
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp),
-                    jnp.asarray(cids), jnp.int32(cpos), jnp.int32(clen),
-                    jnp.asarray(ctable), jnp.asarray(ctemp),
-                    jnp.asarray(ctopk), jnp.asarray(ctopp), key)
-            else:
-                # non-decoding rows (idle or mid-prefill) write at
-                # max_length so the scatter drops them — chunked prefill
-                # owns those rows' contents now
-                dev_pos = np.where(self._active, self._positions,
+                    # non-decoding rows (idle or mid-prefill) write at
+                    # max_length so the scatter drops them — chunked
+                    # prefill owns those rows' contents now
+                    pos = np.where(self._active, self._positions,
                                    self.max_length).astype(np.int32)
-                head = ((jnp.asarray(window), jnp.asarray(dev_pos),
+                    tables = ()
+                    cdst = jnp.int32(cslot)
+                head = ((jnp.asarray(window), jnp.asarray(pos), *tables,
                          jnp.asarray(self._active), jnp.asarray(draft_ok),
                          jnp.asarray(draft_probs))
                         if self.spec else
-                        (jnp.asarray(self._tokens), jnp.asarray(dev_pos),
-                         jnp.asarray(self._active)))
-                res = self._step_fn(
-                    self._params, self._cache, *head,
-                    jnp.asarray(self._temps), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp),
-                    jnp.asarray(cids), jnp.int32(cpos), jnp.int32(clen),
-                    jnp.int32(cslot), jnp.asarray(ctemp),
-                    jnp.asarray(ctopk), jnp.asarray(ctopp), key)
-            if self.spec:
-                out, n_acc, ctok, self._cache = res
-                out, n_acc, ctok = jax.device_get((out, n_acc, ctok))
-            else:
-                nxt, ctok, self._cache = res
-                nxt, ctok = jax.device_get((nxt, ctok))  # the one sync
+                        (jnp.asarray(self._tokens), jnp.asarray(pos),
+                         *tables, jnp.asarray(self._active)))
+                args = (*head, jnp.asarray(self._temps),
+                        jnp.asarray(self._topk), jnp.asarray(self._topp),
+                        jnp.asarray(cids), jnp.int32(cpos),
+                        jnp.int32(clen), cdst, jnp.asarray(ctemp),
+                        jnp.asarray(ctopk), jnp.asarray(ctopp),
+                        jax.random.fold_in(self._base_key, self._ticks))
+            with span(_DISPATCH):
+                res = self._step_fn(self._params, self._cache, *args)
+            with span(_READBACK):      # the one sync
+                if self.spec:
+                    out, n_acc, ctok, self._cache = res
+                    out, n_acc, ctok = jax.device_get((out, n_acc, ctok))
+                else:
+                    nxt, ctok, self._cache = res
+                    nxt, ctok = jax.device_get((nxt, ctok))
         now = self._clock()
-        self._m_step_ms.observe((now - t0) * 1e3)
-        self._perf_tick((now - t0) * 1e3, occ,
-                        chunk_tokens=clen if do_chunk else 0)
-        if self.spec:
-            finished.extend(self._advance_decode_spec(
-                np.asarray(out), np.asarray(n_acc), draft_ok, now))
-        else:
-            finished.extend(self._advance_decode(np.asarray(nxt), now))
-        if do_chunk:
-            finished.extend(self._advance_chunk(pf, clen, int(ctok), now))
-        self._apply_demotions()
+        with span(_ADVANCE):
+            self._m_step_ms.observe((now - t0) * 1e3)
+            self._perf_tick((now - t0) * 1e3, occ,
+                            chunk_tokens=clen if do_chunk else 0)
+            if self.spec:
+                finished.extend(self._advance_decode_spec(
+                    np.asarray(out), np.asarray(n_acc), draft_ok, now))
+            else:
+                finished.extend(self._advance_decode(np.asarray(nxt), now))
+            if do_chunk:
+                finished.extend(
+                    self._advance_chunk(pf, clen, int(ctok), now))
+            self._apply_demotions()
         return finished
 
     def _admit_chunked(self) -> List[int]:
@@ -3372,16 +3434,28 @@ class ServingEngine:
         self._m_waves.inc()
         self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
         self._ticks += 1
-        key = jax.random.fold_in(self._base_key, self._ticks)
-        self._flush_fresh_scales()
-        with self._tracer.span("serving.prefill", bucket=bucket,
-                               rows=len(wave)):
-            tok, self._cache = self._prefill_fn(
-                self._params, self._cache, jnp.asarray(ids),
-                jnp.asarray(prefix), jnp.asarray(slens),
-                jnp.asarray(tables), jnp.asarray(temps),
-                jnp.asarray(topk), jnp.asarray(topp), key)
-            tok = np.asarray(tok)
+        span = self._tracer.span
+        with span(_GROW):
+            self._flush_fresh_scales()
+        with span("serving.prefill", bucket=bucket, rows=len(wave),
+                  padded_rows=nb, tokens=int(slens[:len(wave)].sum())):
+            with span(_BUILD):
+                args = (jnp.asarray(ids), jnp.asarray(prefix),
+                        jnp.asarray(slens), jnp.asarray(tables),
+                        jnp.asarray(temps), jnp.asarray(topk),
+                        jnp.asarray(topp),
+                        jax.random.fold_in(self._base_key, self._ticks))
+            with span(_DISPATCH):
+                tok, self._cache = self._prefill_fn(
+                    self._params, self._cache, *args)
+            with span(_READBACK):
+                tok = np.asarray(tok)
+        with span(_ADVANCE):
+            return self._finish_wave_paged(wave, tok, temps, topk, topp)
+
+    def _finish_wave_paged(self, wave, tok, temps, topk, topp) -> List[int]:
+        """Per-row bookkeeping after a paged wave's token fetch: queued
+        demotions, then each row's slot state and first token."""
         self._apply_demotions()
         t_tok = self._clock()
         finished: List[int] = []
@@ -3463,15 +3537,26 @@ class ServingEngine:
         self._m_waves.inc()
         self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
         self._ticks += 1
-        key = jax.random.fold_in(self._base_key, self._ticks)
-        with self._tracer.span("serving.prefill", bucket=bucket,
-                               rows=len(wave)):
-            tok, self._cache = self._prefill_fn(
-                self._params, self._cache, jnp.asarray(ids),
-                jnp.asarray(plens), jnp.asarray(slot_ids),
-                jnp.asarray(temps), jnp.asarray(topk),
-                jnp.asarray(topp), key)
-            tok = np.asarray(tok)
+        span = self._tracer.span
+        with span("serving.prefill", bucket=bucket, rows=len(wave),
+                  padded_rows=nb, tokens=int(plens[:len(wave)].sum())):
+            with span(_BUILD):
+                args = (jnp.asarray(ids), jnp.asarray(plens),
+                        jnp.asarray(slot_ids), jnp.asarray(temps),
+                        jnp.asarray(topk), jnp.asarray(topp),
+                        jax.random.fold_in(self._base_key, self._ticks))
+            with span(_DISPATCH):
+                tok, self._cache = self._prefill_fn(
+                    self._params, self._cache, *args)
+            with span(_READBACK):
+                tok = np.asarray(tok)
+        with span(_ADVANCE):
+            return self._finish_wave(wave, slots, tok, plens, temps, topk,
+                                     topp)
+
+    def _finish_wave(self, wave, slots, tok, plens, temps, topk,
+                     topp) -> List[int]:
+        """Per-row bookkeeping after a contiguous wave's token fetch."""
         t_tok = self._clock()
         finished: List[int] = []
         for r, (req, si) in enumerate(zip(wave, slots)):

@@ -4,8 +4,8 @@ The acceptance sweep: every clean cache layout (the same 8 the
 static-analysis CLI lints) drains a small trace with ZERO drift
 findings; a scripted-clock engine whose ticks are artificially slowed
 after calibration produces a structured perf-drift Finding and trips
-the anomaly counters; the Perfetto export carries a
-``serving.tick_model`` counter track next to the step spans; and the
+the anomaly counters; every modeled tick is one
+``perf.tick_model_ratio`` sample (and no span-ring write); and the
 metrics registry's label-cardinality guard coalesces offender families
 into an overflow child.
 """
@@ -152,30 +152,27 @@ def test_scripted_slow_tick_produces_drift_finding(lm, monkeypatch):
     assert eng.perf_report()["drift"] == []
 
 
-# -- Perfetto counter track --------------------------------------------------
+# -- one sample per modeled tick ---------------------------------------------
 
-def test_tick_model_counter_track_in_chrome_trace(lm, tmp_path):
+def test_one_ratio_sample_per_modeled_tick_and_none_in_the_span_ring(
+        lm, tmp_path):
+    """Every modeled tick lands once in ``perf.tick_model_ratio`` (what
+    ``perf_report()`` and the Prometheus text read); the span ring gets
+    no per-tick counter sample beside the ``serving.step`` spans, and the
+    Chrome-trace export stays loadable."""
     eng = ServingEngine(lm, num_slots=2, max_length=MAXLEN)
     eng.submit(_prompt(5, seed=60), max_new_tokens=6)
     eng.drain()
     path = tmp_path / "trace.json"
     obs.export_chrome_trace(str(path))
-    loaded = json.loads(path.read_text())   # the file stays loadable
-    events = loaded["traceEvents"]
-    counters = [e for e in events
-                if e.get("ph") == "C" and e["name"] == "serving.tick_model"]
-    steps = [e for e in events
-             if e.get("ph") == "X" and e["name"] == "serving.step"]
-    assert steps, "no step spans in the export"
-    assert counters, "no tick_model counter track"
-    # one counter sample per modeled tick, alongside the step spans
-    assert len(counters) == eng.perf_report()["ticks_modeled"]
-    for e in counters:
-        assert set(e["args"]) == {"predicted_ms", "measured_ms"}
-        assert all(isinstance(v, float) for v in e["args"].values())
-        assert e["args"]["predicted_ms"] > 0
-        for k in ("ts", "pid", "tid", "cat"):
-            assert k in e
+    events = json.loads(path.read_text())["traceEvents"]
+    assert [e for e in events
+            if e.get("ph") == "X" and e["name"] == "serving.step"]
+    assert not [e for e in events if e.get("ph") == "C"]
+    fam = obs.snapshot()["perf.tick_model_ratio"]["series"]
+    samples = sum(row["count"] for row in fam
+                  if row["labels"]["engine"] == eng._eid)
+    assert samples == eng.perf_report()["ticks_modeled"] > 0
 
 
 # -- metrics label-cardinality guard -----------------------------------------

@@ -69,14 +69,22 @@ def test_signature_strips_ids_and_timings():
     assert other.timeline_signature() != run("0", 1.25)
 
 
-def test_events_mirror_into_span_tracer():
+def test_events_stay_out_of_the_span_ring_and_share_its_clock():
+    """A request event is stored once, in the log (it used to be copied
+    into the span ring as a ``request.*`` instant nobody read); the log's
+    ``t_ms`` and a span's ``ts`` run on one origin, so the two timelines
+    line up by conversion, not by copying."""
     log = obs.get_request_log()
     u = log.new_uid()
-    log.event(u, "submitted", prompt_len=4)
-    evs = [e for e in obs.get_tracer().events()
-           if e["name"] == "request.submitted"]
-    assert evs and evs[-1]["args"]["uid"] == u
-    assert evs[-1]["cat"] == "request"
+    with obs.span("around"):
+        log.event(u, "submitted", prompt_len=4)
+    evs = obs.get_tracer().events()
+    assert not [e for e in evs if e["name"].startswith("request.")]
+    sp = [e for e in evs if e["name"] == "around"][-1]
+    t_ev = obs.clock.event_ms_to_perf_counter(log.timeline(u)[0]["t_ms"])
+    t0 = obs.clock.span_ts_to_perf_counter(sp["ts"])
+    t1 = obs.clock.span_ts_to_perf_counter(sp["ts"] + sp["dur"])
+    assert t0 <= t_ev <= t1
 
 
 def test_bounded_store_drops_oldest_whole_requests():

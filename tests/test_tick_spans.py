@@ -1,0 +1,272 @@
+"""The serving tick measured from inside (ISSUE 24).
+
+One span call, two sinks, one clock: the engine's phase spans
+(``serving.engine.TICK_PHASES``) tile ``serving.step`` in the tracer's ring
+AND reach a ``jax.profiler`` trace as ``TraceAnnotation``s; span ``ts``,
+request-log ``t_ms`` and ``perf_counter`` stamps convert into each other;
+``FLAGS_observability_spans`` off silences both sinks; and every Pallas
+kernel carries a name the device trace can show.
+"""
+
+import ast
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu import observability as obs
+from paddle_tpu.serving import ServingEngine
+from paddle_tpu.serving.engine import TICK_PHASES
+
+MAXLEN = 64
+MODES = {"wave": {}, "chunked": {"chunked": True, "prefill_chunk": 8},
+         "spec": {"spec_decode": True, "spec_k": 2}}
+PROMPTS = (5, 11)
+
+
+@pytest.fixture(scope="module")
+def lm():
+    from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
+
+    pt.seed(7)
+    model = LlamaForCausalLM(tiny_llama_config(context_parallel="gspmd"))
+    model.eval()
+    return model
+
+
+def _prompt(n, seed):
+    return np.random.RandomState(seed).randint(0, 256, n).astype(np.int32)
+
+
+def _engine(lm, mode):
+    eng = ServingEngine(lm, num_slots=3, max_length=MAXLEN, paged=True,
+                        block_len=8, **MODES[mode])
+    for i, n in enumerate(PROMPTS):
+        eng.submit(_prompt(n, i + 1), max_new_tokens=6)
+    return eng
+
+
+def _inside(a, b):
+    return (b["ts"] <= a["ts"]
+            and a["ts"] + a["dur"] <= b["ts"] + b["dur"] + 1e-6)
+
+
+def _tick_events():
+    """(the one serving.step span, the phase spans) of the ring, which the
+    caller cleared before the tick."""
+    evs = [e for e in obs.get_tracer().events() if e["ph"] == "X"]
+    (step,) = [e for e in evs if e["name"] == "serving.step"]
+    return step, [e for e in evs if e["name"] in TICK_PHASES], evs
+
+
+# -- (a) the tick in phases --------------------------------------------------
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_tick_phases_tile_the_step(lm, mode):
+    eng = _engine(lm, mode)
+    for tick in range(3):
+        obs.get_tracer().clear()
+        eng.step()
+        step, phases, evs = _tick_events()
+        # exactly the engine's tuple, every tick, in every step body
+        assert {e["name"] for e in phases} == set(TICK_PHASES)
+        assert "tick" in step["args"]
+        for e in evs:
+            if e["name"].startswith("serving."):
+                assert _inside(e, step) and e["tid"] == step["tid"]
+        # nested or disjoint, never overlapping
+        for a in phases:
+            for b in phases:
+                if a is not b and not (_inside(a, b) or _inside(b, a)):
+                    assert (a["ts"] + a["dur"] <= b["ts"] + 1e-6
+                            or b["ts"] + b["dur"] <= a["ts"] + 1e-6)
+        # the outermost phases tile the step.  The share is a statement
+        # about the chip's 80 ms ticks (PERF.md: under 2 %); a warm tick
+        # of this model on a CPU is 2-4 ms, of which the spans' own
+        # bookkeeping is ~0.1 ms, so warm ticks get an absolute bound and
+        # the cold one (it compiles inside serving.dispatch) the share
+        top = [e for e in phases
+               if not any(o is not e and _inside(e, o) for o in phases)]
+        outside = step["dur"] - sum(e["dur"] for e in top)
+        assert 0 <= outside < 1000.0                          # us
+        if tick == 0:
+            assert outside < 0.02 * step["dur"]
+
+
+@pytest.mark.parametrize("mode", ["wave", "spec"])
+def test_wave_prefill_span_says_what_it_padded(lm, mode):
+    eng = _engine(lm, mode)
+    obs.get_tracer().clear()
+    eng.step()
+    step, phases, evs = _tick_events()
+    (wave,) = [e for e in evs if e["name"] == "serving.prefill"]
+    (admit,) = [e for e in phases if e["name"] == "serving.admit"]
+    assert _inside(wave, admit)
+    # both requests enter in one wave: padded to prefill_batch rows of the
+    # longest prompt's power-of-two bucket
+    assert wave["args"] == {"bucket": 16, "rows": len(PROMPTS),
+                            "padded_rows": eng.prefill_batch,
+                            "tokens": sum(PROMPTS)}
+    # the wave's own upload, launch and fetch are phases inside it
+    inner = {e["name"] for e in phases if _inside(e, wave)}
+    assert inner == {"serving.build_inputs", "serving.dispatch",
+                     "serving.readback"}
+
+
+# -- (b) the same spans in a jax.profiler trace ------------------------------
+
+def test_phases_reach_the_profiler_trace(lm, tmp_path):
+    from jax.profiler import ProfileData
+
+    eng = _engine(lm, "chunked")
+    eng.step()                                   # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.step()
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serving.")]
+    steps = [sp for sp in spans if sp[0] == "serving.step"]
+    assert len(steps) == 2
+    for name in TICK_PHASES:
+        mine = [sp for sp in spans if sp[0] == name]
+        assert len(mine) >= 2, name
+        for _, s, e in mine:
+            assert any(s0 <= s and e <= e0 for _, s0, e0 in steps)
+
+
+# -- (c) one clock -----------------------------------------------------------
+
+def test_span_ts_request_t_ms_and_perf_counter_are_one_clock():
+    clock = obs.clock
+    log = obs.get_request_log()
+    uid = log.new_uid()
+    t_before = time.perf_counter()
+    with obs.span("probe"):
+        log.event(uid, "submitted")
+    t_after = time.perf_counter()
+    (sp,) = [e for e in obs.get_tracer().events() if e["name"] == "probe"]
+    t_span = clock.span_ts_to_perf_counter(sp["ts"])
+    t_event = clock.event_ms_to_perf_counter(log.timeline(uid)[0]["t_ms"])
+    # exact, not "within a millisecond": the converted stamps lie between
+    # the two perf_counter() readings taken around them
+    assert t_before <= t_span <= t_event <= t_after
+    # and back, exactly
+    assert clock.perf_counter_to_span_ts(t_span) == pytest.approx(
+        sp["ts"], abs=1e-3)
+    assert clock.perf_counter_to_event_ms(t_event) == pytest.approx(
+        log.timeline(uid)[0]["t_ms"], abs=1e-6)
+    # a private tracer and a private log share the origin too
+    other = obs.SpanTracer(max_events=4, enabled=True)
+    with other.span("again"):
+        pass
+    assert clock.span_ts_to_perf_counter(other.events()[0]["ts"]) \
+        == pytest.approx(time.perf_counter(), abs=0.5)
+    assert clock.origin_s() == obs.RequestLog(max_requests=1)._t0
+
+
+# -- (d) the flag silences both sinks ----------------------------------------
+
+class _CountingAnnotation:
+    entered = exited = 0
+
+    def __init__(self, name, **kw):
+        self.name = name
+
+    def __enter__(self):
+        type(self).entered += 1
+        return self
+
+    def __exit__(self, *exc):
+        type(self).exited += 1
+
+
+def test_spans_off_records_nothing_and_enters_no_annotation(
+        lm, monkeypatch):
+    import jax.profiler
+
+    _CountingAnnotation.entered = _CountingAnnotation.exited = 0
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _CountingAnnotation)
+    eng = _engine(lm, "wave")
+    tracer = obs.get_tracer()
+    eng.step()                                    # on: both sinks written
+    on = len(tracer.events())
+    assert on and _CountingAnnotation.entered == on \
+        == _CountingAnnotation.exited
+    tracer.clear()
+    monkeypatch.setattr(tracer, "enabled", False)
+    eng.step()                                    # off: neither
+    from paddle_tpu.profiler import RecordEvent
+    with RecordEvent("user_scope"):
+        pass
+    assert tracer.events() == []
+    assert _CountingAnnotation.entered == on
+
+
+# -- (e) kernels carry names -------------------------------------------------
+
+_PALLAS_DIR = os.path.join(os.path.dirname(pt.__file__), "ops", "pallas")
+_CALL_SITES = [("decode_attention.py", 0), ("flash_attention.py", 0),
+               ("flash_attention.py", 1), ("flash_attention.py", 2),
+               ("int8_matmul.py", 0), ("rms_norm.py", 0)]
+
+
+def _pallas_calls(filename):
+    with open(os.path.join(_PALLAS_DIR, filename)) as f:
+        tree = ast.parse(f.read())
+    return [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute)
+            and n.func.attr == "pallas_call"]
+
+
+def test_the_call_sites_are_the_six():
+    found = [(os.path.basename(p), i)
+             for p in sorted(glob.glob(os.path.join(_PALLAS_DIR, "*.py")))
+             for i in range(len(_pallas_calls(os.path.basename(p))))]
+    assert found == _CALL_SITES
+
+
+@pytest.mark.parametrize("filename,index", _CALL_SITES)
+def test_every_pallas_call_has_a_name(filename, index):
+    call = sorted(_pallas_calls(filename), key=lambda n: n.lineno)[index]
+    (name,) = [k.value for k in call.keywords if k.arg == "name"]
+    # built by ops._dispatch.kernel_name, so a program part can lead it
+    assert isinstance(name, ast.Call) and "kernel_name" in ast.dump(name.func)
+    assert isinstance(name.args[0], ast.Constant) and name.args[0].value
+
+
+def test_program_part_leads_the_kernel_name_in_the_traced_program():
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import _dispatch as disp
+    from paddle_tpu.ops.pallas.decode_attention import \
+        decode_attention_pallas
+
+    assert disp.kernel_name("flash_decode") == "flash_decode"
+    q = jnp.zeros((2, 1, 4, 128), jnp.float32)
+    pool = jnp.zeros((5, 128, 2, 128), jnp.float32)
+    tables = jnp.zeros((2, 2), jnp.int32)
+
+    def rows(q, k, v):
+        with disp.program_part("_step_impl", "decode_rows"):
+            return decode_attention_pallas(q, k, v, jnp.zeros((2,), jnp.int32),
+                                           block_tables=tables,
+                                           interpret=True)
+
+    text = str(jax.make_jaxpr(rows)(q, pool, pool))
+    assert "_step_impl_decode_rows_flash_decode" in text
+    assert disp.kernel_name("flash_decode") == "flash_decode"   # restored
