@@ -14,6 +14,7 @@ to 4 layers and the vocabulary to 8192 rows, bf16, seeded random weights:
     mixed prompt lengths through the default constructor (contiguous cache,
     wave prefill) and through ``paged=True, chunked=True``; every request
     retires in full, one trace per step program, the Pallas paths counted;
+    the compiled paged decode step holds no temporary of a layer's K+V;
   * with four or more devices, the train step under mp2 x sharding2 ZeRO-3
     and ``ServingEngine(mesh="mp2dp2")``, every device holding bytes.
 
@@ -86,10 +87,12 @@ def _decode_inputs(b, s, hq, hkv, d, kv_len, seed, dtype):
     return q, k, v, pos
 
 
-def _scatter_to_pool(k, v, block_len, seed):
-    """Contiguous (B, L, Hkv, D) rows -> a (B*L/bl + 1, bl, Hkv, D) pool with
-    the rows' blocks at PERMUTED physical ids (block 0 stays the null block)
-    and the (B, L/bl) table that finds them."""
+def _scatter_to_pool(k, v, block_len, seed, layers=2):
+    """Contiguous (B, L, Hkv, D) rows -> the LAST layer of a stacked pool
+    (layers, 2, B*L/bl + 1, bl, Hkv*D) in the layout the engine stores,
+    the rows' blocks at PERMUTED physical ids (block 0 stays the null
+    block; the other layers stay zero), and the (B, L/bl) table that finds
+    them."""
     import jax.numpy as jnp
     b, kv_len, hkv, d = k.shape
     nb = kv_len // block_len
@@ -97,28 +100,33 @@ def _scatter_to_pool(k, v, block_len, seed):
     tables = perm.reshape(b, nb).astype(np.int32)
     order = np.argsort(perm)                  # physical id -> logical block
 
-    def pool(x):
-        blocks = x.reshape(b * nb, block_len, hkv, d)
-        return jnp.concatenate([jnp.zeros_like(blocks[:1]), blocks[order]])
+    def blocks(x):
+        x = x.reshape(b * nb, block_len, hkv * d)
+        return jnp.concatenate([jnp.zeros_like(x[:1]), x[order]])
 
-    return pool(k), pool(v), jnp.asarray(tables)
+    layer = jnp.stack([blocks(k), blocks(v)])
+    pool = jnp.concatenate(
+        [jnp.zeros((layers - 1,) + layer.shape, layer.dtype), layer[None]])
+    return pool, layers - 1, jnp.asarray(tables)
 
 
 def parity_decode(*, paged=False, s=1, b=8, hq=32, hkv=8, d=128,
                   kv_len=8192, block_len=128, dtype="bfloat16",
                   interpret=False):
     """Flash-decode kernel vs ``cached_decode_attention_reference``:
-    contiguous or paged, s=1 (steady decode), s=5 (spec-verify window,
-    k+1) or s=256 (a q-tiled prefill chunk)."""
+    contiguous or paged (the stacked pool handed over whole), s=1 (steady
+    decode), s=5 (spec-verify window, k+1) or s=256 (a q-tiled prefill
+    chunk)."""
     from paddle_tpu.ops.attention import cached_decode_attention_reference
-    from paddle_tpu.ops.pallas.decode_attention import decode_attention_pallas
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_pallas, paged_decode_attention_pallas)
 
     q, k, v, pos = _decode_inputs(b, s, hq, hkv, d, kv_len, 40 + s, dtype)
     want = cached_decode_attention_reference(q, k, v, pos)
     if paged:
-        kp, vp, tables = _scatter_to_pool(k, v, block_len, 7)
-        got = decode_attention_pallas(q, kp, vp, pos, block_tables=tables,
-                                      interpret=interpret)
+        pool, layer, tables = _scatter_to_pool(k, v, block_len, 7)
+        got = paged_decode_attention_pallas(q, pool, layer, pos, tables,
+                                            interpret=interpret)
     else:
         got = decode_attention_pallas(q, k, v, pos, interpret=interpret)
     return _close(got, want, ATTN_TOL,
@@ -131,25 +139,23 @@ def parity_decode_int8_paged(*, b=8, hq=32, hkv=8, d=128, kv_len=8192,
     """int8-KV flash-decode (scales in SMEM) vs the XLA gather + dequant
     reference over the SAME int8 pool and scales."""
     import jax.numpy as jnp
-    from paddle_tpu.ops.attention import cached_decode_attention_reference
-    from paddle_tpu.ops.pallas.decode_attention import decode_attention_pallas
+    from paddle_tpu.ops.attention import paged_decode_attention_reference
+    from paddle_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention_pallas
 
     q, k, v, pos = _decode_inputs(b, 1, hq, hkv, d, kv_len, 60, "float32")
     q = q.astype(dtype)
-    kp, vp, tables = _scatter_to_pool(k, v, block_len, 9)
-
-    def quantize(pool):       # per-block-per-kv-head absmax / 127
-        sc = jnp.maximum(jnp.max(jnp.abs(pool), axis=(1, 3)) / 127.0, 1e-8)
-        q8 = jnp.clip(jnp.round(pool / sc[:, None, :, None]), -127, 127)
-        return q8.astype(jnp.int8), sc.astype(jnp.float32)
-
-    k8, ks = quantize(kp)
-    v8, vs = quantize(vp)
-    want = cached_decode_attention_reference(
-        q, k8, v8, pos, block_tables=tables, k_scale=ks, v_scale=vs)
-    got = decode_attention_pallas(q, k8, v8, pos, block_tables=tables,
-                                  k_scale=ks, v_scale=vs,
-                                  interpret=interpret)
+    pool, layer, tables = _scatter_to_pool(k, v, block_len, 9)
+    # per-block-per-kv-head absmax / 127, heads apart for the reduction
+    heads = pool.reshape(pool.shape[:4] + (hkv, d))
+    sc = jnp.maximum(jnp.max(jnp.abs(heads), axis=(3, 5)) / 127.0, 1e-8)
+    pool8 = jnp.clip(jnp.round(heads / sc[:, :, :, None, :, None]),
+                     -127, 127).astype(jnp.int8).reshape(pool.shape)
+    sc = sc.astype(jnp.float32)
+    want = paged_decode_attention_reference(q, pool8, layer, pos, tables,
+                                            pool_scale=sc)
+    got = paged_decode_attention_pallas(q, pool8, layer, pos, tables,
+                                        pool_scale=sc, interpret=interpret)
     return _close(got, want, INT8_KV_TOL, "decode int8-KV paged s=1")
 
 
@@ -348,6 +354,32 @@ def serve_leg(model, prompts, new_tokens, *, expect_paths=(),
             "kernel_paths": paths, "tokens": warm["tokens"]}
 
 
+def paged_step_temporaries(model, **engine_kw):
+    """Compile the paged decode step (``_step_impl_paged``, pool donated) of
+    ``ServingEngine(model, paged=True, **engine_kw)`` at that geometry and
+    read ``memory_analysis()``: the pool is stored as the flash-decode
+    kernel reads it and handed over whole, so the program may hold no
+    temporary the size of a layer's K and V — the check that found the
+    per-layer relayout copy (ROADMAP S1).  Fails at a layer's K+V or more."""
+    import jax
+
+    from paddle_tpu.serving import ServingEngine
+
+    model.eval()
+    eng = ServingEngine(model, paged=True, **engine_kw)
+    compiled = jax.jit(eng._step_fn.python_fn, donate_argnums=(1,)).lower(
+        *eng._lint_args()).compile()
+    temp = int(compiled.memory_analysis().temp_size_in_bytes)
+    pool = eng._cache
+    layer_kv = int(pool.nbytes // pool.shape[0])
+    if temp >= layer_kv:
+        raise AssertionError(
+            f"paged decode step holds {temp} bytes of temporaries; a "
+            f"layer's K+V is {layer_kv}: something copies the pool")
+    return {"temp_bytes": temp, "layer_kv_bytes": layer_kv,
+            "pool_shape": list(pool.shape)}
+
+
 def agreeing_share(a, b):
     """Share of generated positions at which two runs of the same requests
     produced the same token, counted up to each request's first divergence
@@ -526,6 +558,10 @@ def main():
     say("serve paged+chunked", dict(paged, compile_s=compiles.drain()))
     say("layouts agree", {"share_of_tokens_before_first_divergence":
                           agreeing_share(wave_tokens, paged_tokens)})
+    gc.collect()
+    say("paged step temporaries",
+        dict(paged_step_temporaries(model, num_slots=8, max_length=8192),
+             compile_s=compiles.drain()))
     del model
     gc.collect()
 

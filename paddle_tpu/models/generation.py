@@ -256,9 +256,10 @@ def decode_mesh_specs(model, params, axis_names, paged_cache=False,
       * the stacked KV cache (L, 2, B, max_len, Hkv, D): batch over
         dp×sharding, kv heads over ``mp`` — the serving layout matching
         how training shards attention.  The paged pool
-        (L, 2, num_blocks, block_len, Hkv, D) shards kv heads on ``mp``
-        only: any block can back any slot, so the block axis must NOT
-        be split over the batch axes;
+        (L, 2, num_blocks, block_len, Hkv·D) shards its fused head axis
+        on ``mp`` only (head-major, so a shard is whole kv heads): any
+        block can back any slot, so the block axis must NOT be split
+        over the batch axes;
       * input ids: batch over dp×sharding.
 
     :func:`_place_on_mesh` commits these specs with ``device_put``; the
@@ -296,7 +297,7 @@ def decode_mesh_specs(model, params, axis_names, paged_cache=False,
         fs(*tuple(_lookup(path) or P())) for path, _ in flat])
     batch = tuple(a for a in ("dp", "sharding") if a in names)
     if paged_cache:
-        cache_spec = fs(None, None, None, None, "mp", None)
+        cache_spec = fs(None, None, None, None, "mp")
         scale_spec = fs(None, None, None, "mp")
     else:
         cache_spec = fs(None, None, batch, None, "mp", None)
@@ -339,7 +340,8 @@ def _place_on_mesh(model, params, cache, input_ids, paged_cache=False,
         lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
         params, param_specs)
     input_ids = jax.device_put(input_ids, NamedSharding(mesh, ids_spec))
-    if quantized or (isinstance(cache, jax.Array) and cache.ndim == 6):
+    if quantized or (isinstance(cache, jax.Array)
+                     and cache.ndim == (5 if paged_cache else 6)):
         cache = jax.tree_util.tree_map(
             lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
             cache, cache_spec)

@@ -118,9 +118,10 @@ def _quantized_paged_write(kv, sc, idx: int, kvsl: int, x, phys, off):
     half of the quantized KV cache (the read half is the flash-decode
     kernel's in-chunk dequant).
 
-    ``kv``: (L, 2, nb, bl, Hkv, D) int8 pool; ``sc``: (L, 2, nb, Hkv)
+    ``kv``: (L, 2, nb, bl, Hkv·D) int8 pool; ``sc``: (L, 2, nb, Hkv)
     f32 per-block-per-kv-head scales; ``x``: (B, s, Hkv, D) new K or V;
     ``phys``/``off``: (B, s) physical block / in-block offset per token.
+    Heads are split on the (B, s) gathered blocks only, never on the pool.
 
     Per-block scales are RUNNING maxima, so a new token whose absmax
     exceeds its block's current scale grows the scale — and the block's
@@ -142,18 +143,21 @@ def _quantized_paged_write(kv, sc, idx: int, kvsl: int, x, phys, off):
     token) quantizes through a guard divisor of 1.
     """
     f32 = jnp.float32
+    b, s, hkv, d = x.shape
     needed = jnp.max(jnp.abs(x.astype(f32)), axis=-1) / 127.0  # (B,s,Hkv)
-    old = sc[idx, kvsl][phys]                                  # (B,s,Hkv)
+    old = sc[idx, kvsl, phys]                                  # (B,s,Hkv)
     sc = sc.at[idx, kvsl, phys].max(needed)
-    new = sc[idx, kvsl][phys]
+    new = sc[idx, kvsl, phys]
     safe = jnp.where(new > 0, new, 1.0)
     ratio = jnp.where(new > 0, old / safe, 0.0)
-    pay = kv[idx, kvsl][phys]                            # (B,s,bl,Hkv,D)
-    pay = jnp.clip(jnp.round(pay.astype(f32)
+    pay = kv[idx, kvsl, phys]                            # (B,s,bl,Hkv·D)
+    pay = jnp.clip(jnp.round(pay.astype(f32).reshape(b, s, -1, hkv, d)
                              * ratio[:, :, None, :, None]), -127, 127)
-    kv = kv.at[idx, kvsl, phys].set(pay.astype(jnp.int8))
+    kv = kv.at[idx, kvsl, phys].set(
+        pay.astype(jnp.int8).reshape(b, s, -1, hkv * d))
     tok = jnp.clip(jnp.round(x.astype(f32) / safe[..., None]), -127, 127)
-    kv = kv.at[idx, kvsl, phys, off].set(tok.astype(jnp.int8))
+    kv = kv.at[idx, kvsl, phys, off].set(
+        tok.astype(jnp.int8).reshape(b, s, hkv * d))
     return kv, sc
 
 
@@ -257,9 +261,10 @@ class LlamaAttention(Layer):
 
     def decode(self, x, rope_cache, pos, cache, idx: int,
                block_tables=None):
-        """Incremental decode against the STACKED cache
-        (L, 2, B, max_len, Hkv, D): write this chunk's K/V in place at
-        ``(idx, ·, ·, pos)`` and attend over this layer's slices.
+        """Incremental decode against the STACKED cache — contiguous
+        (L, 2, B, max_len, Hkv, D), or the paged pool (last paragraph):
+        write this chunk's K/V in place at ``(idx, ·, ·, pos)`` and attend
+        over this layer's part of it.
 
         Dataflow is the design here (round-5 measurement): the carried
         cache is only ever touched by *chunk-sized*
@@ -301,21 +306,26 @@ class LlamaAttention(Layer):
 
         ``block_tables`` (int (B, max_blocks)) switches to the PAGED
         cache (serving/kv_cache.py): ``cache`` is the pooled
-        (L, 2, num_blocks, block_len, Hkv, D) array and row i's logical
-        position p lives at physical ``(block_tables[i, p // block_len],
+        (L, 2, num_blocks, block_len, Hkv·D) array — stored as the
+        flash-decode kernel reads it — and row i's logical position p
+        lives at physical ``(block_tables[i, p // block_len],
         p % block_len)``.  Writes become (physical block, offset)
-        scatters; positions past the table's coverage — prompt padding in
-        a prefill-into-slot wave — are steered to the null block (id 0,
-        scratch by convention), so a padded wave can never clobber live
-        or shared blocks.  The attention read hands the table straight to
-        :func:`~paddle_tpu.ops.attention.cached_decode_attention`, whose
-        Pallas kernel dereferences it in the scalar-prefetch index maps.
-        Paged decode always uses per-row positions (a scalar is
-        broadcast).
+        scatters of whole ``Hkv·D`` rows; positions past the table's
+        coverage — prompt padding in a prefill-into-slot wave — are
+        steered to the null block (id 0, scratch by convention), so a
+        padded wave can never clobber live or shared blocks.  The
+        attention read hands THE POOL, this layer's index and the table
+        to :func:`~paddle_tpu.ops.attention.paged_decode_attention`,
+        whose Pallas kernel dereferences all three in its scalar-prefetch
+        index maps: no layer's K or V is sliced out of the pool or
+        reshaped, so the step's cache traffic is what it writes and the
+        live blocks it reads.  Paged decode always uses per-row positions
+        (a scalar is broadcast).
 
         x: (B, s, H*D).  Returns (out, cache).
         """
-        from ..ops.attention import cached_decode_attention
+        from ..ops.attention import (cached_decode_attention,
+                                     paged_decode_attention)
 
         b, s, _ = x.shape
         quantized = isinstance(cache, dict)
@@ -347,30 +357,23 @@ class LlamaAttention(Layer):
                 jnp.int32(0))              # out-of-table pads -> null block
             off = position_ids % bl
             q = constrain(q, ("dp", "sharding"), None, "mp", None)
+            sc = None
             if quantized:
-                sc = cache["scale"]
-                kvp, sc = _quantized_paged_write(kvp, sc, idx, 0, k,
-                                                 phys, off)
+                kvp, sc = _quantized_paged_write(kvp, cache["scale"], idx,
+                                                 0, k, phys, off)
                 kvp, sc = _quantized_paged_write(kvp, sc, idx, 1, v,
                                                  phys, off)
-                kvp = constrain(kvp, None, None, None, None, "mp", None)
                 sc = constrain(sc, None, None, None, "mp")
-                cache = {"kv": kvp, "scale": sc}
-                out = cached_decode_attention(
-                    q, kvp[idx, 0], kvp[idx, 1], pos,
-                    block_tables=block_tables,
-                    k_scale=sc[idx, 0], v_scale=sc[idx, 1])
-                return matmul(out.reshape(b, s, -1), self.o_proj), cache
-            with jax.named_scope("kv_write"):
-                cache = cache.at[idx, 0, phys, off].set(
-                    k.astype(cache.dtype))
-                cache = cache.at[idx, 1, phys, off].set(
-                    v.astype(cache.dtype))
-                cache = constrain(cache, None, None, None, None, "mp", None)
-            with jax.named_scope("kv_slice"):
-                k_pool, v_pool = cache[idx, 0], cache[idx, 1]
-            out = cached_decode_attention(q, k_pool, v_pool, pos,
-                                          block_tables=block_tables)
+            else:
+                with jax.named_scope("kv_write"):
+                    kvp = kvp.at[idx, 0, phys, off].set(
+                        k.astype(kvp.dtype).reshape(b, s, -1))
+                    kvp = kvp.at[idx, 1, phys, off].set(
+                        v.astype(kvp.dtype).reshape(b, s, -1))
+            kvp = constrain(kvp, None, None, None, None, "mp")
+            cache = {"kv": kvp, "scale": sc} if quantized else kvp
+            out = paged_decode_attention(q, kvp, idx, pos, block_tables,
+                                         pool_scale=sc)
             return matmul(out.reshape(b, s, -1), self.o_proj), cache
         if quantized:
             sc = cache["scale"]

@@ -325,9 +325,18 @@ def _decode_attention_decision(b, s, hq, hkv, d, kv_len, has_extra_mask,
     return path, why
 
 
+def _mesh_axes():
+    """(mesh, batch axes or None, "mp" or None) of the active mesh — how
+    the shard_map fast path splits rows and kv heads."""
+    from ..distributed import env as _denv
+    mesh = _denv.active_mesh()
+    names = set(mesh.axis_names)
+    batch = tuple(a for a in ("dp", "sharding") if a in names) or None
+    return mesh, batch, "mp" if "mp" in names else None
+
+
 def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
-                                live_len=None, block_tables=None,
-                                k_scale=None, v_scale=None):
+                                live_len=None, k_scale=None, v_scale=None):
     """The mesh fast path: re-enter :func:`cached_decode_attention`
     PER SHARD under ``shard_map`` — kv-heads split over ``mp`` (exactly
     how mp attention layers place them: contiguous head blocks, so the
@@ -339,47 +348,23 @@ def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
     if the kernel refuses at call time.  Attention is embarrassingly
     parallel over rows and kv-head groups, so the body needs NO
     collectives and the output stays row-parallel (the PR-8 comm model
-    is unchanged).
-
-    Paged layout: the pool is head-sharded only (every shard holds all
-    blocks at its head slice) and the block tables are per-row logical
-    — they ride the row axes with their rows, whole per shard."""
+    is unchanged)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed import env as _denv
-    mesh = _denv.active_mesh()
-    names = set(mesh.axis_names)
-    batch = tuple(a for a in ("dp", "sharding") if a in names) or None
-    mp = "mp" if "mp" in names else None
-    paged = block_tables is not None
+    mesh, batch, mp = _mesh_axes()
     quantized = k_scale is not None
     q_spec = P(batch, None, mp, None)
-    kv_spec = P(None, None, mp, None) if paged else P(batch, None, mp,
-                                                      None)
     args = [q, k_cache, v_cache, pos]
-    in_specs = [q_spec, kv_spec, kv_spec,
+    in_specs = [q_spec, q_spec, q_spec,
                 P(batch) if getattr(pos, "ndim", 0) == 1 else P()]
-    if paged:
-        args.append(block_tables)
-        in_specs.append(P(batch, None))
     if quantized:
-        s_spec = P(None, mp) if paged else P(batch, None, mp)
         args += [jnp.asarray(k_scale, jnp.float32),
                  jnp.asarray(v_scale, jnp.float32)]
-        in_specs += [s_spec, s_spec]
+        in_specs += [P(batch, None, mp)] * 2
 
-    def body(*ops):
-        q_, k_, v_, pos_ = ops[:4]
-        i = 4
-        bt_ = ks_ = vs_ = None
-        if paged:
-            bt_ = ops[i]
-            i += 1
-        if quantized:
-            ks_, vs_ = ops[i], ops[i + 1]
+    def body(q_, k_, v_, pos_, ks_=None, vs_=None):
         return cached_decode_attention(q_, k_, v_, pos_, scale=scale,
                                        live_len=live_len,
-                                       block_tables=bt_,
                                        k_scale=ks_, v_scale=vs_)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
@@ -387,15 +372,70 @@ def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
     return fn(*args)
 
 
+def _shard_map_paged_decode_attention(q, pool, layer, pos, block_tables,
+                                      scale=None, live_len=None,
+                                      pool_scale=None):
+    """:func:`_shard_map_decode_attention` for the paged pool: the pool is
+    head-sharded only — its fused ``Hkv·D`` axis splits over ``mp`` as
+    whole heads (head-major, contiguous), every shard holding all blocks
+    of all layers at its head slice — and the block tables are per-row
+    logical, so they ride the row axes with their rows, whole per shard.
+    Per shard ``Hkv`` is again read from the shapes."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh, batch, mp = _mesh_axes()
+    q_spec = P(batch, None, mp, None)
+    args = [q, pool, pos, block_tables]
+    in_specs = [q_spec, P(None, None, None, None, mp),
+                P(batch) if getattr(pos, "ndim", 0) == 1 else P(),
+                P(batch, None)]
+    if pool_scale is not None:
+        args.append(jnp.asarray(pool_scale, jnp.float32))
+        in_specs.append(P(None, None, None, mp))
+
+    def body(q_, pool_, pos_, bt_, sc_=None):
+        return paged_decode_attention(q_, pool_, layer, pos_, bt_,
+                                      scale=scale, live_len=live_len,
+                                      pool_scale=sc_)
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=q_spec, check_vma=False)
+    return fn(*args)
+
+
+def _run_decode_path(path, reason, shard_map_fn, pallas_fn, reference_fn):
+    """Run the dispatch decision ``(path, reason)``: the chosen kernel
+    form, or the XLA math path when the decision was ``xla_math`` or the
+    kernel refuses the shape at call time (NotImplementedError)."""
+    if path == "pallas_decode_shard_map":
+        try:
+            return shard_map_fn()
+        except NotImplementedError as e:
+            reason = FallbackReason(str(e), KIND_KERNEL)
+    elif path == "pallas_decode":
+        try:
+            return pallas_fn()
+        except NotImplementedError as e:
+            reason = FallbackReason(str(e), KIND_KERNEL)
+    if _dispatch.use_pallas() and reason_kind(reason) in WARN_KINDS:
+        # shape/kernel demotions ARE perf surprises worth one log line;
+        # backend/mesh/policy demotions are the design (see the kind
+        # contract at the top of this module)
+        vlog_once(1, f"decode_attention:{reason}",
+                  f"cached_decode_attention: falling back to the XLA math "
+                  f"path ({reason})")
+    return reference_fn()
+
+
 def cached_decode_attention(q, k_cache, v_cache, pos,
                             scale: Optional[float] = None,
                             extra_mask=None, live_len: Optional[int] = None,
-                            block_tables=None,
                             k_scale=None, v_scale=None):
-    """Incremental decode attention over a pre-allocated cache — the
-    serving hot path (parity: the reference's masked_multihead_attention /
-    fused decode-attention core, upstream
+    """Incremental decode attention over a pre-allocated CONTIGUOUS cache
+    — the serving hot path (parity: the reference's
+    masked_multihead_attention / fused decode-attention core, upstream
     paddle/phi/kernels/fusion/gpu/masked_multihead_attention_kernel.cu).
+    The paged pool goes through :func:`paged_decode_attention`.
 
     q: (B, s, Hq, D) — the new tokens (s is 1 in steady-state decode);
     k_cache/v_cache: (B, L, Hkv, D) with the new K/V already written at
@@ -415,64 +455,78 @@ def cached_decode_attention(q, k_cache, v_cache, pos,
     XLA math path, which the decode bench measured at the weight-stream
     bound for short caches.  Returns (B, s, Hq, D) in q.dtype.
 
-    ``block_tables``: int (B, max_blocks) — switches to the PAGED cache
-    layout (serving/kv_cache.py): k_cache/v_cache are the pooled
-    (num_blocks, block_len, Hkv, D) arrays and row i's logical block j
-    lives in physical block ``block_tables[i, j]``.  The Pallas kernel
-    dereferences the table in its scalar-prefetch index maps; the XLA
-    fallback gathers the table into the contiguous layout first.
-
-    ``k_scale``/``v_scale``: f32 per-block-per-kv-head dequant scales for
-    an int8 cache (paged: ``(num_blocks, Hkv)``; contiguous:
-    ``(B, n_granules, Hkv)``) — the Pallas kernel dequantizes inside its
-    KV-chunk loop; the XLA fallback dequantizes after its gather.
+    ``k_scale``/``v_scale``: f32 ``(B, n_granules, Hkv)``
+    per-granule-per-kv-head dequant scales for an int8 cache — the Pallas
+    kernel dequantizes inside its KV-chunk loop; the XLA fallback
+    dequantizes first.
     """
     b, s, hq, d = q.shape
-    quantized = k_scale is not None
-    if block_tables is not None:
-        _, block_len, hkv, _ = k_cache.shape
-        kv_len = block_tables.shape[1] * block_len
-        path, reason = decode_attention_path(b, s, hq, hkv, d, kv_len,
-                                             extra_mask is not None,
-                                             paged_block_len=block_len,
-                                             quantized=quantized)
-    else:
-        _, kv_len, hkv, _ = k_cache.shape
-        path, reason = decode_attention_path(b, s, hq, hkv, d, kv_len,
-                                             extra_mask is not None,
-                                             quantized=quantized)
-    if path == "pallas_decode_shard_map":
-        try:
-            return _shard_map_decode_attention(
-                q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
-                block_tables=block_tables,
-                k_scale=k_scale, v_scale=v_scale)
-        except NotImplementedError as e:
-            reason = FallbackReason(str(e), KIND_KERNEL)
-    elif path == "pallas_decode":
-        try:
-            from .pallas.decode_attention import decode_attention_pallas
-            return decode_attention_pallas(
-                q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
-                block_tables=block_tables,
-                k_scale=k_scale, v_scale=v_scale,
-                interpret=_dispatch.pallas_interpret())
-        except NotImplementedError as e:
-            reason = FallbackReason(str(e), KIND_KERNEL)
-    if _dispatch.use_pallas() and reason_kind(reason) in WARN_KINDS:
-        # shape/kernel demotions ARE perf surprises worth one log line;
-        # backend/mesh/policy demotions are the design (see the kind
-        # contract at the top of this module)
-        vlog_once(1, f"decode_attention:{reason}",
-                  f"cached_decode_attention: falling back to the XLA math "
-                  f"path ({reason})")
-    return cached_decode_attention_reference(q, k_cache, v_cache, pos,
-                                             scale=scale,
-                                             extra_mask=extra_mask,
-                                             live_len=live_len,
-                                             block_tables=block_tables,
-                                             k_scale=k_scale,
-                                             v_scale=v_scale)
+    _, kv_len, hkv, _ = k_cache.shape
+    path, reason = decode_attention_path(b, s, hq, hkv, d, kv_len,
+                                         extra_mask is not None,
+                                         quantized=k_scale is not None)
+
+    def pallas():
+        from .pallas.decode_attention import decode_attention_pallas
+        return decode_attention_pallas(
+            q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
+            k_scale=k_scale, v_scale=v_scale,
+            interpret=_dispatch.pallas_interpret())
+
+    return _run_decode_path(
+        path, reason,
+        lambda: _shard_map_decode_attention(
+            q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
+            k_scale=k_scale, v_scale=v_scale),
+        pallas,
+        lambda: cached_decode_attention_reference(
+            q, k_cache, v_cache, pos, scale=scale, extra_mask=extra_mask,
+            live_len=live_len, k_scale=k_scale, v_scale=v_scale))
+
+
+def paged_decode_attention(q, pool, layer: int, pos, block_tables,
+                           scale: Optional[float] = None, extra_mask=None,
+                           live_len: Optional[int] = None, pool_scale=None):
+    """:func:`cached_decode_attention` of layer ``layer`` over the PAGED
+    pool (serving/kv_cache.py): ``pool`` is the whole
+    ``(L, 2, num_blocks, block_len, Hkv·D)`` array of every layer's K and
+    V blocks, ``layer`` a static int, and row i's logical block j lives
+    in physical block ``block_tables[i, j]`` (int (B, max_blocks)).
+
+    The pool is handed on as it is: the Pallas kernel
+    (``paged_decode_attention_pallas``) takes it whole and dereferences
+    layer, K/V and block table in its scalar-prefetch index maps, so no
+    per-layer slice of it is ever formed; the XLA fallback
+    (:func:`paged_decode_attention_reference`) gathers the table's blocks
+    into the contiguous layout first.  ``pos`` is the int (B,) vector of
+    per-row positions.  ``pool_scale``: the int8 pool's f32
+    ``(L, 2, num_blocks, Hkv)`` per-block-per-kv-head dequant scales.
+    Dispatch, ``live_len``, ``extra_mask`` and the result are
+    :func:`cached_decode_attention`'s."""
+    b, s, hq, d = q.shape
+    block_len, hd = pool.shape[-2:]
+    path, reason = decode_attention_path(
+        b, s, hq, hd // d, d, block_tables.shape[1] * block_len,
+        extra_mask is not None, paged_block_len=block_len,
+        quantized=pool_scale is not None)
+
+    def pallas():
+        from .pallas.decode_attention import paged_decode_attention_pallas
+        return paged_decode_attention_pallas(
+            q, pool, layer, pos, block_tables, scale=scale,
+            live_len=live_len, pool_scale=pool_scale,
+            interpret=_dispatch.pallas_interpret())
+
+    return _run_decode_path(
+        path, reason,
+        lambda: _shard_map_paged_decode_attention(
+            q, pool, layer, pos, block_tables, scale=scale,
+            live_len=live_len, pool_scale=pool_scale),
+        pallas,
+        lambda: paged_decode_attention_reference(
+            q, pool, layer, pos, block_tables, scale=scale,
+            extra_mask=extra_mask, live_len=live_len,
+            pool_scale=pool_scale))
 
 
 @jax.jit
@@ -496,11 +550,47 @@ def _dequant_decode_attention(k_cache, v_cache, k_scale, v_scale):
     return k, v
 
 
+def paged_decode_attention_reference(q, pool, layer: int, pos, block_tables,
+                                     scale: Optional[float] = None,
+                                     extra_mask=None,
+                                     live_len: Optional[int] = None,
+                                     pool_scale=None):
+    """The XLA math path of :func:`paged_decode_attention` (and its
+    numerical oracle): one gather takes each row's physical blocks out of
+    ``pool[layer, 0 | 1]`` into the contiguous ``(B, max_blocks·block_len,
+    Hkv, D)`` view — heads are split on the gathered rows, never on the
+    pool — after which the math is
+    :func:`cached_decode_attention_reference`'s.  The gather is an HBM
+    copy of what the tables name: this is the parity oracle and the
+    small-shape fallback, not the long-cache hot path.  A ``live_len``
+    bound trims whole table columns before the gather; an int8 pool
+    (``pool_scale``) gathers the same blocks' scale rows and widens."""
+    b, _, _, d = q.shape
+    bl, hd = pool.shape[-2:]
+    hkv = hd // d
+    mb = block_tables.shape[1]
+    if live_len is not None and live_len < mb * bl:
+        mb = -(-int(live_len) // bl)
+        block_tables = block_tables[:, :mb]
+    bt = jnp.clip(block_tables, 0, pool.shape[2] - 1)
+    # (B, mb) gather of (bl, Hkv·D) blocks -> heads apart
+    k_cache = pool[layer, 0, bt].reshape(b, mb, bl, hkv, d)
+    v_cache = pool[layer, 1, bt].reshape(b, mb, bl, hkv, d)
+    if pool_scale is not None:
+        # the named helper keeps the widening lint-allowlistable
+        sc = jnp.asarray(pool_scale, jnp.float32)
+        k_cache, v_cache = _dequant_decode_attention(
+            k_cache, v_cache, sc[layer, 0, bt], sc[layer, 1, bt])
+    return cached_decode_attention_reference(
+        q, k_cache.reshape(b, mb * bl, hkv, d),
+        v_cache.reshape(b, mb * bl, hkv, d), pos, scale=scale,
+        extra_mask=extra_mask, live_len=live_len)
+
+
 def cached_decode_attention_reference(q, k_cache, v_cache, pos,
                                       scale: Optional[float] = None,
                                       extra_mask=None,
                                       live_len: Optional[int] = None,
-                                      block_tables=None,
                                       k_scale=None, v_scale=None):
     """The XLA math path of :func:`cached_decode_attention` (and its
     numerical oracle): masked softmax over the whole cache read.
@@ -521,37 +611,9 @@ def cached_decode_attention_reference(q, k_cache, v_cache, pos,
     regime at short max_length; its per-step cost is O(S·max_len) —
     streaming the dead cache tail — which is what the flash-decode
     kernel's live-prefix reads fix at long max_length.
-
-    ``block_tables`` (int (B, max_blocks)): PAGED layout — k_cache/
-    v_cache are the pooled (num_blocks, block_len, Hkv, D) arrays; the
-    per-row physical blocks are gathered into the contiguous
-    (B, max_blocks·block_len, Hkv, D) view first (an HBM copy — this is
-    the parity oracle and the small-shape fallback, not the long-cache
-    hot path), after which the math is identical.  A ``live_len`` bound
-    trims whole table columns before the gather.
     """
     b, s, hq, d = q.shape
-    if block_tables is not None:
-        _, bl, hkv_p, _ = k_cache.shape
-        mb = block_tables.shape[1]
-        if live_len is not None and live_len < mb * bl:
-            mb = -(-int(live_len) // bl)
-            block_tables = block_tables[:, :mb]
-        # (B, mb) pool gather -> (B, mb, bl, Hkv, D) -> contiguous view
-        k_cache = jnp.take(k_cache, block_tables, axis=0, mode="clip")
-        v_cache = jnp.take(v_cache, block_tables, axis=0, mode="clip")
-        if k_scale is not None:
-            # int8 pool: gather the same blocks' scale rows and widen
-            # (the named helper keeps the widening lint-allowlistable)
-            k_cache, v_cache = _dequant_decode_attention(
-                k_cache, v_cache,
-                jnp.take(jnp.asarray(k_scale, jnp.float32), block_tables,
-                         axis=0, mode="clip"),
-                jnp.take(jnp.asarray(v_scale, jnp.float32), block_tables,
-                         axis=0, mode="clip"))
-        k_cache = k_cache.reshape(b, mb * bl, hkv_p, d)
-        v_cache = v_cache.reshape(b, mb * bl, hkv_p, d)
-    elif k_scale is not None:
+    if k_scale is not None:
         # contiguous int8 rows: view each row as its scale granules,
         # widen under the per-granule-per-head scales, view back
         _, L0, hkv_c, _ = k_cache.shape

@@ -32,10 +32,14 @@ cache every step):
 
 GQA stays grouped: Q is reshaped to (B, Hkv, G·s, D) and each kv head's
 (G·s, D) query tile contracts the cache directly — bf16 operands on the
-MXU with an fp32 accumulator, no Hq/Hkv KV broadcast.  The cache is read
-in its **native** (B, L, Hkv, D) layout, viewed as (B, L, Hkv·D) so each
-KV chunk is one contiguous DMA; the per-head (bk, D) slice is a static
-lane slice in VMEM.  Per-row ``pos`` masking happens inside the kernel
+MXU with an fp32 accumulator, no Hq/Hkv KV broadcast.  A KV chunk is a
+``(bk, Hkv·D)`` tile — rows of all kv heads' features side by side — so
+it is one contiguous DMA and the per-head (bk, D) slice is a static lane
+slice in VMEM.  The paged pool is STORED that way (below); the
+contiguous (B, L, Hkv, D) cache is reshaped to it per call, which on the
+TPU's tiled layouts is a physical copy of the layer's K and V (ROADMAP
+S1; that cache's writers keep heads apart, so it keeps that cost).
+Per-row ``pos`` masking happens inside the kernel
 (key j visible to query row (si, g) iff j <= pos_b + si) with the same
 fully-masked-row convention as the flash kernel (out = 0).
 
@@ -66,23 +70,27 @@ kernel surface; the engine wraps its verify trace in
 their routing decisions) land under ``ops.kernel_path{op="spec_verify"}``
 instead of the prefill-chunk label.
 
-**Paged KV cache** (serving/kv_cache.py): the kernel also serves the
-block-table layout, where the cache is one pooled ``(num_blocks,
-block_len, Hkv, D)`` array and each row's logical positions are backed by
-the physical blocks its ``(B, max_blocks)`` block table names.  The table
-rides in as a SECOND scalar-prefetch operand and the KV-chunk index maps
-dereference it: grid step (bi, ki) DMAs physical block
-``table[bi, min(ki, last_live)]``.  One KV chunk == one cache block
-(``block_len`` must be 128-aligned), so a block is one contiguous DMA
-exactly as before, blocks may be scattered anywhere in the pool, shared
+**Paged KV cache** (serving/kv_cache.py,
+:func:`paged_decode_attention_pallas`): the kernel also serves the
+block-table layout, where the cache of ALL layers is one pooled
+``(L, 2, num_blocks, block_len, Hkv·D)`` array and each row's logical
+positions are backed by the physical blocks its ``(B, max_blocks)`` block
+table names.  The kernel is handed **the pool itself** and the static
+layer index: K and V are the same operand, and their index maps return
+``(layer, 0 | 1, table[bi, min(ki, last_live)], 0, 0)`` — the table rides
+in as a SECOND scalar-prefetch operand.  Nothing is sliced out of the
+pool and nothing is reshaped, so a step's HBM traffic is the live blocks
+it reads and the rows it writes, whatever the pool's size.  One KV chunk
+== one cache block (``block_len`` must be 128-aligned), so a block is one
+contiguous DMA, blocks may be scattered anywhere in the pool, shared
 between rows, or partially filled (the in-kernel ``pos`` mask already
 handles partial blocks — column indices are logical).  The contiguous
-layout is the degenerate case: the caller's cache reshapes to a
-``(B·chunks, bk, Hkv·D)`` pool (a free view) under the identity table
-``table[bi, ki] = bi·chunks + ki``, which is how PR 2's dead-tail
-clamping now reads — clamping the logical chunk index before the table
-lookup maps dead-tail grid steps to the row's last live block, the DMA is
-elided, and HBM traffic still stops at the live prefix.
+layout runs the same kernel under the identity table
+``table[bi, ki] = bi·chunks + ki`` over its reshaped
+``(B·chunks, bk, Hkv·D)`` cache, which is how PR 2's dead-tail clamping
+now reads — clamping the logical chunk index before the table lookup maps
+dead-tail grid steps to the row's last live block, the DMA is elided, and
+the kernel's reads still stop at the live prefix.
 """
 
 from __future__ import annotations
@@ -204,24 +212,15 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
                             block_kv: int = 0,
                             live_len: Optional[int] = None,
                             interpret: bool = False,
-                            block_tables=None,
                             k_scale=None, v_scale=None):
-    """Flash-decode over a pre-allocated cache → (B, s, Hq, D) in q.dtype.
+    """Flash-decode over a pre-allocated CONTIGUOUS cache → (B, s, Hq, D)
+    in q.dtype (the paged pool has its own entry,
+    :func:`paged_decode_attention_pallas`).
 
     q: (B, s, Hq, D) new-token queries (s = 1 in steady-state decode,
     small for prefill-into-occupied-slot); ``pos``: scalar or int (B,)
-    per-row positions — cache slots > pos+i are masked.  Two cache
-    layouts:
-
-      * **contiguous** (``block_tables`` is None): k_cache/v_cache are
-        (B, L, Hkv, D) with the new K/V already written;
-      * **paged**: k_cache/v_cache are the pooled (num_blocks, block_len,
-        Hkv, D) arrays and ``block_tables`` is the int (B, max_blocks)
-        map from each row's logical block index to its physical block
-        (serving/kv_cache.py conventions: every entry valid, dead tail
-        null-filled).  The logical cache length is
-        ``max_blocks · block_len`` and the KV chunk is pinned to one
-        block, so ``block_len`` must be 128-aligned.
+    per-row positions — cache slots > pos+i are masked.  k_cache/v_cache
+    are (B, L, Hkv, D) with the new K/V already written.
 
     ``live_len``: optional static bound on max(pos)+s (trims the chunk
     grid outright; without it the scalar-prefetch clamp stops the HBM
@@ -230,47 +229,112 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     fall back to the XLA math path).
 
     **int8 cache** (``k_scale``/``v_scale`` given): k_cache/v_cache hold
-    int8 payloads and the f32 scales carry the per-block-per-kv-head
-    dequant factor — paged: ``(num_blocks, Hkv)`` rows of the same pool
-    the block table indexes; contiguous: ``(B, n_granules, Hkv)`` where
-    the KV chunk is pinned to the scale granule
-    (``kv_len // n_granules``, 128-aligned).  Dequant happens inside the
-    chunk loop by folding each block's scale into the post-dot scalar
-    multiplies, so the HBM stream is the int8 payload — half the bf16
-    bytes.
+    int8 payloads and the f32 ``(B, n_granules, Hkv)`` scales carry the
+    per-granule-per-kv-head dequant factor; the KV chunk is pinned to
+    the scale granule (``kv_len // n_granules``, 128-aligned).  Dequant
+    happens inside the chunk loop by folding each granule's scale into
+    the post-dot scalar multiplies, so the HBM stream is the int8
+    payload — half the bf16 bytes.
     """
-    b, s, hq, d = q.shape
     quantized = k_scale is not None
     if quantized and v_scale is None:
         raise ValueError("int8 cache needs both k_scale and v_scale")
-    if block_tables is not None:
-        n_pool, bk, hkv, _ = k_cache.shape
-        if bk % 128:
+    b, kv_len, hkv, d = k_cache.shape
+    _check_q(q, hkv)
+    if quantized:
+        # the scale granule pins the KV chunk: one chunk == one
+        # (block, head) scale entry, exactly the paged contract
+        n_gran = k_scale.shape[1]
+        bk = kv_len // n_gran
+        if bk * n_gran != kv_len or bk % 128:
             raise NotImplementedError(
-                f"paged block_len {bk} is not 128-aligned")
-        bt = jnp.asarray(block_tables, jnp.int32)
-        kv_len = bt.shape[1] * bk
-        # pool layout: one physical block == one KV chunk == one DMA
-        with jax.named_scope("kv_relayout"):
-            k2 = k_cache.reshape(n_pool, bk, hkv * d)
-            v2 = v_cache.reshape(n_pool, bk, hkv * d)
-        if quantized:
-            # gather each row's scale rows through its block table here,
-            # so the SMEM tables scale with the batch geometry (like the
-            # block table itself), not with the pool
-            ks2 = jnp.take(jnp.asarray(k_scale, jnp.float32), bt, axis=0,
-                           mode="clip")
-            vs2 = jnp.take(jnp.asarray(v_scale, jnp.float32), bt, axis=0,
-                           mode="clip")
+                f"int8 scale granule {kv_len}/{n_gran} is not a "
+                f"128-aligned divisor of the cache length")
     else:
-        _, kv_len, hkv, _ = k_cache.shape
-    if hq % hkv or hkv == 0:
+        if not block_kv:
+            from ...flags import flag
+            block_kv = int(flag("decode_attention_block_kv"))
+        bk = _pick_block_kv(kv_len, block_kv)
+        if not bk:
+            raise NotImplementedError(
+                f"max_length {kv_len} has no 128-aligned chunk "
+                f"divisor <= {block_kv}")
+    # contiguous = paged under the identity table: the cache reshaped to
+    # a (B·chunks, bk, Hkv·D) pool with table [bi, ki] = bi·chunks + ki —
+    # same DMAs, one kernel
+    full = kv_len // bk
+    bt = (jnp.arange(b, dtype=jnp.int32)[:, None] * full
+          + jnp.arange(full, dtype=jnp.int32)[None, :])
+    k2 = k_cache.reshape(b * full, bk, hkv * d)
+    v2 = v_cache.reshape(b * full, bk, hkv * d)
+
+    def at(blk):
+        return (blk, 0, 0)
+
+    return _flash_decode(
+        q, k2, v2, (1, bk, hkv * d), at, at, pos, bt, scale=scale,
+        live_len=live_len, interpret=interpret, layout="contiguous",
+        scales=(k_scale, v_scale) if quantized else None)
+
+
+def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
+                                  scale: Optional[float] = None,
+                                  live_len: Optional[int] = None,
+                                  interpret: bool = False,
+                                  pool_scale=None):
+    """Flash-decode of layer ``layer`` over the PAGED pool
+    (serving/kv_cache.py ``init_paged_kv_cache``) → (B, s, Hq, D).
+
+    ``pool`` is the whole ``(L, 2, num_blocks, block_len, Hkv·D)`` array
+    of every layer's K (index 0) and V (index 1) blocks, the new K/V
+    already written, and ``layer`` a static int: the kernel takes the
+    pool as its K and its V operand and its index maps pick
+    ``(layer, 0 | 1, block)`` — no per-layer slice, no reshape.
+    ``block_tables`` is the int (B, max_blocks) map from each row's
+    logical block index to its physical block (every entry valid, dead
+    tail null-filled).  The logical cache length is
+    ``max_blocks · block_len`` and the KV chunk is pinned to one block,
+    so ``block_len`` must be 128-aligned.  ``Hkv`` is read from the
+    shapes (``pool.shape[-1] // q.shape[-1]``), so a head-sharded shard of
+    the pool works unchanged.  ``pos``, ``live_len`` and the refusals
+    are :func:`decode_attention_pallas`'s.
+
+    **int8 pool** (``pool_scale`` given): ``pool`` holds int8 payloads and
+    ``pool_scale`` is the f32 ``(L, 2, num_blocks, Hkv)`` array of
+    per-block-per-kv-head dequant factors; each row's scale rows are
+    gathered through its block table here, so the kernel's SMEM tables
+    scale with the batch geometry (like the block table itself), not
+    with the pool.
+    """
+    d = q.shape[-1]
+    bk, hd = pool.shape[-2:]
+    if bk % 128:
+        raise NotImplementedError(
+            f"paged block_len {bk} is not 128-aligned")
+    _check_q(q, hd // d)
+    bt = jnp.asarray(block_tables, jnp.int32)
+    scales = None
+    if pool_scale is not None:
+        rows = jnp.take(jnp.asarray(pool_scale, jnp.float32)[layer], bt,
+                        axis=1, mode="clip")    # (2, B, max_blocks, Hkv)
+        scales = (rows[0], rows[1])
+    return _flash_decode(
+        q, pool, pool, (None, None, 1, bk, hd),
+        lambda blk: (layer, 0, blk, 0, 0),
+        lambda blk: (layer, 1, blk, 0, 0), pos, bt, scale=scale,
+        live_len=live_len, interpret=interpret, layout="paged",
+        scales=scales)
+
+
+def _check_q(q, hkv: int) -> None:
+    """The q-side shape gates both layouts share (ops/pallas/limits.py)."""
+    _, s, hq, d = q.shape
+    if hkv == 0 or hq % hkv:
         raise NotImplementedError(
             f"q heads ({hq}) must be a multiple of kv heads ({hkv})")
-    g = hq // hkv
-    rows = s * g
-    if g > _MAX_Q_ROWS:
-        raise NotImplementedError(f"GQA group size {g} > {_MAX_Q_ROWS}")
+    if hq // hkv > _MAX_Q_ROWS:
+        raise NotImplementedError(
+            f"GQA group size {hq // hkv} > {_MAX_Q_ROWS}")
     if s > _MAX_Q_LEN:
         raise NotImplementedError(
             f"q_len {s} > {_MAX_Q_LEN}: whole-prefill-shaped q belongs to "
@@ -278,6 +342,22 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     if d > _limits.MAX_HEAD_DIM:
         raise NotImplementedError(
             f"head_dim {d} > {_limits.MAX_HEAD_DIM}")
+
+
+def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
+                  live_len, interpret, layout, scales):
+    """The one ``pallas_call`` behind both layouts.  ``k_arr``/``v_arr``
+    are the operands as they lie in HBM, ``kv_block`` the BlockSpec shape
+    that cuts one ``(1, bk, Hkv·D)`` chunk out of them, and
+    ``k_at``/``v_at`` map a physical block id to that chunk's block
+    index; ``bt`` is the (B, chunks) table of block ids and ``scales``
+    the int8 cache's (B, chunks, Hkv) K and V scale tables, or None."""
+    b, s, hq, d = q.shape
+    bk, hd = kv_block[-2:]
+    hkv = hd // d
+    g = hq // hkv
+    rows = s * g
+    quantized = scales is not None
     # q tiling: one grid step covers bq query tokens (bq·g MXU rows).
     # s <= bq is the steady-decode / small-s case — nq == 1, exactly the
     # original kernel.  Larger s (a chunked-prefill q chunk attending its
@@ -289,37 +369,7 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     nq = -(-s // bq)
     if scale is None:
         scale = d ** -0.5
-    if block_tables is None:
-        if quantized:
-            # the scale granule pins the KV chunk: one chunk == one
-            # (block, head) scale entry, exactly the paged contract
-            n_gran = k_scale.shape[1]
-            bk = kv_len // n_gran
-            if bk * n_gran != kv_len or bk % 128:
-                raise NotImplementedError(
-                    f"int8 scale granule {kv_len}/{n_gran} is not a "
-                    f"128-aligned divisor of the cache length")
-        else:
-            if not block_kv:
-                from ...flags import flag
-                block_kv = int(flag("decode_attention_block_kv"))
-            bk = _pick_block_kv(kv_len, block_kv)
-            if not bk:
-                raise NotImplementedError(
-                    f"max_length {kv_len} has no 128-aligned chunk "
-                    f"divisor <= {block_kv}")
-        # contiguous = paged under the identity table: view the cache as a
-        # (B·chunks, bk, Hkv·D) pool (free reshape) with table
-        # [bi, ki] = bi·chunks + ki — same DMAs, one code path
-        full = kv_len // bk
-        bt = (jnp.arange(b, dtype=jnp.int32)[:, None] * full
-              + jnp.arange(full, dtype=jnp.int32)[None, :])
-        k2 = k_cache.reshape(b * full, bk, hkv * d)
-        v2 = v_cache.reshape(b * full, bk, hkv * d)
-        if quantized:
-            ks2 = jnp.asarray(k_scale, jnp.float32)
-            vs2 = jnp.asarray(v_scale, jnp.float32)
-    n_cols = chunks = kv_len // bk
+    n_cols = chunks = bt.shape[1]
     if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
         raise NotImplementedError(
             f"int8 scale table {b}x{n_cols}x{hkv} > "
@@ -355,8 +405,7 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     _disp.count_kernel_path(
         _disp.kernel_path_op(
             "chunked_prefill" if nq > 1 else "decode_attention_kernel"),
-        "paged" if block_tables is not None else "contiguous",
-        **({"cache": "int8"} if quantized else {}))
+        layout, **({"cache": "int8"} if quantized else {}))
 
     kernel = functools.partial(
         _kernel, scale=float(scale), s=s, g=g, hkv=hkv, d=d, bq=bq,
@@ -366,7 +415,7 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     def q_idx(bi, qi, ki, pos_ref, bt_ref, *_):
         return (bi, 0, qi, 0)
 
-    def kv_idx(bi, qi, ki, pos_ref, bt_ref, *_):
+    def live_block(bi, qi, ki, pos_ref, bt_ref):
         # clamp the LOGICAL chunk index to this q tile's last live block,
         # then dereference the block table: dead-tail chunks re-map to the
         # same physical block as the previous grid step → Pallas elides
@@ -378,11 +427,18 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         # column (<= last) mapping to block 0 would alias the null
         # block's pad data into this row's attention window.
         last = (pos_ref[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return (bt_ref[bi, jnp.minimum(ki, last)], 0, 0)
+        return bt_ref[bi, jnp.minimum(ki, last)]
+
+    def k_idx(bi, qi, ki, pos_ref, bt_ref, *_):
+        return k_at(live_block(bi, qi, ki, pos_ref, bt_ref))
+
+    def v_idx(bi, qi, ki, pos_ref, bt_ref, *_):
+        return v_at(live_block(bi, qi, ki, pos_ref, bt_ref))
 
     scalars = (pos_arr, bt)
     if quantized:
-        scalars += (ks2.reshape(-1), vs2.reshape(-1))
+        scalars += tuple(jnp.asarray(t, jnp.float32).reshape(-1)
+                         for t in scales)
 
     out = pl.pallas_call(
         kernel,
@@ -391,8 +447,8 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
             grid=(b, nq, chunks),
             in_specs=[
                 pl.BlockSpec((1, hkv, tile_p, d), q_idx),
-                pl.BlockSpec((1, bk, hkv * d), kv_idx),
-                pl.BlockSpec((1, bk, hkv * d), kv_idx),
+                pl.BlockSpec(kv_block, k_idx),
+                pl.BlockSpec(kv_block, v_idx),
             ],
             out_specs=pl.BlockSpec((1, hkv, tile_p, d), q_idx),
             scratch_shapes=[
@@ -406,7 +462,7 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name=_disp.kernel_name("flash_decode"),
-    )(*scalars, qg, k2, v2)
+    )(*scalars, qg, k_arr, v_arr)
     out = out.reshape(b, hkv, nq, tile_p, d)[:, :, :, :bq * g]
     out = out.reshape(b, hkv, nq * bq * g, d)[:, :, :rows]
     out = out.reshape(b, hkv, s, g, d).transpose(0, 2, 1, 3, 4)

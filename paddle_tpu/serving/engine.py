@@ -44,7 +44,7 @@ offline parity/eval batches; the engine is the right tool for traffic.
 
 **Paged mode** (``paged=True`` / FLAGS_serving_paged_kv): the per-slot
 cache rows are replaced by the kv_cache.py block pool — one
-``(L, 2, num_blocks, block_len, Hkv, D)`` array plus a host-side
+``(L, 2, num_blocks, block_len, Hkv·D)`` array plus a host-side
 :class:`~paddle_tpu.serving.kv_cache.BlockManager`.  What changes and
 what doesn't:
 
@@ -615,13 +615,19 @@ class ServingEngine:
             # layout of the hot path).  Applied AFTER the dispatch that
             # writes the block's contents (registration precedes the
             # wave-prefill dispatch), via the _pending_demote queue.
+            hkv = int(model.config.num_key_value_heads)
+
             def _demote_impl(c, bid):
-                blk = c[:, :, bid].astype(jnp.float32)  # (L,2,bl,Hkv,D)
+                flat = c[:, :, bid]                     # (L,2,bl,Hkv·D)
+                # per-kv-head absmax: heads apart on this one block
+                blk = flat.astype(jnp.float32).reshape(
+                    flat.shape[:3] + (hkv, -1))
                 sc = jnp.max(jnp.abs(blk), axis=(2, 4),
                              keepdims=True) / 127.0
                 safe = jnp.where(sc > 0, sc, 1.0)
                 q = jnp.clip(jnp.round(blk / safe), -127, 127)
-                return c.at[:, :, bid].set((q * safe).astype(c.dtype))
+                return c.at[:, :, bid].set(
+                    (q * safe).astype(c.dtype).reshape(flat.shape))
             self._demote_fn = _obs.track_retraces(
                 _demote_impl, "serving.demote",
                 labels={"engine": self._eid}, donate_argnums=(0,),
@@ -2856,6 +2862,7 @@ class ServingEngine:
                     b, s, hq, hkv, d_p, block_len=bl_p,
                     max_blocks=mb_p,
                     num_blocks=self.num_slots * mb_p + 1,
+                    num_layers=int(c.num_hidden_layers),
                     quantized=quantized, variant=tag))
             else:
                 kv_p = max(min_len,

@@ -3,11 +3,16 @@
 The vLLM PagedAttention memory model, TPU-shaped: instead of one
 contiguous ``(max_length, Hkv, D)`` cache row per slot (capacity paid at
 worst-case length, identical system prompts stored once per request), the
-device cache is ONE pooled array ``(L, 2, num_blocks, block_len, Hkv, D)``
+device cache is ONE pooled array ``(L, 2, num_blocks, block_len, Hkv·D)``
 of fixed-size KV blocks, and each slot owns a *block table* — the ordered
 list of physical block ids that back its logical token positions.  Cache
 cost becomes ``live tokens + shared prefixes`` instead of
-``num_slots × max_length``.
+``num_slots × max_length``.  A block is stored as the flash-decode
+kernel reads it — ``block_len`` rows of all kv heads' features side by
+side, one contiguous DMA — so the step programs hand the kernel the pool
+itself and never slice or re-lay-out a layer of it; code that needs the
+heads apart (per-head int8 scales, the XLA gather) reshapes the few
+blocks it touched.
 
 Division of labour:
 
@@ -20,11 +25,12 @@ Division of labour:
     a tiny traced ``(num_slots, max_blocks)`` int32 input, so allocation
     changes never retrace);
   * the device-side dereference lives in the attention paths: the Pallas
-    flash-decode kernel takes the table as a second scalar-prefetch
-    operand and its KV-chunk index maps look physical blocks up *before*
-    each grid step (ops/pallas/decode_attention.py), and the XLA math path
-    gathers ``pool[block_table]`` into the contiguous layout
-    (ops/attention.py).  Writes are batched scatters to
+    flash-decode kernel takes the whole pool, and the table as a second
+    scalar-prefetch operand, and its KV-chunk index maps look layer, K/V
+    and physical block up *before* each grid step
+    (ops/pallas/decode_attention.py), and the XLA math path gathers
+    ``pool[layer, k|v, block_table]`` into the contiguous layout
+    (ops/attention.py).  Writes are batched scatters of ``Hkv·D`` rows to
     ``(physical_block, offset)`` pairs (models/llama.py ``decode``).
 
 Conventions the device side relies on:
@@ -165,11 +171,13 @@ class _StatsView(Mapping):
 
 def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
                         quantized: bool = False):
-    """Pooled paged cache: (L, 2, num_blocks, block_len, kv_heads, head_dim)
-    — the contiguous cache's (B, max_len) plane re-cut into fixed blocks.
+    """Pooled paged cache: (L, 2, num_blocks, block_len, kv_heads·head_dim)
+    — the contiguous cache's (B, max_len) plane re-cut into fixed blocks,
+    each block in the layout the flash-decode kernel DMAs (heads fused
+    into the last axis, head-major).  The block axis is axis 2.
 
     ``quantized``: the int8 pool — a two-leaf pytree
-    ``{"kv": int8 (L, 2, nb, bl, Hkv, D), "scale": f32 (L, 2, nb, Hkv)}``
+    ``{"kv": int8 (L, 2, nb, bl, Hkv·D), "scale": f32 (L, 2, nb, Hkv)}``
     where ``scale[l, kv, b, h]`` is physical block ``b``'s
     per-kv-head symmetric dequant factor (absmax/127, running-max across
     scatter-time writes).  Zero scale == empty block (dequantizes to 0).
@@ -178,17 +186,15 @@ def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
     """
     import jax.numpy as jnp
 
+    shape = (config.num_hidden_layers, 2, num_blocks, block_len,
+             config.num_key_value_heads * config.head_dim)
     if quantized:
         return {
-            "kv": jnp.zeros((config.num_hidden_layers, 2, num_blocks,
-                             block_len, config.num_key_value_heads,
-                             config.head_dim), jnp.int8),
-            "scale": jnp.zeros((config.num_hidden_layers, 2, num_blocks,
-                                config.num_key_value_heads), jnp.float32),
+            "kv": jnp.zeros(shape, jnp.int8),
+            "scale": jnp.zeros(shape[:3] + (config.num_key_value_heads,),
+                               jnp.float32),
         }
-    dt = dtype if dtype is not None else config.dtype
-    return jnp.zeros((config.num_hidden_layers, 2, num_blocks, block_len,
-                      config.num_key_value_heads, config.head_dim), dt)
+    return jnp.zeros(shape, dtype if dtype is not None else config.dtype)
 
 
 class _SlotAlloc:

@@ -317,13 +317,18 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
                           quantized: bool = False,
                           n_granules: Optional[int] = None,
                           q_dtype: str = "bfloat16",
-                          variant: Optional[str] = None) -> KernelSpec:
-    """KernelSpec for one ``decode_attention_pallas`` call.
+                          variant: Optional[str] = None,
+                          num_layers: int = 1) -> KernelSpec:
+    """KernelSpec for one ``decode_attention_pallas`` /
+    ``paged_decode_attention_pallas`` call.
 
-    Contiguous layout: pass ``kv_len`` (the cache max_length; the pool
-    is the identity-table view ``(b*chunks, bk, hkv*d)``).  Paged: pass
-    ``block_len`` + ``max_blocks`` (+ ``num_blocks``, default the
-    serving engine's ``num_slots*max_blocks + 1`` null-block pool).
+    Contiguous layout: pass ``kv_len`` (the cache max_length; the K/V
+    operands are the identity-table view ``(b*chunks, bk, hkv*d)``).
+    Paged: pass ``block_len`` + ``max_blocks`` (+ ``num_blocks``, default
+    the serving engine's ``num_slots*max_blocks + 1`` null-block pool);
+    the K and V operands are then THE pool
+    ``(num_layers, 2, num_blocks, bk, hkv*d)`` itself, and the spec is the
+    last layer's call — the index maps' largest layer index.
     ``quantized`` adds the two f32 scale tables as scalar-prefetch
     operands; contiguous int8 pins
     the KV chunk to the scale granule (``n_granules`` — the
@@ -415,13 +420,30 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         bi, qi, ki = grid_ivs
         return (bi, Iv.const(0), qi, Iv.const(0))
 
-    def kv_idx(grid_ivs, sc):
+    def live_block(grid_ivs, sc):
         bi, qi, ki = grid_ivs
         pos = sc.lookup("pos", bi)
         last = (pos + iv_min((qi + 1) * bq, Iv.const(s)) - 1) // bk
         col = iv_min(ki, last)
-        blk = sc.lookup("bt", bi, col)
-        return (blk, Iv.const(0), Iv.const(0))
+        return sc.lookup("bt", bi, col)
+
+    if paged:
+        # the kernel's operand is the stacked pool: (layer, K|V, block)
+        layer = Iv.const(int(num_layers) - 1)
+        kv_block = (1, 1, 1, bk, hkv * d)
+        kv_array = (int(num_layers), 2, n_pool, bk, hkv * d)
+
+        def kv_idx(which):
+            return lambda grid_ivs, sc: (
+                layer, Iv.const(which), live_block(grid_ivs, sc),
+                Iv.const(0), Iv.const(0))
+    else:
+        kv_block = (1, bk, hkv * d)
+        kv_array = (n_pool, bk, hkv * d)
+
+        def kv_idx(which):
+            return lambda grid_ivs, sc: (
+                live_block(grid_ivs, sc), Iv.const(0), Iv.const(0))
 
     clamp = ClampCheck(table="bt", pin_scalar="pos", pin_axis=1,
                        expected=expected_last)
@@ -433,14 +455,12 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
 
     q_block = (1, hkv, tile_p, d)
     q_array = (b, hkv, nq * tile_p, d)
-    kv_block = (1, bk, hkv * d)
-    kv_array = (n_pool, bk, hkv * d)
     operands = [
         BlockOperand("q", q_block, q_array, q_dtype, q_idx,
                      sublane_padded=True, fetches=q_fetches),
-        BlockOperand("k", kv_block, kv_array, kv_dtype, kv_idx,
+        BlockOperand("k", kv_block, kv_array, kv_dtype, kv_idx(0),
                      fetches=kv_fetches, kv_stream=True, clamp=clamp),
-        BlockOperand("v", kv_block, kv_array, kv_dtype, kv_idx,
+        BlockOperand("v", kv_block, kv_array, kv_dtype, kv_idx(1),
                      fetches=kv_fetches, kv_stream=True, clamp=clamp),
     ]
     operands.append(
@@ -620,9 +640,10 @@ def registered_kernel_specs() -> List[KernelSpec]:
                               quantized=True, n_granules=8192 // 128,
                               variant="contiguous+int8,decode"),
         decode_attention_spec(8, 1, 32, 8, 128, block_len=128,
-                              max_blocks=64, variant="paged,decode"),
+                              max_blocks=64, num_layers=32,
+                              variant="paged,decode"),
         decode_attention_spec(8, 1, 32, 8, 128, block_len=128,
-                              max_blocks=64, quantized=True,
+                              max_blocks=64, num_layers=32, quantized=True,
                               variant="paged+int8,decode"),
         # the q-tiled modes: a chunked-prefill q chunk and the
         # speculative verify window, through the same kernel

@@ -191,3 +191,22 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
     assert r.returncode == 0, r.stderr
     assert r.stdout.strip() == str(tmp_path)
     assert os.listdir(tmp_path)             # jax wrote its entries there
+
+
+def test_paged_step_temporaries_reads_the_compiled_step():
+    """A roomy pool: the decode step's temporaries (activations, the
+    gathered blocks of two rows) stay far under a layer's K+V, and the
+    layer's bytes are the pool's own; a pool of three 8-token blocks is
+    smaller than the step's activations, which is what a copy of the pool
+    would look like."""
+    cfg = _config()
+    model = chip_smoke.build_model(cfg)
+    facts = chip_smoke.paged_step_temporaries(
+        model, num_slots=2, max_length=256, block_len=128, num_blocks=65)
+    assert facts["pool_shape"] == [
+        cfg.num_hidden_layers, 2, 65, 128,
+        cfg.num_key_value_heads * cfg.head_dim]
+    assert 0 < facts["temp_bytes"] < facts["layer_kv_bytes"]
+    with pytest.raises(AssertionError, match="something copies the pool"):
+        chip_smoke.paged_step_temporaries(
+            model, num_slots=2, max_length=64, block_len=8, num_blocks=3)

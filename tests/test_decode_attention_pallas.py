@@ -14,8 +14,11 @@ import pytest
 from paddle_tpu import flags
 from paddle_tpu.ops.attention import (cached_decode_attention,
                                       cached_decode_attention_reference,
-                                      decode_attention_path)
-from paddle_tpu.ops.pallas.decode_attention import decode_attention_pallas
+                                      decode_attention_path,
+                                      paged_decode_attention,
+                                      paged_decode_attention_reference)
+from paddle_tpu.ops.pallas.decode_attention import (
+    decode_attention_pallas, paged_decode_attention_pallas)
 
 
 def _qkv(b, s, hq, hkv, d, L, seed=0, dtype=jnp.float32):
@@ -186,17 +189,25 @@ def test_spec_verify_dispatch_contract():
 
 # -- paged cache: block-table dereference ------------------------------------
 
-def _paged_pool(kc, vc, tables, num_pool, bl):
-    """Scatter each row's logical blocks into the physical pool slots the
-    table names (the inverse of what the kernel/gather path computes)."""
+LAYER = 1          # the layer the paged cases read, of a 3-layer pool
+
+
+def _paged_pool(kc, vc, tables, num_pool, bl, layers=3, layer=LAYER):
+    """Scatter each row's logical blocks into the physical slots the table
+    names (the inverse of what the kernel/gather path computes) of layer
+    ``layer`` of a STACKED pool ``(layers, 2, num_pool, bl, Hkv·D)``; the
+    other layers hold noise, so reading the wrong layer, or V for K,
+    cannot pass."""
     b, L, hkv, d = kc.shape
-    kp = np.zeros((num_pool, bl, hkv, d), kc.dtype)
-    vp = np.zeros_like(kp)
+    pool = np.random.default_rng(99).normal(
+        size=(layers, 2, num_pool, bl, hkv * d)).astype(kc.dtype)
+    pool[layer] = 0
     for r in range(b):
         for j in range(L // bl):
-            kp[tables[r, j]] = kc[r, j * bl:(j + 1) * bl]
-            vp[tables[r, j]] = vc[r, j * bl:(j + 1) * bl]
-    return jnp.asarray(kp), jnp.asarray(vp)
+            sl = slice(j * bl, (j + 1) * bl)
+            pool[layer, 0, tables[r, j]] = kc[r, sl].reshape(bl, hkv * d)
+            pool[layer, 1, tables[r, j]] = vc[r, sl].reshape(bl, hkv * d)
+    return jnp.asarray(pool)
 
 
 PAGED_CASES = [
@@ -244,27 +255,25 @@ def test_paged_kernel_matches_contiguous_reference(b, s, hq, hkv, d, mb,
     pos = jnp.asarray(pos, jnp.int32)
     want = cached_decode_attention_reference(q, jnp.asarray(kc),
                                              jnp.asarray(vc), pos)
-    kp, vp = _paged_pool(kc, vc, tables, num_pool=10, bl=bl)
-    got = decode_attention_pallas(q, kp, vp, pos,
-                                  block_tables=jnp.asarray(tables),
-                                  interpret=True)
+    pool = _paged_pool(kc, vc, tables, num_pool=10, bl=bl)
+    got = paged_decode_attention_pallas(q, pool, LAYER, pos,
+                                        jnp.asarray(tables), interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # the XLA gather path is the same oracle through the table
-    got_ref = cached_decode_attention_reference(
-        q, kp, vp, pos, block_tables=jnp.asarray(tables))
+    got_ref = paged_decode_attention_reference(q, pool, LAYER, pos,
+                                               jnp.asarray(tables))
     np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_paged_kernel_rejects_unaligned_block_len():
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.normal(size=(4, 64, 2, 32)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(2, 2, 4, 64, 2 * 32)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(1, 1, 4, 32)), jnp.float32)
     with pytest.raises(NotImplementedError, match="128-aligned"):
-        decode_attention_pallas(q, kp, kp, 5,
-                                block_tables=jnp.asarray([[1, 2]]),
-                                interpret=True)
+        paged_decode_attention_pallas(q, pool, 1, 5, jnp.asarray([[1, 2]]),
+                                      interpret=True)
 
 
 def test_paged_live_len_trims_table_columns():
@@ -274,12 +283,12 @@ def test_paged_live_len_trims_table_columns():
     vc = rng.normal(size=(2, mb * bl, 2, 64)).astype(np.float32)
     q = jnp.asarray(rng.normal(size=(2, 1, 8, 64)), jnp.float32)
     tables = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
-    kp, vp = _paged_pool(kc, vc, tables, num_pool=9, bl=bl)
+    pool = _paged_pool(kc, vc, tables, num_pool=9, bl=bl)
     pos = jnp.asarray([100, 200], jnp.int32)
-    full = cached_decode_attention_reference(
-        q, kp, vp, pos, block_tables=jnp.asarray(tables))
-    trimmed = cached_decode_attention_reference(
-        q, kp, vp, pos, block_tables=jnp.asarray(tables), live_len=256)
+    full = paged_decode_attention_reference(q, pool, LAYER, pos,
+                                            jnp.asarray(tables))
+    trimmed = paged_decode_attention_reference(
+        q, pool, LAYER, pos, jnp.asarray(tables), live_len=256)
     np.testing.assert_allclose(np.asarray(trimmed), np.asarray(full),
                                rtol=1e-6, atol=1e-6)
 
@@ -370,9 +379,9 @@ class TestDispatch:
         from paddle_tpu.ops.pallas import decode_attention as mod
 
         calls = []
-        real = mod.decode_attention_pallas
+        real = mod.paged_decode_attention_pallas
         monkeypatch.setattr(
-            mod, "decode_attention_pallas",
+            mod, "paged_decode_attention_pallas",
             lambda *a, **kw: calls.append(1) or real(*a, **kw))
         bl, mb = 128, 2
         rng = np.random.default_rng(41)
@@ -380,10 +389,10 @@ class TestDispatch:
         vc = rng.normal(size=(2, mb * bl, 2, 64)).astype(np.float32)
         q = jnp.asarray(rng.normal(size=(2, 1, 8, 64)), jnp.float32)
         tables = np.asarray([[4, 2], [3, 1]], np.int32)
-        kp, vp = _paged_pool(kc, vc, tables, num_pool=5, bl=bl)
+        pool = _paged_pool(kc, vc, tables, num_pool=5, bl=bl)
         pos = jnp.asarray([130, 77], jnp.int32)
-        got = cached_decode_attention(q, kp, vp, pos,
-                                      block_tables=jnp.asarray(tables))
+        got = paged_decode_attention(q, pool, LAYER, pos,
+                                     jnp.asarray(tables))
         assert calls, "eligible paged shape did not route to the kernel"
         want = cached_decode_attention_reference(q, jnp.asarray(kc),
                                                  jnp.asarray(vc), pos)
@@ -398,7 +407,7 @@ class TestDispatch:
         from paddle_tpu.ops.pallas import decode_attention as mod
 
         calls = []
-        monkeypatch.setattr(mod, "decode_attention_pallas",
+        monkeypatch.setattr(mod, "paged_decode_attention_pallas",
                             lambda *a, **kw: calls.append(1))
         bl, mb = 64, 4                         # 64 % 128 != 0
         rng = np.random.default_rng(43)
@@ -406,9 +415,9 @@ class TestDispatch:
         vc = rng.normal(size=(1, mb * bl, 2, 64)).astype(np.float32)
         q = jnp.asarray(rng.normal(size=(1, 1, 8, 64)), jnp.float32)
         tables = np.asarray([[4, 3, 2, 1]], np.int32)
-        kp, vp = _paged_pool(kc, vc, tables, num_pool=5, bl=bl)
-        got = cached_decode_attention(q, kp, vp, 100,
-                                      block_tables=jnp.asarray(tables))
+        pool = _paged_pool(kc, vc, tables, num_pool=5, bl=bl)
+        got = paged_decode_attention(q, pool, LAYER, 100,
+                                     jnp.asarray(tables))
         assert not calls
         want = cached_decode_attention_reference(q, jnp.asarray(kc),
                                                  jnp.asarray(vc), 100)
@@ -441,8 +450,11 @@ class TestDispatch:
         # 128-token block per row at this max_length)
         tables = np.asarray([[3], [1]], np.int32)
         pool = init_paged_kv_cache(lm.config, 5, 128)
-        pool = pool.at[:, :, 3].set(cache[:, :, 0])
-        pool = pool.at[:, :, 1].set(cache[:, :, 1])
+
+        def fused(row):         # (L, 2, 128, Hkv, D) -> the pool's block
+            return row.reshape(row.shape[:3] + (-1,))
+        pool = pool.at[:, :, 3].set(fused(cache[:, :, 0]))
+        pool = pool.at[:, :, 1].set(fused(cache[:, :, 1]))
         flags.set_flags({"decode_attention_min_len": 128})
         try:
             logits_p, pool = lm.decode_step(
@@ -454,10 +466,10 @@ class TestDispatch:
                                    rtol=2e-4, atol=2e-4)
         # the paged write landed in each row's physical block
         np.testing.assert_allclose(np.asarray(pool[:, :, 3]),
-                                   np.asarray(cache_c[:, :, 0]),
+                                   np.asarray(fused(cache_c[:, :, 0])),
                                    rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(np.asarray(pool[:, :, 1]),
-                                   np.asarray(cache_c[:, :, 1]),
+                                   np.asarray(fused(cache_c[:, :, 1])),
                                    rtol=2e-5, atol=2e-5)
 
     def test_llama_decode_step_through_kernel(self):

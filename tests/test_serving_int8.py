@@ -24,7 +24,9 @@ from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
 from paddle_tpu.models.generation import init_kv_cache
 from paddle_tpu.ops.attention import (cached_decode_attention_reference,
                                       decode_attention_path)
-from paddle_tpu.ops.pallas.decode_attention import decode_attention_pallas
+from paddle_tpu.ops.attention import paged_decode_attention_reference
+from paddle_tpu.ops.pallas.decode_attention import (
+    decode_attention_pallas, paged_decode_attention_pallas)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_cache import BlockManager, init_paged_kv_cache
 from paddle_tpu.static_analysis.rules import DtypePromotionRule
@@ -83,32 +85,31 @@ def test_paged_int8_kernel_matches_dequantized_reference():
     want = cached_decode_attention_reference(
         q, jnp.asarray(kdeq), jnp.asarray(vdeq), pos)
 
-    # scatter rows into a 6-block pool per the tables
-    npool = 6
-    kp = np.zeros((npool, bl, hkv, d), np.int8)
-    vp = np.zeros((npool, bl, hkv, d), np.int8)
-    ksc = np.zeros((npool, hkv), np.float32)
-    vsc = np.zeros((npool, hkv), np.float32)
+    # scatter rows into layer 1 of a stacked 2-layer, 6-block pool per the
+    # tables; layer 0 holds another payload under other scales
+    npool, layer = 6, 1
+    pool = np.zeros((2, 2, npool, bl, hkv * d), np.int8)
+    pool[0] = rng.integers(-127, 128, pool[0].shape)
+    psc = np.zeros((2, 2, npool, hkv), np.float32)
+    psc[0] = 0.5
     for r in range(b):
         for j in range(mb):
             phys = int(tables[r, j])
-            kp[phys] = kq[r, j * bl:(j + 1) * bl]
-            vp[phys] = vq[r, j * bl:(j + 1) * bl]
-            ksc[phys] = ks[r, j]
-            vsc[phys] = vs[r, j]
+            sl = slice(j * bl, (j + 1) * bl)
+            pool[layer, 0, phys] = kq[r, sl].reshape(bl, hkv * d)
+            pool[layer, 1, phys] = vq[r, sl].reshape(bl, hkv * d)
+            psc[layer, 0, phys] = ks[r, j]
+            psc[layer, 1, phys] = vs[r, j]
 
-    got = decode_attention_pallas(
-        q, jnp.asarray(kp), jnp.asarray(vp), pos,
-        block_tables=jnp.asarray(tables),
-        k_scale=jnp.asarray(ksc), v_scale=jnp.asarray(vsc),
-        interpret=True)
+    got = paged_decode_attention_pallas(
+        q, jnp.asarray(pool), layer, pos, jnp.asarray(tables),
+        pool_scale=jnp.asarray(psc), interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # the XLA gather+dequant path is the same oracle through the table
-    got_ref = cached_decode_attention_reference(
-        q, jnp.asarray(kp), jnp.asarray(vp), pos,
-        block_tables=jnp.asarray(tables),
-        k_scale=jnp.asarray(ksc), v_scale=jnp.asarray(vsc))
+    got_ref = paged_decode_attention_reference(
+        q, jnp.asarray(pool), layer, pos, jnp.asarray(tables),
+        pool_scale=jnp.asarray(psc))
     np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -124,18 +125,22 @@ def test_int8_kernel_lowers_for_tpu(paged):
     S = jax.ShapeDtypeStruct
     q, pos = S((b, 1, hq, d), jnp.bfloat16), S((b,), jnp.int32)
     if paged:
-        npool = b * (L // bl) + 1
-        kv, sc = S((npool, bl, hkv, d), jnp.int8), S((npool, hkv),
-                                                     jnp.float32)
-        args = (q, kv, kv, pos, sc, sc, S((b, L // bl), jnp.int32))
+        npool, layers = b * (L // bl) + 1, 4
+        args = (q, S((layers, 2, npool, bl, hkv * d), jnp.int8), pos,
+                S((b, L // bl), jnp.int32),
+                S((layers, 2, npool, hkv), jnp.float32))
+
+        def f(q, pool, pos, bt, sc):
+            return paged_decode_attention_pallas(q, pool, layers - 1, pos,
+                                                 bt, pool_scale=sc)
     else:
         kv, sc = S((b, L, hkv, d), jnp.int8), S((b, L // bl, hkv),
                                                 jnp.float32)
         args = (q, kv, kv, pos, sc, sc)
 
-    def f(q, k, v, pos, ks, vs, bt=None):
-        return decode_attention_pallas(q, k, v, pos, block_tables=bt,
-                                       k_scale=ks, v_scale=vs)
+        def f(q, k, v, pos, ks, vs):
+            return decode_attention_pallas(q, k, v, pos, k_scale=ks,
+                                           v_scale=vs)
 
     text = jax.jit(f).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()

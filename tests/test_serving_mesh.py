@@ -145,7 +145,8 @@ def test_shard_map_decode_parity_and_routing():
     from paddle_tpu import observability as obs
     from paddle_tpu.distributed import env as denv
     from paddle_tpu.ops.attention import (cached_decode_attention,
-                                          cached_decode_attention_reference)
+                                          cached_decode_attention_reference,
+                                          paged_decode_attention)
 
     b, s, hq, hkv, d, kv_len, bl = 4, 1, 8, 2, 64, 8192, 128
     rs = np.random.RandomState(17)
@@ -154,8 +155,12 @@ def test_shard_map_decode_parity_and_routing():
     vc = jnp.asarray(rs.normal(size=(b, kv_len, hkv, d)).astype(np.float32))
     pos = jnp.asarray([37, 513, 129, 1025], jnp.int32)
     n_blocks = kv_len // bl
-    pool_k = jnp.reshape(kc, (b * n_blocks, bl, hkv, d))
-    pool_v = jnp.reshape(vc, (b * n_blocks, bl, hkv, d))
+    # the stacked pool in kernel layout, read at layer 1; layer 0 is noise
+    pool = jnp.stack([
+        jnp.asarray(rs.normal(size=(2, b * n_blocks, bl, hkv * d))
+                    .astype(np.float32)),
+        jnp.stack([jnp.reshape(kc, (b * n_blocks, bl, hkv * d)),
+                   jnp.reshape(vc, (b * n_blocks, bl, hkv * d))])])
     tables = jnp.reshape(jnp.arange(b * n_blocks, dtype=jnp.int32),
                          (b, n_blocks))
     reg = obs.default_registry()
@@ -169,8 +174,7 @@ def test_shard_map_decode_parity_and_routing():
         mesh = ServingEngine._resolve_mesh("mp2dp2")
         with denv.use_mesh(mesh):
             got = cached_decode_attention(q, kc, vc, pos)
-            got_paged = cached_decode_attention(q, pool_k, pool_v, pos,
-                                                block_tables=tables)
+            got_paged = paged_decode_attention(q, pool, 1, pos, tables)
     finally:
         flags_mod.set_flags({"pallas_interpret": old})
     want = cached_decode_attention_reference(q, kc, vc, pos)

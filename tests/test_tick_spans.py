@@ -254,19 +254,19 @@ def test_program_part_leads_the_kernel_name_in_the_traced_program():
 
     from paddle_tpu.ops import _dispatch as disp
     from paddle_tpu.ops.pallas.decode_attention import \
-        decode_attention_pallas
+        paged_decode_attention_pallas
 
     assert disp.kernel_name("flash_decode") == "flash_decode"
     q = jnp.zeros((2, 1, 4, 128), jnp.float32)
-    pool = jnp.zeros((5, 128, 2, 128), jnp.float32)
+    pool = jnp.zeros((2, 2, 5, 128, 2 * 128), jnp.float32)
     tables = jnp.zeros((2, 2), jnp.int32)
 
-    def rows(q, k, v):
+    def rows(q, pool):
         with disp.program_part("_step_impl", "decode_rows"):
-            return decode_attention_pallas(q, k, v, jnp.zeros((2,), jnp.int32),
-                                           block_tables=tables,
-                                           interpret=True)
+            return paged_decode_attention_pallas(
+                q, pool, 1, jnp.zeros((2,), jnp.int32), tables,
+                interpret=True)
 
-    text = str(jax.make_jaxpr(rows)(q, pool, pool))
+    text = str(jax.make_jaxpr(rows)(q, pool))
     assert "_step_impl_decode_rows_flash_decode" in text
     assert disp.kernel_name("flash_decode") == "flash_decode"   # restored
