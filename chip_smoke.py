@@ -112,25 +112,55 @@ def _scatter_to_pool(k, v, block_len, seed, layers=2):
 
 def parity_decode(*, paged=False, s=1, b=8, hq=32, hkv=8, d=128,
                   kv_len=8192, block_len=128, dtype="bfloat16",
-                  interpret=False):
+                  interpret=False, window=None):
     """Flash-decode kernel vs ``cached_decode_attention_reference``:
     contiguous or paged (the stacked pool handed over whole), s=1 (steady
     decode), s=5 (spec-verify window, k+1) or s=256 (a q-tiled prefill
-    chunk)."""
+    chunk); with ``window`` the sliding-window form of both (rows deeper
+    than the window start their block walk behind it)."""
     from paddle_tpu.ops.attention import cached_decode_attention_reference
     from paddle_tpu.ops.pallas.decode_attention import (
         decode_attention_pallas, paged_decode_attention_pallas)
 
     q, k, v, pos = _decode_inputs(b, s, hq, hkv, d, kv_len, 40 + s, dtype)
-    want = cached_decode_attention_reference(q, k, v, pos)
+    win = {} if window is None else {"window": window}
+    want = cached_decode_attention_reference(q, k, v, pos, **win)
     if paged:
         pool, layer, tables = _scatter_to_pool(k, v, block_len, 7)
         got = paged_decode_attention_pallas(q, pool, layer, pos, tables,
-                                            interpret=interpret)
+                                            interpret=interpret, **win)
     else:
-        got = decode_attention_pallas(q, k, v, pos, interpret=interpret)
+        got = decode_attention_pallas(q, k, v, pos, interpret=interpret,
+                                      **win)
     return _close(got, want, ATTN_TOL,
-                  f"decode {'paged' if paged else 'contiguous'} s={s}")
+                  f"decode {'paged' if paged else 'contiguous'} s={s}"
+                  f"{'' if window is None else f' window={window}'}")
+
+
+def parity_moe_experts(*, rows=768, experts=32, k=3072, n=3072,
+                       dtype="bfloat16", interpret=False):
+    """The held experts' grouped matrix product (Pallas) vs
+    ``jax.lax.ragged_dot`` over the rows that belong to a group: uneven
+    groups, two experts that no pair chose, and a tail of rows routed to
+    experts held elsewhere (a sixth of the rows), which are multiplied with
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.distributed.moe import _grouped_matmul_fn
+
+    rng = np.random.default_rng(5)
+    sizes = rng.multinomial(rows - rows // 6, np.ones(experts) / experts)
+    sizes[[1, experts - 2]] = 0
+    held = int(sizes.sum())
+    xs = jnp.asarray(rng.normal(size=(rows, k)), dtype)
+    w = jnp.asarray(rng.normal(size=(experts, k, n)) * k ** -0.5, dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = _grouped_matmul_fn(rows, k, n, pallas=True)(xs, w, gs)
+    want = jax.lax.ragged_dot(xs, w, gs,
+                              preferred_element_type=jnp.float32)
+    del interpret       # the dispatcher reads FLAGS_pallas_interpret / CPU
+    return _close(got[:held], want[:held], ATTN_TOL,
+                  f"moe_experts rows={rows} experts={experts}")
 
 
 def parity_decode_int8_paged(*, b=8, hq=32, hkv=8, d=128, kv_len=8192,
@@ -218,6 +248,13 @@ PARITY_CASES = {
     "decode_paged_spec_window5": functools.partial(parity_decode, paged=True,
                                                    s=5),
     "decode_paged_int8_kv": parity_decode_int8_paged,
+    # the AFMoE cell's geometry: 48 q / 8 kv heads, depth 6k, window 4096
+    "decode_paged_window_s1": functools.partial(
+        parity_decode, paged=True, hq=48, kv_len=6144, window=4096),
+    "decode_paged_window_chunk256": functools.partial(
+        parity_decode, paged=True, s=256, b=1, hq=48, kv_len=6144,
+        window=4096),
+    "moe_experts_gmm": parity_moe_experts,
     "int8_matmul": parity_int8_matmul,
     "flash_fwd_bwd": parity_flash,
 }
@@ -278,6 +315,26 @@ def build_model(config):
     from paddle_tpu.models import LlamaForCausalLM
     pt.seed(0)
     return LlamaForCausalLM(config)
+
+
+def build_afmoe_model(config):
+    """An AFMoE model built to be loaded (no initializer runs), then given
+    seeded weights one array at a time."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import nn
+    from paddle_tpu.models import AfmoeForCausalLM
+    with nn.abstract_parameters():
+        model = AfmoeForCausalLM(config)
+    key = jax.random.key(0)
+    for i, (_, p) in enumerate(model.named_parameters()):
+        if not isinstance(p.value, jax.ShapeDtypeStruct):
+            continue
+        z = jax.random.normal(jax.random.fold_in(key, i), p.shape,
+                              jnp.float32)
+        p.value = ((1.0 + 0.1 * z) if len(p.shape) == 1 else 0.02 * z
+                   ).astype(p.value.dtype)
+    return model
 
 
 def smoke_prompts(vocab, lengths, seed=0):
@@ -563,6 +620,29 @@ def main():
         dict(paged_step_temporaries(model, num_slots=8, max_length=8192),
              compile_s=compiles.drain()))
     del model
+    gc.collect()
+
+    # -- the second architecture the engine serves: AFMoE at its published
+    # widths (Trinity-Large: 3072 wide, 48 q / 8 kv heads of 128, experts
+    # of 3072), one dense and two expert layers (a window layer and a
+    # global one), 8 of 64 experts held; the window cut to 1024 so that the
+    # 2500-token prompt is served past it
+    from paddle_tpu.models import AfmoeConfig
+    afmoe = build_afmoe_model(AfmoeConfig(
+        vocab_size=8192, num_hidden_layers=3, num_dense_layers=1,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention"),
+        num_experts=64, ep_size=8, sliding_window=1024,
+        max_position_embeddings=8192, dtype="bfloat16"))
+    moe = serve_leg(
+        afmoe, prompts, NEW_TOKENS, num_slots=8, max_length=8192,
+        paged=True, chunked=True,
+        expect_paths=("decode_attention/pallas_decode/paged",
+                      "decode_attention_kernel/paged",
+                      "chunked_prefill/paged", "moe_experts/pallas_gmm"))
+    moe.pop("tokens")
+    say("serve afmoe paged+chunked", dict(moe, compile_s=compiles.drain()))
+    del afmoe
     gc.collect()
 
     # -- four chips: the sharded paths on real devices ---------------------

@@ -32,12 +32,24 @@ index-based too: O(T·k) int32 routing metadata plus the (E, C, D) expert
 batches, no (T, E, C) tensors at all.
 
 Everything is jit-traceable — static shapes, no data-dependent control flow.
+
+**Dropless, and told which experts it holds** (:class:`HeldExpertsMoE`,
+routed by :class:`SigmoidTopKGate`): the layer expert parallelism needs on
+every chip.  The router scores ALL experts; the layer holds the weights of
+one contiguous range of them and computes their part of the result — one
+grouped matrix product a projection over the (token, expert) pairs routed
+to a held expert, sorted by expert.  There is no ``(E, capacity, …)``
+buffer and no token is dropped, whatever the load; the parts of all ranks
+add up to the uncut layer.  On one chip the layer runs without its
+exchange.
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
-from typing import Callable, Optional
+import threading
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +60,11 @@ from ..nn import functional as F
 from ..tensor.math import einsum
 from ..nn import initializer as I
 from ..nn.layer import Layer
+from ..ops import _dispatch
 from .fleet.mp_layers import constrain
 
-__all__ = ["Gate", "SwitchGate", "GShardGate", "MoELayer"]
+__all__ = ["Gate", "SwitchGate", "GShardGate", "MoELayer",
+           "SigmoidTopKGate", "HeldExpertsMoE", "expert_load"]
 
 EP_AXES = ("dp", "sharding")  # expert dim rides the combined dp×sharding axes
 
@@ -288,3 +302,184 @@ class MoELayer(Layer):
         fwd = self._forward_index if mode == "index" else self._forward_dense
         out, aux = fwd(xt)
         return out.reshape(shape), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless routed experts, one expert-parallel rank's share
+# ---------------------------------------------------------------------------
+
+class SigmoidTopKGate(Gate):
+    """Sigmoid router with a selection bias (the aux-loss-free balancing
+    term).  Scores are ``sigmoid(x·W)`` in float32 over ALL experts; the
+    ``top_k`` experts are those of the largest ``scores + expert_bias``;
+    their WEIGHTS are the unbiased scores, divided by their sum where
+    ``route_norm`` and scaled by ``route_scale``: the bias moves the
+    selection and never the weight."""
+
+    def __init__(self, hidden_size: int, num_experts: int, top_k: int,
+                 route_scale: float = 1.0, route_norm: bool = True,
+                 dtype=None):
+        super().__init__(hidden_size, num_experts, dtype=dtype)
+        self.top_k = int(top_k)
+        self.route_scale = float(route_scale)
+        self.route_norm = bool(route_norm)
+        self.expert_bias = self.create_parameter(
+            (num_experts,), dtype="float32", initializer=I.Constant(0.0),
+            attr_name="expert_bias")
+
+    def logits(self, x):
+        # a choice near a tie flips on a rounding: every pass of the f32
+        # product, where the base class leaves the backend's default
+        return jnp.dot(x.astype(jnp.float32), self.weight.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+    def route(self, x):
+        """x (T, D) → (idx (T, top_k) int32 over ALL experts, weights
+        (T, top_k) float32)."""
+        scores = jax.nn.sigmoid(self.logits(x))                  # (T, E)
+        idx = jax.lax.top_k(
+            scores + self.expert_bias.astype(jnp.float32), self.top_k)[1]
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if self.route_norm:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w * self.route_scale
+
+
+_LOAD = threading.local()
+
+
+@contextlib.contextmanager
+def expert_load():
+    """Collect, at TRACE time, the load every :class:`HeldExpertsMoE`
+    called inside sees: yields a list that fills with one int32
+    ``(held + 1,)`` vector a layer call — the (token, expert) pairs routed
+    to each held expert, then the pairs routed to experts held elsewhere.
+    It only collects: what a layer computes does not depend on it.  The
+    serving step programs stack the list and return it beside the sampled
+    tokens.  Thread-local, like ``ops._dispatch.program_part``."""
+    prev = getattr(_LOAD, "sink", None)
+    _LOAD.sink = sink = []
+    try:
+        yield sink
+    finally:
+        _LOAD.sink = prev
+
+
+def grouped_kernel_takes(k: int, n: int) -> bool:
+    """The shape half of the grouped product's dispatch: the kernel's weight
+    tiles are lane-aligned cuts of (k, n)."""
+    from ..ops.pallas.limits import LANES
+    return k % LANES == 0 and n % LANES == 0
+
+
+def _grouped_matmul_fn(rows: int, k: int, n: int,
+                       pallas: Optional[bool] = None):
+    """The grouped matrix product ``(xs (rows, K), w (E, K, N), group_sizes
+    (E,)) -> (rows, N)``: rows ``[Σ sizes[:e], Σ sizes[:e+1])`` times
+    ``w[e]``, rows behind the last group left undefined.  On a Pallas
+    backend the grouped-matmul kernel (``ops/pallas/grouped_matmul.py``),
+    which walks only the row tiles of non-empty groups, so an expert no
+    pair chose costs no weight traffic; elsewhere, and for matrices that are
+    not lane-aligned, ``jax.lax.ragged_dot`` (``pallas`` overrides the
+    backend's choice: the parity cases).
+    Counted as ``ops.kernel_path{op="moe_experts"}``."""
+    if not ((_dispatch.use_pallas() if pallas is None else pallas)
+            and grouped_kernel_takes(k, n)):
+        _dispatch.count_kernel_path("moe_experts", "xla_reference")
+        return jax.lax.ragged_dot
+    from ..ops.pallas.grouped_matmul import TILE_ROWS, grouped_matmul_pallas
+    _dispatch.count_kernel_path("moe_experts", "pallas_gmm")
+    pad = -rows % TILE_ROWS
+
+    def grouped(xs, w, group_sizes):
+        if pad:
+            xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        out = grouped_matmul_pallas(
+            xs, w, group_sizes, interpret=_dispatch.pallas_interpret())
+        return out[:rows] if pad else out
+    return grouped
+
+
+class HeldExpertsMoE(Layer):
+    """Dropless routed experts, one expert-parallel rank's share.
+
+    The router (its own layer) chooses over all ``num_experts``; this layer
+    holds the stacked SwiGLU weights of the experts ``[held[0], held[1])``
+    and, given the tokens and the router's choice, returns Σ over the
+    chosen experts THAT ARE HELD of ``w_e · E_e(x)``.  What the absent
+    experts would add is left out: the expert-parallel exchange (or, on one
+    chip, nothing) supplies it.
+
+    The product is grouped: the ``T·k`` (token, expert) pairs are sorted by
+    held expert (pairs of absent experts sort behind every group, where no
+    product is taken of them), their tokens gathered once, and one grouped
+    matmul a projection (:func:`_grouped_matmul_fn`) applies each expert's
+    matrices to its own contiguous run of rows — work and weight traffic
+    follow the pairs actually routed here, and no pair is ever dropped."""
+
+    def __init__(self, hidden_size: int, intermediate_size: int,
+                 num_experts: int, top_k: int,
+                 held: Optional[Tuple[int, int]] = None, dtype=None):
+        super().__init__()
+        lo, hi = (0, num_experts) if held is None else map(int, held)
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(
+                f"held experts [{lo}, {hi}) are no range of the router's "
+                f"{num_experts}")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.held = (lo, hi)
+        n = hi - lo
+        init = I.Normal(std=0.02)
+        self.gate_proj = self.create_parameter(
+            (n, hidden_size, intermediate_size), dtype=dtype,
+            initializer=init, sharding=P(EP_AXES), attr_name="gate_proj")
+        self.up_proj = self.create_parameter(
+            (n, hidden_size, intermediate_size), dtype=dtype,
+            initializer=init, sharding=P(EP_AXES), attr_name="up_proj")
+        self.down_proj = self.create_parameter(
+            (n, intermediate_size, hidden_size), dtype=dtype,
+            initializer=init, sharding=P(EP_AXES), attr_name="down_proj")
+
+    def forward(self, x, idx, w, valid=None):
+        """x (..., D), the router's ``idx``/``w`` (T, top_k) over all
+        experts → this share's part of the routed result (..., D).
+        ``valid`` (bool, any shape that flattens to the T tokens; None:
+        all) marks the REAL tokens: the rest are padding (an idle slot's
+        row, a prompt chunk's tail), which is routed to no expert — it
+        reads no expert's weights, its rows of the result are zero and it
+        is counted nowhere."""
+        shape = x.shape
+        xt = x.reshape(-1, shape[-1])                            # (T, D)
+        lo, hi = self.held
+        t, k, n = xt.shape[0], idx.shape[1], hi - lo
+        with jax.named_scope("ffn.route"):
+            # global expert id -> slot in the held stack, n where absent
+            slot = jnp.where((idx >= lo) & (idx < hi), idx - lo,
+                             n).reshape(-1)                      # (T·k,)
+            real = t * k
+            if valid is not None:
+                ok = jnp.asarray(valid).reshape(-1)
+                slot = jnp.where(jnp.repeat(ok, k), slot, n)
+                real = ok.sum(dtype=jnp.int32) * k
+            order = jnp.argsort(slot, stable=True)
+            group_sizes = jnp.zeros((n + 1,), jnp.int32).at[slot].add(1)[:n]
+            token = order // k
+            sink = getattr(_LOAD, "sink", None)
+            if sink is not None:
+                sink.append(jnp.concatenate(
+                    [group_sizes, (real - group_sizes.sum())[None]]))
+        with jax.named_scope("ffn.experts"):
+            d, f = self.gate_proj.shape[1:]
+            into, out_of = (_grouped_matmul_fn(t * k, d, f),
+                            _grouped_matmul_fn(t * k, f, d))
+            xs = xt[token]                                       # (T·k, D)
+            g = into(xs, self.gate_proj, group_sizes)
+            u = into(xs, self.up_proj, group_sizes)
+            ys = out_of(F.swiglu(g, u), self.down_proj, group_sizes)
+            # rows behind the last group belong to absent experts: their
+            # weight is zero, whatever the product left there
+            held = (slot[order] < n)[:, None]
+            ys = jnp.where(held, ys.astype(jnp.float32)
+                           * w.reshape(-1)[order][:, None], 0.0)
+            out = jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
+        return out.astype(x.dtype).reshape(shape)

@@ -6,6 +6,7 @@ measured on (BASELINE.md) and they double as integration tests of the hybrid
 parallel stack.
 """
 
+from .afmoe import AfmoeConfig, AfmoeForCausalLM, tiny_afmoe_config
 from .generation import (DecodeStep, accept_draft_tokens, greedy_generate,
                          init_kv_cache, sample_tokens)
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
@@ -17,4 +18,5 @@ __all__ = [
     "tiny_llama_config", "llama_pipe_descs", "causal_lm_loss",
     "DecodeStep", "greedy_generate", "init_kv_cache", "sample_tokens",
     "accept_draft_tokens", "draft_model_from",
+    "AfmoeConfig", "AfmoeForCausalLM", "tiny_afmoe_config",
 ]

@@ -1,0 +1,461 @@
+"""AFMoE decoder (``model_type: afmoe``, the Trinity family): sigmoid-routed
+dropless experts beside a shared expert, gated attention that alternates
+sliding-window layers (with RoPE) and global layers (with no position
+encoding at all), sandwich norms.
+
+With ``x`` the residual stream and ``N(·)`` an RMS norm with its own weight::
+
+    x0      = E[ids] · sqrt(hidden)                        (mup_enabled)
+    a       = N_post_attn(Attn(N_in(x)));      h  = x + a
+    m       = N_post_mlp(F(N_pre_mlp(h)));     x' = h + m
+    logits  = N_f(x_L) · W_head
+
+``Attn``: q as ``num_attention_heads`` heads of ``head_dim``, k and v as
+``num_key_value_heads``, and a gate ``g = sigmoid(y W_g)`` as wide as q; q
+and k take a per-head RMS norm (one weight vector of ``head_dim`` for all
+heads of a kind); RoPE (rotate-half) on ``sliding_attention`` layers only;
+causal softmax attention, on sliding layers restricted to the last
+``sliding_window`` keys; the output is ``(attn ⊙ g) W_o``.
+
+``F`` is a SwiGLU MLP of ``intermediate_size`` on the first
+``num_dense_layers`` layers, and on the others ``Shared(y) + Σ_k w_k ·
+Expert_{e_k}(y)``: :class:`~paddle_tpu.distributed.moe.SigmoidTopKGate`
+chooses ``num_experts_per_tok`` of ``num_experts`` and
+:class:`~paddle_tpu.distributed.moe.HeldExpertsMoE` computes the experts
+this expert-parallel rank holds (``ep_rank`` of ``ep_size``; all of them at
+``ep_size`` 1).  What experts held elsewhere would add is left out — the
+exchange that supplies it is not part of this model — and that partial
+result goes on to the next layer; the shared expert is computed whole.
+
+The cache row is plain K and V of ``num_key_value_heads · head_dim``, so
+the model decodes over the stacked caches llama uses: the paged pool
+(``serving/kv_cache.py``) as it is, written through llama's
+:func:`~paddle_tpu.models.llama.paged_kv_write`, and for ``generate()`` the
+contiguous cache.  Window layers pass their window to the cached-attention
+ops, whose kernel neither reads nor scores blocks behind it; the allocator
+frees nothing behind a window yet (ROADMAP R5).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
+from ..distributed.moe import HeldExpertsMoE, SigmoidTopKGate
+from ..nn import initializer as I
+from ..nn.common import RMSNorm
+from ..nn.layer import Layer, LayerList
+from ..ops import build_rope_cache, flash_attention, fused_rope
+from ..tensor.math import matmul
+from .llama import LlamaMLP, paged_kv_write
+
+__all__ = ["AfmoeConfig", "AfmoeAttention", "AfmoeMoE",
+           "AfmoeDecoderLayer", "AfmoeModel", "AfmoeForCausalLM",
+           "tiny_afmoe_config"]
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+@dataclasses.dataclass
+class AfmoeConfig:
+    """The published ``afmoe`` keys (defaults: Trinity-Large-Preview), plus
+    the expert-parallel share this instance holds."""
+    vocab_size: int = 200192
+    hidden_size: int = 3072
+    intermediate_size: int = 12288          # the leading dense layers' MLP
+    moe_intermediate_size: int = 3072       # one expert, routed or shared
+    num_hidden_layers: int = 60
+    num_dense_layers: int = 6
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    num_experts: int = 256
+    num_experts_per_tok: int = 4
+    num_shared_experts: int = 1
+    route_norm: bool = True
+    route_scale: float = 2.448
+    score_func: str = "sigmoid"
+    n_group: int = 1
+    topk_group: int = 1
+    sliding_window: int = 4096
+    global_attn_every_n_layers: int = 4
+    layer_types: Optional[Tuple[str, ...]] = None
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 262144
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True
+    tie_word_embeddings: bool = False
+    initializer_range: float = 0.02
+    dtype: str = "float32"
+    # this instance's share of every expert layer: rank ``ep_rank`` of
+    # ``ep_size`` holds experts [rank, rank + 1) · num_experts / ep_size
+    ep_size: int = 1
+    ep_rank: int = 0
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            n = self.global_attn_every_n_layers
+            self.layer_types = tuple(
+                FULL if (i + 1) % n == 0 else SLIDING
+                for i in range(self.num_hidden_layers))
+        self.layer_types = tuple(self.layer_types)
+        if (len(self.layer_types) != self.num_hidden_layers
+                or set(self.layer_types) - {SLIDING, FULL}):
+            raise ValueError(
+                f"layer_types must name {self.num_hidden_layers} layers as "
+                f"{SLIDING!r} or {FULL!r}, got {self.layer_types}")
+        if self.score_func != "sigmoid" or self.n_group != 1 \
+                or self.topk_group != 1:
+            raise NotImplementedError(
+                "AfmoeConfig: only sigmoid routing without a group limit "
+                f"(score_func={self.score_func!r}, n_group={self.n_group}, "
+                f"topk_group={self.topk_group})")
+        if (self.num_experts % self.ep_size
+                or not 0 <= self.ep_rank < self.ep_size):
+            raise ValueError(
+                f"{self.num_experts} experts do not split over ep_size "
+                f"{self.ep_size} (ep_rank {self.ep_rank})")
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """[lo, hi): the routed experts whose weights this rank holds."""
+        n = self.num_experts // self.ep_size
+        return self.ep_rank * n, (self.ep_rank + 1) * n
+
+    @property
+    def num_expert_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+
+def tiny_afmoe_config(**overrides) -> AfmoeConfig:
+    """Small config for tests: one dense layer, then a whole period of
+    three window layers and a global one."""
+    cfg = AfmoeConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        moe_intermediate_size=32, num_hidden_layers=5, num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        num_experts=8, num_experts_per_tok=2, sliding_window=16,
+        layer_types=(SLIDING,) * 4 + (FULL,), max_position_embeddings=128)
+    return dataclasses.replace(cfg, **overrides)
+
+
+class AfmoeAttention(Layer):
+    """Gated GQA attention with per-head q/k norms; a window and RoPE on
+    sliding layers, neither on global ones."""
+
+    def __init__(self, config: AfmoeConfig, layer_type: str):
+        super().__init__()
+        c = config
+        self.config = c
+        self.window = int(c.sliding_window) if layer_type == SLIDING else None
+        self.scope = "attn.window" if self.window else "attn.global"
+        hd, nh, nkv = c.head_dim, c.num_attention_heads, c.num_key_value_heads
+        init = I.Normal(std=c.initializer_range)
+
+        def proj(name, shape, spec):
+            return self.create_parameter(shape, dtype=c.dtype,
+                                         initializer=init, sharding=spec,
+                                         attr_name=name)
+        col, row = P("sharding", "mp"), P("mp", "sharding")
+        self.q_proj = proj("q_proj", (c.hidden_size, nh * hd), col)
+        self.k_proj = proj("k_proj", (c.hidden_size, nkv * hd), col)
+        self.v_proj = proj("v_proj", (c.hidden_size, nkv * hd), col)
+        self.gate_proj = proj("gate_proj", (c.hidden_size, nh * hd), col)
+        self.o_proj = proj("o_proj", (nh * hd, c.hidden_size), row)
+        self.q_norm = RMSNorm(hd, epsilon=c.rms_norm_eps, dtype=c.dtype)
+        self.k_norm = RMSNorm(hd, epsilon=c.rms_norm_eps, dtype=c.dtype)
+
+    def _qkv(self, x, rope_cache, position_ids):
+        c = self.config
+        b, s, _ = x.shape
+        q = matmul(x, self.q_proj).reshape(b, s, c.num_attention_heads,
+                                           c.head_dim)
+        k = matmul(x, self.k_proj).reshape(b, s, c.num_key_value_heads,
+                                           c.head_dim)
+        v = matmul(x, self.v_proj).reshape(b, s, c.num_key_value_heads,
+                                           c.head_dim)
+        q, k = self.q_norm(q), self.k_norm(k)
+        if self.window is not None:         # global layers: no position
+            q, k = fused_rope(q, k, *rope_cache, position_ids)
+        return q, k, v
+
+    def _out(self, x, attn):
+        b, s, _ = x.shape
+        with jax.named_scope("attn.gate"):
+            gate = jax.nn.sigmoid(matmul(x, self.gate_proj))
+            return matmul(attn.reshape(b, s, -1) * gate, self.o_proj)
+
+    def _band(self, s: int):
+        """(1, 1, s, s) bool: key j inside query i's window."""
+        i = jnp.arange(s)
+        return (i[:, None] - i[None, :] < self.window)[None, None]
+
+    def forward(self, x, rope_cache, position_ids=None):
+        with jax.named_scope(self.scope):
+            q, k, v = self._qkv(x, rope_cache, position_ids)
+            mask = None if self.window is None else self._band(x.shape[1])
+            out = flash_attention(q, k, v, causal=True, attn_mask=mask)
+            return self._out(x, out)
+
+    def decode(self, x, rope_cache, pos, cache, idx: int,
+               block_tables=None):
+        """Decode over the stacked cache, as ``LlamaAttention.decode``:
+        with ``block_tables`` the paged pool (per-row ``pos``), without
+        them the contiguous cache at a scalar ``pos`` (``generate()``).
+        Returns (out, cache)."""
+        from ..ops.attention import (cached_decode_attention,
+                                     paged_decode_attention)
+        b, s, _ = x.shape
+        win = {} if self.window is None else {"window": self.window}
+        if isinstance(cache, dict):
+            raise NotImplementedError(
+                "AfmoeAttention.decode: the int8 KV cache is not supported")
+        with jax.named_scope(self.scope):
+            if block_tables is not None:
+                if getattr(pos, "ndim", 0) != 1:
+                    pos = jnp.full((b,), pos, jnp.int32)
+                position_ids = pos[:, None] + jnp.arange(s)[None, :]
+                # prompt-pad positions may run past the RoPE table
+                rope_ids = jnp.minimum(position_ids,
+                                       rope_cache[0].shape[0] - 1)
+                q, k, v = self._qkv(x, rope_cache, rope_ids)
+                cache, kvp, _ = paged_kv_write(cache, idx, k, v,
+                                               position_ids, block_tables)
+                out = paged_decode_attention(q, kvp, idx, pos, block_tables,
+                                             **win)
+                return self._out(x, out), cache
+            if getattr(pos, "ndim", 0) != 0:
+                raise NotImplementedError(
+                    "AfmoeAttention.decode: per-row positions need the "
+                    "paged pool (block_tables); the contiguous cache is "
+                    "decoded at one scalar position")
+            q, k, v = self._qkv(x, rope_cache,
+                                pos + jnp.arange(s)[None, :])
+            cache = jax.lax.dynamic_update_slice(
+                cache, k.astype(cache.dtype)[None, None],
+                (idx, 0, 0, pos, 0, 0))
+            cache = jax.lax.dynamic_update_slice(
+                cache, v.astype(cache.dtype)[None, None],
+                (idx, 1, 0, pos, 0, 0))
+            if isinstance(pos, int) and pos == 0 and s > 1:
+                mask = None if self.window is None else self._band(s)
+                out = flash_attention(q, k, v, causal=True, attn_mask=mask)
+            else:
+                out = cached_decode_attention(q, cache[idx, 0],
+                                              cache[idx, 1], pos, **win)
+            return self._out(x, out), cache
+
+
+def swiglu_mlp(config: AfmoeConfig, width: int) -> LlamaMLP:
+    """SwiGLU MLP of a given width — the dense layers' and the shared
+    expert's: llama's, which reads ``hidden_size``, ``intermediate_size``,
+    ``initializer_range`` and ``dtype`` of whatever config it is given."""
+    return LlamaMLP(dataclasses.replace(config, intermediate_size=width))
+
+
+class AfmoeMoE(Layer):
+    """Router, this rank's share of the routed experts, and the shared
+    expert (whole)."""
+
+    def __init__(self, config: AfmoeConfig):
+        super().__init__()
+        c = config
+        self.router = SigmoidTopKGate(
+            c.hidden_size, c.num_experts, c.num_experts_per_tok,
+            route_scale=c.route_scale, route_norm=c.route_norm,
+            dtype=c.dtype)
+        self.experts = HeldExpertsMoE(
+            c.hidden_size, c.moe_intermediate_size, c.num_experts,
+            c.num_experts_per_tok, held=c.experts_held, dtype=c.dtype)
+        self.shared_experts = swiglu_mlp(
+            c, c.moe_intermediate_size * c.num_shared_experts)
+
+    def forward(self, x, valid=None):
+        with jax.named_scope("ffn.route"):
+            idx, w = self.router.route(x.reshape(-1, x.shape[-1]))
+        routed = self.experts(x, idx, w, valid=valid)
+        with jax.named_scope("ffn.shared"):
+            return self.shared_experts(x) + routed
+
+
+class AfmoeDecoderLayer(Layer):
+    def __init__(self, config: AfmoeConfig, index: int):
+        super().__init__()
+        c = config
+
+        def norm():
+            return RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps,
+                           dtype=c.dtype)
+        self.input_layernorm = norm()
+        self.self_attn = AfmoeAttention(c, c.layer_types[index])
+        self.post_attention_layernorm = norm()
+        self.pre_mlp_layernorm = norm()
+        self.dense = index < c.num_dense_layers
+        self.mlp = (swiglu_mlp(c, c.intermediate_size) if self.dense
+                    else AfmoeMoE(c))
+        self.post_mlp_layernorm = norm()
+
+    def _ffn(self, h, valid=None):
+        y = self.pre_mlp_layernorm(h)
+        if self.dense:
+            with jax.named_scope("ffn.dense"):
+                m = self.mlp(y)
+        else:
+            m = self.mlp(y, valid=valid)
+        return h + self.post_mlp_layernorm(m)
+
+    def forward(self, x, rope_cache, position_ids=None):
+        a = self.self_attn(self.input_layernorm(x), rope_cache, position_ids)
+        return self._ffn(x + self.post_attention_layernorm(a))
+
+    def decode(self, x, rope_cache, pos, cache, idx: int,
+               block_tables=None, valid=None):
+        with jax.named_scope("attn"):
+            a, cache = self.self_attn.decode(
+                self.input_layernorm(x), rope_cache, pos, cache, idx,
+                block_tables=block_tables)
+            h = x + self.post_attention_layernorm(a)
+        with jax.named_scope("ffn"):
+            return self._ffn(h, valid), cache
+
+
+class AfmoeModel(Layer):
+    def __init__(self, config: AfmoeConfig):
+        super().__init__()
+        c = config
+        self.config = c
+        self.embed_tokens = self.create_parameter(
+            (c.vocab_size, c.hidden_size), dtype=c.dtype,
+            initializer=I.Normal(std=c.initializer_range),
+            sharding=P("mp", "sharding"), attr_name="embed_tokens")
+        self.layers = LayerList(
+            [AfmoeDecoderLayer(c, i) for i in range(c.num_hidden_layers)])
+        self.norm = RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps,
+                            dtype=c.dtype)
+        cos, sin = build_rope_cache(c.max_position_embeddings, c.head_dim,
+                                    base=c.rope_theta)
+        self.register_buffer("rope_cos", cos)
+        self.register_buffer("rope_sin", sin)
+
+    def _embed(self, input_ids):
+        x = vocab_parallel_lookup(self.embed_tokens, input_ids)
+        if self.config.mup_enabled:
+            # scaled in float32: sqrt(hidden) has no exact bf16 form
+            x = (x.astype(jnp.float32)
+                 * math.sqrt(self.config.hidden_size)).astype(x.dtype)
+        return x
+
+    def forward(self, input_ids, position_ids=None):
+        x = self._embed(input_ids)
+        rope = (self.rope_cos, self.rope_sin)
+        for block in self.layers:
+            x = block(x, rope, position_ids)
+        return self.norm(x)
+
+    def decode(self, input_ids, cache, pos, block_tables=None, valid=None):
+        """Cache-carrying decode pass over the stacked cache (contiguous
+        from ``init_kv_cache`` or, with ``block_tables``, the paged pool).
+        Returns (hidden, cache)."""
+        x = constrain(self._embed(input_ids), ("dp", "sharding"), None, None)
+        rope = (self.rope_cos, self.rope_sin)
+        for i, block in enumerate(self.layers):
+            x, cache = block.decode(x, rope, pos, cache, i,
+                                    block_tables=block_tables, valid=valid)
+        return self.norm(x), cache
+
+
+class AfmoeForCausalLM(Layer):
+    """Causal LM over :class:`AfmoeModel`; the serving engine's contract
+    is ``config`` + ``decode_step`` over the stacked cache."""
+
+    def __init__(self, config: AfmoeConfig):
+        super().__init__()
+        self.config = config
+        self.model = AfmoeModel(config)
+        if not config.tie_word_embeddings:
+            self.lm_head = self.create_parameter(
+                (config.hidden_size, config.vocab_size), dtype=config.dtype,
+                initializer=I.Normal(std=config.initializer_range),
+                sharding=P("sharding", "mp"), attr_name="lm_head")
+
+    def logits(self, hidden):
+        if self.config.tie_word_embeddings:
+            return matmul(hidden, self.model.embed_tokens.T)
+        return matmul(hidden, self.lm_head)
+
+    def forward(self, input_ids, position_ids=None):
+        return self.logits(self.model(input_ids, position_ids))
+
+    def decode_step(self, input_ids, cache, pos, block_tables=None,
+                    valid=None):
+        """(logits, cache): one cache-carrying decode step, as
+        ``LlamaForCausalLM.decode_step``.  ``valid`` (bool, shaped as
+        ``input_ids``; None: all) marks the real tokens; the routed
+        experts leave padding out (``HeldExpertsMoE.forward``)."""
+        hidden, cache = self.model.decode(input_ids, cache, pos,
+                                          block_tables=block_tables,
+                                          valid=valid)
+        with jax.named_scope("lm_head"):
+            return self.logits(hidden), cache
+
+    def generate(self, input_ids, max_new_tokens: int = 32, **kw):
+        from .generation import greedy_generate
+        return greedy_generate(self, input_ids, max_new_tokens, **kw)
+
+    # -- what the serving engine asks a model -------------------------------
+
+    @property
+    def expert_layers(self) -> int:
+        """Expert layers: the step programs hand ``decode_step`` the real
+        tokens (``valid=``) and return the layers' load beside the sampled
+        tokens (``distributed.moe.expert_load``)."""
+        return self.config.num_expert_layers
+
+    @property
+    def attention_windows(self) -> Tuple[Optional[int], ...]:
+        """Per layer, the sliding window its attention reads, or None."""
+        return tuple(block.self_attn.window for block in self.model.layers)
+
+    def serving_kernel_specs(self, token_rows):
+        """Pre-flight specs of the kernels only this model's step programs
+        build: the held experts' grouped products (in and out projection),
+        per program part of ``token_rows`` tokens."""
+        from ..distributed.moe import grouped_kernel_takes
+        from ..static_analysis import moe_experts_spec
+        c = self.config
+        lo, hi = c.experts_held
+        h, fm = c.hidden_size, c.moe_intermediate_size
+        return [moe_experts_spec(rows * c.num_experts_per_tok, hi - lo, k, n,
+                                 variant=f"tokens={rows},{k}x{n}")
+                for rows in token_rows
+                for k, n in sorted({(h, fm), (fm, h)})
+                if grouped_kernel_takes(k, n)]
+
+    def check_serving_layout(self, *, paged, kv_cache_dtype, mesh,
+                             spec_decode, int8_weights):
+        """Refuse, by name, the engine layouts this model cannot run."""
+        def no(what, why):
+            raise NotImplementedError(
+                f"AfmoeForCausalLM cannot be served with {what}: {why}")
+        if not paged:
+            no("the contiguous cache (paged=False)",
+               "its decode takes per-row positions over the paged pool only")
+        if kv_cache_dtype != "bf16":
+            no(f"kv_cache_dtype={kv_cache_dtype!r}",
+               "the windowed attention path has no int8 pool")
+        if mesh is not None:
+            no("a mesh", "the held-experts layer has no exchange and the "
+               "grouped product no sharded form")
+        if spec_decode:
+            no("speculative decoding",
+               "the model drafter keeps a contiguous cache and "
+               "draft_model_from truncates a llama")
+        if int8_weights:
+            no("int8_weights", "quantize_for_decode knows no stacked "
+               "expert weights")

@@ -195,6 +195,43 @@ def _quantized_contiguous_write(kv, sc, idx: int, kvsl: int, x,
     return kv, sc
 
 
+def paged_kv_write(cache, idx: int, k, v, position_ids, block_tables):
+    """Write a chunk's K and V, (B, s, Hkv, D) at the logical
+    ``position_ids`` (B, s), into layer ``idx`` of the paged pool through
+    the rows' ``block_tables`` (B, max_blocks): (physical block, offset)
+    scatters of whole ``Hkv·D`` rows; positions past a table's coverage —
+    prompt padding — are steered to the null block (id 0).  ``cache`` is
+    the pool array or the int8 pool's ``{"kv", "scale"}``; returns
+    ``(cache, pool payload, scales or None)``.  Shared by every attention
+    layer that keeps plain K and V rows in the pool."""
+    quantized = isinstance(cache, dict)
+    kvp = cache["kv"] if quantized else cache
+    b, s = position_ids.shape
+    bl = kvp.shape[3]
+    max_blocks = block_tables.shape[1]
+    rows = jnp.arange(b)[:, None]                                  # (B, 1)
+    lb = position_ids // bl                                        # (B, s)
+    phys = jnp.where(
+        lb < max_blocks,
+        block_tables[rows, jnp.minimum(lb, max_blocks - 1)],
+        jnp.int32(0))                      # out-of-table pads -> null block
+    off = position_ids % bl
+    sc = None
+    if quantized:
+        kvp, sc = _quantized_paged_write(kvp, cache["scale"], idx, 0, k,
+                                         phys, off)
+        kvp, sc = _quantized_paged_write(kvp, sc, idx, 1, v, phys, off)
+        sc = constrain(sc, None, None, None, "mp")
+    else:
+        with jax.named_scope("kv_write"):
+            kvp = kvp.at[idx, 0, phys, off].set(
+                k.astype(kvp.dtype).reshape(b, s, -1))
+            kvp = kvp.at[idx, 1, phys, off].set(
+                v.astype(kvp.dtype).reshape(b, s, -1))
+    kvp = constrain(kvp, None, None, None, None, "mp")
+    return ({"kv": kvp, "scale": sc} if quantized else kvp), kvp, sc
+
+
 class LlamaAttention(Layer):
     """GQA attention with RoPE and flash attention.
 
@@ -347,31 +384,9 @@ class LlamaAttention(Layer):
             rope_ids = position_ids
         q, k, v = self._qkv(x, rope_cache, rope_ids)
         if paged:
-            bl = kvp.shape[3]
-            max_blocks = block_tables.shape[1]
-            rows = jnp.arange(b)[:, None]                          # (B, 1)
-            lb = position_ids // bl                                # (B, s)
-            phys = jnp.where(
-                lb < max_blocks,
-                block_tables[rows, jnp.minimum(lb, max_blocks - 1)],
-                jnp.int32(0))              # out-of-table pads -> null block
-            off = position_ids % bl
             q = constrain(q, ("dp", "sharding"), None, "mp", None)
-            sc = None
-            if quantized:
-                kvp, sc = _quantized_paged_write(kvp, cache["scale"], idx,
-                                                 0, k, phys, off)
-                kvp, sc = _quantized_paged_write(kvp, sc, idx, 1, v,
-                                                 phys, off)
-                sc = constrain(sc, None, None, None, "mp")
-            else:
-                with jax.named_scope("kv_write"):
-                    kvp = kvp.at[idx, 0, phys, off].set(
-                        k.astype(kvp.dtype).reshape(b, s, -1))
-                    kvp = kvp.at[idx, 1, phys, off].set(
-                        v.astype(kvp.dtype).reshape(b, s, -1))
-            kvp = constrain(kvp, None, None, None, None, "mp")
-            cache = {"kv": kvp, "scale": sc} if quantized else kvp
+            cache, kvp, sc = paged_kv_write(cache, idx, k, v, position_ids,
+                                            block_tables)
             out = paged_decode_attention(q, kvp, idx, pos, block_tables,
                                          pool_scale=sc)
             return matmul(out.reshape(b, s, -1), self.o_proj), cache

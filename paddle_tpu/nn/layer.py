@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import threading
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -36,7 +37,29 @@ from ..framework import dtype as _dtype_mod
 from ..framework import random as _random
 
 __all__ = ["Parameter", "Layer", "Sequential", "LayerList",
-           "functional_call", "bind_params"]
+           "functional_call", "bind_params", "abstract_parameters"]
+
+_ABSTRACT = threading.local()
+
+
+@contextlib.contextmanager
+def abstract_parameters():
+    """Build layers WITHOUT initialising their parameters: inside,
+    :meth:`Layer.create_parameter` registers a ``jax.ShapeDtypeStruct`` of
+    the parameter's shape and dtype where it would run the initializer.
+    For a model that is built to be LOADED (``set_state_dict`` checks each
+    array against the placeholder's shape and replaces it): a model near
+    the device's memory cannot hold its random weights beside the ones it
+    is given, and its initializers, dispatched without waiting, hold their
+    float32 temporaries side by side (a 9.1 GB bf16 model's constructor
+    peaked at 16.5 GB of a 17.2 GB chip, PR 26).  Buffers are computed as
+    ever.  A placeholder that is never replaced fails at first use."""
+    prev = getattr(_ABSTRACT, "on", False)
+    _ABSTRACT.on = True
+    try:
+        yield
+    finally:
+        _ABSTRACT.on = prev
 
 
 class Parameter:
@@ -142,7 +165,10 @@ class Layer:
 
         dt = _dtype_mod.to_jax_dtype(dtype)
         init = initializer if initializer is not None else I.XavierNormal()
-        value = init(shape, dt, _random.site_key())
+        if getattr(_ABSTRACT, "on", False):
+            value = jax.ShapeDtypeStruct(tuple(shape), dt)
+        else:
+            value = init(shape, dt, _random.site_key())
         name = attr_name or f"param_{len(self._parameters)}"
         object.__setattr__(self, name, value)
         self._parameters[name] = Parameter(self, name, trainable=trainable,

@@ -336,7 +336,8 @@ def _mesh_axes():
 
 
 def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
-                                live_len=None, k_scale=None, v_scale=None):
+                                live_len=None, k_scale=None, v_scale=None,
+                                window=None):
     """The mesh fast path: re-enter :func:`cached_decode_attention`
     PER SHARD under ``shard_map`` — kv-heads split over ``mp`` (exactly
     how mp attention layers place them: contiguous head blocks, so the
@@ -365,7 +366,8 @@ def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
     def body(q_, k_, v_, pos_, ks_=None, vs_=None):
         return cached_decode_attention(q_, k_, v_, pos_, scale=scale,
                                        live_len=live_len,
-                                       k_scale=ks_, v_scale=vs_)
+                                       k_scale=ks_, v_scale=vs_,
+                                       window=window)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=q_spec, check_vma=False)
@@ -374,7 +376,7 @@ def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
 
 def _shard_map_paged_decode_attention(q, pool, layer, pos, block_tables,
                                       scale=None, live_len=None,
-                                      pool_scale=None):
+                                      pool_scale=None, window=None):
     """:func:`_shard_map_decode_attention` for the paged pool: the pool is
     head-sharded only — its fused ``Hkv·D`` axis splits over ``mp`` as
     whole heads (head-major, contiguous), every shard holding all blocks
@@ -396,7 +398,7 @@ def _shard_map_paged_decode_attention(q, pool, layer, pos, block_tables,
     def body(q_, pool_, pos_, bt_, sc_=None):
         return paged_decode_attention(q_, pool_, layer, pos_, bt_,
                                       scale=scale, live_len=live_len,
-                                      pool_scale=sc_)
+                                      pool_scale=sc_, window=window)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=q_spec, check_vma=False)
@@ -430,7 +432,8 @@ def _run_decode_path(path, reason, shard_map_fn, pallas_fn, reference_fn):
 def cached_decode_attention(q, k_cache, v_cache, pos,
                             scale: Optional[float] = None,
                             extra_mask=None, live_len: Optional[int] = None,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None,
+                            window: Optional[int] = None):
     """Incremental decode attention over a pre-allocated CONTIGUOUS cache
     — the serving hot path (parity: the reference's
     masked_multihead_attention / fused decode-attention core, upstream
@@ -459,34 +462,41 @@ def cached_decode_attention(q, k_cache, v_cache, pos,
     per-granule-per-kv-head dequant scales for an int8 cache — the Pallas
     kernel dequantizes inside its KV-chunk loop; the XLA fallback
     dequantizes first.
+
+    ``window`` (static int): sliding-window attention — the query at
+    position ``i`` sees key ``j`` only while ``i - j < window``.  The
+    kernel starts its block walk at the window's first block; the XLA path
+    masks.  ``None`` is full causal attention, the programs as they were.
     """
     b, s, hq, d = q.shape
     _, kv_len, hkv, _ = k_cache.shape
     path, reason = decode_attention_path(b, s, hq, hkv, d, kv_len,
                                          extra_mask is not None,
                                          quantized=k_scale is not None)
+    win = {} if window is None else {"window": int(window)}
 
     def pallas():
         from .pallas.decode_attention import decode_attention_pallas
         return decode_attention_pallas(
             q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
             k_scale=k_scale, v_scale=v_scale,
-            interpret=_dispatch.pallas_interpret())
+            interpret=_dispatch.pallas_interpret(), **win)
 
     return _run_decode_path(
         path, reason,
         lambda: _shard_map_decode_attention(
             q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
-            k_scale=k_scale, v_scale=v_scale),
+            k_scale=k_scale, v_scale=v_scale, **win),
         pallas,
         lambda: cached_decode_attention_reference(
             q, k_cache, v_cache, pos, scale=scale, extra_mask=extra_mask,
-            live_len=live_len, k_scale=k_scale, v_scale=v_scale))
+            live_len=live_len, k_scale=k_scale, v_scale=v_scale, **win))
 
 
 def paged_decode_attention(q, pool, layer: int, pos, block_tables,
                            scale: Optional[float] = None, extra_mask=None,
-                           live_len: Optional[int] = None, pool_scale=None):
+                           live_len: Optional[int] = None, pool_scale=None,
+                           window: Optional[int] = None):
     """:func:`cached_decode_attention` of layer ``layer`` over the PAGED
     pool (serving/kv_cache.py): ``pool`` is the whole
     ``(L, 2, num_blocks, block_len, Hkv·D)`` array of every layer's K and
@@ -501,10 +511,11 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
     into the contiguous layout first.  ``pos`` is the int (B,) vector of
     per-row positions.  ``pool_scale``: the int8 pool's f32
     ``(L, 2, num_blocks, Hkv)`` per-block-per-kv-head dequant scales.
-    Dispatch, ``live_len``, ``extra_mask`` and the result are
+    Dispatch, ``live_len``, ``extra_mask``, ``window`` and the result are
     :func:`cached_decode_attention`'s."""
     b, s, hq, d = q.shape
     block_len, hd = pool.shape[-2:]
+    win = {} if window is None else {"window": int(window)}
     path, reason = decode_attention_path(
         b, s, hq, hd // d, d, block_tables.shape[1] * block_len,
         extra_mask is not None, paged_block_len=block_len,
@@ -515,18 +526,18 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
         return paged_decode_attention_pallas(
             q, pool, layer, pos, block_tables, scale=scale,
             live_len=live_len, pool_scale=pool_scale,
-            interpret=_dispatch.pallas_interpret())
+            interpret=_dispatch.pallas_interpret(), **win)
 
     return _run_decode_path(
         path, reason,
         lambda: _shard_map_paged_decode_attention(
             q, pool, layer, pos, block_tables, scale=scale,
-            live_len=live_len, pool_scale=pool_scale),
+            live_len=live_len, pool_scale=pool_scale, **win),
         pallas,
         lambda: paged_decode_attention_reference(
             q, pool, layer, pos, block_tables, scale=scale,
             extra_mask=extra_mask, live_len=live_len,
-            pool_scale=pool_scale))
+            pool_scale=pool_scale, **win))
 
 
 @jax.jit
@@ -554,7 +565,8 @@ def paged_decode_attention_reference(q, pool, layer: int, pos, block_tables,
                                      scale: Optional[float] = None,
                                      extra_mask=None,
                                      live_len: Optional[int] = None,
-                                     pool_scale=None):
+                                     pool_scale=None,
+                                     window: Optional[int] = None):
     """The XLA math path of :func:`paged_decode_attention` (and its
     numerical oracle): one gather takes each row's physical blocks out of
     ``pool[layer, 0 | 1]`` into the contiguous ``(B, max_blocks·block_len,
@@ -584,14 +596,15 @@ def paged_decode_attention_reference(q, pool, layer: int, pos, block_tables,
     return cached_decode_attention_reference(
         q, k_cache.reshape(b, mb * bl, hkv, d),
         v_cache.reshape(b, mb * bl, hkv, d), pos, scale=scale,
-        extra_mask=extra_mask, live_len=live_len)
+        extra_mask=extra_mask, live_len=live_len, window=window)
 
 
 def cached_decode_attention_reference(q, k_cache, v_cache, pos,
                                       scale: Optional[float] = None,
                                       extra_mask=None,
                                       live_len: Optional[int] = None,
-                                      k_scale=None, v_scale=None):
+                                      k_scale=None, v_scale=None,
+                                      window: Optional[int] = None):
     """The XLA math path of :func:`cached_decode_attention` (and its
     numerical oracle): masked softmax over the whole cache read.
 
@@ -643,10 +656,15 @@ def cached_decode_attention_reference(q, k_cache, v_cache, pos,
     if getattr(pos, "ndim", 0) == 1:                  # per-row positions
         qi = pos[:, None] + jnp.arange(s)[None, :]    # (B, s)
         keep = (kj[None, None] <= qi[:, :, None])     # (B, s, L)
+        if window is not None:
+            keep &= kj[None, None] > qi[:, :, None] - window
         keep = keep[:, None, None]                    # (B,1,1,s,L)
     else:
         qi = pos + jnp.arange(s)[:, None]             # (s, 1)
-        keep = (kj[None] <= qi)[None, None, None]     # (1,1,1,s,L)
+        keep = kj[None] <= qi                         # (s, L)
+        if window is not None:
+            keep &= kj[None] > qi - window
+        keep = keep[None, None, None]                 # (1,1,1,s,L)
     if extra_mask is not None:
         # bool; (B, L) key-padding form, or rank-3 broadcastable to
         # (B, s, L) — lifted into the (B, Hkv, G, s, L) layout

@@ -125,7 +125,7 @@ def _pick_block_kv(kv_len: int, cap: int) -> int:
 
 
 def _kernel(pos_ref, bt_ref, *refs, scale, s, g, hkv, d, bq, tile_p, bk,
-            chunks, n_cols, quantized):
+            chunks, n_cols, quantized, window=None):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
         # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
@@ -150,7 +150,13 @@ def _kernel(pos_ref, bt_ref, *refs, scale, s, g, hkv, d, bq, tile_p, bk,
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    @pl.when(ki <= last_live)
+    live = ki <= last_live
+    if window is not None:
+        # first chunk holding a key inside the window of ANY row of this
+        # q tile (its earliest query, offset qi·bq, sees back furthest)
+        live &= ki >= jnp.maximum(pos_b + qi * bq - window + 1, 0) // bk
+
+    @pl.when(live)
     def _compute():
         # key j visible to tile row r = si·g + gi (si local to the tile)
         # iff j <= pos_b + qi·bq + si; rows past bq·g are sublane padding
@@ -160,6 +166,8 @@ def _kernel(pos_ref, bt_ref, *refs, scale, s, g, hkv, d, bq, tile_p, bk,
         rr = jax.lax.broadcasted_iota(jnp.int32, (tile_p, bk), 0)
         si = qi * bq + rr // g
         keep = (cols <= pos_b + si) & (rr < bq * g) & (si < s)
+        if window is not None:
+            keep &= cols > pos_b + si - window
         kv = k_ref[0]  # (bk, hkv·d) — one contiguous chunk, all kv heads
         vv = v_ref[0]
         for h in range(hkv):
@@ -212,7 +220,8 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
                             block_kv: int = 0,
                             live_len: Optional[int] = None,
                             interpret: bool = False,
-                            k_scale=None, v_scale=None):
+                            k_scale=None, v_scale=None,
+                            window: Optional[int] = None):
     """Flash-decode over a pre-allocated CONTIGUOUS cache → (B, s, Hq, D)
     in q.dtype (the paged pool has its own entry,
     :func:`paged_decode_attention_pallas`).
@@ -274,14 +283,15 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
     return _flash_decode(
         q, k2, v2, (1, bk, hkv * d), at, at, pos, bt, scale=scale,
         live_len=live_len, interpret=interpret, layout="contiguous",
-        scales=(k_scale, v_scale) if quantized else None)
+        scales=(k_scale, v_scale) if quantized else None, window=window)
 
 
 def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
                                   scale: Optional[float] = None,
                                   live_len: Optional[int] = None,
                                   interpret: bool = False,
-                                  pool_scale=None):
+                                  pool_scale=None,
+                                  window: Optional[int] = None):
     """Flash-decode of layer ``layer`` over the PAGED pool
     (serving/kv_cache.py ``init_paged_kv_cache``) → (B, s, Hq, D).
 
@@ -305,6 +315,13 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
     gathered through its block table here, so the kernel's SMEM tables
     scale with the batch geometry (like the block table itself), not
     with the pool.
+
+    ``window`` (static): sliding-window attention — key ``j`` is visible
+    to the query at position ``i`` only while ``i - j < window``.  The
+    mask gains that lower bound, and the block walk a lower clamp beside
+    the dead-tail one: blocks wholly behind the window of every query of a
+    q tile are neither DMA'd nor scored.  ``None`` builds the kernel as it
+    was.
     """
     d = q.shape[-1]
     bk, hd = pool.shape[-2:]
@@ -323,7 +340,7 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
         lambda blk: (layer, 0, blk, 0, 0),
         lambda blk: (layer, 1, blk, 0, 0), pos, bt, scale=scale,
         live_len=live_len, interpret=interpret, layout="paged",
-        scales=scales)
+        scales=scales, window=window)
 
 
 def _check_q(q, hkv: int) -> None:
@@ -345,13 +362,14 @@ def _check_q(q, hkv: int) -> None:
 
 
 def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
-                  live_len, interpret, layout, scales):
+                  live_len, interpret, layout, scales, window=None):
     """The one ``pallas_call`` behind both layouts.  ``k_arr``/``v_arr``
     are the operands as they lie in HBM, ``kv_block`` the BlockSpec shape
     that cuts one ``(1, bk, Hkv·D)`` chunk out of them, and
     ``k_at``/``v_at`` map a physical block id to that chunk's block
     index; ``bt`` is the (B, chunks) table of block ids and ``scales``
-    the int8 cache's (B, chunks, Hkv) K and V scale tables, or None."""
+    the int8 cache's (B, chunks, Hkv) K and V scale tables, or None;
+    ``window`` the static sliding window, or None."""
     b, s, hq, d = q.shape
     bk, hd = kv_block[-2:]
     hkv = hd // d
@@ -410,7 +428,8 @@ def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
     kernel = functools.partial(
         _kernel, scale=float(scale), s=s, g=g, hkv=hkv, d=d, bq=bq,
         tile_p=tile_p, bk=bk, chunks=chunks, n_cols=n_cols,
-        quantized=quantized)
+        quantized=quantized,
+        **({} if window is None else {"window": int(window)}))
 
     def q_idx(bi, qi, ki, pos_ref, bt_ref, *_):
         return (bi, 0, qi, 0)
@@ -427,7 +446,13 @@ def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
         # column (<= last) mapping to block 0 would alias the null
         # block's pad data into this row's attention window.
         last = (pos_ref[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        return bt_ref[bi, jnp.minimum(ki, last)]
+        if window is None:
+            return bt_ref[bi, jnp.minimum(ki, last)]
+        # the same trick from below: grid steps before the tile's first
+        # block inside the window re-map to that block, which is then
+        # fetched once and, until the walk reaches it, not scored
+        first = jnp.maximum(pos_ref[bi] + qi * bq - window + 1, 0) // bk
+        return bt_ref[bi, jnp.clip(ki, first, last)]
 
     def k_idx(bi, qi, ki, pos_ref, bt_ref, *_):
         return k_at(live_block(bi, qi, ki, pos_ref, bt_ref))

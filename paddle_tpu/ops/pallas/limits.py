@@ -32,7 +32,12 @@ The values themselves are TPU architecture facts, not tunables:
   * ``MAX_SCALE_TABLE`` — entries (rows · chunks · kv-heads) in ONE of
     the int8 decode kernel's two f32 scale tables.  They ride in SMEM
     as scalar prefetch beside the block table; Mosaic for a v5e took
-    65,536-entry tables and refused 131,072 (AOT compile, PR 21).
+    65,536-entry tables and refused 131,072 (AOT compile, PR 21);
+  * ``GMM_TILE_VALUES`` / ``GMM_VMEM_LIMIT`` — the grouped matmul's
+    (``grouped_matmul.py``) weight tile, in values, and the scoped VMEM
+    it asks Mosaic for: the tile is the unit of the weights' HBM stream
+    and Pallas holds two of them, so the limit is twice the tile in bf16
+    plus the row tiles and the f32 accumulator, with room.
 """
 
 from __future__ import annotations
@@ -43,6 +48,8 @@ MAX_Q_LEN = 2048     # q longer than any prefill chunk => flash kernel
 MAX_HEAD_DIM = 256   # decode-attention head_dim ceiling (2 lane tiles)
 MAX_GEMM_ROWS = 256  # int8_matmul row ceiling (decode-shaped GEMMs)
 MAX_SCALE_TABLE = 65536  # int8 decode: per-table SMEM scale entries
+GMM_TILE_VALUES = 3 * 1024 * 1024   # grouped matmul: values of a weight tile
+GMM_VMEM_LIMIT = 32 * 1024 * 1024   # grouped matmul: scoped VMEM, bytes
 
 # second-minor register-tile height by dtype name (jnp dtype .name)
 SUBLANES = {

@@ -153,6 +153,7 @@ import numpy as np
 
 from .. import flags as _flags
 from .. import observability as _obs
+from ..distributed import moe as _moe
 from ..models.generation import (_place_on_mesh, accept_draft_tokens,
                                  decode_mesh_specs, init_kv_cache,
                                  sample_tokens)
@@ -500,10 +501,22 @@ class ServingEngine:
                 "(or FLAGS_serving_host_blocks) >= 1")
         self._host_blocks = hb if self.paged else 0
         self.mesh = self._resolve_mesh(mesh)
-        self._init_metrics()
-
         # quantized-decode hooks, exactly as models/generation.py binds
         self._bind = getattr(model, "unwrapped", model)
+        # a model says itself which of the engine's layouts it cannot run,
+        # whether its step programs return a routed-expert load beside the
+        # tokens, and which of its layers read a sliding window only
+        check_layout = getattr(self._bind, "check_serving_layout", None)
+        if check_layout is not None:
+            check_layout(paged=self.paged, kv_cache_dtype=self.kv_dtype,
+                         mesh=self.mesh, spec_decode=self.spec,
+                         int8_weights=self._int8_weights)
+        self._expert_layers = int(getattr(self._bind, "expert_layers", 0))
+        self._windows = tuple(
+            int(w) for w in getattr(self._bind, "attention_windows", ())
+            if w is not None)
+        self._init_metrics()
+
         self._prepare = getattr(model, "_prepare_params", lambda p: p)
         params = model.state_dict(include_buffers=True)
         if self.paged:
@@ -1186,6 +1199,31 @@ class ServingEngine:
         self._f_resumed = ctr(
             "serving.resumes",
             "preempted requests restored to a slot, by mode")
+        # what only some models feed (``_note_model_counters``), and only
+        # their engines register: routed experts' load (the per-expert
+        # children of ``moe.expert_load`` are made at the first load
+        # vector) and window layers' dead positions
+        self._expert_pairs: Optional[np.ndarray] = None
+        self._expert_totals = np.zeros(3, np.int64)
+        self._window_dead = 0
+        self._model_counters = bool(self._expert_layers or self._windows)
+        if self._expert_layers:
+            self._m_pairs_elsewhere = ctr(
+                "moe.pairs_elsewhere",
+                "(token, expert) pairs routed to experts another expert-"
+                "parallel rank holds: what the exchange would carry"
+                ).labels(**lbl)
+            self._m_experts_touched = hist(
+                "moe.experts_touched",
+                "held experts with at least one routed pair, per expert-"
+                "layer call: the expert weights the grouped product had "
+                "to read").labels(**lbl)
+        if self._windows:
+            self._m_window_dead = gauge(
+                "kv_cache.window_dead_positions",
+                "live (position, window layer) pairs behind their layer's "
+                "sliding window at the last tick: what a window-aware "
+                "allocator would free").labels(**lbl)
         self._m_swap_out_bytes = ctr(
             "serving.swap_out_bytes",
             "HBM→host bytes moved by swap-outs and trie demotions "
@@ -1273,19 +1311,34 @@ class ServingEngine:
             cache, sub)
         return tok, cache
 
+    def _experts(self, valid):
+        """For a model with expert layers: the trace-time collector of
+        their load (``distributed.moe.expert_load``) and the ``valid=`` its
+        ``decode_step`` takes (``valid`` builds the mask of the real
+        tokens).  For any other model an empty collector and no argument,
+        so that its step programs are traced as they ever were."""
+        if not self._expert_layers:
+            return contextlib.nullcontext(()), {}
+        return _moe.expert_load(), {"valid": valid()}
+
     def _step_impl_paged(self, params, cache, tokens, positions, tables,
                          slot_mask, temps, topk, topp, key):
         """Paged twin of ``_step_impl``: identical but the block table
         rides along as a traced input, so allocation changes (slots
         deepening into fresh blocks, prefix adoptions, evictions) reach
         the device as data.  Compiled exactly once."""
+        collect, real = self._experts(lambda: slot_mask[:, None])
         with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, self._prepare(params)):
+                bind_params(self._bind, self._prepare(params)), \
+                collect as load:
             logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions, block_tables=tables)
+                tokens[:, None], cache, positions, block_tables=tables,
+                **real)
         with jax.named_scope("sample"):
             nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
             nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        if load:        # a model with routed experts: their load rides out
+            return nxt, jnp.stack(load)[None], cache
         return nxt, cache
 
     def _prefill_impl_paged(self, params, cache, ids, prefix_lens,
@@ -1367,21 +1420,28 @@ class ServingEngine:
         and a chunk-free tick passes the all-null table itself.  No
         row slicing — the pool IS the cache for both parts."""
         prep = self._prepare(params)
+        collect, real = self._experts(lambda: slot_mask[:, None])
         with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, prep):
+                bind_params(self._bind, prep), collect as load:
             logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions, block_tables=tables)
+                tokens[:, None], cache, positions, block_tables=tables,
+                **real)
         with jax.named_scope("sample"):
             nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
             nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
+        collect, real = self._experts(
+            lambda: (jnp.arange(cids.shape[1]) < clen)[None])
         with _disp.program_part(_STEP, "prompt_chunk"), \
-                bind_params(self._bind, prep):
+                bind_params(self._bind, prep), collect as cload:
             clogits, cache = self.model.decode_step(
-                cids, cache, cpos[None], block_tables=ctable)
+                cids, cache, cpos[None], block_tables=ctable, **real)
         with jax.named_scope("sample_chunk"):
             ctok = sample_tokens(clogits[0, clen - 1][None],
                                  jax.random.fold_in(key, 1),
                                  ctemp, ctopk, ctopp)[0]
+        if load:        # part 0: the decode rows' load, part 1: the chunk's
+            return (nxt, ctok, jnp.stack([jnp.stack(load),
+                                          jnp.stack(cload)]), cache)
         return nxt, ctok, cache
 
     # -- jitted device programs: speculative decoding ----------------------
@@ -2192,14 +2252,18 @@ class ServingEngine:
                         jnp.asarray(self._topk), jnp.asarray(self._topp),
                         jax.random.fold_in(self._base_key, self._ticks))
             with span(_DISPATCH):
-                nxt, self._cache = self._step_fn(
+                nxt, *load, self._cache = self._step_fn(
                     self._params, self._cache, *args)
             with span(_READBACK):
+                if load:                 # routed experts: one transfer
+                    nxt, *load = jax.device_get((nxt, *load))
                 nxt = np.asarray(nxt)    # the tick's one host sync
         now = self._clock()
         with span(_ADVANCE):
             self._m_step_ms.observe((now - t0) * 1e3)
             self._perf_tick((now - t0) * 1e3, occ)
+            if self._model_counters:
+                self._note_model_counters(load)
             finished.extend(self._advance_decode(nxt, now))
         return finished
 
@@ -2531,13 +2595,17 @@ class ServingEngine:
                     out, n_acc, ctok, self._cache = res
                     out, n_acc, ctok = jax.device_get((out, n_acc, ctok))
                 else:
-                    nxt, ctok, self._cache = res
-                    nxt, ctok = jax.device_get((nxt, ctok))
+                    nxt, ctok, *load, self._cache = res
+                    nxt, ctok, *load = jax.device_get((nxt, ctok, *load))
+                    # a chunk-free tick ran the chunk's rows on padding
+                    load = [x[:1 + int(do_chunk)] for x in load]
         now = self._clock()
         with span(_ADVANCE):
             self._m_step_ms.observe((now - t0) * 1e3)
             self._perf_tick((now - t0) * 1e3, occ,
                             chunk_tokens=clen if do_chunk else 0)
+            if self._model_counters and not self.spec:
+                self._note_model_counters(load)
             if self.spec:
                 finished.extend(self._advance_decode_spec(
                     np.asarray(out), np.asarray(n_acc), draft_ok, now))
@@ -2864,6 +2932,14 @@ class ServingEngine:
                     num_blocks=self.num_slots * mb_p + 1,
                     num_layers=int(c.num_hidden_layers),
                     quantized=quantized, variant=tag))
+                # a window layer's call of the same kernel: the block
+                # walk clamped from below too
+                specs.extend(_sa.decode_attention_spec(
+                    b, s, hq, hkv, d_p, block_len=bl_p, max_blocks=mb_p,
+                    num_blocks=self.num_slots * mb_p + 1,
+                    num_layers=int(c.num_hidden_layers), window=w,
+                    variant=f"{tag},window={w}")
+                    for w in sorted(set(self._windows)))
             else:
                 kv_p = max(min_len,
                            -(-self.max_length // lanes) * lanes)
@@ -2874,6 +2950,9 @@ class ServingEngine:
                     # 128-token granule (kv_p is lane-aligned above)
                     n_granules=kv_p // lanes if quantized else None,
                     variant=tag))
+        extra = getattr(self._bind, "serving_kernel_specs", None)
+        if extra is not None:       # kernels only this model's steps build
+            specs.extend(extra([b * s for b, s, _ in shapes]))
         return specs
 
     def kernel_preflight(self, rules=None) -> Dict[str, object]:
@@ -3117,6 +3196,66 @@ class ServingEngine:
     @property
     def prefill_tokens_total(self) -> int:
         return int(self._m_prefill_total.value())
+
+    def _note_model_counters(self, load) -> None:
+        """Per tick, what only some models have: the routed-expert load the
+        step program returned beside the tokens (``load``: nothing, or one
+        int array (parts, expert layers, held + 1) — per program part and
+        expert layer the (token, expert) pairs routed to each held expert,
+        then the pairs routed to experts held elsewhere), and the live
+        positions of window layers that lie behind their window."""
+        if self._windows:
+            pos = self._positions[self._active].astype(np.int64)
+            dead = sum(int(np.maximum(pos + 1 - w, 0).sum())
+                       for w in self._windows)
+            self._window_dead = dead
+            self._m_window_dead.set(float(dead))
+        if not load:
+            return
+        load = load[0].astype(np.int64)
+        held = load[..., :-1]
+        by_layer = held.sum(axis=0)                 # (layers, held)
+        if self._expert_pairs is None:
+            fam = _obs.default_registry().counter(
+                "moe.expert_load",
+                "(token, expert) pairs routed to a held expert, by expert "
+                "layer and held expert, over every step program run")
+            self._m_expert_load = [
+                [fam.labels(engine=self._eid, layer=str(li), expert=str(e))
+                 for e in range(by_layer.shape[1])]
+                for li in range(by_layer.shape[0])]
+            self._expert_pairs = np.zeros(by_layer.shape, np.int64)
+        self._expert_pairs += by_layer
+        for li, e in zip(*np.nonzero(by_layer)):
+            self._m_expert_load[li][e].inc(int(by_layer[li, e]))
+        elsewhere = int(load[..., -1].sum())
+        touched = (held > 0).sum(axis=-1)           # (parts, layers)
+        self._expert_totals += (elsewhere, int(touched.sum()), touched.size)
+        self._m_pairs_elsewhere.inc(elsewhere)
+        for n in touched.reshape(-1):
+            self._m_experts_touched.observe(float(n))
+
+    @property
+    def expert_load(self) -> Optional[Dict[str, object]]:
+        """The routed-expert counters' totals since construction —
+        ``pairs`` (int (expert layers, held): pairs routed to each held
+        expert), ``pairs_elsewhere``, ``experts_touched`` (held experts
+        with at least one pair, summed over expert-layer calls: the expert
+        weights the grouped product had to read) and ``layer_calls`` — or
+        None while no step of a model with routed experts has run."""
+        if self._expert_pairs is None:
+            return None
+        elsewhere, touched, calls = (int(x) for x in self._expert_totals)
+        return {"pairs": self._expert_pairs.copy(),
+                "pairs_elsewhere": elsewhere, "experts_touched": touched,
+                "layer_calls": calls}
+
+    @property
+    def window_dead_positions(self) -> int:
+        """Live (position, window layer) pairs that lay behind their
+        layer's window at the last tick: what a window-aware allocator
+        would have freed.  0 for a model without window layers."""
+        return self._window_dead
 
     def metrics(self) -> Dict[str, object]:
         """This engine's serving-SLO metrics read from the shared

@@ -99,6 +99,12 @@ def iv_min(a, b) -> Iv:
     return Iv(min(a.lo, b.lo), min(a.hi, b.hi))
 
 
+def iv_max(a, b) -> Iv:
+    """max is monotone in both args, like min."""
+    a, b = iv(a), iv(b)
+    return Iv(max(a.lo, b.lo), max(a.hi, b.hi))
+
+
 # ---------------------------------------------------------------------------
 # spec dataclasses
 # ---------------------------------------------------------------------------
@@ -318,7 +324,8 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
                           n_granules: Optional[int] = None,
                           q_dtype: str = "bfloat16",
                           variant: Optional[str] = None,
-                          num_layers: int = 1) -> KernelSpec:
+                          num_layers: int = 1,
+                          window: Optional[int] = None) -> KernelSpec:
     """KernelSpec for one ``decode_attention_pallas`` /
     ``paged_decode_attention_pallas`` call.
 
@@ -336,6 +343,9 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     in ``dims`` for the rules to flag (the kernel would raise at call
     time; the pre-flight's job is to say so beforehand) — only shapes
     with no expressible geometry raise :class:`KernelSpecError`.
+    ``window`` is the sliding window of a window layer's call: the block
+    walk gains the lower clamp at the window's first block, and the
+    streamed-bytes model counts the blocks inside the window only.
 
     Mesh-sharded callers (the shard_map fast path) must pass PER-SHARD
     geometry — ``hq/mp`` and ``hkv/mp`` heads — and tag ``variant``
@@ -416,6 +426,13 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         # row position p — the kernel's `last_live`, clamped to the grid
         return min(chunks - 1, (p + min((q + 1) * bq, s) - 1) // bk)
 
+    def expected_first(p: int, q: int) -> int:
+        # first chunk holding a key inside the window of the tile's
+        # earliest query (none behind it is fetched or scored)
+        if window is None:
+            return 0
+        return max(p + q * bq - int(window) + 1, 0) // bk
+
     def q_idx(grid_ivs, sc):
         bi, qi, ki = grid_ivs
         return (bi, Iv.const(0), qi, Iv.const(0))
@@ -425,6 +442,9 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         pos = sc.lookup("pos", bi)
         last = (pos + iv_min((qi + 1) * bq, Iv.const(s)) - 1) // bk
         col = iv_min(ki, last)
+        if window is not None:
+            first = iv_max(pos + qi * bq - (int(window) - 1), 0) // bk
+            col = iv_min(iv_max(ki, first), last)
         return sc.lookup("bt", bi, col)
 
     if paged:
@@ -450,7 +470,8 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     # streamed-bytes model: per (bi, qi) the clamp's DMA elision fetches
     # only the tile's live prefix; the worst case (pos at its declared
     # max) is the committed per-step bound
-    kv_fetches = b * sum(expected_last(pos_hi, q) + 1 for q in range(nq))
+    kv_fetches = b * sum(expected_last(pos_hi, q)
+                         - expected_first(pos_hi, q) + 1 for q in range(nq))
     q_fetches = b * nq
 
     q_block = (1, hkv, tile_p, d)
@@ -474,7 +495,7 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     dims.update({
         "b": b, "s": s, "g": g, "hkv": hkv, "d": d, "bq": bq, "nq": nq,
         "tile_p": tile_p, "bk": bk, "chunks": chunks, "kv_len": kv_len,
-        "paged": paged, "quantized": quantized,
+        "paged": paged, "quantized": quantized, "window": window,
         "lane_slice": (d, hkv), "lanes_128": tuple(lanes_128),
     })
     spec = KernelSpec(
@@ -590,6 +611,60 @@ def int8_matmul_spec(rows: int, k: int, n: int, *,
 
 
 # ---------------------------------------------------------------------------
+# grouped_matmul_pallas (ops/pallas/grouped_matmul.py)
+# ---------------------------------------------------------------------------
+
+def moe_experts_spec(rows: int, experts: int, k: int, n: int, *,
+                     dtype: str = "bfloat16",
+                     variant: Optional[str] = None) -> KernelSpec:
+    """KernelSpec for one grouped matrix product of the held experts:
+    ``rows`` (token, expert) pairs sorted by expert against ``experts``
+    stacked ``(k, n)`` matrices.  The grid's middle dimension walks the
+    (row tile, group) pairs that hold a row — at most ``row tiles +
+    experts - 1`` of them — through two scalar-prefetch tables, so the
+    weight operand's worst case is every expert read once and one more
+    read per row-tile boundary; the tiling is the kernel's own."""
+    from ..ops.pallas.grouped_matmul import TILE_ROWS, pick_tiles
+    tm = TILE_ROWS
+    tk, tn = pick_tiles(k, n)
+    rows_p = -(-rows // tm) * tm
+    tiles_m = rows_p // tm
+    steps = tiles_m + experts - 1
+    scalars = (ScalarOperand("group_ids", (steps,), 0, experts - 1),
+               ScalarOperand("m_tile_ids", (steps,), 0, tiles_m - 1))
+
+    def x_idx(grid_ivs, sc):
+        ni, gi, ki = grid_ivs
+        return (sc.lookup("m_tile_ids", gi), ki)
+
+    def w_idx(grid_ivs, sc):
+        ni, gi, ki = grid_ivs
+        return (sc.lookup("group_ids", gi), ki, ni)
+
+    def o_idx(grid_ivs, sc):
+        ni, gi, ki = grid_ivs
+        return (sc.lookup("m_tile_ids", gi), ni)
+
+    tiles = (k // tk) * (n // tn)
+    operands = (
+        BlockOperand("x", (tm, tk), (rows_p, k), dtype, x_idx,
+                     fetches=steps * tiles),
+        BlockOperand("w", (1, tk, tn), (experts, k, n), dtype, w_idx,
+                     fetches=steps * tiles),
+        BlockOperand("out", (tm, tn), (rows_p, n), dtype, o_idx,
+                     fetches=steps * (n // tn)),
+    )
+    dims = {"rows": rows, "rows_p": rows_p, "experts": experts, "k": k,
+            "n": n, "tm": tm, "tk": tk, "tn": tn,
+            "lanes_128": (("K", k), ("N", n), ("tk", tk), ("tn", tn))}
+    return KernelSpec(
+        op="moe_experts", grid=(n // tn, steps, k // tk),
+        variant=variant or f"rows={rows},experts={experts},k={k},n={n}",
+        operands=operands, scratch=(((tm, tn), "float32"),),
+        scalars=scalars, dims=dims)
+
+
+# ---------------------------------------------------------------------------
 # rms_norm (ops/pallas/rms_norm.py)
 # ---------------------------------------------------------------------------
 
@@ -653,6 +728,16 @@ def registered_kernel_specs() -> List[KernelSpec]:
         decode_attention_spec(8, 5, 32, 8, 128, block_len=128,
                               max_blocks=64, quantized=True,
                               variant="paged+int8,spec_verify"),
+        # a window layer's calls: the block walk clamped from below too
+        decode_attention_spec(8, 1, 48, 8, 128, block_len=128,
+                              max_blocks=64, num_layers=5, window=4096,
+                              variant="paged,decode,window"),
+        decode_attention_spec(1, 256, 48, 8, 128, block_len=128,
+                              max_blocks=64, num_layers=5, window=4096,
+                              variant="paged,chunked_prefill,window"),
+        # the held experts' grouped product: decode rows and a chunk
+        moe_experts_spec(192 * 4, 32, 3072, 3072),
+        moe_experts_spec(256 * 4, 32, 3072, 3072),
         flash_attention_spec(1, 32, 8, 2048, 2048, 128),
         int8_matmul_spec(8, 4096, 4096),
         rms_norm_spec(256, 4096),

@@ -25,6 +25,10 @@ TINY_GEOMETRY = {
     "decode_paged_chunk256": dict(TINY, b=1, kv_len=512),
     "decode_paged_spec_window5": TINY,
     "decode_paged_int8_kv": TINY,
+    "decode_paged_window_s1": dict(TINY, window=100),
+    "decode_paged_window_chunk256": dict(TINY, b=1, kv_len=512, window=200),
+    "moe_experts_gmm": dict(rows=96, experts=6, k=128, n=256,
+                            dtype="float32", interpret=True),
     "int8_matmul": dict(rows=8, k=256, n=256, dtype="float32",
                         interpret=True),
     "flash_fwd_bwd": dict(b=1, s=256, hq=4, hkv=2, d=64, dtype="float32",
@@ -84,6 +88,23 @@ def test_serve_legs_tiny(interpreted):
         chip_smoke.serve_leg(model, prompts[:1], 2, num_slots=2,
                              max_length=256,
                              expect_paths=("int8_matmul/pallas_int8",))
+
+
+def test_afmoe_serve_leg_tiny(interpreted):
+    """The smoke's second architecture: built to be loaded, served on the
+    paged, chunked engine past its window, the grouped product counted."""
+    from paddle_tpu.models import tiny_afmoe_config
+    cfg = tiny_afmoe_config(max_position_embeddings=256, head_dim=64,
+                            hidden_size=128, moe_intermediate_size=128,
+                            sliding_window=64, num_experts=8, ep_size=2)
+    model = chip_smoke.build_afmoe_model(cfg)
+    leg = chip_smoke.serve_leg(
+        model, chip_smoke.smoke_prompts(cfg.vocab_size, (70, 100, 130)), 4,
+        num_slots=2, max_length=256, paged=True, chunked=True,
+        block_len=128, prefill_chunk=64,
+        expect_paths=("decode_attention/pallas_decode/paged",
+                      "chunked_prefill/paged", "moe_experts/pallas_gmm"))
+    assert leg["step_traces"] == 1 and leg["rounds_agree"] == 1.0
 
 
 def test_serve_leg_fails_when_a_kernel_gives_way():

@@ -222,7 +222,8 @@ def test_spans_off_records_nothing_and_enters_no_annotation(
 _PALLAS_DIR = os.path.join(os.path.dirname(pt.__file__), "ops", "pallas")
 _CALL_SITES = [("decode_attention.py", 0), ("flash_attention.py", 0),
                ("flash_attention.py", 1), ("flash_attention.py", 2),
-               ("int8_matmul.py", 0), ("rms_norm.py", 0)]
+               ("grouped_matmul.py", 0), ("int8_matmul.py", 0),
+               ("rms_norm.py", 0)]
 
 
 def _pallas_calls(filename):
@@ -233,7 +234,7 @@ def _pallas_calls(filename):
             and n.func.attr == "pallas_call"]
 
 
-def test_the_call_sites_are_the_six():
+def test_the_call_sites_are_the_seven():
     found = [(os.path.basename(p), i)
              for p in sorted(glob.glob(os.path.join(_PALLAS_DIR, "*.py")))
              for i in range(len(_pallas_calls(os.path.basename(p))))]
