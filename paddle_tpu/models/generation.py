@@ -78,6 +78,24 @@ def sample_tokens(logits, key, temperature=0.0, top_k=None, top_p=None):
         without retracing.  Row conventions: ``temperature <= 0`` ⇒
         greedy, ``top_k == 0`` and ``top_p == 1.0`` ⇒ off.
 
+    The traced program does only what its rows ask for, by two
+    conditionals inside the one program (:data:`SAMPLE_PATHS` names the
+    three ways through; :func:`sample_path` is the host's mirror of the
+    predicates):
+
+      * the argmax always runs; everything else runs under
+        ``any(temperature > 0)`` — a tick whose rows are all greedy (idle
+        slots carry temperature 0) returns the argmax and nothing more;
+      * inside it, the truncation (:func:`_truncate`: ONE descending sort
+        of the scaled logits, shared by top-k and top-p) runs under
+        ``any(temperature > 0 and (top_k > 0 or top_p < 1))``; rows that
+        only set a temperature go straight to ``categorical``.
+
+    **A row's token never depends on which other rows share its batch**:
+    the conditionals decide what is computed, never what a row gets — a
+    greedy row gets its argmax, and a row whose knobs are off keeps its
+    whole vocabulary, whether or not a neighbour samples or truncates.
+
     ``logits``: (B, vocab).  Returns int32 (B,).
     """
     logits = logits.astype(jnp.float32)
@@ -91,22 +109,78 @@ def sample_tokens(logits, key, temperature=0.0, top_k=None, top_p=None):
         if top_p is not None:
             logits = _nucleus_mask(logits, top_p)
         return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
-    # traced per-row knobs: greedy rows take the argmax below regardless
-    # of what the (well-defined, never-NaN) sampling branch computes
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    vocab = logits.shape[-1]
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    if top_k is not None:
-        # per-row dynamic k: kth-largest via a descending sort (no static
-        # k for lax.top_k to use); k == 0 keeps the whole row
-        srt = jnp.sort(scaled, axis=-1)[..., ::-1]
-        k_eff = jnp.where(top_k > 0, jnp.clip(top_k, 1, vocab), vocab)
-        kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
-        scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
-    if top_p is not None:
-        scaled = _nucleus_mask(scaled, top_p[:, None])
-    samp = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy, samp)
+    live = temperature > 0.0
+
+    def sampled():
+        # greedy rows ride along through a (well-defined, never-NaN)
+        # clamped scale and take their argmax at the end
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        scaled = _truncate(
+            scaled, live[:, None],
+            None if top_k is None else top_k[:, None],
+            None if top_p is None else top_p[:, None])
+        samp = jax.random.categorical(key, scaled, axis=-1)
+        return jnp.where(live, samp.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(live), sampled, lambda: greedy)
+
+
+# the three ways through sample_tokens' traced branch, lightest first
+SAMPLE_PATHS = ("greedy", "categorical", "truncated")
+
+
+def sample_path(temperature, top_k, top_p) -> int:
+    """Which way a call of :func:`sample_tokens` with these per-row
+    vectors goes, as an index into :data:`SAMPLE_PATHS` — the device's
+    two predicates, evaluated on the host's copies of the same vectors
+    (numpy, the dtypes the program is handed), so a scheduler can name a
+    tick's path without a readback."""
+    live = temperature > 0.0
+    if not live.any():
+        return 0
+    return 2 if (live & ((top_k > 0) | (top_p < 1.0))).any() else 1
+
+
+def _truncate(scaled, live, top_k, top_p):
+    """Per-row top-k and top-p truncation of temperature-scaled logits —
+    the ONE copy :func:`sample_tokens` and :func:`_target_probs` share.
+
+    ``scaled``: (..., V); ``live`` (bool: the row samples), ``top_k``
+    (int, 0 ⇒ off) and ``top_p`` (float, 1.0 ⇒ off): per-row arrays
+    broadcastable against ``scaled[..., :1]``; a knob given as None is
+    off for every row.
+    Returns ``scaled`` with every token outside a row's kept set at -inf.
+
+    Guarded: the work runs under ``any(live and (top_k > 0 or top_p <
+    1))`` and is otherwise skipped whole (``scaled`` comes back as it
+    is).  When it runs it sorts ONCE, descending: top-k reads its k-th
+    value off the sorted row, and the sorted *masked* row that top-p
+    needs is that same sort with the values under the k-th at -inf (same
+    values, same order).  Both thresholds are applied row-wise, to the
+    rows the guard counts and no others — a row that does not sample, or
+    whose ``top_k == 0`` (``k = V``) and ``top_p == 1.0`` (no
+    threshold), keeps its whole vocabulary whether or not the work ran
+    for a neighbour: a row's result is independent of its batch."""
+    if top_k is None and top_p is None:
+        return scaled
+    vocab = scaled.shape[-1]
+    top_k = 0 if top_k is None else top_k
+    top_p = 1.0 if top_p is None else top_p
+    by_k, by_p = live & (top_k > 0), live & (top_p < 1.0)
+
+    def truncated():
+        srt = jnp.sort(scaled, axis=-1)[..., ::-1]               # desc
+        # per-row dynamic k: the k-th largest, read off the sort (no
+        # static k for lax.top_k to use); an untruncated row keeps V
+        k_eff = jnp.where(by_k, jnp.clip(top_k, 1, vocab), vocab)
+        floor = jnp.take_along_axis(srt, k_eff - 1, axis=-1)
+        srt = jnp.where(srt < floor, -jnp.inf, srt)
+        floor = jnp.where(
+            by_p, jnp.maximum(floor, _nucleus_floor(srt, top_p)), floor)
+        return jnp.where(scaled < floor, -jnp.inf, scaled)
+
+    return jax.lax.cond(jnp.any(by_k | by_p), truncated, lambda: scaled)
 
 
 def _target_probs(logits, temperature, top_k=None, top_p=None):
@@ -114,26 +188,25 @@ def _target_probs(logits, temperature, top_k=None, top_p=None):
     explicit per-token probabilities — the p(x) of the rejection-sampling
     acceptance rule (Leviathan et al. 2023).  Applies EXACTLY the same
     transforms as ``sample_tokens``' traced branch (fp32 cast,
-    clamped-temperature scaling, per-row dynamic top-k, nucleus mask)
-    and then normalises, so accept/resample decisions are made against
-    the same distribution the plain step would sample.
+    clamped-temperature scaling, then :func:`_truncate`, the one copy of
+    the per-row top-k / top-p mask, under the same guard) and then
+    normalises, so accept/resample decisions are made against the same
+    distribution the plain step would sample.  As there, a row's
+    probabilities are independent of its batch.
 
     ``logits``: (B, S, V); knobs: (B,) vectors (or static scalars,
     broadcast).  Returns f32 (B, S, V) rows summing to 1."""
     logits = logits.astype(jnp.float32)
     b = logits.shape[0]
-    vocab = logits.shape[-1]
-    t = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (b,))
-    scaled = logits / jnp.maximum(t, 1e-6)[:, None, None]
-    if top_k is not None:
-        tk = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (b,))
-        srt = jnp.sort(scaled, axis=-1)[..., ::-1]
-        k_eff = jnp.where(tk > 0, jnp.clip(tk, 1, vocab), vocab)
-        kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None, None], axis=-1)
-        scaled = jnp.where(scaled < kth, -jnp.inf, scaled)
-    if top_p is not None:
-        tp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (b,))
-        scaled = _nucleus_mask(scaled, tp[:, None, None])
+
+    def rows(knob, dtype):
+        if knob is None:
+            return None
+        return jnp.broadcast_to(jnp.asarray(knob, dtype), (b,))[:, None, None]
+
+    t = rows(temperature, jnp.float32)
+    scaled = _truncate(logits / jnp.maximum(t, 1e-6), t > 0.0,
+                       rows(top_k, jnp.int32), rows(top_p, jnp.float32))
     return jax.nn.softmax(scaled, axis=-1)
 
 
@@ -470,21 +543,31 @@ def greedy_generate(model, input_ids, max_new_tokens: int,
     return jnp.concatenate([input_ids, out], axis=1)
 
 
+def _nucleus_floor(sorted_logits, top_p):
+    """The smallest logit top-p (nucleus) truncation keeps, from rows
+    already sorted DESCENDING: the smallest set of tokens whose
+    cumulative probability reaches ``top_p`` is kept (the first token
+    always).  (..., 1)."""
+    probs = jax.nn.softmax(sorted_logits, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    # drop tokens whose PRECEDING mass already reached p; the threshold
+    # is the smallest kept logit
+    drop = (cum - probs) >= top_p
+    return jnp.min(jnp.where(drop, jnp.inf, sorted_logits), axis=-1,
+                   keepdims=True)
+
+
 def _nucleus_mask(logits, top_p):
     """Top-p (nucleus) truncation (parity: generation_utils'
     TopPProcess, upstream PaddleNLP layout): keep the smallest set of
     tokens whose cumulative probability reaches ``top_p``; mask the rest
     to -inf.  Sort-based — lax-friendly, no data-dependent shapes.
-    ``top_p``: static float or a broadcastable (B, 1) per-row array
-    (1.0 ⇒ keep everything)."""
+    ``top_p``: a static float (``sample_tokens``' static-knobs branch;
+    unguarded, its own sort).  The traced per-row form is
+    :func:`_truncate`, which shares the threshold rule below
+    (:func:`_nucleus_floor`) and its one sort with top-k."""
     sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]         # desc
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # drop tokens whose PRECEDING mass already reached p (the first token
-    # is always kept); threshold = smallest kept logit
-    drop = (cum - probs) >= top_p
-    kth = jnp.min(jnp.where(drop, jnp.inf, sorted_logits), axis=-1,
-                  keepdims=True)
+    kth = _nucleus_floor(sorted_logits, top_p)
     return jnp.where(logits < kth, -jnp.inf, logits)
 
 

@@ -12,7 +12,14 @@ Design (Orca-style iteration-level scheduling, expressed TPU-first):
     position vectors (ops/attention.py cache masking, llama.py scatter
     writes) are what let one program serve rows at different depths, and
     per-slot sampling vectors (generation.py ``sample_tokens``, traced
-    form) let greedy and sampled requests share a batch.  The same
+    form) let greedy and sampled requests share a batch; that epilogue
+    does only what the tick's vectors ask for, by conditionals inside the
+    one program — an all-greedy tick runs the argmax alone, the
+    full-vocabulary sort runs only when a sampling row truncates, and a
+    row's token never depends on its neighbours.  The host holds the
+    same vectors, so each tick's span (``sample_path=``) and the
+    ``serving.sample_path`` counter name the way it went without a
+    readback.  The same
     position vector doubles as the flash-decode kernel's live-prefix
     hint: at max_length >= FLAGS_decode_attention_min_len the attention
     dispatcher hands it to ops/pallas/decode_attention.py as a
@@ -154,9 +161,9 @@ import numpy as np
 from .. import flags as _flags
 from .. import observability as _obs
 from ..distributed import moe as _moe
-from ..models.generation import (_place_on_mesh, accept_draft_tokens,
-                                 decode_mesh_specs, init_kv_cache,
-                                 sample_tokens)
+from ..models.generation import (SAMPLE_PATHS, _place_on_mesh,
+                                 accept_draft_tokens, decode_mesh_specs,
+                                 init_kv_cache, sample_path, sample_tokens)
 from ..nn.layer import bind_params
 from ..ops import _dispatch as _disp
 from .drafter import DraftModelDrafter, NgramDrafter
@@ -1102,6 +1109,15 @@ class ServingEngine:
             "serving.prefill_bucket",
             "admission waves per padded prefill bucket length (paged: "
             "suffix bucket)")
+        f_path = ctr(
+            "serving.sample_path",
+            "step and prefill programs run, by the way their sampling "
+            "epilogue went (the heavier of a mixed step's two): greedy "
+            "(argmax alone) | categorical (a row samples, none "
+            "truncates) | truncated (a sampling row set top_k or top_p: "
+            "the full-vocabulary sort)")
+        self._m_sample_path = tuple(
+            f_path.labels(path=p, **lbl) for p in SAMPLE_PATHS)
         self._m_waves = ctr(
             "serving.prefill_waves", "batched prefill waves").labels(**lbl)
         self._m_blocked = ctr(
@@ -2223,6 +2239,16 @@ class ServingEngine:
                          violation="cancelled")
         self._tracer.instant("serving.cancelled", rid=req.request_id)
 
+    def _note_sample_path(self, *knobs) -> str:
+        """Name and count the way this tick's sampling epilogue goes:
+        ``knobs`` are the (temperature, top_k, top_p) vectors of each
+        ``sample_tokens`` call the program makes (a mixed step: the
+        rows', then the chunk's), read by the device's own predicates
+        (``generation.sample_path``); the tick's name is the heaviest."""
+        i = max(sample_path(*k) for k in knobs)
+        self._m_sample_path[i].inc()
+        return SAMPLE_PATHS[i]
+
     def _step_inner(self) -> List[int]:
         span = self._tracer.span
         with span(_ADMIT):
@@ -2233,7 +2259,9 @@ class ServingEngine:
             return finished
         self._ticks += 1
         t0 = self._clock()
-        with span("serving.decode", slots=occ):
+        with span("serving.decode", slots=occ,
+                  sample_path=self._note_sample_path(
+                      (self._temps, self._topk, self._topp))):
             if self.paged:
                 with span(_GROW):
                     for i, slot in enumerate(self._slots):
@@ -2379,7 +2407,9 @@ class ServingEngine:
         self._ticks += 1
         t0 = self._clock()
         with span("serving.verify", slots=occ,
-                  drafted=int(draft_ok.sum())):
+                  drafted=int(draft_ok.sum()),
+                  sample_path=self._note_sample_path(
+                      (self._temps, self._topk, self._topp))):
             if self.paged:
                 with span(_GROW):
                     for i, slot in enumerate(self._slots):
@@ -2525,12 +2555,17 @@ class ServingEngine:
                 window = np.concatenate([self._tokens[:, None], drafts],
                                         axis=1)
         t0 = self._clock()
+        knobs = [(self._temps, self._topk, self._topp)]
+        if do_chunk:         # the chunk's one row, as build_inputs fills it
+            sp = pf.req.sampling
+            knobs.append((np.float32(sp.temperature), np.int32(sp.top_k),
+                          np.float32(sp.top_p)))
         chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
                            tokens=clen)
                       if do_chunk else contextlib.nullcontext())
         decode_span = span(
             "serving.verify" if self.spec else "serving.decode",
-            slots=occ)
+            slots=occ, sample_path=self._note_sample_path(*knobs))
         with decode_span, chunk_span:
             if self.paged:
                 with span(_GROW):
@@ -3584,7 +3619,8 @@ class ServingEngine:
         with span(_GROW):
             self._flush_fresh_scales()
         with span("serving.prefill", bucket=bucket, rows=len(wave),
-                  padded_rows=nb, tokens=int(slens[:len(wave)].sum())):
+                  padded_rows=nb, tokens=int(slens[:len(wave)].sum()),
+                  sample_path=self._note_sample_path((temps, topk, topp))):
             with span(_BUILD):
                 args = (jnp.asarray(ids), jnp.asarray(prefix),
                         jnp.asarray(slens), jnp.asarray(tables),
@@ -3685,7 +3721,8 @@ class ServingEngine:
         self._ticks += 1
         span = self._tracer.span
         with span("serving.prefill", bucket=bucket, rows=len(wave),
-                  padded_rows=nb, tokens=int(plens[:len(wave)].sum())):
+                  padded_rows=nb, tokens=int(plens[:len(wave)].sum()),
+                  sample_path=self._note_sample_path((temps, topk, topp))):
             with span(_BUILD):
                 args = (jnp.asarray(ids), jnp.asarray(plens),
                         jnp.asarray(slot_ids), jnp.asarray(temps),

@@ -1,28 +1,60 @@
-"""sha256 of the lowered (StableHLO) text of the llama step programs at the
-Mistral cells' geometry (published widths, the engine settings of the cells'
-files; 2 of the 16 layers: the layers are one code path repeated), lowered
-on the CPU for the chip's dispatch.  A tool, not a test: run it on two
-checkouts and compare the lines (PR 27 used it to show that a second model
-in the engine left the llama step programs as they were):
+"""The lowered (StableHLO) form of the engine's step programs, lowered on the
+CPU for the chip.  A tool, and the helpers of tests that read a lowered
+program (``tests/test_sample_paths.py``):
 
     JAX_PLATFORMS=cpu python tests/lowered_step_text.py <repo root>
-"""
-import hashlib, json, os, sys
-root = os.path.abspath(sys.argv[1]); sys.path.insert(0, root)
-import jax, jax.numpy as jnp
-import paddle_tpu.ops._dispatch as D
-D.default_backend = lambda: "tpu"          # the chip's dispatch, lowered here
-import paddle_tpu as pt
-from paddle_tpu.models import LlamaForCausalLM
-from paddle_tpu.models.llama import LlamaConfig
-from paddle_tpu.serving import ServingEngine
-cfg = json.load(open(os.path.join(root, "benchmark/configs/mistral-7b.json")))
-fields = {k: cfg[k] for k in ("vocab_size", "hidden_size", "intermediate_size",
-    "num_attention_heads", "num_key_value_heads", "max_position_embeddings",
-    "rms_norm_eps", "rope_theta", "tie_word_embeddings")}
-pt.seed(0)
-model = LlamaForCausalLM(LlamaConfig(dtype="bfloat16", num_hidden_layers=2, **fields)); model.eval()
-import base64, re
+
+prints the sha256 of the text of the llama step programs at the Mistral
+cells' geometry (published widths, the engine settings of the cells' files; 2
+of the 16 layers: the layers are one code path repeated), under the chip's
+dispatch.  Run it on two checkouts and compare the lines (PR 27 used it to
+show that a second model in the engine left the llama step programs as they
+were)."""
+import base64
+import hashlib
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+
+
+def lowered(fn, args):
+    """``fn`` (a step program's Python body; its second argument, the cache,
+    donated as the engine donates it) lowered for the chip."""
+    return jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(
+        lowering_platforms=("tpu",))
+
+
+def sorts_over(low, width):
+    """Every ``stablehlo.sort`` of a lowered program whose operand's last axis
+    is ``width`` long, once for each chain of calls that reaches it from
+    ``main``, as (operand shape, inside a conditional's branch or not)."""
+    module = low.compiler_ir("stablehlo")
+    funcs = {f.name.value: f for f in module.body.operations
+             if f.operation.name == "func.func"}
+    found = []
+
+    def walk(op, in_branch):
+        name = op.operation.name
+        if name == "stablehlo.sort":
+            shape = tuple(op.operands[0].type.shape)
+            if shape and shape[-1] == width:
+                found.append((shape, in_branch))
+        if name == "func.call":
+            walk(funcs[op.attributes["callee"].value], in_branch)
+        inside = in_branch or name in ("stablehlo.case", "stablehlo.if")
+        for region in op.operation.regions:
+            for block in region.blocks:
+                for child in block.operations:
+                    walk(child, inside)
+
+    walk(funcs["main"], False)
+    return found
+
+
 def strip_locations(txt):
     """A Mosaic kernel's body is MLIR bytecode that carries the kernel
     source's file:line locations, so any edit above a kernel moves it.
@@ -30,26 +62,69 @@ def strip_locations(txt):
     from jax._src.interpreters import mlir as jmlir
     from jax._src.lib import tpu
     from jax._src.lib.mlir import ir
+
     def plain(m):
-        ctx = jmlir.make_ir_context(); tpu.register_dialect(ctx); ctx.allow_unregistered_dialects = True
+        ctx = jmlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
         with ctx:
             mod = ir.Module.parse(base64.b64decode(m.group(1)))
-            return 'body\\22: \\22' + mod.operation.get_asm(enable_debug_info=False).replace("\n", " ") + '\\22'
+            return ('body\\22: \\22' + mod.operation.get_asm(
+                enable_debug_info=False).replace("\n", " ") + '\\22')
     return re.sub(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', plain, txt)
-def sha(fn, args):
-    txt = jax.jit(fn, donate_argnums=(1,)).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def sha(low):
+    txt = low.as_text()
     raw = hashlib.sha256(txt.encode()).hexdigest()[:8]
     txt = strip_locations(txt)
-    print("   raw text", raw, "kernel bodies re-printed:", txt.count("body\\22: \\22module"))
+    print("   raw text", raw, "kernel bodies re-printed:",
+          txt.count("body\\22: \\22module"))
     return hashlib.sha256(txt.encode()).hexdigest()[:16], len(txt)
-for cell in ("mistral-7b.decode-saturated", "mistral-7b.chat-open"):
-    eng_kw = json.load(open(os.path.join(root, "benchmark/workloads", cell + ".json")))["engine"]
-    eng = ServingEngine(model, seed=0, **eng_kw)
-    print(cell, "step", eng._step_fn.python_fn.__name__, *sha(eng._step_fn.python_fn, eng._lint_args()))
-    if eng._prefill_fn is not None:
-        nb, L = eng.prefill_batch, 256
-        z = lambda *s, dt=jnp.int32: jnp.zeros(s, dt)
-        args = (eng._params, eng._cache, z(nb, L), z(nb), z(nb), z(nb, eng.max_blocks),
-                z(nb, dt=jnp.float32), z(nb), jnp.ones((nb,), jnp.float32), jax.random.key(0))
-        print(cell, "prefill", eng._prefill_fn.python_fn.__name__, *sha(eng._prefill_fn.python_fn, args))
-    del eng
+
+
+def main(root):
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    import paddle_tpu.ops._dispatch as D
+    D.default_backend = lambda: "tpu"      # the chip's dispatch, lowered here
+    import paddle_tpu as pt
+    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.serving import ServingEngine
+    cfg = json.load(open(os.path.join(
+        root, "benchmark/configs/mistral-7b.json")))
+    fields = {k: cfg[k] for k in (
+        "vocab_size", "hidden_size", "intermediate_size",
+        "num_attention_heads", "num_key_value_heads",
+        "max_position_embeddings", "rms_norm_eps", "rope_theta",
+        "tie_word_embeddings")}
+    pt.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        dtype="bfloat16", num_hidden_layers=2, **fields))
+    model.eval()
+    for cell in ("mistral-7b.decode-saturated", "mistral-7b.chat-open"):
+        eng_kw = json.load(open(os.path.join(
+            root, "benchmark/workloads", cell + ".json")))["engine"]
+        eng = ServingEngine(model, seed=0, **eng_kw)
+        step = eng._step_fn.python_fn
+        low = lowered(step, eng._lint_args())
+        print(cell, "step", step.__name__, *sha(low))
+        print("   sorts over the vocabulary (shape, in a branch):",
+              sorts_over(low, fields["vocab_size"]))
+        if eng._prefill_fn is not None:
+            nb, L = eng.prefill_batch, 256
+
+            def z(*s, dt=jnp.int32):
+                return jnp.zeros(s, dt)
+            args = (eng._params, eng._cache, z(nb, L), z(nb), z(nb),
+                    z(nb, eng.max_blocks), z(nb, dt=jnp.float32), z(nb),
+                    jnp.ones((nb,), jnp.float32), jax.random.key(0))
+            prefill = eng._prefill_fn.python_fn
+            print(cell, "prefill", prefill.__name__,
+                  *sha(lowered(prefill, args)))
+        del eng
+
+
+if __name__ == "__main__":
+    main(sys.argv[1])

@@ -110,7 +110,8 @@ def test_wave_prefill_span_says_what_it_padded(lm, mode):
     # longest prompt's power-of-two bucket
     assert wave["args"] == {"bucket": 16, "rows": len(PROMPTS),
                             "padded_rows": eng.prefill_batch,
-                            "tokens": sum(PROMPTS)}
+                            "tokens": sum(PROMPTS),
+                            "sample_path": "greedy"}
     # the wave's own upload, launch and fetch are phases inside it
     inner = {e["name"] for e in phases if _inside(e, wave)}
     assert inner == {"serving.build_inputs", "serving.dispatch",
