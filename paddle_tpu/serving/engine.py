@@ -91,9 +91,7 @@ replaces the wave with a **token-budget scheduler**:
     whole-prompt stall, and TTFT pipelines across ticks;
   * the mixed step is jitted ONCE (chunk size static, budget-1
     ``track_retraces`` site ``serving.step``); chunk-free ticks ride the
-    same program with a dummy chunk whose writes are steered harmless
-    (contiguous: positions past ``max_length`` drop out of the scatter;
-    paged: the all-null table lands them in the null block);
+    same program with a dummy chunk whose writes are steered harmless;
   * ``chunk_policy`` trades the two SLOs: ``"prefill"`` (default) runs a
     pending chunk every tick, ``"decode"`` interleaves chunks with
     chunk-free ticks while decodes are active;
@@ -134,12 +132,22 @@ token.  Spec mode does that without a second model:
     re-credited, trie invalidated past the cut);
   * rows with no draft hit ride the SAME program as depth-1 decode (k is
     static; absent drafts are pad columns masked out of acceptance, with
-    their junk writes steered exactly like idle rows' — past max_length
-    contiguous, into the null block paged), so the retrace budget stays
-    1 and the graph lint stays green in every layout.  Chunked prefill
-    composes: the mixed step's decode half becomes the verify window
-    while a prefilling slot — inactive by construction — drafts nothing
-    until its cursor completes.
+    their junk writes steered exactly like idle rows'), so the retrace
+    budget stays 1 and the graph lint stays green in every layout.
+    Chunked prefill composes: the mixed step's decode half becomes the
+    verify window while a prefilling slot — inactive by construction —
+    drafts nothing until its cursor completes.
+
+**One path** (PR 29).  The three switches select parts of one composed
+path, not copies of it: ONE step program (``_step_program``: a rows part —
+plain or verify — then a chunk part when chunked, over block tables or
+slot rows; where each kind of idle write lands is argued there, once) and
+one prefill program for the wave engines, each with its signature written
+down once as an operand table (``_operand_tables``) that the uploads, the
+lint's arguments and the mesh shardings all read; ONE tick
+(``_step_inner``); and one device seam each for the tick and the wave
+(``_device_step``, ``_device_prefill``), which is all the fleet simulator
+replaces.
 """
 
 from __future__ import annotations
@@ -152,7 +160,8 @@ import itertools
 import json
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Deque, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -172,8 +181,8 @@ from .kv_cache import BlockManager, init_paged_kv_cache
 __all__ = ["ServingEngine", "SamplingParams", "Request", "TICK_PHASES"]
 
 #: The phases of one scheduler tick: the names of the non-overlapping
-#: child spans that tile ``serving.step`` in every step body and in both
-#: prefill waves (a wave's phases nest inside ``serving.admit`` >
+#: child spans that tile ``serving.step`` in the tick and in the prefill
+#: wave (a wave's phases nest inside ``serving.admit`` >
 #: ``serving.prefill``).  Whoever attributes host time or a device idle
 #: gap to the tick reads these names (benchmark/harness/engine_spans.py):
 #:
@@ -223,6 +232,21 @@ def _slot_row_update(cache, row, cslot):
     return jax.tree_util.tree_map(
         lambda a, r: jax.lax.dynamic_update_slice(
             a, r, (z, z, cslot) + (z,) * (a.ndim - 3)), cache, row)
+
+
+class _Operand(NamedTuple):
+    """One operand of a device program after ``(params, cache)``: a row of
+    the engine's operand tables (``ServingEngine._operand_tables``)."""
+
+    name: str                  # what the program's body calls it
+    shape: Tuple               # a ``None`` is the prefill wave's bucket
+    dtype: object
+    # where the host takes the value from: a mirror array of the engine,
+    # uploaded as it stands, or the name under which the tick (the wave)
+    # hands over a value of its own
+    src: object
+    put: Callable = jnp.asarray    # the upload, one call an operand
+    fill: int = 0              # the abstract trace's value (``_lint_args``)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -489,24 +513,7 @@ class ServingEngine:
         # re-prefills prompt+committed tokens through the prefix trie.
         # Both are host-side pool surgery + block-table updates — the
         # once-jitted step never sees a new trace.
-        self.preempt = str(_flags.flag("serving_preempt")
-                           if preempt is None else preempt)
-        if self.preempt not in ("off", "swap", "recompute"):
-            raise ValueError(
-                f"preempt must be off|swap|recompute, got "
-                f"{self.preempt!r}")
-        if self.preempt != "off" and not self.paged:
-            raise ValueError(
-                "preemption requires the paged cache: victim block free "
-                "and swap/recompute resume are BlockManager operations")
-        self._preempt_after = int(_flags.flag("serving_preempt_after"))
-        hb = int(_flags.flag("serving_host_blocks")
-                 if host_blocks is None else host_blocks)
-        if self.preempt == "swap" and hb < 1:
-            raise ValueError(
-                "preempt='swap' needs a host tier: pass host_blocks "
-                "(or FLAGS_serving_host_blocks) >= 1")
-        self._host_blocks = hb if self.paged else 0
+        self._init_preempt(preempt, host_blocks)
         self.mesh = self._resolve_mesh(mesh)
         # quantized-decode hooks, exactly as models/generation.py binds
         self._bind = getattr(model, "unwrapped", model)
@@ -523,25 +530,12 @@ class ServingEngine:
             int(w) for w in getattr(self._bind, "attention_windows", ())
             if w is not None)
         self._init_metrics()
+        self._init_scheduler_state()
 
         self._prepare = getattr(model, "_prepare_params", lambda p: p)
         params = model.state_dict(include_buffers=True)
         if self.paged:
-            bl = int(block_len or _flags.flag("kv_cache_block_len"))
-            if self.max_length % bl:
-                raise ValueError(
-                    f"max_length {self.max_length} is not a multiple of "
-                    f"block_len {bl}")
-            self.block_len = bl
-            self.max_blocks = self.max_length // bl
-            nb = int(num_blocks or _flags.flag("kv_cache_num_blocks")
-                     or self.num_slots * self.max_blocks + 1)
-            self.kv = BlockManager(
-                nb, bl,
-                prefix_cache=bool(_flags.flag("serving_prefix_cache")
-                                  if prefix_cache is None else prefix_cache),
-                kv_dtype=self.kv_dtype,
-                host_blocks=self._host_blocks)
+            nb, bl = self._init_pool(block_len, num_blocks, prefix_cache)
             cache = init_paged_kv_cache(model.config, nb, bl,
                                         quantized=self.quantized)
             # arm the pool's bytes_by_dtype gauges with this model's
@@ -554,8 +548,6 @@ class ServingEngine:
                 "bf16": tok * bl * native,
                 "int8": tok * bl
                 + c.num_hidden_layers * 2 * c.num_key_value_heads * 4})
-            self._tables = np.zeros((self.num_slots, self.max_blocks),
-                                    np.int32)
         else:
             cache = init_kv_cache(model.config, self.num_slots,
                                   self.max_length,
@@ -571,7 +563,15 @@ class ServingEngine:
             self._drafter = self._make_drafter(sel)
             self._drafters[getattr(self._drafter, "kind", "custom")] = \
                 self._drafter
-        self._pending_demote: List[int] = []
+        def pool_program(impl, site, n_args):
+            # one of the cache's own small programs: the cache first and
+            # donated, keeping its declared sharding under a mesh
+            return _obs.track_retraces(
+                impl, site, labels={"engine": self._eid},
+                donate_argnums=(0,),
+                **(self._mesh_jit_shardings(n_args, 1, cache_argnum=0,
+                                            with_params=False)
+                   if self.mesh is not None else {}))
         if self.paged:
             # COW device copy (compiled once; only dispatched when a
             # shared block is about to be written — see kv_cache.py).
@@ -590,13 +590,7 @@ class ServingEngine:
             else:
                 def _cow_impl(c, src, dst):
                     return c.at[:, :, dst].set(c[:, :, src])
-            self._cow_fn = _obs.track_retraces(
-                _cow_impl,
-                "serving.cow", labels={"engine": self._eid},
-                donate_argnums=(0,),
-                **(self._mesh_jit_shardings(3, 1, cache_argnum=0,
-                                            with_params=False)
-                   if self.mesh is not None else {}))
+            self._cow_fn = pool_program(_cow_impl, "serving.cow", 3)
         if self.paged and self.quantized:
             # a reused block carries its previous tenant's scale row; the
             # running-max write path would inherit it and quantize the
@@ -608,24 +602,16 @@ class ServingEngine:
                 return {"kv": c["kv"],
                         "scale": jnp.where(mask[None, None, :, None],
                                            jnp.float32(0), c["scale"])}
-            self._scale_reset_fn = _obs.track_retraces(
-                _reset_impl, "serving.scale_reset",
-                labels={"engine": self._eid}, donate_argnums=(0,),
-                **(self._mesh_jit_shardings(2, 1, cache_argnum=0,
-                                            with_params=False)
-                   if self.mesh is not None else {}))
+            self._scale_reset_fn = pool_program(
+                _reset_impl, "serving.scale_reset", 2)
         if not self.paged and self.quantized:
             # contiguous slot reuse (chunked admission writes into a row
             # a retired request used): zero the row's granule scales
             def _row_reset_impl(c, slot):
                 return {"kv": c["kv"],
                         "scale": c["scale"].at[:, :, slot].set(0.0)}
-            self._row_reset_fn = _obs.track_retraces(
-                _row_reset_impl, "serving.scale_reset",
-                labels={"engine": self._eid}, donate_argnums=(0,),
-                **(self._mesh_jit_shardings(2, 1, cache_argnum=0,
-                                            with_params=False)
-                   if self.mesh is not None else {}))
+            self._row_reset_fn = pool_program(
+                _row_reset_impl, "serving.scale_reset", 2)
         if self.paged and self.kv_dtype == "mixed":
             # mixed mode: the pool stays bf16 (plain array, plain step
             # programs) and a block demoted by the BlockManager — cold
@@ -648,14 +634,9 @@ class ServingEngine:
                 q = jnp.clip(jnp.round(blk / safe), -127, 127)
                 return c.at[:, :, bid].set(
                     (q * safe).astype(c.dtype).reshape(flat.shape))
-            self._demote_fn = _obs.track_retraces(
-                _demote_impl, "serving.demote",
-                labels={"engine": self._eid}, donate_argnums=(0,),
-                **(self._mesh_jit_shardings(2, 1, cache_argnum=0,
-                                            with_params=False)
-                   if self.mesh is not None else {}))
+            self._demote_fn = pool_program(
+                _demote_impl, "serving.demote", 2)
             self.kv.on_demote = self._pending_demote.extend
-        self._tick_swap_bytes = 0      # host<->HBM bytes moved this tick
         if self.paged:
             # block movers are built on first use (_block_movers): the
             # host tier's swap hooks AND the ISSUE-18 export/import
@@ -668,35 +649,12 @@ class ServingEngine:
                 self.kv.on_swap_out = self._host_swap_out
                 self.kv.on_swap_in = self._host_swap_in
 
-        # host-side mirrors of the step inputs (tiny; re-uploaded per tick)
-        s = self.num_slots
-        self._tokens = np.zeros((s,), np.int32)
-        self._positions = np.zeros((s,), np.int32)
-        self._active = np.zeros((s,), bool)
-        self._temps = np.zeros((s,), np.float32)
-        self._topk = np.zeros((s,), np.int32)
-        self._topp = np.ones((s,), np.float32)
-
-        self._slots: List[Optional[_Slot]] = [None] * s
-        self._prefill: Optional[_Prefill] = None   # chunked-mode cursor
-        self._queue: Deque[Request] = deque()
-        # preempted work awaiting resume, each kept sorted by
-        # (-priority, request id) so resume order is deterministic
-        self._swap_resume: List[_SwapResume] = []
-        self._resume_q: Deque[Request] = deque()
-        # every preemption decision, in order — preempt_signature()
-        # hashes this list, the loadgen saturated gate replays it
-        self._preempt_log: List[Dict[str, object]] = []
-        self._results: Dict[int, List[int]] = {}
-        self._next_rid = 0
         self._base_key = jax.random.key(seed)
-        self._ticks = 0
         # the scheduler's time source: every SLO stamp (t_submit,
         # queue-wait, TTFT, TPOT) reads through this indirection, so the
         # fleet simulator (serving/fleet_sim.py) can drive the SAME
         # scheduler with a cost-model clock instead of the wall
         self._clock = time.perf_counter
-        self._kernel_preflight_cache = None  # memoized kernel_preflight()
         # trace accounting rides the retrace watchdog
         # (observability/watchdog.py): the wrapper counts compilations —
         # python side effects fire at TRACE time only — into the shared
@@ -721,55 +679,105 @@ class ServingEngine:
         # donated cache aliasable in place (in/out layouts provably
         # match) and makes the step's sharding contract the same one
         # mesh_preflight lints abstractly.
-        n_out = 2 + int(self.chunked) + int(self.spec)
-        step_kwargs = dict(donate)
-        if self.mesh is not None:
-            step_kwargs.update(self._mesh_jit_shardings(
-                len(self._lint_args()), n_out))
-        if self.chunked:
-            # chunked mode: ONE program serves every tick — num_slots
-            # decode rows plus one (possibly empty) prompt chunk, chunk
-            # size static.  The budget of 1 IS the token-budget
-            # scheduler's contract: admission, chunk progress and
-            # retirement all move through traced inputs.  Spec mode
-            # swaps the decode half for the (k+1)-deep verify window —
-            # still one static-shape program.
-            if self.spec:
-                impl = (self._spec_mixed_step_impl_paged if self.paged
-                        else self._spec_mixed_step_impl)
-            else:
-                impl = (self._mixed_step_impl_paged if self.paged
-                        else self._mixed_step_impl)
-            self._step_fn = _obs.track_retraces(
-                self._under_mesh(impl), "serving.step", budget=1,
-                labels=lbl, **step_kwargs)
-            self._prefill_fn = None
-        else:
-            if self.spec:
-                impl = (self._spec_step_impl_paged if self.paged
-                        else self._spec_step_impl)
-            else:
-                impl = (self._step_impl_paged if self.paged
-                        else self._step_impl)
-            self._step_fn = _obs.track_retraces(
-                self._under_mesh(impl), "serving.step", budget=1,
-                labels=lbl, **step_kwargs)
-            prefill_kwargs = dict(donate)
+        self._step_table, self._prefill_table = self._operand_tables()
+        self._step_outputs = (
+            ("tokens",) + ("n_acc",) * self.spec
+            + ("chunk_token",) * self.chunked
+            + ("expert_load",) * bool(self._expert_layers) + ("cache",))
+
+        def program(body, table, outputs, site, budget):
+            kwargs = dict(donate)
             if self.mesh is not None:
-                prefill_kwargs.update(self._mesh_jit_shardings(
-                    10 if self.paged else 9, 2))
-            self._prefill_fn = _obs.track_retraces(
-                self._under_mesh(self._prefill_impl_paged if self.paged
-                                 else self._prefill_impl),
-                "serving.prefill",
-                budget=_PREFILL_TRACE_BUDGET, labels=lbl,
-                **prefill_kwargs)
+                kwargs.update(self._mesh_jit_shardings(
+                    2 + len(table), len(outputs)))
+            return _obs.track_retraces(self._under_mesh(body), site,
+                                       budget=budget, labels=lbl, **kwargs)
+        # ONE step program serves every tick.  The budget of 1 IS the
+        # scheduler's contract: admission, chunk progress, drafts and
+        # retirement all move through traced inputs.  The cursor engine
+        # has no other program; a wave engine has its prefill program,
+        # compiled once per bucket.
+        self._step_fn = program(self._step_program(), self._step_table,
+                                self._step_outputs, "serving.step", 1)
+        self._prefill_fn = None if self.chunked else program(
+            self._prefill_program(), self._prefill_table,
+            ("tokens", "cache"), "serving.prefill", _PREFILL_TRACE_BUDGET)
         self._linted = False           # first-tick self-lint (graph_lint)
         # per-tick roofline cost model (ISSUE 15): predictions are
         # memoized host math, so the steady-state tick pays a dict
         # lookup; FLAGS_perf_model 'off' skips the layer entirely
         self._perf = (self._build_perf_model()
                       if _flags.flag("perf_model") == "on" else None)
+
+    def _init_preempt(self, preempt: Optional[str],
+                      host_blocks: Optional[int]):
+        self.preempt = str(_flags.flag("serving_preempt")
+                           if preempt is None else preempt)
+        if self.preempt not in ("off", "swap", "recompute"):
+            raise ValueError(
+                f"preempt must be off|swap|recompute, got "
+                f"{self.preempt!r}")
+        if self.preempt != "off" and not self.paged:
+            raise ValueError(
+                "preemption requires the paged cache: victim block free "
+                "and swap/recompute resume are BlockManager operations")
+        self._preempt_after = int(_flags.flag("serving_preempt_after"))
+        hb = int(_flags.flag("serving_host_blocks")
+                 if host_blocks is None else host_blocks)
+        if self.preempt == "swap" and hb < 1:
+            raise ValueError(
+                "preempt='swap' needs a host tier: pass host_blocks "
+                "(or FLAGS_serving_host_blocks) >= 1")
+        self._host_blocks = hb if self.paged else 0
+
+    def _init_pool(self, block_len, num_blocks, prefix_cache):
+        """The paged cache's host side: the BlockManager and the slots'
+        block tables.  Returns (blocks in the pool, block length)."""
+        bl = int(block_len or _flags.flag("kv_cache_block_len"))
+        if self.max_length % bl:
+            raise ValueError(
+                f"max_length {self.max_length} is not a multiple of "
+                f"block_len {bl}")
+        self.block_len = bl
+        self.max_blocks = self.max_length // bl
+        nb = int(num_blocks or _flags.flag("kv_cache_num_blocks")
+                 or self.num_slots * self.max_blocks + 1)
+        self.kv = BlockManager(
+            nb, bl,
+            prefix_cache=bool(_flags.flag("serving_prefix_cache")
+                              if prefix_cache is None else prefix_cache),
+            kv_dtype=self.kv_dtype,
+            host_blocks=self._host_blocks)
+        self._tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
+        return nb, bl
+
+    def _init_scheduler_state(self):
+        """The scheduler's host state, empty (the simulator's too)."""
+        # host-side mirrors of the step inputs (tiny; re-uploaded per tick)
+        s = self.num_slots
+        self._tokens = np.zeros((s,), np.int32)
+        self._positions = np.zeros((s,), np.int32)
+        self._active = np.zeros((s,), bool)
+        self._temps = np.zeros((s,), np.float32)
+        self._topk = np.zeros((s,), np.int32)
+        self._topp = np.ones((s,), np.float32)
+
+        self._slots: List[Optional[_Slot]] = [None] * s
+        self._prefill: Optional[_Prefill] = None   # chunked-mode cursor
+        self._queue: Deque[Request] = deque()
+        # preempted work awaiting resume, each kept sorted by
+        # (-priority, request id) so resume order is deterministic
+        self._swap_resume: List[_SwapResume] = []
+        self._resume_q: Deque[Request] = deque()
+        # every preemption decision, in order — preempt_signature()
+        # hashes this list, the loadgen saturated gate replays it
+        self._preempt_log: List[Dict[str, object]] = []
+        self._results: Dict[int, List[int]] = {}
+        self._next_rid = 0
+        self._ticks = 0
+        self._tick_swap_bytes = 0      # host<->HBM bytes moved this tick
+        self._pending_demote: List[int] = []
+        self._kernel_preflight_cache = None  # memoized kernel_preflight()
 
     # -- cost model / perf attribution (ISSUE 15) --------------------------
 
@@ -1286,46 +1294,62 @@ class ServingEngine:
                 self._f_spec_accept.labels(**lbl))
         return m
 
-    # -- jitted device programs -------------------------------------------
+    # -- the device programs: one operand table, one builder each ---------
 
-    def _step_impl(self, params, cache, tokens, positions, slot_mask,
-                   temps, topk, topp, key):
-        """One decode step for ALL slots: row i holds request state at
-        position ``positions[i]``.  Compiled exactly once."""
-        with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, self._prepare(params)):
-            logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions)
-        with jax.named_scope("sample"):
-            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        return nxt, cache
+    def _operand_tables(self):
+        """The signatures of the step program and of the prefill wave's
+        (None for the cursor engine, which has no wave), after ``(params,
+        cache)``: each a list of :class:`_Operand` in the program's order.
+        Built once; everything that states a signature reads it — the
+        program bodies (by name), the uploads of a tick and of a wave
+        (``_upload``), ``_lint_args`` and the lengths of a mesh engine's
+        declared shardings."""
+        s, k, nb = self.num_slots, self.spec_k, self.prefill_batch
+        mb = self.max_blocks if self.paged else 0
+        i32, f32, op = np.int32, np.float32, _Operand
+        key = op("key", (), self._base_key.dtype, "key",
+                 functools.partial(jax.random.fold_in, self._base_key))
 
-    def _prefill_impl(self, params, cache, ids, plens, slot_ids,
-                      temps, topk, topp, key):
-        """Batched prefill of one admission wave: run the prompts through
-        the static-``pos=0`` path (flash-eligible) on a fresh
-        ``prefill_batch``-row cache, sample each row's first token from
-        the logits at its LAST REAL position, then scatter the finished
-        cache rows into their slots.  Dummy rows carry ``slot_id ==
-        num_slots``; the ``mode="drop"`` scatter discards them.  One
-        compilation per padded prompt-bucket length."""
-        nb = ids.shape[0]
-        sub = init_kv_cache(self.config, nb, self.max_length,
-                            quantized=self.quantized)
-        with _disp.program_part(_PREFILL, "wave_rows"), \
-                bind_params(self._bind, self._prepare(params)):
-            logits, sub = self.model.decode_step(ids, sub, 0)
-        with jax.named_scope("sample"):
-            last = logits[jnp.arange(nb), plens - 1]       # (nb, vocab)
-            tok = sample_tokens(last, key, temps, topk, topp)
-        # leaf-wise slot scatter (the int8 cache is a {kv, scale} pytree
-        # with batch at axis 2 in both leaves; the fresh sub-cache's zero
-        # scales reset the reused rows' quantization state for free)
-        cache = jax.tree_util.tree_map(
-            lambda c, s: c.at[:, :, slot_ids].set(s, mode="drop"),
-            cache, sub)
-        return tok, cache
+        def knobs(n, c, temps, topk, topp):
+            # the three per-row vectors of one sample_tokens call
+            return [op(c + "temps", (n,), f32, temps),
+                    op(c + "topk", (n,), i32, topk),
+                    op(c + "topp", (n,), f32, topp, fill=1)]
+        step = [op("tokens", (s, k + 1), i32, "tokens") if self.spec
+                else op("tokens", (s,), i32, self._tokens),
+                # the cursor engine on the contiguous cache steers its idle
+                # rows' positions each tick (``_device_step``)
+                op("positions", (s,), i32,
+                   "positions" if self.chunked and not self.paged
+                   else self._positions)]
+        if self.paged:
+            step.append(op("tables", (s, mb), i32, self._tables))
+        step.append(op("slot_mask", (s,), bool, self._active))
+        if self.spec:
+            step += [op("draft_ok", (s, k), bool, "draft_ok"),
+                     op("draft_probs", (s, k, self.config.vocab_size), f32,
+                        "draft_probs")]
+        step += knobs(s, "", self._temps, self._topk, self._topp)
+        if self.chunked:
+            step += [op("cids", (1, self.prefill_chunk), i32, "cids"),
+                     op("cpos", (), i32, "cpos", jnp.int32),
+                     op("clen", (), i32, "clen", jnp.int32, fill=1),
+                     # where the chunk is written: its slot's row of the
+                     # block table, or its slot
+                     op("cdst", (1, mb), i32, "cdst") if self.paged
+                     else op("cdst", (), i32, "cdst", jnp.int32)]
+            step += knobs(1, "c", "ctemps", "ctopk", "ctopp")
+            return step + [key], None
+        wave = [op("ids", (nb, None), i32, "ids")]
+        if self.paged:
+            wave += [op("prefix_lens", (nb,), i32, "prefix_lens"),
+                     op("lens", (nb,), i32, "lens", fill=1),
+                     op("tables", (nb, mb), i32, "tables")]
+        else:
+            wave += [op("lens", (nb,), i32, "lens", fill=1),
+                     op("slot_ids", (nb,), i32, "slot_ids")]
+        return step + [key], wave + knobs(nb, "", "temps", "topk",
+                                          "topp") + [key]
 
     def _experts(self, valid):
         """For a model with expert layers: the trace-time collector of
@@ -1337,239 +1361,178 @@ class ServingEngine:
             return contextlib.nullcontext(()), {}
         return _moe.expert_load(), {"valid": valid()}
 
-    def _step_impl_paged(self, params, cache, tokens, positions, tables,
-                         slot_mask, temps, topk, topp, key):
-        """Paged twin of ``_step_impl``: identical but the block table
-        rides along as a traced input, so allocation changes (slots
-        deepening into fresh blocks, prefix adoptions, evictions) reach
-        the device as data.  Compiled exactly once."""
-        collect, real = self._experts(lambda: slot_mask[:, None])
-        with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, self._prepare(params)), \
-                collect as load:
-            logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions, block_tables=tables,
-                **real)
-        with jax.named_scope("sample"):
-            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        if load:        # a model with routed experts: their load rides out
-            return nxt, jnp.stack(load)[None], cache
-        return nxt, cache
+    def _step_program(self):
+        """The Python body of THE step program, composed for this engine's
+        layout and compiled exactly once: a rows part, then a chunk part
+        when ``chunked``, over the cache addressed through block tables when
+        ``paged`` and by slot row when not.  Named by its layout,
+        ``_[spec_][mixed_]step_impl[_paged]``: the device trace's module
+        line and the benchmark's readers go by that name.
 
-    def _prefill_impl_paged(self, params, cache, ids, prefix_lens,
-                            suffix_lens, tables, temps, topk, topp, key):
-        """Paged prefill of one admission wave: each row computes ONLY
-        its prompt suffix — the tokens its prefix-cache match did not
-        cover — as a decode-at-depth over the pool (per-row ``pos`` =
-        adopted prefix length; the adopted blocks are read, not
-        recomputed).  Writes scatter straight into the rows' own blocks
-        (kv_cache.py's null-block convention absorbs bucket padding, and
-        rows admitted in the same wave see each other's writes because
-        every layer's scatter precedes its attention read).  The first
-        token samples from the logits at each row's last REAL suffix
-        position.  One compilation per padded suffix-bucket length."""
-        nb = ids.shape[0]
-        with _disp.program_part(_PREFILL, "wave_rows"), \
-                bind_params(self._bind, self._prepare(params)):
-            logits, cache = self.model.decode_step(
-                ids, cache, prefix_lens, block_tables=tables)
-        with jax.named_scope("sample"):
-            last = logits[jnp.arange(nb), suffix_lens - 1]  # (nb, vocab)
-            tok = sample_tokens(last, key, temps, topk, topp)
-        return tok, cache
+        Rows part.  Row i holds request state at ``positions[i]``.  Plain:
+        every row advances one token.  ``spec``: ``tokens`` is the
+        (num_slots, k+1) window matrix ``[current, d_1..d_k]`` (pad columns
+        where the drafter had nothing), ``draft_ok`` the real-proposal mask,
+        and ONE forward scores every row's window at its own depth — q-depth
+        k+1 rides the q-tiled flash-decode path (``kernel_path_hint``
+        relabels the trace's dispatch counts ``op="spec_verify"``), so all
+        drafts of all slots cost a single pass of the weights — then
+        ``accept_draft_tokens`` keeps each row's longest verified prefix
+        plus the bonus token: greedy rows by the exact prefix-match rule,
+        sampled rows by rejection sampling against ``draft_probs`` (the
+        (s, k, vocab) proposal distributions: one-hot for deterministic
+        proposers, the draft model's softmax otherwise), so every committed
+        token is distributed exactly as plain sampling.  Row i writes K/V at
+        ``positions[i]..positions[i]+k``; the host commits only the accepted
+        prefix and never advances past it, so writes past an accept point
+        are dead cells the next steps overwrite before any mask can read
+        them (the stale-tail argument plain decode already relies on).  A
+        draft-free tick is the same program with all-pad windows.
 
-    def _mixed_step_impl(self, params, cache, tokens, positions, slot_mask,
-                         temps, topk, topp, cids, cpos, clen, cslot,
-                         ctemp, ctopk, ctopp, key):
-        """One MIXED step (chunked mode, contiguous cache): the decode
-        rows advance one token each AND one prompt chunk streams into its
-        slot's cache row — a single program, compiled exactly once, whose
-        token budget is ``num_slots + prefill_chunk`` every tick.
+        Where a row that is not decoding writes.  Paged: its table row is
+        all null, so the write lands in the null block (kv_cache.py's
+        convention; a spec row's pad columns past its chain land there too,
+        so a row near its reservation ceiling never allocates for drafts it
+        did not propose).  Contiguous, wave engine: junk at position 0 of an
+        idle row, which the next wave prefill rebuilds whole.  Contiguous,
+        cursor engine: the host steers the position to ``max_length`` and
+        the scatter drops out of bounds, because chunked prefill builds a
+        row incrementally and an idle write must be dropped, not absorbed.
 
-        Decode part: identical math to ``_step_impl``, but the host
-        steers every NON-decoding row's position to ``max_length`` so its
-        K/V scatter drops out of bounds instead of clobbering a row that
-        chunked prefill is mid-way through writing (the wave engine could
-        write junk at position 0 of idle rows because wave prefill
-        rebuilt the whole row afterwards; chunked prefill builds the row
-        incrementally, so idle writes must be dropped, not absorbed).
-
-        Chunk part: decode-at-depth of ``cids`` (one (1, chunk) row,
-        chunk size static) over the ``cslot`` cache row pulled out with a
-        dynamic slice and scattered back — per-row positions
-        ``cpos..cpos+chunk-1``, so pad-tail writes past the prompt land
-        at positions decode will overwrite before the mask can read them
-        (the wave-prefill padding argument), and a chunk-free tick rides
-        the same program with ``cpos = max_length`` (every write drops,
-        the row round-trips bit-identical).  The sampled ``ctok`` is the
+        Chunk part: decode-at-depth of ``cids`` (one (1, chunk) row, chunk
+        size static) at positions ``cpos..cpos+chunk-1``: paged, through the
+        slot's own (1, max_blocks) table row ``cdst`` straight into its
+        blocks (the rows part saw that slot as an all-null row); contiguous,
+        over the ``cdst`` cache row pulled out with a dynamic slice and put
+        back.  Pad-tail writes past the prompt land where decode overwrites
+        them before the mask can read them (the wave-prefill padding
+        argument); a chunk-free tick rides the same program with an all-null
+        table, or ``cpos = max_length`` (every write drops, the row
+        round-trips bit-identical).  The sampled chunk token is the
         request's FIRST token when this chunk completes the prompt; the
-        host discards it otherwise."""
-        prep = self._prepare(params)
-        with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, prep):
-            logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions)
-        with jax.named_scope("sample"):
-            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        with _disp.program_part(_STEP, "prompt_chunk"), \
-                bind_params(self._bind, prep):
-            row = _slot_row(cache, cslot)
-            clogits, row = self.model.decode_step(
-                cids, row, cpos[None])          # (1,) per-row position
-            cache = _slot_row_update(cache, row, cslot)
-        with jax.named_scope("sample_chunk"):
-            ctok = sample_tokens(clogits[0, clen - 1][None],
-                                 jax.random.fold_in(key, 1),
-                                 ctemp, ctopk, ctopp)[0]
-        return nxt, ctok, cache
+        host discards it otherwise.  A prefilling slot is inactive until
+        its cursor completes, so the two parts never touch the same row.
 
-    def _mixed_step_impl_paged(self, params, cache, tokens, positions,
-                               tables, slot_mask, temps, topk, topp,
-                               cids, cpos, clen, ctable,
-                               ctemp, ctopk, ctopp, key):
-        """Paged twin of ``_mixed_step_impl``: the chunk writes scatter
-        straight into the slot's blocks through its own (1, max_blocks)
-        table row (the decode part sees the prefilling slot as an
-        all-null-table row, so its idle write lands in the null block),
-        and a chunk-free tick passes the all-null table itself.  No
-        row slicing — the pool IS the cache for both parts."""
-        prep = self._prepare(params)
-        collect, real = self._experts(lambda: slot_mask[:, None])
-        with _disp.program_part(_STEP, "decode_rows"), \
-                bind_params(self._bind, prep), collect as load:
-            logits, cache = self.model.decode_step(
-                tokens[:, None], cache, positions, block_tables=tables,
-                **real)
-        with jax.named_scope("sample"):
-            nxt = sample_tokens(logits[:, -1], key, temps, topk, topp)
-            nxt = jnp.where(slot_mask, nxt, jnp.int32(self.pad_token_id))
-        collect, real = self._experts(
-            lambda: (jnp.arange(cids.shape[1]) < clen)[None])
-        with _disp.program_part(_STEP, "prompt_chunk"), \
-                bind_params(self._bind, prep), collect as cload:
-            clogits, cache = self.model.decode_step(
-                cids, cache, cpos[None], block_tables=ctable, **real)
-        with jax.named_scope("sample_chunk"):
-            ctok = sample_tokens(clogits[0, clen - 1][None],
-                                 jax.random.fold_in(key, 1),
-                                 ctemp, ctopk, ctopp)[0]
-        if load:        # part 0: the decode rows' load, part 1: the chunk's
-            return (nxt, ctok, jnp.stack([jnp.stack(load),
-                                          jnp.stack(cload)]), cache)
-        return nxt, ctok, cache
+        Returns ``_step_outputs``: a model with expert layers adds their
+        load, (parts, expert layers, held + 1), before the cache."""
+        paged, chunked, spec = self.paged, self.chunked, self.spec
+        names = [o.name for o in self._step_table]
 
-    # -- jitted device programs: speculative decoding ----------------------
+        def step(params, cache, *operands):
+            a = dict(zip(names, operands))
+            prep = self._prepare(params)
+            mask, key = a["slot_mask"], a["key"]
+            knobs = (a["temps"], a["topk"], a["topp"])
+            at = {"block_tables": a["tables"]} if paged else {}
+            if spec:
+                collect, real = self._experts(
+                    lambda: mask[:, None] & jnp.concatenate(
+                        [jnp.ones_like(mask)[:, None], a["draft_ok"]], 1))
+                with _disp.program_part(_STEP, "verify_rows"), \
+                        bind_params(self._bind, prep), collect as load, \
+                        _disp.kernel_path_hint("spec_verify"):
+                    logits, cache = self.model.decode_step(
+                        a["tokens"], cache, a["positions"], **at, **real)
+                with jax.named_scope("accept"):
+                    out, n_acc = accept_draft_tokens(
+                        logits, a["tokens"][:, 1:], a["draft_ok"], key,
+                        *knobs, pad_token_id=self.pad_token_id,
+                        draft_probs=a["draft_probs"])
+                outs = [jnp.where(mask[:, None], out,
+                                  jnp.int32(self.pad_token_id)), n_acc]
+            else:
+                collect, real = self._experts(lambda: mask[:, None])
+                with _disp.program_part(_STEP, "decode_rows"), \
+                        bind_params(self._bind, prep), collect as load:
+                    logits, cache = self.model.decode_step(
+                        a["tokens"][:, None], cache, a["positions"], **at,
+                        **real)
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits[:, -1], key, *knobs)
+                    outs = [jnp.where(mask, nxt,
+                                      jnp.int32(self.pad_token_id))]
+            if not chunked:
+                # a model with routed experts: their load rides out
+                return (*outs, *([jnp.stack(load)[None]] if load else ()),
+                        cache)
+            cids, cpos, clen, cdst = (a[n] for n in
+                                      ("cids", "cpos", "clen", "cdst"))
+            collect, real = self._experts(
+                lambda: (jnp.arange(cids.shape[1]) < clen)[None])
+            with _disp.program_part(_STEP, "prompt_chunk"), \
+                    bind_params(self._bind, prep), collect as cload:
+                if paged:       # the pool IS the cache for both parts
+                    clogits, cache = self.model.decode_step(
+                        cids, cache, cpos[None], block_tables=cdst, **real)
+                else:
+                    row = _slot_row(cache, cdst)
+                    clogits, row = self.model.decode_step(
+                        cids, row, cpos[None], **real)
+                    cache = _slot_row_update(cache, row, cdst)
+            with jax.named_scope("sample_chunk"):
+                outs.append(sample_tokens(
+                    clogits[0, clen - 1][None], jax.random.fold_in(key, 1),
+                    a["ctemps"], a["ctopk"], a["ctopp"])[0])
+            if load:    # part 0: the rows' load, part 1: the chunk's
+                outs.append(jnp.stack([jnp.stack(load), jnp.stack(cload)]))
+            return (*outs, cache)
 
-    def _verify_window(self, params, cache, tokens, positions, draft_ok,
-                       draft_probs, temps, topk, topp, key,
-                       block_tables=None):
-        """The shared verify core of every spec step: score each row's
-        (k+1)-token window ``[current, d_1..d_k]`` at its own depth in
-        ONE forward — q-depth k+1 rides the q-tiled flash-decode path,
-        per-row positions as scalar-prefetch, so all drafts of all slots
-        cost a single pass of the weights — then keep each row's longest
-        verified prefix plus the bonus token (models/generation.py
-        ``accept_draft_tokens``).  ``draft_probs`` is the (s, k, vocab)
-        proposal-distribution stack q: greedy rows keep the exact
-        prefix-match rule, sampled rows run the rejection-sampling
-        acceptance against q (one-hot for deterministic proposers,
-        the draft model's softmax otherwise) so every committed token
-        is distributed exactly as plain sampling.  The
-        kernel_path_hint relabels this trace's dispatch counts as
-        ``op="spec_verify"``."""
-        with _disp.program_part(_STEP, "verify_rows"), \
-                bind_params(self._bind, self._prepare(params)):
-            with _disp.kernel_path_hint("spec_verify"):
-                logits, cache = self.model.decode_step(
-                    tokens, cache, positions, block_tables=block_tables)
-        with jax.named_scope("accept"):
-            out, n_acc = accept_draft_tokens(
-                logits, tokens[:, 1:], draft_ok, key, temps, topk, topp,
-                pad_token_id=self.pad_token_id, draft_probs=draft_probs)
-        return out, n_acc, cache
+        step.__name__ = step.__qualname__ = (
+            "_" + "spec_" * spec + "mixed_" * chunked + "step_impl"
+            + "_paged" * paged)
+        return step
 
-    def _spec_step_impl(self, params, cache, tokens, positions, slot_mask,
-                        draft_ok, draft_probs, temps, topk, topp, key):
-        """Speculative twin of ``_step_impl``: ``tokens`` is the
-        (num_slots, k+1) window matrix (pad columns where the drafter
-        had nothing), ``draft_ok`` the (num_slots, k) real-proposal
-        mask.  Row i writes K/V at ``positions[i]..positions[i]+k`` —
-        the host commits only the accepted prefix and never advances
-        past it, so rejected-suffix writes are dead cells the next steps
-        overwrite before any mask can read them (the same stale-tail
-        argument plain decode already relies on).  Compiled exactly
-        once; a draft-free tick is the same program with all-pad
-        windows."""
-        out, n_acc, cache = self._verify_window(
-            params, cache, tokens, positions, draft_ok, draft_probs,
-            temps, topk, topp, key)
-        out = jnp.where(slot_mask[:, None], out,
-                        jnp.int32(self.pad_token_id))
-        return out, n_acc, cache
+    def _prefill_program(self):
+        """The Python body of the wave engine's prefill program
+        (``_prefill_impl[_paged]``), one compilation per padded bucket
+        length; each row's first token samples from the logits at its last
+        REAL position (``lens``).
 
-    def _spec_step_impl_paged(self, params, cache, tokens, positions,
-                              tables, slot_mask, draft_ok, draft_probs,
-                              temps, topk, topp, key):
-        """Paged twin of ``_spec_step_impl``: the block table rides
-        along; the host pre-grows each row's chain over its REAL draft
-        span (and COW-privatises it), while pad-column writes past the
-        chain steer to the null block — so a row near its reservation
-        ceiling never allocates for drafts it didn't propose."""
-        out, n_acc, cache = self._verify_window(
-            params, cache, tokens, positions, draft_ok, draft_probs,
-            temps, topk, topp, key, block_tables=tables)
-        out = jnp.where(slot_mask[:, None], out,
-                        jnp.int32(self.pad_token_id))
-        return out, n_acc, cache
+        Contiguous: the prompts run through the static-``pos=0`` path
+        (flash-eligible) on a fresh ``prefill_batch``-row cache, and the
+        finished rows are scattered into their slots.  Dummy rows carry
+        ``slot_id == num_slots``; the ``mode="drop"`` scatter discards them.
 
-    def _spec_mixed_step_impl(self, params, cache, tokens, positions,
-                              slot_mask, draft_ok, draft_probs, temps,
-                              topk, topp, cids, cpos, clen, cslot,
-                              ctemp, ctopk, ctopp, key):
-        """Chunked × speculative (contiguous): ``_mixed_step_impl`` with
-        the decode half replaced by the verify window.  The chunk half
-        is untouched — a prefilling slot is inactive (its spec window
-        suspended) until its cursor completes, so the two halves never
-        touch the same row."""
-        out, n_acc, cache = self._verify_window(
-            params, cache, tokens, positions, draft_ok, draft_probs,
-            temps, topk, topp, key)
-        out = jnp.where(slot_mask[:, None], out,
-                        jnp.int32(self.pad_token_id))
-        with _disp.program_part(_STEP, "prompt_chunk"), \
-                bind_params(self._bind, self._prepare(params)):
-            row = _slot_row(cache, cslot)
-            clogits, row = self.model.decode_step(cids, row, cpos[None])
-            cache = _slot_row_update(cache, row, cslot)
-        with jax.named_scope("sample_chunk"):
-            ctok = sample_tokens(clogits[0, clen - 1][None],
-                                 jax.random.fold_in(key, 1),
-                                 ctemp, ctopk, ctopp)[0]
-        return out, n_acc, ctok, cache
+        Paged: each row computes ONLY its prompt suffix — the tokens its
+        prefix-cache match did not cover — as a decode-at-depth over the
+        pool (per-row ``pos`` = adopted prefix length; the adopted blocks
+        are read, not recomputed).  Writes scatter straight into the rows'
+        own blocks (the null block absorbs bucket padding, and rows admitted
+        in the same wave see each other's writes because every layer's
+        scatter precedes its attention read)."""
+        paged = self.paged
+        names = [o.name for o in self._prefill_table]
 
-    def _spec_mixed_step_impl_paged(self, params, cache, tokens,
-                                    positions, tables, slot_mask,
-                                    draft_ok, draft_probs, temps, topk,
-                                    topp, cids, cpos, clen, ctable,
-                                    ctemp, ctopk, ctopp, key):
-        """Chunked × speculative (paged): verify window over the pool,
-        then the chunk half exactly as ``_mixed_step_impl_paged``."""
-        out, n_acc, cache = self._verify_window(
-            params, cache, tokens, positions, draft_ok, draft_probs,
-            temps, topk, topp, key, block_tables=tables)
-        out = jnp.where(slot_mask[:, None], out,
-                        jnp.int32(self.pad_token_id))
-        with _disp.program_part(_STEP, "prompt_chunk"), \
-                bind_params(self._bind, self._prepare(params)):
-            clogits, cache = self.model.decode_step(
-                cids, cache, cpos[None], block_tables=ctable)
-        with jax.named_scope("sample_chunk"):
-            ctok = sample_tokens(clogits[0, clen - 1][None],
-                                 jax.random.fold_in(key, 1),
-                                 ctemp, ctopk, ctopp)[0]
-        return out, n_acc, ctok, cache
+        def prefill(params, cache, *operands):
+            a = dict(zip(names, operands))
+            ids = a["ids"]
+            nb = ids.shape[0]
+            if paged:
+                rows, pos, at = cache, a["prefix_lens"], {
+                    "block_tables": a["tables"]}
+            else:
+                rows, pos, at = init_kv_cache(
+                    self.config, nb, self.max_length,
+                    quantized=self.quantized), 0, {}
+            with _disp.program_part(_PREFILL, "wave_rows"), \
+                    bind_params(self._bind, self._prepare(params)):
+                logits, rows = self.model.decode_step(ids, rows, pos, **at)
+            with jax.named_scope("sample"):
+                last = logits[jnp.arange(nb), a["lens"] - 1]  # (nb, vocab)
+                tok = sample_tokens(last, a["key"], a["temps"], a["topk"],
+                                    a["topp"])
+            if paged:
+                return tok, rows
+            # leaf-wise slot scatter (the int8 cache is a {kv, scale} pytree
+            # with batch at axis 2 in both leaves; the fresh sub-cache's zero
+            # scales reset the reused rows' quantization state for free)
+            return tok, jax.tree_util.tree_map(
+                lambda c, s: c.at[:, :, a["slot_ids"]].set(s, mode="drop"),
+                cache, rows)
+
+        prefill.__name__ = prefill.__qualname__ = (
+            "_prefill_impl" + "_paged" * paged)
+        return prefill
 
     # -- public API --------------------------------------------------------
 
@@ -1693,10 +1656,6 @@ class ServingEngine:
                 _sa.enforce(self.lint_step(),
                             context=f"serving.step engine={self._eid}")
         with self._tracer.span("serving.step", tick=self._ticks):
-            if self.chunked:
-                return self._step_inner_chunked()
-            if self.spec:
-                return self._step_inner_spec()
             return self._step_inner()
 
     def _grow_row_for_writes(self, i: int, last_pos: int):
@@ -1977,6 +1936,13 @@ class ServingEngine:
         assert best is not None
         return best[1], best[2]
 
+    def _free_slots(self) -> List[int]:
+        """The slots a request can enter.  The cursor engine's mid-prefill
+        slot owns a kv chain but no ``_Slot`` yet — it is NOT free."""
+        busy = -1 if self._prefill is None else self._prefill.slot
+        return [i for i, s in enumerate(self._slots)
+                if s is None and i != busy]
+
     def _service_swap_resumes(self):
         """Admission preamble: restore swapped-out requests (highest
         priority, then oldest, first) into free slots whenever the pool
@@ -1985,11 +1951,7 @@ class ServingEngine:
         swap-in compose without ever touching the step program."""
         while self._swap_resume:
             entry = self._swap_resume[0]
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            if self._prefill is not None:
-                # chunked mode: the mid-prefill slot owns a kv chain but
-                # no _Slot yet — it is NOT free
-                free = [i for i in free if i != self._prefill.slot]
+            free = self._free_slots()
             if not free:
                 return
             si = free[0]
@@ -2005,17 +1967,10 @@ class ServingEngine:
             req = entry.req
             # restore the EXACT pre-preemption slot state: mirrors,
             # table row, decode budget, original TTFT clock
-            self._drafter_reset(si)
-            self._slots[si] = _Slot(req.request_id, entry.remaining,
-                                    t_first=entry.t_first,
-                                    prompt=req.prompt, req=req)
-            self._active[si] = True
-            self._tokens[si] = entry.last_token
-            self._positions[si] = entry.position
-            self._temps[si] = req.sampling.temperature
-            self._topk[si] = req.sampling.top_k
-            self._topp[si] = req.sampling.top_p
-            self._tables[si] = self.kv.table_row(si, self.max_blocks)
+            self._seat(si, _Slot(req.request_id, entry.remaining,
+                                 t_first=entry.t_first, prompt=req.prompt,
+                                 req=req),
+                       entry.last_token, entry.position, req.sampling)
             # re-register the prompt so prefix sharing resumes (the
             # round trip preserved per-block dtype tags, so mixed-mode
             # re-registration never re-demotes an int8 block)
@@ -2023,11 +1978,7 @@ class ServingEngine:
                                          int(req.prompt.size))
             self._rlog.event(req.uid, "swapped_in", engine=self._eid,
                              slot=int(si), blocks=int(got))
-            self._rlog.event(req.uid, "resumed", engine=self._eid,
-                             mode="swap", slot=int(si))
-            self._f_resumed.labels(engine=self._eid, mode="swap").inc()
-            self._tracer.instant("serving.resumed", rid=req.request_id,
-                                 mode="swap", slot=int(si))
+            self._note_resumed(req, "swap", si)
 
     def preempt_signature(self) -> str:
         """SHA-256 over the ordered preemption-decision log (victim,
@@ -2127,9 +2078,7 @@ class ServingEngine:
                 "import_request requires the paged cache "
                 "(ServingEngine(..., paged=True))")
         self._block_movers()
-        free = [i for i, s in enumerate(self._slots) if s is None]
-        if self._prefill is not None:
-            free = [i for i in free if i != self._prefill.slot]
+        free = self._free_slots()
         if not free:
             return None
         si = free[0]
@@ -2163,18 +2112,12 @@ class ServingEngine:
         # decode budget; the TPOT clock restarts on this engine's clock
         # (cross-process wall clocks don't compare — BASELINE.md
         # "Multi-host accounting conventions")
-        self._drafter_reset(si)
-        self._slots[si] = _Slot(rid, int(record["remaining"]),
-                                t_first=(self._clock()
-                                         if record["had_first"] else 0.0),
-                                prompt=prompt, req=req)
-        self._active[si] = True
-        self._tokens[si] = int(record["last_token"])
-        self._positions[si] = int(record["position"])
-        self._temps[si] = req.sampling.temperature
-        self._topk[si] = req.sampling.top_k
-        self._topp[si] = req.sampling.top_p
-        self._tables[si] = self.kv.table_row(si, self.max_blocks)
+        self._seat(si, _Slot(rid, int(record["remaining"]),
+                             t_first=(self._clock()
+                                      if record["had_first"] else 0.0),
+                             prompt=prompt, req=req),
+                   int(record["last_token"]), int(record["position"]),
+                   req.sampling)
         self.kv.register_prompt_upto(si, prompt, int(prompt.size))
         nbytes = int(record.get("payload_bytes", 0))
         self._m_mig_in.inc()
@@ -2250,50 +2193,160 @@ class ServingEngine:
         return SAMPLE_PATHS[i]
 
     def _step_inner(self) -> List[int]:
+        """THE tick, for every layout: admit (waves into free slots, or
+        the cursor engine's one prompt cursor) → draft (spec) → grow
+        (paged) → build inputs → dispatch → readback → advance the rows →
+        advance the chunk (if one ran) → queued demotions.  Device work is
+        ONE step-program call, so a long prompt under the cursor engine
+        costs every in-flight decode a bounded, chunk-sized bump per tick
+        instead of a whole-prompt stall; a verify step commits 1..k+1
+        tokens a row for one pass of the weights."""
         span = self._tracer.span
+        paged, chunked, spec = self.paged, self.chunked, self.spec
         with span(_ADMIT):
-            finished = self._admit()
+            finished = self._admit_chunked() if chunked else self._admit()
             occ = int(self._active.sum())
             self._set_occupancy(occ)
-        if not occ:
+            pf = self._prefill
+            if chunked:
+                self._m_chunk_queue.observe(self._pending_chunks())
+            # decode-priority policy: while decodes are active, pending
+            # chunks run on alternate ticks only (odd _ticks), halving the
+            # prompt-ingest rate to shave the mixed-step TPOT bump
+            do_chunk = pf is not None and (
+                self._chunk_policy == "prefill" or occ == 0
+                or self._ticks % 2 == 1)
+        if not occ and not do_chunk:
             return finished
         self._ticks += 1
+        own, chunk, clen, draft_ok = {"key": self._ticks}, None, 0, None
+        if do_chunk:
+            clen = min(self.prefill_chunk, pf.req.prompt.size - pf.cursor)
+            cpos, cslot = pf.cursor, pf.slot
+        elif chunked:
+            # chunk-free tick, same compiled program: contiguous writes
+            # drop past max_length, paged writes land in the null block
+            clen, cslot, cpos = 1, 0, 0 if paged else self.max_length
+        if spec:
+            # the draft builds the verify window, and growth below needs its
+            # real span: an input-building phase of its own, before the grow.
+            # A prefilling slot is inactive until its cursor completes, so
+            # its window is suspended by construction.  The draft model's
+            # seed is this tick's number on the cursor engine and one less
+            # on a wave engine: replays of either are byte-stable on it.
+            with span(_BUILD), span("serving.draft"):
+                drafts, draft_ok, own["draft_probs"] = self._propose_drafts(
+                    seed=self._ticks - (not chunked))
+                own["tokens"] = np.concatenate(
+                    [self._tokens[:, None], drafts], axis=1)
+                own["draft_ok"] = draft_ok
         t0 = self._clock()
-        with span("serving.decode", slots=occ,
-                  sample_path=self._note_sample_path(
-                      (self._temps, self._topk, self._topp))):
-            if self.paged:
+        knobs = [(self._temps, self._topk, self._topp)]
+        if do_chunk:         # the chunk's one row, as build_inputs fills it
+            sp = pf.req.sampling
+            knobs.append((np.float32(sp.temperature), np.int32(sp.top_k),
+                          np.float32(sp.top_p)))
+        rows_span = span(
+            "serving.verify" if spec else "serving.decode", slots=occ,
+            sample_path=self._note_sample_path(*knobs),
+            **({"drafted": int(draft_ok.sum())} if spec else {}))
+        chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
+                           tokens=clen)
+                      if do_chunk else contextlib.nullcontext())
+        with rows_span, chunk_span:
+            if paged:
                 with span(_GROW):
                     for i, slot in enumerate(self._slots):
                         if slot is None:
                             continue
-                        # this tick writes K/V at positions[i]
+                        # this tick writes K/V at positions[i] and, spec,
+                        # over the row's REAL draft span only: pad-column
+                        # writes past the chain steer to the null block, so
+                        # no block is ever allocated for a draft that was
+                        # never proposed
                         self._grow_row_for_writes(
-                            i, int(self._positions[i]))
+                            i, int(self._positions[i])
+                            + (int(draft_ok[i].sum()) if spec else 0))
+                    if do_chunk:
+                        # grow the chain to cover this chunk's real
+                        # tokens; pad-tail positions fall past the chain
+                        # and steer to the null block (the admission
+                        # reservation makes the growth infallible)
+                        self.kv.ensure_capacity(cslot, cpos + clen - 1)
+                        cdst = self.kv.table_row(cslot,
+                                                 self.max_blocks)[None]
+                    elif chunked:
+                        cdst = np.zeros((1, self.max_blocks), np.int32)
                     self._flush_fresh_scales()
-            with span(_BUILD):
-                tables = ((jnp.asarray(self._tables),) if self.paged
-                          else ())
-                args = (jnp.asarray(self._tokens),
-                        jnp.asarray(self._positions), *tables,
-                        jnp.asarray(self._active), jnp.asarray(self._temps),
-                        jnp.asarray(self._topk), jnp.asarray(self._topp),
-                        jax.random.fold_in(self._base_key, self._ticks))
-            with span(_DISPATCH):
-                nxt, *load, self._cache = self._step_fn(
-                    self._params, self._cache, *args)
-            with span(_READBACK):
-                if load:                 # routed experts: one transfer
-                    nxt, *load = jax.device_get((nxt, *load))
-                nxt = np.asarray(nxt)    # the tick's one host sync
+            if chunked:
+                chunk = (pf if do_chunk else None, cpos, clen,
+                         cdst if paged else cslot)
+            out = iter(self._device_step(own, chunk))
         now = self._clock()
         with span(_ADVANCE):
             self._m_step_ms.observe((now - t0) * 1e3)
-            self._perf_tick((now - t0) * 1e3, occ)
+            self._perf_tick((now - t0) * 1e3, occ,
+                            chunk_tokens=clen if do_chunk else 0)
+            toks = next(out)
+            n_acc = next(out) if spec else None
+            ctok = next(out) if chunked else None
             if self._model_counters:
-                self._note_model_counters(load)
-            finished.extend(self._advance_decode(nxt, now))
+                # a chunk-free tick ran the chunk's rows on padding
+                self._note_model_counters(
+                    [x[:1 + int(do_chunk)] for x in out])
+            finished.extend(
+                self._advance_decode_spec(toks, n_acc, draft_ok, now)
+                if spec else self._advance_decode(toks, now))
+            if do_chunk:
+                finished.extend(
+                    self._advance_chunk(pf, clen, int(ctok), now))
+            self._apply_demotions()
         return finished
+
+    def _upload(self, table, own) -> List:
+        """The upload of ``serving.build_inputs``, for either program:
+        one walk of its operand table, one host->device transfer an
+        operand, each strongly typed as the table says (``jnp.int32`` chunk
+        scalars, a typed key folded from the tick's number).  ROADMAP S3
+        (one packed upload) is a change to this function."""
+        return [put(src if src.__class__ is np.ndarray else own[src])
+                for _, _, _, src, put, _ in table]
+
+    def _device_step(self, own, chunk) -> List[np.ndarray]:
+        """The tick's device seam: build and upload the step program's
+        operands, call it, fetch what it returned but the cache (the tick's
+        ONE host sync), as host arrays in ``_step_outputs`` order.  ``own``
+        holds the tick's own operand values by name (the rest are mirrors);
+        ``chunk`` the cursor engine's ``(cursor or None, start, tokens,
+        destination)``.  ``fleet_sim.SimEngine`` overrides this and nothing
+        else of the tick."""
+        span = self._tracer.span
+        with span(_BUILD):
+            if chunk is not None:
+                pf, own["cpos"], clen, own["cdst"] = chunk
+                cids = np.full((1, self.prefill_chunk), self.pad_token_id,
+                               np.int32)
+                sp = SamplingParams() if pf is None else pf.req.sampling
+                if pf is not None:
+                    cids[0, :clen] = pf.req.prompt[pf.cursor:pf.cursor + clen]
+                own.update(
+                    cids=cids, clen=clen,
+                    ctemps=np.full((1,), sp.temperature, np.float32),
+                    ctopk=np.full((1,), sp.top_k, np.int32),
+                    ctopp=np.full((1,), sp.top_p, np.float32))
+                if not self.paged:
+                    # non-decoding rows (idle or mid-prefill) write at
+                    # max_length so the scatter drops them — chunked
+                    # prefill owns those rows' contents now
+                    own["positions"] = np.where(
+                        self._active, self._positions,
+                        self.max_length).astype(np.int32)
+            args = self._upload(self._step_table, own)
+        with span(_DISPATCH):
+            *out, self._cache = self._step_fn(self._params, self._cache,
+                                              *args)
+        with span(_READBACK):
+            return jax.device_get(out)
 
     def _advance_decode(self, nxt: np.ndarray, now: float) -> List[int]:
         """Per-slot bookkeeping after a decode/mixed step's token fetch."""
@@ -2315,8 +2368,8 @@ class ServingEngine:
 
     # -- speculative-decode scheduler (verify steps) -----------------------
 
-    def _propose_drafts(self) -> Tuple[np.ndarray, np.ndarray,
-                                       np.ndarray]:
+    def _propose_drafts(self, seed: int) -> Tuple[np.ndarray, np.ndarray,
+                                                  np.ndarray]:
         """The draft phase: ask each slot's drafter (engine default or
         the request's ``submit(drafter=...)`` override) for up to
         ``spec_k`` tokens, capped so an accepted window can never
@@ -2374,7 +2427,7 @@ class ServingEngine:
                 self._spec_m(kinds[i])[0].inc(m)
         for did, rows in device_jobs.items():
             dd, dp = device_objs[did].propose_batch(
-                rows, self._temps, seed=self._ticks)
+                rows, self._temps, seed=seed)
             for i in rows:
                 m = int(caps[i])
                 drafts[i, :m] = dd[i, :m]
@@ -2384,67 +2437,6 @@ class ServingEngine:
                 self._spec_m(kinds[i])[0].inc(m)
         self._tick_drafter_kind = kinds
         return drafts, ok, probs
-
-    def _step_inner_spec(self) -> List[int]:
-        """One speculative tick: wave admission unchanged, then draft
-        (host n-gram per slot, or ONE batched draft-model step) and run
-        ONE verify step over every slot's (k+1)-token window.  Each row
-        commits 1..k+1 tokens; the weight stream — the b=1 bound
-        BENCH_DECODE.json proves — is paid once either way."""
-        span = self._tracer.span
-        with span(_ADMIT):
-            finished = self._admit()
-            occ = int(self._active.sum())
-            self._set_occupancy(occ)
-        if not occ:
-            return finished
-        # the draft builds the verify window, and growth below needs its
-        # real span: an input-building phase of its own, before the grow
-        with span(_BUILD), span("serving.draft"):
-            drafts, draft_ok, draft_probs = self._propose_drafts()
-            window = np.concatenate([self._tokens[:, None], drafts],
-                                    axis=1)
-        self._ticks += 1
-        t0 = self._clock()
-        with span("serving.verify", slots=occ,
-                  drafted=int(draft_ok.sum()),
-                  sample_path=self._note_sample_path(
-                      (self._temps, self._topk, self._topp))):
-            if self.paged:
-                with span(_GROW):
-                    for i, slot in enumerate(self._slots):
-                        if slot is None:
-                            continue
-                        # grow/privatise over the row's REAL draft span
-                        # only: pad-column writes past the chain steer to
-                        # the null block, so no block is ever allocated
-                        # for a draft that was never proposed
-                        self._grow_row_for_writes(
-                            i, int(self._positions[i])
-                            + int(draft_ok[i].sum()))
-                    self._flush_fresh_scales()
-            with span(_BUILD):
-                tables = ((jnp.asarray(self._tables),) if self.paged
-                          else ())
-                args = (jnp.asarray(window), jnp.asarray(self._positions),
-                        *tables, jnp.asarray(self._active),
-                        jnp.asarray(draft_ok), jnp.asarray(draft_probs),
-                        jnp.asarray(self._temps), jnp.asarray(self._topk),
-                        jnp.asarray(self._topp),
-                        jax.random.fold_in(self._base_key, self._ticks))
-            with span(_DISPATCH):
-                out, n_acc, self._cache = self._step_fn(
-                    self._params, self._cache, *args)
-            with span(_READBACK):
-                # the one host sync
-                out, n_acc = jax.device_get((out, n_acc))
-        now = self._clock()
-        with span(_ADVANCE):
-            self._m_step_ms.observe((now - t0) * 1e3)
-            self._perf_tick((now - t0) * 1e3, occ)
-            finished.extend(self._advance_decode_spec(
-                np.asarray(out), np.asarray(n_acc), draft_ok, now))
-        return finished
 
     def _advance_decode_spec(self, out: np.ndarray, n_acc: np.ndarray,
                              draft_ok: np.ndarray, now: float
@@ -2514,144 +2506,6 @@ class ServingEngine:
 
     # -- chunked-prefill scheduler (mixed steps) ---------------------------
 
-    def _step_inner_chunked(self) -> List[int]:
-        """One token-budget tick: admit the FIFO head into a free slot
-        (no prefill dispatched yet — just a cursor), then run ONE mixed
-        step carrying every decode row plus at most one
-        ``prefill_chunk``-token slice of the admitted prompt.  A long
-        prompt therefore costs a bounded latency bump per tick instead
-        of stalling every in-flight decode for its whole prefill."""
-        span = self._tracer.span
-        with span(_ADMIT):
-            finished = self._admit_chunked()
-            occ = int(self._active.sum())
-            self._set_occupancy(occ)
-            pf = self._prefill
-            self._m_chunk_queue.observe(self._pending_chunks())
-            # decode-priority policy: while decodes are active, pending
-            # chunks run on alternate ticks only (odd _ticks), halving the
-            # prompt-ingest rate to shave the mixed-step TPOT bump
-            do_chunk = pf is not None and (
-                self._chunk_policy == "prefill" or occ == 0
-                or self._ticks % 2 == 1)
-        if not occ and not do_chunk:
-            return finished
-        self._ticks += 1
-        ch = self.prefill_chunk
-        if do_chunk:
-            clen = min(ch, pf.req.prompt.size - pf.cursor)
-            cpos, cslot = pf.cursor, pf.slot
-        else:
-            # chunk-free tick, same compiled program: contiguous writes
-            # drop past max_length, paged writes land in the null block
-            clen, cslot = 1, 0
-            cpos = 0 if self.paged else self.max_length
-        if self.spec:
-            # spec × chunked: the decode half becomes the verify window.
-            # A prefilling slot is inactive until its cursor completes,
-            # so its spec window is suspended by construction.
-            with span(_BUILD), span("serving.draft"):
-                drafts, draft_ok, draft_probs = self._propose_drafts()
-                window = np.concatenate([self._tokens[:, None], drafts],
-                                        axis=1)
-        t0 = self._clock()
-        knobs = [(self._temps, self._topk, self._topp)]
-        if do_chunk:         # the chunk's one row, as build_inputs fills it
-            sp = pf.req.sampling
-            knobs.append((np.float32(sp.temperature), np.int32(sp.top_k),
-                          np.float32(sp.top_p)))
-        chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
-                           tokens=clen)
-                      if do_chunk else contextlib.nullcontext())
-        decode_span = span(
-            "serving.verify" if self.spec else "serving.decode",
-            slots=occ, sample_path=self._note_sample_path(*knobs))
-        with decode_span, chunk_span:
-            if self.paged:
-                with span(_GROW):
-                    for i, slot in enumerate(self._slots):
-                        if slot is None:
-                            continue
-                        last = int(self._positions[i])
-                        if self.spec:
-                            last += int(draft_ok[i].sum())
-                        self._grow_row_for_writes(i, last)
-                    if do_chunk:
-                        # grow the chain to cover this chunk's real
-                        # tokens; pad-tail positions fall past the chain
-                        # and steer to the null block (the admission
-                        # reservation makes the growth infallible)
-                        self.kv.ensure_capacity(cslot, cpos + clen - 1)
-                        ctable = self.kv.table_row(cslot,
-                                                   self.max_blocks)[None]
-                    else:
-                        ctable = np.zeros((1, self.max_blocks), np.int32)
-                    self._flush_fresh_scales()
-            with span(_BUILD):
-                # the chunk operand
-                cids = np.full((1, ch), self.pad_token_id, np.int32)
-                ctemp = np.zeros((1,), np.float32)
-                ctopk = np.zeros((1,), np.int32)
-                ctopp = np.ones((1,), np.float32)
-                if do_chunk:
-                    cids[0, :clen] = pf.req.prompt[
-                        pf.cursor:pf.cursor + clen]
-                    sp = pf.req.sampling
-                    ctemp[0], ctopk[0], ctopp[0] = (
-                        sp.temperature, sp.top_k, sp.top_p)
-                if self.paged:
-                    pos = self._positions
-                    tables = (jnp.asarray(self._tables),)
-                    cdst = jnp.asarray(ctable)
-                else:
-                    # non-decoding rows (idle or mid-prefill) write at
-                    # max_length so the scatter drops them — chunked
-                    # prefill owns those rows' contents now
-                    pos = np.where(self._active, self._positions,
-                                   self.max_length).astype(np.int32)
-                    tables = ()
-                    cdst = jnp.int32(cslot)
-                head = ((jnp.asarray(window), jnp.asarray(pos), *tables,
-                         jnp.asarray(self._active), jnp.asarray(draft_ok),
-                         jnp.asarray(draft_probs))
-                        if self.spec else
-                        (jnp.asarray(self._tokens), jnp.asarray(pos),
-                         *tables, jnp.asarray(self._active)))
-                args = (*head, jnp.asarray(self._temps),
-                        jnp.asarray(self._topk), jnp.asarray(self._topp),
-                        jnp.asarray(cids), jnp.int32(cpos),
-                        jnp.int32(clen), cdst, jnp.asarray(ctemp),
-                        jnp.asarray(ctopk), jnp.asarray(ctopp),
-                        jax.random.fold_in(self._base_key, self._ticks))
-            with span(_DISPATCH):
-                res = self._step_fn(self._params, self._cache, *args)
-            with span(_READBACK):      # the one sync
-                if self.spec:
-                    out, n_acc, ctok, self._cache = res
-                    out, n_acc, ctok = jax.device_get((out, n_acc, ctok))
-                else:
-                    nxt, ctok, *load, self._cache = res
-                    nxt, ctok, *load = jax.device_get((nxt, ctok, *load))
-                    # a chunk-free tick ran the chunk's rows on padding
-                    load = [x[:1 + int(do_chunk)] for x in load]
-        now = self._clock()
-        with span(_ADVANCE):
-            self._m_step_ms.observe((now - t0) * 1e3)
-            self._perf_tick((now - t0) * 1e3, occ,
-                            chunk_tokens=clen if do_chunk else 0)
-            if self._model_counters and not self.spec:
-                self._note_model_counters(load)
-            if self.spec:
-                finished.extend(self._advance_decode_spec(
-                    np.asarray(out), np.asarray(n_acc), draft_ok, now))
-            else:
-                finished.extend(self._advance_decode(np.asarray(nxt), now))
-            if do_chunk:
-                finished.extend(
-                    self._advance_chunk(pf, clen, int(ctok), now))
-            self._apply_demotions()
-        return finished
-
     def _admit_chunked(self) -> List[int]:
         """Move the FIFO head into a free slot as a partially-prefilled
         request — a cursor, not a prefill dispatch.  One prompt streams
@@ -2663,7 +2517,7 @@ class ServingEngine:
         if (self._prefill is not None
                 or not (self._resume_q or self._queue)):
             return []
-        free = [i for i, s in enumerate(self._slots) if s is None]
+        free = self._free_slots()
         if not free:
             return []
         src, req = self._next_admit()
@@ -2675,27 +2529,9 @@ class ServingEngine:
             self._defer(req)
             return []
         si = free[0]
-        m = 0
-        if self.paged:
-            got = self.kv.admit(si, req.prompt, req.prompt.size,
-                                req.max_new_tokens, chunked=True)
-            while got is None and self._try_preempt(
-                    priority=req.priority, rid=req.request_id,
-                    blocked_ticks=req.blocked_ticks):
-                got = self.kv.admit(si, req.prompt, req.prompt.size,
-                                    req.max_new_tokens, chunked=True)
-            if got is None:          # pool full: wait for retirements
-                self._m_blocked.inc()
-                self._tracer.instant("serving.admission_blocked",
-                                     rid=req.request_id)
-                req.blocked_ticks += 1
-                if req.blocked_ticks == 1:
-                    # the preemption-relevant wait: log once per wait
-                    # episode, not per blocked tick
-                    self._rlog.event(req.uid, "admission_wait",
-                                     engine=self._eid, reason="pool_full")
-                return []
-            m = got                  # adopted prefix tokens skip compute
+        m = self._pool_admit(si, req) if self.paged else 0
+        if m is None:
+            return []
         # remove by IDENTITY: a preemption inside the retry loop may
         # have re-ordered the resume queue under us
         src.remove(req)
@@ -2731,53 +2567,11 @@ class ServingEngine:
             # register the now-written full blocks for prefix sharing —
             # never earlier: an unwritten block must not satisfy a lookup
             self.kv.register_prompt_upto(pf.slot, pf.req.prompt, pf.cursor)
-        plen = int(pf.req.prompt.size)
-        if pf.cursor < plen:
+        if pf.cursor < pf.req.prompt.size:
             return []
-        si, req = pf.slot, pf.req
         self._prefill = None
-        ri = req.resume
-        if ri is not None:
-            # recompute resume (chunked): discard the re-sampled token,
-            # force the last committed one back, restore the original
-            # decode budget / TTFT clock — see _prefill_wave_paged
-            first = ri.last_token
-            slot = _Slot(req.request_id, ri.remaining, t_first=ri.t_first,
-                         prompt=ri.orig.prompt, req=ri.orig)
-        else:
-            first = ctok
-            slot = _Slot(req.request_id, req.max_new_tokens - 1,
-                         t_first=now, prompt=req.prompt, req=req)
-        self._drafter_reset(si)
-        self._slots[si] = slot
-        self._active[si] = True
-        self._tokens[si] = first
-        self._positions[si] = plen
-        self._temps[si] = req.sampling.temperature
-        self._topk[si] = req.sampling.top_k
-        self._topp[si] = req.sampling.top_p
-        if self.paged:
-            self._tables[si] = self.kv.table_row(si, self.max_blocks)
-        if ri is not None:
-            self._rlog.event(req.uid, "resumed", engine=self._eid,
-                             mode="recompute", slot=int(si))
-            self._f_resumed.labels(engine=self._eid,
-                                   mode="recompute").inc()
-            self._tracer.instant("serving.resumed", rid=req.request_id,
-                                 mode="recompute", slot=int(si))
-            return []
-        self._results[req.request_id].append(first)
-        self._m_tokens.inc()
-        self._m_ttft.observe((now - req.t_submit) * 1e3)
-        if self._perf is not None:
-            self._perf.on_ttft((now - req.t_submit) * 1e3)
-        self._rlog.event(req.uid, "first_token", engine=self._eid,
-                         ttft_ms=(now - req.t_submit) * 1e3)
-        reason = self._finish_reason(first, slot, si)
-        if reason is not None:
-            self._retire(slot, si, reason, now)
-            return [req.request_id]
-        return []
+        return ([pf.req.request_id]
+                if self._install(pf.req, pf.slot, ctok, now) else [])
 
     def _pending_chunks(self) -> int:
         """Chunks still to ingest: the active prompt's remainder plus
@@ -2841,50 +2635,19 @@ class ServingEngine:
 
     # -- static analysis (graph lint) --------------------------------------
 
-    def _lint_args(self) -> Tuple:
-        """Representative step-function arguments for an ABSTRACT trace:
-        zero-valued, but exactly the shapes/dtypes every real tick
-        passes (strong-typed vectors, jnp.int32 chunk scalars, a typed
-        PRNG key) — the lint sees the program the scheduler runs."""
-        s = self.num_slots
-        toks = jnp.zeros((s,), jnp.int32)
-        pos = jnp.zeros((s,), jnp.int32)
-        mask = jnp.zeros((s,), bool)
-        temps = jnp.zeros((s,), jnp.float32)
-        topk = jnp.zeros((s,), jnp.int32)
-        topp = jnp.ones((s,), jnp.float32)
-        key = jax.random.fold_in(self._base_key, 0)
-        if self.spec:
-            # the verify step's window matrix + real-proposal mask +
-            # proposal-distribution stack ride in place of the (s,)
-            # token vector
-            head = (jnp.zeros((s, self.spec_k + 1), jnp.int32), pos)
-            tail_mask = (mask, jnp.zeros((s, self.spec_k), bool),
-                         jnp.zeros((s, self.spec_k,
-                                    self.config.vocab_size), jnp.float32))
-        else:
-            head, tail_mask = (toks, pos), (mask,)
-        if self.chunked:
-            cids = jnp.zeros((1, self.prefill_chunk), jnp.int32)
-            cpos, clen = jnp.int32(0), jnp.int32(1)
-            ctemp = jnp.zeros((1,), jnp.float32)
-            ctopk = jnp.zeros((1,), jnp.int32)
-            ctopp = jnp.ones((1,), jnp.float32)
-            if self.paged:
-                tables = jnp.zeros((s, self.max_blocks), jnp.int32)
-                ctable = jnp.zeros((1, self.max_blocks), jnp.int32)
-                return (self._params, self._cache, *head, tables,
-                        *tail_mask, temps, topk, topp, cids, cpos, clen,
-                        ctable, ctemp, ctopk, ctopp, key)
-            return (self._params, self._cache, *head, *tail_mask, temps,
-                    topk, topp, cids, cpos, clen, jnp.int32(0), ctemp,
-                    ctopk, ctopp, key)
-        if self.paged:
-            tables = jnp.zeros((s, self.max_blocks), jnp.int32)
-            return (self._params, self._cache, *head, tables, *tail_mask,
-                    temps, topk, topp, key)
-        return (self._params, self._cache, *head, *tail_mask, temps,
-                topk, topp, key)
+    def _lint_args(self, prefill_bucket: Optional[int] = None) -> Tuple:
+        """Representative arguments for an ABSTRACT trace of the step
+        program (with ``prefill_bucket``: of the prefill program at that
+        bucket length): the operand table's shapes and dtypes through the
+        table's own uploads, so the lint sees the program the scheduler
+        runs — tests/test_step_signature.py holds these to the operands of
+        a real tick and a real wave."""
+        table = (self._step_table if prefill_bucket is None
+                 else self._prefill_table)
+        return (self._params, self._cache, *(
+            o.put(0 if o.name == "key" else np.full(
+                [prefill_bucket if d is None else d for d in o.shape],
+                o.fill, o.dtype)) for o in table))
 
     def lint_step(self, mesh=None):
         """Graph-lint this engine's once-jitted step function (one
@@ -3037,10 +2800,7 @@ class ServingEngine:
         param_specs, cache_spec, _ = decode_mesh_specs(
             self._bind, self._params, minfo.names,
             paged_cache=self.paged, quantized_cache=self.quantized)
-        args = self._lint_args()
-        specs = [None] * len(args)
-        specs[0], specs[1] = param_specs, cache_spec
-        return tuple(specs)
+        return (param_specs, cache_spec) + (None,) * len(self._step_table)
 
     def mesh_preflight(self, mesh=None, rules=None) -> Dict[str, object]:
         """Mesh pre-flight of the once-jitted step (ISSUE 8): findings
@@ -3465,71 +3225,38 @@ class ServingEngine:
 
     # -- scheduler internals ----------------------------------------------
 
-    @staticmethod
-    def _bucket(plen: int) -> int:
-        """Padded prefill length: next power of two (floor 8) — bounds the
-        number of compiled prefill programs at log2(max_length)."""
+    def _wave_bucket(self, plen: int) -> int:
+        """Padded prefill length: next power of two (floor 8, ceiling
+        max_length) — bounds the number of compiled prefill programs at
+        log2(max_length)."""
         b = 8
         while b < plen:
             b *= 2
-        return b
+        return min(b, self.max_length)
 
     def _admit(self) -> List[int]:
-        """Move queued requests into free slots, one batched-prefill wave
-        per contiguous FIFO run sharing a bucket.  Returns ids that
-        finished AT admission (first token was EOS / max_new_tokens=1)."""
+        """Wave admission: move queued requests into free slots, one
+        batched-prefill wave at a time.  Returns ids that finished AT
+        admission (first token was EOS / max_new_tokens=1).  The head that
+        cannot be admitted blocks the queue: head-of-line order is the
+        contract in both layouts.
+
+        Contiguous: strict submit FIFO, a wave being a run of prompts that
+        share one padded bucket.  Paged: a request enters once the block
+        pool covers its worst case (``_pool_admit``), adopting any cached
+        prompt prefix on the way in, and a wave shares one padded SUFFIX
+        bucket (prefix-hit rows only compute what the cache missed).  With
+        preemption on, admission drains BOTH the recompute-resume queue and
+        the submit queue by priority class (stable FIFO within a class —
+        ``_next_admit``, resume entries winning ties) and a pool-full head
+        may instead evict a running victim and retry; swapped chains are
+        restored first of all."""
         if self.paged:
-            return self._admit_paged()
-        finished: List[int] = []
-        deferred = False
-        while self._queue and not deferred:
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            if not free:
-                break
-            occ = self.num_slots - len(free)
-            live = int(self._positions[self._active].sum()) if occ else 0
-            bucket = min(self._bucket(len(self._queue[0].prompt)),
-                         self.max_length)
-            wave: List[Request] = []
-            wave_tokens = 0
-            while (self._queue
-                   and len(wave) < min(self.prefill_batch, len(free))
-                   and min(self._bucket(len(self._queue[0].prompt)),
-                           self.max_length) == bucket):
-                head = self._queue[0]
-                if self._admission_defer(
-                        head, occ + len(wave) + 1,
-                        live + wave_tokens + int(head.prompt.size)):
-                    self._defer(head)
-                    deferred = True
-                    break
-                wave.append(self._queue.popleft())
-                wave_tokens += int(head.prompt.size)
-            if not wave:
-                break
-            finished.extend(self._prefill_wave(wave, free[:len(wave)],
-                                               bucket))
-        return finished
-
-    def _admit_paged(self) -> List[int]:
-        """Paged admission: FIFO requests enter free slots once the block
-        pool covers their worst case (kv_cache.py reservations), adopting
-        any cached prompt prefix on the way in.  A wave shares one padded
-        SUFFIX bucket (prefix-hit rows only compute what the cache
-        missed).  The FIFO head blocking on pool space blocks the queue —
-        head-of-line order is the contiguous engine's contract too.
-
-        With preemption on, admission drains BOTH the recompute-resume
-        queue and the submit queue by priority class (stable FIFO
-        within a class — _next_admit, resume entries winning ties) and
-        a pool-full head may instead evict a running victim (see
-        _try_preempt) and retry; swapped chains are restored first of
-        all."""
-        self._service_swap_resumes()
+            self._service_swap_resumes()
         finished: List[int] = []
         deferred = False
         while (self._resume_q or self._queue) and not deferred:
-            free = [i for i, s in enumerate(self._slots) if s is None]
+            free = self._free_slots()
             if not free:
                 break
             occ = self.num_slots - len(free)
@@ -3538,7 +3265,12 @@ class ServingEngine:
             wave_tokens = 0
             while ((self._resume_q or self._queue)
                    and len(wave) < min(self.prefill_batch, len(free))):
-                src, req = self._next_admit()
+                src, req = (self._next_admit() if self.paged
+                            else (self._queue, self._queue[0]))
+                if (wave and not self.paged
+                        and self._wave_bucket(req.prompt.size)
+                        != self._wave_bucket(wave[0][0].prompt.size)):
+                    break
                 if self._admission_defer(
                         req, occ + len(wave) + 1,
                         live + wave_tokens + int(req.prompt.size)):
@@ -3546,225 +3278,187 @@ class ServingEngine:
                     deferred = True
                     break
                 si = free[len(wave)]
-                m = self.kv.admit(si, req.prompt, req.prompt.size,
-                                  req.max_new_tokens)
-                while m is None and self._try_preempt(
-                        priority=req.priority, rid=req.request_id,
-                        blocked_ticks=req.blocked_ticks):
-                    m = self.kv.admit(si, req.prompt, req.prompt.size,
-                                      req.max_new_tokens)
-                if m is None:          # pool full: wait for retirements
-                    self._m_blocked.inc()
-                    self._tracer.instant("serving.admission_blocked",
-                                         rid=req.request_id)
-                    req.blocked_ticks += 1
-                    if req.blocked_ticks == 1:
-                        self._rlog.event(req.uid, "admission_wait",
-                                         engine=self._eid,
-                                         reason="pool_full")
+                m = self._pool_admit(si, req) if self.paged else 0
+                if m is None:
                     break
                 # remove by IDENTITY: a preemption inside the retry loop
                 # may have pushed a new resume entry ahead of req
                 src.remove(req)
-                self._tables[si] = self.kv.table_row(si, self.max_blocks)
                 wave.append((req, si, m))
                 wave_tokens += int(req.prompt.size)
             if not wave:
                 break
-            finished.extend(self._prefill_wave_paged(wave))
+            finished.extend(self._prefill_wave(wave))
         return finished
 
-    def _prefill_wave_paged(self, wave: List[Tuple[Request, int, int]]
-                            ) -> List[int]:
+    def _pool_admit(self, si: int, req: Request) -> Optional[int]:
+        """Reserve the block pool for ``req`` in slot ``si``: the prompt
+        tokens its adopted prefix covers, or None while the pool cannot
+        cover the request's worst case — after preempting every victim
+        ``_try_preempt`` allows and retrying, and with the blocked head
+        counted every tick and logged once an episode."""
+        while True:
+            got = self.kv.admit(si, req.prompt, req.prompt.size,
+                                req.max_new_tokens, chunked=self.chunked)
+            if got is not None or not self._try_preempt(
+                    priority=req.priority, rid=req.request_id,
+                    blocked_ticks=req.blocked_ticks):
+                break
+        if got is None:              # pool full: wait for retirements
+            self._m_blocked.inc()
+            self._tracer.instant("serving.admission_blocked",
+                                 rid=req.request_id)
+            req.blocked_ticks += 1
+            if req.blocked_ticks == 1:
+                # the preemption-relevant wait: log once per wait
+                # episode, not per blocked tick
+                self._rlog.event(req.uid, "admission_wait",
+                                 engine=self._eid, reason="pool_full")
+        return got
+
+    def _prefill_wave(self, wave: List[Tuple[Request, int, int]]
+                      ) -> List[int]:
+        """Prefill one admission wave of rows ``(request, slot, adopted
+        prefix tokens)`` — 0 adopted on the contiguous cache — in one
+        program call at the wave's padded bucket, and install each row into
+        its slot.  Returns the ids that finished at admission."""
         t_adm = self._clock()
-        nb = self.prefill_batch
-        bucket = min(max(self._bucket(req.prompt.size - m)
-                         for req, _, m in wave), self.max_length)
-        ids = np.full((nb, bucket), self.pad_token_id, np.int32)
-        prefix = np.zeros((nb,), np.int32)
-        slens = np.ones((nb,), np.int32)
-        # dummy rows keep all-null tables: their writes land in the
-        # scratch block and their sampled token is discarded
-        tables = np.zeros((nb, self.max_blocks), np.int32)
-        temps = np.zeros((nb,), np.float32)
-        topk = np.zeros((nb,), np.int32)
-        topp = np.ones((nb,), np.float32)
-        for r, (req, si, m) in enumerate(wave):
-            suffix = req.prompt[m:]
-            ids[r, :suffix.size] = suffix
-            prefix[r] = m
-            slens[r] = suffix.size
-            tables[r] = self._tables[si]
-            temps[r] = req.sampling.temperature
-            topk[r] = req.sampling.top_k
-            topp[r] = req.sampling.top_p
-            self._m_prefill_computed.inc(int(suffix.size))
+        bucket = max(self._wave_bucket(req.prompt.size - m)
+                     for req, _, m in wave)
+        for req, si, m in wave:
+            self._m_prefill_computed.inc(int(req.prompt.size) - m)
             self._m_prefill_total.inc(int(req.prompt.size))
             if req.resume is None:
                 self._m_queue_wait.observe((t_adm - req.t_submit) * 1e3)
                 req.t_admit = t_adm
                 self._rlog.event(req.uid, "admitted", engine=self._eid,
                                  slot=int(si),
-                                 queue_wait_ms=(t_adm - req.t_submit)
-                                 * 1e3,
+                                 queue_wait_ms=(t_adm - req.t_submit) * 1e3,
                                  blocked_ticks=int(req.blocked_ticks),
                                  prefix_hit_tokens=int(m))
             self._rlog.event(req.uid, "prefill", engine=self._eid,
                              bucket=int(bucket),
-                             tokens=int(suffix.size))
+                             tokens=int(req.prompt.size) - m)
         self._m_waves.inc()
         self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
         self._ticks += 1
         span = self._tracer.span
-        with span(_GROW):
-            self._flush_fresh_scales()
-        with span("serving.prefill", bucket=bucket, rows=len(wave),
-                  padded_rows=nb, tokens=int(slens[:len(wave)].sum()),
-                  sample_path=self._note_sample_path((temps, topk, topp))):
-            with span(_BUILD):
-                args = (jnp.asarray(ids), jnp.asarray(prefix),
-                        jnp.asarray(slens), jnp.asarray(tables),
-                        jnp.asarray(temps), jnp.asarray(topk),
-                        jnp.asarray(topp),
-                        jax.random.fold_in(self._base_key, self._ticks))
-            with span(_DISPATCH):
-                tok, self._cache = self._prefill_fn(
-                    self._params, self._cache, *args)
-            with span(_READBACK):
-                tok = np.asarray(tok)
+        if self.paged:
+            with span(_GROW):
+                self._flush_fresh_scales()
+        tok = self._device_prefill(wave, bucket)
         with span(_ADVANCE):
-            return self._finish_wave_paged(wave, tok, temps, topk, topp)
+            # queued demotions first (a wave's registration precedes its
+            # prefill), then each row's slot state and first token
+            self._apply_demotions()
+            t_tok = self._clock()
+            return [req.request_id for (req, si, _), t in zip(wave, tok)
+                    if self._install(req, si, t, t_tok)]
 
-    def _finish_wave_paged(self, wave, tok, temps, topk, topp) -> List[int]:
-        """Per-row bookkeeping after a paged wave's token fetch: queued
-        demotions, then each row's slot state and first token."""
-        self._apply_demotions()
-        t_tok = self._clock()
-        finished: List[int] = []
-        for r, (req, si, m) in enumerate(wave):
-            ri = req.resume
-            if ri is not None:
-                # recompute resume: the re-sampled token re-derives the
-                # last committed one (greedy: identical); it is DISCARDED
-                # and the committed token forced back, so the resumed
-                # decode replays no token and drops none
-                first = ri.last_token
-                slot = _Slot(req.request_id, ri.remaining,
-                             t_first=ri.t_first, prompt=ri.orig.prompt,
-                             req=ri.orig)
-            else:
-                first = int(tok[r])
-                slot = _Slot(req.request_id, req.max_new_tokens - 1,
-                             t_first=t_tok, prompt=req.prompt, req=req)
-            self._drafter_reset(si)
-            self._slots[si] = slot
-            self._active[si] = True
-            self._tokens[si] = first
-            self._positions[si] = req.prompt.size
-            self._temps[si] = temps[r]
-            self._topk[si] = topk[r]
-            self._topp[si] = topp[r]
-            if ri is not None:
-                self._rlog.event(req.uid, "resumed", engine=self._eid,
-                                 mode="recompute", slot=int(si))
-                self._f_resumed.labels(engine=self._eid,
-                                       mode="recompute").inc()
-                self._tracer.instant("serving.resumed",
-                                     rid=req.request_id,
-                                     mode="recompute", slot=int(si))
-                continue
-            self._results[req.request_id].append(first)
-            self._m_tokens.inc()
-            self._m_ttft.observe((t_tok - req.t_submit) * 1e3)
-            if self._perf is not None:
-                self._perf.on_ttft((t_tok - req.t_submit) * 1e3)
-            self._rlog.event(req.uid, "first_token", engine=self._eid,
-                             ttft_ms=(t_tok - req.t_submit) * 1e3)
-            reason = self._finish_reason(first, slot, si)
-            if reason is not None:
-                finished.append(req.request_id)
-                self._retire(slot, si, reason, t_tok)
-        return finished
-
-    def _prefill_wave(self, wave: List[Request], slots: List[int],
-                      bucket: int) -> List[int]:
-        t_adm = self._clock()
-        nb = self.prefill_batch
+    def _device_prefill(self, wave, bucket: int) -> Sequence[int]:
+        """The wave's device seam (``serving.prefill``): pad the wave to
+        ``prefill_batch`` rows of ``bucket`` tokens, upload the prefill
+        program's operands, call it, fetch each row's first token.
+        ``fleet_sim.SimEngine`` overrides this and nothing else of the
+        wave."""
+        nb, paged = self.prefill_batch, self.paged
         ids = np.full((nb, bucket), self.pad_token_id, np.int32)
-        plens = np.ones((nb,), np.int32)
-        # dummy rows scatter to the out-of-bounds slot id and are dropped
-        slot_ids = np.full((nb,), self.num_slots, np.int32)
+        lens = np.ones((nb,), np.int32)
         temps = np.zeros((nb,), np.float32)
         topk = np.zeros((nb,), np.int32)
         topp = np.ones((nb,), np.float32)
-        for r, (req, si) in enumerate(zip(wave, slots)):
-            ids[r, :req.prompt.size] = req.prompt
-            plens[r] = req.prompt.size
-            slot_ids[r] = si
+        own = dict(ids=ids, lens=lens, temps=temps, topk=topk, topp=topp,
+                   key=self._ticks)
+        if paged:
+            # dummy rows keep all-null tables: their writes land in the
+            # scratch block and their sampled token is discarded
+            prefix = own["prefix_lens"] = np.zeros((nb,), np.int32)
+            where = own["tables"] = np.zeros((nb, self.max_blocks), np.int32)
+        else:
+            # dummy rows scatter to the out-of-bounds slot id and are dropped
+            where = own["slot_ids"] = np.full((nb,), self.num_slots,
+                                              np.int32)
+        for r, (req, si, m) in enumerate(wave):
+            suffix = req.prompt[m:]
+            ids[r, :suffix.size] = suffix
+            lens[r] = suffix.size
+            if paged:
+                prefix[r] = m
+                where[r] = self.kv.table_row(si, self.max_blocks)
+            else:
+                where[r] = si
             temps[r] = req.sampling.temperature
             topk[r] = req.sampling.top_k
             topp[r] = req.sampling.top_p
-            self._m_queue_wait.observe((t_adm - req.t_submit) * 1e3)
-            self._m_prefill_computed.inc(int(req.prompt.size))
-            self._m_prefill_total.inc(int(req.prompt.size))
-            req.t_admit = t_adm
-            self._rlog.event(req.uid, "admitted", engine=self._eid,
-                             slot=int(si),
-                             queue_wait_ms=(t_adm - req.t_submit) * 1e3,
-                             blocked_ticks=int(req.blocked_ticks),
-                             prefix_hit_tokens=0)
-            self._rlog.event(req.uid, "prefill", engine=self._eid,
-                             bucket=int(bucket),
-                             tokens=int(req.prompt.size))
-        self._m_waves.inc()
-        self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
-        self._ticks += 1
         span = self._tracer.span
         with span("serving.prefill", bucket=bucket, rows=len(wave),
-                  padded_rows=nb, tokens=int(plens[:len(wave)].sum()),
+                  padded_rows=nb, tokens=int(lens[:len(wave)].sum()),
                   sample_path=self._note_sample_path((temps, topk, topp))):
             with span(_BUILD):
-                args = (jnp.asarray(ids), jnp.asarray(plens),
-                        jnp.asarray(slot_ids), jnp.asarray(temps),
-                        jnp.asarray(topk), jnp.asarray(topp),
-                        jax.random.fold_in(self._base_key, self._ticks))
+                args = self._upload(self._prefill_table, own)
             with span(_DISPATCH):
                 tok, self._cache = self._prefill_fn(
                     self._params, self._cache, *args)
             with span(_READBACK):
-                tok = np.asarray(tok)
-        with span(_ADVANCE):
-            return self._finish_wave(wave, slots, tok, plens, temps, topk,
-                                     topp)
+                return np.asarray(tok)
 
-    def _finish_wave(self, wave, slots, tok, plens, temps, topk,
-                     topp) -> List[int]:
-        """Per-row bookkeeping after a contiguous wave's token fetch."""
-        t_tok = self._clock()
-        finished: List[int] = []
-        for r, (req, si) in enumerate(zip(wave, slots)):
+    def _install(self, req: Request, si: int, tok, now: float) -> bool:
+        """Install a request whose prompt is in the cache into slot ``si``
+        (a wave's row, or the cursor's prompt at its last chunk): the
+        slot's state and mirrors, then the first token ``tok`` with its
+        TTFT.  True when the request finished right here (first token EOS
+        / max_new_tokens=1) and was retired."""
+        ri = req.resume
+        if ri is not None:
+            # recompute resume: the re-sampled token re-derives the last
+            # committed one (greedy: identical); it is DISCARDED and the
+            # committed token forced back with the original decode budget
+            # and TTFT clock, so the resumed decode replays no token and
+            # drops none
+            first = ri.last_token
+            slot = _Slot(req.request_id, ri.remaining, t_first=ri.t_first,
+                         prompt=ri.orig.prompt, req=ri.orig)
+        else:
+            first = int(tok)
             slot = _Slot(req.request_id, req.max_new_tokens - 1,
-                         t_first=t_tok, prompt=req.prompt, req=req)
-            self._drafter_reset(si)
-            self._slots[si] = slot
-            self._active[si] = True
-            self._tokens[si] = tok[r]
-            self._positions[si] = plens[r]
-            self._temps[si] = temps[r]
-            self._topk[si] = topk[r]
-            self._topp[si] = topp[r]
-            self._results[req.request_id].append(int(tok[r]))
-            self._m_tokens.inc()
-            self._m_ttft.observe((t_tok - req.t_submit) * 1e3)
-            if self._perf is not None:
-                self._perf.on_ttft((t_tok - req.t_submit) * 1e3)
-            self._rlog.event(req.uid, "first_token", engine=self._eid,
-                             ttft_ms=(t_tok - req.t_submit) * 1e3)
-            reason = self._finish_reason(int(tok[r]), slot, si)
-            if reason is not None:
-                finished.append(req.request_id)
-                self._retire(slot, si, reason, t_tok)
-        return finished
+                         t_first=now, prompt=req.prompt, req=req)
+        self._seat(si, slot, first, req.prompt.size, req.sampling)
+        if ri is not None:
+            self._note_resumed(req, "recompute", si)
+            return False
+        self._results[req.request_id].append(first)
+        self._m_tokens.inc()
+        self._m_ttft.observe((now - req.t_submit) * 1e3)
+        if self._perf is not None:
+            self._perf.on_ttft((now - req.t_submit) * 1e3)
+        self._rlog.event(req.uid, "first_token", engine=self._eid,
+                         ttft_ms=(now - req.t_submit) * 1e3)
+        reason = self._finish_reason(first, slot, si)
+        if reason is not None:
+            self._retire(slot, si, reason, now)
+        return reason is not None
+
+    def _seat(self, si: int, slot: _Slot, token: int, position: int,
+              sampling: SamplingParams):
+        """Put ``slot`` into slot ``si``: the host mirrors the step
+        uploads and, paged, its row of the block table."""
+        self._drafter_reset(si)
+        self._slots[si] = slot
+        self._active[si] = True
+        self._tokens[si] = token
+        self._positions[si] = position
+        self._temps[si] = sampling.temperature
+        self._topk[si] = sampling.top_k
+        self._topp[si] = sampling.top_p
+        if self.paged:
+            self._tables[si] = self.kv.table_row(si, self.max_blocks)
+
+    def _note_resumed(self, req: Request, mode: str, si: int):
+        self._rlog.event(req.uid, "resumed", engine=self._eid, mode=mode,
+                         slot=int(si))
+        self._f_resumed.labels(engine=self._eid, mode=mode).inc()
+        self._tracer.instant("serving.resumed", rid=req.request_id,
+                             mode=mode, slot=int(si))
 
     def _finish_reason(self, tok: int, slot: _Slot,
                        i: int) -> Optional[str]:

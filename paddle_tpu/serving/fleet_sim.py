@@ -4,7 +4,7 @@ clock (ISSUE 17 tentpole c).
 ``SimEngine`` is a :class:`~paddle_tpu.serving.engine.ServingEngine`
 with the device removed and NOTHING else replaced: the same ``submit``
 / ``step`` / ``drain`` scheduler, the same paged admission
-(``_admit_paged``), the same :class:`~paddle_tpu.serving.kv_cache.
+(``_admit``, ``_prefill_wave``), the same :class:`~paddle_tpu.serving.kv_cache.
 BlockManager` pool (prefix trie, COW, reservations, host tier), the
 same preemption machinery and the same predictive-admission gate — but
 every jitted dispatch is replaced by the roofline cost model's
@@ -52,7 +52,6 @@ import dataclasses
 import hashlib
 import json
 import time
-from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -61,8 +60,7 @@ from .. import flags as _flags
 from ..observability import costmodel as _cm
 from ..observability import tracing as _obs
 from . import loadgen as _loadgen
-from .engine import ServingEngine, _Slot
-from .kv_cache import BlockManager
+from .engine import ServingEngine
 from .router import ReplicaRouter
 
 __all__ = ["SimSpec", "SimEngine", "FleetSim", "fleet_load_spec",
@@ -115,11 +113,12 @@ class SimEngine(ServingEngine):
     ``ServingEngine.__init__`` — there is no model, no params, no
     jitted program — but it builds the identical host-side state
     catalog, so every inherited scheduler method (``submit``, ``step``,
-    ``_admit_paged``, preemption, cancel, metrics, the predictive
+    ``_admit``, the tick, preemption, cancel, metrics, the predictive
     admission gate) runs unmodified.  Only four methods are overridden:
-    ``_step_inner`` and ``_prefill_wave_paged`` swap the dispatch for a
-    cost-model prediction + simulated-clock advance, and the two host-
-    tier hooks account swap bytes without moving payloads."""
+    the two device seams (``_device_step``, ``_device_prefill``) swap
+    upload, dispatch and readback for a cost-model prediction +
+    simulated-clock advance, and the two host-tier hooks account swap
+    bytes without moving payloads."""
 
     def __init__(self, spec: SimSpec, *, num_slots: int = 8,
                  max_length: int = 1024, prefill_batch: int = 4,
@@ -161,76 +160,29 @@ class SimEngine(ServingEngine):
         self._chunk_policy = "prefill"
         self.spec = False
         self.spec_k = int(_flags.flag("serving_spec_k"))
-        self.preempt = str(_flags.flag("serving_preempt")
-                           if preempt is None else preempt)
-        if self.preempt not in ("off", "swap", "recompute"):
-            raise ValueError(
-                f"preempt must be off|swap|recompute, got "
-                f"{self.preempt!r}")
-        self._preempt_after = int(_flags.flag("serving_preempt_after"))
-        hb = int(_flags.flag("serving_host_blocks")
-                 if host_blocks is None else host_blocks)
-        if self.preempt == "swap" and hb < 1:
-            raise ValueError(
-                "preempt='swap' needs a host tier: pass host_blocks "
-                "(or FLAGS_serving_host_blocks) >= 1")
-        self._host_blocks = hb
+        self._init_preempt(preempt, host_blocks)
         self.mesh = None
         self._init_metrics()
-        bl = int(block_len or _flags.flag("kv_cache_block_len"))
-        if self.max_length % bl:
-            raise ValueError(
-                f"max_length {self.max_length} is not a multiple of "
-                f"block_len {bl}")
-        self.block_len = bl
-        self.max_blocks = self.max_length // bl
-        nb = int(num_blocks or _flags.flag("kv_cache_num_blocks")
-                 or self.num_slots * self.max_blocks + 1)
-        self.kv = BlockManager(
-            nb, bl,
-            prefix_cache=bool(_flags.flag("serving_prefix_cache")
-                              if prefix_cache is None else prefix_cache),
-            kv_dtype=self.kv_dtype,
-            host_blocks=self._host_blocks)
+        self._init_scheduler_state()
+        _, bl = self._init_pool(block_len, num_blocks, prefix_cache)
         self._sim_block_nbytes = int(round(spec.kv_token_bytes * bl))
         self.kv.set_block_nbytes({"bf16": self._sim_block_nbytes})
-        self._tables = np.zeros((self.num_slots, self.max_blocks),
-                                np.int32)
         self._params = None
         self._cache = None               # the pool has no device twin
-        self._pending_demote: List[int] = []
         # COW privatisation is pool bookkeeping here; the device copy
         # the real engine dispatches has no simulated cost of its own
         # (it rides inside the tick the cost model already prices)
         self._cow_fn = lambda cache, src, dst: cache
-        self._tick_swap_bytes = 0
         if self._host_blocks > 0:
             self.kv.on_swap_out = self._host_swap_out
             self.kv.on_swap_in = self._host_swap_in
-        s = self.num_slots
-        self._tokens = np.zeros((s,), np.int32)
-        self._positions = np.zeros((s,), np.int32)
-        self._active = np.zeros((s,), bool)
-        self._temps = np.zeros((s,), np.float32)
-        self._topk = np.zeros((s,), np.int32)
-        self._topp = np.ones((s,), np.float32)
-        self._slots: List[Optional[_Slot]] = [None] * s
-        self._prefill = None
-        self._queue = deque()
-        self._swap_resume = []
-        self._resume_q = deque()
-        self._preempt_log: List[Dict[str, object]] = []
-        self._results: Dict[int, List[int]] = {}
-        self._next_rid = 0
         self._base_key = None            # tokens are hash-synthesized
         self._seed = int(seed)
-        self._ticks = 0
         # the simulated clock: every SLO stamp reads _clock(), and the
-        # overridden tick bodies advance _now_s by the model's
+        # overridden device seams advance _now_s by the model's
         # prediction — sim seconds ARE predicted milliseconds / 1e3
         self._now_s = 0.0
         self._clock = lambda: self._now_s
-        self._kernel_preflight_cache = None
         self._step_fn = None
         self._prefill_fn = None
         self._linted = True              # no jitted program to lint
@@ -250,133 +202,47 @@ class SimEngine(ServingEngine):
         """This replica's simulated clock (cost-model seconds)."""
         return self._now_s
 
-    def _sim_token(self, slot: _Slot, i: int) -> int:
+    def _sim_token(self, rid: int, pos: int) -> int:
         """Deterministic token synthesis: a pure hash of (request id,
         position, seed), steered off the EOS id so the trace's
         max_new_tokens — not sampling luck — decides every length."""
-        pos = int(self._positions[i])
-        tok = (slot.rid * 1_000_003 + pos * 10_007
+        tok = (rid * 1_000_003 + pos * 10_007
                + self._seed * 7_919) % _SIM_VOCAB
         if self.eos_token_id is not None and tok == self.eos_token_id:
             tok = (tok + 1) % _SIM_VOCAB
         return tok
 
-    # -- overridden tick bodies --------------------------------------------
+    # -- the device seams --------------------------------------------------
 
-    def _step_inner(self) -> List[int]:
-        """The real ``_step_inner`` with the jitted decode dispatch
-        replaced by a cost-model prediction: identical admission,
-        identical paged bookkeeping (chain growth, COW, tables),
-        identical retirement — the simulated clock advances by the
-        tick's predicted milliseconds and ``_perf_tick`` records
-        measured == predicted (ratio 1.0, no drift, byte-stable
-        perf signature)."""
-        finished = self._admit()
-        occ = int(self._active.sum())
-        self._set_occupancy(occ)
-        if not occ:
-            return finished
-        self._ticks += 1
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            self._grow_row_for_writes(i, int(self._positions[i]))
+    def _device_step(self, own, chunk) -> List[np.ndarray]:
+        """The step program replaced by the cost model's prediction for
+        this tick: the simulated clock advances by the predicted
+        milliseconds, so ``_perf_tick`` records measured == predicted
+        (same memo key: ratio 1.0, no drift, byte-stable perf signature),
+        and every busy row's token is synthesized."""
         # inactive rows hold position 0 (_clear_slot), so the full sum
         # IS the live-token depth — no boolean-mask temporary
-        live = int(self._positions.sum())
-        swap_bytes, self._tick_swap_bytes = self._tick_swap_bytes, 0
-        pred = self._cost.predicted_tick_ms(occ, live,
-                                            swap_bytes=swap_bytes)
+        pred = self._cost.predicted_tick_ms(
+            self.num_active, int(self._positions.sum()),
+            swap_bytes=self._tick_swap_bytes)
         self._now_s += pred / 1e3
-        now = self._clock()
-        self._m_step_ms.observe(pred)
-        if self._perf is not None:
-            # same memo key as the prediction above: measured ==
-            # predicted exactly, ratio 1.0, detectors quiet
-            self._perf.on_tick(pred, occ=occ, live_tokens=live,
-                               swap_bytes=swap_bytes)
         nxt = np.full((self.num_slots,), self.pad_token_id, np.int32)
         for i, slot in enumerate(self._slots):
             if slot is not None:
-                nxt[i] = self._sim_token(slot, i)
-        finished.extend(self._advance_decode(nxt, now))
-        return finished
+                nxt[i] = self._sim_token(slot.rid, int(self._positions[i]))
+        return [nxt]
 
-    def _prefill_wave_paged(self, wave) -> List[int]:
-        """The real paged wave prefill minus the device: identical
-        admission bookkeeping and lifecycle events, first tokens
-        synthesized, and the simulated clock advanced by the wave's
-        modeled cost — priced as one tick whose chunk term carries the
-        computed suffix tokens (prefix hits ride free, exactly like the
-        real wave's suffix-only compute)."""
-        t_adm = self._clock()
-        bucket = min(max(self._bucket(req.prompt.size - m)
-                         for req, _, m in wave), self.max_length)
-        suffix_tokens = 0
-        for req, si, m in wave:
-            suffix = int(req.prompt.size) - int(m)
-            suffix_tokens += suffix
-            self._m_prefill_computed.inc(suffix)
-            self._m_prefill_total.inc(int(req.prompt.size))
-            if req.resume is None:
-                self._m_queue_wait.observe((t_adm - req.t_submit) * 1e3)
-                req.t_admit = t_adm
-                self._rlog.event(req.uid, "admitted", engine=self._eid,
-                                 slot=int(si),
-                                 queue_wait_ms=(t_adm - req.t_submit)
-                                 * 1e3,
-                                 blocked_ticks=int(req.blocked_ticks),
-                                 prefix_hit_tokens=int(m))
-            self._rlog.event(req.uid, "prefill", engine=self._eid,
-                             bucket=int(bucket), tokens=suffix)
-        self._m_waves.inc()
-        self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
-        self._ticks += 1
-        pred = self._cost.predicted_tick_ms(
-            len(wave), suffix_tokens, chunk_tokens=suffix_tokens)
+    def _device_prefill(self, wave, bucket) -> List[int]:
+        """The prefill program replaced by its modeled cost — one tick
+        whose chunk term carries the computed suffix tokens (prefix hits
+        ride free, exactly like the real wave's suffix-only compute) —
+        and synthesized first tokens."""
+        suffix = sum(int(req.prompt.size) - int(m) for req, _, m in wave)
+        pred = self._cost.predicted_tick_ms(len(wave), suffix,
+                                            chunk_tokens=suffix)
         self._now_s += pred / 1e3
-        t_tok = self._clock()
-        finished: List[int] = []
-        for req, si, m in wave:
-            ri = req.resume
-            if ri is not None:
-                first = ri.last_token
-                slot = _Slot(req.request_id, ri.remaining,
-                             t_first=ri.t_first, prompt=ri.orig.prompt,
-                             req=ri.orig)
-            else:
-                slot = _Slot(req.request_id, req.max_new_tokens - 1,
-                             t_first=t_tok, prompt=req.prompt, req=req)
-            self._slots[si] = slot
-            self._active[si] = True
-            self._positions[si] = req.prompt.size
-            self._temps[si] = req.sampling.temperature
-            self._topk[si] = req.sampling.top_k
-            self._topp[si] = req.sampling.top_p
-            if ri is not None:
-                self._tokens[si] = first
-                self._rlog.event(req.uid, "resumed", engine=self._eid,
-                                 mode="recompute", slot=int(si))
-                self._f_resumed.labels(engine=self._eid,
-                                       mode="recompute").inc()
-                self._tracer.instant("serving.resumed",
-                                     rid=req.request_id,
-                                     mode="recompute", slot=int(si))
-                continue
-            first = self._sim_token(slot, si)
-            self._tokens[si] = first
-            self._results[req.request_id].append(first)
-            self._m_tokens.inc()
-            self._m_ttft.observe((t_tok - req.t_submit) * 1e3)
-            if self._perf is not None:
-                self._perf.on_ttft((t_tok - req.t_submit) * 1e3)
-            self._rlog.event(req.uid, "first_token", engine=self._eid,
-                             ttft_ms=(t_tok - req.t_submit) * 1e3)
-            reason = self._finish_reason(first, slot, si)
-            if reason is not None:
-                finished.append(req.request_id)
-                self._retire(slot, si, reason, t_tok)
-        return finished
+        return [self._sim_token(req.request_id, int(req.prompt.size))
+                for req, _, _ in wave]
 
     # -- host-tier hooks (byte accounting only) ----------------------------
 

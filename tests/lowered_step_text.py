@@ -4,12 +4,14 @@ program (``tests/test_sample_paths.py``):
 
     JAX_PLATFORMS=cpu python tests/lowered_step_text.py <repo root>
 
-prints the sha256 of the text of the llama step programs at the Mistral
-cells' geometry (published widths, the engine settings of the cells' files; 2
-of the 16 layers: the layers are one code path repeated), under the chip's
-dispatch.  Run it on two checkouts and compare the lines (PR 27 used it to
-show that a second model in the engine left the llama step programs as they
-were)."""
+prints, under the chip's dispatch, the sha256 of the text of the step and
+prefill programs of every benchmark cell (published widths, the engine
+settings of the cells' files; 2 layers: the layers are one code path
+repeated) and of the engine's eight layouts (paged x chunked x spec) at tiny
+llama geometry.  Run it on two checkouts and compare the lines: PR 27 used
+it to show that a second model in the engine left the llama step programs as
+they were, PR 29 that one composed step program lowers to the text of the
+eight hand-written ones."""
 import base64
 import hashlib
 import json
@@ -83,17 +85,51 @@ def sha(low):
     return hashlib.sha256(txt.encode()).hexdigest()[:16], len(txt)
 
 
+def prefill_args(eng, bucket):
+    """The prefill program's operands at one bucket length: the engine's own
+    table of them, or, on a checkout from before PR 29, the hand copy."""
+    try:
+        return eng._lint_args(bucket)
+    except TypeError:
+        nb = eng.prefill_batch
+
+        def z(*s, dt=jnp.int32):
+            return jnp.zeros(s, dt)
+        rows = ((z(nb), z(nb), z(nb, eng.max_blocks)) if eng.paged
+                else (z(nb), z(nb)))
+        return (eng._params, eng._cache, z(nb, bucket), *rows,
+                z(nb, dt=jnp.float32), z(nb), jnp.ones((nb,), jnp.float32),
+                jax.random.key(0))
+
+
+def programs(label, eng, bucket, vocab=None):
+    step = eng._step_fn.python_fn
+    low = lowered(step, eng._lint_args())
+    print(label, "step", step.__name__, *sha(low))
+    if vocab:
+        print("   sorts over the vocabulary (shape, in a branch):",
+              sorts_over(low, vocab))
+    if eng._prefill_fn is not None:
+        prefill = eng._prefill_fn.python_fn
+        print(label, "prefill", prefill.__name__,
+              *sha(lowered(prefill, prefill_args(eng, bucket))))
+
+
 def main(root):
     root = os.path.abspath(root)
     sys.path.insert(0, root)
     import paddle_tpu.ops._dispatch as D
     D.default_backend = lambda: "tpu"      # the chip's dispatch, lowered here
     import paddle_tpu as pt
-    from paddle_tpu.models import LlamaForCausalLM
+    from paddle_tpu import nn
+    from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
     from paddle_tpu.models.llama import LlamaConfig
     from paddle_tpu.serving import ServingEngine
-    cfg = json.load(open(os.path.join(
-        root, "benchmark/configs/mistral-7b.json")))
+
+    def cell_file(kind, name):
+        return json.load(open(os.path.join(
+            root, "benchmark", kind, name + ".json")))
+    cfg = cell_file("configs", "mistral-7b")
     fields = {k: cfg[k] for k in (
         "vocab_size", "hidden_size", "intermediate_size",
         "num_attention_heads", "num_key_value_heads",
@@ -104,26 +140,43 @@ def main(root):
         dtype="bfloat16", num_hidden_layers=2, **fields))
     model.eval()
     for cell in ("mistral-7b.decode-saturated", "mistral-7b.chat-open"):
-        eng_kw = json.load(open(os.path.join(
-            root, "benchmark/workloads", cell + ".json")))["engine"]
-        eng = ServingEngine(model, seed=0, **eng_kw)
-        step = eng._step_fn.python_fn
-        low = lowered(step, eng._lint_args())
-        print(cell, "step", step.__name__, *sha(low))
-        print("   sorts over the vocabulary (shape, in a branch):",
-              sorts_over(low, fields["vocab_size"]))
-        if eng._prefill_fn is not None:
-            nb, L = eng.prefill_batch, 256
-
-            def z(*s, dt=jnp.int32):
-                return jnp.zeros(s, dt)
-            args = (eng._params, eng._cache, z(nb, L), z(nb), z(nb),
-                    z(nb, eng.max_blocks), z(nb, dt=jnp.float32), z(nb),
-                    jnp.ones((nb,), jnp.float32), jax.random.key(0))
-            prefill = eng._prefill_fn.python_fn
-            print(cell, "prefill", prefill.__name__,
-                  *sha(lowered(prefill, args)))
+        eng = ServingEngine(model, seed=0,
+                            **cell_file("workloads", cell)["engine"])
+        programs(cell, eng, 256, vocab=fields["vocab_size"])
         del eng
+    del model
+
+    # the third cell: the published layer 0 (dense, window) and one global
+    # expert layer, every held expert; built to be loaded, since only shapes
+    # are lowered and a CPU need not hold 2 GB of experts (the cost model
+    # would sum the weights' bytes, and is no part of a program)
+    from benchmark.harness import serve_afmoe
+    from paddle_tpu.models.afmoe import AfmoeForCausalLM
+    cell = "trinity-large-ep8.longtail-saturated"
+    kw = cell_file("workloads", cell)["engine"]
+    cfg = dict(cell_file("configs", "trinity-large-ep8"), num_hidden_layers=2,
+               layer_types=["sliding_attention", "full_attention"])
+    with nn.abstract_parameters():
+        model = AfmoeForCausalLM(
+            serve_afmoe.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    programs(cell, ServingEngine(model, seed=0, **kw), 256)
+    pt.flags.set_flags({"perf_model": "on"})
+    del model
+
+    pt.seed(7)
+    model = LlamaForCausalLM(tiny_llama_config(context_parallel="gspmd"))
+    model.eval()
+    for paged in (False, True):
+        for chunked in (False, True):
+            for spec in (False, True):
+                eng = ServingEngine(
+                    model, num_slots=3, max_length=64, block_len=8,
+                    prefill_chunk=8, spec_k=2, seed=0, paged=paged,
+                    chunked=chunked, spec_decode=spec)
+                programs(f"tiny-llama paged={int(paged)} chunked="
+                         f"{int(chunked)} spec={int(spec)}", eng, 16)
 
 
 if __name__ == "__main__":

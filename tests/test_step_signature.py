@@ -1,0 +1,98 @@
+"""The step program's signature, tested where it was only trusted (ISSUE 29).
+
+The engine writes the operands of its two device programs down once
+(``ServingEngine._operand_tables``).  The graph lint, the mesh pre-flight,
+``chip_smoke.py`` and ``tests/lowered_step_text.py`` take ``_lint_args()`` to
+be "the program the scheduler runs"; here a real tick's and a real wave's
+operands are held to it, in every layout.
+"""
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.serving import ServingEngine
+
+SLOTS, MAXLEN, K = 3, 64, 2
+
+
+@pytest.fixture(scope="module")
+def lm():
+    from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
+
+    pt.seed(7)
+    model = LlamaForCausalLM(tiny_llama_config(context_parallel="gspmd"))
+    model.eval()
+    return model
+
+
+def _types(tree):
+    return jax.tree_util.tree_map(jax.typeof, tree)
+
+
+@pytest.mark.parametrize("spec", [False, True])
+@pytest.mark.parametrize("chunked", [False, True])
+@pytest.mark.parametrize("paged", [False, True])
+def test_a_real_tick_runs_the_program_the_table_describes(
+        lm, paged, chunked, spec):
+    eng = ServingEngine(lm, num_slots=SLOTS, max_length=MAXLEN, block_len=8,
+                        prefill_chunk=8, spec_k=K, paged=paged,
+                        chunked=chunked, spec_decode=spec)
+    step, prefill = eng._step_fn, eng._prefill_fn
+
+    # (a) the jitted bodies keep the names the device trace is read by
+    assert step.python_fn.__name__ == (
+        "_" + "spec_" * spec + "mixed_" * chunked + "step_impl"
+        + "_paged" * paged)
+    assert (prefill is None) == chunked
+    if prefill is not None:
+        assert prefill.python_fn.__name__ == (
+            "_prefill_impl" + "_paged" * paged)
+
+    # (b) what a real tick and a real wave hand the device
+    seen = {"step": [], "prefill": []}
+
+    def spy(fn, where):
+        def call(*args):
+            seen[where].append(_types(args))
+            return fn(*args)
+        return call
+    eng._linted = True      # the first tick's self-lint would trace the spy
+    eng._step_fn = spy(step, "step")
+    if prefill is not None:
+        eng._prefill_fn = spy(prefill, "prefill")
+    rs = np.random.RandomState(3)
+    for n in (5, 11):
+        eng.submit(rs.randint(0, 256, n).astype(np.int32), max_new_tokens=4)
+    out = eng.drain()
+    assert [len(toks) for _, toks in out] == [4, 4]
+    assert seen["step"] and all(
+        got == _types(eng._lint_args()) for got in seen["step"])
+    assert bool(seen["prefill"]) == (prefill is not None)
+    for got in seen["prefill"]:
+        bucket = got[2].shape[1]
+        assert got == _types(eng._lint_args(bucket))
+
+    # (c) the body returns what the engine declares
+    s = SLOTS
+    i32 = np.dtype(np.int32)
+    want = {"tokens": ((s, K + 1) if spec else (s,), i32),
+            "n_acc": ((s,), i32), "chunk_token": ((), i32)}
+    res = jax.eval_shape(step.python_fn, *eng._lint_args())
+    assert len(res) == len(eng._step_outputs)
+    assert eng._step_outputs == (
+        ("tokens",) + ("n_acc",) * spec + ("chunk_token",) * chunked
+        + ("cache",))
+    for name, got in zip(eng._step_outputs[:-1], res):
+        assert (got.shape, got.dtype) == want[name], name
+    assert _types(res[-1]) == _types(eng._cache)
+    if prefill is not None:
+        tok, cache = jax.eval_shape(prefill.python_fn, *eng._lint_args(16))
+        assert (tok.shape, tok.dtype) == ((eng.prefill_batch,), i32)
+        assert _types(cache) == _types(eng._cache)
+
+    # (d) one step program for the engine's life
+    assert eng.step_traces == 1
+    assert eng.prefill_traces == (0 if chunked else len(
+        {got[2].shape[1] for got in seen["prefill"]}))
