@@ -486,8 +486,8 @@ def run_op_decode_attention(steps):
             "block_kv_cap": int(flags.flag("decode_attention_block_kv")),
             "read_model": "pallas rows stream only the live cache prefix "
                           "(per-row positions ride in as scalar prefetch "
-                          "and clamp the KV-chunk index maps; dead-tail "
-                          "DMAs are elided) — per-step time tracks depth; "
+                          "and size each row's in-kernel block walk) — "
+                          "per-step time tracks depth; "
                           "xla rows stream the whole max_length every step",
             "note": "chosen_path records the cached_decode_attention "
                     "dispatch for each shape at the committed flag default"}

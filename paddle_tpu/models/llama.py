@@ -326,8 +326,8 @@ class LlamaAttention(Layer):
             (max_len >= FLAGS_decode_attention_min_len) on Pallas
             backends to the split-KV flash-decode kernel
             (ops/pallas/decode_attention.py): the position vector rides
-            into the kernel as a scalar-prefetch operand and clamps the
-            KV-chunk index maps, so each step streams only each row's
+            into the kernel as a scalar-prefetch operand and sizes each
+            row's block walk, so each step streams only each row's
             LIVE cache prefix — per-step cost follows actual context
             depth, not max_len (the b=8 max_len-8192 regression in
             BENCH_DECODE.json).  Short caches keep the XLA math path,
@@ -353,8 +353,8 @@ class LlamaAttention(Layer):
         padded wave can never clobber live or shared blocks.  The
         attention read hands THE POOL, this layer's index and the table
         to :func:`~paddle_tpu.ops.attention.paged_decode_attention`,
-        whose Pallas kernel dereferences all three in its scalar-prefetch
-        index maps: no layer's K or V is sliced out of the pool or
+        whose Pallas kernel dereferences all three in the copies its body
+        issues: no layer's K or V is sliced out of the pool or
         reshaped, so the step's cache traffic is what it writes and the
         live blocks it reads.  Paged decode always uses per-row positions
         (a scalar is broadcast).

@@ -446,21 +446,22 @@ def cached_decode_attention(q, k_cache, v_cache, pos,
     (whole-batch decode, the ``generate()`` path) or an int (B,) vector of
     per-row positions (the serving engine's slot batch, where every row
     is a different request at a different depth).  ``live_len``: optional
-    STATIC upper bound on max(pos)+s — both paths then read only the
-    first ``live_len`` cache slots.
+    STATIC upper bound on max(pos)+s — the XLA path then reads only the
+    first ``live_len`` cache slots (the kernel stops at each row's own
+    depth without it).
 
     Dispatch: long-cache shapes (kv_len >= FLAGS_decode_attention_min_len)
     on Pallas backends route to the split-KV flash-decode kernel
-    (ops/pallas/decode_attention.py), whose scalar-prefetch-clamped index
-    maps stream only each row's LIVE cache prefix — per-step cost scales
-    with actual context depth, not max_length.  Everything else (and any
+    (ops/pallas/decode_attention.py), whose body walks only each row's
+    LIVE cache blocks (the trip count is read from ``pos``) — per-step
+    cost scales with actual context depth, not max_length.  Everything else (and any
     ``extra_mask``) runs :func:`cached_decode_attention_reference`, the
     XLA math path, which the decode bench measured at the weight-stream
     bound for short caches.  Returns (B, s, Hq, D) in q.dtype.
 
     ``k_scale``/``v_scale``: f32 ``(B, n_granules, Hkv)``
     per-granule-per-kv-head dequant scales for an int8 cache — the Pallas
-    kernel dequantizes inside its KV-chunk loop; the XLA fallback
+    kernel dequantizes inside its block walk; the XLA fallback
     dequantizes first.
 
     ``window`` (static int): sliding-window attention — the query at
@@ -478,7 +479,7 @@ def cached_decode_attention(q, k_cache, v_cache, pos,
     def pallas():
         from .pallas.decode_attention import decode_attention_pallas
         return decode_attention_pallas(
-            q, k_cache, v_cache, pos, scale=scale, live_len=live_len,
+            q, k_cache, v_cache, pos, scale=scale,
             k_scale=k_scale, v_scale=v_scale,
             interpret=_dispatch.pallas_interpret(), **win)
 
@@ -504,8 +505,8 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
     in physical block ``block_tables[i, j]`` (int (B, max_blocks)).
 
     The pool is handed on as it is: the Pallas kernel
-    (``paged_decode_attention_pallas``) takes it whole and dereferences
-    layer, K/V and block table in its scalar-prefetch index maps, so no
+    (``paged_decode_attention_pallas``) takes it whole, leaves it in HBM
+    and copies ``pool[layer, K|V, table[row, col]]`` block by block, so no
     per-layer slice of it is ever formed; the XLA fallback
     (:func:`paged_decode_attention_reference`) gathers the table's blocks
     into the contiguous layout first.  ``pos`` is the int (B,) vector of
@@ -525,7 +526,7 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
         from .pallas.decode_attention import paged_decode_attention_pallas
         return paged_decode_attention_pallas(
             q, pool, layer, pos, block_tables, scale=scale,
-            live_len=live_len, pool_scale=pool_scale,
+            pool_scale=pool_scale,
             interpret=_dispatch.pallas_interpret(), **win)
 
     return _run_decode_path(

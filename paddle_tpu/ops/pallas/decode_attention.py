@@ -4,35 +4,44 @@ serving hot path.
 TPU-native equivalent of the FlashDecoding scheme (Dao et al.; the
 PagedAttention-class engines' decode kernel on GPU): at q_len 1 the
 (1, L) score row gives the MXU nothing to tile, so the win is pure
-dataflow — split the KV cache into chunks, keep online-softmax partials
-(m, l, acc) in VMEM across the chunk walk, and never materialise the
+dataflow — read each row's live keys once, keep online-softmax partials
+(m, l, acc) in VMEM across the walk, and never materialise the
 (B, Hq, s, L) score tensor the XLA math path
 (:func:`~paddle_tpu.ops.attention.cached_decode_attention_reference`)
 builds in HBM.
 
-What makes this kernel O(actual context depth) instead of O(max_length)
-— the regime BENCH_DECODE.json flagged (b=8, max_length 8192: 4.27 ms vs
-the 2.78 ms bf16 weight-stream floor, 0.652x of the bound, because the
-math path streams and mask-softmaxes the dead tail of the pre-allocated
-cache every step):
+**The walk is the row's own.**  The grid is ``(rows, q tiles)`` and
+nothing else; the cache stays in HBM (``memory_space=pl.ANY``) and the
+per-row positions, the block table and the int8 scale tables ride in as
+scalar-prefetch operands.  For its ``(row, q tile)`` the body computes the
+range of table columns that hold a key some query of the tile may see —
+``[first, last]``, :func:`live_block_range`: the dead tail past the row's
+depth is outside it, and so is everything behind a sliding window — and
+runs a loop of ``ceil((last - first + 1) / G)`` trips over it.  Each trip
+waits on one *group* of ``G`` blocks of K and of V, copied by
+``pltpu.make_async_copy`` into a double-buffered VMEM scratch (one DMA
+semaphore a copy), starts the next group's copies, and makes one
+online-softmax update over the group's ``G · block`` keys a head: one
+``(tile, D) x (D, G · block)`` product and one rescale a group.  A column
+of the last group past ``last`` is not copied (its V rows are zeroed, its
+scores masked by the position mask), and no table column outside
+``[first, last]`` is ever dereferenced: those may hold the null block.
+The step program is traced once and the trip count is read from the
+positions, so a tick costs what its rows' live blocks cost, whatever the
+deepest row or the table's width.  The next ``(row, q tile)``'s first
+group is issued during this one's last trip, so a row's first copy does
+not wait behind the row before it; the grid is therefore sequential
+(``arbitrary``) and one SMEM word carries which buffer the next step
+starts in.
 
-  * per-row positions arrive as a **scalar-prefetch** operand, so the
-    KV-chunk BlockSpec index maps can read them *before* the grid step
-    runs and **clamp dead-tail chunks to the last live block** — Pallas
-    elides the DMA when consecutive grid steps map to the same block, so
-    the dead tail of the cache is never streamed from HBM.  This is the
-    dynamic-shape-safe form of "the host passes ceil((max(pos)+s)/BLOCK)
-    as the KV-chunk grid bound": the bound is derived in-kernel from the
-    position vector itself, the grid stays static, and the serving
-    engine's once-jitted step function never retraces as slots deepen;
-  * a caller who *does* know a static bound (the bench depth sweep)
-    passes ``live_len`` and the grid is trimmed outright;
-  * dead chunks also skip their matmuls via ``pl.when`` — a skipped
-    chunk costs one predicated-off grid step, not bandwidth.
+``G`` is :func:`group_blocks`: as many blocks as hold ``GROUP_KEYS`` keys
+(4 of the paged pool's 128-position blocks).  :func:`walk_counts` gives,
+from the same bounds, the blocks a call's rows need and the block slots
+the kernel walks for them; the serving engine puts both on its spans.
 
 GQA stays grouped: Q is reshaped to (B, Hkv, G·s, D) and each kv head's
 (G·s, D) query tile contracts the cache directly — bf16 operands on the
-MXU with an fp32 accumulator, no Hq/Hkv KV broadcast.  A KV chunk is a
+MXU with an fp32 accumulator, no Hq/Hkv KV broadcast.  A KV block is a
 ``(bk, Hkv·D)`` tile — rows of all kv heads' features side by side — so
 it is one contiguous DMA and the per-head (bk, D) slice is a static lane
 slice in VMEM.  The paged pool is STORED that way (below); the
@@ -43,20 +52,21 @@ Per-row ``pos`` masking happens inside the kernel
 (key j visible to query row (si, g) iff j <= pos_b + si) with the same
 fully-masked-row convention as the flash kernel (out = 0).
 
-The cross-chunk merge is the same LSE algebra the ring-attention path
+The cross-group merge is the same LSE algebra the ring-attention path
 uses (ops/ring_attention.py ``merge_attention``), specialised to the
-running (m, l, acc) form since chunks arrive sequentially.
+running (m, l, acc) form since groups arrive sequentially.
 
 **Chunked prefill** (serving/engine.py mixed steps): the same kernel
 generalises from q_len 1 to a q *chunk* — a span of prompt tokens
 attending its cached prefix plus its own causal self-block.  q is cut
 into tiles of ``bq`` tokens (``bq·G <= 64`` MXU rows each, sublane-padded
-per tile) walked by a second grid dimension; the per-row ``pos`` mask
+per tile) walked by the second grid dimension; the per-row ``pos`` mask
 already encodes "key j visible to query offset si iff j <= pos + si", so
-prefix + self-block causality needs no new machinery, and the dead-tail
-clamp becomes per-tile (early q tiles skip the chunk's own later KV
-blocks — causal block skipping for free).  Routing for these shapes is
-counted under ``ops.kernel_path{op="chunked_prefill"}``.
+prefix + self-block causality needs no new machinery, and ``last`` is
+per tile (early q tiles stop before the chunk's own later KV blocks —
+causal block skipping for free).  Each tile reads its range again.
+Routing for these shapes is counted under
+``ops.kernel_path{op="chunked_prefill"}``.
 
 **Speculative verify** (serving/engine.py spec-decode steps): the q-tile
 machinery above IS the verify pass of self-drafted speculative decoding —
@@ -71,35 +81,35 @@ their routing decisions) land under ``ops.kernel_path{op="spec_verify"}``
 instead of the prefill-chunk label.
 
 **Paged KV cache** (serving/kv_cache.py,
-:func:`paged_decode_attention_pallas`): the kernel also serves the
-block-table layout, where the cache of ALL layers is one pooled
-``(L, 2, num_blocks, block_len, Hkv·D)`` array and each row's logical
-positions are backed by the physical blocks its ``(B, max_blocks)`` block
-table names.  The kernel is handed **the pool itself** and the static
-layer index: K and V are the same operand, and their index maps return
-``(layer, 0 | 1, table[bi, min(ki, last_live)], 0, 0)`` — the table rides
-in as a SECOND scalar-prefetch operand.  Nothing is sliced out of the
-pool and nothing is reshaped, so a step's HBM traffic is the live blocks
-it reads and the rows it writes, whatever the pool's size.  One KV chunk
-== one cache block (``block_len`` must be 128-aligned), so a block is one
-contiguous DMA, blocks may be scattered anywhere in the pool, shared
-between rows, or partially filled (the in-kernel ``pos`` mask already
+:func:`paged_decode_attention_pallas`): the cache of ALL layers is one
+pooled ``(L, 2, num_blocks, block_len, Hkv·D)`` array and each row's
+logical positions are backed by the physical blocks its
+``(B, max_blocks)`` block table names.  The kernel is handed **the pool
+itself** and copies ``pool[layer, 0 | 1, table[row, col]]``.  Nothing is
+sliced out of the pool and nothing is reshaped, so a step's HBM traffic
+is the live blocks it reads and the rows it writes, whatever the pool's
+size.  The layer index rides in as one more scalar-prefetch value and
+the call is jitted on its own (:func:`_flash_call`), so a model's layers
+are ONE kernel body traced and lowered once a program, not once a layer
+(the body unrolls heads and a group's blocks: traced sixteen times a
+program it doubled the benchmark's set-up time).  One table
+column == one cache block (``block_len`` must be 128-aligned), so a block
+is one contiguous DMA, blocks may be scattered anywhere in the pool,
+shared between rows, or partially filled (the in-kernel ``pos`` mask
 handles partial blocks — column indices are logical).  The contiguous
 layout runs the same kernel under the identity table
 ``table[bi, ki] = bi·chunks + ki`` over its reshaped
-``(B·chunks, bk, Hkv·D)`` cache, which is how PR 2's dead-tail clamping
-now reads — clamping the logical chunk index before the table lookup maps
-dead-tail grid steps to the row's last live block, the DMA is elided, and
-the kernel's reads still stop at the live prefix.
+``(B·chunks, bk, Hkv·D)`` cache.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -114,6 +124,12 @@ _LANES = _limits.LANES  # VPU lane width: m/l scratch rows padded to this
 _MAX_Q_ROWS = _limits.MAX_Q_ROWS  # per-TILE s·G row cap — larger q tiles
 _MAX_Q_LEN = _limits.MAX_Q_LEN  # beyond this: whole-prefill, flash territory
 
+# keys one copy group holds: four of the pool's 128-position blocks, 1 MB
+# of K and 1 MB of V at Hkv·D = 1024 in bf16, twice that double-buffered.
+# Chosen on the chip (PERF.md §6, PR 30); kernel.decode_walk_live_pct is
+# what another choice is judged by.
+GROUP_KEYS = 512
+
 
 def _pick_block_kv(kv_len: int, cap: int) -> int:
     """Largest KV chunk <= cap that divides kv_len on the 128-lane
@@ -124,101 +140,245 @@ def _pick_block_kv(kv_len: int, cap: int) -> int:
     return 0
 
 
-def _kernel(pos_ref, bt_ref, *refs, scale, s, g, hkv, d, bq, tile_p, bk,
-            chunks, n_cols, quantized, window=None):
+def contiguous_block_kv(kv_len: int, n_gran: Optional[int] = None,
+                        block_kv: int = 0) -> int:
+    """The KV block the kernel cuts a CONTIGUOUS cache of ``kv_len``
+    positions into: an int8 cache's scale granule (``n_gran`` of them: one
+    block == one (block, head) scale entry, exactly the paged contract),
+    else the largest 128-aligned divisor under ``block_kv`` (0: the
+    flag's cap).  Raises NotImplementedError where there is none."""
+    if n_gran is not None:
+        bk = kv_len // n_gran
+        if bk * n_gran != kv_len or bk % 128:
+            raise NotImplementedError(
+                f"int8 scale granule {kv_len}/{n_gran} is not a "
+                f"128-aligned divisor of the cache length")
+        return bk
+    if not block_kv:
+        from ...flags import flag
+        block_kv = int(flag("decode_attention_block_kv"))
+    bk = _pick_block_kv(kv_len, block_kv)
+    if not bk:
+        raise NotImplementedError(
+            f"max_length {kv_len} has no 128-aligned chunk "
+            f"divisor <= {block_kv}")
+    return bk
+
+
+def group_blocks(bk: int) -> int:
+    """Blocks of ``bk`` positions one copy group holds (the kernel's G)."""
+    return max(1, GROUP_KEYS // int(bk))
+
+
+def q_tiles(s: int, g: int) -> Tuple[int, int]:
+    """``(bq, nq)``: one grid step covers ``bq`` query tokens (``bq·g``
+    MXU rows) and ``nq`` of them cover the ``s`` tokens.  ``s <= bq`` is
+    steady decode or a verify window, one tile."""
+    bq = min(s, max(1, _MAX_Q_ROWS // g))
+    return bq, -(-s // bq)
+
+
+def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, xp=jnp):
+    """``(first, last)``: the table columns holding a key that some query
+    of q tile ``qi`` (offsets ``qi·bq .. min((qi+1)·bq, s) - 1``) of a row
+    at position ``pos`` may see — the columns the kernel walks, and the
+    only ones it dereferences.  ``last`` holds the tile's last query's own
+    key; ``first`` is 0, or with a sliding ``window`` the block of the
+    oldest key the tile's FIRST query still sees.  Both stay inside the
+    table whatever ``pos`` (an idle row parked past the cache included).
+    Scalars in the kernel (``xp=jnp``), arrays on the host (``xp=np``)."""
+    last = xp.minimum((pos + xp.minimum((qi + 1) * bq, s) - 1) // bk,
+                      n_cols - 1)
+    if window is None:
+        return xp.zeros_like(last), last
+    return xp.minimum(xp.maximum(pos + qi * bq - window + 1, 0) // bk,
+                      last), last
+
+
+def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
+                window: Optional[int] = None) -> Tuple[int, int]:
+    """``(kv_blocks, kv_walk)`` of one kernel call on the host, from the
+    bounds the kernel itself uses: the blocks its rows' q tiles need
+    (``last - first + 1`` each) and the block slots it walks for them
+    (whole groups of :func:`group_blocks`).  ``pos``: the call's per-row
+    positions; ``s``, ``g``: its q length and GQA group size."""
+    bq, nq = q_tiles(int(s), int(g))
+    first, last = live_block_range(
+        np.asarray(pos, np.int64).reshape(-1, 1), np.arange(nq)[None],
+        s=int(s), bq=bq, bk=int(bk), n_cols=int(n_cols), window=window,
+        xp=np)
+    need = last - first + 1
+    gb = group_blocks(bk)
+    return int(need.sum()), int((-(-need // gb) * gb).sum())
+
+
+def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
+            tile_p, bk, gb, n_cols, quantized, paged, window):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
         # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
         # already gathered per row by the wrapper, so the body reads one
-        # scalar per (row, chunk, head) and nothing scale-sized is ever
+        # scalar per (row, block, head) and nothing scale-sized is ever
         # blocked through VMEM (a (1, hkv) block does not tile on a TPU)
-        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_sc, m_sc, l_sc = refs
-    del bt_ref  # consumed by the index maps, not the body
+        ks_ref, vs_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref,
+     k_buf, v_buf, sems, slot_sc, acc_sc, m_sc, l_sc) = refs
     bi = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    n_rows = pl.num_programs(0)
+    bounds = functools.partial(live_block_range, s=s, bq=bq, bk=bk,
+                               n_cols=n_cols, window=window)
     pos_b = pos_ref[bi]
-    # last chunk holding a key visible to ANY row of this q tile (query
-    # offsets qi·bq .. min((qi+1)·bq, s) - 1)
-    last_live = (pos_b + jnp.minimum((qi + 1) * bq, s) - 1) // bk
+    first, last = bounds(pos_b, qi)
+    n_groups = (last - first + gb) // gb
+    gk = gb * bk                                  # keys a group
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
+    def block_at(ref, which, blk):
+        """The ``(bk, Hkv·D)`` block ``blk`` of K (``which`` 0) or V (1) as
+        it lies in HBM: the pool's, under this call's layer, or the
+        reshaped contiguous cache's."""
+        return ref.at[layer_ref[0], which, blk] if paged else ref.at[blk]
 
-    live = ki <= last_live
-    if window is not None:
-        # first chunk holding a key inside the window of ANY row of this
-        # q tile (its earliest query, offset qi·bq, sees back furthest)
-        live &= ki >= jnp.maximum(pos_b + qi * bq - window + 1, 0) // bk
+    def copies(row, lo, hi, j, slot):
+        """Per block of group ``j`` of ``row``'s walk ``[lo, hi]``: whether
+        its column is inside the walk, and its K and V copies into buffer
+        ``slot``.  The table is read at ``min(col, hi)``, never outside
+        the walk."""
+        out = []
+        for i in range(gb):
+            col = lo + j * gb + i
+            blk = bt_ref[row, jnp.minimum(col, hi)]
+            at = pl.ds(i * bk, bk)
+            out.append((
+                col <= hi,
+                pltpu.make_async_copy(block_at(k_hbm, 0, blk),
+                                      k_buf.at[slot, at],
+                                      sems.at[slot, 0, i]),
+                pltpu.make_async_copy(block_at(v_hbm, 1, blk),
+                                      v_buf.at[slot, at],
+                                      sems.at[slot, 1, i])))
+        return out
 
-    @pl.when(live)
-    def _compute():
+    def start(row, lo, hi, j, slot):
+        for live, ck, cv in copies(row, lo, hi, j, slot):
+            @pl.when(live)
+            def _start():
+                ck.start()
+                cv.start()
+
+    # the walk's buffers alternate across the WHOLE grid: this step's
+    # group j sits in buffer (slot0 + j) % 2, and its first group was
+    # issued by the step before (the very first step issues its own)
+    @pl.when((bi == 0) & (qi == 0))
+    def _first_step():
+        slot_sc[0] = 0
+        start(bi, first, last, 0, 0)
+
+    slot0 = slot_sc[0]
+    step_q = jnp.where(qi + 1 < nq, qi + 1, 0)
+    step_b = jnp.minimum(jnp.where(qi + 1 < nq, bi, bi + 1), n_rows - 1)
+    has_next = (qi + 1 < nq) | (bi + 1 < n_rows)
+    next_first, next_last = bounds(pos_ref[step_b], step_q)
+
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+
+    def group(j, carry):
+        slot = (slot0 + j) % 2
+        in_row = j + 1 < n_groups
+
+        @pl.when(in_row | has_next)
+        def _prefetch():
+            start(jnp.where(in_row, bi, step_b),
+                  jnp.where(in_row, first, next_first),
+                  jnp.where(in_row, last, next_last),
+                  jnp.where(in_row, j + 1, 0), 1 - slot)
+
+        for i, (live, ck, cv) in enumerate(copies(bi, first, last, j, slot)):
+            @pl.when(live)
+            def _wait():
+                ck.wait()
+                cv.wait()
+
+            if i:       # a group's first column is always inside the walk
+                @pl.when(jnp.logical_not(live))
+                def _blank():
+                    # never copied: whatever the buffer holds there must
+                    # not reach the PV product as 0 · NaN (its scores are
+                    # masked: the columns lie past every visible position)
+                    v_buf[slot, pl.ds(i * bk, bk)] = jnp.zeros(
+                        (bk, hkv * d), v_buf.dtype)
+
         # key j visible to tile row r = si·g + gi (si local to the tile)
         # iff j <= pos_b + qi·bq + si; rows past bq·g are sublane padding
         # and rows whose query offset runs past s are the last tile's
         # ragged tail — both fully masked (out = 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (tile_p, bk), 1) + ki * bk
-        rr = jax.lax.broadcasted_iota(jnp.int32, (tile_p, bk), 0)
+        cols = (jax.lax.broadcasted_iota(jnp.int32, (tile_p, gk), 1)
+                + (first + j * gb) * bk)
+        rr = jax.lax.broadcasted_iota(jnp.int32, (tile_p, gk), 0)
         si = qi * bq + rr // g
         keep = (cols <= pos_b + si) & (rr < bq * g) & (si < s)
         if window is not None:
             keep &= cols > pos_b + si - window
-        kv = k_ref[0]  # (bk, hkv·d) — one contiguous chunk, all kv heads
-        vv = v_ref[0]
+        if quantized:
+            block_of = jax.lax.broadcasted_iota(jnp.int32, (1, gk), 1) // bk
+
+            def by_block(ref, h):
+                """The group's per-block scales of head ``h`` as one
+                (1, gk) row (a scalar when the group is one block)."""
+                at = [(bi * n_cols + jnp.minimum(first + j * gb + i, last))
+                      * hkv + h for i in range(gb)]
+                row = ref[at[0]]
+                for i in range(1, gb):
+                    row = jnp.where(block_of >= i, ref[at[i]], row)
+                return row
+
         for h in range(hkv):
-            qh = q_ref[0, h]                   # (tile_p, d)
-            kh = kv[:, h * d:(h + 1) * d]      # static lane slice
-            vh = vv[:, h * d:(h + 1) * d]
+            qh = q_ref[0, h]                              # (tile_p, d)
+            kh = k_buf[slot, :, pl.ds(h * d, d)]          # static lane slice
+            vh = v_buf[slot, :, pl.ds(h * d, d)]
+            k_s = scale
             if quantized:
                 # int8 in [-127, 127] is exact in bf16, so the cast is
-                # lossless; the block's uniform scale folds into the
-                # existing post-dot scalar multiplies (K into the
-                # softmax scale, V after the PV accumulate) — no
-                # per-element dequant multiply on the chunk
+                # lossless; each block's uniform scale folds into the
+                # multiplies that are there anyway (K into the softmax
+                # scale, V into the probabilities) — no per-element
+                # dequant multiply on the blocks
                 kh = kh.astype(qh.dtype)
                 vh = vh.astype(qh.dtype)
-                sc_at = (bi * n_cols + ki) * hkv + h
-                k_s = scale * ks_ref[sc_at]
-                v_s = vs_ref[sc_at]
-            else:
-                k_s = scale
+                k_s = scale * by_block(ks_ref, h)
             sc = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * k_s  # (tile_p, bk)
+                preferred_element_type=jnp.float32) * k_s  # (tile_p, gk)
             sc = jnp.where(keep, sc, NEG_INF)
             m_prev = m_sc[h][:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)    # rescale earlier chunks
+            alpha = jnp.exp(m_prev - m_new)    # rescale earlier groups
             p = jnp.exp(sc - m_new)
             p = jnp.where(keep, p, 0.0)  # kill exp(NEG_INF - NEG_INF) = 1
             l_new = alpha * l_sc[h][:, :1] + jnp.sum(p, axis=1,
                                                      keepdims=True)
+            if quantized:
+                p = p * by_block(vs_ref, h)
             pv = jax.lax.dot_general(
                 p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            if quantized:
-                pv = pv * v_s
             acc_sc[h] = acc_sc[h] * alpha + pv
             m_sc[h] = jnp.broadcast_to(m_new, m_sc[h].shape)
             l_sc[h] = jnp.broadcast_to(l_new, l_sc[h].shape)
+        return carry
 
-    @pl.when(ki == chunks - 1)
-    def _finish():
-        for h in range(hkv):
-            l = l_sc[h][:, :1]
-            o_ref[0, h] = (acc_sc[h]
-                           / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    slot_sc[0] = (slot0 + n_groups) % 2
+    for h in range(hkv):
+        l = l_sc[h][:, :1]
+        o_ref[0, h] = (acc_sc[h] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k_cache, v_cache, pos,
                             scale: Optional[float] = None,
                             block_kv: int = 0,
-                            live_len: Optional[int] = None,
                             interpret: bool = False,
                             k_scale=None, v_scale=None,
                             window: Optional[int] = None):
@@ -228,21 +388,17 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
 
     q: (B, s, Hq, D) new-token queries (s = 1 in steady-state decode,
     small for prefill-into-occupied-slot); ``pos``: scalar or int (B,)
-    per-row positions — cache slots > pos+i are masked.  k_cache/v_cache
-    are (B, L, Hkv, D) with the new K/V already written.
-
-    ``live_len``: optional static bound on max(pos)+s (trims the chunk
-    grid outright; without it the scalar-prefetch clamp stops the HBM
-    streaming at each row's live prefix dynamically).  Raises
-    NotImplementedError for shapes the kernel does not cover (callers
-    fall back to the XLA math path).
+    per-row positions — cache slots > pos+i are masked, and the blocks
+    past them are not read.  k_cache/v_cache are (B, L, Hkv, D) with the
+    new K/V already written.  Raises NotImplementedError for shapes the
+    kernel does not cover (callers fall back to the XLA math path).
 
     **int8 cache** (``k_scale``/``v_scale`` given): k_cache/v_cache hold
     int8 payloads and the f32 ``(B, n_granules, Hkv)`` scales carry the
-    per-granule-per-kv-head dequant factor; the KV chunk is pinned to
+    per-granule-per-kv-head dequant factor; the KV block is pinned to
     the scale granule (``kv_len // n_granules``, 128-aligned).  Dequant
-    happens inside the chunk loop by folding each granule's scale into
-    the post-dot scalar multiplies, so the HBM stream is the int8
+    happens inside the walk by folding each granule's scale into the
+    multiplies that follow the products, so the HBM stream is the int8
     payload — half the bf16 bytes.
     """
     quantized = k_scale is not None
@@ -250,24 +406,8 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
         raise ValueError("int8 cache needs both k_scale and v_scale")
     b, kv_len, hkv, d = k_cache.shape
     _check_q(q, hkv)
-    if quantized:
-        # the scale granule pins the KV chunk: one chunk == one
-        # (block, head) scale entry, exactly the paged contract
-        n_gran = k_scale.shape[1]
-        bk = kv_len // n_gran
-        if bk * n_gran != kv_len or bk % 128:
-            raise NotImplementedError(
-                f"int8 scale granule {kv_len}/{n_gran} is not a "
-                f"128-aligned divisor of the cache length")
-    else:
-        if not block_kv:
-            from ...flags import flag
-            block_kv = int(flag("decode_attention_block_kv"))
-        bk = _pick_block_kv(kv_len, block_kv)
-        if not bk:
-            raise NotImplementedError(
-                f"max_length {kv_len} has no 128-aligned chunk "
-                f"divisor <= {block_kv}")
+    bk = contiguous_block_kv(
+        kv_len, k_scale.shape[1] if quantized else None, block_kv)
     # contiguous = paged under the identity table: the cache reshaped to
     # a (B·chunks, bk, Hkv·D) pool with table [bi, ki] = bi·chunks + ki —
     # same DMAs, one kernel
@@ -276,19 +416,13 @@ def decode_attention_pallas(q, k_cache, v_cache, pos,
           + jnp.arange(full, dtype=jnp.int32)[None, :])
     k2 = k_cache.reshape(b * full, bk, hkv * d)
     v2 = v_cache.reshape(b * full, bk, hkv * d)
-
-    def at(blk):
-        return (blk, 0, 0)
-
     return _flash_decode(
-        q, k2, v2, (1, bk, hkv * d), at, at, pos, bt, scale=scale,
-        live_len=live_len, interpret=interpret, layout="contiguous",
+        q, k2, v2, pos, bt, scale=scale, interpret=interpret, layer=None,
         scales=(k_scale, v_scale) if quantized else None, window=window)
 
 
 def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
                                   scale: Optional[float] = None,
-                                  live_len: Optional[int] = None,
                                   interpret: bool = False,
                                   pool_scale=None,
                                   window: Optional[int] = None):
@@ -298,15 +432,15 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
     ``pool`` is the whole ``(L, 2, num_blocks, block_len, Hkv·D)`` array
     of every layer's K (index 0) and V (index 1) blocks, the new K/V
     already written, and ``layer`` a static int: the kernel takes the
-    pool as its K and its V operand and its index maps pick
-    ``(layer, 0 | 1, block)`` — no per-layer slice, no reshape.
+    pool as it lies in HBM and copies ``pool[layer, 0 | 1, block]`` — no
+    per-layer slice, no reshape.
     ``block_tables`` is the int (B, max_blocks) map from each row's
     logical block index to its physical block (every entry valid, dead
     tail null-filled).  The logical cache length is
-    ``max_blocks · block_len`` and the KV chunk is pinned to one block,
+    ``max_blocks · block_len`` and one table column is one block,
     so ``block_len`` must be 128-aligned.  ``Hkv`` is read from the
     shapes (``pool.shape[-1] // q.shape[-1]``), so a head-sharded shard of
-    the pool works unchanged.  ``pos``, ``live_len`` and the refusals
+    the pool works unchanged.  ``pos`` and the refusals
     are :func:`decode_attention_pallas`'s.
 
     **int8 pool** (``pool_scale`` given): ``pool`` holds int8 payloads and
@@ -318,10 +452,10 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
 
     ``window`` (static): sliding-window attention — key ``j`` is visible
     to the query at position ``i`` only while ``i - j < window``.  The
-    mask gains that lower bound, and the block walk a lower clamp beside
-    the dead-tail one: blocks wholly behind the window of every query of a
-    q tile are neither DMA'd nor scored.  ``None`` builds the kernel as it
-    was.
+    mask gains that lower bound, and the block walk starts at the window's
+    first block: blocks wholly behind the window of every query of a
+    q tile are neither copied nor scored.  ``None`` is full causal
+    attention.
     """
     d = q.shape[-1]
     bk, hd = pool.shape[-2:]
@@ -336,11 +470,8 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
                         axis=1, mode="clip")    # (2, B, max_blocks, Hkv)
         scales = (rows[0], rows[1])
     return _flash_decode(
-        q, pool, pool, (None, None, 1, bk, hd),
-        lambda blk: (layer, 0, blk, 0, 0),
-        lambda blk: (layer, 1, blk, 0, 0), pos, bt, scale=scale,
-        live_len=live_len, interpret=interpret, layout="paged",
-        scales=scales, window=window)
+        q, pool, pool, pos, bt, scale=scale, interpret=interpret,
+        layer=int(layer), scales=scales, window=window)
 
 
 def _check_q(q, hkv: int) -> None:
@@ -361,44 +492,72 @@ def _check_q(q, hkv: int) -> None:
             f"head_dim {d} > {_limits.MAX_HEAD_DIM}")
 
 
-def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
-                  live_len, interpret, layout, scales, window=None):
-    """The one ``pallas_call`` behind both layouts.  ``k_arr``/``v_arr``
-    are the operands as they lie in HBM, ``kv_block`` the BlockSpec shape
-    that cuts one ``(1, bk, Hkv·D)`` chunk out of them, and
-    ``k_at``/``v_at`` map a physical block id to that chunk's block
-    index; ``bt`` is the (B, chunks) table of block ids and ``scales``
-    the int8 cache's (B, chunks, Hkv) K and V scale tables, or None;
-    ``window`` the static sliding window, or None."""
+def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
+                  scales, window=None):
+    """Both layouts' way into the one ``pallas_call``.  ``k_arr``/``v_arr``
+    are the operands as they lie in HBM (the kernel leaves them there):
+    the paged pool twice with its ``layer``, or the contiguous cache's K
+    and V reshaped to ``(blocks, bk, Hkv·D)`` with ``layer`` None; ``bt``
+    is the (B, columns) table of block ids and ``scales`` the int8 cache's
+    (B, columns, Hkv) K and V scale tables, or None; ``window`` the static
+    sliding window, or None."""
     b, s, hq, d = q.shape
-    bk, hd = kv_block[-2:]
-    hkv = hd // d
-    g = hq // hkv
-    rows = s * g
+    hkv = k_arr.shape[-1] // d
     quantized = scales is not None
-    # q tiling: one grid step covers bq query tokens (bq·g MXU rows).
-    # s <= bq is the steady-decode / small-s case — nq == 1, exactly the
-    # original kernel.  Larger s (a chunked-prefill q chunk attending its
-    # paged prefix plus its own causal self-block) walks q tiles over a
-    # second grid dimension; the per-tile dead-tail clamp skips KV chunks
-    # past pos + (qi+1)·bq - 1, so early tiles also skip the chunk's own
-    # later keys — causal block skipping for free.
-    bq = min(s, max(1, _MAX_Q_ROWS // g))
-    nq = -(-s // bq)
-    if scale is None:
-        scale = d ** -0.5
-    n_cols = chunks = bt.shape[1]
+    n_cols = bt.shape[1]
     if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
         raise NotImplementedError(
             f"int8 scale table {b}x{n_cols}x{hkv} > "
             f"{_limits.MAX_SCALE_TABLE} SMEM entries")
-    if live_len is not None:
-        chunks = max(1, min(chunks, -(-int(live_len) // bk)))
-    tile_p = max(8, -(-(bq * g) // 8) * 8)  # sublane-pad each q tile
     if getattr(pos, "ndim", 0) == 1:
         pos_arr = jnp.asarray(pos, jnp.int32)
     else:
         pos_arr = jnp.full((b,), pos, jnp.int32)
+    paged = layer is not None
+
+    # past every eligibility gate: this trace builds the kernel — count
+    # which cache layout it was built for (routing visibility, trace-time
+    # side effect only); a tiled q walk is the chunked-prefill mode, and
+    # an active kernel_path_hint relabels the build (the serving engine's
+    # speculative verify window counts as op="spec_verify" — same q-tiled
+    # machinery, different meaning: the q rows are draft tokens scored
+    # against the live cache, not a prompt chunk streaming in)
+    from .. import _dispatch as _disp
+    _disp.count_kernel_path(
+        _disp.kernel_path_op(
+            "chunked_prefill" if q_tiles(s, hq // hkv)[1] > 1
+            else "decode_attention_kernel"),
+        "paged" if paged else "contiguous",
+        **({"cache": "int8"} if quantized else {}))
+
+    scalars = (pos_arr, bt, jnp.full((1,), layer or 0, jnp.int32))
+    if quantized:
+        scalars += tuple(jnp.asarray(t, jnp.float32).reshape(-1)
+                         for t in scales)
+    return _flash_call(
+        scalars, q, k_arr, v_arr,
+        scale=float(d ** -0.5 if scale is None else scale), paged=paged,
+        window=None if window is None else int(window),
+        interpret=interpret, name=_disp.kernel_name("flash_decode"))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "paged", "window",
+                                             "interpret", "name"))
+def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
+                name):
+    """The ``pallas_call`` with the q layout round it, jitted on its own:
+    a model's layers differ only in the VALUE of the layer scalar, so they
+    share one trace of the kernel body and one lowering of it in every
+    program that calls them (``scalars``: positions, block table, layer,
+    then the int8 cache's K and V scale tables)."""
+    b, s, hq, d = q.shape
+    bk, hd = k_arr.shape[-2:]
+    hkv = hd // d
+    g = hq // hkv
+    rows = s * g
+    bq, nq = q_tiles(s, g)
+    gb = group_blocks(bk)
+    tile_p = max(8, -(-(bq * g) // 8) * 8)  # sublane-pad each q tile
     # grouped-GQA q layout: (B, Hkv, s·G, D), row r = si·g + gi — then cut
     # into nq tiles of bq·g rows, each sublane-padded to tile_p, so one
     # BlockSpec block == one padded tile at row offset qi·tile_p
@@ -412,81 +571,43 @@ def _flash_decode(q, k_arr, v_arr, kv_block, k_at, v_at, pos, bt, *, scale,
                           (0, 0)))
     qg = qg.reshape(b, hkv, nq * tile_p, d)
 
-    # past every eligibility gate: this trace builds the kernel — count
-    # which cache layout it was built for (routing visibility, trace-time
-    # side effect only); a tiled q walk is the chunked-prefill mode, and
-    # an active kernel_path_hint relabels the build (the serving engine's
-    # speculative verify window counts as op="spec_verify" — same q-tiled
-    # machinery, different meaning: the q rows are draft tokens scored
-    # against the live cache, not a prompt chunk streaming in)
-    from .. import _dispatch as _disp
-    _disp.count_kernel_path(
-        _disp.kernel_path_op(
-            "chunked_prefill" if nq > 1 else "decode_attention_kernel"),
-        layout, **({"cache": "int8"} if quantized else {}))
-
     kernel = functools.partial(
-        _kernel, scale=float(scale), s=s, g=g, hkv=hkv, d=d, bq=bq,
-        tile_p=tile_p, bk=bk, chunks=chunks, n_cols=n_cols,
-        quantized=quantized,
-        **({} if window is None else {"window": int(window)}))
+        _kernel, scale=scale, s=s, g=g, hkv=hkv, d=d, bq=bq, nq=nq,
+        tile_p=tile_p, bk=bk, gb=gb, n_cols=scalars[1].shape[1],
+        quantized=len(scalars) > 3, paged=paged, window=window)
 
-    def q_idx(bi, qi, ki, pos_ref, bt_ref, *_):
+    def q_idx(bi, qi, *_):
         return (bi, 0, qi, 0)
-
-    def live_block(bi, qi, ki, pos_ref, bt_ref):
-        # clamp the LOGICAL chunk index to this q tile's last live block,
-        # then dereference the block table: dead-tail chunks re-map to the
-        # same physical block as the previous grid step → Pallas elides
-        # the DMA, so HBM traffic stops at the tile's live prefix.
-        # Null-block aliasing rule (checked statically by the kernel
-        # pre-flight's ClampCheck and asserted by kv_cache.table_row):
-        # dead-tail table columns past `last` MAY hold NULL_BLOCK (0) —
-        # the clamp guarantees they are never dereferenced — but a LIVE
-        # column (<= last) mapping to block 0 would alias the null
-        # block's pad data into this row's attention window.
-        last = (pos_ref[bi] + jnp.minimum((qi + 1) * bq, s) - 1) // bk
-        if window is None:
-            return bt_ref[bi, jnp.minimum(ki, last)]
-        # the same trick from below: grid steps before the tile's first
-        # block inside the window re-map to that block, which is then
-        # fetched once and, until the walk reaches it, not scored
-        first = jnp.maximum(pos_ref[bi] + qi * bq - window + 1, 0) // bk
-        return bt_ref[bi, jnp.clip(ki, first, last)]
-
-    def k_idx(bi, qi, ki, pos_ref, bt_ref, *_):
-        return k_at(live_block(bi, qi, ki, pos_ref, bt_ref))
-
-    def v_idx(bi, qi, ki, pos_ref, bt_ref, *_):
-        return v_at(live_block(bi, qi, ki, pos_ref, bt_ref))
-
-    scalars = (pos_arr, bt)
-    if quantized:
-        scalars += tuple(jnp.asarray(t, jnp.float32).reshape(-1)
-                         for t in scales)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(b, nq, chunks),
+            grid=(b, nq),
             in_specs=[
                 pl.BlockSpec((1, hkv, tile_p, d), q_idx),
-                pl.BlockSpec(kv_block, k_idx),
-                pl.BlockSpec(kv_block, v_idx),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, hkv, tile_p, d), q_idx),
             scratch_shapes=[
+                # two buffers of one group of K and of V blocks, a DMA
+                # semaphore a copy, and the buffer the next step starts in
+                pltpu.VMEM((2, gb * bk, hd), k_arr.dtype),
+                pltpu.VMEM((2, gb * bk, hd), v_arr.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, gb)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((hkv, tile_p, d), jnp.float32),
                 pltpu.VMEM((hkv, tile_p, _LANES), jnp.float32),
                 pltpu.VMEM((hkv, tile_p, _LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, nq * tile_p, d), q.dtype),
+        # sequential: a step issues the next step's first copies
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name=_disp.kernel_name("flash_decode"),
+        name=name,
     )(*scalars, qg, k_arr, v_arr)
     out = out.reshape(b, hkv, nq, tile_p, d)[:, :, :, :bq * g]
     out = out.reshape(b, hkv, nq * bq * g, d)[:, :, :rows]

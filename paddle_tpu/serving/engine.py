@@ -23,7 +23,7 @@ Design (Orca-style iteration-level scheduling, expressed TPU-first):
     position vector doubles as the flash-decode kernel's live-prefix
     hint: at max_length >= FLAGS_decode_attention_min_len the attention
     dispatcher hands it to ops/pallas/decode_attention.py as a
-    scalar-prefetch operand that clamps the KV-chunk reads, so each step
+    scalar-prefetch operand that sizes each row's block walk, so each step
     streams only each slot's live cache prefix — slots at shallow,
     heterogeneous depths under a worst-case-sized max_length stop paying
     for the dead tail, with no retrace;
@@ -552,6 +552,7 @@ class ServingEngine:
             cache = init_kv_cache(model.config, self.num_slots,
                                   self.max_length,
                                   quantized=self.quantized)
+        self._init_kv_walk()
         params, cache, _ = _place_on_mesh(
             self._bind, params, cache,
             jnp.zeros((self.num_slots, 1), jnp.int32),
@@ -1318,7 +1319,7 @@ class ServingEngine:
         step = [op("tokens", (s, k + 1), i32, "tokens") if self.spec
                 else op("tokens", (s,), i32, self._tokens),
                 # the cursor engine on the contiguous cache steers its idle
-                # rows' positions each tick (``_device_step``)
+                # rows' positions each tick (``_step_inner``)
                 op("positions", (s,), i32,
                    "positions" if self.chunked and not self.paged
                    else self._positions)]
@@ -2182,6 +2183,52 @@ class ServingEngine:
                          violation="cancelled")
         self._tracer.instant("serving.cancelled", rid=req.request_id)
 
+    def _init_kv_walk(self):
+        """What a span needs to count the flash-decode kernel's block walk
+        (:meth:`_kv_walk`): the GQA group, the block length and table
+        width the kernel is handed for this cache layout, and how many
+        layers read each sliding window (None: the whole prefix)."""
+        from ..ops.pallas.decode_attention import contiguous_block_kv
+        c = self.config
+        if self.paged:
+            bk, cols = self.block_len, self.max_blocks
+        else:
+            try:        # init_kv_cache's granules, where the cache is int8
+                bk = contiguous_block_kv(
+                    self.max_length,
+                    max(1, self.max_length // 128) if self.quantized
+                    else None)
+            except NotImplementedError:     # no kernel at this length
+                bk = self.max_length
+            cols = self.max_length // bk
+        windows = (getattr(self._bind, "attention_windows", None)
+                   or (None,) * int(c.num_hidden_layers))
+        self._kv_walk_geom = (
+            int(c.num_attention_heads) // int(c.num_key_value_heads),
+            int(bk), int(cols),
+            tuple((w, windows.count(w)) for w in set(windows)))
+
+    def _kv_walk(self, *calls) -> Dict[str, int]:
+        """``kv_blocks=`` and ``kv_walk=`` of a tick's or a wave's span:
+        over the program's flash-decode calls (``calls``: the positions
+        vector and q length of each, as uploaded) and the model's layers,
+        the KV blocks the rows' q tiles need and the block slots the
+        kernel walks for them (whole copy groups), by the kernel's own
+        bounds (``ops.pallas.decode_attention.walk_counts``).  Nothing
+        where there is no kernel (the simulator)."""
+        if self._kv_walk_geom is None:
+            return {}
+        from ..ops.pallas.decode_attention import walk_counts
+        g, bk, cols, layers = self._kv_walk_geom
+        blocks = walk = 0
+        for pos, s in calls:
+            for window, n in layers:
+                kb, kw = walk_counts(pos, s, g, bk=bk, n_cols=cols,
+                                     window=window)
+                blocks += n * kb
+                walk += n * kw
+        return {"kv_blocks": blocks, "kv_walk": walk}
+
     def _note_sample_path(self, *knobs) -> str:
         """Name and count the way this tick's sampling epilogue goes:
         ``knobs`` are the (temperature, top_k, top_p) vectors of each
@@ -2246,9 +2293,22 @@ class ServingEngine:
             sp = pf.req.sampling
             knobs.append((np.float32(sp.temperature), np.int32(sp.top_k),
                           np.float32(sp.top_p)))
+        with span(_BUILD):
+            rows_pos = self._positions
+            if chunked and not paged:
+                # non-decoding rows (idle or mid-prefill) write at
+                # max_length so the scatter drops them — chunked
+                # prefill owns those rows' contents now
+                rows_pos = own["positions"] = np.where(
+                    self._active, self._positions,
+                    self.max_length).astype(np.int32)
+            walks = [(rows_pos, self.spec_k + 1 if spec else 1)]
+            if chunked:      # the chunk part runs every tick, real or not
+                walks.append(([cpos], self.prefill_chunk))
+            kv_walk = self._kv_walk(*walks)
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
-            sample_path=self._note_sample_path(*knobs),
+            sample_path=self._note_sample_path(*knobs), **kv_walk,
             **({"drafted": int(draft_ok.sum())} if spec else {}))
         chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
                            tokens=clen)
@@ -2334,13 +2394,6 @@ class ServingEngine:
                     ctemps=np.full((1,), sp.temperature, np.float32),
                     ctopk=np.full((1,), sp.top_k, np.int32),
                     ctopp=np.full((1,), sp.top_p, np.float32))
-                if not self.paged:
-                    # non-decoding rows (idle or mid-prefill) write at
-                    # max_length so the scatter drops them — chunked
-                    # prefill owns those rows' contents now
-                    own["positions"] = np.where(
-                        self._active, self._positions,
-                        self.max_length).astype(np.int32)
             args = self._upload(self._step_table, own)
         with span(_DISPATCH):
             *out, self._cache = self._step_fn(self._params, self._cache,
@@ -2731,7 +2784,7 @@ class ServingEngine:
                     num_layers=int(c.num_hidden_layers),
                     quantized=quantized, variant=tag))
                 # a window layer's call of the same kernel: the block
-                # walk clamped from below too
+                # walk starts at the window's first block
                 specs.extend(_sa.decode_attention_spec(
                     b, s, hq, hkv, d_p, block_len=bl_p, max_blocks=mb_p,
                     num_blocks=self.num_slots * mb_p + 1,
@@ -3393,7 +3446,9 @@ class ServingEngine:
         span = self._tracer.span
         with span("serving.prefill", bucket=bucket, rows=len(wave),
                   padded_rows=nb, tokens=int(lens[:len(wave)].sum()),
-                  sample_path=self._note_sample_path((temps, topk, topp))):
+                  sample_path=self._note_sample_path((temps, topk, topp)),
+                  # a contiguous wave reads no cache: the flash kernel
+                  **(self._kv_walk((prefix, bucket)) if paged else {})):
             with span(_BUILD):
                 args = self._upload(self._prefill_table, own)
             with span(_DISPATCH):

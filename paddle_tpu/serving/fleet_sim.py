@@ -139,8 +139,10 @@ class SimEngine(ServingEngine):
         self.pad_token_id = int(pad_token_id)
         self.prefill_batch = int(prefill_batch)
         self._int8_weights = False
-        # no model: no expert layers' load, no window layers' dead positions
+        # no model: no expert layers' load, no window layers' dead
+        # positions, no kernel whose block walk the spans would count
         self._expert_layers, self._windows = 0, ()
+        self._kv_walk_geom = None
         # the simulator is paged-only: the BlockManager IS the part of
         # the memory system worth simulating (admission blocking,
         # prefix hits, preemption, the host tier)

@@ -1121,8 +1121,8 @@ class BlockManager:
 
         Null-block aliasing rule (ISSUE 14; the kernel pre-flight's
         ClampCheck proves the other half): PAD columns past the chain
-        may map to ``NULL_BLOCK`` — the decode kernel's dead-tail clamp
-        guarantees they are never dereferenced — but a LIVE chain entry
+        may map to ``NULL_BLOCK`` — the decode kernel's walk stops at
+        the row's last live block, so they are never dereferenced — but a LIVE chain entry
         mapping to block 0 would alias the null block's pad data into
         the row's attention window, silently corrupting the output.
         The allocator can never produce one (block 0 is excluded from

@@ -2,18 +2,19 @@
 
 The graph-lint layer (ISSUE 6/8) stops at the jaxpr: a ``pallas_call``
 is one opaque eqn, so the kernels the serving stack rides — the q-tiled
-flash-decode kernel with scalar-prefetch-clamped index maps, the paged
+flash-decode kernel whose body walks each row's live blocks, the paged
 block-table dereference, the int8 scale tables — were validated only
 by running them.  This module re-expresses each kernel's GEOMETRY as a
 :class:`KernelSpec`: the grid, every BlockSpec's block shape and index
-map (rewritten over closed integer intervals, :class:`Iv`), the
-scalar-prefetch operands with their DECLARED value ranges, the VMEM
-scratch, and the derived tile dims.  ``kernel_rules.py`` walks a spec
+map (rewritten over closed integer intervals, :class:`Iv`), the blocks a
+kernel body copies by hand out of an operand left in HBM (the same
+way), the scalar-prefetch operands with their DECLARED value ranges,
+the VMEM scratch, and the derived tile dims.  ``kernel_rules.py`` walks a spec
 WITHOUT compiling anything: VMEM footprint, index-map bounds over the
 full grid domain, alignment/tiling, and the streamed-bytes model.
 
 The builders mirror the kernels LINE FOR LINE — ``bq``/``tile_p``/
-``chunks`` come from the same arithmetic, the 128-lane and row-cap
+the copy group come from the kernel's own functions, the 128-lane and row-cap
 gates import :mod:`paddle_tpu.ops.pallas.limits` (the same constants
 the kernels and the dispatch rules read), and the block-picking helpers
 (``_pick_block_kv``, ``_block_sizes``, ``_pick``, ``_pick_block_rows``)
@@ -32,7 +33,10 @@ no false positives on the committed kernels.
 from __future__ import annotations
 
 import dataclasses
+import types
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from ..ops.pallas import limits as _limits
 
@@ -105,6 +109,12 @@ def iv_max(a, b) -> Iv:
     return Iv(max(a.lo, b.lo), max(a.hi, b.hi))
 
 
+# what ``ops.pallas.decode_attention.live_block_range`` asks of its ``xp``:
+# the kernel's own bounds, evaluated over intervals
+_IV_OPS = types.SimpleNamespace(minimum=iv_min, maximum=iv_max,
+                                zeros_like=lambda _: Iv.const(0))
+
+
 # ---------------------------------------------------------------------------
 # spec dataclasses
 # ---------------------------------------------------------------------------
@@ -144,18 +154,22 @@ class ScalarEnv:
 
 @dataclasses.dataclass(frozen=True)
 class ClampCheck:
-    """Declares that an index map's dereference of ``table`` is the
+    """Declares that an operand's dereference of ``table`` is the
     dead-tail clamp: with the row position pinned to ``p`` and the
-    q-tile grid axis ``pin_axis`` pinned to ``q``, the table COLUMN the
-    map touches must top out at exactly ``expected(p, q)`` — the last
+    q-tile grid axis ``pin_axis`` pinned to ``q``, the table COLUMN it
+    touches must top out at exactly ``expected(p, q)`` — the last
     live block.  Higher = unclamped (the dead tail streams, and its
     null-filled entries alias block 0 into live rows); lower =
-    over-clamped (live KV silently truncated)."""
+    over-clamped (live KV silently truncated).  ``expected_first``, where
+    given, holds the smallest column the same way: the first block of
+    the walk (0, or a sliding window's first block) — lower reads what no
+    query sees and may be another request's by now, higher truncates."""
 
     table: str
     pin_scalar: str
     pin_axis: int
     expected: Callable[[int, int], int]
+    expected_first: Optional[Callable[[int, int], int]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,7 +185,12 @@ class BlockOperand:
     (None = one per grid step; the dead-tail clamp's DMA elision makes
     the decode KV operands' count smaller); ``sublane_padded`` marks
     blocks the kernel explicitly pads to the sublane tile (the decode
-    q tiles), exempting them from the sublane lint."""
+    q tiles), exempting them from the sublane lint.  ``manual`` marks
+    an operand Pallas does not pipeline at all: it stays in HBM and the
+    kernel BODY copies blocks of ``block_shape`` out of it into VMEM
+    scratch the spec lists under ``scratch`` (so it adds no VMEM of its
+    own); its ``index_map`` returns every block index those copies take
+    over one grid step's whole loop, and ``fetches`` counts them."""
 
     name: str
     block_shape: Tuple[int, ...]
@@ -183,6 +202,7 @@ class BlockOperand:
     fetches: Optional[int] = None
     kv_stream: bool = False
     clamp: Optional[ClampCheck] = None
+    manual: bool = False
 
     def block_bytes(self) -> int:
         n = 1
@@ -215,10 +235,12 @@ class KernelSpec:
 def vmem_footprint(spec: KernelSpec) -> int:
     """Per-grid-step VMEM bytes: every block-shaped operand tile
     (streamed operands x2 for Pallas's DMA double-buffering) plus the
-    scratch accumulators, which persist across the grid walk."""
+    scratch — accumulators, which persist across the grid walk, and the
+    buffers a kernel body copies its ``manual`` operands into."""
     total = 0
     for op in spec.operands:
-        total += op.block_bytes() * (2 if op.streamed else 1)
+        if not op.manual:
+            total += op.block_bytes() * (2 if op.streamed else 1)
     for shape, dtype in spec.scratch:
         n = 1
         for d in shape:
@@ -236,9 +258,9 @@ def _grid_size(spec: KernelSpec) -> int:
 
 def streamed_bytes(spec: KernelSpec) -> int:
     """HBM bytes one kernel call moves: per operand, distinct block
-    fetches x block bytes.  ``fetches`` encodes the dead-tail clamp's
-    DMA elision (consecutive grid steps mapping to the same block cost
-    one fetch); operands without it fetch once per grid step."""
+    fetches x block bytes.  ``fetches`` is what the kernel really copies
+    (the flash-decode body copies each q tile's live blocks and no
+    other); operands without it fetch once per grid step."""
     total = 0
     grid_n = _grid_size(spec)
     for op in spec.operands:
@@ -334,8 +356,9 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     Paged: pass ``block_len`` + ``max_blocks`` (+ ``num_blocks``, default
     the serving engine's ``num_slots*max_blocks + 1`` null-block pool);
     the K and V operands are then THE pool
-    ``(num_layers, 2, num_blocks, bk, hkv*d)`` itself, and the spec is the
-    last layer's call — the index maps' largest layer index.
+    ``(num_layers, 2, num_blocks, bk, hkv*d)`` itself, and the layer a
+    scalar-prefetch value in ``[0, num_layers)``: one spec for every
+    layer's call, as they share one kernel body.
     ``quantized`` adds the two f32 scale tables as scalar-prefetch
     operands; contiguous int8 pins
     the KV chunk to the scale granule (``n_granules`` — the
@@ -344,8 +367,17 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
     time; the pre-flight's job is to say so beforehand) — only shapes
     with no expressible geometry raise :class:`KernelSpecError`.
     ``window`` is the sliding window of a window layer's call: the block
-    walk gains the lower clamp at the window's first block, and the
-    streamed-bytes model counts the blocks inside the window only.
+    walk starts at the window's first block, and the streamed-bytes
+    model counts the blocks inside the window only.
+
+    K and V are ``manual`` operands: the kernel leaves them in HBM and
+    its body copies the blocks of each ``(row, q tile)``'s walk —
+    ``ops.pallas.decode_attention.live_block_range``, imported here —
+    into the two double-buffered group buffers listed first under
+    ``scratch`` (2 buffers x G blocks, for K and for V).  The
+    :class:`ClampCheck` holds both ends: at pinned ``(pos, q tile)`` the
+    largest and the smallest table column the body dereferences are the
+    walk's last and first.
 
     Mesh-sharded callers (the shard_map fast path) must pass PER-SHARD
     geometry — ``hq/mp`` and ``hkv/mp`` heads — and tag ``variant``
@@ -399,79 +431,86 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         chunks = max(1, kv_len // bk)
         n_pool = b * chunks
 
-    # the kernel's own tiling arithmetic, verbatim
-    bq = min(s, max(1, _limits.MAX_Q_ROWS // g))
-    nq = -(-s // bq)
+    # the kernel's own tiling arithmetic and walk bounds, imported
+    from ..ops.pallas.decode_attention import (group_blocks,
+                                               live_block_range, q_tiles)
+    bq, nq = q_tiles(s, g)
+    gb = group_blocks(bk)
     tile_p = max(8, -(-(bq * g) // 8) * 8)
     kv_dtype = "int8" if quantized else q_dtype
 
     pos_hi = max(0, kv_len - s)
     scalars = (
         ScalarOperand("pos", (b,), 0, pos_hi),
-        # every entry a valid pool index; dead-tail columns are
-        # null-filled (block 0) — live rows must never dereference them
+        # every entry a valid pool index; columns outside a row's walk
+        # are null-filled (block 0) — live rows must never dereference
+        # them
         ScalarOperand("bt", (b, chunks), 0, max(0, n_pool - 1)),
+        # which layer of the pool this call reads (0 for the contiguous
+        # cache, whose operands are one layer's already)
+        ScalarOperand("layer", (1,), 0, int(num_layers) - 1 if paged else 0),
     )
     if quantized:
         # the f32 scale tables, gathered per row by the wrapper, ride in
-        # SMEM beside the block table (their VALUES never feed an index
-        # map, so the declared range is vacuous)
+        # SMEM beside the block table (their VALUES never feed an index,
+        # so the declared range is vacuous)
         scalars += (ScalarOperand("k_scale", (b * chunks * hkv,), 0, 0,
                                   kv_stream=True),
                     ScalarOperand("v_scale", (b * chunks * hkv,), 0, 0,
                                   kv_stream=True))
 
-    def expected_last(p: int, q: int) -> int:
-        # last chunk holding a key visible to ANY row of q tile q at
-        # row position p — the kernel's `last_live`, clamped to the grid
-        return min(chunks - 1, (p + min((q + 1) * bq, s) - 1) // bk)
-
-    def expected_first(p: int, q: int) -> int:
-        # first chunk holding a key inside the window of the tile's
-        # earliest query (none behind it is fetched or scored)
-        if window is None:
-            return 0
-        return max(p + q * bq - int(window) + 1, 0) // bk
+    def expected_walk(p: int, q: int):
+        # the kernel's own bounds at a pinned (pos, q tile): the first and
+        # last table column holding a key some query of the tile may see
+        first, last = live_block_range(
+            np.int64(p), np.int64(q), s=s, bq=bq, bk=bk, n_cols=chunks,
+            window=window, xp=np)
+        return int(first), int(last)
 
     def q_idx(grid_ivs, sc):
-        bi, qi, ki = grid_ivs
+        bi, qi = grid_ivs
         return (bi, Iv.const(0), qi, Iv.const(0))
 
-    def live_block(grid_ivs, sc):
-        bi, qi, ki = grid_ivs
-        pos = sc.lookup("pos", bi)
-        last = (pos + iv_min((qi + 1) * bq, Iv.const(s)) - 1) // bk
-        col = iv_min(ki, last)
-        if window is not None:
-            first = iv_max(pos + qi * bq - (int(window) - 1), 0) // bk
-            col = iv_min(iv_max(ki, first), last)
+    def walk_block(grid_ivs, sc):
+        # every block id the body's copies take at grid step (bi, qi): the
+        # body reads ``bt[bi, min(first + j·gb + i, last)]`` for group j
+        # of its loop and block i of the group — k = j·gb + i runs from 0
+        # to at most one group past the table's width, and the min keeps
+        # the column at or under ``last``.  (The copies a step issues for
+        # the NEXT step are that step's first group: the same set.)
+        bi, qi = grid_ivs
+        first, last = live_block_range(
+            sc.lookup("pos", bi), qi, s=s, bq=bq, bk=bk, n_cols=chunks,
+            window=window, xp=_IV_OPS)
+        col = iv_min(first + Iv(0, chunks + gb - 2), last)
         return sc.lookup("bt", bi, col)
 
     if paged:
         # the kernel's operand is the stacked pool: (layer, K|V, block)
-        layer = Iv.const(int(num_layers) - 1)
         kv_block = (1, 1, 1, bk, hkv * d)
         kv_array = (int(num_layers), 2, n_pool, bk, hkv * d)
 
         def kv_idx(which):
             return lambda grid_ivs, sc: (
-                layer, Iv.const(which), live_block(grid_ivs, sc),
-                Iv.const(0), Iv.const(0))
+                sc.lookup("layer", 0), Iv.const(which),
+                walk_block(grid_ivs, sc), Iv.const(0), Iv.const(0))
     else:
         kv_block = (1, bk, hkv * d)
         kv_array = (n_pool, bk, hkv * d)
 
         def kv_idx(which):
             return lambda grid_ivs, sc: (
-                live_block(grid_ivs, sc), Iv.const(0), Iv.const(0))
+                walk_block(grid_ivs, sc), Iv.const(0), Iv.const(0))
 
     clamp = ClampCheck(table="bt", pin_scalar="pos", pin_axis=1,
-                       expected=expected_last)
-    # streamed-bytes model: per (bi, qi) the clamp's DMA elision fetches
-    # only the tile's live prefix; the worst case (pos at its declared
-    # max) is the committed per-step bound
-    kv_fetches = b * sum(expected_last(pos_hi, q)
-                         - expected_first(pos_hi, q) + 1 for q in range(nq))
+                       expected=lambda p, q: expected_walk(p, q)[1],
+                       expected_first=lambda p, q: expected_walk(p, q)[0])
+    # streamed-bytes model: per (bi, qi) the body copies the blocks of
+    # the tile's walk and nothing else (a group's columns past ``last``
+    # are not copied); the worst case (pos at its declared max) is the
+    # committed per-step bound
+    kv_fetches = b * sum(last - first + 1 for first, last in
+                         (expected_walk(pos_hi, q) for q in range(nq)))
     q_fetches = b * nq
 
     q_block = (1, hkv, tile_p, d)
@@ -480,26 +519,32 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
         BlockOperand("q", q_block, q_array, q_dtype, q_idx,
                      sublane_padded=True, fetches=q_fetches),
         BlockOperand("k", kv_block, kv_array, kv_dtype, kv_idx(0),
-                     fetches=kv_fetches, kv_stream=True, clamp=clamp),
+                     fetches=kv_fetches, kv_stream=True, clamp=clamp,
+                     manual=True),
         BlockOperand("v", kv_block, kv_array, kv_dtype, kv_idx(1),
-                     fetches=kv_fetches, kv_stream=True, clamp=clamp),
+                     fetches=kv_fetches, kv_stream=True, clamp=clamp,
+                     manual=True),
     ]
     operands.append(
         BlockOperand("out", q_block, q_array, q_dtype, q_idx,
                      sublane_padded=True, fetches=q_fetches))
 
-    scratch = (((hkv, tile_p, d), "float32"),
+    # two buffers of one group of K and of V blocks, then the accumulators
+    scratch = (((2, gb * bk, hkv * d), kv_dtype),
+               ((2, gb * bk, hkv * d), kv_dtype),
+               ((hkv, tile_p, d), "float32"),
                ((hkv, tile_p, _limits.LANES), "float32"),
                ((hkv, tile_p, _limits.LANES), "float32"))
 
     dims.update({
         "b": b, "s": s, "g": g, "hkv": hkv, "d": d, "bq": bq, "nq": nq,
-        "tile_p": tile_p, "bk": bk, "chunks": chunks, "kv_len": kv_len,
-        "paged": paged, "quantized": quantized, "window": window,
-        "lane_slice": (d, hkv), "lanes_128": tuple(lanes_128),
+        "tile_p": tile_p, "bk": bk, "gb": gb, "chunks": chunks,
+        "kv_len": kv_len, "paged": paged, "quantized": quantized,
+        "window": window, "lane_slice": (d, hkv),
+        "lanes_128": tuple(lanes_128),
     })
     spec = KernelSpec(
-        op="decode_attention", grid=(b, nq, chunks),
+        op="decode_attention", grid=(b, nq),
         variant=variant or (f"{'paged' if paged else 'contiguous'}"
                             f"{'+int8' if quantized else ''},s={s}"),
         operands=tuple(operands), scratch=scratch, scalars=scalars,

@@ -11,13 +11,17 @@ and scalar-prefetch value ranges.
 Rules (BASELINE.md "Kernel pre-flight conventions"):
 
   * ``kernel-vmem`` — per-grid-step footprint (streamed operand tiles
-    x2 for DMA double-buffering + scratch) vs
+    x2 for DMA double-buffering + scratch, a body's own copy buffers
+    among it) vs
     ``FLAGS_kernel_lint_vmem_bytes`` (default 16 MiB/core);
   * ``kernel-bounds`` — interval evaluation of every index map over the
-    full grid domain: block indices within the array, scalar-prefetch
-    accesses within the operand shape, and the dead-tail ClampCheck
-    corners (unclamped = dead-tail DMA streaming null (block 0)
-    entries; over-clamped = live KV silently truncated);
+    full grid domain (for an operand the kernel body copies by hand:
+    of every block its copies take): block indices within the array,
+    scalar-prefetch accesses within the operand shape, and the
+    ClampCheck corners — the largest and smallest table column
+    dereferenced at a pinned position are the walk's last and first
+    (past it = the dead tail's null (block 0) entries stream; short of
+    it = live KV silently truncated);
   * ``kernel-align`` — array%block divisibility, last-dim %128 lanes,
     second-minor sublane multiples per dtype, declared 128-lane dims
     (paged block_len, flash block_kv), and the head-slice layout
@@ -149,7 +153,14 @@ class KernelBoundsRule(KernelRule):
     def _clamp_corners(self, spec, op, out, seen) -> None:
         cl = op.clamp
         sc = {s.name: s for s in spec.scalars}[cl.pin_scalar]
-        table = {s.name: s for s in spec.scalars}[cl.table]
+
+        def flag(msg):
+            if msg not in seen:
+                seen.add(msg)
+                out.append(core.Finding(
+                    rule=self.name, severity=self.severity,
+                    path=spec.path, message=msg))
+
         for p in {sc.lo, sc.hi}:
             for q in {0, max(0, spec.grid[cl.pin_axis] - 1)}:
                 env = _kr.ScalarEnv(spec.scalars, pins={cl.pin_scalar: p})
@@ -160,36 +171,34 @@ class KernelBoundsRule(KernelRule):
                 op.index_map(tuple(grid_ivs), env)
                 cols = [a for name, a in env.accesses if name == cl.table]
                 if not cols:
-                    msg = (f"operand '{op.name}': declared ClampCheck "
-                           f"on table '{cl.table}' but the index map "
-                           f"never dereferences it")
-                    if msg not in seen:
-                        seen.add(msg)
-                        out.append(core.Finding(
-                            rule=self.name, severity=self.severity,
-                            path=spec.path, message=msg))
+                    flag(f"operand '{op.name}': declared ClampCheck "
+                         f"on table '{cl.table}' but the index map "
+                         f"never dereferences it")
                     continue
+                if cl.expected_first is not None:
+                    want = int(cl.expected_first(p, q))
+                    got = min(a[-1].lo for a in cols)
+                    if got != want:
+                        flag(f"operand '{op.name}': '{cl.table}' column "
+                             f"starts at {got}, not at the walk's first "
+                             f"block {want}, at pos={p}; "
+                             + ("blocks no query sees are read (null or "
+                                "another request's)" if got < want else
+                                "live KV is silently truncated"))
                 want = int(cl.expected(p, q))
                 got = max(a[-1].hi for a in cols)
                 if got > want:
-                    msg = (f"operand '{op.name}': unclamped table "
-                           f"dereference — '{cl.table}' column reaches "
-                           f"{got} past last live block {want} at "
-                           f"pos={p}; the dead tail streams, and its "
-                           f"null-filled (block 0) entries would alias "
-                           f"pad data into live rows")
+                    flag(f"operand '{op.name}': unclamped table "
+                         f"dereference — '{cl.table}' column reaches "
+                         f"{got} past last live block {want} at "
+                         f"pos={p}; the dead tail streams, and its "
+                         f"null-filled (block 0) entries would alias "
+                         f"pad data into live rows")
                 elif got < want:
-                    msg = (f"operand '{op.name}': over-clamped table "
-                           f"dereference — '{cl.table}' column tops out "
-                           f"at {got} below last live block {want} at "
-                           f"pos={p}; live KV is silently truncated")
-                else:
-                    continue
-                if msg not in seen:
-                    seen.add(msg)
-                    out.append(core.Finding(
-                        rule=self.name, severity=self.severity,
-                        path=spec.path, message=msg))
+                    flag(f"operand '{op.name}': over-clamped table "
+                         f"dereference — '{cl.table}' column tops out "
+                         f"at {got} below last live block {want} at "
+                         f"pos={p}; live KV is silently truncated")
 
     def run(self, spec):
         out: List[core.Finding] = []

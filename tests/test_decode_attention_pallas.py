@@ -2,7 +2,9 @@
 mode on CPU: parity vs cached_decode_attention's XLA math path across the
 shapes the serving engine produces — scalar and per-row ``pos``, GQA group
 sizes {1, 4}, s > 1 (prefill-into-occupied-slot), depths ending mid-KV-
-chunk, bf16 — plus the cached_decode_attention dispatch contract (routing,
+chunk, bf16 — the in-kernel block walk's ragged cases with every block
+outside a row's walk poisoned, plus the cached_decode_attention dispatch
+contract (routing,
 threshold, extra_mask fallback).  The real-TPU lane (tests/test_tpu_lane.py)
 compiles the same kernel via Mosaic."""
 
@@ -18,7 +20,8 @@ from paddle_tpu.ops.attention import (cached_decode_attention,
                                       paged_decode_attention,
                                       paged_decode_attention_reference)
 from paddle_tpu.ops.pallas.decode_attention import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    decode_attention_pallas, group_blocks, paged_decode_attention_pallas,
+    q_tiles)
 
 
 def _qkv(b, s, hq, hkv, d, L, seed=0, dtype=jnp.float32):
@@ -71,17 +74,20 @@ def test_kernel_bf16_fp32_accum():
                                rtol=3e-2, atol=3e-2)
 
 
-def test_live_len_hint_trims_but_matches():
+def test_walk_reads_nothing_past_the_rows_own_depth():
+    """The kernel needs no static ``live_len``: each row's walk stops at
+    its own last block, so a cache whose tail past it is NaN reads as the
+    XLA path trimmed to the batch's depth does (``live_len`` keeps its
+    meaning there)."""
     q, k, v = _qkv(2, 1, 8, 2, 64, 512, seed=9)
     pos = jnp.asarray([10, 140], jnp.int32)
-    full = decode_attention_pallas(q, k, v, pos, block_kv=128,
-                                   interpret=True)
-    trimmed = decode_attention_pallas(q, k, v, pos, block_kv=128,
-                                      live_len=160, interpret=True)
-    np.testing.assert_allclose(np.asarray(trimmed), np.asarray(full),
-                               rtol=1e-6, atol=1e-6)
     ref = cached_decode_attention_reference(q, k, v, pos, live_len=160)
-    np.testing.assert_allclose(np.asarray(trimmed), np.asarray(ref),
+    for row, p in enumerate((10, 140)):         # NaN past each row's block
+        k = k.at[row, (p // 128 + 1) * 128:].set(jnp.nan)
+        v = v.at[row, (p // 128 + 1) * 128:].set(jnp.nan)
+    got = decode_attention_pallas(q, k, v, pos, block_kv=128,
+                                  interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -264,6 +270,143 @@ def test_paged_kernel_matches_contiguous_reference(b, s, hq, hkv, d, mb,
     got_ref = paged_decode_attention_reference(q, pool, LAYER, pos,
                                                jnp.asarray(tables))
     np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+# -- the walk: each row's live blocks and nothing else ------------------------
+
+GB = group_blocks(128)         # blocks a copy group: the kernel's G
+RAGGED = {
+    # name: (s, hq, hkv, d, mb, window, positions, int8)
+    # depths on both sides of a block's and of a group's edge, an empty row
+    # and a full table, in one batch
+    "depths": (1, 8, 2, 64, 2 * GB + 1, None,
+               [0, 127, 128, GB * 128 - 1, GB * 128, GB * 128 + 1,
+                (2 * GB + 1) * 128 - 1], False),
+    # a window whose first block is past block 0 (rows 700 and 1100 deep),
+    # beside a row shallower than the window
+    "window-first-past-0": (1, 8, 2, 64, 9, 200, [700, 1100, 90], False),
+    # a window inside ONE block: the walk is that block
+    "window-one-block": (1, 8, 2, 64, 6, 64, [484, 127, 600], False),
+    # the mixed step's prompt chunk over a prefix: 16 q tiles, each with
+    # its own last block (and, windowed, its own first)
+    "chunk-256": (256, 8, 2, 64, 6, None, [300], False),
+    "chunk-256-window": (256, 8, 2, 64, 6, 160, [300], False),
+    # a k+1 verify window over prefixes that end at a block's edge
+    "verify-k+1": (5, 8, 2, 64, 5, None, [37, 125, 380], False),
+    # the int8 pool: a scale a block and kv head, blocks of one group
+    # scaled apart
+    "int8": (1, 8, 2, 64, 2 * GB + 1, None, [5, 300, GB * 128 + 77], True),
+    "int8-chunk": (40, 8, 2, 64, 4, None, [200], True),
+}
+
+
+def _ragged_case(name):
+    """(q, clean pool, poisoned pool, scales, positions, clean tables,
+    poisoned tables, window) of a RAGGED case.  Rows 0 and 1 share their
+    first blocks (a common prefix).  Poisoned: the null block, every block
+    no row owns, and a block of NaN that every table column outside its
+    row's walk points at."""
+    from paddle_tpu.ops.pallas.decode_attention import live_block_range
+    s, hq, hkv, d, mb, window, pos, int8 = RAGGED[name]
+    rng = np.random.default_rng(len(name))
+    b, bl = len(pos), 128
+    nblk = b * mb + 2
+    tables = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+    if b > 1:           # the shared prefix: row 1 reads row 0's blocks
+        shared = min(int(pos[0]), int(pos[1])) // bl
+        tables[1, :shared] = tables[0, :shared]
+    pool = rng.normal(size=(3, 2, nblk, bl, hkv * d)).astype(np.float32)
+    scales = None
+    if int8:
+        pool = np.clip(np.round(pool * 40), -127, 127)
+        scales = rng.uniform(0.5, 2.0, (3, 2, nblk, hkv)).astype(np.float32)
+    poisoned, bad = pool.copy(), tables.copy()
+    nan = 0 if int8 else np.nan     # an int8 payload has no NaN: its SCALE
+    poisoned[:, :, [0, nblk - 1]] = nan
+    bq, nq = q_tiles(s, hq // hkv)
+    for r in range(b):
+        lo, hi = zip(*(live_block_range(
+            np.int64(pos[r]), np.int64(qi), s=s, bq=bq, bk=bl, n_cols=mb,
+            window=window, xp=np) for qi in range(nq)))
+        keep = np.zeros(mb, bool)
+        keep[min(lo):max(hi) + 1] = True
+        bad[r, ~keep] = [0, nblk - 1][r % 2]
+    owned = np.unique(bad[:, :])
+    for blk in set(range(nblk)) - set(int(x) for x in owned):
+        poisoned[:, :, blk] = nan
+    bad_scales = None
+    if int8:
+        bad_scales = scales.copy()
+        unowned = sorted(set(range(nblk)) - set(int(x) for x in owned)
+                         | {0, nblk - 1})
+        bad_scales[:, :, unowned] = np.nan
+        pool, poisoned = pool.astype(np.int8), poisoned.astype(np.int8)
+    q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
+    return (q, jnp.asarray(pool), jnp.asarray(poisoned),
+            (scales, bad_scales), jnp.asarray(pos, jnp.int32),
+            jnp.asarray(tables), jnp.asarray(bad), window)
+
+
+@pytest.mark.parametrize("poison", [False, True], ids=["clean", "poison"])
+@pytest.mark.parametrize("name", list(RAGGED))
+def test_ragged_walk_matches_reference(name, poison):
+    """The cases a grid over table columns never told apart: every one
+    against the XLA reference on the clean pool.  ``poison``: the null
+    block, every unowned block and every table column outside a row's
+    ``[first, last]`` hold NaN (the int8 pool: NaN scales) — the walk
+    dereferences none of them, so the output does not change."""
+    q, pool, poisoned, (sc, bad_sc), pos, tables, bad, window = \
+        _ragged_case(name)
+    kw = {} if window is None else {"window": window}
+    want = paged_decode_attention_reference(
+        q, pool, LAYER, pos, tables,
+        **({} if sc is None else {"pool_scale": jnp.asarray(sc)}), **kw)
+    assert np.isfinite(np.asarray(want)).all()
+    if poison:
+        pool, tables, sc = poisoned, bad, bad_sc
+    got = paged_decode_attention_pallas(
+        q, pool, LAYER, pos, tables, interpret=True,
+        **({} if sc is None else {"pool_scale": jnp.asarray(sc)}), **kw)
+    tol = 2e-3 if sc is not None else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_layers_share_one_kernel_body():
+    """The layer is a scalar the kernel reads, not a constant of its body:
+    a program that reads three layers holds ONE lowered kernel, called
+    three times (sixteen bodies a program doubled the benchmark's set-up
+    time), and each call still reads its own layer."""
+    q, pool, _, _, pos, tables, _, _ = _ragged_case("depths")
+
+    def three(q, pool, interpret=False):
+        return [paged_decode_attention_pallas(q, pool, layer, pos, tables,
+                                              interpret=interpret)
+                for layer in range(3)]
+
+    text = jax.jit(three).trace(q, pool).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    for layer, got in enumerate(three(q, pool, interpret=True)):
+        want = paged_decode_attention_reference(q, pool, layer, pos, tables)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_padding_of_a_group_never_reaches_the_output():
+    """A walk's last group is seldom full: its columns past ``last`` are not
+    copied, and what the buffer holds there (here NaN: the TPU interpreter
+    hands out uninitialised scratch so) is masked out of the scores and
+    blanked out of V."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, pool, _, _, pos, tables, _, _ = _ragged_case("depths")
+    want = paged_decode_attention_reference(q, pool, LAYER, pos, tables)
+    got = paged_decode_attention_pallas(
+        q, pool, LAYER, pos, tables,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 

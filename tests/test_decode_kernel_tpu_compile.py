@@ -1,0 +1,84 @@
+"""The flash-decode kernel compiled FOR the chip WITHOUT one, at the
+benchmark cells' widths: Mosaic and XLA:TPU run against a described v5e
+(``jax.experimental.topologies``), so what interpret mode cannot see — a
+slice off the tiling, a DMA shape, a loop Mosaic will not lower, more VMEM
+than a kernel may take — fails here and not on the chip.  Nothing runs: no
+result and no time comes out of this file.
+
+The topology is described inside a fixture, never at import: one process at
+a time may load libtpu, and every xdist worker imports every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas.decode_attention import (
+    decode_attention_pallas, paged_decode_attention_pallas)
+
+HKV, D, BLOCK = 8, 128, 128
+
+# name: (rows, q length, q heads, layers, pool blocks, table columns, window,
+# cache dtype) — the decode rows, the prompt chunk and a wave's rows of the
+# Mistral cells, the Trinity cell's window layers, an int8 pool
+PAGED = {
+    "mistral-rows": (96, 1, 32, 16, 769, 32, None, jnp.bfloat16),
+    "mistral-chunk": (1, 256, 32, 16, 385, 32, None, jnp.bfloat16),
+    "mistral-wave-1024": (4, 1024, 32, 16, 769, 32, None, jnp.bfloat16),
+    "trinity-rows-window": (192, 1, 48, 5, 1921, 64, 4096, jnp.bfloat16),
+    "trinity-chunk-window": (1, 256, 48, 5, 1921, 64, 4096, jnp.bfloat16),
+    "int8-rows": (8, 1, 32, 4, 257, 32, None, jnp.int8),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry compiled for a described chip cannot be read back without
+    # one: keep these compiles out of the persistent cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("name", list(PAGED))
+def test_paged_kernel_compiles_for_v5e(one_chip, name):
+    b, s, hq, layers, blocks, cols, window, dtype = PAGED[name]
+    args = [_spec(one_chip, (b, s, hq, D), jnp.bfloat16),
+            _spec(one_chip, (layers, 2, blocks, BLOCK, HKV * D), dtype),
+            _spec(one_chip, (b,), jnp.int32),
+            _spec(one_chip, (b, cols), jnp.int32)]
+    if dtype == jnp.int8:
+        args.append(_spec(one_chip, (layers, 2, blocks, HKV), jnp.float32))
+
+    def call(q, pool, pos, tables, scale=None):
+        return paged_decode_attention_pallas(
+            q, pool, layers - 1, pos, tables, pool_scale=scale,
+            window=window)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool is read where it lies: no temporary of a layer's size
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_contiguous_kernel_compiles_for_v5e(one_chip):
+    q = _spec(one_chip, (8, 1, 32, D), jnp.bfloat16)
+    kv = _spec(one_chip, (8, 4096, HKV, D), jnp.bfloat16)
+    compiled = jax.jit(decode_attention_pallas).lower(
+        q, kv, kv, _spec(one_chip, (8,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -6,8 +6,13 @@ import json
 
 import pytest
 
+import numpy as np
+
 from paddle_tpu.flags import flag
 from paddle_tpu.ops.pallas import limits as _limits
+from paddle_tpu.ops.pallas.decode_attention import (group_blocks,
+                                                    live_block_range,
+                                                    q_tiles, walk_counts)
 from paddle_tpu.static_analysis import kernel_registry as kr
 from paddle_tpu.static_analysis import kernel_rules as krl
 
@@ -61,8 +66,9 @@ def test_vmem_rule_offender_and_clean():
 
 def test_vmem_estimate_matches_hand_computed_tile_sum():
     """ISSUE 14 acceptance: the q-tiled paged decode estimate equals
-    the hand-computed tile sum (double-buffered streamed operands x2 +
-    scratch) within the lint tolerance."""
+    the hand-computed tile sum (Pallas's double-buffered q and out, the
+    body's own K and V group buffers, the accumulators) within the lint
+    tolerance."""
     b, s, hq, hkv, d = 1, 256, 32, 8, 128
     bl, mb = 128, 64
     spec = kr.decode_attention_spec(b, s, hq, hkv, d, block_len=bl,
@@ -72,12 +78,20 @@ def test_vmem_estimate_matches_hand_computed_tile_sum():
     tile_p = max(8, -(-bq * g // 8) * 8)            # 64 padded q rows
     q_tile = 1 * hkv * tile_p * d * 2               # bf16
     kv_tile = 1 * bl * (hkv * d) * 2                # bf16
+    gb = group_blocks(bl)                           # 4 blocks a copy group
     scratch = (hkv * tile_p * d) * 4 \
         + 2 * (hkv * tile_p * _limits.LANES) * 4    # f32 acc + m/l rows
-    hand = 2 * (2 * q_tile) + 2 * (2 * kv_tile) + scratch
+    # q and out are Pallas's (double-buffered); K and V stay in HBM and the
+    # body copies groups of them into its OWN two buffers each
+    manual = 2 * (2 * gb * kv_tile)
+    hand = 2 * (2 * q_tile) + manual + scratch
     got = kr.vmem_footprint(spec)
     assert abs(got - hand) <= flag("graph_lint_hbm_tol") * hand
     assert got == hand                              # the model is exact here
+    # the manual operands add nothing beside the buffers they are copied to
+    assert {o.name for o in spec.operands if o.manual} == {"k", "v"}
+    assert spec.scratch[:2] == (((2, gb * bl, hkv * d), "bfloat16"),) * 2
+    assert spec.grid == (b, -(-s // bq))            # no table axis
 
 
 # ---------------------------------------------------------------------------
@@ -159,6 +173,120 @@ def test_paged_decode_spec_bounds_clean_across_scalar_domain():
     chunked = kr.decode_attention_spec(1, 256, 32, 8, 128, block_len=128,
                                        max_blocks=64)
     assert krl.KernelBoundsRule().run(chunked) == []
+
+
+def test_streamed_bytes_are_the_live_ranges():
+    """The body copies the blocks of each q tile's walk and no other: a
+    decode call at the deepest position reads every block once, a window
+    layer's the window's blocks, a chunk's each tile's range again."""
+    block = 128 * (8 * 128) * 2                      # one bf16 K or V block
+    q_io = lambda sp: sum(o.fetches * o.block_bytes()      # noqa: E731
+                          for o in sp.operands if not o.manual)
+    rows = kr.decode_attention_spec(4, 1, 32, 8, 128, block_len=128,
+                                    max_blocks=8)
+    assert kr.streamed_bytes(rows) - q_io(rows) == 4 * 8 * 2 * block
+    win = kr.decode_attention_spec(4, 1, 32, 8, 128, block_len=128,
+                                   max_blocks=8, window=256)
+    # positions 768..1023 of the deepest row: blocks 6 and 7
+    assert kr.streamed_bytes(win) - q_io(win) == 4 * 2 * 2 * block
+    chunk = kr.decode_attention_spec(1, 256, 32, 8, 128, block_len=128,
+                                     max_blocks=8)
+    bq, nq = q_tiles(256, 4)
+    tiles = sum((1024 - 256 + min((q + 1) * bq, 256) - 1) // 128 + 1
+                for q in range(nq))                  # 7 or 8 blocks a tile
+    assert kr.streamed_bytes(chunk) - q_io(chunk) == tiles * 2 * block
+
+
+def _walk_spec(mode):
+    """One row, one q tile, a 4-column table and a window of 2 blocks of 2
+    positions: the body's walk mirrored right ('walk'), started one column
+    early ('early') or late ('late')."""
+    chunks, n_pool, bk, window = 4, 10, 2, 4
+    pos = kr.ScalarOperand("pos", (1,), 0, 7)
+    bt = kr.ScalarOperand("bt", (1, chunks), 0, n_pool - 1)
+
+    def bounds(p, q):
+        first, last = live_block_range(np.int64(p), np.int64(q), s=1, bq=1,
+                                       bk=bk, n_cols=chunks, window=window,
+                                       xp=np)
+        return int(first), int(last)
+
+    def idx(grid, env):
+        p = env.lookup("pos", kr.iv(0))
+        last = kr.iv_min(p // bk, chunks - 1)
+        first = kr.iv_min(kr.iv_max(p - (window - 1), 0) // bk, last)
+        if mode == "early":
+            first = kr.iv_max(first - 1, 0)
+        elif mode == "late":
+            first = kr.iv_min(first + 1, last)
+        col = kr.iv_min(first + kr.Iv(0, chunks), last)
+        return (env.lookup("bt", kr.iv(0), col), kr.iv(0), kr.iv(0))
+
+    op = kr.BlockOperand(
+        "k", (1, bk, 128), (n_pool, bk, 128), "bfloat16", idx, manual=True,
+        clamp=kr.ClampCheck("bt", "pos", 1, lambda p, q: bounds(p, q)[1],
+                            expected_first=lambda p, q: bounds(p, q)[0]))
+    return kr.KernelSpec(op="mini_walk", variant=mode, grid=(1, 1),
+                         operands=(op,), scalars=(pos, bt))
+
+
+def test_bounds_hold_both_ends_of_the_walk():
+    """At a pinned position the largest AND the smallest table column the
+    body dereferences are the walk's last and first block."""
+    assert krl.KernelBoundsRule().run(_walk_spec("walk")) == []
+    early = krl.KernelBoundsRule().run(_walk_spec("early"))
+    assert any("not at the walk's first block" in f.message
+               and "no query sees" in f.message for f in early)
+    late = krl.KernelBoundsRule().run(_walk_spec("late"))
+    assert any("not at the walk's first block" in f.message
+               and "silently truncated" in f.message for f in late)
+    # the real spec declares both ends, from the kernel's own function
+    spec = kr.decode_attention_spec(4, 1, 48, 8, 128, block_len=128,
+                                    max_blocks=64, window=4096)
+    clamp = next(o.clamp for o in spec.operands if o.name == "k")
+    assert (clamp.expected_first(5000, 0), clamp.expected(5000, 0)) == (
+        (5000 - 4095) // 128, 5000 // 128)
+    assert krl.KernelBoundsRule().run(spec) == []
+
+
+def test_host_walk_counts_agree_with_the_mask():
+    """``walk_counts`` — what the engine's spans carry — and
+    ``live_block_range`` — what the kernel's loop runs over — against the
+    mask's own definition on random positions: the blocks of a q tile's
+    walk are exactly those from the first to the last that hold a key some
+    query of the tile may see."""
+    rng = np.random.default_rng(0)
+    for _ in range(200):
+        bk = int(rng.choice([8, 16, 128]))
+        s = int(rng.choice([1, 3, 5, 40]))
+        n_cols = int(rng.integers(-(-s // bk), 12))   # the table holds s
+        g = int(rng.choice([1, 4, 6]))
+        window = [None, int(rng.integers(1, 4 * bk))][int(rng.integers(2))]
+        b = int(rng.integers(1, 5))
+        pos = rng.integers(0, n_cols * bk - s + 1, b)
+        bq, nq = q_tiles(s, g)
+        need = walk = 0
+        for p in pos:
+            for qi in range(nq):
+                seen = set()
+                for i in range(qi * bq, min((qi + 1) * bq, s)):
+                    lo = 0 if window is None else max(p + i - window + 1, 0)
+                    seen.update(range(lo // bk, (p + i) // bk + 1))
+                first, last = live_block_range(
+                    np.int64(p), np.int64(qi), s=s, bq=bq, bk=bk,
+                    n_cols=n_cols, window=window, xp=np)
+                assert (first, last) == (min(seen), max(seen))
+                need += last - first + 1
+                gb = group_blocks(bk)
+                walk += -(-(last - first + 1) // gb) * gb
+        assert walk_counts(pos, s, g, bk=bk, n_cols=n_cols,
+                           window=window) == (need, walk)
+    # a row parked past the cache (the contiguous cursor engine's idle
+    # rows) stays inside the table
+    assert live_block_range(np.int64(64), np.int64(0), s=1, bq=1, bk=8,
+                            n_cols=8, window=16, xp=np) == (6, 7)
+    assert live_block_range(np.int64(640), np.int64(0), s=1, bq=1, bk=8,
+                            n_cols=8, window=16, xp=np) == (7, 7)
 
 
 # ---------------------------------------------------------------------------

@@ -108,14 +108,78 @@ def test_wave_prefill_span_says_what_it_padded(lm, mode):
     assert _inside(wave, admit)
     # both requests enter in one wave: padded to prefill_batch rows of the
     # longest prompt's power-of-two bucket
+    # ... and the paged wave's rows go through the flash-decode kernel at
+    # prefix 0: the blocks its q tiles need and the block slots it walks
+    # for them, by the kernel's own bounds, over the model's layers
+    from paddle_tpu.ops.pallas.decode_attention import walk_counts
+    c = lm.config
+    need, walk = walk_counts(
+        np.zeros(eng.prefill_batch), 16,
+        c.num_attention_heads // c.num_key_value_heads, bk=8,
+        n_cols=MAXLEN // 8)
+    assert 0 < need <= walk
     assert wave["args"] == {"bucket": 16, "rows": len(PROMPTS),
                             "padded_rows": eng.prefill_batch,
                             "tokens": sum(PROMPTS),
-                            "sample_path": "greedy"}
+                            "sample_path": "greedy",
+                            "kv_blocks": c.num_hidden_layers * need,
+                            "kv_walk": c.num_hidden_layers * walk}
     # the wave's own upload, launch and fetch are phases inside it
     inner = {e["name"] for e in phases if _inside(e, wave)}
     assert inner == {"serving.build_inputs", "serving.dispatch",
                      "serving.readback"}
+
+
+WALK_MODES = dict(MODES, contiguous_chunked={
+    "paged": False, "chunked": True, "prefill_chunk": 8})
+
+
+@pytest.mark.parametrize("mode", sorted(WALK_MODES))
+def test_tick_spans_count_the_kernels_block_walk(lm, mode):
+    """``kv_blocks=`` / ``kv_walk=`` on the tick's rows span: the flash-decode
+    kernel's own bounds (``walk_counts``) over the positions the tick
+    UPLOADS — the rows' (a verify window's q length with them) and, on a
+    cursor engine, the chunk part's, real or not — times the layers."""
+    from paddle_tpu.ops.pallas.decode_attention import walk_counts
+
+    kw = dict({"paged": True, "block_len": 8}, **WALK_MODES[mode])
+    eng = ServingEngine(lm, num_slots=3, max_length=MAXLEN, **kw)
+    for i, n in enumerate(PROMPTS):
+        eng.submit(_prompt(n, i + 1), max_new_tokens=6)
+    eng._linted = True              # or the first tick's lint traces the spy
+    handed, step_fn = [], eng._step_fn
+    names = [o.name for o in eng._step_table]
+
+    def spy(params, cache, *args):
+        handed.append({n: np.array(a, copy=True)    # the host's mirrors
+                       for n, a in zip(names, args)    # change after a tick
+                       if n in ("positions", "cpos")})
+        return step_fn(params, cache, *args)
+    eng._step_fn = spy
+    c = lm.config
+    g = c.num_attention_heads // c.num_key_value_heads
+    bk, cols = (8, MAXLEN // 8) if eng.paged else (MAXLEN, 1)
+    seen = 0
+    for _ in range(8):
+        obs.get_tracer().clear()
+        before = len(handed)
+        eng.step()
+        rows = [e for e in obs.get_tracer().events() if e["ph"] == "X"
+                and e["name"] in ("serving.decode", "serving.verify")]
+        assert len(rows) == len(handed) - before <= 1
+        if not rows:
+            continue
+        (ops,) = handed[before:]
+        calls = [(ops["positions"], eng.spec_k + 1 if eng.spec else 1)]
+        if eng.chunked:
+            calls.append(([int(ops["cpos"])], eng.prefill_chunk))
+        need, walk = (sum(x) for x in zip(*(
+            walk_counts(p, s, g, bk=bk, n_cols=cols) for p, s in calls)))
+        assert 0 < need <= walk
+        assert (rows[0]["args"]["kv_blocks"], rows[0]["args"]["kv_walk"]) \
+            == (c.num_hidden_layers * need, c.num_hidden_layers * walk)
+        seen += 1
+    assert seen >= 4
 
 
 # -- (b) the same spans in a jax.profiler trace ------------------------------
@@ -246,6 +310,13 @@ def test_the_call_sites_are_the_seven():
 def test_every_pallas_call_has_a_name(filename, index):
     call = sorted(_pallas_calls(filename), key=lambda n: n.lineno)[index]
     (name,) = [k.value for k in call.keywords if k.arg == "name"]
+    if isinstance(name, ast.Name):
+        # handed down as the static ``name=`` of the jitted function round
+        # the call (decode_attention._flash_call): read it where it is made
+        with open(os.path.join(_PALLAS_DIR, filename)) as f:
+            (name,) = [k.value for n in ast.walk(ast.parse(f.read()))
+                       if isinstance(n, ast.Call) for k in n.keywords
+                       if k.arg == "name" and isinstance(k.value, ast.Call)]
     # built by ops._dispatch.kernel_name, so a program part can lead it
     assert isinstance(name, ast.Call) and "kernel_name" in ast.dump(name.func)
     assert isinstance(name.args[0], ast.Constant) and name.args[0].value
