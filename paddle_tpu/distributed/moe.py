@@ -313,16 +313,18 @@ class SigmoidTopKGate(Gate):
     term).  Scores are ``sigmoid(x·W)`` in float32 over ALL experts; the
     ``top_k`` experts are those of the largest ``scores + expert_bias``;
     their WEIGHTS are the unbiased scores, divided by their sum where
-    ``route_norm`` and scaled by ``route_scale``: the bias moves the
-    selection and never the weight."""
+    ``route_norm`` (``norm_eps`` guards the division: the families differ
+    in it) and scaled by ``route_scale``: the bias moves the selection and
+    never the weight."""
 
     def __init__(self, hidden_size: int, num_experts: int, top_k: int,
                  route_scale: float = 1.0, route_norm: bool = True,
-                 dtype=None):
+                 norm_eps: float = 1e-20, dtype=None):
         super().__init__(hidden_size, num_experts, dtype=dtype)
         self.top_k = int(top_k)
         self.route_scale = float(route_scale)
         self.route_norm = bool(route_norm)
+        self.norm_eps = float(norm_eps)
         self.expert_bias = self.create_parameter(
             (num_experts,), dtype="float32", initializer=I.Constant(0.0),
             attr_name="expert_bias")
@@ -341,7 +343,7 @@ class SigmoidTopKGate(Gate):
             scores + self.expert_bias.astype(jnp.float32), self.top_k)[1]
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if self.route_norm:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+            w = w / (w.sum(-1, keepdims=True) + self.norm_eps)
         return idx.astype(jnp.int32), w * self.route_scale
 
 

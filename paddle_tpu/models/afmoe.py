@@ -57,7 +57,7 @@ from .llama import LlamaMLP, paged_kv_write
 
 __all__ = ["AfmoeConfig", "AfmoeAttention", "AfmoeMoE",
            "AfmoeDecoderLayer", "AfmoeModel", "AfmoeForCausalLM",
-           "tiny_afmoe_config"]
+           "tiny_afmoe_config", "held_experts_kernel_specs"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -252,6 +252,24 @@ class AfmoeAttention(Layer):
             return self._out(x, out), cache
 
 
+def held_experts_kernel_specs(config, token_rows):
+    """Pre-flight specs of the kernels only a held-experts model's step
+    programs build: the grouped products (in and out projection), per
+    program part of ``token_rows`` tokens.  ``config``: any with
+    ``experts_held``, ``num_experts_per_tok``, ``hidden_size`` and
+    ``moe_intermediate_size``."""
+    from ..distributed.moe import grouped_kernel_takes
+    from ..static_analysis import moe_experts_spec
+    c = config
+    lo, hi = c.experts_held
+    h, fm = c.hidden_size, c.moe_intermediate_size
+    return [moe_experts_spec(rows * c.num_experts_per_tok, hi - lo, k, n,
+                             variant=f"tokens={rows},{k}x{n}")
+            for rows in token_rows
+            for k, n in sorted({(h, fm), (fm, h)})
+            if grouped_kernel_takes(k, n)]
+
+
 def swiglu_mlp(config: AfmoeConfig, width: int) -> LlamaMLP:
     """SwiGLU MLP of a given width — the dense layers' and the shared
     expert's: llama's, which reads ``hidden_size``, ``intermediate_size``,
@@ -423,23 +441,12 @@ class AfmoeForCausalLM(Layer):
         return tuple(block.self_attn.window for block in self.model.layers)
 
     def serving_kernel_specs(self, token_rows):
-        """Pre-flight specs of the kernels only this model's step programs
-        build: the held experts' grouped products (in and out projection),
-        per program part of ``token_rows`` tokens."""
-        from ..distributed.moe import grouped_kernel_takes
-        from ..static_analysis import moe_experts_spec
-        c = self.config
-        lo, hi = c.experts_held
-        h, fm = c.hidden_size, c.moe_intermediate_size
-        return [moe_experts_spec(rows * c.num_experts_per_tok, hi - lo, k, n,
-                                 variant=f"tokens={rows},{k}x{n}")
-                for rows in token_rows
-                for k, n in sorted({(h, fm), (fm, h)})
-                if grouped_kernel_takes(k, n)]
+        return held_experts_kernel_specs(self.config, token_rows)
 
     def check_serving_layout(self, *, paged, kv_cache_dtype, mesh,
-                             spec_decode, int8_weights):
-        """Refuse, by name, the engine layouts this model cannot run."""
+                             spec_decode, int8_weights, **_):
+        """Refuse, by name, the engine layouts this model cannot run (of
+        the arguments the engine states, those this model has a say on)."""
         def no(what, why):
             raise NotImplementedError(
                 f"AfmoeForCausalLM cannot be served with {what}: {why}")

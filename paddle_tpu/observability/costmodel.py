@@ -143,7 +143,8 @@ def resolve_profile(name: Optional[str] = None) -> HardwareProfile:
 
 
 def kv_bytes_per_token(config: Any, kv_dtype: str, *,
-                       block_len: int = 0) -> float:
+                       block_len: int = 0,
+                       num_layers: Optional[int] = None) -> float:
     """HBM bytes one live context token costs the decode KV fetch.
 
     Matches the engine's pool accounting exactly (engine.py block-nbytes
@@ -153,12 +154,13 @@ def kv_bytes_per_token(config: Any, kv_dtype: str, *,
     f32 scale row amortized over ``block_len`` tokens.  ``mixed`` keeps
     the device pool at native precision, so it streams full bytes."""
     c = config
-    tok = int(c.num_hidden_layers) * 2 * int(c.num_key_value_heads) \
-        * int(c.head_dim)
+    # the layers that hold K/V: every layer unless the caller says fewer
+    layers = int(c.num_hidden_layers if num_layers is None else num_layers)
+    tok = layers * 2 * int(c.num_key_value_heads) * int(c.head_dim)
     import jax.numpy as jnp
     native = jnp.zeros((), c.dtype).dtype.itemsize
     if kv_dtype == "int8":
-        scales = int(c.num_hidden_layers) * 2 * int(c.num_key_value_heads) * 4
+        scales = layers * 2 * int(c.num_key_value_heads) * 4
         # contiguous int8 rows carry per-position scales too; default the
         # amortization granule to one position when there is no block
         return float(tok + scales / max(1, int(block_len)))

@@ -565,7 +565,8 @@ def _canonical_trace(trace: Dict[str, Any]) -> Dict[str, Any]:
 def _sig_labels(labels: Dict[str, str]) -> List[Tuple[str, str]]:
     # engine ids are per-process counters (different on every run, like
     # timeline_signature's _SIGNATURE_SKIP); worker names carry the
-    # stable identity
+    # stable identity.  The series arrive ordered by engine id AS A STRING
+    # ("10" before "9"), so the signature sorts them by what is left
     return sorted((k, v) for k, v in labels.items() if k != "engine")
 
 
@@ -586,13 +587,13 @@ def fleet_obs_signature(merged_trace: Dict[str, Any],
         if fam["type"] == "histogram":
             metrics_part[name] = {
                 "count": fam["pooled"]["count"],
-                "series": [[_sig_labels(r["labels"]), r["count"]]
-                           for r in fam["series"]]}
+                "series": sorted([_sig_labels(r["labels"]), r["count"]]
+                                 for r in fam["series"])}
         else:
             metrics_part[name] = {
                 "total": fam["pooled"]["value"],
-                "series": [[_sig_labels(r["labels"]), r["value"]]
-                           for r in fam["series"]]}
+                "series": sorted([_sig_labels(r["labels"]), r["value"]]
+                                 for r in fam["series"])}
     health = {
         name: {"alive": w["alive"],
                "heartbeat_age_ticks": w["heartbeat_age_ticks"],

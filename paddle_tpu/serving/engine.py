@@ -443,10 +443,6 @@ class ServingEngine:
         ``quantize_for_decode`` so the engine's linear layers run the
         weight-only int8 path.  Both compose with every layout above;
         every program stays jitted once."""
-        if hasattr(model, "init_decode_state"):
-            raise NotImplementedError(
-                "ServingEngine requires the stacked KV cache; recurrent "
-                "decode states (Mamba/RWKV) are not slot-addressable yet")
         limit = getattr(model.config, "max_position_embeddings", None)
         if limit is not None and max_length > limit:
             raise ValueError(
@@ -517,14 +513,34 @@ class ServingEngine:
         self.mesh = self._resolve_mesh(mesh)
         # quantized-decode hooks, exactly as models/generation.py binds
         self._bind = getattr(model, "unwrapped", model)
-        # a model says itself which of the engine's layouts it cannot run,
-        # whether its step programs return a routed-expert load beside the
-        # tokens, and which of its layers read a sliding window only
+        # THE CONTRACT for a model that is not llama: it says itself which
+        # leaves of its serving cache are fixed-size per slot beside the
+        # paged pool (``slot_state``: their names; slot axis 1) and makes
+        # the whole cache for N slots (``init_serving_cache``), which of the
+        # engine's layouts it cannot run (``check_serving_layout``), whether
+        # its step programs return a routed-expert load beside the tokens
+        # (``expert_layers``), and which of its layers read a sliding window
+        # only (``attention_windows``).  A model that keeps a decode state of
+        # its own and declares none of it is refused here, by name.
+        self._slot_leaves = tuple(getattr(self._bind, "slot_state", ()))
+        if (hasattr(self._bind, "init_decode_state")
+                and not self._slot_leaves):
+            raise NotImplementedError(
+                f"{type(self._bind).__name__} cannot be served: it keeps a "
+                f"decode state of its own (init_decode_state) and does not "
+                f"declare it as serving state — slot_state (the leaves that "
+                f"are fixed-size per slot) and init_serving_cache (the "
+                f"paged pool and those leaves for N slots)")
+        if prefix_cache is None:
+            prefix_cache = bool(_flags.flag("serving_prefix_cache"))
         check_layout = getattr(self._bind, "check_serving_layout", None)
         if check_layout is not None:
-            check_layout(paged=self.paged, kv_cache_dtype=self.kv_dtype,
-                         mesh=self.mesh, spec_decode=self.spec,
-                         int8_weights=self._int8_weights)
+            check_layout(paged=self.paged, chunked=self.chunked,
+                         prefix_cache=self.paged and bool(prefix_cache),
+                         kv_cache_dtype=self.kv_dtype, mesh=self.mesh,
+                         spec_decode=self.spec,
+                         int8_weights=self._int8_weights,
+                         preempt=self.preempt, host_blocks=self._host_blocks)
         self._expert_layers = int(getattr(self._bind, "expert_layers", 0))
         self._windows = tuple(
             int(w) for w in getattr(self._bind, "attention_windows", ())
@@ -534,20 +550,32 @@ class ServingEngine:
 
         self._prepare = getattr(model, "_prepare_params", lambda p: p)
         params = model.state_dict(include_buffers=True)
+        # the layers that hold K/V: all of them, or the paged leaf's own
+        # count where the model makes its serving cache itself
+        self._kv_layers = int(model.config.num_hidden_layers)
         if self.paged:
             nb, bl = self._init_pool(block_len, num_blocks, prefix_cache)
-            cache = init_paged_kv_cache(model.config, nb, bl,
-                                        quantized=self.quantized)
+            if self._slot_leaves:
+                # one row a slot and a null row, which a chunk-free tick's
+                # chunk part addresses
+                cache = self._bind.init_serving_cache(self.num_slots + 1,
+                                                      nb, bl)
+                (pool,) = (v for k, v in cache.items()
+                           if k not in self._slot_leaves)
+                self._kv_layers = int(pool.shape[0])
+            else:
+                cache = init_paged_kv_cache(model.config, nb, bl,
+                                            quantized=self.quantized)
             # arm the pool's bytes_by_dtype gauges with this model's
             # per-block costs (payload + the int8 block's scale row)
             c = model.config
-            tok = (c.num_hidden_layers * 2 * c.num_key_value_heads
+            tok = (self._kv_layers * 2 * c.num_key_value_heads
                    * c.head_dim)
             native = jnp.zeros((), c.dtype).dtype.itemsize
             self.kv.set_block_nbytes({
                 "bf16": tok * bl * native,
                 "int8": tok * bl
-                + c.num_hidden_layers * 2 * c.num_key_value_heads * 4})
+                + self._kv_layers * 2 * c.num_key_value_heads * 4})
         else:
             cache = init_kv_cache(model.config, self.num_slots,
                                   self.max_length,
@@ -558,6 +586,10 @@ class ServingEngine:
             jnp.zeros((self.num_slots, 1), jnp.int32),
             paged_cache=self.paged, mesh=self.mesh)
         self._params, self._cache = params, cache
+        if self._slot_leaves:
+            self._m_state_rows.set(float(self.num_slots + 1))
+            self._m_state_bytes.set(float(sum(
+                cache[k].nbytes for k in self._slot_leaves)))
         if self.spec:
             sel = (self._drafter_arg if self._drafter_arg is not None
                    else str(_flags.flag("serving_spec_drafter")))
@@ -798,7 +830,8 @@ class ServingEngine:
         # granule (models/generation.init_kv_cache)
         kv_tok = _cm.kv_bytes_per_token(
             self.config, self.kv_dtype,
-            block_len=self.block_len if self.paged else 128)
+            block_len=self.block_len if self.paged else 128,
+            num_layers=self._kv_layers)
         comm_fn = None
         if self.mesh is not None:
             def comm_fn():
@@ -1231,6 +1264,7 @@ class ServingEngine:
         self._expert_pairs: Optional[np.ndarray] = None
         self._expert_totals = np.zeros(3, np.int64)
         self._window_dead = 0
+        self._state_live = 0
         self._model_counters = bool(self._expert_layers or self._windows)
         if self._expert_layers:
             self._m_pairs_elsewhere = ctr(
@@ -1243,6 +1277,20 @@ class ServingEngine:
                 "held experts with at least one routed pair, per expert-"
                 "layer call: the expert weights the grouped product had "
                 "to read").labels(**lbl)
+        if self._slot_leaves:
+            self._m_state_live = gauge(
+                "kv_cache.state_rows_live",
+                "rows of the fixed-size per-slot state held by a resident "
+                "request (decoding, or mid-prompt under the cursor) at the "
+                "last tick").labels(**lbl)
+            self._m_state_rows = gauge(
+                "kv_cache.state_rows",
+                "rows of the fixed-size per-slot state allocated: one a "
+                "slot and the null row").labels(**lbl)
+            self._m_state_bytes = gauge(
+                "kv_cache.state_bytes",
+                "bytes of the fixed-size per-slot state leaves").labels(
+                    **lbl)
         if self._windows:
             self._m_window_dead = gauge(
                 "kv_cache.window_dead_positions",
@@ -1339,6 +1387,11 @@ class ServingEngine:
                      # block table, or its slot
                      op("cdst", (1, mb), i32, "cdst") if self.paged
                      else op("cdst", (), i32, "cdst", jnp.int32)]
+            if self._slot_leaves:
+                # the row of the per-slot state the chunk part addresses:
+                # the cursor's slot (a table row names blocks, not a slot);
+                # the null row on a chunk-free tick
+                step.append(op("cslot", (), i32, "cslot", jnp.int32))
             step += knobs(1, "c", "ctemps", "ctopk", "ctopp")
             return step + [key], None
         wave = [op("ids", (nb, None), i32, "ids")]
@@ -1354,13 +1407,34 @@ class ServingEngine:
 
     def _experts(self, valid):
         """For a model with expert layers: the trace-time collector of
-        their load (``distributed.moe.expert_load``) and the ``valid=`` its
-        ``decode_step`` takes (``valid`` builds the mask of the real
-        tokens).  For any other model an empty collector and no argument,
-        so that its step programs are traced as they ever were."""
-        if not self._expert_layers:
-            return contextlib.nullcontext(()), {}
-        return _moe.expert_load(), {"valid": valid()}
+        their load (``distributed.moe.expert_load``); for one with expert
+        layers or per-slot state: the ``valid=`` its ``decode_step`` takes
+        (``valid`` builds the mask of the real tokens: padding is routed
+        to no expert and advances no state).  For any other model an empty
+        collector and no argument, so that its step programs are traced as
+        they ever were."""
+        return ((_moe.expert_load() if self._expert_layers
+                 else contextlib.nullcontext(())),
+                {"valid": valid()}
+                if self._expert_layers or self._slot_leaves else {})
+
+    def _state_rows(self, cache, start, n: int):
+        """``cache`` as one program part sees it: the paged leaf whole, the
+        per-slot leaves cut to the ``n`` rows from ``start`` that the part
+        addresses (slot axis 1).  No-op for a model without such leaves."""
+        if not self._slot_leaves:
+            return cache
+        return {k: jax.lax.dynamic_slice_in_dim(v, start, n, axis=1)
+                if k in self._slot_leaves else v for k, v in cache.items()}
+
+    def _state_rows_back(self, cache, part, start):
+        """Put a part's view back: its rows into the per-slot leaves, the
+        paged leaf as the part left it."""
+        if not self._slot_leaves:
+            return part
+        return {k: jax.lax.dynamic_update_slice_in_dim(cache[k], v, start,
+                                                       axis=1)
+                if k in self._slot_leaves else v for k, v in part.items()}
 
     def _step_program(self):
         """The Python body of THE step program, composed for this engine's
@@ -1445,9 +1519,12 @@ class ServingEngine:
                 collect, real = self._experts(lambda: mask[:, None])
                 with _disp.program_part(_STEP, "decode_rows"), \
                         bind_params(self._bind, prep), collect as load:
-                    logits, cache = self.model.decode_step(
-                        a["tokens"][:, None], cache, a["positions"], **at,
-                        **real)
+                    # per-slot state: the slots' rows (the null row stays out)
+                    logits, part = self.model.decode_step(
+                        a["tokens"][:, None],
+                        self._state_rows(cache, 0, self.num_slots),
+                        a["positions"], **at, **real)
+                    cache = self._state_rows_back(cache, part, 0)
                 with jax.named_scope("sample"):
                     nxt = sample_tokens(logits[:, -1], key, *knobs)
                     outs = [jnp.where(mask, nxt,
@@ -1463,8 +1540,11 @@ class ServingEngine:
             with _disp.program_part(_STEP, "prompt_chunk"), \
                     bind_params(self._bind, prep), collect as cload:
                 if paged:       # the pool IS the cache for both parts
-                    clogits, cache = self.model.decode_step(
-                        cids, cache, cpos[None], block_tables=cdst, **real)
+                    cslot = a.get("cslot")      # per-slot state: its one row
+                    clogits, part = self.model.decode_step(
+                        cids, self._state_rows(cache, cslot, 1), cpos[None],
+                        block_tables=cdst, **real)
+                    cache = self._state_rows_back(cache, part, cslot)
                 else:
                     row = _slot_row(cache, cdst)
                     clogits, row = self.model.decode_step(
@@ -1996,6 +2076,13 @@ class ServingEngine:
 
     # -- cross-worker migration (ISSUE 18) ---------------------------------
 
+    def _refuse_state_migration(self, what: str):
+        if self._slot_leaves:
+            raise NotImplementedError(
+                f"{type(self._bind).__name__} cannot be served with "
+                f"{what}: a request's record carries its KV blocks and not "
+                f"its fixed-size per-slot state {self._slot_leaves}")
+
     def export_request(self, rid: int,
                        release: bool = True) -> Optional[Dict[str, object]]:
         """Serialize an ACTIVELY DECODING request for migration to
@@ -2017,6 +2104,7 @@ class ServingEngine:
             raise RuntimeError(
                 "export_request requires the paged cache "
                 "(ServingEngine(..., paged=True))")
+        self._refuse_state_migration("export_request")
         self._block_movers()
         for i, slot in enumerate(self._slots):
             if slot is None or slot.rid != rid:
@@ -2078,6 +2166,7 @@ class ServingEngine:
             raise RuntimeError(
                 "import_request requires the paged cache "
                 "(ServingEngine(..., paged=True))")
+        self._refuse_state_migration("import_request")
         self._block_movers()
         free = self._free_slots()
         if not free:
@@ -2202,7 +2291,7 @@ class ServingEngine:
                 bk = self.max_length
             cols = self.max_length // bk
         windows = (getattr(self._bind, "attention_windows", None)
-                   or (None,) * int(c.num_hidden_layers))
+                   or (None,) * self._kv_layers)
         self._kv_walk_geom = (
             int(c.num_attention_heads) // int(c.num_key_value_heads),
             int(bk), int(cols),
@@ -2306,12 +2395,22 @@ class ServingEngine:
             if chunked:      # the chunk part runs every tick, real or not
                 walks.append(([cpos], self.prefill_chunk))
             kv_walk = self._kv_walk(*walks)
+            state = {}
+            if self._slot_leaves:
+                # the state row the chunk part addresses: the cursor's
+                # slot, or the null row (a chunk-free tick's junk lands
+                # there); the rows part advances the decoding rows alone
+                own["cslot"] = cslot if do_chunk else self.num_slots
+                state = {"state": "carried" if cpos else "fresh"}
+                kv_walk["state_rows"] = occ
+                self._state_live = occ + (pf is not None)
+                self._m_state_live.set(float(self._state_live))
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
             sample_path=self._note_sample_path(*knobs), **kv_walk,
             **({"drafted": int(draft_ok.sum())} if spec else {}))
         chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
-                           tokens=clen)
+                           tokens=clen, **state)
                       if do_chunk else contextlib.nullcontext())
         with rows_span, chunk_span:
             if paged:
@@ -2781,14 +2880,14 @@ class ServingEngine:
                     b, s, hq, hkv, d_p, block_len=bl_p,
                     max_blocks=mb_p,
                     num_blocks=self.num_slots * mb_p + 1,
-                    num_layers=int(c.num_hidden_layers),
+                    num_layers=self._kv_layers,
                     quantized=quantized, variant=tag))
                 # a window layer's call of the same kernel: the block
                 # walk starts at the window's first block
                 specs.extend(_sa.decode_attention_spec(
                     b, s, hq, hkv, d_p, block_len=bl_p, max_blocks=mb_p,
                     num_blocks=self.num_slots * mb_p + 1,
-                    num_layers=int(c.num_hidden_layers), window=w,
+                    num_layers=self._kv_layers, window=w,
                     variant=f"{tag},window={w}")
                     for w in sorted(set(self._windows)))
             else:
@@ -3097,6 +3196,16 @@ class ServingEngine:
         return {"pairs": self._expert_pairs.copy(),
                 "pairs_elsewhere": elsewhere, "experts_touched": touched,
                 "layer_calls": calls}
+
+    @property
+    def state_rows(self) -> Optional[Tuple[int, int]]:
+        """Of the fixed-size per-slot state: (rows held by a resident
+        request at the last tick — decoding, or mid-prompt under the
+        cursor — and rows allocated: one a slot and the null row); None
+        for a model that declares no such state."""
+        if not self._slot_leaves:
+            return None
+        return self._state_live, self.num_slots + 1
 
     @property
     def window_dead_positions(self) -> int:

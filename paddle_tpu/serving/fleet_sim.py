@@ -140,8 +140,9 @@ class SimEngine(ServingEngine):
         self.prefill_batch = int(prefill_batch)
         self._int8_weights = False
         # no model: no expert layers' load, no window layers' dead
-        # positions, no kernel whose block walk the spans would count
-        self._expert_layers, self._windows = 0, ()
+        # positions, no per-slot state, no kernel whose block walk the
+        # spans would count
+        self._expert_layers, self._windows, self._slot_leaves = 0, (), ()
         self._kv_walk_geom = None
         # the simulator is paged-only: the BlockManager IS the part of
         # the memory system worth simulating (admission blocking,

@@ -170,11 +170,13 @@ class _StatsView(Mapping):
 
 
 def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
-                        quantized: bool = False):
+                        quantized: bool = False,
+                        num_layers: Optional[int] = None):
     """Pooled paged cache: (L, 2, num_blocks, block_len, kv_heads·head_dim)
     — the contiguous cache's (B, max_len) plane re-cut into fixed blocks,
     each block in the layout the flash-decode kernel DMAs (heads fused
-    into the last axis, head-major).  The block axis is axis 2.
+    into the last axis, head-major).  The block axis is axis 2.  ``L`` is
+    ``num_layers``, the layers that hold K/V (None: every layer).
 
     ``quantized``: the int8 pool — a two-leaf pytree
     ``{"kv": int8 (L, 2, nb, bl, Hkv·D), "scale": f32 (L, 2, nb, Hkv)}``
@@ -186,7 +188,8 @@ def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
     """
     import jax.numpy as jnp
 
-    shape = (config.num_hidden_layers, 2, num_blocks, block_len,
+    shape = (config.num_hidden_layers if num_layers is None
+             else int(num_layers), 2, num_blocks, block_len,
              config.num_key_value_heads * config.head_dim)
     if quantized:
         return {

@@ -5,13 +5,13 @@ program (``tests/test_sample_paths.py``):
     JAX_PLATFORMS=cpu python tests/lowered_step_text.py <repo root>
 
 prints, under the chip's dispatch, the sha256 of the text of the step and
-prefill programs of every benchmark cell (published widths, the engine
-settings of the cells' files; 2 layers: the layers are one code path
-repeated) and of the engine's eight layouts (paged x chunked x spec) at tiny
-llama geometry.  Run it on two checkouts and compare the lines: PR 27 used
-it to show that a second model in the engine left the llama step programs as
-they were, PR 29 that one composed step program lowers to the text of the
-eight hand-written ones."""
+prefill programs of every benchmark configuration (published widths, the
+engine settings of the cells' files; 2 layers, or 4 where the layers are of
+four kinds: the layers are one code path repeated) and of the engine's eight
+layouts (paged x chunked x spec) at tiny llama geometry.  Run it on two
+checkouts and compare the lines: PR 27 used it to show that a second model in
+the engine left the llama step programs as they were, PR 29 that one composed
+step program lowers to the text of the eight hand-written ones."""
 import base64
 import hashlib
 import json
@@ -115,6 +115,31 @@ def programs(label, eng, bucket, vocab=None):
               *sha(lowered(prefill, prefill_args(eng, bucket))))
 
 
+def lfm2_cell(root, cell_file):
+    """The fourth cell, on a checkout that has it: a dense convolution
+    layer, a dense one, then an attention layer and a convolution layer with
+    experts (the published layers 0-3), every held expert."""
+    name = "lfm2-8b-a1b-ep2.decode-wide-saturated"
+    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
+                                       name + ".json")):
+        return
+    import paddle_tpu as pt
+    from benchmark.harness import serve_lfm2
+    from paddle_tpu import nn
+    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM
+    from paddle_tpu.serving import ServingEngine
+    kw = dict(cell_file("workloads", name)["engine"], num_blocks=129)
+    cfg = cell_file("configs", "lfm2-8b-a1b-ep2")
+    cfg = dict(cfg, num_hidden_layers=4, layer_types=cfg["layer_types"][:4])
+    with nn.abstract_parameters():
+        model = Lfm2MoeForCausalLM(
+            serve_lfm2.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    programs(name, ServingEngine(model, seed=0, **kw), 256)
+    pt.flags.set_flags({"perf_model": "on"})
+
+
 def main(root):
     root = os.path.abspath(root)
     sys.path.insert(0, root)
@@ -164,6 +189,7 @@ def main(root):
     programs(cell, ServingEngine(model, seed=0, **kw), 256)
     pt.flags.set_flags({"perf_model": "on"})
     del model
+    lfm2_cell(root, cell_file)
 
     pt.seed(7)
     model = LlamaForCausalLM(tiny_llama_config(context_parallel="gspmd"))

@@ -22,8 +22,10 @@ from paddle_tpu.ops.pallas.decode_attention import (
 HKV, D, BLOCK = 8, 128, 128
 
 # name: (rows, q length, q heads, layers, pool blocks, table columns, window,
-# cache dtype) — the decode rows, the prompt chunk and a wave's rows of the
-# Mistral cells, the Trinity cell's window layers, an int8 pool
+# cache dtype[, head size]) — the decode rows, the prompt chunk and a wave's
+# rows of the Mistral cells, the Trinity cell's window layers, an int8 pool,
+# and the LFM2 cell's six K/V layers at head size 64: half a lane tile, so
+# the body slices a group's (keys, Hkv·D) buffer at 64-lane offsets
 PAGED = {
     "mistral-rows": (96, 1, 32, 16, 769, 32, None, jnp.bfloat16),
     "mistral-chunk": (1, 256, 32, 16, 385, 32, None, jnp.bfloat16),
@@ -31,6 +33,8 @@ PAGED = {
     "trinity-rows-window": (192, 1, 48, 5, 1921, 64, 4096, jnp.bfloat16),
     "trinity-chunk-window": (1, 256, 48, 5, 1921, 64, 4096, jnp.bfloat16),
     "int8-rows": (8, 1, 32, 4, 257, 32, None, jnp.int8),
+    "lfm2-rows-d64": (320, 1, 32, 6, 2049, 32, None, jnp.bfloat16, 64),
+    "lfm2-chunk-d64": (1, 256, 32, 6, 2049, 32, None, jnp.bfloat16, 64),
 }
 
 
@@ -57,9 +61,10 @@ def _spec(one_chip, shape, dtype):
 
 @pytest.mark.parametrize("name", list(PAGED))
 def test_paged_kernel_compiles_for_v5e(one_chip, name):
-    b, s, hq, layers, blocks, cols, window, dtype = PAGED[name]
-    args = [_spec(one_chip, (b, s, hq, D), jnp.bfloat16),
-            _spec(one_chip, (layers, 2, blocks, BLOCK, HKV * D), dtype),
+    b, s, hq, layers, blocks, cols, window, dtype, *d = PAGED[name]
+    d = d[0] if d else D
+    args = [_spec(one_chip, (b, s, hq, d), jnp.bfloat16),
+            _spec(one_chip, (layers, 2, blocks, BLOCK, HKV * d), dtype),
             _spec(one_chip, (b,), jnp.int32),
             _spec(one_chip, (b, cols), jnp.int32)]
     if dtype == jnp.int8:

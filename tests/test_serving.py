@@ -178,7 +178,8 @@ def test_recurrent_models_rejected():
     pt.seed(9)
     model = Mamba2ForCausalLM(tiny_mamba2_config())
     model.eval()
-    with pytest.raises(NotImplementedError, match="slot-addressable"):
+    with pytest.raises(NotImplementedError,
+                       match="does not declare it as serving state"):
         ServingEngine(model, num_slots=2, max_length=32)
 
 
