@@ -53,7 +53,9 @@ from ..nn.common import RMSNorm
 from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
-from .llama import LlamaMLP, paged_kv_write
+from .llama import LlamaMLP, paged_kv_write, part_site
+from .parts import (DecodePart, head_tokens, join_tokens, join_valid,
+                    part_by_part, split_tokens)
 
 __all__ = ["AfmoeConfig", "AfmoeAttention", "AfmoeMoE",
            "AfmoeDecoderLayer", "AfmoeModel", "AfmoeForCausalLM",
@@ -171,7 +173,9 @@ class AfmoeAttention(Layer):
         self.q_norm = RMSNorm(hd, epsilon=c.rms_norm_eps, dtype=c.dtype)
         self.k_norm = RMSNorm(hd, epsilon=c.rms_norm_eps, dtype=c.dtype)
 
-    def _qkv(self, x, rope_cache, position_ids):
+    def _proj(self, x):
+        """q, k (normed) and v of every token, split into heads:
+        token-wise."""
         c = self.config
         b, s, _ = x.shape
         q = matmul(x, self.q_proj).reshape(b, s, c.num_attention_heads,
@@ -180,10 +184,16 @@ class AfmoeAttention(Layer):
                                            c.head_dim)
         v = matmul(x, self.v_proj).reshape(b, s, c.num_key_value_heads,
                                            c.head_dim)
-        q, k = self.q_norm(q), self.k_norm(k)
-        if self.window is not None:         # global layers: no position
-            q, k = fused_rope(q, k, *rope_cache, position_ids)
-        return q, k, v
+        return self.q_norm(q), self.k_norm(k), v
+
+    def _rope(self, q, k, rope_cache, position_ids):
+        if self.window is None:             # global layers: no position
+            return q, k
+        return fused_rope(q, k, *rope_cache, position_ids)
+
+    def _qkv(self, x, rope_cache, position_ids):
+        q, k, v = self._proj(x)
+        return (*self._rope(q, k, rope_cache, position_ids), v)
 
     def _out(self, x, attn):
         b, s, _ = x.shape
@@ -203,59 +213,64 @@ class AfmoeAttention(Layer):
             out = flash_attention(q, k, v, causal=True, attn_mask=mask)
             return self._out(x, out)
 
-    def decode(self, x, rope_cache, pos, cache, idx: int,
-               block_tables=None):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         """Decode over the stacked cache, as ``LlamaAttention.decode``:
-        with ``block_tables`` the paged pool (per-row ``pos``), without
-        them the contiguous cache at a scalar ``pos`` (``generate()``).
-        Returns (out, cache)."""
-        from ..ops.attention import (cached_decode_attention,
-                                     paged_decode_attention)
-        b, s, _ = x.shape
-        win = {} if self.window is None else {"window": self.window}
+        the projections, the gate and the output projection once over the
+        tokens of all ``parts``; each part's RoPE, K/V write and read at
+        its own positions — with ``block_tables`` through the paged pool
+        (per-row ``pos``), without them over the contiguous cache at a
+        scalar ``pos`` (``generate()``).  Returns (out, cache)."""
         if isinstance(cache, dict):
             raise NotImplementedError(
                 "AfmoeAttention.decode: the int8 KV cache is not supported")
         with jax.named_scope(self.scope):
-            if block_tables is not None:
-                if getattr(pos, "ndim", 0) != 1:
-                    pos = jnp.full((b,), pos, jnp.int32)
-                position_ids = pos[:, None] + jnp.arange(s)[None, :]
-                # prompt-pad positions may run past the RoPE table
-                rope_ids = jnp.minimum(position_ids,
-                                       rope_cache[0].shape[0] - 1)
-                q, k, v = self._qkv(x, rope_cache, rope_ids)
-                cache, kvp, _ = paged_kv_write(cache, idx, k, v,
-                                               position_ids, block_tables)
-                out = paged_decode_attention(q, kvp, idx, pos, block_tables,
-                                             **win)
-                return self._out(x, out), cache
-            if getattr(pos, "ndim", 0) != 0:
-                raise NotImplementedError(
-                    "AfmoeAttention.decode: per-row positions need the "
-                    "paged pool (block_tables); the contiguous cache is "
-                    "decoded at one scalar position")
-            q, k, v = self._qkv(x, rope_cache,
-                                pos + jnp.arange(s)[None, :])
-            cache = jax.lax.dynamic_update_slice(
-                cache, k.astype(cache.dtype)[None, None],
-                (idx, 0, 0, pos, 0, 0))
-            cache = jax.lax.dynamic_update_slice(
-                cache, v.astype(cache.dtype)[None, None],
-                (idx, 1, 0, pos, 0, 0))
-            if isinstance(pos, int) and pos == 0 and s > 1:
-                mask = None if self.window is None else self._band(s)
-                out = flash_attention(q, k, v, causal=True, attn_mask=mask)
-            else:
-                out = cached_decode_attention(q, cache[idx, 0],
-                                              cache[idx, 1], pos, **win)
+            sites = [self._site(p, rope_cache) for p in parts]
+            out, cache = part_by_part(
+                parts, self._proj(x), cache,
+                lambda i, p, cache, q, k, v: self._attend(
+                    q, k, v, rope_cache, p, sites[i], cache, idx))
             return self._out(x, out), cache
+
+    @staticmethod
+    def _site(part, rope_cache):
+        if part.block_tables is None and getattr(part.pos, "ndim", 0) != 0:
+            raise NotImplementedError(
+                "AfmoeAttention.decode: per-row positions need the "
+                "paged pool (block_tables); the contiguous cache is "
+                "decoded at one scalar position")
+        return part_site(part, rope_cache)
+
+    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
+        """One part's RoPE, write and read against layer ``idx``."""
+        from ..ops.attention import (cached_decode_attention,
+                                     paged_decode_attention)
+        pos, position_ids, rope_ids = site
+        s = q.shape[1]
+        win = {} if self.window is None else {"window": self.window}
+        q, k = self._rope(q, k, rope_cache, rope_ids)
+        if part.block_tables is not None:
+            cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
+                                           part.block_tables)
+            return paged_decode_attention(q, kvp, idx, pos,
+                                          part.block_tables, **win), cache
+        cache = jax.lax.dynamic_update_slice(
+            cache, k.astype(cache.dtype)[None, None],
+            (idx, 0, 0, pos, 0, 0))
+        cache = jax.lax.dynamic_update_slice(
+            cache, v.astype(cache.dtype)[None, None],
+            (idx, 1, 0, pos, 0, 0))
+        if isinstance(pos, int) and pos == 0 and s > 1:
+            mask = None if self.window is None else self._band(s)
+            return flash_attention(q, k, v, causal=True,
+                                   attn_mask=mask), cache
+        return cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
+                                       pos, **win), cache
 
 
 def held_experts_kernel_specs(config, token_rows):
     """Pre-flight specs of the kernels only a held-experts model's step
     programs build: the grouped products (in and out projection), per
-    program part of ``token_rows`` tokens.  ``config``: any with
+    pass of the weights over ``token_rows`` tokens.  ``config``: any with
     ``experts_held``, ``num_experts_per_tok``, ``hidden_size`` and
     ``moe_intermediate_size``."""
     from ..distributed.moe import grouped_kernel_takes
@@ -332,15 +347,13 @@ class AfmoeDecoderLayer(Layer):
         a = self.self_attn(self.input_layernorm(x), rope_cache, position_ids)
         return self._ffn(x + self.post_attention_layernorm(a))
 
-    def decode(self, x, rope_cache, pos, cache, idx: int,
-               block_tables=None, valid=None):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         with jax.named_scope("attn"):
             a, cache = self.self_attn.decode(
-                self.input_layernorm(x), rope_cache, pos, cache, idx,
-                block_tables=block_tables)
+                self.input_layernorm(x), rope_cache, parts, cache, idx)
             h = x + self.post_attention_layernorm(a)
         with jax.named_scope("ffn"):
-            return self._ffn(h, valid), cache
+            return self._ffn(h, join_valid(parts)), cache
 
 
 class AfmoeModel(Layer):
@@ -376,21 +389,26 @@ class AfmoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.norm(x)
 
-    def decode(self, input_ids, cache, pos, block_tables=None, valid=None):
-        """Cache-carrying decode pass over the stacked cache (contiguous
-        from ``init_kv_cache`` or, with ``block_tables``, the paged pool).
-        Returns (hidden, cache)."""
-        x = constrain(self._embed(input_ids), ("dp", "sharding"), None, None)
+    def decode(self, parts, cache):
+        """Cache-carrying decode pass of ``parts``
+        (:mod:`~paddle_tpu.models.parts`) over the stacked cache
+        (contiguous from ``init_kv_cache`` or, for parts with
+        ``block_tables``, the paged pool).  Returns (the normed hidden
+        states the head is taken of, their per-part (rows, positions),
+        cache)."""
+        x = constrain(
+            self._embed(join_tokens([p.input_ids for p in parts])),
+            ("dp", "sharding"), None, None)
         rope = (self.rope_cos, self.rope_sin)
         for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, pos, cache, i,
-                                    block_tables=block_tables, valid=valid)
-        return self.norm(x), cache
+            x, cache = block.decode(x, rope, parts, cache, i)
+        x, shapes = head_tokens(x, parts)
+        return self.norm(x), shapes, cache
 
 
 class AfmoeForCausalLM(Layer):
     """Causal LM over :class:`AfmoeModel`; the serving engine's contract
-    is ``config`` + ``decode_step`` over the stacked cache."""
+    is ``config`` + ``decode_parts`` over the stacked cache."""
 
     def __init__(self, config: AfmoeConfig):
         super().__init__()
@@ -410,17 +428,23 @@ class AfmoeForCausalLM(Layer):
     def forward(self, input_ids, position_ids=None):
         return self.logits(self.model(input_ids, position_ids))
 
+    def decode_parts(self, parts, cache):
+        """([logits a part], cache): ONE pass of the weights over the
+        tokens of every part, as ``LlamaForCausalLM.decode_parts``: one
+        routing, one sort and three grouped products an expert layer over
+        all of them.  A part's ``valid`` marks its real tokens; the routed
+        experts leave padding out (``HeldExpertsMoE.forward``)."""
+        hidden, shapes, cache = self.model.decode(parts, cache)
+        with jax.named_scope("lm_head"):
+            return split_tokens(self.logits(hidden), shapes), cache
+
     def decode_step(self, input_ids, cache, pos, block_tables=None,
                     valid=None):
         """(logits, cache): one cache-carrying decode step, as
-        ``LlamaForCausalLM.decode_step``.  ``valid`` (bool, shaped as
-        ``input_ids``; None: all) marks the real tokens; the routed
-        experts leave padding out (``HeldExpertsMoE.forward``)."""
-        hidden, cache = self.model.decode(input_ids, cache, pos,
-                                          block_tables=block_tables,
-                                          valid=valid)
-        with jax.named_scope("lm_head"):
-            return self.logits(hidden), cache
+        ``LlamaForCausalLM.decode_step``: the pass over one part."""
+        (logits,), cache = self.decode_parts(
+            [DecodePart(input_ids, pos, block_tables, valid)], cache)
+        return logits, cache
 
     def generate(self, input_ids, max_new_tokens: int = 32, **kw):
         from .generation import greedy_generate

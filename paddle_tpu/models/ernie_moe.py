@@ -31,6 +31,7 @@ from ..nn.layer import Layer, LayerList
 from ..ops.rope import build_rope_cache
 from .llama import (LlamaAttention, LlamaConfig, LlamaMLP, RMSNorm,
                     _batch_spec, causal_lm_loss)
+from .parts import DecodePart
 
 __all__ = ["ErnieMoEConfig", "ErnieMoEModel", "ErnieMoEForCausalLM",
            "tiny_ernie_moe_config", "ernie45_moe_config"]
@@ -133,9 +134,9 @@ class ErnieMoEDecoderLayer(Layer):
             return h + moe_out, aux
         return h + self.mlp(y), jnp.zeros((), jnp.float32)
 
-    def decode(self, x, rope_cache, pos, cache, idx: int):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         a, cache = self.self_attn.decode(
-            self.input_layernorm(x), rope_cache, pos, cache, idx)
+            self.input_layernorm(x), rope_cache, parts, cache, idx)
         h = x + a
         out, _ = self._ffn(h, self.post_attention_layernorm(h))
         return out, cache
@@ -187,8 +188,9 @@ class ErnieMoEModel(Layer):
         # never rematerialises the full table per device (MULTICHIP_r02)
         x = constrain(x, ("dp", "sharding"), None, None)
         rope = (self.rope_cos, self.rope_sin)
+        parts = [DecodePart(input_ids, pos)]
         for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, pos, cache, i)
+            x, cache = block.decode(x, rope, parts, cache, i)
         return self.norm(x), cache
 
 

@@ -56,7 +56,9 @@ from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
 from .afmoe import held_experts_kernel_specs
-from .llama import LlamaMLP, paged_kv_write
+from .llama import LlamaMLP, paged_kv_write, part_site
+from .parts import (DecodePart, head_tokens, join_tokens, join_valid,
+                    part_by_part, slot_rows, slot_rows_back, split_tokens)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2ShortConv", "Lfm2Attention",
            "Lfm2MoeForCausalLM", "tiny_lfm2_config"]
@@ -197,28 +199,39 @@ class Lfm2ShortConv(Layer):
             y = self._filter(ext, x.shape[1]).astype(x.dtype)
             return matmul(c * y, self.out_proj)
 
-    def decode(self, x, state, pos, valid=None):
-        """x (B, s, H) at per-row (or one scalar) position ``pos`` against
-        ``state`` (B, L-1, H), the rows' last gated inputs.  Returns (out,
-        state as of each row's last VALID token): ``valid`` (bool (B, s), a
-        prefix of each row; None: all) marks the real tokens, a row without
-        one keeps its state, and a row at position 0 starts from zeros."""
-        b, s, _ = x.shape
+    def decode(self, x, parts, state):
+        """The tokens of ``parts`` (:mod:`~paddle_tpu.models.parts`; x
+        (B, s, H), or all parts' tokens (T, 1, H)) against ``state``
+        (rows, L-1, H), every row's last gated inputs.  The two
+        projections run once over all tokens; each part filters its own
+        tokens behind the state of ITS rows (``slots``; None: all) at ITS
+        per-row (or one scalar) position.  Returns (out, state): a part's
+        rows as of each row's last VALID token — ``valid`` (bool (B, s), a
+        prefix of each row; None: all) marks the real tokens, a row
+        without one keeps its state, and a row at position 0 starts from
+        zeros."""
         keep = self.taps - 1
-        with jax.named_scope("conv"):
-            c, u = self._gated(x)
-            fresh = jnp.broadcast_to(jnp.asarray(pos) == 0, (b,))
-            prev = jnp.where(fresh[:, None, None], 0, state).astype(u.dtype)
+
+        def filtered(_, p, state, u):
+            b, s, _ = u.shape
+            rows = slot_rows(state, p.slots, 0)
+            fresh = jnp.broadcast_to(jnp.asarray(p.pos) == 0, (b,))
+            prev = jnp.where(fresh[:, None, None], 0, rows).astype(u.dtype)
             ext = jnp.concatenate([prev, u], axis=1)
             y = self._filter(ext, s).astype(x.dtype)
-            out = matmul(c * y, self.out_proj)
-            if valid is None:
-                return out, ext[:, s:].astype(state.dtype)
-            n = jnp.asarray(valid).sum(axis=1, dtype=jnp.int32)      # (B,)
-            last = jax.vmap(lambda e, i: jax.lax.dynamic_slice_in_dim(
-                e, i, keep, axis=0))(ext, n)
-            return out, jnp.where((n > 0)[:, None, None],
-                                  last.astype(state.dtype), state)
+            if p.valid is None:
+                rows = ext[:, s:].astype(state.dtype)
+            else:
+                n = jnp.asarray(p.valid).sum(axis=1, dtype=jnp.int32)  # (B,)
+                last = jax.vmap(lambda e, i: jax.lax.dynamic_slice_in_dim(
+                    e, i, keep, axis=0))(ext, n)
+                rows = jnp.where((n > 0)[:, None, None],
+                                 last.astype(state.dtype), rows)
+            return y, slot_rows_back(state, rows, p.slots, 0)
+        with jax.named_scope("conv"):
+            c, u = self._gated(x)
+            y, state = part_by_part(parts, (u,), state, filtered)
+            return matmul(c * y, self.out_proj), state
 
 
 class Lfm2Attention(Layer):
@@ -244,7 +257,9 @@ class Lfm2Attention(Layer):
         self.q_layernorm = RMSNorm(hd, epsilon=c.norm_eps, dtype=c.dtype)
         self.k_layernorm = RMSNorm(hd, epsilon=c.norm_eps, dtype=c.dtype)
 
-    def _qkv(self, x, rope_cache, position_ids):
+    def _proj(self, x):
+        """q, k (normed) and v of every token, split into heads:
+        token-wise."""
         c = self.config
         b, s, _ = x.shape
         q = matmul(x, self.q_proj).reshape(b, s, c.num_attention_heads,
@@ -253,9 +268,11 @@ class Lfm2Attention(Layer):
                                            c.head_dim)
         v = matmul(x, self.v_proj).reshape(b, s, c.num_key_value_heads,
                                            c.head_dim)
-        q, k = fused_rope(self.q_layernorm(q), self.k_layernorm(k),
-                          *rope_cache, position_ids)
-        return q, k, v
+        return self.q_layernorm(q), self.k_layernorm(k), v
+
+    def _qkv(self, x, rope_cache, position_ids):
+        q, k, v = self._proj(x)
+        return (*fused_rope(q, k, *rope_cache, position_ids), v)
 
     def forward(self, x, rope_cache, position_ids=None):
         with jax.named_scope("attn.global"):
@@ -263,45 +280,53 @@ class Lfm2Attention(Layer):
             out = flash_attention(q, k, v, causal=True)
             return matmul(out.reshape(*x.shape[:2], -1), self.out_proj)
 
-    def decode(self, x, rope_cache, pos, cache, idx: int, block_tables=None):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         """Decode over the attention layers' stacked cache, as
-        ``AfmoeAttention.decode``: with ``block_tables`` the paged pool
-        (per-row ``pos``), without them the contiguous cache at a scalar
+        ``AfmoeAttention.decode``: the projections once over the tokens
+        of all ``parts``; each part's RoPE, write and read at its own
+        positions — with ``block_tables`` through the paged pool (per-row
+        ``pos``), without them over the contiguous cache at a scalar
         ``pos`` (``generate()``).  Returns (out, cache)."""
+        with jax.named_scope("attn.global"):
+            sites = [self._site(p, rope_cache) for p in parts]
+            out, cache = part_by_part(
+                parts, self._proj(x), cache,
+                lambda i, p, cache, q, k, v: self._attend(
+                    q, k, v, rope_cache, p, sites[i], cache, idx))
+            return matmul(out.reshape(*out.shape[:2], -1),
+                          self.out_proj), cache
+
+    @staticmethod
+    def _site(part, rope_cache):
+        if part.block_tables is None and getattr(part.pos, "ndim", 0) != 0:
+            raise NotImplementedError(
+                "Lfm2Attention.decode: per-row positions need the "
+                "paged pool (block_tables); the contiguous cache is "
+                "decoded at one scalar position")
+        return part_site(part, rope_cache)
+
+    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
+        """One part's RoPE, write and read against KV layer ``idx``."""
         from ..ops.attention import (cached_decode_attention,
                                      paged_decode_attention)
-        b, s, _ = x.shape
-        with jax.named_scope("attn.global"):
-            if block_tables is not None:
-                if getattr(pos, "ndim", 0) != 1:
-                    pos = jnp.full((b,), pos, jnp.int32)
-                position_ids = pos[:, None] + jnp.arange(s)[None, :]
-                # prompt-pad positions may run past the RoPE table
-                rope_ids = jnp.minimum(position_ids,
-                                       rope_cache[0].shape[0] - 1)
-                q, k, v = self._qkv(x, rope_cache, rope_ids)
-                cache, kvp, _ = paged_kv_write(cache, idx, k, v,
-                                               position_ids, block_tables)
-                out = paged_decode_attention(q, kvp, idx, pos, block_tables)
-                return matmul(out.reshape(b, s, -1), self.out_proj), cache
-            if getattr(pos, "ndim", 0) != 0:
-                raise NotImplementedError(
-                    "Lfm2Attention.decode: per-row positions need the "
-                    "paged pool (block_tables); the contiguous cache is "
-                    "decoded at one scalar position")
-            q, k, v = self._qkv(x, rope_cache, pos + jnp.arange(s)[None, :])
-            cache = jax.lax.dynamic_update_slice(
-                cache, k.astype(cache.dtype)[None, None],
-                (idx, 0, 0, pos, 0, 0))
-            cache = jax.lax.dynamic_update_slice(
-                cache, v.astype(cache.dtype)[None, None],
-                (idx, 1, 0, pos, 0, 0))
-            if isinstance(pos, int) and pos == 0 and s > 1:
-                out = flash_attention(q, k, v, causal=True)
-            else:
-                out = cached_decode_attention(q, cache[idx, 0],
-                                              cache[idx, 1], pos)
-            return matmul(out.reshape(b, s, -1), self.out_proj), cache
+        pos, position_ids, rope_ids = site
+        s = q.shape[1]
+        q, k = fused_rope(q, k, *rope_cache, rope_ids)
+        if part.block_tables is not None:
+            cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
+                                           part.block_tables)
+            return paged_decode_attention(q, kvp, idx, pos,
+                                          part.block_tables), cache
+        cache = jax.lax.dynamic_update_slice(
+            cache, k.astype(cache.dtype)[None, None],
+            (idx, 0, 0, pos, 0, 0))
+        cache = jax.lax.dynamic_update_slice(
+            cache, v.astype(cache.dtype)[None, None],
+            (idx, 1, 0, pos, 0, 0))
+        if isinstance(pos, int) and pos == 0 and s > 1:
+            return flash_attention(q, k, v, causal=True), cache
+        return cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
+                                       pos), cache
 
 
 class Lfm2MoE(Layer):
@@ -358,23 +383,21 @@ class Lfm2DecoderLayer(Layer):
               if self.kind == FULL else self.conv(y))
         return self._ffn(x + op)
 
-    def decode(self, x, rope_cache, pos, cache, block_tables=None,
-               valid=None):
+    def decode(self, x, rope_cache, parts, cache):
         """One layer against the two-leaf state ``cache``: an attention
         layer writes and reads its layer of ``"attn"``, a convolution layer
-        advances its layer of ``"conv"``."""
+        advances its layer of ``"conv"`` (each part its own rows)."""
         y, i = self.operator_norm(x), self.state_index
         if self.kind == FULL:
             with jax.named_scope("attn"):
                 op, attn = self.self_attn.decode(
-                    y, rope_cache, pos, cache["attn"], i,
-                    block_tables=block_tables)
+                    y, rope_cache, parts, cache["attn"], i)
             cache = dict(cache, attn=attn)
         else:
-            op, row = self.conv.decode(y, cache["conv"][i], pos, valid)
-            cache = dict(cache, conv=cache["conv"].at[i].set(row))
+            op, rows = self.conv.decode(y, parts, cache["conv"][i])
+            cache = dict(cache, conv=cache["conv"].at[i].set(rows))
         with jax.named_scope("ffn"):
-            return self._ffn(x + op, valid), cache
+            return self._ffn(x + op, join_valid(parts)), cache
 
 
 class Lfm2MoeModel(Layer):
@@ -402,21 +425,26 @@ class Lfm2MoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.embedding_norm(x)
 
-    def decode(self, input_ids, cache, pos, block_tables=None, valid=None):
-        """Cache-carrying decode pass over the two-leaf state.  Returns
-        (hidden, cache)."""
-        x = constrain(vocab_parallel_lookup(self.embed_tokens, input_ids),
-                      ("dp", "sharding"), None, None)
+    def decode(self, parts, cache):
+        """Cache-carrying decode pass of ``parts``
+        (:mod:`~paddle_tpu.models.parts`) over the two-leaf state.
+        Returns (the normed hidden states the head is taken of, their
+        per-part (rows, positions), cache)."""
+        x = constrain(
+            vocab_parallel_lookup(
+                self.embed_tokens,
+                join_tokens([p.input_ids for p in parts])),
+            ("dp", "sharding"), None, None)
         rope = (self.rope_cos, self.rope_sin)
         for block in self.layers:
-            x, cache = block.decode(x, rope, pos, cache,
-                                    block_tables=block_tables, valid=valid)
-        return self.embedding_norm(x), cache
+            x, cache = block.decode(x, rope, parts, cache)
+        x, shapes = head_tokens(x, parts)
+        return self.embedding_norm(x), shapes, cache
 
 
 class Lfm2MoeForCausalLM(Layer):
     """Causal LM over :class:`Lfm2MoeModel`; the serving engine's contract
-    is ``config`` + ``decode_step`` + the declarations at the end."""
+    is ``config`` + ``decode_parts`` + the declarations at the end."""
 
     def __init__(self, config: Lfm2MoeConfig):
         super().__init__()
@@ -436,18 +464,24 @@ class Lfm2MoeForCausalLM(Layer):
     def forward(self, input_ids, position_ids=None):
         return self.logits(self.model(input_ids, position_ids))
 
+    def decode_parts(self, parts, cache):
+        """([logits a part], cache): ONE pass of the weights over the
+        tokens of every part, as ``LlamaForCausalLM.decode_parts``, over
+        ``cache = {"attn", "conv"}``.  A part's ``valid`` marks its real
+        tokens: the routed experts leave padding out and the convolution
+        state advances by the real tokens only; its ``slots`` are its rows
+        of ``"conv"``."""
+        hidden, shapes, cache = self.model.decode(parts, cache)
+        with jax.named_scope("lm_head"):
+            return split_tokens(self.logits(hidden), shapes), cache
+
     def decode_step(self, input_ids, cache, pos, block_tables=None,
                     valid=None):
-        """(logits, cache): one cache-carrying decode step over ``cache =
-        {"attn", "conv"}``.  ``valid`` (bool, shaped as ``input_ids``, a
-        prefix of each row; None: all) marks the real tokens: the routed
-        experts leave padding out and the convolution state advances by the
-        real tokens only."""
-        hidden, cache = self.model.decode(input_ids, cache, pos,
-                                          block_tables=block_tables,
-                                          valid=valid)
-        with jax.named_scope("lm_head"):
-            return self.logits(hidden), cache
+        """(logits, cache): one cache-carrying decode step: the pass over
+        one part that addresses every row of ``"conv"``."""
+        (logits,), cache = self.decode_parts(
+            [DecodePart(input_ids, pos, block_tables, valid)], cache)
+        return logits, cache
 
     def _conv_state(self, rows: int):
         c = self.config

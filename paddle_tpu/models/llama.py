@@ -38,6 +38,8 @@ from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer
 from ..ops import build_rope_cache, flash_attention, fused_rope
+from .parts import (DecodePart, head_tokens, join_tokens, part_by_part,
+                    split_tokens)
 
 __all__ = ["LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
            "LlamaModel", "LlamaForCausalLM", "llama3_8b_config",
@@ -232,6 +234,46 @@ def paged_kv_write(cache, idx: int, k, v, position_ids, block_tables):
     return ({"kv": kvp, "scale": sc} if quantized else kvp), kvp, sc
 
 
+def part_site(part, rope_cache):
+    """Where one part's tokens sit: (pos — per row over the paged pool —,
+    (B, s) position ids, the ids RoPE rotates by).  Shared by every
+    attention layer that decodes over the stacked caches."""
+    b, s = part.input_ids.shape
+    pos = part.pos
+    paged = part.block_tables is not None
+    per_row = getattr(pos, "ndim", 0) == 1
+    if paged and not per_row:
+        pos = jnp.full((b,), pos, jnp.int32)
+        per_row = True
+    if per_row:
+        position_ids = pos[:, None] + jnp.arange(s)[None, :]      # (B, s)
+    else:
+        position_ids = pos + jnp.arange(s)[None, :]
+    if paged:
+        # prompt-pad positions may run past the RoPE table; clamp for
+        # the rotation only (pad rows' outputs are never consumed)
+        rope_ids = jnp.minimum(position_ids, rope_cache[0].shape[0] - 1)
+    else:
+        rope_ids = position_ids
+    return pos, position_ids, rope_ids
+
+
+def _layer_slots(leaf, idx: int, slots):
+    """Layer ``idx`` of a contiguous-cache leaf (layer axis 0, slot axis 2)
+    cut to a part's ``slots``: a one-layer cache of those rows."""
+    first, n = slots
+    z = jnp.int32(0)
+    return jax.lax.dynamic_slice(
+        leaf, (jnp.int32(idx), z, first) + (z,) * (leaf.ndim - 3),
+        (1, leaf.shape[1], n) + leaf.shape[3:])
+
+
+def _layer_slots_back(leaf, rows, idx: int, slots):
+    z = jnp.int32(0)
+    return jax.lax.dynamic_update_slice(
+        leaf, rows, (jnp.int32(idx), z, slots[0]) + (z,) * (leaf.ndim - 3))
+
+
 class LlamaAttention(Layer):
     """GQA attention with RoPE and flash attention.
 
@@ -258,7 +300,8 @@ class LlamaAttention(Layer):
             (nh * hd, c.hidden_size), dtype=c.dtype, initializer=init,
             sharding=P("mp", "sharding"), attr_name="o_proj")
 
-    def _qkv(self, x, rope_cache, position_ids=None):
+    def _proj(self, x):
+        """q, k, v of every token of ``x``, split into heads: token-wise."""
         c = self.config
         b, s, _ = x.shape
         q = matmul(x, self.q_proj).reshape(b, s, c.num_attention_heads,
@@ -267,6 +310,10 @@ class LlamaAttention(Layer):
                                            c.head_dim)
         v = matmul(x, self.v_proj).reshape(b, s, c.num_key_value_heads,
                                            c.head_dim)
+        return q, k, v
+
+    def _qkv(self, x, rope_cache, position_ids=None):
+        q, k, v = self._proj(x)
         cos, sin = rope_cache
         q, k = fused_rope(q, k, cos, sin, position_ids)
         return q, k, v
@@ -296,8 +343,7 @@ class LlamaAttention(Layer):
                                   segment_ids=segment_ids)
         return matmul(out.reshape(b, s, -1), self.o_proj)
 
-    def decode(self, x, rope_cache, pos, cache, idx: int,
-               block_tables=None):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         """Incremental decode against the STACKED cache — contiguous
         (L, 2, B, max_len, Hkv, D), or the paged pool (last paragraph):
         write this chunk's K/V in place at ``(idx, ·, ·, pos)`` and attend
@@ -359,37 +405,53 @@ class LlamaAttention(Layer):
         live blocks it reads.  Paged decode always uses per-row positions
         (a scalar is broadcast).
 
-        x: (B, s, H*D).  Returns (out, cache).
+        ``parts`` (:mod:`~paddle_tpu.models.parts`): ``x`` holds the
+        tokens of every part.  The projections and the output projection
+        run once over all of them; each part then takes its own tokens'
+        q, k, v through RoPE at ITS positions, the write and the read
+        described above through ITS table (or over its ``slots`` of the
+        contiguous cache: one layer's rows are cut out, written, read and
+        put back), in list order on the one cache, inside its ``scope``.
+
+        x: (B, s, H*D), or all parts' tokens (T, 1, H*D).  Returns
+        (out, cache).
         """
+        sites = [part_site(p, rope_cache) for p in parts]
+
+        def attend(i, p, cache, q, k, v):
+            if p.block_tables is not None or p.slots is None:
+                return self._attend(q, k, v, rope_cache, p, sites[i], cache,
+                                    idx)
+            # its slots of the contiguous cache: a one-layer cache of them
+            rows = jax.tree_util.tree_map(
+                lambda a: _layer_slots(a, idx, p.slots), cache)
+            out, rows = self._attend(q, k, v, rope_cache, p, sites[i], rows,
+                                     0)
+            return out, jax.tree_util.tree_map(
+                lambda a, r: _layer_slots_back(a, r, idx, p.slots), cache,
+                rows)
+        out, cache = part_by_part(parts, self._proj(x), cache, attend)
+        return matmul(out.reshape(*out.shape[:2], -1), self.o_proj), cache
+
+    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
+        """One part's write and read: its (B, s, heads, D) projections
+        against layer ``idx`` of ``cache``.  Returns ((B, s, H, D), cache)."""
         from ..ops.attention import (cached_decode_attention,
                                      paged_decode_attention)
 
-        b, s, _ = x.shape
+        pos, position_ids, rope_ids = site
+        b, s = q.shape[:2]
         quantized = isinstance(cache, dict)
         kvp = cache["kv"] if quantized else cache
-        paged = block_tables is not None
         per_row = getattr(pos, "ndim", 0) == 1
-        if paged and not per_row:
-            pos = jnp.full((b,), pos, jnp.int32)
-            per_row = True
-        if per_row:
-            position_ids = pos[:, None] + jnp.arange(s)[None, :]  # (B, s)
-        else:
-            position_ids = pos + jnp.arange(s)[None, :]
-        if paged:
-            # prompt-pad positions may run past the RoPE table; clamp for
-            # the rotation only (pad rows' outputs are never consumed)
-            rope_ids = jnp.minimum(position_ids, rope_cache[0].shape[0] - 1)
-        else:
-            rope_ids = position_ids
-        q, k, v = self._qkv(x, rope_cache, rope_ids)
-        if paged:
+        q, k = fused_rope(q, k, *rope_cache, rope_ids)
+        if part.block_tables is not None:
             q = constrain(q, ("dp", "sharding"), None, "mp", None)
             cache, kvp, sc = paged_kv_write(cache, idx, k, v, position_ids,
-                                            block_tables)
-            out = paged_decode_attention(q, kvp, idx, pos, block_tables,
-                                         pool_scale=sc)
-            return matmul(out.reshape(b, s, -1), self.o_proj), cache
+                                            part.block_tables)
+            return paged_decode_attention(q, kvp, idx, pos,
+                                          part.block_tables,
+                                          pool_scale=sc), cache
         if quantized:
             sc = cache["scale"]
             kvp, sc = _quantized_contiguous_write(kvp, sc, idx, 0, k,
@@ -411,7 +473,7 @@ class LlamaAttention(Layer):
                 out = cached_decode_attention(
                     q, kvp[idx, 0], kvp[idx, 1], pos,
                     k_scale=sc[idx, 0], v_scale=sc[idx, 1])
-            return matmul(out.reshape(b, s, -1), self.o_proj), cache
+            return out, cache
         if per_row:
             rows = jnp.arange(b)[:, None]                          # (B, 1)
             cache = cache.at[idx, 0, rows, position_ids].set(
@@ -435,7 +497,7 @@ class LlamaAttention(Layer):
         else:
             out = cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
                                           pos)
-        return matmul(out.reshape(b, s, -1), self.o_proj), cache
+        return out, cache
 
 
 class LlamaMLP(Layer):
@@ -482,14 +544,12 @@ class LlamaDecoderLayer(Layer):
         x = x + self.mlp(self.post_attention_layernorm(x))
         return constrain(x, *_batch_spec(x.ndim))
 
-    def decode(self, x, rope_cache, pos, cache, idx: int,
-               block_tables=None):
+    def decode(self, x, rope_cache, parts, cache, idx: int):
         # named for the device trace (the scopes reach every op's
         # ``op_name``; the engine's program_part names the kernel)
         with jax.named_scope("attn"):
             a, cache = self.self_attn.decode(
-                self.input_layernorm(x), rope_cache, pos, cache, idx,
-                block_tables=block_tables)
+                self.input_layernorm(x), rope_cache, parts, cache, idx)
             x = x + a
         with jax.named_scope("ffn"):
             x = x + self.mlp(self.post_attention_layernorm(x))
@@ -535,15 +595,18 @@ class LlamaModel(Layer):
                 x = block(x, rope, position_ids, segment_ids)
         return self.norm(x)
 
-    def decode(self, input_ids, cache, pos, block_tables=None):
-        """Cache-carrying decode pass.  ``cache``: the stacked
+    def decode(self, parts, cache):
+        """Cache-carrying decode pass over ``parts``
+        (:mod:`~paddle_tpu.models.parts`).  ``cache``: the stacked
         (L, 2, B, max_len, Hkv, D) array from
-        :func:`paddle_tpu.models.generation.init_kv_cache` — or, with
-        ``block_tables``, the pooled paged cache from
-        :func:`paddle_tpu.serving.kv_cache.init_paged_kv_cache`; ``pos``
-        is the number of tokens already in the cache.  Returns
-        (hidden, cache)."""
-        x = vocab_parallel_lookup(self.embed_tokens, input_ids)
+        :func:`paddle_tpu.models.generation.init_kv_cache` — or, for parts
+        with ``block_tables``, the pooled paged cache from
+        :func:`paddle_tpu.serving.kv_cache.init_paged_kv_cache`; a part's
+        ``pos`` is the number of tokens already in the cache.  Returns
+        (the normed hidden states the head is taken of, their per-part
+        (rows, positions), cache)."""
+        x = vocab_parallel_lookup(
+            self.embed_tokens, join_tokens([p.input_ids for p in parts]))
         # constrain the gathered activations (batch over dp×sharding) so
         # the SPMD partitioner shards the lookup output instead of falling
         # back to rematerialising the full embedding table per device
@@ -551,9 +614,9 @@ class LlamaModel(Layer):
         x = constrain(x, ("dp", "sharding"), None, None)
         rope = (self.rope_cos, self.rope_sin)
         for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, pos, cache, i,
-                                    block_tables=block_tables)
-        return self.norm(x), cache
+            x, cache = block.decode(x, rope, parts, cache, i)
+        x, shapes = head_tokens(x, parts)
+        return self.norm(x), shapes, cache
 
 
 def mask_boundary_labels(labels, segment_ids):
@@ -610,16 +673,24 @@ class LlamaForCausalLM(Layer):
         return causal_lm_loss(
             self.forward(input_ids, position_ids, segment_ids), labels)
 
+    def decode_parts(self, parts, cache):
+        """([logits a part], cache): ONE pass of the weights over the
+        tokens of every :class:`~paddle_tpu.models.parts.DecodePart`, each
+        addressing its own piece of ``cache``; a part's logits are
+        (rows, positions, vocab), or (rows, 1, vocab) at its ``last``."""
+        hidden, shapes, cache = self.model.decode(parts, cache)
+        with jax.named_scope("lm_head"):
+            return split_tokens(self.logits(hidden), shapes), cache
+
     def decode_step(self, input_ids, cache, pos, block_tables=None):
         """(logits, cache): one cache-carrying decode step (prefill when
         ``input_ids`` is the whole prompt at pos=0, incremental when it is
-        the last token).  See models/generation.py for the cache layout,
-        serving/kv_cache.py for the paged layout ``block_tables``
-        selects."""
-        hidden, cache = self.model.decode(input_ids, cache, pos,
-                                          block_tables=block_tables)
-        with jax.named_scope("lm_head"):
-            return self.logits(hidden), cache
+        the last token): the pass over one part.  See models/generation.py
+        for the cache layout, serving/kv_cache.py for the paged layout
+        ``block_tables`` selects."""
+        (logits,), cache = self.decode_parts(
+            [DecodePart(input_ids, pos, block_tables)], cache)
+        return logits, cache
 
     def generate(self, input_ids, max_new_tokens: int = 32, **kw):
         """Greedy/sampled generation with the pre-allocated KV cache

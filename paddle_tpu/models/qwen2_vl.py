@@ -37,6 +37,7 @@ from ..ops import build_rope_cache, flash_attention
 from ..tensor.math import matmul
 from .llama import (LlamaConfig, LlamaDecoderLayer, _batch_spec,
                     causal_lm_loss)
+from .parts import DecodePart
 
 __all__ = ["Qwen2VLConfig", "VisionTower", "Qwen2VLForConditionalGeneration",
            "tiny_qwen2_vl_config"]
@@ -274,8 +275,9 @@ class Qwen2VLForConditionalGeneration(Layer):
         # never rematerialises the full table per device (MULTICHIP_r02)
         x = constrain(x, ("dp", "sharding"), None, None)
         rope = (self.rope_cos, self.rope_sin)
+        parts = [DecodePart(input_ids, pos)]
         for i, blk in enumerate(self.layers):
-            x, cache = blk.decode(x, rope, pos, cache, i)
+            x, cache = blk.decode(x, rope, parts, cache, i)
             if i in self._cross_at:
                 x = self._cross_layer(i)(x, vision)
         return matmul(self.norm(x), self.lm_head), cache

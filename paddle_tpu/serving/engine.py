@@ -140,8 +140,11 @@ token.  Spec mode does that without a second model:
 
 **One path** (PR 29).  The three switches select parts of one composed
 path, not copies of it: ONE step program (``_step_program``: a rows part —
-plain or verify — then a chunk part when chunked, over block tables or
-slot rows; where each kind of idle write lands is argued there, once) and
+plain or verify — and a chunk part when chunked, over block tables or
+slot rows, in ONE pass of the model's weights (``decode_parts``, since PR
+34: every token-wise operation once over both parts' tokens, attention and
+per-slot state a part at a time); where each kind of idle write lands is
+argued there, once) and
 one prefill program for the wave engines, each with its signature written
 down once as an operand table (``_operand_tables``) that the uploads, the
 lint's arguments and the mesh shardings all read; ONE tick
@@ -173,6 +176,7 @@ from ..distributed import moe as _moe
 from ..models.generation import (SAMPLE_PATHS, _place_on_mesh,
                                  accept_draft_tokens, decode_mesh_specs,
                                  init_kv_cache, sample_path, sample_tokens)
+from ..models.parts import DecodePart
 from ..nn.layer import bind_params
 from ..ops import _dispatch as _disp
 from .drafter import DraftModelDrafter, NgramDrafter
@@ -218,20 +222,6 @@ _ENGINE_IDS = itertools.count()
 # one compiled prefill program per power-of-two bucket (plus the paged
 # suffix buckets) — generous static ceiling for the prefill trace budget
 _PREFILL_TRACE_BUDGET = 16
-
-
-def _slot_row(cache, cslot):
-    """One slot's row of the contiguous cache — batch is axis 2 in every
-    leaf, for the plain array and the int8 {kv, scale} pytree alike."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, cslot, 1, axis=2), cache)
-
-
-def _slot_row_update(cache, row, cslot):
-    z = jnp.int32(0)
-    return jax.tree_util.tree_map(
-        lambda a, r: jax.lax.dynamic_update_slice(
-            a, r, (z, z, cslot) + (z,) * (a.ndim - 3)), cache, row)
 
 
 class _Operand(NamedTuple):
@@ -350,7 +340,7 @@ class _SwapResume:
 
 class ServingEngine:
     """Continuous-batching serving over a causal LM with the stacked KV
-    cache (``decode_step`` + ``init_kv_cache`` layout; plain or
+    cache (``decode_parts`` + ``init_kv_cache`` layout; plain or
     ``quantize_for_decode``-wrapped models both work).
 
     ``submit()`` enqueues, ``step()`` runs one scheduler tick (admit →
@@ -513,7 +503,16 @@ class ServingEngine:
         self.mesh = self._resolve_mesh(mesh)
         # quantized-decode hooks, exactly as models/generation.py binds
         self._bind = getattr(model, "unwrapped", model)
-        # THE CONTRACT for a model that is not llama: it says itself which
+        # THE CONTRACT.  Every model: ``decode_parts(parts, cache)``, ONE
+        # pass of its weights over the tokens of every part
+        # (``models.parts.DecodePart``: a run of tokens — ids, positions —
+        # with its own way into the per-request state: a block table or its
+        # slot rows of the cache, the mask of its real tokens, its rows of
+        # a per-slot state, the position its logits are wanted at, the
+        # scope its kernels are named by) — everything token-wise once over
+        # all of them, what addresses the state a part at a time in list
+        # order; ``decode_step`` is the pass over one part.
+        # A model that is not llama also says itself which
         # leaves of its serving cache are fixed-size per slot beside the
         # paged pool (``slot_state``: their names; slot axis 1) and makes
         # the whole cache for N slots (``init_serving_cache``), which of the
@@ -1405,44 +1404,28 @@ class ServingEngine:
         return step + [key], wave + knobs(nb, "", "temps", "topk",
                                           "topp") + [key]
 
-    def _experts(self, valid):
-        """For a model with expert layers: the trace-time collector of
-        their load (``distributed.moe.expert_load``); for one with expert
-        layers or per-slot state: the ``valid=`` its ``decode_step`` takes
-        (``valid`` builds the mask of the real tokens: padding is routed
-        to no expert and advances no state).  For any other model an empty
-        collector and no argument, so that its step programs are traced as
-        they ever were."""
-        return ((_moe.expert_load() if self._expert_layers
-                 else contextlib.nullcontext(())),
-                {"valid": valid()}
-                if self._expert_layers or self._slot_leaves else {})
-
-    def _state_rows(self, cache, start, n: int):
-        """``cache`` as one program part sees it: the paged leaf whole, the
-        per-slot leaves cut to the ``n`` rows from ``start`` that the part
-        addresses (slot axis 1).  No-op for a model without such leaves."""
-        if not self._slot_leaves:
-            return cache
-        return {k: jax.lax.dynamic_slice_in_dim(v, start, n, axis=1)
-                if k in self._slot_leaves else v for k, v in cache.items()}
-
-    def _state_rows_back(self, cache, part, start):
-        """Put a part's view back: its rows into the per-slot leaves, the
-        paged leaf as the part left it."""
-        if not self._slot_leaves:
-            return part
-        return {k: jax.lax.dynamic_update_slice_in_dim(cache[k], v, start,
-                                                       axis=1)
-                if k in self._slot_leaves else v for k, v in part.items()}
-
     def _step_program(self):
         """The Python body of THE step program, composed for this engine's
-        layout and compiled exactly once: a rows part, then a chunk part
-        when ``chunked``, over the cache addressed through block tables when
-        ``paged`` and by slot row when not.  Named by its layout,
+        layout and compiled exactly once: ONE pass of the model's weights
+        (``decode_parts``) over a rows part and, when ``chunked``, a chunk
+        part, over the cache addressed through block tables when ``paged``
+        and by slot row when not.  Named by its layout,
         ``_[spec_][mixed_]step_impl[_paged]``: the device trace's module
         line and the benchmark's readers go by that name.
+
+        A part (``models.parts.DecodePart``) is a run of tokens with its
+        own way into the per-request state: ids, positions, its block
+        table (paged) or its slot rows of the cache (contiguous), the mask
+        of its real tokens for a model that routes experts or keeps
+        per-slot state, its rows of that state, the position its logits
+        are wanted at, and the ``program_part`` its kernels are named by.
+        The model runs everything token-wise — norms, projections, FFNs,
+        routed experts, the head — once over both parts' tokens laid end to
+        end (``num_slots·(k+1) + prefill_chunk`` of them, named
+        ``token_pass``), so a tick streams every weight once; RoPE, the K/V
+        write, the cached-attention read and a per-slot state's update run
+        a part at a time, the rows' before the chunk's on the one cache.
+        A program of one part is that part's ``decode_step``.
 
         Rows part.  Row i holds request state at ``positions[i]``.  Plain:
         every row advances one token.  ``spec``: ``tokens`` is the
@@ -1478,90 +1461,115 @@ class ServingEngine:
         size static) at positions ``cpos..cpos+chunk-1``: paged, through the
         slot's own (1, max_blocks) table row ``cdst`` straight into its
         blocks (the rows part saw that slot as an all-null row); contiguous,
-        over the ``cdst`` cache row pulled out with a dynamic slice and put
-        back.  Pad-tail writes past the prompt land where decode overwrites
-        them before the mask can read them (the wave-prefill padding
-        argument); a chunk-free tick rides the same program with an all-null
-        table, or ``cpos = max_length`` (every write drops, the row
-        round-trips bit-identical).  The sampled chunk token is the
+        over the ``cdst`` cache row, each layer's cut out with a dynamic
+        slice and put back.  Pad-tail writes past the prompt land where
+        decode overwrites them before the mask can read them (the
+        wave-prefill padding argument); a chunk-free tick rides the same
+        program with an all-null table, or ``cpos = max_length`` (every
+        write drops, the row round-trips bit-identical).  Its logits are
+        taken at ``clen - 1`` alone: the sampled chunk token is the
         request's FIRST token when this chunk completes the prompt; the
         host discards it otherwise.  A prefilling slot is inactive until
         its cursor completes, so the two parts never touch the same row.
 
+        Per-slot state (a model's ``slot_state`` leaves): the rows part
+        addresses the slots' rows (the null row stays out), the chunk part
+        the row ``cslot``: the cursor's slot (a table row names blocks, not
+        a slot), the null row on a chunk-free tick.
+
         Returns ``_step_outputs``: a model with expert layers adds their
-        load, (parts, expert layers, held + 1), before the cache."""
-        paged, chunked, spec = self.paged, self.chunked, self.spec
+        load, (1, expert layers, held + 1), before the cache."""
+        chunked, spec = self.chunked, self.spec
         names = [o.name for o in self._step_table]
 
         def step(params, cache, *operands):
             a = dict(zip(names, operands))
             prep = self._prepare(params)
             mask, key = a["slot_mask"], a["key"]
-            knobs = (a["temps"], a["topk"], a["topp"])
-            at = {"block_tables": a["tables"]} if paged else {}
+            parts = self._step_parts(a)
+            tokens = parts[0].input_ids
+            if chunked:
+                whole = _disp.program_part(_STEP, "token_pass")
+            else:
+                # one part: the pass IS the part, named as it ever was
+                whole = parts[0].scope()
+                parts = [parts[0]._replace(scope=contextlib.nullcontext)]
+            with whole, bind_params(self._bind, prep), \
+                    (_moe.expert_load() if self._expert_layers
+                     else contextlib.nullcontext(())) as load:
+                (logits, *clogits), cache = self.model.decode_parts(parts,
+                                                                    cache)
             if spec:
-                collect, real = self._experts(
-                    lambda: mask[:, None] & jnp.concatenate(
-                        [jnp.ones_like(mask)[:, None], a["draft_ok"]], 1))
-                with _disp.program_part(_STEP, "verify_rows"), \
-                        bind_params(self._bind, prep), collect as load, \
-                        _disp.kernel_path_hint("spec_verify"):
-                    logits, cache = self.model.decode_step(
-                        a["tokens"], cache, a["positions"], **at, **real)
                 with jax.named_scope("accept"):
                     out, n_acc = accept_draft_tokens(
-                        logits, a["tokens"][:, 1:], a["draft_ok"], key,
-                        *knobs, pad_token_id=self.pad_token_id,
+                        logits, tokens[:, 1:], a["draft_ok"], key,
+                        a["temps"], a["topk"], a["topp"],
+                        pad_token_id=self.pad_token_id,
                         draft_probs=a["draft_probs"])
                 outs = [jnp.where(mask[:, None], out,
                                   jnp.int32(self.pad_token_id)), n_acc]
             else:
-                collect, real = self._experts(lambda: mask[:, None])
-                with _disp.program_part(_STEP, "decode_rows"), \
-                        bind_params(self._bind, prep), collect as load:
-                    # per-slot state: the slots' rows (the null row stays out)
-                    logits, part = self.model.decode_step(
-                        a["tokens"][:, None],
-                        self._state_rows(cache, 0, self.num_slots),
-                        a["positions"], **at, **real)
-                    cache = self._state_rows_back(cache, part, 0)
                 with jax.named_scope("sample"):
-                    nxt = sample_tokens(logits[:, -1], key, *knobs)
+                    nxt = sample_tokens(logits[:, -1], key, a["temps"],
+                                        a["topk"], a["topp"])
                     outs = [jnp.where(mask, nxt,
                                       jnp.int32(self.pad_token_id))]
-            if not chunked:
-                # a model with routed experts: their load rides out
-                return (*outs, *([jnp.stack(load)[None]] if load else ()),
-                        cache)
-            cids, cpos, clen, cdst = (a[n] for n in
-                                      ("cids", "cpos", "clen", "cdst"))
-            collect, real = self._experts(
-                lambda: (jnp.arange(cids.shape[1]) < clen)[None])
-            with _disp.program_part(_STEP, "prompt_chunk"), \
-                    bind_params(self._bind, prep), collect as cload:
-                if paged:       # the pool IS the cache for both parts
-                    cslot = a.get("cslot")      # per-slot state: its one row
-                    clogits, part = self.model.decode_step(
-                        cids, self._state_rows(cache, cslot, 1), cpos[None],
-                        block_tables=cdst, **real)
-                    cache = self._state_rows_back(cache, part, cslot)
-                else:
-                    row = _slot_row(cache, cdst)
-                    clogits, row = self.model.decode_step(
-                        cids, row, cpos[None], **real)
-                    cache = _slot_row_update(cache, row, cdst)
-            with jax.named_scope("sample_chunk"):
-                outs.append(sample_tokens(
-                    clogits[0, clen - 1][None], jax.random.fold_in(key, 1),
-                    a["ctemps"], a["ctopk"], a["ctopp"])[0])
-            if load:    # part 0: the rows' load, part 1: the chunk's
-                outs.append(jnp.stack([jnp.stack(load), jnp.stack(cload)]))
-            return (*outs, cache)
+            if chunked:
+                with jax.named_scope("sample_chunk"):
+                    outs.append(sample_tokens(
+                        clogits[0][:, 0], jax.random.fold_in(key, 1),
+                        a["ctemps"], a["ctopk"], a["ctopp"])[0])
+            # a model with routed experts: their load rides out
+            return (*outs, *([jnp.stack(load)[None]] if load else ()), cache)
 
         step.__name__ = step.__qualname__ = (
             "_" + "spec_" * spec + "mixed_" * chunked + "step_impl"
-            + "_paged" * paged)
+            + "_paged" * self.paged)
         return step
+
+    @property
+    def _pass_rows(self) -> int:
+        """The token rows of the step program's one pass of the weights:
+        its parts' rows x positions, padding and all."""
+        return (self.num_slots * (self.spec_k + 1 if self.spec else 1)
+                + self.prefill_chunk * self.chunked)
+
+    def _step_parts(self, a):
+        """The step program's parts (``_step_program``), from its operands
+        by name: the rows part, then the chunk part when ``chunked``."""
+        spec, paged = self.spec, self.paged
+        part = functools.partial(_disp.program_part, _STEP)
+
+        @contextlib.contextmanager
+        def verify_rows():
+            with part("verify_rows"), _disp.kernel_path_hint("spec_verify"):
+                yield
+        # a model with expert layers or per-slot state is told the real
+        # tokens: padding is routed to no expert and advances no state
+        masked = bool(self._expert_layers or self._slot_leaves)
+        mask = a["slot_mask"]
+        real = None
+        if masked:
+            real = mask[:, None] & jnp.concatenate(
+                [jnp.ones_like(mask)[:, None], a["draft_ok"]],
+                1) if spec else mask[:, None]
+        parts = [DecodePart(
+            a["tokens"] if spec else a["tokens"][:, None], a["positions"],
+            a.get("tables"), valid=real,
+            slots=(0, self.num_slots) if self._slot_leaves else None,
+            scope=(verify_rows if spec
+                   else functools.partial(part, "decode_rows")))]
+        if self.chunked:
+            cids, clen, cdst = a["cids"], a["clen"], a["cdst"]
+            parts.append(DecodePart(
+                cids, a["cpos"][None], cdst if paged else None,
+                valid=((jnp.arange(cids.shape[1]) < clen)[None]
+                       if masked else None),
+                slots=((a["cslot"], 1) if self._slot_leaves
+                       else None if paged else (cdst, 1)),
+                last=clen - 1,
+                scope=functools.partial(part, "prompt_chunk")))
+        return parts
 
     def _prefill_program(self):
         """The Python body of the wave engine's prefill program
@@ -2361,8 +2369,10 @@ class ServingEngine:
             cpos, cslot = pf.cursor, pf.slot
         elif chunked:
             # chunk-free tick, same compiled program: contiguous writes
-            # drop past max_length, paged writes land in the null block
-            clen, cslot, cpos = 1, 0, 0 if paged else self.max_length
+            # drop past max_length, paged writes land in the null block,
+            # and no position is a real token (``clen`` 0: none reaches an
+            # expert or advances a state)
+            cslot, cpos = 0, 0 if paged else self.max_length
         if spec:
             # the draft builds the verify window, and growth below needs its
             # real span: an input-building phase of its own, before the grow.
@@ -2405,9 +2415,15 @@ class ServingEngine:
                 kv_walk["state_rows"] = occ
                 self._state_live = occ + (pf is not None)
                 self._m_state_live.set(float(self._state_live))
+        drafted = int(draft_ok[self._active].sum()) if spec else 0
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
             sample_path=self._note_sample_path(*knobs), **kv_walk,
+            # the program's one ``decode_parts`` call: how often the tick
+            # streams the token-wise weights, over how many padded token
+            # rows, how many of them real (live rows' tokens + the chunk's)
+            weight_passes=1, pass_rows=self._pass_rows,
+            pass_tokens=occ + drafted + clen,
             **({"drafted": int(draft_ok.sum())} if spec else {}))
         chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
                            tokens=clen, **state)
@@ -2450,9 +2466,7 @@ class ServingEngine:
             n_acc = next(out) if spec else None
             ctok = next(out) if chunked else None
             if self._model_counters:
-                # a chunk-free tick ran the chunk's rows on padding
-                self._note_model_counters(
-                    [x[:1 + int(do_chunk)] for x in out])
+                self._note_model_counters(list(out))
             finished.extend(
                 self._advance_decode_spec(toks, n_acc, draft_ok, now)
                 if spec else self._advance_decode(toks, now))
@@ -2901,8 +2915,10 @@ class ServingEngine:
                     n_granules=kv_p // lanes if quantized else None,
                     variant=tag))
         extra = getattr(self._bind, "serving_kernel_specs", None)
-        if extra is not None:       # kernels only this model's steps build
-            specs.extend(extra([b * s for b, s, _ in shapes]))
+        if extra is not None:
+            # kernels only this model's steps build, token-wise: once over
+            # the tokens of the step program's one pass
+            specs.extend(extra([self._pass_rows]))
         return specs
 
     def kernel_preflight(self, rules=None) -> Dict[str, object]:
@@ -3147,10 +3163,11 @@ class ServingEngine:
     def _note_model_counters(self, load) -> None:
         """Per tick, what only some models have: the routed-expert load the
         step program returned beside the tokens (``load``: nothing, or one
-        int array (parts, expert layers, held + 1) — per program part and
-        expert layer the (token, expert) pairs routed to each held expert,
-        then the pairs routed to experts held elsewhere), and the live
-        positions of window layers that lie behind their window."""
+        int array (weight passes, expert layers, held + 1) — per pass of
+        the weights, one a program, and expert layer the (token, expert)
+        pairs routed to each held expert, then the pairs routed to experts
+        held elsewhere), and the live positions of window layers that lie
+        behind their window."""
         if self._windows:
             pos = self._positions[self._active].astype(np.int64)
             dead = sum(int(np.maximum(pos + 1 - w, 0).sum())
@@ -3176,7 +3193,7 @@ class ServingEngine:
         for li, e in zip(*np.nonzero(by_layer)):
             self._m_expert_load[li][e].inc(int(by_layer[li, e]))
         elsewhere = int(load[..., -1].sum())
-        touched = (held > 0).sum(axis=-1)           # (parts, layers)
+        touched = (held > 0).sum(axis=-1)           # (passes, layers)
         self._expert_totals += (elsewhere, int(touched.sum()), touched.size)
         self._m_pairs_elsewhere.inc(elsewhere)
         for n in touched.reshape(-1):

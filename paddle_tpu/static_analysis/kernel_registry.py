@@ -780,9 +780,10 @@ def registered_kernel_specs() -> List[KernelSpec]:
         decode_attention_spec(1, 256, 48, 8, 128, block_len=128,
                               max_blocks=64, num_layers=5, window=4096,
                               variant="paged,chunked_prefill,window"),
-        # the held experts' grouped product: decode rows and a chunk
-        moe_experts_spec(192 * 4, 32, 3072, 3072),
-        moe_experts_spec(256 * 4, 32, 3072, 3072),
+        # the held experts' grouped product over the pairs of a mixed
+        # step's one pass: (decode rows + chunk positions) x top-k
+        moe_experts_spec((192 + 256) * 4, 32, 3072, 3072),
+        moe_experts_spec((320 + 256) * 4, 16, 2048, 1792),
         flash_attention_spec(1, 32, 8, 2048, 2048, 128),
         int8_matmul_spec(8, 4096, 4096),
         rms_norm_spec(256, 4096),

@@ -30,31 +30,87 @@ def lowered(fn, args):
         lowering_platforms=("tpu",))
 
 
-def sorts_over(low, width):
-    """Every ``stablehlo.sort`` of a lowered program whose operand's last axis
-    is ``width`` long, once for each chain of calls that reaches it from
-    ``main``, as (operand shape, inside a conditional's branch or not)."""
+def _functions(low):
     module = low.compiler_ir("stablehlo")
-    funcs = {f.name.value: f for f in module.body.operations
-             if f.operation.name == "func.func"}
-    found = []
+    return {f.name.value: f for f in module.body.operations
+            if f.operation.name == "func.func"}
 
-    def walk(op, in_branch):
+
+def walk(low):
+    """Every operation of a lowered program, once for each chain of calls
+    that reaches it from ``main``, as (operation, inside a conditional's
+    branch or not)."""
+    funcs = _functions(low)
+
+    def visit(op, in_branch):
         name = op.operation.name
-        if name == "stablehlo.sort":
-            shape = tuple(op.operands[0].type.shape)
-            if shape and shape[-1] == width:
-                found.append((shape, in_branch))
+        yield op, in_branch
         if name == "func.call":
-            walk(funcs[op.attributes["callee"].value], in_branch)
+            yield from visit(funcs[op.attributes["callee"].value], in_branch)
         inside = in_branch or name in ("stablehlo.case", "stablehlo.if")
         for region in op.operation.regions:
             for block in region.blocks:
                 for child in block.operations:
-                    walk(child, inside)
+                    yield from visit(child, inside)
 
-    walk(funcs["main"], False)
+    yield from visit(funcs["main"], False)
+
+
+def sorts_over(low, width):
+    """Every ``stablehlo.sort`` of a lowered program whose operand's last axis
+    is ``width`` long, once for each chain of calls that reaches it from
+    ``main``, as (operand shape, inside a conditional's branch or not)."""
+    found = []
+    for op, in_branch in walk(low):
+        if op.operation.name == "stablehlo.sort":
+            shape = tuple(op.operands[0].type.shape)
+            if shape and shape[-1] == width:
+                found.append((shape, in_branch))
     return found
+
+
+def kernel_calls(low):
+    """How often a lowered program calls each Pallas kernel, by the
+    kernel's name (``ops._dispatch.kernel_name``)."""
+    calls = {}
+    for op, _ in walk(low):
+        if (op.operation.name == "stablehlo.custom_call" and
+                op.attributes["call_target_name"].value == "tpu_custom_call"):
+            name = op.attributes["kernel_name"].value
+            calls[name] = calls.get(name, 0) + 1
+    return calls
+
+
+# what hands a weight on as it is, on its way to the product that reads it
+_PASSES_ON = ("stablehlo.transpose", "stablehlo.convert", "stablehlo.reshape")
+
+
+def product_reads(low, args):
+    """Per leaf of ``args[0]`` (the parameters) of two or more axes: how
+    many matrix products of the lowered program read it — a
+    ``dot_general``, or a Pallas kernel's call — followed through calls and
+    through transposes, conversions and reshapes."""
+    funcs = _functions(low)
+
+    def reads(value):
+        n = 0
+        for use in value.uses:
+            op = use.owner
+            if op.name in ("stablehlo.dot_general", "stablehlo.custom_call"):
+                n += 1
+            elif op.name == "func.call":
+                callee = funcs[op.attributes["callee"].value]
+                n += reads(callee.arguments[use.operand_number])
+            elif op.name in _PASSES_ON:
+                n += sum(reads(r) for r in op.results)
+        return n
+
+    leaves = jax.tree_util.tree_flatten_with_path(args)[0]
+    main = funcs["main"]
+    assert len(leaves) == len(main.arguments)
+    return {jax.tree_util.keystr(path[1:]): reads(arg)
+            for (path, leaf), arg in zip(leaves, main.arguments)
+            if path[0].idx == 0 and len(leaf.shape) >= 2}
 
 
 def strip_locations(txt):
@@ -115,39 +171,17 @@ def programs(label, eng, bucket, vocab=None):
               *sha(lowered(prefill, prefill_args(eng, bucket))))
 
 
-def lfm2_cell(root, cell_file):
-    """The fourth cell, on a checkout that has it: a dense convolution
-    layer, a dense one, then an attention layer and a convolution layer with
-    experts (the published layers 0-3), every held expert."""
-    name = "lfm2-8b-a1b-ep2.decode-wide-saturated"
-    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
-                                       name + ".json")):
-        return
-    import paddle_tpu as pt
-    from benchmark.harness import serve_lfm2
-    from paddle_tpu import nn
-    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM
-    from paddle_tpu.serving import ServingEngine
-    kw = dict(cell_file("workloads", name)["engine"], num_blocks=129)
-    cfg = cell_file("configs", "lfm2-8b-a1b-ep2")
-    cfg = dict(cfg, num_hidden_layers=4, layer_types=cfg["layer_types"][:4])
-    with nn.abstract_parameters():
-        model = Lfm2MoeForCausalLM(
-            serve_lfm2.program_config(cfg, kw["max_length"]))
-    model.eval()
-    pt.flags.set_flags({"perf_model": "off"})
-    programs(name, ServingEngine(model, seed=0, **kw), 256)
-    pt.flags.set_flags({"perf_model": "on"})
-
-
-def main(root):
-    root = os.path.abspath(root)
-    sys.path.insert(0, root)
-    import paddle_tpu.ops._dispatch as D
-    D.default_backend = lambda: "tpu"      # the chip's dispatch, lowered here
+def cell_engines(root):
+    """(cell, its engine, the vocabulary a sort would span or None) for
+    every benchmark cell of the checkout at ``root`` (already on
+    ``sys.path``), one at a time: published widths, the engine settings of
+    the cell's file, 2 layers, or 4 where the layers are of four kinds.
+    The expert models are built to be loaded, since only shapes are lowered
+    and a CPU need not hold 2 GB of experts (the cost model would sum the
+    weights' bytes, and is no part of a program)."""
     import paddle_tpu as pt
     from paddle_tpu import nn
-    from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
+    from paddle_tpu.models import LlamaForCausalLM
     from paddle_tpu.models.llama import LlamaConfig
     from paddle_tpu.serving import ServingEngine
 
@@ -165,16 +199,13 @@ def main(root):
         dtype="bfloat16", num_hidden_layers=2, **fields))
     model.eval()
     for cell in ("mistral-7b.decode-saturated", "mistral-7b.chat-open"):
-        eng = ServingEngine(model, seed=0,
-                            **cell_file("workloads", cell)["engine"])
-        programs(cell, eng, 256, vocab=fields["vocab_size"])
-        del eng
+        yield cell, ServingEngine(
+            model, seed=0, **cell_file("workloads", cell)["engine"]), \
+            fields["vocab_size"]
     del model
 
     # the third cell: the published layer 0 (dense, window) and one global
-    # expert layer, every held expert; built to be loaded, since only shapes
-    # are lowered and a CPU need not hold 2 GB of experts (the cost model
-    # would sum the weights' bytes, and is no part of a program)
+    # expert layer, every held expert
     from benchmark.harness import serve_afmoe
     from paddle_tpu.models.afmoe import AfmoeForCausalLM
     cell = "trinity-large-ep8.longtail-saturated"
@@ -186,10 +217,43 @@ def main(root):
             serve_afmoe.program_config(cfg, kw["max_length"]))
     model.eval()
     pt.flags.set_flags({"perf_model": "off"})
-    programs(cell, ServingEngine(model, seed=0, **kw), 256)
+    yield cell, ServingEngine(model, seed=0, **kw), None
     pt.flags.set_flags({"perf_model": "on"})
     del model
-    lfm2_cell(root, cell_file)
+
+    # the fourth cell, on a checkout that has it: a dense convolution
+    # layer, a dense one, then an attention layer and a convolution layer
+    # with experts (the published layers 0-3), every held expert
+    cell = "lfm2-8b-a1b-ep2.decode-wide-saturated"
+    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
+                                       cell + ".json")):
+        return
+    from benchmark.harness import serve_lfm2
+    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM
+    kw = dict(cell_file("workloads", cell)["engine"], num_blocks=129)
+    cfg = cell_file("configs", "lfm2-8b-a1b-ep2")
+    cfg = dict(cfg, num_hidden_layers=4, layer_types=cfg["layer_types"][:4])
+    with nn.abstract_parameters():
+        model = Lfm2MoeForCausalLM(
+            serve_lfm2.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    yield cell, ServingEngine(model, seed=0, **kw), None
+    pt.flags.set_flags({"perf_model": "on"})
+
+
+def main(root):
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    import paddle_tpu.ops._dispatch as D
+    D.default_backend = lambda: "tpu"      # the chip's dispatch, lowered here
+    import paddle_tpu as pt
+    from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
+    from paddle_tpu.serving import ServingEngine
+
+    for cell, eng, vocab in cell_engines(root):
+        programs(cell, eng, 256, vocab=vocab)
+        del eng
 
     pt.seed(7)
     model = LlamaForCausalLM(tiny_llama_config(context_parallel="gspmd"))

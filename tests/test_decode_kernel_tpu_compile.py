@@ -5,6 +5,13 @@ slice off the tiling, a DMA shape, a loop Mosaic will not lower, more VMEM
 than a kernel may take — fails here and not on the chip.  Nothing runs: no
 result and no time comes out of this file.
 
+Also the chunked cells' whole MIXED step programs (published widths, the
+cells' engine settings, ``lowered_step_text``'s cut depth), under the chip's
+dispatch: that one pass of the weights reads each weight in one product and
+calls the grouped product three times an expert layer, that Mosaic takes it
+at the flat pair count, and that the program holds no temporary of a
+weight's or the pool's size.
+
 The topology is described inside a fixture, never at import: one process at
 a time may load libtpu, and every xdist worker imports every test file.
 """
@@ -15,7 +22,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
+from lowered_step_text import (cell_engines, kernel_calls, lowered,
+                               product_reads)
 
+from paddle_tpu.ops import _dispatch
 from paddle_tpu.ops.pallas.decode_attention import (
     decode_attention_pallas, paged_decode_attention_pallas)
 
@@ -87,3 +97,71 @@ def test_contiguous_kernel_compiles_for_v5e(one_chip):
     compiled = jax.jit(decode_attention_pallas).lower(
         q, kv, kv, _spec(one_chip, (8,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the mixed step programs, whole -------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# cell: expert layers at the cut depth
+MIXED = {"mistral-7b.chat-open": 0,
+         "trinity-large-ep8.longtail-saturated": 1,
+         "lfm2-8b-a1b-ep2.decode-wide-saturated": 2}
+
+
+@pytest.fixture(scope="module")
+def mixed_engines():
+    """cell -> its engine, built under the chip's dispatch (the code asks
+    ``default_backend()`` which kernels to build; the test steers it, the
+    program has no option for it).  The patch stays for the module's tests:
+    they lower and compile, nothing runs."""
+    patch = pytest.MonkeyPatch()
+    patch.setattr(_dispatch, "default_backend", lambda: "tpu")
+    yield {cell: eng for cell, eng, _ in cell_engines(ROOT) if eng.chunked}
+    patch.undo()
+
+
+@pytest.mark.parametrize("cell", list(MIXED))
+def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell):
+    eng = mixed_engines[cell]
+    args = eng._lint_args()
+    low = lowered(eng._step_fn.python_fn, args)
+    reads = product_reads(low, args)
+    # tables and filters no product reads: the embedding where the head is
+    # not tied to it, RoPE's, a short convolution's taps
+    assert {k for k, n in reads.items() if n == 0} <= {
+        k for k in reads if k.endswith(("rope_cos']", "rope_sin']",
+                                        "embed_tokens']", ".conv.conv']"))}
+    twice = {k: n for k, n in reads.items() if n > 1}
+    assert not twice, twice
+    assert sum(reads.values()) >= 5 * eng.config.num_hidden_layers
+    calls = kernel_calls(low)
+    assert calls.get("_step_impl_token_pass_moe_experts", 0) == 3 * MIXED[cell]
+    assert not [k for k in calls if "moe_experts" in k
+                and k != "_step_impl_token_pass_moe_experts"], calls
+    # attention stays a part at a time, under the part's own name
+    assert {k for k in calls if "flash_decode" in k} == {
+        "_step_impl_decode_rows_flash_decode",
+        "_step_impl_prompt_chunk_flash_decode"}
+
+
+@pytest.mark.parametrize("cell", list(MIXED))
+def test_mixed_program_compiles_for_v5e(one_chip, mixed_engines, cell):
+    eng = mixed_engines[cell]
+    params, cache, *operands = jax.tree_util.tree_map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), eng._lint_args())
+    compiled = jax.jit(eng._step_fn.python_fn, donate_argnums=(1,)).lower(
+        params, cache, *operands).compile()
+    text = compiled.as_text()
+    assert text.count("_step_impl_token_pass_moe_experts") >= 3 * MIXED[cell]
+    assert "_step_impl_prompt_chunk_flash_decode" in text
+
+    def largest(tree):
+        return max(x.size * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(tree))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # no temporary of a weight's size (LFM2's 260 MB are the sampling
+    # epilogue's sort branch over 320 x 65,536 logits, as at the parent),
+    # nor of the pool's (the tool cuts LFM2's pool to 129 blocks: there the
+    # weights' bound is the tighter one)
+    assert temp < largest(params)
+    assert temp < max(largest(cache), largest(params))

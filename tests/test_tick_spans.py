@@ -182,6 +182,55 @@ def test_tick_spans_count_the_kernels_block_walk(lm, mode):
     assert seen >= 4
 
 
+PASS_MODES = dict(MODES, spec_chunked={
+    "spec_decode": True, "spec_k": 2, "chunked": True, "prefill_chunk": 8})
+
+
+@pytest.mark.parametrize("mode", sorted(PASS_MODES))
+def test_tick_spans_say_what_the_one_weight_pass_ran_over(lm, mode):
+    """``weight_passes=`` / ``pass_rows=`` / ``pass_tokens=`` on the tick's
+    rows span: the step program streams the token-wise weights once, over
+    ``num_slots·(k+1) + prefill_chunk`` token rows, of which the live rows'
+    tokens (a verify window's real drafts with them) and the chunk's real
+    tokens are real — none of a chunk-free tick's chunk part."""
+    eng = ServingEngine(lm, num_slots=3, max_length=MAXLEN, paged=True,
+                        block_len=8, **PASS_MODES[mode])
+    from paddle_tpu.serving.drafter import Drafter
+
+    class TwoTokens(Drafter):       # proposes on every tick it may
+        def propose(self, history):
+            return np.asarray([3, 4], np.int32)
+    for i, n in enumerate((13, 7)):
+        eng.submit(_prompt(n, i + 1), max_new_tokens=8,
+                   drafter=TwoTokens() if eng.spec else None)
+    rows_of = eng.num_slots * (eng.spec_k + 1 if eng.spec else 1) \
+        + eng.prefill_chunk * eng.chunked
+    kinds = set()
+    while eng.queue_depth or eng.last_occupancy or not kinds:
+        obs.get_tracer().clear()
+        eng.step()
+        evs = [e for e in obs.get_tracer().events() if e["ph"] == "X"]
+        rows = [e["args"] for e in evs
+                if e["name"] in ("serving.decode", "serving.verify")]
+        chunk = [e["args"] for e in evs if e["name"] == "serving.chunk"]
+        if not rows:
+            continue
+        (a,) = rows
+        assert (a["weight_passes"], a["pass_rows"]) == (1, rows_of)
+        real = a["slots"] + a.get("drafted", 0) + sum(
+            c["tokens"] for c in chunk)
+        assert a["pass_tokens"] == real <= rows_of
+        kinds.add((a["slots"] > 0, bool(chunk), a.get("drafted", 0) > 0))
+    # live rows with and (on a cursor engine) without a chunk beside them,
+    # a chunk with no live row; a verify window with real drafts
+    assert any(live and not chunk for live, chunk, _ in kinds)
+    if eng.chunked:
+        assert any(live and chunk for live, chunk, _ in kinds)
+        assert any(chunk and not live for live, chunk, _ in kinds)
+    if eng.spec:
+        assert any(drafted for *_, drafted in kinds)
+
+
 # -- (b) the same spans in a jax.profiler trace ------------------------------
 
 def test_phases_reach_the_profiler_trace(lm, tmp_path):
