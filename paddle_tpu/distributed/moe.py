@@ -64,7 +64,8 @@ from ..ops import _dispatch
 from .fleet.mp_layers import constrain
 
 __all__ = ["Gate", "SwitchGate", "GShardGate", "MoELayer",
-           "SigmoidTopKGate", "HeldExpertsMoE", "expert_load"]
+           "SigmoidTopKGate", "SoftmaxTopKGate", "HeldExpertsMoE",
+           "expert_load"]
 
 EP_AXES = ("dp", "sharding")  # expert dim rides the combined dp×sharding axes
 
@@ -345,6 +346,32 @@ class SigmoidTopKGate(Gate):
         if self.route_norm:
             w = w / (w.sum(-1, keepdims=True) + self.norm_eps)
         return idx.astype(jnp.int32), w * self.route_scale
+
+
+class SoftmaxTopKGate(Gate):
+    """Softmax router: ``p = softmax(x·W)`` in float32 over ALL experts,
+    the ``top_k`` largest, and where ``norm_topk_prob`` their weights
+    divided by their sum (the chosen probabilities then add up to 1;
+    without it they are the softmax's own).  No bias, no scale.  The
+    logits are :class:`SigmoidTopKGate`'s (every pass of the f32 product)
+    and so is ``route``'s contract."""
+
+    def __init__(self, hidden_size: int, num_experts: int, top_k: int,
+                 norm_topk_prob: bool = True, dtype=None):
+        super().__init__(hidden_size, num_experts, dtype=dtype)
+        self.top_k = int(top_k)
+        self.norm_topk_prob = bool(norm_topk_prob)
+
+    logits = SigmoidTopKGate.logits
+
+    def route(self, x):
+        """x (T, D) → (idx (T, top_k) int32 over ALL experts, weights
+        (T, top_k) float32)."""
+        w, idx = jax.lax.top_k(jax.nn.softmax(self.logits(x), axis=-1),
+                               self.top_k)
+        if self.norm_topk_prob:
+            w = w / w.sum(-1, keepdims=True)
+        return idx.astype(jnp.int32), w
 
 
 _LOAD = threading.local()

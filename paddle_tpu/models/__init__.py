@@ -11,7 +11,11 @@ without a prefix cache; ``lfm2`` (LFM2-MoE: short-convolution state a slot
 beside the paged pool) as ``paged=True, chunked=True, prefix_cache=False``
 and nothing else — its ``check_serving_layout`` names what it refuses (a
 prefix cache, preemption and the host tier, export/import, the contiguous
-cache, wave prefill, int8 KV, speculation, a mesh, int8 weights).  A model
+cache, wave prefill, int8 KV, speculation, a mesh, int8 weights); ``sdar``
+(SDAR-MoE: generation by diffusion over blocks, declared as
+``block_diffusion`` — the engine's rows part is then a block of positions a
+row, tokens leave it a block at a time) as ``paged=True, chunked=True,
+prefix_cache=False`` too, refusing the same list by name.  A model
 that keeps a decode state of its own (``init_decode_state``: ``mamba``,
 ``rwkv``) and does not declare it as serving state (``slot_state`` +
 ``init_serving_cache``) is refused at construction.
@@ -21,6 +25,7 @@ from .afmoe import AfmoeConfig, AfmoeForCausalLM, tiny_afmoe_config
 from .generation import (DecodeStep, accept_draft_tokens, greedy_generate,
                          init_kv_cache, sample_tokens)
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, tiny_lfm2_config
+from .sdar import SdarMoeConfig, SdarMoeForCausalLM, tiny_sdar_config
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     causal_lm_loss, draft_model_from, llama3_8b_config,
                     llama_pipe_descs, tiny_llama_config)
@@ -32,4 +37,5 @@ __all__ = [
     "accept_draft_tokens", "draft_model_from",
     "AfmoeConfig", "AfmoeForCausalLM", "tiny_afmoe_config",
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "tiny_lfm2_config",
+    "SdarMoeConfig", "SdarMoeForCausalLM", "tiny_sdar_config",
 ]

@@ -26,7 +26,7 @@ Design — everything is shaped for XLA's static-shape compilation model:
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -315,6 +315,93 @@ def accept_draft_tokens(logits, drafts, draft_mask, key, temperature=0.0,
                      jnp.where(col == (n - 1)[:, None], cand,
                                jnp.int32(pad_token_id)))
     return toks, n
+
+
+UNMASK_STRATEGIES = ("low_confidence_dynamic", "low_confidence_static")
+
+
+class BlockDiffusion(NamedTuple):
+    """How a block-diffusion decoder generates (a model's
+    ``block_diffusion``; the serving engine composes its block rows part
+    from it): blocks of ``length`` positions, each opened as ``length``
+    copies of ``mask_token_id`` and denoised in place; ``steps``,
+    ``strategy`` and ``threshold`` are the unmasking rule's defaults,
+    which a request may override (``SamplingParams``)."""
+
+    length: int
+    mask_token_id: int
+    steps: int = 4
+    strategy: str = "low_confidence_dynamic"
+    threshold: float = 0.9
+
+    def static_count(self, strategy: Optional[str] = None) -> int:
+        """What :func:`unmask_block` takes as a row's ``n_static``: the
+        positions ``low_confidence_static`` unmasks a forward (``length /
+        steps``, rounded up), or 0 for ``low_confidence_dynamic``."""
+        strategy = self.strategy if strategy is None else strategy
+        if strategy not in UNMASK_STRATEGIES:
+            raise ValueError(
+                f"unmasking strategy {strategy!r} is none of "
+                f"{UNMASK_STRATEGIES}")
+        if strategy == "low_confidence_dynamic":
+            return 0
+        return -(-self.length // max(1, self.steps))
+
+
+def unmask_block(logits, block, mask_token_id: int, key, temperature,
+                 top_k, top_p, n_static, threshold):
+    """One denoising forward's epilogue, beside :func:`sample_tokens` and
+    :func:`accept_draft_tokens`: from the logits of each row's block, unmask
+    some of its still-masked positions.
+
+    ``logits``: (S, B, V), position j's predicting the token AT j;
+    ``block``: int (S, B), ``mask_token_id`` where masked; the knobs are
+    :func:`sample_tokens`' per-row vectors; ``n_static`` int (S,) and
+    ``threshold`` f32 (S,) the row's unmasking rule.  At every position the
+    candidate ``x0`` is :func:`sample_tokens`' choice (the argmax of a
+    greedy row: no sort; the mask token is never a candidate) and its
+    confidence the softmax probability of ``x0`` (of the temperature-scaled
+    logits where the row samples).  Of the MASKED positions a row unmasks
+
+      * ``n_static == 0`` (``low_confidence_dynamic``): every one whose
+        confidence passes ``threshold``, and the single most confident one
+        if none does;
+      * ``n_static > 0`` (``low_confidence_static``): the ``n_static`` most
+        confident (all, where fewer are left).
+
+    Ties go to the earlier position.  An unmasked token is never touched;
+    a block that comes in mask-free (a commit forward) goes out as it is.
+    Returns (the new block (S, B) int32, positions unmasked (S,) int32)."""
+    s, b, vocab = logits.shape
+    is_mask = jnp.arange(vocab) == mask_token_id
+    scale = jnp.where(temperature > 0.0, temperature, 1.0)[:, None]
+    x0, conf = [], []
+    # a position of the block at a time: the float32 logits and the
+    # sampling branch's temporaries (a sort of rows x vocabulary) are then
+    # a B-th of the block's, and reused
+    for j in range(b):
+        lg = jnp.where(is_mask, -jnp.inf, logits[:, j].astype(jnp.float32))
+        tok = sample_tokens(lg, jax.random.fold_in(key, j), temperature,
+                            top_k, top_p)
+        scaled = lg / scale
+        x0.append(tok)
+        conf.append(jnp.exp(
+            jnp.take_along_axis(scaled, tok[:, None], axis=-1)[:, 0]
+            - jax.nn.logsumexp(scaled, axis=-1)))
+    x0, conf = jnp.stack(x0, axis=1), jnp.stack(conf, axis=1)    # (S, B)
+    masked = block == mask_token_id
+    conf = jnp.where(masked, conf, -1.0)
+    # a position's rank among its row's by confidence, ties to the earlier:
+    # B x B comparisons, no sort
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (jnp.arange(b)[None, None, :] < jnp.arange(b)[None, :, None]))
+    rank = ahead.sum(-1)
+    dynamic = (conf > threshold[:, None]) | (rank == 0)
+    take = masked & jnp.where((n_static > 0)[:, None],
+                              rank < n_static[:, None], dynamic)
+    return (jnp.where(take, x0, block).astype(jnp.int32),
+            take.sum(-1, dtype=jnp.int32))
 
 
 def decode_mesh_specs(model, params, axis_names, paged_cache=False,

@@ -376,7 +376,7 @@ def _shard_map_decode_attention(q, k_cache, v_cache, pos, scale=None,
 
 def _shard_map_paged_decode_attention(q, pool, layer, pos, block_tables,
                                       scale=None, live_len=None,
-                                      pool_scale=None, window=None):
+                                      pool_scale=None, window=None, block=1):
     """:func:`_shard_map_decode_attention` for the paged pool: the pool is
     head-sharded only — its fused ``Hkv·D`` axis splits over ``mp`` as
     whole heads (head-major, contiguous), every shard holding all blocks
@@ -398,7 +398,8 @@ def _shard_map_paged_decode_attention(q, pool, layer, pos, block_tables,
     def body(q_, pool_, pos_, bt_, sc_=None):
         return paged_decode_attention(q_, pool_, layer, pos_, bt_,
                                       scale=scale, live_len=live_len,
-                                      pool_scale=sc_, window=window)
+                                      pool_scale=sc_, window=window,
+                                      block=block)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                        out_specs=q_spec, check_vma=False)
@@ -497,7 +498,7 @@ def cached_decode_attention(q, k_cache, v_cache, pos,
 def paged_decode_attention(q, pool, layer: int, pos, block_tables,
                            scale: Optional[float] = None, extra_mask=None,
                            live_len: Optional[int] = None, pool_scale=None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None, block: int = 1):
     """:func:`cached_decode_attention` of layer ``layer`` over the PAGED
     pool (serving/kv_cache.py): ``pool`` is the whole
     ``(L, 2, num_blocks, block_len, Hkv·D)`` array of every layer's K and
@@ -513,10 +514,16 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
     per-row positions.  ``pool_scale``: the int8 pool's f32
     ``(L, 2, num_blocks, Hkv)`` per-block-per-kv-head dequant scales.
     Dispatch, ``live_len``, ``extra_mask``, ``window`` and the result are
-    :func:`cached_decode_attention`'s."""
+    :func:`cached_decode_attention`'s.  ``block`` (static int) is the
+    block-causal mask of a block-diffusion decoder: the query at position
+    ``i`` sees key ``j`` iff ``j // block <= i // block``; ``pos`` and the
+    q length are multiples of it.  1 is the causal mask, the programs as
+    they were."""
     b, s, hq, d = q.shape
     block_len, hd = pool.shape[-2:]
     win = {} if window is None else {"window": int(window)}
+    if int(block) != 1:
+        win["block"] = int(block)
     path, reason = decode_attention_path(
         b, s, hq, hd // d, d, block_tables.shape[1] * block_len,
         extra_mask is not None, paged_block_len=block_len,
@@ -567,7 +574,8 @@ def paged_decode_attention_reference(q, pool, layer: int, pos, block_tables,
                                      extra_mask=None,
                                      live_len: Optional[int] = None,
                                      pool_scale=None,
-                                     window: Optional[int] = None):
+                                     window: Optional[int] = None,
+                                     block: int = 1):
     """The XLA math path of :func:`paged_decode_attention` (and its
     numerical oracle): one gather takes each row's physical blocks out of
     ``pool[layer, 0 | 1]`` into the contiguous ``(B, max_blocks·block_len,
@@ -597,7 +605,7 @@ def paged_decode_attention_reference(q, pool, layer: int, pos, block_tables,
     return cached_decode_attention_reference(
         q, k_cache.reshape(b, mb * bl, hkv, d),
         v_cache.reshape(b, mb * bl, hkv, d), pos, scale=scale,
-        extra_mask=extra_mask, live_len=live_len, window=window)
+        extra_mask=extra_mask, live_len=live_len, window=window, block=block)
 
 
 def cached_decode_attention_reference(q, k_cache, v_cache, pos,
@@ -605,9 +613,12 @@ def cached_decode_attention_reference(q, k_cache, v_cache, pos,
                                       extra_mask=None,
                                       live_len: Optional[int] = None,
                                       k_scale=None, v_scale=None,
-                                      window: Optional[int] = None):
+                                      window: Optional[int] = None,
+                                      block: int = 1):
     """The XLA math path of :func:`cached_decode_attention` (and its
     numerical oracle): masked softmax over the whole cache read.
+    ``block`` > 1 is the block-causal mask (a position sees its own block
+    of ``block`` whole), as :func:`paged_decode_attention` states it.
 
     Decode is HBM-bound, so this path is shaped around traffic, where the
     generic ``flash_attention_reference`` (a training oracle) is not:
@@ -656,13 +667,15 @@ def cached_decode_attention_reference(q, k_cache, v_cache, pos,
     kj = jnp.arange(L)
     if getattr(pos, "ndim", 0) == 1:                  # per-row positions
         qi = pos[:, None] + jnp.arange(s)[None, :]    # (B, s)
-        keep = (kj[None, None] <= qi[:, :, None])     # (B, s, L)
+        seen = qi if block == 1 else qi // block * block + (block - 1)
+        keep = (kj[None, None] <= seen[:, :, None])   # (B, s, L)
         if window is not None:
             keep &= kj[None, None] > qi[:, :, None] - window
         keep = keep[:, None, None]                    # (B,1,1,s,L)
     else:
         qi = pos + jnp.arange(s)[:, None]             # (s, 1)
-        keep = kj[None] <= qi                         # (s, L)
+        seen = qi if block == 1 else qi // block * block + (block - 1)
+        keep = kj[None] <= seen                       # (s, L)
         if window is not None:
             keep &= kj[None] > qi - window
         keep = keep[None, None, None]                 # (1,1,1,s,L)

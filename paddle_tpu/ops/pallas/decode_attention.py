@@ -49,7 +49,9 @@ contiguous (B, L, Hkv, D) cache is reshaped to it per call, which on the
 TPU's tiled layouts is a physical copy of the layer's K and V (ROADMAP
 S1; that cache's writers keep heads apart, so it keeps that cost).
 Per-row ``pos`` masking happens inside the kernel
-(key j visible to query row (si, g) iff j <= pos_b + si) with the same
+(key j visible to query row (si, g) iff j <= pos_b + si; with a static
+``block`` > 1, iff j lies no later than the end of that position's block:
+the block-causal mask of a block-diffusion decoder) with the same
 fully-masked-row convention as the flash kernel (out = 0).
 
 The cross-group merge is the same LSE algebra the ring-attention path
@@ -213,7 +215,7 @@ def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
 
 
 def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
-            tile_p, bk, gb, n_cols, quantized, paged, window):
+            tile_p, bk, gb, n_cols, quantized, paged, window, block=1):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
         # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
@@ -311,14 +313,19 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
                         (bk, hkv * d), v_buf.dtype)
 
         # key j visible to tile row r = si·g + gi (si local to the tile)
-        # iff j <= pos_b + qi·bq + si; rows past bq·g are sublane padding
-        # and rows whose query offset runs past s are the last tile's
-        # ragged tail — both fully masked (out = 0)
+        # iff j <= pos_b + qi·bq + si — under a block mask, iff j lies no
+        # later than the END of that position's block of ``block``; rows
+        # past bq·g are sublane padding and rows whose query offset runs
+        # past s are the last tile's ragged tail — both fully masked
+        # (out = 0)
         cols = (jax.lax.broadcasted_iota(jnp.int32, (tile_p, gk), 1)
                 + (first + j * gb) * bk)
         rr = jax.lax.broadcasted_iota(jnp.int32, (tile_p, gk), 0)
         si = qi * bq + rr // g
-        keep = (cols <= pos_b + si) & (rr < bq * g) & (si < s)
+        seen = pos_b + si
+        if block > 1:
+            seen = seen // block * block + (block - 1)
+        keep = (cols <= seen) & (rr < bq * g) & (si < s)
         if window is not None:
             keep &= cols > pos_b + si - window
         if quantized:
@@ -425,7 +432,8 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
                                   scale: Optional[float] = None,
                                   interpret: bool = False,
                                   pool_scale=None,
-                                  window: Optional[int] = None):
+                                  window: Optional[int] = None,
+                                  block: int = 1):
     """Flash-decode of layer ``layer`` over the PAGED pool
     (serving/kv_cache.py ``init_paged_kv_cache``) → (B, s, Hq, D).
 
@@ -456,6 +464,14 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
     first block: blocks wholly behind the window of every query of a
     q tile are neither copied nor scored.  ``None`` is full causal
     attention.
+
+    ``block`` (static): the BLOCK-CAUSAL mask of block-diffusion decoders
+    — key ``j`` is visible to the query at position ``i`` iff ``j // block
+    <= i // block``: a position sees its own block whole.  1 is the causal
+    mask.  The caller keeps ``pos`` and ``s`` multiples of ``block`` (and
+    ``block`` divides a q tile), so a tile's last visible key is its last
+    query's own and the block walk (:func:`live_block_range`) is the causal
+    one.
     """
     d = q.shape[-1]
     bk, hd = pool.shape[-2:]
@@ -471,7 +487,7 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
         scales = (rows[0], rows[1])
     return _flash_decode(
         q, pool, pool, pos, bt, scale=scale, interpret=interpret,
-        layer=int(layer), scales=scales, window=window)
+        layer=int(layer), scales=scales, window=window, block=block)
 
 
 def _check_q(q, hkv: int) -> None:
@@ -493,16 +509,22 @@ def _check_q(q, hkv: int) -> None:
 
 
 def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
-                  scales, window=None):
+                  scales, window=None, block=1):
     """Both layouts' way into the one ``pallas_call``.  ``k_arr``/``v_arr``
     are the operands as they lie in HBM (the kernel leaves them there):
     the paged pool twice with its ``layer``, or the contiguous cache's K
     and V reshaped to ``(blocks, bk, Hkv·D)`` with ``layer`` None; ``bt``
     is the (B, columns) table of block ids and ``scales`` the int8 cache's
     (B, columns, Hkv) K and V scale tables, or None; ``window`` the static
-    sliding window, or None."""
+    sliding window, or None; ``block`` the static length of the
+    block-causal mask's blocks (1: causal)."""
     b, s, hq, d = q.shape
     hkv = k_arr.shape[-1] // d
+    block = int(block)
+    if block > 1 and (s % block or q_tiles(s, hq // hkv)[0] % block):
+        raise NotImplementedError(
+            f"block mask of {block}: q_len {s} and the q tile "
+            f"{q_tiles(s, hq // hkv)[0]} must be multiples of it")
     quantized = scales is not None
     n_cols = bt.shape[1]
     if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
@@ -537,14 +559,14 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
     return _flash_call(
         scalars, q, k_arr, v_arr,
         scale=float(d ** -0.5 if scale is None else scale), paged=paged,
-        window=None if window is None else int(window),
+        window=None if window is None else int(window), block=block,
         interpret=interpret, name=_disp.kernel_name("flash_decode"))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "paged", "window",
-                                             "interpret", "name"))
+                                             "block", "interpret", "name"))
 def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
-                name):
+                name, block=1):
     """The ``pallas_call`` with the q layout round it, jitted on its own:
     a model's layers differ only in the VALUE of the layer scalar, so they
     share one trace of the kernel body and one lowering of it in every
@@ -574,7 +596,7 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
     kernel = functools.partial(
         _kernel, scale=scale, s=s, g=g, hkv=hkv, d=d, bq=bq, nq=nq,
         tile_p=tile_p, bk=bk, gb=gb, n_cols=scalars[1].shape[1],
-        quantized=len(scalars) > 3, paged=paged, window=window)
+        quantized=len(scalars) > 3, paged=paged, window=window, block=block)
 
     def q_idx(bi, qi, *_):
         return (bi, 0, qi, 0)

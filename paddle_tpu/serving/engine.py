@@ -138,9 +138,32 @@ token.  Spec mode does that without a second model:
     verify window while a prefilling slot — inactive by construction —
     drafts nothing until its cursor completes.
 
+**Block diffusion** (a model that declares ``block_diffusion``: its block
+length ``B`` and mask token).  Everything above says a row advances one
+token a tick and a prompt's last chunk samples the request's first token;
+this is the one exception, and it is no switch of the constructor's: the
+rows part becomes a **block rows part** — ``tokens`` is the ``(num_slots,
+B)`` matrix of each row's current block (the mask token where a position is
+still masked), at the block's first position, the verify window's shape —
+whose epilogue is the unmasking rule (``models.generation.unmask_block``)
+in place of one sampled token.  A row's tick is a **denoising forward**
+(it unmasks one or more positions by confidence) or, when its block went in
+mask-free, the **commit forward** (it unmasks nothing; the K/V it writes
+are what later blocks read) after which the row advances by ``B`` and opens
+its next block.  A block that comes back mask-free is DELIVERED at once:
+``result(rid)`` grows a block at a time, cut at ``max_new_tokens``, and the
+first delivery is the request's first token; ``unmask_steps(rid)`` says at
+which forward-in-block each token was unmasked.  A prompt's whole blocks
+stream in through the chunk part under the same block-causal mask (the
+model's attention states the mask; the chunk's sampled token is unused) and
+the ``P mod B`` tokens left over open the first block already unmasked.
+Growth and the admission reservation go a block of positions at a time.
+Strategy and threshold are per request (``SamplingParams``).
+
 **One path** (PR 29).  The three switches select parts of one composed
 path, not copies of it: ONE step program (``_step_program``: a rows part —
-plain or verify — and a chunk part when chunked, over block tables or
+plain, verify or a block-diffusion model's block rows — and a chunk part
+when chunked, over block tables or
 slot rows, in ONE pass of the model's weights (``decode_parts``, since PR
 34: every token-wise operation once over both parts' tokens, attention and
 per-slot state a part at a time); where each kind of idle write lands is
@@ -175,7 +198,8 @@ from .. import observability as _obs
 from ..distributed import moe as _moe
 from ..models.generation import (SAMPLE_PATHS, _place_on_mesh,
                                  accept_draft_tokens, decode_mesh_specs,
-                                 init_kv_cache, sample_path, sample_tokens)
+                                 init_kv_cache, sample_path, sample_tokens,
+                                 unmask_block)
 from ..models.parts import DecodePart
 from ..nn.layer import bind_params
 from ..ops import _dispatch as _disp
@@ -244,11 +268,18 @@ class SamplingParams:
     """Per-request sampling knobs.  These become traced (num_slots,)
     vectors inside the step function, so any mixture across the batch
     reuses the one compiled program.  Conventions: ``temperature <= 0``
-    ⇒ greedy; ``top_k == 0`` ⇒ no top-k; ``top_p == 1.0`` ⇒ no top-p."""
+    ⇒ greedy; ``top_k == 0`` ⇒ no top-k; ``top_p == 1.0`` ⇒ no top-p.
+    ``unmask_strategy`` / ``unmask_threshold`` are read by a
+    block-diffusion model's engine alone (``models.generation
+    .unmask_block``: ``"low_confidence_dynamic"`` |
+    ``"low_confidence_static"``, and the dynamic rule's confidence
+    threshold); None ⇒ the model's own default."""
 
     temperature: float = 0.0
     top_k: int = 0
     top_p: float = 1.0
+    unmask_strategy: Optional[str] = None
+    unmask_threshold: Optional[float] = None
 
 
 class _Rejected(Exception):
@@ -297,6 +328,13 @@ class _Slot:
     prompt: Optional[np.ndarray] = None
     # the originating request — retirement reads its uid + SLO deadlines
     req: Optional[Request] = None
+    # block diffusion (``ServingEngine``, "Block diffusion"): the forwards
+    # the slot's current block has had, per position the forward at which
+    # it was unmasked (0: given by the prompt), and how many of the block's
+    # leading positions the prompt gave
+    forwards: int = 0
+    unmasked_at: Optional[np.ndarray] = None
+    given: int = 0
 
 
 @dataclasses.dataclass
@@ -307,6 +345,7 @@ class _Prefill:
     req: Request
     slot: int
     cursor: int                        # prompt tokens already in the cache
+    end: int = 0                       # prompt tokens the cursor ingests
 
 
 @dataclasses.dataclass
@@ -347,6 +386,11 @@ class ServingEngine:
     one jitted decode step → retire), ``drain()`` runs ticks until every
     request is finished and returns outputs in arrival order.
     """
+
+    # a block-diffusion model's ``block_diffusion`` and its block length
+    # (0: every row's tick is one token); set once, at construction
+    _diffusion = None
+    _block = 0
 
     def __init__(self, model, num_slots: int = 8, max_length: int = 1024,
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
@@ -519,8 +563,13 @@ class ServingEngine:
         # engine's layouts it cannot run (``check_serving_layout``), whether
         # its step programs return a routed-expert load beside the tokens
         # (``expert_layers``), and which of its layers read a sliding window
-        # only (``attention_windows``).  A model that keeps a decode state of
+        # only (``attention_windows``), and whether it generates by diffusion
+        # over blocks (``block_diffusion``: the block length and mask token,
+        # by which the rows part becomes a block rows part, and the unmasking
+        # rule's defaults).  A model that keeps a decode state of
         # its own and declares none of it is refused here, by name.
+        self._diffusion = getattr(self._bind, "block_diffusion", None)
+        self._block = int(self._diffusion.length) if self._diffusion else 0
         self._slot_leaves = tuple(getattr(self._bind, "slot_state", ()))
         if (hasattr(self._bind, "init_decode_state")
                 and not self._slot_leaves):
@@ -540,6 +589,12 @@ class ServingEngine:
                          spec_decode=self.spec,
                          int8_weights=self._int8_weights,
                          preempt=self.preempt, host_blocks=self._host_blocks)
+        if self._block and (self.prefill_chunk % self._block
+                            or self.max_length % self._block):
+            raise ValueError(
+                f"prefill_chunk {self.prefill_chunk} and max_length "
+                f"{self.max_length} must be multiples of the model's block "
+                f"of {self._block}: a chunk and a cache hold whole blocks")
         self._expert_layers = int(getattr(self._bind, "expert_layers", 0))
         self._windows = tuple(
             int(w) for w in getattr(self._bind, "attention_windows", ())
@@ -554,6 +609,10 @@ class ServingEngine:
         self._kv_layers = int(model.config.num_hidden_layers)
         if self.paged:
             nb, bl = self._init_pool(block_len, num_blocks, prefix_cache)
+            if self._block and bl % self._block:
+                raise ValueError(
+                    f"block_len {bl} is no multiple of the model's block "
+                    f"of {self._block}")
             if self._slot_leaves:
                 # one row a slot and a null row, which a chunk-free tick's
                 # chunk part addresses
@@ -714,6 +773,7 @@ class ServingEngine:
         self._step_table, self._prefill_table = self._operand_tables()
         self._step_outputs = (
             ("tokens",) + ("n_acc",) * self.spec
+            + ("n_unmasked",) * bool(self._block)
             + ("chunk_token",) * self.chunked
             + ("expert_load",) * bool(self._expert_layers) + ("cache",))
 
@@ -793,6 +853,15 @@ class ServingEngine:
         self._temps = np.zeros((s,), np.float32)
         self._topk = np.zeros((s,), np.int32)
         self._topp = np.ones((s,), np.float32)
+        if self._block:
+            # block diffusion: each row's current block (mask id where
+            # masked) in place of its one token, and its unmasking rule
+            self._blocks = np.full((s, self._block),
+                                   self._diffusion.mask_token_id, np.int32)
+            self._unmask_n = np.zeros((s,), np.int32)
+            self._unmask_thr = np.ones((s,), np.float32)
+            # per delivered token, the forward-in-block that unmasked it
+            self._unmasked_at: Dict[int, List[int]] = {}
 
         self._slots: List[Optional[_Slot]] = [None] * s
         self._prefill: Optional[_Prefill] = None   # chunked-mode cursor
@@ -857,8 +926,7 @@ class ServingEngine:
         self._perf.on_tick(
             measured_ms, occ=occ, live_tokens=live,
             chunk_tokens=chunk_tokens,
-            window=self.spec_k + 1 if self.spec else 1,
-            swap_bytes=swap_bytes)
+            window=self._row_tokens, swap_bytes=swap_bytes)
 
     def perf_report(self) -> Dict[str, object]:
         """Predicted-vs-measured attribution for this engine: per-bound
@@ -905,7 +973,7 @@ class ServingEngine:
                  else 0)
         pred = self._perf.model.predicted_tick_ms(
             occ_after, live + int(prompt_len), chunk_tokens=chunk,
-            window=self.spec_k + 1 if self.spec else 1)
+            window=self._row_tokens)
         waves = 1 + backlog // max(1, self.prefill_batch)
         return {"predicted_tick_ms": pred,
                 "predicted_tpot_ms": pred,
@@ -941,7 +1009,7 @@ class ServingEngine:
             return False
         pred = self._perf.model.predicted_tick_ms(
             occ_after, live_after, chunk_tokens=chunk_tokens,
-            window=self.spec_k + 1 if self.spec else 1)
+            window=self._row_tokens)
         calib = float(_flags.flag("serving_admission_calib"))
         slack = float(_flags.flag("serving_admission_slack"))
         return pred * calib > min(guards) * slack
@@ -1276,6 +1344,19 @@ class ServingEngine:
                 "held experts with at least one routed pair, per expert-"
                 "layer call: the expert weights the grouped product had "
                 "to read").labels(**lbl)
+        if self._block:
+            self._m_diffusion = tuple(ctr(
+                "serving.diffusion." + name, text).labels(**lbl)
+                for name, text in (
+                    ("forwards", "rows' forwards over a block: every live "
+                     "row of every block step, denoising or commit"),
+                    ("unmasked", "positions unmasked by a denoising "
+                     "forward"),
+                    ("commits", "rows' forwards that found their block "
+                     "mask-free: they unmask nothing and write the K/V "
+                     "later blocks read"),
+                    ("delivered", "tokens delivered to requests, a block "
+                     "at a time, cut at max_new_tokens")))
         if self._slot_leaves:
             self._m_state_live = gauge(
                 "kv_cache.state_rows_live",
@@ -1364,6 +1445,8 @@ class ServingEngine:
                     op(c + "topk", (n,), i32, topk),
                     op(c + "topp", (n,), f32, topp, fill=1)]
         step = [op("tokens", (s, k + 1), i32, "tokens") if self.spec
+                else op("tokens", (s, self._block), i32, self._blocks)
+                if self._block
                 else op("tokens", (s,), i32, self._tokens),
                 # the cursor engine on the contiguous cache steers its idle
                 # rows' positions each tick (``_step_inner``)
@@ -1378,6 +1461,9 @@ class ServingEngine:
                      op("draft_probs", (s, k, self.config.vocab_size), f32,
                         "draft_probs")]
         step += knobs(s, "", self._temps, self._topk, self._topp)
+        if self._block:          # each row's unmasking rule (unmask_block)
+            step += [op("unmask_n", (s,), i32, self._unmask_n),
+                     op("unmask_thr", (s,), f32, self._unmask_thr, fill=1)]
         if self.chunked:
             step += [op("cids", (1, self.prefill_chunk), i32, "cids"),
                      op("cpos", (), i32, "cpos", jnp.int32),
@@ -1446,6 +1532,16 @@ class ServingEngine:
         are dead cells the next steps overwrite before any mask can read
         them (the stale-tail argument plain decode already relies on).  A
         draft-free tick is the same program with all-pad windows.
+        Block rows (a model that declares ``block_diffusion``; the module
+        docstring's "Block diffusion"): ``tokens`` is the (num_slots, B)
+        matrix of each row's current block at ``positions[i]``, a multiple
+        of B; ONE forward runs every row's block under the model's
+        block-causal mask and ``unmask_block`` returns each row's new block
+        and how many positions it unmasked.  The window's two arguments
+        carry over: the block's K/V at ``positions[i]..positions[i]+B-1``
+        are written before they are read, and a denoising forward's K/V are
+        overwritten by the next forward of the same block, the commit
+        forward's being final (no mask reads past a block's end).
 
         Where a row that is not decoding writes.  Paged: its table row is
         all null, so the write lands in the null block (kv_cache.py's
@@ -1469,16 +1565,20 @@ class ServingEngine:
         write drops, the row round-trips bit-identical).  Its logits are
         taken at ``clen - 1`` alone: the sampled chunk token is the
         request's FIRST token when this chunk completes the prompt; the
-        host discards it otherwise.  A prefilling slot is inactive until
-        its cursor completes, so the two parts never touch the same row.
+        host discards it otherwise (always, for a block-diffusion model:
+        its cursor stops at the prompt's last whole block and its first
+        token comes with the first block's delivery).  A prefilling slot is
+        inactive until its cursor completes, so the two parts never touch
+        the same row.
 
         Per-slot state (a model's ``slot_state`` leaves): the rows part
         addresses the slots' rows (the null row stays out), the chunk part
         the row ``cslot``: the cursor's slot (a table row names blocks, not
         a slot), the null row on a chunk-free tick.
 
-        Returns ``_step_outputs``: a model with expert layers adds their
-        load, (1, expert layers, held + 1), before the cache."""
+        Returns ``_step_outputs``: block rows add ``n_unmasked`` after the
+        blocks, a model with expert layers adds their load, (1, expert
+        layers, held + 1), before the cache."""
         chunked, spec = self.chunked, self.spec
         names = [o.name for o in self._step_table]
 
@@ -1499,7 +1599,17 @@ class ServingEngine:
                      else contextlib.nullcontext(())) as load:
                 (logits, *clogits), cache = self.model.decode_parts(parts,
                                                                     cache)
-            if spec:
+            if self._block:
+                # the block rows' epilogue: the unmasking rule in place of
+                # one sampled token a row (``unmask_block``)
+                with jax.named_scope("unmask"):
+                    new, n_un = unmask_block(
+                        logits, tokens, self._diffusion.mask_token_id, key,
+                        a["temps"], a["topk"], a["topp"], a["unmask_n"],
+                        a["unmask_thr"])
+                outs = [jnp.where(mask[:, None], new, tokens),
+                        jnp.where(mask, n_un, 0)]
+            elif spec:
                 with jax.named_scope("accept"):
                     out, n_acc = accept_draft_tokens(
                         logits, tokens[:, 1:], a["draft_ok"], key,
@@ -1531,8 +1641,14 @@ class ServingEngine:
     def _pass_rows(self) -> int:
         """The token rows of the step program's one pass of the weights:
         its parts' rows x positions, padding and all."""
-        return (self.num_slots * (self.spec_k + 1 if self.spec else 1)
+        return (self.num_slots * self._row_tokens
                 + self.prefill_chunk * self.chunked)
+
+    @property
+    def _row_tokens(self) -> int:
+        """The positions one row of the rows part holds: the verify window,
+        a block-diffusion model's block, or one token."""
+        return self.spec_k + 1 if self.spec else self._block or 1
 
     def _step_parts(self, a):
         """The step program's parts (``_step_program``), from its operands
@@ -1552,13 +1668,15 @@ class ServingEngine:
         if masked:
             real = mask[:, None] & jnp.concatenate(
                 [jnp.ones_like(mask)[:, None], a["draft_ok"]],
-                1) if spec else mask[:, None]
+                1) if spec else jnp.broadcast_to(
+                    mask[:, None], (self.num_slots, self._block)
+                ) if self._block else mask[:, None]
         parts = [DecodePart(
-            a["tokens"] if spec else a["tokens"][:, None], a["positions"],
-            a.get("tables"), valid=real,
+            a["tokens"] if spec or self._block else a["tokens"][:, None],
+            a["positions"], a.get("tables"), valid=real,
             slots=(0, self.num_slots) if self._slot_leaves else None,
-            scope=(verify_rows if spec
-                   else functools.partial(part, "decode_rows")))]
+            scope=(verify_rows if spec else functools.partial(
+                part, "block_rows" if self._block else "decode_rows")))]
         if self.chunked:
             cids, clen, cdst = a["cids"], a["clen"], a["cdst"]
             parts.append(DecodePart(
@@ -1690,7 +1808,9 @@ class ServingEngine:
                     f"({max_new_tokens}) exceeds the engine's max_length "
                     f"({self.max_length})")
             if self.paged:
-                need = self.kv.blocks_needed(prompt.size, max_new_tokens)
+                need = self.kv.blocks_needed(
+                    prompt.size,
+                    self._reserved_new(prompt.size, max_new_tokens))
                 if need > self.kv.usable_blocks:
                     raise _Rejected(
                         "pool_too_small",
@@ -2085,6 +2205,11 @@ class ServingEngine:
     # -- cross-worker migration (ISSUE 18) ---------------------------------
 
     def _refuse_state_migration(self, what: str):
+        if self._block:
+            raise NotImplementedError(
+                f"{type(self._bind).__name__} cannot be served with "
+                f"{what}: a request's record carries one last token, not a "
+                f"block under denoising and the forwards it has had")
         if self._slot_leaves:
             raise NotImplementedError(
                 f"{type(self._bind).__name__} cannot be served with "
@@ -2344,7 +2469,8 @@ class ServingEngine:
         ONE step-program call, so a long prompt under the cursor engine
         costs every in-flight decode a bounded, chunk-sized bump per tick
         instead of a whole-prompt stall; a verify step commits 1..k+1
-        tokens a row for one pass of the weights."""
+        tokens a row for one pass of the weights, a block step delivers 0
+        or a block's tokens a row."""
         span = self._tracer.span
         paged, chunked, spec = self.paged, self.chunked, self.spec
         with span(_ADMIT):
@@ -2365,7 +2491,7 @@ class ServingEngine:
         self._ticks += 1
         own, chunk, clen, draft_ok = {"key": self._ticks}, None, 0, None
         if do_chunk:
-            clen = min(self.prefill_chunk, pf.req.prompt.size - pf.cursor)
+            clen = min(self.prefill_chunk, pf.end - pf.cursor)
             cpos, cslot = pf.cursor, pf.slot
         elif chunked:
             # chunk-free tick, same compiled program: contiguous writes
@@ -2401,7 +2527,7 @@ class ServingEngine:
                 rows_pos = own["positions"] = np.where(
                     self._active, self._positions,
                     self.max_length).astype(np.int32)
-            walks = [(rows_pos, self.spec_k + 1 if spec else 1)]
+            walks = [(rows_pos, self._row_tokens)]
             if chunked:      # the chunk part runs every tick, real or not
                 walks.append(([cpos], self.prefill_chunk))
             kv_walk = self._kv_walk(*walks)
@@ -2416,6 +2542,16 @@ class ServingEngine:
                 self._state_live = occ + (pf is not None)
                 self._m_state_live.set(float(self._state_live))
         drafted = int(draft_ok[self._active].sum()) if spec else 0
+        diffusion = {}
+        if self._block:
+            # what goes in: the live rows' masked positions, and the rows
+            # whose block is mask-free (their forward is the commit);
+            # ``unmasked`` and ``delivered`` follow the readback
+            live_masked = (self._blocks[self._active]
+                           == self._diffusion.mask_token_id).sum(-1)
+            diffusion = {"block": self._block,
+                         "masked_in": int(live_masked.sum()),
+                         "commits": int((live_masked == 0).sum())}
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
             sample_path=self._note_sample_path(*knobs), **kv_walk,
@@ -2423,14 +2559,16 @@ class ServingEngine:
             # streams the token-wise weights, over how many padded token
             # rows, how many of them real (live rows' tokens + the chunk's)
             weight_passes=1, pass_rows=self._pass_rows,
-            pass_tokens=occ + drafted + clen,
-            **({"drafted": int(draft_ok.sum())} if spec else {}))
+            pass_tokens=occ * (self._block or 1) + drafted + clen,
+            **({"drafted": int(draft_ok.sum())} if spec else {}),
+            **diffusion)
         chunk_span = (span("serving.chunk", slot=cslot, start=cpos,
                            tokens=clen, **state)
                       if do_chunk else contextlib.nullcontext())
-        with rows_span, chunk_span:
+        with rows_span as rows_open, chunk_span:
             if paged:
                 with span(_GROW):
+                    beyond = self._row_tokens - 1
                     for i, slot in enumerate(self._slots):
                         if slot is None:
                             continue
@@ -2441,7 +2579,7 @@ class ServingEngine:
                         # never proposed
                         self._grow_row_for_writes(
                             i, int(self._positions[i])
-                            + (int(draft_ok[i].sum()) if spec else 0))
+                            + (int(draft_ok[i].sum()) if spec else beyond))
                     if do_chunk:
                         # grow the chain to cover this chunk's real
                         # tokens; pad-tail positions fall past the chain
@@ -2457,18 +2595,35 @@ class ServingEngine:
                 chunk = (pf if do_chunk else None, cpos, clen,
                          cdst if paged else cslot)
             out = iter(self._device_step(own, chunk))
+            toks = next(out)
+            if self._block:
+                # what the forward did, on the tick's span: positions
+                # unmasked, and the tokens of the blocks that came back
+                # mask-free (delivered now, ``_advance_block``)
+                n_unmasked = next(out)
+                deliver = self._block_deliveries(toks)
+                diffusion.update(
+                    unmasked=int(n_unmasked.sum()),
+                    delivered=sum(len(d) for d in deliver.values()))
+                if rows_open is not None:
+                    rows_open.args.update(diffusion)
+                for m, n in zip(self._m_diffusion, (
+                        occ, *(diffusion[k] for k in
+                               ("unmasked", "commits", "delivered")))):
+                    m.inc(n)
         now = self._clock()
         with span(_ADVANCE):
             self._m_step_ms.observe((now - t0) * 1e3)
             self._perf_tick((now - t0) * 1e3, occ,
                             chunk_tokens=clen if do_chunk else 0)
-            toks = next(out)
             n_acc = next(out) if spec else None
             ctok = next(out) if chunked else None
             if self._model_counters:
                 self._note_model_counters(list(out))
             finished.extend(
-                self._advance_decode_spec(toks, n_acc, draft_ok, now)
+                self._advance_block(toks, deliver, now)
+                if self._block
+                else self._advance_decode_spec(toks, n_acc, draft_ok, now)
                 if spec else self._advance_decode(toks, now))
             if do_chunk:
                 finished.extend(
@@ -2531,6 +2686,107 @@ class ServingEngine:
                 finished.append(slot.rid)
                 self._retire(slot, i, reason, now)
         return finished
+
+    # -- block-diffusion scheduler (block rows) -----------------------------
+
+    def _prompt_commit(self, req: Request) -> int:
+        """The prompt tokens the cursor ingests: all of them, or for a
+        block-diffusion model the prompt's whole blocks (the rest open the
+        first block, already unmasked)."""
+        n = int(req.prompt.size)
+        return n - n % self._block if self._block else n
+
+    def _reserved_new(self, prompt_len: int, max_new_tokens: int) -> int:
+        """The new positions a request reserves: ``max_new_tokens``, or for
+        a block-diffusion model up to the end of the block the last token
+        falls in (a forward writes its block whole)."""
+        return int(max_new_tokens) + -(
+            int(prompt_len) + int(max_new_tokens)) % (self._block or 1)
+
+    def _open_block(self, i: int, given=()):
+        """Open slot ``i``'s next block at its position: ``given`` (the
+        prompt's tokens past its last whole block) and then mask tokens."""
+        slot = self._slots[i]
+        self._blocks[i] = self._diffusion.mask_token_id
+        self._blocks[i, :len(given)] = given
+        slot.forwards, slot.given = 0, len(given)
+        slot.unmasked_at = np.zeros((self._block,), np.int32)
+
+    def _block_deliveries(self, new: np.ndarray) -> Dict[int, List[int]]:
+        """Per slot whose block went in with a mask and came back
+        mask-free, the tokens it delivers now: the block's generated
+        positions, cut at the request's budget and after an EOS."""
+        mask_id = self._diffusion.mask_token_id
+        out = {}
+        for i, slot in enumerate(self._slots):
+            if (slot is None or (new[i] == mask_id).any()
+                    or not (self._blocks[i] == mask_id).any()):
+                continue
+            toks = [int(t) for t in
+                    new[i, slot.given:slot.given + slot.remaining]]
+            if self.eos_token_id in toks:
+                toks = toks[:toks.index(self.eos_token_id) + 1]
+            out[i] = toks
+        return out
+
+    def _advance_block(self, new: np.ndarray, deliver: Dict[int, List[int]],
+                       now: float) -> List[int]:
+        """Per-slot bookkeeping after a block step.  A row whose block went
+        in mask-free has COMMITTED it (that forward's K/V are final):
+        advance by a block, open the next.  Any other row had a denoising
+        forward: note which positions it unmasked and at which
+        forward-in-block; if the block came back mask-free its tokens are
+        delivered now (``deliver``) — the request's first delivery is its
+        first token — and the request retires here when they end it, its
+        commit forward being the next tick otherwise."""
+        mask_id = self._diffusion.mask_token_id
+        finished: List[int] = []
+        for i, slot in enumerate(self._slots):
+            if slot is None:
+                continue
+            was = self._blocks[i] == mask_id
+            if not was.any():
+                self._positions[i] += self._block
+                self._open_block(i)
+                continue
+            slot.forwards += 1
+            slot.unmasked_at[was & (new[i] != mask_id)] = slot.forwards
+            self._blocks[i] = new[i]
+            toks = deliver.get(i)
+            if toks is None:
+                continue
+            if slot.t_first == 0.0:
+                slot.t_first = now
+                self._note_first_token(slot.req, now)
+            self._results[slot.rid].extend(toks)
+            self._unmasked_at[slot.rid].extend(
+                int(f) for f in
+                slot.unmasked_at[slot.given:slot.given + len(toks)])
+            slot.remaining -= len(toks)
+            self._m_tokens.inc(len(toks))
+            reason = ("eos" if toks[-1] == self.eos_token_id
+                      and self.eos_token_id is not None
+                      else "max_new_tokens" if slot.remaining <= 0
+                      else "max_length" if (int(self._positions[i])
+                                            + 2 * self._block
+                                            > self.max_length) else None)
+            if reason is not None:
+                finished.append(slot.rid)
+                self._retire(slot, i, reason, now)
+        return finished
+
+    def unmask_steps(self, rid: int) -> List[int]:
+        """Beside :meth:`result`, for a block-diffusion model: per token
+        delivered so far, the forward-in-block (1 = the block's first
+        denoising forward) at which it was unmasked.  With the tokens it
+        says what each of a block's forwards was fed — at forward f the
+        positions unmasked at a forward before f, the rest masked — which is
+        what a reference needs to recompute the logits behind a token."""
+        if not self._block:
+            raise RuntimeError(
+                f"{type(self._bind).__name__} does not generate by "
+                f"diffusion over blocks: its tokens have no unmask order")
+        return list(self._unmasked_at[rid])
 
     # -- speculative-decode scheduler (verify steps) -----------------------
 
@@ -2715,7 +2971,12 @@ class ServingEngine:
                              queue_wait_ms=(now - req.t_submit) * 1e3,
                              blocked_ticks=int(req.blocked_ticks),
                              prefix_hit_tokens=int(m))
-        self._prefill = _Prefill(req, si, int(m))
+        self._prefill = _Prefill(req, si, int(m), self._prompt_commit(req))
+        if self._prefill.cursor >= self._prefill.end:
+            # a prompt shorter than a block commits nothing: it opens the
+            # first block whole
+            self._prefill = None
+            self._install(req, si, None, now)
         return []
 
     def _advance_chunk(self, pf: _Prefill, clen: int, ctok: int,
@@ -2733,7 +2994,7 @@ class ServingEngine:
             # register the now-written full blocks for prefix sharing —
             # never earlier: an unwritten block must not satisfy a lookup
             self.kv.register_prompt_upto(pf.slot, pf.req.prompt, pf.cursor)
-        if pf.cursor < pf.req.prompt.size:
+        if pf.cursor < pf.end:
             return []
         self._prefill = None
         return ([pf.req.request_id]
@@ -2745,10 +3006,13 @@ class ServingEngine:
         ch = self.prefill_chunk
         n = 0
         if self._prefill is not None:
-            n += -(-(self._prefill.req.prompt.size
-                     - self._prefill.cursor) // ch)
+            n += -(-(self._prefill.end - self._prefill.cursor) // ch)
+        # every tick walks the whole queue (thousands against a backlog):
+        # a block-diffusion model's whole blocks, else the size as it is
+        blk = self._block
         for req in itertools.chain(self._resume_q, self._queue):
-            n += -(-req.prompt.size // ch)
+            size = req.prompt.size
+            n += -(-(size - size % blk if blk else size) // ch)
         return n
 
     def drain(self) -> List[Tuple[int, List[int]]]:
@@ -2880,7 +3144,9 @@ class ServingEngine:
         # q shapes per step mode: the decode rows (or the spec-verify
         # window), plus the chunked-prefill q chunk when armed
         shapes = [(self.num_slots, self.spec_k + 1, "spec_verify")
-                  if self.spec else (self.num_slots, 1, "decode")]
+                  if self.spec
+                  else (self.num_slots, self._block, "block_decode")
+                  if self._block else (self.num_slots, 1, "decode")]
         if self.chunked:
             shapes.append((1, self.prefill_chunk, "chunked_prefill"))
         specs = []
@@ -3478,7 +3744,9 @@ class ServingEngine:
         counted every tick and logged once an episode."""
         while True:
             got = self.kv.admit(si, req.prompt, req.prompt.size,
-                                req.max_new_tokens, chunked=self.chunked)
+                                self._reserved_new(req.prompt.size,
+                                                   req.max_new_tokens),
+                                chunked=self.chunked)
             if got is not None or not self._try_preempt(
                     priority=req.priority, rid=req.request_id,
                     blocked_ticks=req.blocked_ticks):
@@ -3599,6 +3867,17 @@ class ServingEngine:
             first = ri.last_token
             slot = _Slot(req.request_id, ri.remaining, t_first=ri.t_first,
                          prompt=ri.orig.prompt, req=ri.orig)
+        elif self._block:
+            # block diffusion: the prompt's whole blocks are committed, the
+            # rest open the first block; no token yet — the first comes
+            # with the first block's delivery (``_advance_block``)
+            slot = _Slot(req.request_id, req.max_new_tokens,
+                         prompt=req.prompt, req=req)
+            at = self._prompt_commit(req)
+            self._seat(si, slot, self.pad_token_id, at, req.sampling)
+            self._open_block(si, req.prompt[at:])
+            self._unmasked_at[req.request_id] = []
+            return False
         else:
             first = int(tok)
             slot = _Slot(req.request_id, req.max_new_tokens - 1,
@@ -3609,15 +3888,22 @@ class ServingEngine:
             return False
         self._results[req.request_id].append(first)
         self._m_tokens.inc()
-        self._m_ttft.observe((now - req.t_submit) * 1e3)
-        if self._perf is not None:
-            self._perf.on_ttft((now - req.t_submit) * 1e3)
-        self._rlog.event(req.uid, "first_token", engine=self._eid,
-                         ttft_ms=(now - req.t_submit) * 1e3)
+        self._note_first_token(req, now)
         reason = self._finish_reason(first, slot, si)
         if reason is not None:
             self._retire(slot, si, reason, now)
         return reason is not None
+
+    def _note_first_token(self, req: Request, now: float):
+        """TTFT, where the request's first token reaches it: its last
+        chunk's sampled token, or a block-diffusion model's first
+        delivery."""
+        ttft = (now - req.t_submit) * 1e3
+        self._m_ttft.observe(ttft)
+        if self._perf is not None:
+            self._perf.on_ttft(ttft)
+        self._rlog.event(req.uid, "first_token", engine=self._eid,
+                         ttft_ms=ttft)
 
     def _seat(self, si: int, slot: _Slot, token: int, position: int,
               sampling: SamplingParams):
@@ -3631,6 +3917,12 @@ class ServingEngine:
         self._temps[si] = sampling.temperature
         self._topk[si] = sampling.top_k
         self._topp[si] = sampling.top_p
+        if self._block:
+            d = self._diffusion
+            self._unmask_n[si] = d.static_count(sampling.unmask_strategy)
+            self._unmask_thr[si] = (d.threshold
+                                    if sampling.unmask_threshold is None
+                                    else sampling.unmask_threshold)
         if self.paged:
             self._tables[si] = self.kv.table_row(si, self.max_blocks)
 
@@ -3673,3 +3965,7 @@ class ServingEngine:
         self._temps[i] = 0.0
         self._topk[i] = 0
         self._topp[i] = 1.0
+        if self._block:
+            self._blocks[i] = self._diffusion.mask_token_id
+            self._unmask_n[i] = 0
+            self._unmask_thr[i] = 1.0

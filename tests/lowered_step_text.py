@@ -240,6 +240,25 @@ def cell_engines(root):
     pt.flags.set_flags({"perf_model": "off"})
     yield cell, ServingEngine(model, seed=0, **kw), None
     pt.flags.set_flags({"perf_model": "on"})
+    del model
+
+    # the fifth cell, on a checkout that has it: two of its 48 layers (all
+    # alike), every held expert
+    cell = "sdar-30b-a3b-ep8.block-decode-saturated"
+    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
+                                       cell + ".json")):
+        return
+    from benchmark.harness import serve_sdar
+    from paddle_tpu.models.sdar import SdarMoeForCausalLM
+    kw = dict(cell_file("workloads", cell)["engine"], num_blocks=129)
+    cfg = dict(cell_file("configs", "sdar-30b-a3b-ep8"), num_hidden_layers=2)
+    with nn.abstract_parameters():
+        model = SdarMoeForCausalLM(
+            serve_sdar.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    yield cell, ServingEngine(model, seed=0, **kw), None
+    pt.flags.set_flags({"perf_model": "on"})
 
 
 def main(root):

@@ -35,7 +35,8 @@ HKV, D, BLOCK = 8, 128, 128
 # cache dtype[, head size]) — the decode rows, the prompt chunk and a wave's
 # rows of the Mistral cells, the Trinity cell's window layers, an int8 pool,
 # and the LFM2 cell's six K/V layers at head size 64: half a lane tile, so
-# the body slices a group's (keys, Hkv·D) buffer at 64-lane offsets
+# the body slices a group's (keys, Hkv·D) buffer at 64-lane offsets (the
+# SDAR cell's block-masked calls have a test of their own below)
 PAGED = {
     "mistral-rows": (96, 1, 32, 16, 769, 32, None, jnp.bfloat16),
     "mistral-chunk": (1, 256, 32, 16, 385, 32, None, jnp.bfloat16),
@@ -91,6 +92,28 @@ def test_paged_kernel_compiles_for_v5e(one_chip, name):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+@pytest.mark.parametrize("rows, s", [(96, 4), (1, 256)],
+                         ids=["sdar-block-rows", "sdar-chunk"])
+def test_block_masked_kernel_compiles_for_v5e(one_chip, rows, s):
+    """The body under the block-causal mask at the SDAR cell's geometry:
+    4 K/V heads of 128 (512 lanes a key), a GQA group of 8 (q tiles of 8
+    positions), a row's block of 4 and a 256-token chunk, all 48 layers."""
+    hkv, hq, layers, blocks, cols = 4, 32, 48, 321, 32
+    args = [_spec(one_chip, (rows, s, hq, D), jnp.bfloat16),
+            _spec(one_chip, (layers, 2, blocks, BLOCK, hkv * D),
+                  jnp.bfloat16),
+            _spec(one_chip, (rows,), jnp.int32),
+            _spec(one_chip, (rows, cols), jnp.int32)]
+
+    def call(q, pool, pos, tables):
+        return paged_decode_attention_pallas(q, pool, layers - 1, pos,
+                                             tables, block=4)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_contiguous_kernel_compiles_for_v5e(one_chip):
     q = _spec(one_chip, (8, 1, 32, D), jnp.bfloat16)
     kv = _spec(one_chip, (8, 4096, HKV, D), jnp.bfloat16)
@@ -105,7 +128,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # cell: expert layers at the cut depth
 MIXED = {"mistral-7b.chat-open": 0,
          "trinity-large-ep8.longtail-saturated": 1,
-         "lfm2-8b-a1b-ep2.decode-wide-saturated": 2}
+         "lfm2-8b-a1b-ep2.decode-wide-saturated": 2,
+         "sdar-30b-a3b-ep8.block-decode-saturated": 2}
+# the name of the rows part's attention kernel, where it is not the decode
+# rows': a block-diffusion model's rows are blocks
+ROWS_KERNEL = {"sdar-30b-a3b-ep8.block-decode-saturated":
+               "_step_impl_block_rows_flash_decode"}
 
 
 @pytest.fixture(scope="module")
@@ -140,7 +168,7 @@ def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell):
                 and k != "_step_impl_token_pass_moe_experts"], calls
     # attention stays a part at a time, under the part's own name
     assert {k for k in calls if "flash_decode" in k} == {
-        "_step_impl_decode_rows_flash_decode",
+        ROWS_KERNEL.get(cell, "_step_impl_decode_rows_flash_decode"),
         "_step_impl_prompt_chunk_flash_decode"}
 
 
