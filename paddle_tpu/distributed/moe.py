@@ -441,10 +441,18 @@ class HeldExpertsMoE(Layer):
 
     The product is grouped: the ``T·k`` (token, expert) pairs are sorted by
     held expert (pairs of absent experts sort behind every group, where no
-    product is taken of them), their tokens gathered once, and one grouped
-    matmul a projection (:func:`_grouped_matmul_fn`) applies each expert's
-    matrices to its own contiguous run of rows — work and weight traffic
-    follow the pairs actually routed here, and no pair is ever dropped."""
+    product is taken of them), their tokens' rows gathered once IN, and one
+    grouped matmul a projection (:func:`_grouped_matmul_fn`) applies each
+    expert's matrices to its own contiguous run of rows — work and weight
+    traffic follow the pairs actually routed here, and no pair is ever
+    dropped.  The result's rows are gathered once BACK into (choice, token)
+    order and summed over the ``k`` choices in float32.  Nothing is
+    scattered: the group sizes are a count over a one-hot of the pairs'
+    slots, and a pair's place in the sorted order (the stable sort's
+    inverse) is its group's start plus its running count within its slot —
+    a TPU scatter goes an update at a time, a gather of the same rows costs
+    a quarter.  Counted as ``ops.kernel_path{op="moe_combine",
+    path="gather_sum"}``."""
 
     def __init__(self, hidden_size: int, intermediate_size: int,
                  num_experts: int, top_k: int,
@@ -483,32 +491,46 @@ class HeldExpertsMoE(Layer):
         t, k, n = xt.shape[0], idx.shape[1], hi - lo
         with jax.named_scope("ffn.route"):
             # global expert id -> slot in the held stack, n where absent
-            slot = jnp.where((idx >= lo) & (idx < hi), idx - lo,
-                             n).reshape(-1)                      # (T·k,)
-            real = t * k
+            slot = jnp.where((idx >= lo) & (idx < hi), idx - lo, n)  # (T, k)
+            real = t
             if valid is not None:
-                ok = jnp.asarray(valid).reshape(-1)
-                slot = jnp.where(jnp.repeat(ok, k), slot, n)
-                real = ok.sum(dtype=jnp.int32) * k
+                ok = jnp.asarray(valid).reshape(-1, 1)
+                slot = jnp.where(ok, slot, n)
+                real = ok.sum(dtype=jnp.int32)
+            held = slot < n
+            slot = slot.reshape(-1)                              # (T·k,)
+            # sorted by held expert: order[i] is the pair at place i,
+            # dest[p] the place of pair p = t·k + j — the stable sort's
+            # inverse, by counting: the pairs of the slots before its own,
+            # plus the pairs of its own slot up to itself
             order = jnp.argsort(slot, stable=True)
-            group_sizes = jnp.zeros((n + 1,), jnp.int32).at[slot].add(1)[:n]
-            token = order // k
+            onehot = slot[:, None] == jnp.arange(n + 1)          # (T·k, n+1)
+            counts = onehot.sum(0, dtype=jnp.int32)
+            before = jnp.cumsum(counts) - counts
+            upto = jnp.cumsum(onehot, 0, dtype=jnp.int32)
+            dest = jnp.where(onehot, before + upto - 1, 0).sum(1)
+            group_sizes = counts[:n]
             sink = getattr(_LOAD, "sink", None)
             if sink is not None:
+                # pairs a held expert, then the REAL tokens' pairs held
+                # elsewhere (padding sorts to slot n too, and counts nowhere)
                 sink.append(jnp.concatenate(
-                    [group_sizes, (real - group_sizes.sum())[None]]))
+                    [group_sizes, (real * k - group_sizes.sum())[None]]))
         with jax.named_scope("ffn.experts"):
             d, f = self.gate_proj.shape[1:]
             into, out_of = (_grouped_matmul_fn(t * k, d, f),
                             _grouped_matmul_fn(t * k, f, d))
-            xs = xt[token]                                       # (T·k, D)
+            _dispatch.count_kernel_path("moe_combine", "gather_sum")
+            xs = xt[order // k]                                  # (T·k, D)
             g = into(xs, self.gate_proj, group_sizes)
             u = into(xs, self.up_proj, group_sizes)
             ys = out_of(F.swiglu(g, u), self.down_proj, group_sizes)
-            # rows behind the last group belong to absent experts: their
-            # weight is zero, whatever the product left there
-            held = (slot[order] < n)[:, None]
-            ys = jnp.where(held, ys.astype(jnp.float32)
-                           * w.reshape(-1)[order][:, None], 0.0)
-            out = jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
+            # back in (choice, token) order — the k choices are k slabs of
+            # whole (T, D) tiles, so their sum is plain adds — weighed and
+            # summed.  The where stays: the rows of absent experts lie
+            # behind the last group, where the product leaves whatever its
+            # output buffer held (a NaN times a zero weight is a NaN)
+            back = ys[dest.reshape(t, k).T.reshape(-1)].reshape(k, t, -1)
+            out = jnp.where(held.T[..., None], back.astype(jnp.float32)
+                            * w.T[..., None], 0.0).sum(0)
         return out.astype(x.dtype).reshape(shape)

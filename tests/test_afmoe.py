@@ -27,6 +27,7 @@ from paddle_tpu.ops.pallas.decode_attention import (
     decode_attention_pallas, paged_decode_attention_pallas)
 from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
 from paddle_tpu.serving import ServingEngine
+from paddle_tpu.static_analysis.core import iter_eqns
 
 # the benchmark's configuration keys of the tiny model, as its files hold
 # them (num_experts is the number HELD; the router keeps num_experts_routed)
@@ -365,6 +366,132 @@ def test_padding_rows_read_no_expert_and_count_nowhere():
     real = jnp.asarray([0, 1, 3])
     assert float(jnp.abs(layer.experts(x, idx, w)[real]
                          - out[real]).max()) < 1e-6
+
+
+def _held_layer(k, held, num_experts=16, d=32, f=16, seed=0):
+    pt.seed(seed)
+    return moe.HeldExpertsMoE(d, f, num_experts, k, held=held)
+
+
+def _per_pair(layer, x, idx, w, valid=None):
+    """The plain reference: a loop over tokens and choices in float32. Also
+    the load vector: pairs of real tokens a held expert, then held
+    elsewhere."""
+    lo, hi = layer.held
+    gate, up, down = (np.asarray(m, np.float32) for m in (
+        layer.gate_proj, layer.up_proj, layer.down_proj))
+    x, idx, w = np.asarray(x, np.float32), np.asarray(idx), np.asarray(w)
+    out = np.zeros_like(x)
+    load = np.zeros((hi - lo + 1,), np.int64)
+    for t in range(x.shape[0]):
+        if valid is not None and not bool(valid[t]):
+            continue
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j]) - lo
+            if not 0 <= e < hi - lo:
+                load[-1] += 1
+                continue
+            load[e] += 1
+            g, u = x[t] @ gate[e], x[t] @ up[e]
+            out[t] += w[t, j] * ((g / (1 + np.exp(-g)) * u) @ down[e])
+    return out, load
+
+
+def _routing(case, t=24):
+    """(k, held, idx (t, k), valid) of a named case."""
+    rng = np.random.default_rng(7)
+
+    def top(k, num_experts=16):
+        return np.stack([rng.permutation(num_experts)[:k] for _ in range(t)])
+    if case in ("k1", "k4", "k8"):
+        k = int(case[1:])
+        return k, (0, 8), top(k), None
+    if case == "all_to_one_held":
+        return 2, (0, 8), np.full((t, 2), 5), None
+    if case == "none_held":
+        return 4, (0, 8), 8 + top(4, 8), None
+    if case == "padding_in_the_middle":
+        valid = np.ones((t,), bool)
+        valid[[0, 5, 6, 7, 13, t - 1]] = False
+        return 4, (0, 8), top(4), valid
+    assert case == "held_from_6"
+    return 4, (6, 11), top(4), None
+
+
+@pytest.mark.parametrize("case", [
+    "k1", "k4", "k8", "all_to_one_held", "none_held",
+    "padding_in_the_middle", "held_from_6"])
+def test_held_experts_match_the_per_pair_loop(case):
+    k, held, idx, valid = _routing(case)
+    layer = _held_layer(k, held)
+    x = jax.random.normal(jax.random.key(11), (idx.shape[0], 32))
+    w = jax.random.uniform(jax.random.key(12), idx.shape, minval=0.1)
+    with moe.expert_load() as load:
+        got = layer(x, jnp.asarray(idx, jnp.int32), w,
+                    valid=None if valid is None else jnp.asarray(valid))
+    want, pairs = _per_pair(layer, x, idx, w, valid)
+    assert float(np.abs(np.asarray(got) - want).max()) < 1e-5
+    assert np.asarray(load[0]).tolist() == pairs.tolist()
+    if valid is not None:
+        assert float(np.abs(np.asarray(got)[~valid]).max()) == 0.0
+
+
+def test_held_experts_scatter_nothing():
+    """Group sizes by a count, the result by a gather and a sum over k: the
+    layer's jaxpr holds no scatter of any kind, and says so in
+    ``ops.kernel_path`` once a layer call."""
+    k, held, idx, valid = _routing("padding_in_the_middle")
+    layer = _held_layer(k, held)
+    x = jnp.zeros((idx.shape[0], 32))
+    w = jnp.ones(idx.shape, jnp.float32)
+
+    def two_layer_calls(x, idx, w, valid):
+        with moe.expert_load() as load:
+            x = layer(x, idx, w, valid=valid)
+            return layer(x, idx, w), load
+    names = {eqn.primitive.name for _, eqn in iter_eqns(
+        jax.make_jaxpr(two_layer_calls)(
+            x, jnp.asarray(idx, jnp.int32), w, jnp.asarray(valid)).jaxpr)}
+    assert not {n for n in names if "scatter" in n}, names
+    assert {"sort", "gather", "cumsum", "ragged_dot_general"} <= names
+    counted = {(r["labels"]["op"], r["labels"]["path"]): r["value"]
+               for r in obs.snapshot()["ops.kernel_path"]["series"]}
+    assert counted["moe_combine", "gather_sum"] == 2
+
+
+def test_rows_behind_the_last_group_never_reach_the_sum(monkeypatch):
+    """Pallas on (interpreted): every grouped product's rows behind the last
+    group are poisoned with NaN — the kernel leaves them as its output
+    buffer was — and the result stays finite and the per-pair loop's."""
+    k, held, idx, valid = _routing("padding_in_the_middle")
+    layer = _held_layer(k, held, d=128, f=128)
+    x = jax.random.normal(jax.random.key(13), (idx.shape[0], 128))
+    w = jax.random.uniform(jax.random.key(14), idx.shape, minval=0.1)
+    real_fn, poisoned = moe._grouped_matmul_fn, []
+
+    def poisoning(rows, kk, nn, pallas=None):
+        grouped = real_fn(rows, kk, nn, pallas)
+
+        def product(xs, wts, group_sizes):
+            out = grouped(xs, wts, group_sizes)
+            behind = jnp.arange(rows)[:, None] >= group_sizes.sum()
+            poisoned.append(behind.sum())
+            return jnp.where(behind, jnp.nan, out)
+        return product
+    monkeypatch.setattr(moe, "_grouped_matmul_fn", poisoning)
+    old = flags.flag("pallas_interpret")
+    flags.set_flags({"pallas_interpret": True})
+    try:
+        got = layer(x, jnp.asarray(idx, jnp.int32), w, valid=jnp.asarray(valid))
+    finally:
+        flags.set_flags({"pallas_interpret": old})
+    assert len(poisoned) == 3 and min(int(p) for p in poisoned) > 0
+    assert bool(jnp.isfinite(got).all())
+    want, _ = _per_pair(layer, x, idx, w, valid)
+    assert float(np.abs(np.asarray(got) - want).max()) < 1e-4
+    paths = {(r["labels"]["op"], r["labels"]["path"])
+             for r in obs.snapshot()["ops.kernel_path"]["series"]}
+    assert ("moe_experts", "pallas_gmm") in paths
 
 
 @pytest.mark.parametrize("sizes,rows", [([5, 0, 20, 10], 48),
