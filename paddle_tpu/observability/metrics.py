@@ -27,6 +27,7 @@ underscores and prefixes ``paddle_tpu_``; counters gain the
 
 from __future__ import annotations
 
+import bisect
 import json
 import math
 import re
@@ -132,6 +133,16 @@ class Histogram(_Child):
             self._counts[i] += 1
             self._sum += v
             self._count += 1
+
+    def observe_many(self, values: Sequence[float]) -> None:
+        """``observe`` for each of ``values``, under the lock once."""
+        bounds = self._family.buckets
+        slots = [bisect.bisect_left(bounds, v) for v in values]
+        with self._lock:
+            for i in slots:
+                self._counts[i] += 1
+            self._sum += sum(values)
+            self._count += len(slots)
 
     @property
     def count(self) -> int:

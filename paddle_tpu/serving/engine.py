@@ -184,6 +184,7 @@ import functools
 import hashlib
 import itertools
 import json
+import math
 import time
 from collections import deque
 from typing import (Callable, Deque, Dict, List, NamedTuple, Optional,
@@ -206,7 +207,8 @@ from ..ops import _dispatch as _disp
 from .drafter import DraftModelDrafter, NgramDrafter
 from .kv_cache import BlockManager, init_paged_kv_cache
 
-__all__ = ["ServingEngine", "SamplingParams", "Request", "TICK_PHASES"]
+__all__ = ["ServingEngine", "SamplingParams", "Request", "TICK_PHASES",
+           "TICK_COSTS"]
 
 #: The phases of one scheduler tick: the names of the non-overlapping
 #: child spans that tile ``serving.step`` in the tick and in the prefill
@@ -221,14 +223,38 @@ __all__ = ["ServingEngine", "SamplingParams", "Request", "TICK_PHASES"]
 #: - ``serving.build_inputs``: the chunk operand's assembly and the
 #:   host->device uploads (spec mode: also the draft, which builds the
 #:   verify window, in a span of its own before ``serving.grow``);
-#: - ``serving.dispatch``: the call of the step / prefill program, which
-#:   returns when the program is enqueued;
+#: - ``serving.dispatch`` (``leaves=``: the leaves of ``params`` and
+#:   ``cache`` the call flattens): the call of the step / prefill program
+#:   and nothing else, which returns when the program is enqueued;
 #: - ``serving.readback``: the token fetch, the tick's one sync;
-#: - ``serving.advance``: cost-model stamp, per-slot advance, chunk
-#:   accounting, retirement, queued demotions.
+#: - ``serving.advance``: per-slot advance, chunk accounting, retirement,
+#:   queued demotions.
 TICK_PHASES = ("serving.admit", "serving.grow", "serving.build_inputs",
                "serving.dispatch", "serving.readback", "serving.advance")
 _ADMIT, _GROW, _BUILD, _DISPATCH, _READBACK, _ADVANCE = TICK_PHASES
+#: Two costs inside those phases, each a span of its own so that host
+#: time can be put to it (benchmark/harness/tick_host.py reads the self
+#: time of every name of both tuples); a reader that knows the six
+#: phases alone sees through them:
+#:
+#: - ``serving.upload`` (``operands=``, ``bytes=``): the walk of a
+#:   program's operand table, one host->device transfer an operand
+#:   (``_upload``), inside ``serving.build_inputs`` of a tick and of a
+#:   wave alike; what is left of ``serving.build_inputs`` around it is
+#:   assembly on the host;
+#: - ``serving.account``: host work that exists only to feed a span
+#:   argument, a counter, a gauge, a histogram or the cost model — what
+#:   the program's own measurement costs with the profiler off.  At most
+#:   twice a tick: before the device seam, inside ``serving.build_inputs``
+#:   (``_kv_walk``, ``_note_sample_path``, the chunk-queue depth, the
+#:   state gauges, a block step's counts: the arguments of
+#:   ``serving.decode``), and after it, inside ``serving.advance`` (the
+#:   step-latency histogram, ``_perf_tick``, ``_note_model_counters``,
+#:   the ``serving.diffusion.*`` counters); once a wave, before
+#:   ``serving.prefill`` opens (its ``sample_path``, ``kv_blocks``,
+#:   ``kv_walk``).
+TICK_COSTS = ("serving.upload", "serving.account")
+_UPLOAD, _ACCOUNT = TICK_COSTS
 
 # The two families of device program, as ``ops._dispatch.program_part``
 # leads the names of the Pallas kernels built inside their parts
@@ -261,6 +287,23 @@ class _Operand(NamedTuple):
     src: object
     put: Callable = jnp.asarray    # the upload, one call an operand
     fill: int = 0              # the abstract trace's value (``_lint_args``)
+
+
+def _table_bytes(table) -> Tuple[int, int]:
+    """The bytes one walk of an operand table uploads, as (those of the
+    operands of fixed shape, those a token of the prefill wave's bucket
+    adds: the operands with a ``None`` in their shape)."""
+    fixed = a_token = 0
+    for o in table:
+        # a typed PRNG key's dtype is no numpy dtype, and states its size
+        dtype = (o.dtype if jnp.issubdtype(o.dtype, jax.dtypes.prng_key)
+                 else np.dtype(o.dtype))
+        n = dtype.itemsize * math.prod(d for d in o.shape if d is not None)
+        if None in o.shape:
+            a_token += n
+        else:
+            fixed += n
+    return fixed, a_token
 
 
 @dataclasses.dataclass(frozen=True)
@@ -771,6 +814,13 @@ class ServingEngine:
         # match) and makes the step's sharding contract the same one
         # mesh_preflight lints abstractly.
         self._step_table, self._prefill_table = self._operand_tables()
+        # what the spans at the device seam state, computed once: the
+        # bytes a table's walk uploads (``serving.upload``) and the leaves
+        # a program call flattens (``serving.dispatch``)
+        self._step_bytes = _table_bytes(self._step_table)
+        self._prefill_bytes = _table_bytes(self._prefill_table or ())
+        self._program_leaves = len(jax.tree_util.tree_leaves(
+            (self._params, self._cache)))
         self._step_outputs = (
             ("tokens",) + ("n_acc",) * self.spec
             + ("n_unmasked",) * bool(self._block)
@@ -866,6 +916,9 @@ class ServingEngine:
         self._slots: List[Optional[_Slot]] = [None] * s
         self._prefill: Optional[_Prefill] = None   # chunked-mode cursor
         self._queue: Deque[Request] = deque()
+        # the chunks the two queues' prompts will take under the cursor,
+        # kept where a request enters or leaves one (``_pending_chunks``)
+        self._queued_chunks = 0
         # preempted work awaiting resume, each kept sorted by
         # (-priority, request id) so resume order is deterministic
         self._swap_resume: List[_SwapResume] = []
@@ -1826,7 +1879,7 @@ class ServingEngine:
         self._next_rid += 1
         self._results[rid] = []
         self._uids[rid] = uid
-        self._queue.append(Request(
+        self._enqueue(self._queue, Request(
             rid, prompt, int(max_new_tokens),
             sampling or SamplingParams(),
             t_submit=self._clock(), uid=uid,
@@ -2108,7 +2161,7 @@ class ServingEngine:
     def _push_resume_q(self, req: Request):
         # re-order IN PLACE: admission may hold a reference to this
         # deque across a preemption that pushes here (the retry loop)
-        self._resume_q.append(req)
+        self._enqueue(self._resume_q, req)
         if len(self._resume_q) > 1:
             items = sorted(self._resume_q,
                            key=lambda r: (-r.priority, r.request_id))
@@ -2365,7 +2418,7 @@ class ServingEngine:
         for q in (self._queue, self._resume_q):
             for req in q:
                 if req.request_id == rid:
-                    q.remove(req)
+                    self._dequeue(q, req)
                     self._finish_cancel(
                         req if req.resume is None else req.resume.orig)
                     return True
@@ -2478,8 +2531,6 @@ class ServingEngine:
             occ = int(self._active.sum())
             self._set_occupancy(occ)
             pf = self._prefill
-            if chunked:
-                self._m_chunk_queue.observe(self._pending_chunks())
             # decode-priority policy: while decodes are active, pending
             # chunks run on alternate ticks only (odd _ticks), halving the
             # prompt-ingest rate to shave the mixed-step TPOT bump
@@ -2513,11 +2564,6 @@ class ServingEngine:
                     [self._tokens[:, None], drafts], axis=1)
                 own["draft_ok"] = draft_ok
         t0 = self._clock()
-        knobs = [(self._temps, self._topk, self._topp)]
-        if do_chunk:         # the chunk's one row, as build_inputs fills it
-            sp = pf.req.sampling
-            knobs.append((np.float32(sp.temperature), np.int32(sp.top_k),
-                          np.float32(sp.top_p)))
         with span(_BUILD):
             rows_pos = self._positions
             if chunked and not paged:
@@ -2527,10 +2573,6 @@ class ServingEngine:
                 rows_pos = own["positions"] = np.where(
                     self._active, self._positions,
                     self.max_length).astype(np.int32)
-            walks = [(rows_pos, self._row_tokens)]
-            if chunked:      # the chunk part runs every tick, real or not
-                walks.append(([cpos], self.prefill_chunk))
-            kv_walk = self._kv_walk(*walks)
             state = {}
             if self._slot_leaves:
                 # the state row the chunk part addresses: the cursor's
@@ -2538,23 +2580,40 @@ class ServingEngine:
                 # there); the rows part advances the decoding rows alone
                 own["cslot"] = cslot if do_chunk else self.num_slots
                 state = {"state": "carried" if cpos else "fresh"}
-                kv_walk["state_rows"] = occ
-                self._state_live = occ + (pf is not None)
-                self._m_state_live.set(float(self._state_live))
-        drafted = int(draft_ok[self._active].sum()) if spec else 0
-        diffusion = {}
-        if self._block:
-            # what goes in: the live rows' masked positions, and the rows
-            # whose block is mask-free (their forward is the commit);
-            # ``unmasked`` and ``delivered`` follow the readback
-            live_masked = (self._blocks[self._active]
-                           == self._diffusion.mask_token_id).sum(-1)
-            diffusion = {"block": self._block,
-                         "masked_in": int(live_masked.sum()),
-                         "commits": int((live_masked == 0).sum())}
+            with span(_ACCOUNT):
+                # the rows span's arguments, the gauges and histograms of
+                # what goes in: nothing here is read by the device program
+                if chunked:
+                    self._m_chunk_queue.observe(self._pending_chunks())
+                knobs = [(self._temps, self._topk, self._topp)]
+                if do_chunk:  # the chunk's one row, as _device_step fills it
+                    sp = pf.req.sampling
+                    knobs.append((np.float32(sp.temperature),
+                                  np.int32(sp.top_k), np.float32(sp.top_p)))
+                walks = [(rows_pos, self._row_tokens)]
+                if chunked:  # the chunk part runs every tick, real or not
+                    walks.append(([cpos], self.prefill_chunk))
+                facts = dict(sample_path=self._note_sample_path(*knobs),
+                             **self._kv_walk(*walks))
+                if self._slot_leaves:
+                    facts["state_rows"] = occ
+                    self._state_live = occ + (pf is not None)
+                    self._m_state_live.set(float(self._state_live))
+                drafted = int(draft_ok[self._active].sum()) if spec else 0
+                diffusion = {}
+                if self._block:
+                    # what goes in: the live rows' masked positions, and
+                    # the rows whose block is mask-free (their forward is
+                    # the commit); ``unmasked`` and ``delivered`` follow
+                    # the readback
+                    live_masked = (self._blocks[self._active]
+                                   == self._diffusion.mask_token_id).sum(-1)
+                    diffusion = {"block": self._block,
+                                 "masked_in": int(live_masked.sum()),
+                                 "commits": int((live_masked == 0).sum())}
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
-            sample_path=self._note_sample_path(*knobs), **kv_walk,
+            **facts,
             # the program's one ``decode_parts`` call: how often the tick
             # streams the token-wise weights, over how many padded token
             # rows, how many of them real (live rows' tokens + the chunk's)
@@ -2607,19 +2666,23 @@ class ServingEngine:
                     delivered=sum(len(d) for d in deliver.values()))
                 if rows_open is not None:
                     rows_open.args.update(diffusion)
-                for m, n in zip(self._m_diffusion, (
-                        occ, *(diffusion[k] for k in
-                               ("unmasked", "commits", "delivered")))):
-                    m.inc(n)
         now = self._clock()
         with span(_ADVANCE):
-            self._m_step_ms.observe((now - t0) * 1e3)
-            self._perf_tick((now - t0) * 1e3, occ,
-                            chunk_tokens=clen if do_chunk else 0)
             n_acc = next(out) if spec else None
             ctok = next(out) if chunked else None
-            if self._model_counters:
-                self._note_model_counters(list(out))
+            with span(_ACCOUNT):
+                # what the tick did, for the registry and the cost model
+                # (positions are still pre-advance: the depths it read)
+                self._m_step_ms.observe((now - t0) * 1e3)
+                self._perf_tick((now - t0) * 1e3, occ,
+                                chunk_tokens=clen if do_chunk else 0)
+                if self._model_counters:
+                    self._note_model_counters(list(out))
+                if self._block:
+                    for m, n in zip(self._m_diffusion, (
+                            occ, *(diffusion[k] for k in
+                                   ("unmasked", "commits", "delivered")))):
+                        m.inc(n)
             finished.extend(
                 self._advance_block(toks, deliver, now)
                 if self._block
@@ -2631,14 +2694,20 @@ class ServingEngine:
             self._apply_demotions()
         return finished
 
-    def _upload(self, table, own) -> List:
-        """The upload of ``serving.build_inputs``, for either program:
-        one walk of its operand table, one host->device transfer an
-        operand, each strongly typed as the table says (``jnp.int32`` chunk
-        scalars, a typed key folded from the tick's number).  ROADMAP S3
-        (one packed upload) is a change to this function."""
-        return [put(src if src.__class__ is np.ndarray else own[src])
-                for _, _, _, src, put, _ in table]
+    def _upload(self, table, own, bucket: int = 0) -> List:
+        """The upload of ``serving.build_inputs``, for either program
+        (``serving.upload``): one walk of its operand table, one
+        host->device transfer an operand, each strongly typed as the
+        table says (``jnp.int32`` chunk scalars, a typed key folded from
+        the tick's number); their bytes are known from the table
+        (``_table_bytes``) and a wave's ``bucket``.  ROADMAP S3 (one
+        packed upload) is a change to this function."""
+        fixed, a_token = (self._step_bytes if table is self._step_table
+                          else self._prefill_bytes)
+        with self._tracer.span(_UPLOAD, operands=len(table),
+                               bytes=fixed + a_token * bucket):
+            return [put(src if src.__class__ is np.ndarray else own[src])
+                    for _, _, _, src, put, _ in table]
 
     def _device_step(self, own, chunk) -> List[np.ndarray]:
         """The tick's device seam: build and upload the step program's
@@ -2663,7 +2732,7 @@ class ServingEngine:
                     ctopk=np.full((1,), sp.top_k, np.int32),
                     ctopp=np.full((1,), sp.top_p, np.float32))
             args = self._upload(self._step_table, own)
-        with span(_DISPATCH):
+        with span(_DISPATCH, leaves=self._program_leaves):
             *out, self._cache = self._step_fn(self._params, self._cache,
                                               *args)
         with span(_READBACK):
@@ -2956,7 +3025,7 @@ class ServingEngine:
             return []
         # remove by IDENTITY: a preemption inside the retry loop may
         # have re-ordered the resume queue under us
-        src.remove(req)
+        self._dequeue(src, req)
         if self.quantized and not self.paged:
             # chunked admission streams into a reused row: drop the
             # previous tenant's granule scales before the first chunk
@@ -3000,19 +3069,29 @@ class ServingEngine:
         return ([pf.req.request_id]
                 if self._install(pf.req, pf.slot, ctok, now) else [])
 
+    def _chunks_of(self, req: Request) -> int:
+        """The chunks the cursor takes a queued prompt in."""
+        return -(-self._prompt_commit(req) // self.prefill_chunk)
+
+    def _enqueue(self, q: Deque[Request], req: Request):
+        """Append ``req`` to the submit or the resume queue ``q``."""
+        q.append(req)
+        self._queued_chunks += self._chunks_of(req)
+
+    def _dequeue(self, q: Deque[Request], req: Request):
+        """Take ``req`` out of the submit or the resume queue ``q``."""
+        q.remove(req)
+        self._queued_chunks -= self._chunks_of(req)
+
     def _pending_chunks(self) -> int:
         """Chunks still to ingest: the active prompt's remainder plus
-        every queued prompt's worth (the chunk-queue depth histogram)."""
-        ch = self.prefill_chunk
-        n = 0
+        every queued prompt's worth (the chunk-queue depth histogram),
+        the latter from the count kept at the queues' ends: a walk of a
+        backlog of thousands is a percent of a tick."""
+        n = self._queued_chunks
         if self._prefill is not None:
-            n += -(-(self._prefill.end - self._prefill.cursor) // ch)
-        # every tick walks the whole queue (thousands against a backlog):
-        # a block-diffusion model's whole blocks, else the size as it is
-        blk = self._block
-        for req in itertools.chain(self._resume_q, self._queue):
-            size = req.prompt.size
-            n += -(-(size - size % blk if blk else size) // ch)
+            n += -(-(self._prefill.end - self._prefill.cursor)
+                   // self.prefill_chunk)
         return n
 
     def drain(self) -> List[Tuple[int, List[int]]]:
@@ -3446,24 +3525,28 @@ class ServingEngine:
         held = load[..., :-1]
         by_layer = held.sum(axis=0)                 # (layers, held)
         if self._expert_pairs is None:
+            # a series a held expert, summed over the expert layers: the
+            # registry's cap on a family's children holds them all (a
+            # child a (layer, expert) did not fit it, and cost a locked
+            # call each a tick); ``expert_load`` has the pairs by layer
             fam = _obs.default_registry().counter(
                 "moe.expert_load",
-                "(token, expert) pairs routed to a held expert, by expert "
-                "layer and held expert, over every step program run")
+                "(token, expert) pairs routed to a held expert, by held "
+                "expert, over every expert layer of every step program "
+                "run")
             self._m_expert_load = [
-                [fam.labels(engine=self._eid, layer=str(li), expert=str(e))
-                 for e in range(by_layer.shape[1])]
-                for li in range(by_layer.shape[0])]
+                fam.labels(engine=self._eid, expert=str(e))
+                for e in range(by_layer.shape[1])]
             self._expert_pairs = np.zeros(by_layer.shape, np.int64)
         self._expert_pairs += by_layer
-        for li, e in zip(*np.nonzero(by_layer)):
-            self._m_expert_load[li][e].inc(int(by_layer[li, e]))
+        for child, n in zip(self._m_expert_load,
+                            by_layer.sum(axis=0).tolist()):
+            child.inc(n)
         elsewhere = int(load[..., -1].sum())
         touched = (held > 0).sum(axis=-1)           # (passes, layers)
         self._expert_totals += (elsewhere, int(touched.sum()), touched.size)
         self._m_pairs_elsewhere.inc(elsewhere)
-        for n in touched.reshape(-1):
-            self._m_experts_touched.observe(float(n))
+        self._m_experts_touched.observe_many(touched.reshape(-1).tolist())
 
     @property
     def expert_load(self) -> Optional[Dict[str, object]]:
@@ -3728,7 +3811,7 @@ class ServingEngine:
                     break
                 # remove by IDENTITY: a preemption inside the retry loop
                 # may have pushed a new resume entry ahead of req
-                src.remove(req)
+                self._dequeue(src, req)
                 wave.append((req, si, m))
                 wave_tokens += int(req.prompt.size)
             if not wave:
@@ -3838,14 +3921,17 @@ class ServingEngine:
             topk[r] = req.sampling.top_k
             topp[r] = req.sampling.top_p
         span = self._tracer.span
+        with span(_ACCOUNT):
+            facts = dict(
+                sample_path=self._note_sample_path((temps, topk, topp)),
+                # a contiguous wave reads no cache: the flash kernel
+                **(self._kv_walk((prefix, bucket)) if paged else {}))
         with span("serving.prefill", bucket=bucket, rows=len(wave),
                   padded_rows=nb, tokens=int(lens[:len(wave)].sum()),
-                  sample_path=self._note_sample_path((temps, topk, topp)),
-                  # a contiguous wave reads no cache: the flash kernel
-                  **(self._kv_walk((prefix, bucket)) if paged else {})):
+                  **facts):
             with span(_BUILD):
-                args = self._upload(self._prefill_table, own)
-            with span(_DISPATCH):
+                args = self._upload(self._prefill_table, own, bucket)
+            with span(_DISPATCH, leaves=self._program_leaves):
                 tok, self._cache = self._prefill_fn(
                     self._params, self._cache, *args)
             with span(_READBACK):

@@ -150,10 +150,17 @@ def test_engine_counts_expert_load_and_window_dead_positions():
     # every real token sends top_k pairs to each layer, held or elsewhere
     assert pairs.sum() + load["pairs_elsewhere"] == tokens * 2 * 4
     assert 0 < load["experts_touched"] <= load["layer_calls"] * 4
+    # the registry's series: one a held expert over the layers (what the
+    # cap on a family's children holds whole), equal to the exact totals
     snap = obs.snapshot()
-    by_label = {(r["labels"]["layer"], r["labels"]["expert"]): r["value"]
-                for r in snap["moe.expert_load"]["series"]}
-    assert sum(by_label.values()) == pairs.sum()
+    by_expert = {int(r["labels"]["expert"]): r["value"]
+                 for r in snap["moe.expert_load"]["series"]
+                 if r["labels"].get("engine") == eng._eid}
+    assert by_expert == dict(enumerate(pairs.sum(axis=0)))
+    touched = [r for r in snap["moe.experts_touched"]["series"]
+               if r["labels"].get("engine") == eng._eid]
+    assert (touched[0]["count"], touched[0]["sum"]) == (
+        load["layer_calls"], load["experts_touched"])
     assert snap["moe.pairs_elsewhere"]["series"][0]["value"] == \
         load["pairs_elsewhere"]
     # the 28-token prompt's last tick wrote position 32: 32 + 1 - 16

@@ -20,7 +20,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import observability as obs
 from paddle_tpu.serving import ServingEngine
-from paddle_tpu.serving.engine import TICK_PHASES
+from paddle_tpu.serving.engine import TICK_COSTS, TICK_PHASES
 
 MAXLEN = 64
 MODES = {"wave": {}, "chunked": {"chunked": True, "prefill_chunk": 8},
@@ -42,11 +42,11 @@ def _prompt(n, seed):
     return np.random.RandomState(seed).randint(0, 256, n).astype(np.int32)
 
 
-def _engine(lm, mode):
+def _engine(lm, mode, new_tokens=6):
     eng = ServingEngine(lm, num_slots=3, max_length=MAXLEN, paged=True,
                         block_len=8, **MODES[mode])
     for i, n in enumerate(PROMPTS):
-        eng.submit(_prompt(n, i + 1), max_new_tokens=6)
+        eng.submit(_prompt(n, i + 1), max_new_tokens=new_tokens)
     return eng
 
 
@@ -67,8 +67,9 @@ def _tick_events():
 
 @pytest.mark.parametrize("mode", sorted(MODES))
 def test_tick_phases_tile_the_step(lm, mode):
-    eng = _engine(lm, mode)
-    for tick in range(3):
+    eng = _engine(lm, mode, new_tokens=24)
+    outside = []
+    for tick in range(6):
         obs.get_tracer().clear()
         eng.step()
         step, phases, evs = _tick_events()
@@ -84,17 +85,168 @@ def test_tick_phases_tile_the_step(lm, mode):
                 if a is not b and not (_inside(a, b) or _inside(b, a)):
                     assert (a["ts"] + a["dur"] <= b["ts"] + 1e-6
                             or b["ts"] + b["dur"] <= a["ts"] + 1e-6)
-        # the outermost phases tile the step.  The share is a statement
-        # about the chip's 80 ms ticks (PERF.md: under 2 %); a warm tick
-        # of this model on a CPU is 2-4 ms, of which the spans' own
-        # bookkeeping is ~0.1 ms, so warm ticks get an absolute bound and
-        # the cold one (it compiles inside serving.dispatch) the share
+        # the outermost phases tile the step: what is left outside them
         top = [e for e in phases
                if not any(o is not e and _inside(e, o) for o in phases)]
-        outside = step["dur"] - sum(e["dur"] for e in top)
-        assert 0 <= outside < 1000.0                          # us
-        if tick == 0:
-            assert outside < 0.02 * step["dur"]
+        outside.append(step["dur"] - sum(e["dur"] for e in top))
+        assert outside[-1] >= 0
+        if tick == 0:           # it compiles inside serving.dispatch
+            assert outside[0] < 0.02 * step["dur"]
+    # The share is a statement about the chip's 25-80 ms ticks (PERF.md:
+    # under 2 %); a warm tick of this model on a CPU is 2-4 ms, of which
+    # the spans' own bookkeeping is ~0.1 ms, so warm ticks get an absolute
+    # bound, and over their MEDIAN: a worker the machine deschedules for a
+    # tick (six run side by side) cannot fail it
+    assert sorted(outside[1:])[2] < 1000.0                    # us
+
+
+# -- (a') the two costs inside the phases ------------------------------------
+
+def _spy_programs(eng):
+    """Record, per call of the engine's step and prefill programs, the
+    operands handed over after (params, cache): (table, their bytes)."""
+    calls = []
+
+    def spy(fn, table):
+        def call(params, cache, *args):
+            calls.append((table, sum(int(a.nbytes) for a in args)))
+            return fn(params, cache, *args)
+        return call
+    eng._linted = True              # or the first tick's lint traces the spy
+    eng._step_fn = spy(eng._step_fn, eng._step_table)
+    if eng._prefill_fn is not None:
+        eng._prefill_fn = spy(eng._prefill_fn, eng._prefill_table)
+    return calls
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_upload_span_says_what_crossed_to_the_device(lm, mode):
+    """``serving.upload``: once a program call, inside that call's
+    ``serving.build_inputs``, with the operand table's length and the
+    uploaded arrays' bytes; ``serving.dispatch`` is the call alone and
+    says how many leaves of params and cache it flattens."""
+    eng = _engine(lm, mode)
+    calls = _spy_programs(eng)
+    leaves = len(jax.tree_util.tree_leaves((eng._params, eng._cache)))
+    waves = 0
+    for _ in range(4):
+        obs.get_tracer().clear()
+        before = len(calls)
+        eng.step()
+        _, phases, evs = _tick_events()
+        uploads = [e for e in evs if e["name"] == "serving.upload"]
+        assert len(uploads) == len(calls) - before >= 1
+        waves += len(uploads) - 1
+        builds = [e for e in phases if e["name"] == "serving.build_inputs"]
+        launches = [e for e in phases if e["name"] == "serving.dispatch"]
+        assert len(launches) == len(uploads)
+        for up, launch, (table, nbytes) in zip(uploads, launches,
+                                               calls[before:]):
+            assert sum(_inside(up, b) for b in builds) == 1
+            assert up["ts"] + up["dur"] <= launch["ts"] + 1e-6
+            assert up["args"] == {"operands": len(table), "bytes": nbytes}
+            assert launch["args"] == {"leaves": leaves}
+    assert waves == (mode != "chunked")     # the wave engines' one wave
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_account_span_gathers_the_ticks_own_bookkeeping(lm, mode):
+    """``serving.account``: at most twice a tick — inside
+    ``serving.build_inputs`` before the device seam, inside
+    ``serving.advance`` after it — and once a wave, before its
+    ``serving.prefill`` opens; what it computed still reaches the rows
+    span's arguments."""
+    eng = _engine(lm, mode)
+    for tick in range(4):
+        obs.get_tracer().clear()
+        eng.step()
+        _, phases, evs = _tick_events()
+        by = {n: [e for e in evs if e["name"] == n] for n in (
+            "serving.account", "serving.prefill", "serving.build_inputs",
+            "serving.advance", "serving.admit", "serving.dispatch")}
+        accounts, waves = by["serving.account"], by["serving.prefill"]
+        assert len(accounts) == 2 + len(waves) <= 3
+        *of_waves, before, after = accounts
+        (step_launch,) = [d for d in by["serving.dispatch"]
+                          if not any(_inside(d, w) for w in waves)]
+        assert any(_inside(before, b) for b in by["serving.build_inputs"])
+        assert before["ts"] + before["dur"] <= step_launch["ts"] + 1e-6
+        assert any(_inside(after, a) for a in by["serving.advance"])
+        assert step_launch["ts"] + step_launch["dur"] <= after["ts"] + 1e-6
+        for acc, wave in zip(of_waves, waves):
+            assert _inside(acc, by["serving.admit"][0])
+            assert acc["ts"] + acc["dur"] <= wave["ts"] + 1e-6
+            assert {"sample_path", "kv_blocks", "kv_walk"} <= set(
+                wave["args"])
+        (rows,) = [e for e in evs
+                   if e["name"] in ("serving.decode", "serving.verify")]
+        assert {"kv_blocks", "kv_walk", "sample_path", "weight_passes",
+                "pass_rows", "pass_tokens", "slots"} <= set(rows["args"])
+        # what the two names add to the ring: three events a tick, and
+        # two for a wave in it
+        assert sum(e["name"] in TICK_COSTS for e in evs) \
+            == 3 + 2 * len(waves)
+
+
+def _queued_chunks_by_walk(eng):
+    return sum(-(-eng._prompt_commit(r) // eng.prefill_chunk)
+               for q in (eng._resume_q, eng._queue) for r in q)
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_the_kept_chunk_queue_count_is_the_walk(lm, mode):
+    """The chunk-queue depth comes from a count kept where a request
+    enters or leaves a queue, not from a walk of the backlog each tick:
+    equal to the walk after submit, admit, preempt, resume and cancel."""
+    eng = ServingEngine(lm, num_slots=4, max_length=MAXLEN, paged=True,
+                        block_len=8, num_blocks=13, prefill_batch=2,
+                        preempt="recompute", **MODES[mode])
+    seen = set()
+
+    def check(what):
+        assert eng._queued_chunks == _queued_chunks_by_walk(eng), what
+        pf = eng._prefill
+        assert eng._pending_chunks() == eng._queued_chunks + (
+            0 if pf is None else -(-(pf.end - pf.cursor)
+                                   // eng.prefill_chunk)), what
+        seen.add(what)
+
+    low = [eng.submit(_prompt(n, n), max_new_tokens=12, priority=0)
+           for n in (12, 10)]
+    check("submit")
+    assert eng._queued_chunks == sum(-(-n // eng.prefill_chunk)
+                                     for n in (12, 10))
+    for _ in range(6 if eng.chunked else 3):
+        eng.step()
+        check("admit")
+    assert not eng.queue_depth
+    high = [eng.submit(_prompt(n, n), max_new_tokens=12, priority=5)
+            for n in (14, 9)]
+    last = eng.submit(_prompt(20, 20), max_new_tokens=4, priority=0)
+    check("submit")
+    preempted = resumed = 0
+    for _ in range(200):
+        if not (eng.queue_depth or eng.num_preempted or eng.num_active
+                or eng.num_pending):
+            break
+        if eng.queue_depth and preempted and "cancel" not in seen:
+            assert eng.cancel(last)       # still queued behind the rest
+            check("cancel")
+        eng.step()
+        m = eng.metrics()["preempt"]
+        if sum(m["preemptions"].values()) > preempted:
+            preempted = sum(m["preemptions"].values())
+            check("preempt")
+        if sum(m["resumes"].values()) > resumed:
+            resumed = sum(m["resumes"].values())
+            check("resume")
+        check("tick")
+    assert seen == {"submit", "admit", "preempt", "resume", "cancel", "tick"}
+    assert eng._queued_chunks == 0 == eng._pending_chunks()
+    assert all(len(eng.result(r)) == 12 for r in low + high)
+    if eng.chunked:
+        assert eng.metrics()["chunked"]["chunk_queue_depth"]["count"] \
+            == eng._ticks
 
 
 @pytest.mark.parametrize("mode", ["wave", "spec"])
@@ -329,6 +481,36 @@ def test_spans_off_records_nothing_and_enters_no_annotation(
         pass
     assert tracer.events() == []
     assert _CountingAnnotation.entered == on
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_spans_off_silences_the_two_costs_too(lm, monkeypatch, mode):
+    """``serving.upload`` and ``serving.account`` go through the one span
+    call: with the flag off neither is recorded nor annotated, in any
+    step body, and the tick serves what it served."""
+    import jax.profiler
+
+    named = []
+
+    class Annotation(_CountingAnnotation):
+        def __init__(self, name, **kw):
+            named.append(name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    served = _engine(lm, mode).drain()
+    eng = _engine(lm, mode)
+    tracer = obs.get_tracer()
+    tracer.clear()
+    del named[:]
+    eng.step()
+    on = [e["name"] for e in tracer.events()]
+    assert {"serving.upload", "serving.account"} <= set(on)
+    assert sorted(named) == sorted(on)
+    tracer.clear()
+    del named[:]
+    monkeypatch.setattr(tracer, "enabled", False)
+    assert eng.drain() == served
+    assert tracer.events() == [] and named == []
 
 
 # -- (e) kernels carry names -------------------------------------------------
