@@ -169,8 +169,10 @@ slot rows, in ONE pass of the model's weights (``decode_parts``, since PR
 per-slot state a part at a time); where each kind of idle write lands is
 argued there, once) and
 one prefill program for the wave engines, each with its signature written
-down once as an operand table (``_operand_tables``) that the uploads, the
-lint's arguments and the mesh shardings all read; ONE tick
+down once as an operand table (``_operand_tables``) from which the layout of
+the ONE buffer its operands cross in is derived (PR 38: ``_lay_out``, filled
+by ``_upload``, taken apart by the program's first lines, ``_unpack``), and
+which the lint's arguments and the mesh shardings read too; ONE tick
 (``_step_inner``); and one device seam each for the tick and the wave
 (``_device_step``, ``_device_prefill``), which is all the fleet simulator
 replaces.
@@ -187,8 +189,8 @@ import json
 import math
 import time
 from collections import deque
-from typing import (Callable, Deque, Dict, List, NamedTuple, Optional,
-                    Sequence, Tuple)
+from typing import (Deque, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -237,11 +239,12 @@ _ADMIT, _GROW, _BUILD, _DISPATCH, _READBACK, _ADVANCE = TICK_PHASES
 #: time of every name of both tuples); a reader that knows the six
 #: phases alone sees through them:
 #:
-#: - ``serving.upload`` (``operands=``, ``bytes=``): the walk of a
-#:   program's operand table, one host->device transfer an operand
-#:   (``_upload``), inside ``serving.build_inputs`` of a tick and of a
-#:   wave alike; what is left of ``serving.build_inputs`` around it is
-#:   assembly on the host;
+#: - ``serving.upload`` (``operands=``, ``bytes=``, ``transfers=``): a
+#:   program's operands packed into one host buffer by its table's layout
+#:   and sent in ONE host->device transfer (``_upload``; one more for an
+#:   operand that is not small), inside ``serving.build_inputs`` of a tick
+#:   and of a wave alike; what is left of ``serving.build_inputs`` around
+#:   it is assembly on the host;
 #: - ``serving.account``: host work that exists only to feed a span
 #:   argument, a counter, a gauge, a histogram or the cost model — what
 #:   the program's own measurement costs with the profiler off.  At most
@@ -276,34 +279,86 @@ _PREFILL_TRACE_BUDGET = 16
 
 class _Operand(NamedTuple):
     """One operand of a device program after ``(params, cache)``: a row of
-    the engine's operand tables (``ServingEngine._operand_tables``)."""
+    the engine's operand tables (``ServingEngine._operand_tables``), as
+    the program's body sees it."""
 
     name: str                  # what the program's body calls it
     shape: Tuple               # a ``None`` is the prefill wave's bucket
-    dtype: object
+    dtype: object              # int32, float32, bool, or the typed key's
     # where the host takes the value from: a mirror array of the engine,
-    # uploaded as it stands, or the name under which the tick (the wave)
+    # read as it stands, or the name under which the tick (the wave)
     # hands over a value of its own
     src: object
-    put: Callable = jnp.asarray    # the upload, one call an operand
     fill: int = 0              # the abstract trace's value (``_lint_args``)
 
 
-def _table_bytes(table) -> Tuple[int, int]:
-    """The bytes one walk of an operand table uploads, as (those of the
-    operands of fixed shape, those a token of the prefill wave's bucket
-    adds: the operands with a ``None`` in their shape)."""
-    fixed = a_token = 0
-    for o in table:
-        # a typed PRNG key's dtype is no numpy dtype, and states its size
-        dtype = (o.dtype if jnp.issubdtype(o.dtype, jax.dtypes.prng_key)
-                 else np.dtype(o.dtype))
-        n = dtype.itemsize * math.prod(d for d in o.shape if d is not None)
-        if None in o.shape:
-            a_token += n
-        else:
-            fixed += n
-    return fixed, a_token
+# How a table's operands cross to the device (``ServingEngine._upload``):
+# as ONE buffer of 32-bit words that the program takes apart in its first
+# lines (``_unpack``).  int32 rides as it is, float32 as its bits, a bool
+# as a word compared with 0, and the key as the base key's words and the
+# tick's number, which the program folds together itself (the base key is
+# data and no constant of the program: a constant would put the engine's
+# seed into the program's text, and with it into its place in the compile
+# cache).  Each operand starts on a lane tile, so the program's slices are
+# aligned views.  An operand that is not small (the spec engine's
+# ``draft_probs``, megabytes at a real vocabulary) would cost in the
+# staging copy what its transfer costs, so one over ``_OWN_TRANSFER_BYTES``
+# at the wave's largest bucket keeps a transfer and a place in the
+# signature of its own, after the buffer.
+_ALIGN = 128
+_OWN_TRANSFER_BYTES = 1 << 20
+_put = jax.device_put          # the one host->device call of the engine
+
+
+class _Layout(NamedTuple):
+    """Where each operand of a table lies in the packed buffer
+    (``_lay_out``)."""
+
+    packed: Tuple              # (operand, offset in words)
+    own: Tuple                 # the operands with a transfer of their own
+    words: int                 # the buffer's length at bucket 0 ...
+    a_token: int               # ... and what a token of the bucket adds
+
+    def nbytes(self, bucket: int = 0) -> int:
+        """What one upload moves, the buffer's padding included."""
+        return 4 * (self.words + self.a_token * bucket) + sum(
+            np.dtype(o.dtype).itemsize
+            * math.prod(bucket if d is None else d for d in o.shape)
+            for o in self.own)
+
+
+def _is_key(dtype) -> bool:
+    return jnp.issubdtype(dtype, jax.dtypes.prng_key)
+
+
+def _lay_out(table, largest: int) -> _Layout:
+    """The layout of ``table``: its small operands of fixed shape in the
+    table's order, each from a multiple of ``_ALIGN`` words on, then the
+    one whose shape holds the wave's bucket (``ids``), which takes what is
+    left of the buffer — so the offsets are the same at every bucket, and
+    the buffer's length states the bucket to the program compiled for it.
+    ``largest`` is the bucket that decides which operands are not small: a
+    program has one signature whatever the bucket."""
+    def words(o, bucket):
+        if _is_key(o.dtype):        # the base key's words, then the tick
+            return o.dtype.itemsize // 4 + 1
+        return math.prod(bucket if d is None else d for d in o.shape)
+
+    def small(o):
+        return (_is_key(o.dtype)
+                or 4 * words(o, largest) <= _OWN_TRANSFER_BYTES)
+
+    def bucketed(o):
+        return None in o.shape
+
+    at, packed = 0, []
+    for o in sorted(filter(small, table), key=bucketed):        # stable
+        packed.append((o, at))
+        at += -(-words(o, 0) // _ALIGN) * _ALIGN
+    rest = [words(o, 1) for o, _ in packed if bucketed(o)]
+    assert len(rest) <= 1, packed         # one may take "what is left"
+    return _Layout(tuple(packed),
+                   tuple(o for o in table if not small(o)), at, sum(rest))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -784,6 +839,9 @@ class ServingEngine:
                 self.kv.on_swap_in = self._host_swap_in
 
         self._base_key = jax.random.key(seed)
+        # its words as the packed buffer carries them (``_pack``)
+        self._key_bits = np.asarray(
+            jax.random.key_data(self._base_key)).view(np.int32).reshape(-1)
         # the scheduler's time source: every SLO stamp (t_submit,
         # queue-wait, TTFT, TPOT) reads through this indirection, so the
         # fleet simulator (serving/fleet_sim.py) can drive the SAME
@@ -806,19 +864,19 @@ class ServingEngine:
         # rule (paddle_tpu/static_analysis) verifies this stays true.
         donate = {"donate_argnums": (1,)}
         # mesh mode: the SAME once-jitted programs, now with DECLARED
-        # shardings — params/cache per decode_mesh_specs, every small
-        # operand (token/position/mask vectors, block tables, the PRNG
-        # key) replicated, tokens replicated on the way out and the
-        # cache keeping its spec.  Declaring both sides keeps the
+        # shardings — params/cache per decode_mesh_specs, the packed
+        # buffer of operands (token/position/mask vectors, block tables,
+        # the tick's number) replicated, tokens replicated on the way out
+        # and the cache keeping its spec.  Declaring both sides keeps the
         # donated cache aliasable in place (in/out layouts provably
         # match) and makes the step's sharding contract the same one
         # mesh_preflight lints abstractly.
         self._step_table, self._prefill_table = self._operand_tables()
-        # what the spans at the device seam state, computed once: the
-        # bytes a table's walk uploads (``serving.upload``) and the leaves
-        # a program call flattens (``serving.dispatch``)
-        self._step_bytes = _table_bytes(self._step_table)
-        self._prefill_bytes = _table_bytes(self._prefill_table or ())
+        # each table's layout in the packed buffer, computed once
+        self._step_layout = _lay_out(self._step_table, self.max_length)
+        self._prefill_layout = _lay_out(self._prefill_table or (),
+                                        self.max_length)
+        # what ``serving.dispatch`` states: the leaves a call flattens
         self._program_leaves = len(jax.tree_util.tree_leaves(
             (self._params, self._cache)))
         self._step_outputs = (
@@ -831,7 +889,7 @@ class ServingEngine:
             kwargs = dict(donate)
             if self.mesh is not None:
                 kwargs.update(self._mesh_jit_shardings(
-                    2 + len(table), len(outputs)))
+                    self._program_arity(table), len(outputs)))
             return _obs.track_retraces(self._under_mesh(body), site,
                                        budget=budget, labels=lbl, **kwargs)
         # ONE step program serves every tick.  The budget of 1 IS the
@@ -1481,16 +1539,18 @@ class ServingEngine:
     def _operand_tables(self):
         """The signatures of the step program and of the prefill wave's
         (None for the cursor engine, which has no wave), after ``(params,
-        cache)``: each a list of :class:`_Operand` in the program's order.
-        Built once; everything that states a signature reads it — the
-        program bodies (by name), the uploads of a tick and of a wave
-        (``_upload``), ``_lint_args`` and the lengths of a mesh engine's
-        declared shardings."""
+        cache)``: each a list of :class:`_Operand` in the order the
+        program's body names them.  Built once; everything that states a
+        signature reads it — the packed buffer's layout (``_layout``: what
+        a tick and a wave upload, ``_upload``, and what the program bodies
+        take apart, ``_unpack``), ``_lint_args`` and the length of a mesh
+        engine's declared shardings (``_program_arity``).  The key's row
+        says what the body sees; what crosses for it is the base key's
+        words and the tick's number."""
         s, k, nb = self.num_slots, self.spec_k, self.prefill_batch
         mb = self.max_blocks if self.paged else 0
         i32, f32, op = np.int32, np.float32, _Operand
-        key = op("key", (), self._base_key.dtype, "key",
-                 functools.partial(jax.random.fold_in, self._base_key))
+        key = op("key", (), self._base_key.dtype, "key")
 
         def knobs(n, c, temps, topk, topp):
             # the three per-row vectors of one sample_tokens call
@@ -1519,17 +1579,16 @@ class ServingEngine:
                      op("unmask_thr", (s,), f32, self._unmask_thr, fill=1)]
         if self.chunked:
             step += [op("cids", (1, self.prefill_chunk), i32, "cids"),
-                     op("cpos", (), i32, "cpos", jnp.int32),
-                     op("clen", (), i32, "clen", jnp.int32, fill=1),
+                     op("cpos", (), i32, "cpos"),
+                     op("clen", (), i32, "clen", fill=1),
                      # where the chunk is written: its slot's row of the
                      # block table, or its slot
-                     op("cdst", (1, mb), i32, "cdst") if self.paged
-                     else op("cdst", (), i32, "cdst", jnp.int32)]
+                     op("cdst", (1, mb) if self.paged else (), i32, "cdst")]
             if self._slot_leaves:
                 # the row of the per-slot state the chunk part addresses:
                 # the cursor's slot (a table row names blocks, not a slot);
                 # the null row on a chunk-free tick
-                step.append(op("cslot", (), i32, "cslot", jnp.int32))
+                step.append(op("cslot", (), i32, "cslot"))
             step += knobs(1, "c", "ctemps", "ctopk", "ctopp")
             return step + [key], None
         wave = [op("ids", (nb, None), i32, "ids")]
@@ -1542,6 +1601,46 @@ class ServingEngine:
                      op("slot_ids", (nb,), i32, "slot_ids")]
         return step + [key], wave + knobs(nb, "", "temps", "topk",
                                           "topp") + [key]
+
+    def _layout(self, table) -> _Layout:
+        """``table``'s layout in the packed buffer."""
+        return (self._step_layout if table is self._step_table
+                else self._prefill_layout)
+
+    def _program_arity(self, table) -> int:
+        """The arguments of ``table``'s program: params, cache, the packed
+        buffer, and the operands that are not small."""
+        return 3 + len(self._layout(table).own)
+
+    def _unpack(self, table, packed, own) -> Dict[str, jax.Array]:
+        """The first lines of a device program: its operands by name, cut
+        out of the ``packed`` buffer by the layout ``_upload`` filled it
+        by — static slices from lane-tile offsets, a float32's bits read
+        back as float32, a mask's words compared with 0, the key folded
+        from the base key's words and the tick's number (the fold the host
+        made eagerly a tick before PR 38: the same bits) — with the
+        operands that crossed on their ``own`` beside them."""
+        lay = self._layout(table)
+        a = {o.name: x for o, x in zip(lay.own, own)}
+        for o, at in lay.packed:
+            if _is_key(o.dtype):
+                n = self._key_bits.size
+                base = jax.random.wrap_key_data(
+                    jax.lax.bitcast_convert_type(packed[at:at + n],
+                                                 np.uint32),
+                    impl=jax.random.key_impl(self._base_key))
+                a[o.name] = jax.random.fold_in(base, packed[at + n])
+                continue
+            if None in o.shape:     # the wave's ids: the rest of the buffer
+                x = packed[at:].reshape(
+                    [-1 if d is None else d for d in o.shape])
+            else:
+                x = packed[at:at + math.prod(o.shape)].reshape(o.shape)
+            a[o.name] = (
+                x != 0 if o.dtype is bool
+                else jax.lax.bitcast_convert_type(x, np.float32)
+                if o.dtype is np.float32 else x)
+        return a
 
     def _step_program(self):
         """The Python body of THE step program, composed for this engine's
@@ -1633,10 +1732,9 @@ class ServingEngine:
         blocks, a model with expert layers adds their load, (1, expert
         layers, held + 1), before the cache."""
         chunked, spec = self.chunked, self.spec
-        names = [o.name for o in self._step_table]
 
-        def step(params, cache, *operands):
-            a = dict(zip(names, operands))
+        def step(params, cache, packed, *own):
+            a = self._unpack(self._step_table, packed, own)
             prep = self._prepare(params)
             mask, key = a["slot_mask"], a["key"]
             parts = self._step_parts(a)
@@ -1761,10 +1859,9 @@ class ServingEngine:
         in the same wave see each other's writes because every layer's
         scatter precedes its attention read)."""
         paged = self.paged
-        names = [o.name for o in self._prefill_table]
 
-        def prefill(params, cache, *operands):
-            a = dict(zip(names, operands))
+        def prefill(params, cache, packed, *own):
+            a = self._unpack(self._prefill_table, packed, own)
             ids = a["ids"]
             nb = ids.shape[0]
             if paged:
@@ -2694,20 +2791,46 @@ class ServingEngine:
             self._apply_demotions()
         return finished
 
+    def _pack(self, table, value, bucket: int = 0) -> List:
+        """A program's arguments after ``(params, cache)`` from
+        ``value(operand)``, the host's value of each row of ``table``: ONE
+        buffer that holds every small operand at its place in the layout
+        (``_lay_out``; ``_unpack`` is its inverse on the device), then the
+        operands that keep a transfer of their own.  One ``_put`` each.
+
+        The buffer is a tick's own, allocated fresh: nothing writes it
+        after the ``_put``, so an upload that aliases host memory (the CPU
+        backend's zero-copy ``device_put``) or a loop that builds tick
+        n + 1 before tick n's program has read its operands (a
+        dispatch-ahead loop) still hands each program its own tick's
+        values.  The mirrors it copies FROM are mutated in place once the
+        tick's tokens are read back; they never cross themselves."""
+        lay = self._layout(table)
+        buf = np.zeros(lay.words + lay.a_token * bucket, np.int32)
+        bits = buf.view(np.float32)
+        for o, at in lay.packed:
+            flat = (np.append(self._key_bits, np.int32(value(o)))
+                    if _is_key(o.dtype) else np.asarray(value(o)).reshape(-1))
+            (bits if o.dtype is np.float32 else buf)[
+                at:at + flat.size] = flat
+        return [_put(buf)] + [_put(np.asarray(value(o), o.dtype))
+                              for o in lay.own]
+
     def _upload(self, table, own, bucket: int = 0) -> List:
         """The upload of ``serving.build_inputs``, for either program
-        (``serving.upload``): one walk of its operand table, one
-        host->device transfer an operand, each strongly typed as the
-        table says (``jnp.int32`` chunk scalars, a typed key folded from
-        the tick's number); their bytes are known from the table
-        (``_table_bytes``) and a wave's ``bucket``.  ROADMAP S3 (one
-        packed upload) is a change to this function."""
-        fixed, a_token = (self._step_bytes if table is self._step_table
-                          else self._prefill_bytes)
+        (``serving.upload``): the mirrors and the tick's (the wave's)
+        ``own`` values packed by the table's layout (``_pack``) and sent
+        as one buffer — ``transfers`` says how many host->device calls
+        that took (1, or 2 for an engine whose table holds an operand
+        that is not small), ``bytes`` what crossed, the layout's padding
+        included, ``operands`` the table's rows."""
+        lay = self._layout(table)
         with self._tracer.span(_UPLOAD, operands=len(table),
-                               bytes=fixed + a_token * bucket):
-            return [put(src if src.__class__ is np.ndarray else own[src])
-                    for _, _, _, src, put, _ in table]
+                               bytes=lay.nbytes(bucket),
+                               transfers=1 + len(lay.own)):
+            return self._pack(
+                table, lambda o: (o.src if o.src.__class__ is np.ndarray
+                                  else own[o.src]), bucket)
 
     def _device_step(self, own, chunk) -> List[np.ndarray]:
         """The tick's device seam: build and upload the step program's
@@ -3147,16 +3270,17 @@ class ServingEngine:
     def _lint_args(self, prefill_bucket: Optional[int] = None) -> Tuple:
         """Representative arguments for an ABSTRACT trace of the step
         program (with ``prefill_bucket``: of the prefill program at that
-        bucket length): the operand table's shapes and dtypes through the
-        table's own uploads, so the lint sees the program the scheduler
-        runs — tests/test_step_signature.py holds these to the operands of
-        a real tick and a real wave."""
+        bucket length): the operand table's fill values packed as a
+        tick's are (``_pack``), so the lint sees the program the scheduler
+        runs — tests/test_step_signature.py holds these to the arguments
+        of a real tick and a real wave."""
         table = (self._step_table if prefill_bucket is None
                  else self._prefill_table)
-        return (self._params, self._cache, *(
-            o.put(0 if o.name == "key" else np.full(
-                [prefill_bucket if d is None else d for d in o.shape],
-                o.fill, o.dtype)) for o in table))
+        bucket = prefill_bucket or 0
+        return (self._params, self._cache, *self._pack(
+            table, lambda o: 0 if _is_key(o.dtype) else np.full(
+                [bucket if d is None else d for d in o.shape],
+                o.fill, o.dtype), bucket))
 
     def lint_step(self, mesh=None):
         """Graph-lint this engine's once-jitted step function (one
@@ -3307,13 +3431,15 @@ class ServingEngine:
 
     def _mesh_step_shardings(self, minfo):
         """Per-arg declared shardings for the step signature: params and
-        cache per :func:`decode_mesh_specs`, everything else (token/
-        position/mask vectors, block tables, the PRNG key) replicated —
-        they are tiny and every device needs them whole."""
+        cache per :func:`decode_mesh_specs`, the packed buffer (token/
+        position/mask vectors, block tables, the tick's number) and an
+        operand with a transfer of its own replicated — every device needs
+        them whole."""
         param_specs, cache_spec, _ = decode_mesh_specs(
             self._bind, self._params, minfo.names,
             paged_cache=self.paged, quantized_cache=self.quantized)
-        return (param_specs, cache_spec) + (None,) * len(self._step_table)
+        return (param_specs, cache_spec) + (None,) * (
+            self._program_arity(self._step_table) - 2)
 
     def mesh_preflight(self, mesh=None, rules=None) -> Dict[str, object]:
         """Mesh pre-flight of the once-jitted step (ISSUE 8): findings

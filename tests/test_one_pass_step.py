@@ -36,15 +36,14 @@ def _recorded_ticks(eng):
     """Serve a few staggered requests and keep every tick's operands: the
     cache as the program received it and the operands by name."""
     ticks, upload = [], eng._upload
-    names = [o.name for o in eng._step_table]
 
     def spy(table, own):
-        args = upload(table, own)
-        # copies: the program donates the cache, and on the CPU an upload
-        # may alias the host mirror it was made from
-        ticks.append(jax.tree_util.tree_map(
-            jnp.copy, (eng._cache, dict(zip(names, args)))))
-        return args
+        packed, *alone = upload(table, own)
+        # the operands as the program takes them out of the packed buffer
+        # (new arrays), and a copy of the cache: the program donates it
+        ticks.append((jax.tree_util.tree_map(jnp.copy, eng._cache),
+                      eng._unpack(table, packed, alone)))
+        return [packed, *alone]
     eng._upload = spy
     rng = np.random.default_rng(3)
     motif = rng.integers(1, 255, 4)

@@ -365,25 +365,22 @@ def test_engine_counts_each_ticks_path_as_the_device_decides_it(lm, layout):
                         block_len=8, **kw)
     calls = []          # per program call: the knob vectors it was handed
 
-    def spy(fn, where):
-        def call(params, cache, *args):
-            # real copies: on the CPU an operand aliases the host's vector
-            vecs = [np.array(a, copy=True) for a in args
-                    if getattr(a, "ndim", None) == 1
-                    and a.dtype in (jnp.float32, jnp.int32)]
-            # (…positions…, temps, topk, topp[, ctemp, ctopk, ctopp]):
-            # the float vectors mark where each triple starts
-            triples = [tuple(vecs[i:i + 3]) for i, v in enumerate(vecs)
-                       if v.dtype == np.float32 and i + 2 < len(vecs)
-                       and vecs[i + 2].dtype == np.float32]
+    def spy(fn, where, table):
+        def call(params, cache, packed, *own):
+            # what the program takes out of the packed buffer, by name:
+            # (temps, topk, topp)[, (ctemps, ctopk, ctopp)]
+            a = eng._unpack(table, packed, own)
+            triples = [tuple(np.asarray(a[c + n]) for n in
+                             ("temps", "topk", "topp"))
+                       for c in ("", "c") if c + "temps" in a]
             calls.append((where, triples))
-            return fn(params, cache, *args)
+            return fn(params, cache, packed, *own)
         return call
     eng._linted = True      # the first tick's self-lint would trace the spy
     step_fn, prefill_fn = eng._step_fn, eng._prefill_fn
-    eng._step_fn = spy(step_fn, "step")
+    eng._step_fn = spy(step_fn, "step", eng._step_table)
     if prefill_fn is not None:
-        eng._prefill_fn = spy(prefill_fn, "prefill")
+        eng._prefill_fn = spy(prefill_fn, "prefill", eng._prefill_table)
 
     def serve(*samplings):
         rids = [eng.submit(_prompt(11 + 3 * i, 40 + i), max_new_tokens=5,

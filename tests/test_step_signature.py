@@ -1,10 +1,11 @@
 """The step program's signature, tested where it was only trusted (ISSUE 29).
 
 The engine writes the operands of its two device programs down once
-(``ServingEngine._operand_tables``).  The graph lint, the mesh pre-flight,
-``chip_smoke.py`` and ``tests/lowered_step_text.py`` take ``_lint_args()`` to
-be "the program the scheduler runs"; here a real tick's and a real wave's
-operands are held to it, in every layout.
+(``ServingEngine._operand_tables``) and hands them over as ``(params, cache,
+packed[, the operands that are not small])`` (ISSUE 38).  The graph lint, the
+mesh pre-flight, ``chip_smoke.py`` and ``tests/lowered_step_text.py`` take
+``_lint_args()`` to be "the program the scheduler runs"; here a real tick's
+and a real wave's arguments are held to it, in every layout.
 """
 
 import jax
@@ -69,10 +70,20 @@ def test_a_real_tick_runs_the_program_the_table_describes(
     assert [len(toks) for _, toks in out] == [4, 4]
     assert seen["step"] and all(
         got == _types(eng._lint_args()) for got in seen["step"])
+    # params, cache, ONE packed buffer of 32-bit words; nothing here is big
+    assert len(eng._lint_args()) == 3 and not eng._step_layout.own
+    packed = eng._lint_args()[2]
+    assert (packed.shape, packed.dtype) == (
+        (eng._step_layout.words,), np.int32)
     assert bool(seen["prefill"]) == (prefill is not None)
+    buckets = set()
     for got in seen["prefill"]:
-        bucket = got[2].shape[1]
+        # the buffer's length states the wave's bucket: ids takes its rest
+        bucket, odd = divmod(got[2].shape[0] - eng._prefill_layout.words,
+                             eng.prefill_batch)
+        assert not odd and len(got) == 3
         assert got == _types(eng._lint_args(bucket))
+        buckets.add(bucket)
 
     # (c) the body returns what the engine declares
     s = SLOTS
@@ -94,5 +105,5 @@ def test_a_real_tick_runs_the_program_the_table_describes(
 
     # (d) one step program for the engine's life
     assert eng.step_traces == 1
-    assert eng.prefill_traces == (0 if chunked else len(
-        {got[2].shape[1] for got in seen["prefill"]}))
+    assert eng.prefill_traces == (0 if chunked else len(buckets))
+    assert buckets <= {8, 16}

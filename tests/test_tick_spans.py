@@ -103,13 +103,14 @@ def test_tick_phases_tile_the_step(lm, mode):
 # -- (a') the two costs inside the phases ------------------------------------
 
 def _spy_programs(eng):
-    """Record, per call of the engine's step and prefill programs, the
-    operands handed over after (params, cache): (table, their bytes)."""
+    """Record, per call of the engine's step and prefill programs, what was
+    handed over after (params, cache): (table, the arrays, their bytes)."""
     calls = []
 
     def spy(fn, table):
         def call(params, cache, *args):
-            calls.append((table, sum(int(a.nbytes) for a in args)))
+            calls.append((table, len(args),
+                          sum(int(a.nbytes) for a in args)))
             return fn(params, cache, *args)
         return call
     eng._linted = True              # or the first tick's lint traces the spy
@@ -122,9 +123,10 @@ def _spy_programs(eng):
 @pytest.mark.parametrize("mode", sorted(MODES))
 def test_upload_span_says_what_crossed_to_the_device(lm, mode):
     """``serving.upload``: once a program call, inside that call's
-    ``serving.build_inputs``, with the operand table's length and the
-    uploaded arrays' bytes; ``serving.dispatch`` is the call alone and
-    says how many leaves of params and cache it flattens."""
+    ``serving.build_inputs``, with the operand table's length, the
+    uploaded arrays' bytes and how many arrays crossed (ONE: the packed
+    buffer); ``serving.dispatch`` is the call alone and says how many
+    leaves of params and cache it flattens."""
     eng = _engine(lm, mode)
     calls = _spy_programs(eng)
     leaves = len(jax.tree_util.tree_leaves((eng._params, eng._cache)))
@@ -140,11 +142,13 @@ def test_upload_span_says_what_crossed_to_the_device(lm, mode):
         builds = [e for e in phases if e["name"] == "serving.build_inputs"]
         launches = [e for e in phases if e["name"] == "serving.dispatch"]
         assert len(launches) == len(uploads)
-        for up, launch, (table, nbytes) in zip(uploads, launches,
-                                               calls[before:]):
+        for up, launch, (table, arrays, nbytes) in zip(uploads, launches,
+                                                       calls[before:]):
             assert sum(_inside(up, b) for b in builds) == 1
             assert up["ts"] + up["dur"] <= launch["ts"] + 1e-6
-            assert up["args"] == {"operands": len(table), "bytes": nbytes}
+            assert arrays == 1
+            assert up["args"] == {"operands": len(table), "bytes": nbytes,
+                                  "transfers": arrays}
             assert launch["args"] == {"leaves": leaves}
     assert waves == (mode != "chunked")     # the wave engines' one wave
 
@@ -300,13 +304,12 @@ def test_tick_spans_count_the_kernels_block_walk(lm, mode):
         eng.submit(_prompt(n, i + 1), max_new_tokens=6)
     eng._linted = True              # or the first tick's lint traces the spy
     handed, step_fn = [], eng._step_fn
-    names = [o.name for o in eng._step_table]
 
-    def spy(params, cache, *args):
-        handed.append({n: np.array(a, copy=True)    # the host's mirrors
-                       for n, a in zip(names, args)    # change after a tick
-                       if n in ("positions", "cpos")})
-        return step_fn(params, cache, *args)
+    def spy(params, cache, packed, *own):
+        a = eng._unpack(eng._step_table, packed, own)
+        handed.append({n: np.asarray(a[n]) for n in ("positions", "cpos")
+                       if n in a})
+        return step_fn(params, cache, packed, *own)
     eng._step_fn = spy
     c = lm.config
     g = c.num_attention_heads // c.num_key_value_heads
