@@ -30,13 +30,20 @@ Design (Orca-style iteration-level scheduling, expressed TPU-first):
   * **prefill** reuses the existing static-``pos=0`` path — the one that
     routes through the Pallas flash kernel on TPU: admitted prompts are
     right-padded to a power-of-two bucket, run through ``decode_step`` on
-    a fresh ``prefill_batch``-row cache, and the finished rows are
+    a fresh cache of the wave's rows, and the finished rows are
     scattered into their slots.  Padding is sound because attention is
     causal (pad queries influence nobody) and the cache mask never reads
     past the row's position, while decode overwrites each pad slot with
-    fresh K/V before the mask can reach it.  One compiled prefill program
-    per bucket length — short rows ride along via out-of-bounds slot ids,
-    which the scatter drops;
+    fresh K/V before the mask can reach it.  The program's row count
+    follows what a row costs (PR 41): below ``_ROW_FILLS_CHIP`` positions a
+    row's products are bound by the weights' stream, rows beside it ride
+    for nothing, and a wave is padded to ``prefill_batch`` rows — its dummy
+    rows riding along via out-of-bounds slot ids, which the scatter drops;
+    from that bucket on a row is bound by compute, a dummy row costs what a
+    real one does, and each request is prefilled ALONE, in a one-row
+    program at its own bucket.  One compiled prefill program per bucket
+    length either way, so a warm-up of one prompt a bucket reaches every
+    program;
   * the **host scheduler** owns admission and retirement: a FIFO queue,
     waves of batched prefill into free slots, EOS/max-token retirement,
     and per-request outputs returned in arrival order.  Device work per
@@ -272,9 +279,20 @@ _STEP, _PREFILL = "_step_impl", "_prefill_impl"
 # their series (and retrace budgets) independent
 _ENGINE_IDS = itertools.count()
 
-# one compiled prefill program per power-of-two bucket (plus the paged
-# suffix buckets) — generous static ceiling for the prefill trace budget
-_PREFILL_TRACE_BUDGET = 16
+
+# From this bucket on a prompt is prefilled alone, one row a program call:
+# the tokens at which a bf16 matrix product stops being bound by its
+# weights' stream and starts being bound by compute (peak FLOP/s over twice
+# the bytes/s: 240 on a TPU v5e, 229 on a v4, 166 on a v5p), as a bucket.
+# Below it the rows of a wave share one stream of the weights, so padding a
+# wave to ``prefill_batch`` rows costs little and batching several short
+# prompts is a gain; from it on a call's time goes by its positions (PERF.md
+# section 5, PR 41: one row of 256 takes what four of 64 do, one of 512 what
+# four of 128 do), so a dummy row costs what a real one does — four fifths
+# of what the waves of ``mistral-7b.decode-saturated`` computed — and
+# batching real ones buys nothing.  A constant of the hardware family, not
+# a knob.
+_ROW_FILLS_CHIP = 256
 
 
 class _Operand(NamedTuple):
@@ -283,7 +301,9 @@ class _Operand(NamedTuple):
     the program's body sees it."""
 
     name: str                  # what the program's body calls it
-    shape: Tuple               # a ``None`` is the prefill wave's bucket
+    # a ``None`` is the prefill wave's bucket; a wave's operands lead with
+    # the rows its table is for
+    shape: Tuple
     dtype: object              # int32, float32, bool, or the typed key's
     # where the host takes the value from: a mirror array of the engine,
     # read as it stands, or the name under which the tick (the wave)
@@ -489,6 +509,8 @@ class ServingEngine:
     # (0: every row's tick is one token); set once, at construction
     _diffusion = None
     _block = 0
+    # the bucket from which a prompt is prefilled alone (``_wave_rows``)
+    _lone_from = _ROW_FILLS_CHIP
 
     def __init__(self, model, num_slots: int = 8, max_length: int = 1024,
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
@@ -509,7 +531,12 @@ class ServingEngine:
                  host_blocks: Optional[int] = None,
                  drafter=None,
                  draft_model=None):
-        """``paged`` (default FLAGS_serving_paged_kv) selects the paged
+        """``prefill_batch``: the most requests a prefill wave admits, and
+        the rows a wave of short prompts is padded to; a prompt whose bucket
+        is ``_ROW_FILLS_CHIP`` positions or more is prefilled alone, in a
+        one-row program, whatever this says.
+
+        ``paged`` (default FLAGS_serving_paged_kv) selects the paged
         block-pool cache; ``block_len`` (FLAGS_kv_cache_block_len) and
         ``num_blocks`` (FLAGS_kv_cache_num_blocks; 0 derives the
         contiguous cache's footprint, num_slots·max_length/block_len,
@@ -871,11 +898,19 @@ class ServingEngine:
         # donated cache aliasable in place (in/out layouts provably
         # match) and makes the step's sharding contract the same one
         # mesh_preflight lints abstractly.
-        self._step_table, self._prefill_table = self._operand_tables()
+        self._step_table, self._wave_tables = self._operand_tables()
+        # the wave padded to ``prefill_batch`` rows: the table every wave
+        # had before PR 41, and the one whose buffer crosses flat
+        self._prefill_table = self._wave_tables.get(self.prefill_batch)
         # each table's layout in the packed buffer, computed once
         self._step_layout = _lay_out(self._step_table, self.max_length)
-        self._prefill_layout = _lay_out(self._prefill_table or (),
-                                        self.max_length)
+        # (the two tables are ONE program's signature, which a mesh engine
+        # declares its shardings by: what is small is decided for both as
+        # for ``prefill_batch`` rows)
+        self._wave_layouts = {
+            rows: _lay_out(table,
+                           self.max_length * self.prefill_batch // rows)
+            for rows, table in self._wave_tables.items()}
         # what ``serving.dispatch`` states: the leaves a call flattens
         self._program_leaves = len(jax.tree_util.tree_leaves(
             (self._params, self._cache)))
@@ -899,9 +934,11 @@ class ServingEngine:
         # compiled once per bucket.
         self._step_fn = program(self._step_program(), self._step_table,
                                 self._step_outputs, "serving.step", 1)
+        # Its budget is no knob: one program a bucket ``max_length`` allows.
         self._prefill_fn = None if self.chunked else program(
             self._prefill_program(), self._prefill_table,
-            ("tokens", "cache"), "serving.prefill", _PREFILL_TRACE_BUDGET)
+            ("tokens", "cache"), "serving.prefill",
+            len(self._wave_buckets()))
         self._linted = False           # first-tick self-lint (graph_lint)
         # per-tick roofline cost model (ISSUE 15): predictions are
         # memoized host math, so the steady-state tick pays a dict
@@ -1538,7 +1575,9 @@ class ServingEngine:
 
     def _operand_tables(self):
         """The signatures of the step program and of the prefill wave's
-        (None for the cursor engine, which has no wave), after ``(params,
+        — one by the rows its program runs, 1 and ``prefill_batch``
+        (``_wave_rows``); none for the cursor engine, which has no wave —,
+        after ``(params,
         cache)``: each a list of :class:`_Operand` in the order the
         program's body names them.  Built once; everything that states a
         signature reads it — the packed buffer's layout (``_layout``: what
@@ -1547,7 +1586,7 @@ class ServingEngine:
         engine's declared shardings (``_program_arity``).  The key's row
         says what the body sees; what crosses for it is the base key's
         words and the tick's number."""
-        s, k, nb = self.num_slots, self.spec_k, self.prefill_batch
+        s, k = self.num_slots, self.spec_k
         mb = self.max_blocks if self.paged else 0
         i32, f32, op = np.int32, np.float32, _Operand
         key = op("key", (), self._base_key.dtype, "key")
@@ -1590,22 +1629,37 @@ class ServingEngine:
                 # the null row on a chunk-free tick
                 step.append(op("cslot", (), i32, "cslot"))
             step += knobs(1, "c", "ctemps", "ctopk", "ctopp")
-            return step + [key], None
-        wave = [op("ids", (nb, None), i32, "ids")]
-        if self.paged:
-            wave += [op("prefix_lens", (nb,), i32, "prefix_lens"),
-                     op("lens", (nb,), i32, "lens", fill=1),
-                     op("tables", (nb, mb), i32, "tables")]
-        else:
-            wave += [op("lens", (nb,), i32, "lens", fill=1),
-                     op("slot_ids", (nb,), i32, "slot_ids")]
-        return step + [key], wave + knobs(nb, "", "temps", "topk",
-                                          "topp") + [key]
+            return step + [key], {}
+
+        def wave(nb):
+            rows = [op("ids", (nb, None), i32, "ids")]
+            if self.paged:
+                rows += [op("prefix_lens", (nb,), i32, "prefix_lens"),
+                         op("lens", (nb,), i32, "lens", fill=1),
+                         op("tables", (nb, mb), i32, "tables")]
+            else:
+                rows += [op("lens", (nb,), i32, "lens", fill=1),
+                         op("slot_ids", (nb,), i32, "slot_ids")]
+            return rows + knobs(nb, "", "temps", "topk", "topp") + [key]
+        return step + [key], {nb: wave(nb)
+                              for nb in (1, self.prefill_batch)}
 
     def _layout(self, table) -> _Layout:
-        """``table``'s layout in the packed buffer."""
+        """``table``'s layout in the packed buffer (a wave's table is
+        known by its rows: ``ids`` leads it)."""
         return (self._step_layout if table is self._step_table
-                else self._prefill_layout)
+                else self._wave_layouts[table[0].shape[0]])
+
+    def _buffer_shape(self, table, bucket: int = 0) -> Tuple:
+        """The shape ``table``'s packed buffer crosses in.  Flat, its
+        length stating the wave's bucket — but a length cannot also state
+        the rows (one row of ``4b`` tokens and four of ``b`` are one
+        length, and would be one program to ``jit``), so the one-row
+        wave's buffer says its rows by a leading axis of its own."""
+        lay = self._layout(table)
+        n = lay.words + lay.a_token * bucket
+        flat = table is self._step_table or table is self._prefill_table
+        return (n,) if flat else (1, n)             # the one-row wave's
 
     def _program_arity(self, table) -> int:
         """The arguments of ``table``'s program: params, cache, the packed
@@ -1621,6 +1675,8 @@ class ServingEngine:
         made eagerly a tick before PR 38: the same bits) — with the
         operands that crossed on their ``own`` beside them."""
         lay = self._layout(table)
+        if packed.ndim == 2:          # a one-row wave's (``_buffer_shape``)
+            packed = packed[0]
         a = {o.name: x for o, x in zip(lay.own, own)}
         for o, at in lay.packed:
             if _is_key(o.dtype):
@@ -1843,11 +1899,14 @@ class ServingEngine:
     def _prefill_program(self):
         """The Python body of the wave engine's prefill program
         (``_prefill_impl[_paged]``), one compilation per padded bucket
-        length; each row's first token samples from the logits at its last
-        REAL position (``lens``).
+        length; the body is one for both row counts (``_wave_rows``:
+        ``prefill_batch`` rows below ``_ROW_FILLS_CHIP`` positions, one row
+        from there on) and reads the rows off its operands.  Each row's
+        first token samples from the logits at its last REAL position
+        (``lens``).
 
         Contiguous: the prompts run through the static-``pos=0`` path
-        (flash-eligible) on a fresh ``prefill_batch``-row cache, and the
+        (flash-eligible) on a fresh cache of the wave's rows, and the
         finished rows are scattered into their slots.  Dummy rows carry
         ``slot_id == num_slots``; the ``mode="drop"`` scatter discards them.
 
@@ -1861,7 +1920,9 @@ class ServingEngine:
         paged = self.paged
 
         def prefill(params, cache, packed, *own):
-            a = self._unpack(self._prefill_table, packed, own)
+            # the wave's rows, as its buffer's shape says them
+            rows = self.prefill_batch if packed.ndim == 1 else packed.shape[0]
+            a = self._unpack(self._wave_tables[rows], packed, own)
             ids = a["ids"]
             nb = ids.shape[0]
             if paged:
@@ -2806,15 +2867,16 @@ class ServingEngine:
         values.  The mirrors it copies FROM are mutated in place once the
         tick's tokens are read back; they never cross themselves."""
         lay = self._layout(table)
-        buf = np.zeros(lay.words + lay.a_token * bucket, np.int32)
+        shape = self._buffer_shape(table, bucket)
+        buf = np.zeros(shape[-1], np.int32)
         bits = buf.view(np.float32)
         for o, at in lay.packed:
             flat = (np.append(self._key_bits, np.int32(value(o)))
                     if _is_key(o.dtype) else np.asarray(value(o)).reshape(-1))
             (bits if o.dtype is np.float32 else buf)[
                 at:at + flat.size] = flat
-        return [_put(buf)] + [_put(np.asarray(value(o), o.dtype))
-                              for o in lay.own]
+        return [_put(buf.reshape(shape))] + [
+            _put(np.asarray(value(o), o.dtype)) for o in lay.own]
 
     def _upload(self, table, own, bucket: int = 0) -> List:
         """The upload of ``serving.build_inputs``, for either program
@@ -3267,15 +3329,18 @@ class ServingEngine:
 
     # -- static analysis (graph lint) --------------------------------------
 
-    def _lint_args(self, prefill_bucket: Optional[int] = None) -> Tuple:
+    def _lint_args(self, prefill_bucket: Optional[int] = None,
+                   rows: Optional[int] = None) -> Tuple:
         """Representative arguments for an ABSTRACT trace of the step
         program (with ``prefill_bucket``: of the prefill program at that
-        bucket length): the operand table's fill values packed as a
+        bucket length, with the rows the engine runs there or with
+        ``rows``): the operand table's fill values packed as a
         tick's are (``_pack``), so the lint sees the program the scheduler
         runs — tests/test_step_signature.py holds these to the arguments
         of a real tick and a real wave."""
         table = (self._step_table if prefill_bucket is None
-                 else self._prefill_table)
+                 else self._wave_tables[
+                     rows or self._wave_rows(prefill_bucket)])
         bucket = prefill_bucket or 0
         return (self._params, self._cache, *self._pack(
             table, lambda o: 0 if _is_key(o.dtype) else np.full(
@@ -3888,9 +3953,24 @@ class ServingEngine:
             b *= 2
         return min(b, self.max_length)
 
+    def _wave_buckets(self) -> List[int]:
+        """Every bucket ``_wave_bucket`` can give: 8, 16, ... and
+        ``max_length``."""
+        return sorted({self._wave_bucket(1 << e)
+                       for e in range(self.max_length.bit_length() + 1)})
+
+    def _wave_rows(self, bucket: int) -> int:
+        """The rows of the prefill program at ``bucket``: ``prefill_batch``
+        where rows beside a row ride for nothing, one where a row fills
+        the chip by itself (``_ROW_FILLS_CHIP``)."""
+        return 1 if bucket >= self._lone_from else self.prefill_batch
+
     def _admit(self) -> List[int]:
         """Wave admission: move queued requests into free slots, one
-        batched-prefill wave at a time.  Returns ids that finished AT
+        batched-prefill wave at a time — as many requests as there are
+        free slots, ``prefill_batch`` at most; which of them share a
+        program call is ``_wave_calls``' to say (a long prompt goes alone,
+        and not beside dummy rows).  Returns ids that finished AT
         admission (first token was EOS / max_new_tokens=1).  The head that
         cannot be admitted blocks the queue: head-of-line order is the
         contract in both layouts.
@@ -3972,37 +4052,62 @@ class ServingEngine:
                                  engine=self._eid, reason="pool_full")
         return got
 
+    def _wave_calls(self, wave) -> List[Tuple[List, int]]:
+        """The program calls that prefill ``wave``, as (rows, padded
+        bucket) in the wave's order.  One call at the longest row's bucket
+        where the rows share one stream of the weights.  A row whose own
+        bucket fills the chip (``_wave_rows``) gains nothing from company
+        and would make every shorter or dummy row beside it cost as much as
+        itself: it goes alone, at its own bucket, and the runs of short
+        rows between such rows share a call each — in the wave's order,
+        since a row may have adopted blocks that an earlier row of its wave
+        is still to write."""
+        buckets = [self._wave_bucket(req.prompt.size - m)
+                   for req, _, m in wave]
+        calls = []
+        for alone, run in itertools.groupby(
+                zip(wave, buckets),
+                key=lambda rb: self._wave_rows(rb[1]) == 1):
+            run = list(run)
+            calls += ([([row], b) for row, b in run] if alone else
+                      [([row for row, _ in run], max(b for _, b in run))])
+        return calls
+
     def _prefill_wave(self, wave: List[Tuple[Request, int, int]]
                       ) -> List[int]:
         """Prefill one admission wave of rows ``(request, slot, adopted
-        prefix tokens)`` — 0 adopted on the contiguous cache — in one
-        program call at the wave's padded bucket, and install each row into
-        its slot.  Returns the ids that finished at admission."""
+        prefix tokens)`` — 0 adopted on the contiguous cache — in the
+        program calls ``_wave_calls`` gives, each at its padded bucket, and
+        install each row into its slot.  Returns the ids that finished at
+        admission."""
         t_adm = self._clock()
-        bucket = max(self._wave_bucket(req.prompt.size - m)
-                     for req, _, m in wave)
-        for req, si, m in wave:
-            self._m_prefill_computed.inc(int(req.prompt.size) - m)
-            self._m_prefill_total.inc(int(req.prompt.size))
-            if req.resume is None:
-                self._m_queue_wait.observe((t_adm - req.t_submit) * 1e3)
-                req.t_admit = t_adm
-                self._rlog.event(req.uid, "admitted", engine=self._eid,
-                                 slot=int(si),
-                                 queue_wait_ms=(t_adm - req.t_submit) * 1e3,
-                                 blocked_ticks=int(req.blocked_ticks),
-                                 prefix_hit_tokens=int(m))
-            self._rlog.event(req.uid, "prefill", engine=self._eid,
-                             bucket=int(bucket),
-                             tokens=int(req.prompt.size) - m)
-        self._m_waves.inc()
-        self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
-        self._ticks += 1
+        calls = self._wave_calls(wave)
+        for rows, bucket in calls:
+            for req, si, m in rows:
+                self._m_prefill_computed.inc(int(req.prompt.size) - m)
+                self._m_prefill_total.inc(int(req.prompt.size))
+                if req.resume is None:
+                    self._m_queue_wait.observe((t_adm - req.t_submit) * 1e3)
+                    req.t_admit = t_adm
+                    self._rlog.event(
+                        req.uid, "admitted", engine=self._eid, slot=int(si),
+                        queue_wait_ms=(t_adm - req.t_submit) * 1e3,
+                        blocked_ticks=int(req.blocked_ticks),
+                        prefix_hit_tokens=int(m))
+                self._rlog.event(req.uid, "prefill", engine=self._eid,
+                                 bucket=int(bucket),
+                                 tokens=int(req.prompt.size) - m)
         span = self._tracer.span
         if self.paged:
             with span(_GROW):
                 self._flush_fresh_scales()
-        tok = self._device_prefill(wave, bucket)
+        tok: List[int] = []
+        for rows, bucket in calls:
+            self._m_waves.inc()
+            self._f_bucket.labels(engine=self._eid, bucket=str(bucket)).inc()
+            self._ticks += 1
+            # (a padded call returns its dummy rows' tokens too)
+            tok.extend(self._device_prefill(rows, bucket)[:len(rows)])
         with span(_ADVANCE):
             # queued demotions first (a wave's registration precedes its
             # prefill), then each row's slot state and first token
@@ -4013,11 +4118,12 @@ class ServingEngine:
 
     def _device_prefill(self, wave, bucket: int) -> Sequence[int]:
         """The wave's device seam (``serving.prefill``): pad the wave to
-        ``prefill_batch`` rows of ``bucket`` tokens, upload the prefill
-        program's operands, call it, fetch each row's first token.
-        ``fleet_sim.SimEngine`` overrides this and nothing else of the
-        wave."""
-        nb, paged = self.prefill_batch, self.paged
+        the rows of ``bucket``'s program (``_wave_rows``) of ``bucket``
+        tokens, upload the prefill program's operands, call it, fetch each
+        row's first token.  ``fleet_sim.SimEngine`` overrides this and
+        nothing else of the wave."""
+        nb = self._wave_rows(bucket)
+        paged, table = self.paged, self._wave_tables[nb]
         ids = np.full((nb, bucket), self.pad_token_id, np.int32)
         lens = np.ones((nb,), np.int32)
         temps = np.zeros((nb,), np.float32)
@@ -4056,7 +4162,7 @@ class ServingEngine:
                   padded_rows=nb, tokens=int(lens[:len(wave)].sum()),
                   **facts):
             with span(_BUILD):
-                args = self._upload(self._prefill_table, own, bucket)
+                args = self._upload(table, own, bucket)
             with span(_DISPATCH, leaves=self._program_leaves):
                 tok, self._cache = self._prefill_fn(
                     self._params, self._cache, *args)

@@ -11,7 +11,10 @@ four kinds: the layers are one code path repeated) and of the engine's eight
 layouts (paged x chunked x spec) at tiny llama geometry.  Run it on two
 checkouts and compare the lines: PR 27 used it to show that a second model in
 the engine left the llama step programs as they were, PR 29 that one composed
-step program lowers to the text of the eight hand-written ones."""
+step program lowers to the text of the eight hand-written ones, PR 41 that
+a wave engine's step program and its prefill program at ``prefill_batch`` rows
+stayed as they were beside the one-row program (the ``prefill rows=1``
+lines, which an older checkout does not print)."""
 import base64
 import hashlib
 import json
@@ -141,9 +144,17 @@ def sha(low):
     return hashlib.sha256(txt.encode()).hexdigest()[:16], len(txt)
 
 
-def prefill_args(eng, bucket):
-    """The prefill program's operands at one bucket length: the engine's own
-    table of them, or, on a checkout from before PR 29, the hand copy."""
+def prefill_args(eng, bucket, rows=None):
+    """The prefill program's operands at one bucket length, with ``rows``
+    rows (None: ``prefill_batch``, the one row count a checkout from before
+    PR 41 has): the engine's own table of them, or, on a checkout from
+    before PR 29, the hand copy."""
+    rows = rows or eng.prefill_batch
+    try:
+        return eng._lint_args(bucket, rows)
+    except TypeError:                   # before PR 41: one row count
+        if rows != eng.prefill_batch:
+            raise
     try:
         return eng._lint_args(bucket)
     except TypeError:
@@ -169,6 +180,11 @@ def programs(label, eng, bucket, vocab=None):
         prefill = eng._prefill_fn.python_fn
         print(label, "prefill", prefill.__name__,
               *sha(lowered(prefill, prefill_args(eng, bucket))))
+        # the one-row program (PR 41: a prompt whose bucket fills the chip
+        # is prefilled alone)
+        if 1 in getattr(eng, "_wave_tables", ()) and eng.prefill_batch > 1:
+            print(label, "prefill rows=1", prefill.__name__,
+                  *sha(lowered(prefill, prefill_args(eng, bucket, 1))))
 
 
 def cell_engines(root):

@@ -41,6 +41,12 @@ PAGED = {
     "mistral-rows": (96, 1, 32, 16, 769, 32, None, jnp.bfloat16),
     "mistral-chunk": (1, 256, 32, 16, 385, 32, None, jnp.bfloat16),
     "mistral-wave-1024": (4, 1024, 32, 16, 769, 32, None, jnp.bfloat16),
+    # a prompt of 256 positions or more is prefilled alone, one row a
+    # program call (ISSUE 41): the longest such bucket and the shortest
+    "mistral-wave-one-row-1024": (1, 1024, 32, 16, 769, 32, None,
+                                  jnp.bfloat16),
+    "mistral-wave-one-row-256": (1, 256, 32, 16, 769, 32, None,
+                                 jnp.bfloat16),
     "trinity-rows-window": (192, 1, 48, 5, 1921, 64, 4096, jnp.bfloat16),
     "trinity-chunk-window": (1, 256, 48, 5, 1921, 64, 4096, jnp.bfloat16),
     "int8-rows": (8, 1, 32, 4, 257, 32, None, jnp.int8),

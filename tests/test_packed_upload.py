@@ -141,27 +141,36 @@ def test_a_real_ticks_buffer_unpacks_to_what_its_sources_held(models, layout):
     assert eng.step_traces == 1
 
 
-@pytest.mark.parametrize("bucket", [8, 16])
+@pytest.mark.parametrize("bucket", [8, 16, 32])
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
 def test_a_waves_buffer_unpacks_at_its_bucket(models, layout, bucket):
     """The wave's ``ids`` takes what is left of the buffer, so one layout
-    serves every bucket and the buffer's length states the bucket."""
+    serves every bucket and the buffer's length states the bucket.  The
+    program of a bucket that fills the chip runs ONE row (ISSUE 41; the
+    chip's 256 positions brought down to 32 for the tiny model): the two
+    row counts have a table and a layout each, and the one-row wave's
+    buffer says its rows by a leading axis."""
     eng = _engine(models, layout)
+    eng._lone_from = 32
     seen = _spy_uploads(eng)
     rs = np.random.RandomState(bucket)
     eng.submit(rs.randint(1, 250, bucket - 3).astype(np.int32),
                max_new_tokens=2,
                sampling=SamplingParams(temperature=0.9, top_p=0.8))
     eng.drain()
-    (wave,) = [u for u in seen if u[0] is eng._prefill_table]
+    rows = eng._wave_rows(bucket)
+    assert rows == (1 if bucket == 32 else eng.prefill_batch)
+    (wave,) = [u for u in seen if u[0] is not eng._step_table]
     table, got_bucket, held, args = wave
+    assert table is eng._wave_tables[rows]
+    assert (rows == 1) == (table is not eng._prefill_table)
     assert got_bucket == bucket and len(args) == 1
-    lay = eng._prefill_layout
-    assert args[0].shape == (lay.words + eng.prefill_batch * bucket,)
-    assert lay.packed[-1][0].name == "ids" and lay.a_token == \
-        eng.prefill_batch
+    lay = eng._layout(table)
+    assert args[0].shape == ((lay.words + rows * bucket,) if rows > 1
+                             else (1, lay.words + bucket))
+    assert lay.packed[-1][0].name == "ids" and lay.a_token == rows
     _check_unpacked(eng, table, bucket, held, args)
-    assert held["ids"].shape == (eng.prefill_batch, bucket)
+    assert held["ids"].shape == (rows, bucket)
     assert eng.prefill_traces == 1
 
 
@@ -301,9 +310,8 @@ def test_a_program_call_makes_the_transfers_its_span_states(
     assert len(uploads) == len(calls) >= 6
     before = 0
     for up, (arity, after) in zip(uploads, calls):
-        table = (eng._step_table if up["args"]["operands"]
-                 == len(eng._step_table) else eng._prefill_table)
-        wave = table is eng._prefill_table
+        wave = up["args"]["operands"] != len(eng._step_table)
+        table = eng._prefill_table if wave else eng._step_table
         want = 1 if wave else transfers
         assert after - before == want == up["args"]["transfers"]
         assert arity == 2 + want

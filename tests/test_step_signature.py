@@ -79,8 +79,9 @@ def test_a_real_tick_runs_the_program_the_table_describes(
     buckets = set()
     for got in seen["prefill"]:
         # the buffer's length states the wave's bucket: ids takes its rest
-        bucket, odd = divmod(got[2].shape[0] - eng._prefill_layout.words,
-                             eng.prefill_batch)
+        # (these prompts are short: ``prefill_batch`` rows, a flat buffer)
+        bucket, odd = divmod(got[2].shape[0] - eng._wave_layouts[
+            eng.prefill_batch].words, eng.prefill_batch)
         assert not odd and len(got) == 3
         assert got == _types(eng._lint_args(bucket))
         buckets.add(bucket)
@@ -99,9 +100,13 @@ def test_a_real_tick_runs_the_program_the_table_describes(
         assert (got.shape, got.dtype) == want[name], name
     assert _types(res[-1]) == _types(eng._cache)
     if prefill is not None:
-        tok, cache = jax.eval_shape(prefill.python_fn, *eng._lint_args(16))
-        assert (tok.shape, tok.dtype) == ((eng.prefill_batch,), i32)
-        assert _types(cache) == _types(eng._cache)
+        # a short bucket's program runs ``prefill_batch`` rows, a bucket's
+        # that fills the chip one (ISSUE 41): one body, two signatures
+        for bucket, rows in ((16, eng.prefill_batch), (MAXLEN, 1)):
+            tok, cache = jax.eval_shape(prefill.python_fn,
+                                        *eng._lint_args(bucket, rows))
+            assert (tok.shape, tok.dtype) == ((rows,), i32)
+            assert _types(cache) == _types(eng._cache)
 
     # (d) one step program for the engine's life
     assert eng.step_traces == 1
