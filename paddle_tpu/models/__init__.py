@@ -15,7 +15,13 @@ cache, wave prefill, int8 KV, speculation, a mesh, int8 weights); ``sdar``
 (SDAR-MoE: generation by diffusion over blocks, declared as
 ``block_diffusion`` — the engine's rows part is then a block of positions a
 row, tokens leave it a block at a time) as ``paged=True, chunked=True,
-prefix_cache=False`` too, refusing the same list by name.  A model
+prefix_cache=False`` too, refusing the same list by name;
+``latent_moe`` (latent attention, MLA, over a sigmoid-routed MoE: the paged
+pool holds ONE entry a position that is key and value at once, declared as
+``kv_pool_entry``) as ``paged=True, chunked=True`` WITH or without a prefix
+cache, refusing by name the contiguous cache, wave prefill, int8 KV,
+preemption and the host tier, export/import, a mesh, speculation and int8
+weights.  A model
 that keeps a decode state of its own (``init_decode_state``: ``mamba``,
 ``rwkv``) and does not declare it as serving state (``slot_state`` +
 ``init_serving_cache``) is refused at construction.
@@ -24,6 +30,8 @@ that keeps a decode state of its own (``init_decode_state``: ``mamba``,
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, tiny_afmoe_config
 from .generation import (DecodeStep, accept_draft_tokens, greedy_generate,
                          init_kv_cache, sample_tokens)
+from .latent_moe import (LatentMoeConfig, LatentMoeForCausalLM,
+                         tiny_latent_moe_config)
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, tiny_lfm2_config
 from .sdar import SdarMoeConfig, SdarMoeForCausalLM, tiny_sdar_config
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
@@ -38,4 +46,5 @@ __all__ = [
     "AfmoeConfig", "AfmoeForCausalLM", "tiny_afmoe_config",
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "tiny_lfm2_config",
     "SdarMoeConfig", "SdarMoeForCausalLM", "tiny_sdar_config",
+    "LatentMoeConfig", "LatentMoeForCausalLM", "tiny_latent_moe_config",
 ]

@@ -209,15 +209,7 @@ def paged_kv_write(cache, idx: int, k, v, position_ids, block_tables):
     quantized = isinstance(cache, dict)
     kvp = cache["kv"] if quantized else cache
     b, s = position_ids.shape
-    bl = kvp.shape[3]
-    max_blocks = block_tables.shape[1]
-    rows = jnp.arange(b)[:, None]                                  # (B, 1)
-    lb = position_ids // bl                                        # (B, s)
-    phys = jnp.where(
-        lb < max_blocks,
-        block_tables[rows, jnp.minimum(lb, max_blocks - 1)],
-        jnp.int32(0))                      # out-of-table pads -> null block
-    off = position_ids % bl
+    phys, off = paged_write_site(position_ids, block_tables, kvp.shape[3])
     sc = None
     if quantized:
         kvp, sc = _quantized_paged_write(kvp, cache["scale"], idx, 0, k,
@@ -232,6 +224,21 @@ def paged_kv_write(cache, idx: int, k, v, position_ids, block_tables):
                 v.astype(kvp.dtype).reshape(b, s, -1))
     kvp = constrain(kvp, None, None, None, None, "mp")
     return ({"kv": kvp, "scale": sc} if quantized else kvp), kvp, sc
+
+
+def paged_write_site(position_ids, block_tables, bl: int):
+    """(physical block, offset in it), each (B, s), of the logical
+    ``position_ids`` (B, s) through the rows' ``block_tables``
+    (B, max_blocks) over blocks of ``bl``; positions past a table's
+    coverage — prompt padding — go to the null block (id 0)."""
+    max_blocks = block_tables.shape[1]
+    rows = jnp.arange(position_ids.shape[0])[:, None]              # (B, 1)
+    lb = position_ids // bl                                        # (B, s)
+    phys = jnp.where(
+        lb < max_blocks,
+        block_tables[rows, jnp.minimum(lb, max_blocks - 1)],
+        jnp.int32(0))                      # out-of-table pads -> null block
+    return phys, position_ids % bl
 
 
 def part_site(part, rope_cache):

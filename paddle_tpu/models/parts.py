@@ -32,8 +32,28 @@ from typing import Any, Callable, ContextManager, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["DecodePart", "join_tokens", "split_tokens", "join_valid",
-           "part_by_part", "head_tokens", "slot_rows", "slot_rows_back"]
+__all__ = ["DecodePart", "PoolEntry", "join_tokens", "split_tokens",
+           "join_valid", "part_by_part", "head_tokens", "slot_rows",
+           "slot_rows_back"]
+
+
+class PoolEntry(NamedTuple):
+    """What a model declares (``kv_pool_entry``) whose paged pool holds
+    something else a position than K and V rows of
+    ``num_key_value_heads · head_dim``: the serving engine builds the pool,
+    its bytes a block, the cost model's bytes a token and the block-walk
+    counts of its spans from this, once, at construction."""
+
+    arrays: int                 # the pool's second axis: 2 is K and V; 1 an
+    #                             entry that is key and value at once
+    width: int                  # lanes a position an array AS STORED (the
+    #                             layout's padding to 128 lanes included)
+    group: int                  # query rows a key in the flash-decode walk:
+    #                             the heads that share one stored entry
+    layout: Any = None          # the walk's static layout parameter
+    #                             (``ops.pallas.decode_attention
+    #                             .LatentLayout``), whose tiles and copy
+    #                             groups the counts follow
 
 
 class DecodePart(NamedTuple):

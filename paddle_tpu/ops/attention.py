@@ -548,6 +548,73 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
             pool_scale=pool_scale, **win))
 
 
+def latent_decode_attention(q, pool, layer: int, pos, block_tables, layout,
+                            scale: float):
+    """Latent attention in its ABSORBED form over a latent paged pool
+    (``(L, 1, num_blocks, block_len, W)``: one entry a position that is key
+    and value at once, shared by every head) → ``(B, s, H,
+    layout.value_width)``.  ``q`` is ``(B, s, H, W)``: each head's query
+    against the entry as stored; the score is their product over all ``W``
+    lanes times ``scale``, the value the entry's first
+    ``layout.value_width`` lanes (``layout``: the kernel's
+    :class:`~paddle_tpu.ops.pallas.decode_attention.LatentLayout`).  Row
+    ``i``'s logical block ``j`` is physical block ``block_tables[i, j]`` and
+    ``pos`` the int (B,) per-row positions, as for
+    :func:`paged_decode_attention`.
+
+    On a TPU (or with ``FLAGS_pallas_interpret``) the flash-decode walk
+    runs with the latent layout as its static parameter; elsewhere, and in
+    a bare mesh-sharded trace, the XLA twin
+    (:func:`latent_decode_attention_reference`).  Counted under
+    ``ops.kernel_path{op="decode_attention", cache="latent"}``."""
+    path, reason = "pallas_decode", None
+    if not _dispatch.use_pallas():
+        path, reason = "xla_math", FallbackReason(
+            f"no Pallas-capable backend ({_dispatch.default_backend()})",
+            KIND_BACKEND)
+    elif _mesh_sharded_trace():
+        path, reason = "xla_math", FallbackReason(
+            "mesh-sharded trace: the latent walk has no sharded form",
+            KIND_MESH)
+    _dispatch.count_kernel_path(
+        _dispatch.kernel_path_op("decode_attention"), path, cache="latent")
+
+    def pallas():
+        from .pallas.decode_attention import latent_decode_attention_pallas
+        return latent_decode_attention_pallas(
+            q, pool, layer, pos, block_tables, layout, scale,
+            interpret=_dispatch.pallas_interpret())
+
+    return _run_decode_path(
+        path, reason, None, pallas,
+        lambda: latent_decode_attention_reference(
+            q, pool, layer, pos, block_tables, layout.value_width, scale))
+
+
+def latent_decode_attention_reference(q, pool, layer: int, pos, block_tables,
+                                      value_width: int, scale: float):
+    """The XLA math path of :func:`latent_decode_attention` (and its
+    oracle): one gather takes each row's blocks out of ``pool[layer, 0]``,
+    then a masked softmax over the whole table's positions, bf16 operands
+    and float32 accumulation as in the kernel."""
+    b, s, _, w = q.shape
+    bl = pool.shape[-2]
+    mb = block_tables.shape[1]
+    bt = jnp.clip(block_tables, 0, pool.shape[2] - 1)
+    entry = pool[layer, 0, bt].reshape(b, mb * bl, w)
+    scores = jnp.einsum("bshw,blw->bhsl", q, entry,
+                        preferred_element_type=jnp.float32)
+    scores = scores * jnp.float32(scale)
+    qi = jnp.asarray(pos)[:, None] + jnp.arange(s)[None, :]         # (B, s)
+    keep = jnp.arange(mb * bl)[None, None] <= qi[:, :, None]        # (B,s,L)
+    scores = jnp.where(keep[:, None], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhsl,blv->bhsv", p.astype(entry.dtype),
+                     entry[..., :value_width],
+                     preferred_element_type=jnp.float32)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+
+
 @jax.jit
 def _dequant_decode_attention(k_cache, v_cache, k_scale, v_scale):
     """Widen an int8 K/V view back to f32 under its per-block-per-kv-head

@@ -106,6 +106,7 @@ layout runs the same kernel under the identity table
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -167,16 +168,40 @@ def contiguous_block_kv(kv_len: int, n_gran: Optional[int] = None,
     return bk
 
 
-def group_blocks(bk: int) -> int:
+@dataclasses.dataclass(frozen=True)
+class LatentLayout:
+    """The row layout of a LATENT pool (``(L, 1, blocks, block_len, W)``:
+    ONE entry a position that is key and value at once, shared by every
+    head), as a static parameter of the kernel: the value is the entry's
+    first ``value_width`` lanes (the normed latent), the key all ``W`` of
+    them (the latent, the rotated RoPE key, zero lanes up to a multiple of
+    128).  One kv head at a query group of every head, so a q tile may hold
+    ``q_rows`` MXU rows (``q_rows // heads`` tokens of a prompt chunk: each
+    tile reads its range again, so a chunk re-reads it ``s·heads / q_rows``
+    times) and a copy group ``group_keys`` keys (one DMA a block, a third
+    of the K/V layout's bytes a key: longer groups for the same buffers)."""
+    value_width: int
+    q_rows: int = 256
+    group_keys: int = 1024
+
+
+def _tiling(latent: Optional[LatentLayout]) -> Tuple[int, int]:
+    """(a q tile's row cap, a copy group's keys) of a pool's layout: a
+    latent pool's own, or the K/V layout's."""
+    return ((latent.q_rows, latent.group_keys) if latent
+            else (_MAX_Q_ROWS, GROUP_KEYS))
+
+
+def group_blocks(bk: int, group_keys: int = GROUP_KEYS) -> int:
     """Blocks of ``bk`` positions one copy group holds (the kernel's G)."""
-    return max(1, GROUP_KEYS // int(bk))
+    return max(1, int(group_keys) // int(bk))
 
 
-def q_tiles(s: int, g: int) -> Tuple[int, int]:
+def q_tiles(s: int, g: int, max_rows: int = _MAX_Q_ROWS) -> Tuple[int, int]:
     """``(bq, nq)``: one grid step covers ``bq`` query tokens (``bq·g``
-    MXU rows) and ``nq`` of them cover the ``s`` tokens.  ``s <= bq`` is
-    steady decode or a verify window, one tile."""
-    bq = min(s, max(1, _MAX_Q_ROWS // g))
+    MXU rows, at most ``max_rows``) and ``nq`` of them cover the ``s``
+    tokens.  ``s <= bq`` is steady decode or a verify window, one tile."""
+    bq = min(s, max(1, int(max_rows) // g))
     return bq, -(-s // bq)
 
 
@@ -198,24 +223,28 @@ def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, xp=jnp):
 
 
 def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
-                window: Optional[int] = None) -> Tuple[int, int]:
+                window: Optional[int] = None,
+                latent: Optional[LatentLayout] = None) -> Tuple[int, int]:
     """``(kv_blocks, kv_walk)`` of one kernel call on the host, from the
     bounds the kernel itself uses: the blocks its rows' q tiles need
     (``last - first + 1`` each) and the block slots it walks for them
     (whole groups of :func:`group_blocks`).  ``pos``: the call's per-row
-    positions; ``s``, ``g``: its q length and GQA group size."""
-    bq, nq = q_tiles(int(s), int(g))
+    positions; ``s``, ``g``: its q length and GQA group size; ``latent``:
+    the latent pool's layout, whose tiles and groups are its own."""
+    max_rows, group_keys = _tiling(latent)
+    bq, nq = q_tiles(int(s), int(g), max_rows)
     first, last = live_block_range(
         np.asarray(pos, np.int64).reshape(-1, 1), np.arange(nq)[None],
         s=int(s), bq=bq, bk=int(bk), n_cols=int(n_cols), window=window,
         xp=np)
     need = last - first + 1
-    gb = group_blocks(bk)
+    gb = group_blocks(bk, group_keys)
     return int(need.sum()), int((-(-need // gb) * gb).sum())
 
 
 def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
-            tile_p, bk, gb, n_cols, quantized, paged, window, block=1):
+            tile_p, bk, gb, n_cols, quantized, paged, window, block=1,
+            latent=0):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
         # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
@@ -223,8 +252,16 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
         # scalar per (row, block, head) and nothing scale-sized is ever
         # blocked through VMEM (a (1, hkv) block does not tile on a TPU)
         ks_ref, vs_ref, *refs = refs
-    (q_ref, k_hbm, v_hbm, o_ref,
-     k_buf, v_buf, sems, slot_sc, acc_sc, m_sc, l_sc) = refs
+    if latent:
+        # a latent pool (``LatentLayout``): the block that came in as K is
+        # V too — its first ``latent`` lanes — so there is no V operand, no
+        # V buffer and one copy a block
+        (q_ref, k_hbm, o_ref,
+         k_buf, sems, slot_sc, acc_sc, m_sc, l_sc) = refs
+        v_hbm = v_buf = None
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, sems, slot_sc, acc_sc, m_sc, l_sc) = refs
     bi = pl.program_id(0)
     qi = pl.program_id(1)
     n_rows = pl.num_programs(0)
@@ -243,30 +280,29 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
 
     def copies(row, lo, hi, j, slot):
         """Per block of group ``j`` of ``row``'s walk ``[lo, hi]``: whether
-        its column is inside the walk, and its K and V copies into buffer
-        ``slot``.  The table is read at ``min(col, hi)``, never outside
-        the walk."""
+        its column is inside the walk, and its copies into buffer ``slot``
+        (K and V; a latent block's one).  The table is read at
+        ``min(col, hi)``, never outside the walk."""
         out = []
         for i in range(gb):
             col = lo + j * gb + i
             blk = bt_ref[row, jnp.minimum(col, hi)]
             at = pl.ds(i * bk, bk)
-            out.append((
-                col <= hi,
-                pltpu.make_async_copy(block_at(k_hbm, 0, blk),
-                                      k_buf.at[slot, at],
-                                      sems.at[slot, 0, i]),
-                pltpu.make_async_copy(block_at(v_hbm, 1, blk),
-                                      v_buf.at[slot, at],
-                                      sems.at[slot, 1, i])))
+            out.append((col <= hi, [
+                pltpu.make_async_copy(block_at(ref, which, blk),
+                                      buf.at[slot, at],
+                                      sems.at[slot, which, i])
+                for which, (ref, buf) in enumerate(
+                    ((k_hbm, k_buf),) if latent
+                    else ((k_hbm, k_buf), (v_hbm, v_buf)))]))
         return out
 
     def start(row, lo, hi, j, slot):
-        for live, ck, cv in copies(row, lo, hi, j, slot):
+        for live, block_copies in copies(row, lo, hi, j, slot):
             @pl.when(live)
             def _start():
-                ck.start()
-                cv.start()
+                for c in block_copies:
+                    c.start()
 
     # the walk's buffers alternate across the WHOLE grid: this step's
     # group j sits in buffer (slot0 + j) % 2, and its first group was
@@ -297,11 +333,12 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
                   jnp.where(in_row, last, next_last),
                   jnp.where(in_row, j + 1, 0), 1 - slot)
 
-        for i, (live, ck, cv) in enumerate(copies(bi, first, last, j, slot)):
+        for i, (live, block_copies) in enumerate(
+                copies(bi, first, last, j, slot)):
             @pl.when(live)
             def _wait():
-                ck.wait()
-                cv.wait()
+                for c in block_copies:
+                    c.wait()
 
             if i:       # a group's first column is always inside the walk
                 @pl.when(jnp.logical_not(live))
@@ -309,8 +346,9 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
                     # never copied: whatever the buffer holds there must
                     # not reach the PV product as 0 · NaN (its scores are
                     # masked: the columns lie past every visible position)
-                    v_buf[slot, pl.ds(i * bk, bk)] = jnp.zeros(
-                        (bk, hkv * d), v_buf.dtype)
+                    values = k_buf if latent else v_buf
+                    values[slot, pl.ds(i * bk, bk)] = jnp.zeros(
+                        (bk, hkv * d), values.dtype)
 
         # key j visible to tile row r = si·g + gi (si local to the tile)
         # iff j <= pos_b + qi·bq + si — under a block mask, iff j lies no
@@ -344,7 +382,8 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
         for h in range(hkv):
             qh = q_ref[0, h]                              # (tile_p, d)
             kh = k_buf[slot, :, pl.ds(h * d, d)]          # static lane slice
-            vh = v_buf[slot, :, pl.ds(h * d, d)]
+            vh = (k_buf[slot, :, pl.ds(0, latent)] if latent
+                  else v_buf[slot, :, pl.ds(h * d, d)])
             k_s = scale
             if quantized:
                 # int8 in [-127, 127] is exact in bf16, so the cast is
@@ -490,6 +529,45 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
         layer=int(layer), scales=scales, window=window, block=block)
 
 
+def latent_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
+                                   layout: LatentLayout,
+                                   scale: float,
+                                   interpret: bool = False):
+    """Flash-decode of layer ``layer`` over a LATENT paged pool →
+    ``(B, s, H, layout.value_width)``: latent attention in its absorbed
+    form, where every head's key and value are one shared cached entry.
+
+    ``pool`` is the whole ``(L, 1, num_blocks, block_len, W)`` array, the
+    new entries already written; ``q`` is ``(B, s, H, W)``: each head's
+    query against the entry as it is stored (the latent part already
+    carried through the key up-projection, the RoPE part beside it, zeros
+    in the lanes the entry pads).  The score is the plain product over all
+    ``W`` lanes times ``scale`` (the caller's: the plain form's head size
+    decides it, not ``W``), the value the entry's first
+    ``layout.value_width`` lanes.  The walk, the mask, ``pos`` and
+    ``block_tables`` are :func:`paged_decode_attention_pallas`'s; there is
+    no window, no block mask and no int8 form of this layout."""
+    b, s, hq, w = q.shape
+    if pool.ndim != 5 or pool.shape[1] != 1 or pool.shape[-1] != w:
+        raise NotImplementedError(
+            f"latent pool {pool.shape} is not (L, 1, blocks, block_len, "
+            f"{w}) for queries of width {w}")
+    bk = pool.shape[-2]
+    if bk % 128 or w % _LANES or layout.value_width % _LANES \
+            or layout.value_width > w:
+        raise NotImplementedError(
+            f"latent layout: block_len {bk}, entry width {w} and value "
+            f"width {layout.value_width} must be 128-aligned")
+    if hq > layout.q_rows or s > _MAX_Q_LEN:
+        raise NotImplementedError(
+            f"latent layout: {hq} heads > a q tile of {layout.q_rows} "
+            f"rows, or q_len {s} > {_MAX_Q_LEN}")
+    return _flash_decode(
+        q, pool, None, pos, jnp.asarray(block_tables, jnp.int32),
+        scale=scale, interpret=interpret, layer=int(layer), scales=None,
+        latent=layout)
+
+
 def _check_q(q, hkv: int) -> None:
     """The q-side shape gates both layouts share (ops/pallas/limits.py)."""
     _, s, hq, d = q.shape
@@ -509,7 +587,7 @@ def _check_q(q, hkv: int) -> None:
 
 
 def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
-                  scales, window=None, block=1):
+                  scales, window=None, block=1, latent=None):
     """Both layouts' way into the one ``pallas_call``.  ``k_arr``/``v_arr``
     are the operands as they lie in HBM (the kernel leaves them there):
     the paged pool twice with its ``layer``, or the contiguous cache's K
@@ -517,14 +595,16 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
     is the (B, columns) table of block ids and ``scales`` the int8 cache's
     (B, columns, Hkv) K and V scale tables, or None; ``window`` the static
     sliding window, or None; ``block`` the static length of the
-    block-causal mask's blocks (1: causal)."""
+    block-causal mask's blocks (1: causal); ``latent`` the
+    :class:`LatentLayout` of a latent pool (``v_arr`` None), or None."""
     b, s, hq, d = q.shape
     hkv = k_arr.shape[-1] // d
     block = int(block)
-    if block > 1 and (s % block or q_tiles(s, hq // hkv)[0] % block):
+    tiles = q_tiles(s, hq // hkv, _tiling(latent)[0])
+    if block > 1 and (s % block or tiles[0] % block):
         raise NotImplementedError(
             f"block mask of {block}: q_len {s} and the q tile "
-            f"{q_tiles(s, hq // hkv)[0]} must be multiples of it")
+            f"{tiles[0]} must be multiples of it")
     quantized = scales is not None
     n_cols = bt.shape[1]
     if quantized and b * n_cols * hkv > _limits.MAX_SCALE_TABLE:
@@ -547,10 +627,11 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
     from .. import _dispatch as _disp
     _disp.count_kernel_path(
         _disp.kernel_path_op(
-            "chunked_prefill" if q_tiles(s, hq // hkv)[1] > 1
+            "chunked_prefill" if tiles[1] > 1
             else "decode_attention_kernel"),
         "paged" if paged else "contiguous",
-        **({"cache": "int8"} if quantized else {}))
+        **({"cache": "int8"} if quantized else
+           {"cache": "latent"} if latent else {}))
 
     scalars = (pos_arr, bt, jnp.full((1,), layer or 0, jnp.int32))
     if quantized:
@@ -560,25 +641,32 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
         scalars, q, k_arr, v_arr,
         scale=float(d ** -0.5 if scale is None else scale), paged=paged,
         window=None if window is None else int(window), block=block,
-        interpret=interpret, name=_disp.kernel_name("flash_decode"))
+        interpret=interpret, latent=latent, name=_disp.kernel_name(
+            "latent_flash_decode" if latent else "flash_decode"))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "paged", "window",
-                                             "block", "interpret", "name"))
+                                             "block", "interpret", "name",
+                                             "latent"))
 def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
-                name, block=1):
+                name, block=1, latent=None):
     """The ``pallas_call`` with the q layout round it, jitted on its own:
     a model's layers differ only in the VALUE of the layer scalar, so they
     share one trace of the kernel body and one lowering of it in every
     program that calls them (``scalars``: positions, block table, layer,
-    then the int8 cache's K and V scale tables)."""
+    then the int8 cache's K and V scale tables).  ``latent``: the pool is
+    a latent one (``LatentLayout``; ``v_arr`` None): one operand, one
+    buffer, the output ``value_width`` wide."""
     b, s, hq, d = q.shape
     bk, hd = k_arr.shape[-2:]
     hkv = hd // d
     g = hq // hkv
     rows = s * g
-    bq, nq = q_tiles(s, g)
-    gb = group_blocks(bk)
+    max_rows, group_keys = _tiling(latent)
+    bq, nq = q_tiles(s, g, max_rows)
+    gb = group_blocks(bk, group_keys)
+    dv = latent.value_width if latent else d      # the output's width
+    kv = (k_arr,) if latent else (k_arr, v_arr)
     tile_p = max(8, -(-(bq * g) // 8) * 8)  # sublane-pad each q tile
     # grouped-GQA q layout: (B, Hkv, s·G, D), row r = si·g + gi — then cut
     # into nq tiles of bq·g rows, each sublane-padded to tile_p, so one
@@ -596,7 +684,8 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
     kernel = functools.partial(
         _kernel, scale=scale, s=s, g=g, hkv=hkv, d=d, bq=bq, nq=nq,
         tile_p=tile_p, bk=bk, gb=gb, n_cols=scalars[1].shape[1],
-        quantized=len(scalars) > 3, paged=paged, window=window, block=block)
+        quantized=len(scalars) > 3, paged=paged, window=window, block=block,
+        **({"latent": dv} if latent else {}))
 
     def q_idx(bi, qi, *_):
         return (bi, 0, qi, 0)
@@ -606,32 +695,29 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(b, nq),
-            in_specs=[
-                pl.BlockSpec((1, hkv, tile_p, d), q_idx),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, hkv, tile_p, d), q_idx),
+            in_specs=[pl.BlockSpec((1, hkv, tile_p, d), q_idx)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(kv),
+            out_specs=pl.BlockSpec((1, hkv, tile_p, dv), q_idx),
             scratch_shapes=[
-                # two buffers of one group of K and of V blocks, a DMA
-                # semaphore a copy, and the buffer the next step starts in
-                pltpu.VMEM((2, gb * bk, hd), k_arr.dtype),
-                pltpu.VMEM((2, gb * bk, hd), v_arr.dtype),
-                pltpu.SemaphoreType.DMA((2, 2, gb)),
+                # two buffers of one group of K and of V blocks (a latent
+                # pool: of its one array), a DMA semaphore a copy, and the
+                # buffer the next step starts in
+                *(pltpu.VMEM((2, gb * bk, hd), a.dtype) for a in kv),
+                pltpu.SemaphoreType.DMA((2, len(kv), gb)),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((hkv, tile_p, d), jnp.float32),
+                pltpu.VMEM((hkv, tile_p, dv), jnp.float32),
                 pltpu.VMEM((hkv, tile_p, _LANES), jnp.float32),
                 pltpu.VMEM((hkv, tile_p, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * tile_p, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * tile_p, dv), q.dtype),
         # sequential: a step issues the next step's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(*scalars, qg, k_arr, v_arr)
-    out = out.reshape(b, hkv, nq, tile_p, d)[:, :, :, :bq * g]
-    out = out.reshape(b, hkv, nq * bq * g, d)[:, :, :rows]
-    out = out.reshape(b, hkv, s, g, d).transpose(0, 2, 1, 3, 4)
-    return out.reshape(b, s, hq, d).astype(q.dtype)
+    )(*scalars, qg, *kv)
+    out = out.reshape(b, hkv, nq, tile_p, dv)[:, :, :, :bq * g]
+    out = out.reshape(b, hkv, nq * bq * g, dv)[:, :, :rows]
+    out = out.reshape(b, hkv, s, g, dv).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, s, hq, dv).astype(q.dtype)

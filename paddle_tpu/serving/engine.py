@@ -691,8 +691,14 @@ class ServingEngine:
         # only (``attention_windows``), and whether it generates by diffusion
         # over blocks (``block_diffusion``: the block length and mask token,
         # by which the rows part becomes a block rows part, and the unmasking
-        # rule's defaults).  A model that keeps a decode state of
+        # rule's defaults), and what its paged pool holds a position where
+        # that is not K and V rows (``kv_pool_entry``, a
+        # ``models.parts.PoolEntry``: a latent model's one entry that is key
+        # and value at once — the pool's second axis and width, its bytes,
+        # the pre-flight and the spans' walk counts follow it).  A model
+        # that keeps a decode state of
         # its own and declares none of it is refused here, by name.
+        self._pool_entry = getattr(self._bind, "kv_pool_entry", None)
         self._diffusion = getattr(self._bind, "block_diffusion", None)
         self._block = int(self._diffusion.length) if self._diffusion else 0
         self._slot_leaves = tuple(getattr(self._bind, "slot_state", ()))
@@ -748,17 +754,27 @@ class ServingEngine:
                 self._kv_layers = int(pool.shape[0])
             else:
                 cache = init_paged_kv_cache(model.config, nb, bl,
-                                            quantized=self.quantized)
+                                            quantized=self.quantized,
+                                            entry=self._pool_entry)
             # arm the pool's bytes_by_dtype gauges with this model's
             # per-block costs (payload + the int8 block's scale row)
             c = model.config
-            tok = (self._kv_layers * 2 * c.num_key_value_heads
-                   * c.head_dim)
             native = jnp.zeros((), c.dtype).dtype.itemsize
-            self.kv.set_block_nbytes({
-                "bf16": tok * bl * native,
-                "int8": tok * bl
-                + self._kv_layers * 2 * c.num_key_value_heads * 4})
+            if self._pool_entry is not None:
+                # a declared entry: its arrays and stored width a position
+                self._position_bytes = (
+                    self._kv_layers * self._pool_entry.arrays
+                    * self._pool_entry.width * native)
+                self.kv.set_block_nbytes(
+                    {"bf16": self._position_bytes * bl})
+                self._m_pool_position_bytes.set(float(self._position_bytes))
+            else:
+                tok = (self._kv_layers * 2 * c.num_key_value_heads
+                       * c.head_dim)
+                self.kv.set_block_nbytes({
+                    "bf16": tok * bl * native,
+                    "int8": tok * bl
+                    + self._kv_layers * 2 * c.num_key_value_heads * 4})
         else:
             cache = init_kv_cache(model.config, self.num_slots,
                                   self.max_length,
@@ -1044,10 +1060,14 @@ class ServingEngine:
         # int8 scale amortization granule: the paged pool keeps one
         # scale row per block, the contiguous pool one per 128-token
         # granule (models/generation.init_kv_cache)
-        kv_tok = _cm.kv_bytes_per_token(
-            self.config, self.kv_dtype,
-            block_len=self.block_len if self.paged else 128,
-            num_layers=self._kv_layers)
+        if self._pool_entry is not None:
+            # what the walk streams a live position: the entry as stored
+            kv_tok = float(self._position_bytes)
+        else:
+            kv_tok = _cm.kv_bytes_per_token(
+                self.config, self.kv_dtype,
+                block_len=self.block_len if self.paged else 128,
+                num_layers=self._kv_layers)
         comm_fn = None
         if self.mesh is not None:
             def comm_fn():
@@ -1519,6 +1539,12 @@ class ServingEngine:
                 "kv_cache.state_bytes",
                 "bytes of the fixed-size per-slot state leaves").labels(
                     **lbl)
+        if self._pool_entry is not None:
+            self._m_pool_position_bytes = gauge(
+                "kv_cache.position_bytes",
+                "bytes one position holds in the paged pool over every "
+                "layer, as stored (a declared pool entry's arrays and "
+                "padded width)").labels(**lbl)
         if self._windows:
             self._m_window_dead = gauge(
                 "kv_cache.window_dead_positions",
@@ -2426,6 +2452,12 @@ class ServingEngine:
                 f"{type(self._bind).__name__} cannot be served with "
                 f"{what}: a request's record carries its KV blocks and not "
                 f"its fixed-size per-slot state {self._slot_leaves}")
+        if self._pool_entry is not None:
+            raise NotImplementedError(
+                f"{type(self._bind).__name__} cannot be served with "
+                f"{what}: no test shows a record's blocks exact on a pool "
+                f"of a declared entry ({self._pool_entry.arrays} array(s) "
+                f"of {self._pool_entry.width} lanes a position)")
 
     def export_request(self, rid: int,
                        release: bool = True) -> Optional[Dict[str, object]]:
@@ -2636,6 +2668,17 @@ class ServingEngine:
             cols = self.max_length // bk
         windows = (getattr(self._bind, "attention_windows", None)
                    or (None,) * self._kv_layers)
+        if self._pool_entry is not None:
+            # the declared entry's walk: every head one query group over
+            # the one stored entry, tiles and copy groups the layout's; its
+            # spans also say how many of the blocks the rows walk are
+            # distinct (rows of one shared prefix walk the same blocks).
+            # Resolved here, once: the tick calls ``self._kv_walk``
+            self._kv_walk_geom = (
+                int(self._pool_entry.group), int(bk), int(cols),
+                ((None, self._kv_layers),))
+            self._kv_walk = self._kv_walk_shared
+            return
         self._kv_walk_geom = (
             int(c.num_attention_heads) // int(c.num_key_value_heads),
             int(bk), int(cols),
@@ -2661,6 +2704,36 @@ class ServingEngine:
                 blocks += n * kb
                 walk += n * kw
         return {"kv_blocks": blocks, "kv_walk": walk}
+
+    def _kv_walk_shared(self, *calls) -> Dict[str, int]:
+        """:meth:`_kv_walk` for a model that declares its pool's entry
+        (``kv_pool_entry``): the same two counts by the layout's own tiles
+        and groups, and of the ROWS part alone (the first call), a layer:
+        ``rows_depth`` the live rows' summed depths (the positions each
+        row's query sees), ``rows_blocks`` the blocks their walks read,
+        ``rows_distinct`` how many different physical blocks those are and
+        ``rows_positions`` the different positions they hold — rows that
+        adopted one prefix walk the same blocks, each for itself."""
+        from ..ops.pallas.decode_attention import walk_counts
+        g, bk, cols, ((_, layers),) = self._kv_walk_geom
+        layout = self._pool_entry.layout
+        blocks = walk = 0
+        for pos, s in calls:
+            kb, kw = walk_counts(pos, s, g, bk=bk, n_cols=cols,
+                                 latent=layout)
+            blocks += layers * kb
+            walk += layers * kw
+        live = np.flatnonzero(self._active)
+        depth = self._positions[live].astype(np.int64) + 1
+        last = (depth - 1) // bk            # a row's last block is its own
+        full = [self._tables[i, :n] for i, n in zip(live, last)]
+        distinct = len(np.unique(np.concatenate(full))) if full else 0
+        return {"kv_blocks": blocks, "kv_walk": walk,
+                "rows_depth": int(depth.sum()),
+                "rows_blocks": int((last + 1).sum()),
+                "rows_distinct": distinct + len(live),
+                "rows_positions": distinct * bk
+                + int((depth - last * bk).sum())}
 
     def _note_sample_path(self, *knobs) -> str:
         """Name and count the way this tick's sampling epilogue goes:
@@ -3395,6 +3468,11 @@ class ServingEngine:
         the program each device actually compiles; whole-model heads
         would overstate VMEM by mp×)."""
         from .. import static_analysis as _sa
+        extra = getattr(self._bind, "serving_kernel_specs", None)
+        if self._pool_entry is not None:
+            # the registry's decode-attention spec models K and V rows of
+            # Hkv·D; a declared entry's walk has no spec there yet
+            return list(extra([self._pass_rows])) if extra else []
         lanes = 128
         c = self.config
         hkv = int(c.num_key_value_heads)
@@ -3448,7 +3526,6 @@ class ServingEngine:
                     # 128-token granule (kv_p is lane-aligned above)
                     n_granules=kv_p // lanes if quantized else None,
                     variant=tag))
-        extra = getattr(self._bind, "serving_kernel_specs", None)
         if extra is not None:
             # kernels only this model's steps build, token-wise: once over
             # the tokens of the step program's one pass

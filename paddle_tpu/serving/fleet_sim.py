@@ -143,7 +143,7 @@ class SimEngine(ServingEngine):
         # positions, no per-slot state, no kernel whose block walk the
         # spans would count
         self._expert_layers, self._windows, self._slot_leaves = 0, (), ()
-        self._kv_walk_geom = None
+        self._kv_walk_geom = self._pool_entry = None
         # the simulator is paged-only: the BlockManager IS the part of
         # the memory system worth simulating (admission blocking,
         # prefix hits, preemption, the host tier)

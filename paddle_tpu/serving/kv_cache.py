@@ -171,7 +171,7 @@ class _StatsView(Mapping):
 
 def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
                         quantized: bool = False,
-                        num_layers: Optional[int] = None):
+                        num_layers: Optional[int] = None, entry=None):
     """Pooled paged cache: (L, 2, num_blocks, block_len, kv_heads·head_dim)
     — the contiguous cache's (B, max_len) plane re-cut into fixed blocks,
     each block in the layout the flash-decode kernel DMAs (heads fused
@@ -185,12 +185,24 @@ def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
     scatter-time writes).  Zero scale == empty block (dequantizes to 0).
     The pytree threads through the engine's jitted step exactly like the
     plain array (same argnum, donated wholesale).
+
+    ``entry`` (a model's ``kv_pool_entry``, ``models.parts.PoolEntry``):
+    the pool holds ``entry.arrays`` arrays a layer of ``entry.width`` lanes
+    a position instead — a latent model's ONE entry that is key and value
+    at once, ``(L, 1, num_blocks, block_len, width)``.  Blocks, tables, the
+    trie and copy-on-write address axis 2 and know no layout.
     """
     import jax.numpy as jnp
 
     shape = (config.num_hidden_layers if num_layers is None
-             else int(num_layers), 2, num_blocks, block_len,
-             config.num_key_value_heads * config.head_dim)
+             else int(num_layers),
+             *((2, num_blocks, block_len,
+                config.num_key_value_heads * config.head_dim)
+               if entry is None else
+               (int(entry.arrays), num_blocks, block_len, int(entry.width))))
+    if quantized and entry is not None:
+        raise NotImplementedError(
+            "init_paged_kv_cache: a declared pool entry has no int8 form")
     if quantized:
         return {
             "kv": jnp.zeros(shape, jnp.int8),

@@ -275,6 +275,27 @@ def cell_engines(root):
     pt.flags.set_flags({"perf_model": "off"})
     yield cell, ServingEngine(model, seed=0, **kw), None
     pt.flags.set_flags({"perf_model": "on"})
+    del model
+
+    # the sixth cell, on a checkout that has it: the dense layer 0 and one
+    # of the 39 expert layers (all alike), every held expert, latent
+    # attention over a pool of one entry a position
+    cell = "joyai-llm-flash-ep16.shared-doc-saturated"
+    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
+                                       cell + ".json")):
+        return
+    from benchmark.harness import serve_mla
+    from paddle_tpu.models.latent_moe import LatentMoeForCausalLM
+    kw = dict(cell_file("workloads", cell)["engine"], num_blocks=129)
+    cfg = dict(cell_file("configs", "joyai-llm-flash-ep16"),
+               num_hidden_layers=2)
+    with nn.abstract_parameters():
+        model = LatentMoeForCausalLM(
+            serve_mla.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    yield cell, ServingEngine(model, seed=0, **kw), None
+    pt.flags.set_flags({"perf_model": "on"})
 
 
 def main(root):

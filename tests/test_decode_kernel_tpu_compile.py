@@ -23,11 +23,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 from lowered_step_text import (cell_engines, kernel_calls, lowered,
-                               product_reads)
+                               prefill_args, product_reads, sha)
 
 from paddle_tpu.ops import _dispatch
 from paddle_tpu.ops.pallas.decode_attention import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    LatentLayout, decode_attention_pallas, latent_decode_attention_pallas,
+    paged_decode_attention_pallas)
 
 HKV, D, BLOCK = 8, 128, 128
 
@@ -120,6 +121,33 @@ def test_block_masked_kernel_compiles_for_v5e(one_chip, rows, s):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+@pytest.mark.parametrize("rows, s", [(96, 1), (1, 256)],
+                         ids=["latent-rows", "latent-chunk"])
+def test_latent_kernel_compiles_for_v5e(one_chip, rows, s):
+    """The body with the latent layout as its static parameter at the
+    JoyAI cell's geometry: ONE array a layer of 640 stored lanes a position
+    (576 values), 32 heads one query group (a rows tile of 32 MXU rows, a
+    chunk's of 256: 8 tokens), the value the entry's first 512 lanes, copy
+    groups of 1,024 keys, tables of 96 columns, all 40 layers."""
+    heads, width, layers, blocks, cols = 32, 640, 40, 700, 96
+    args = [_spec(one_chip, (rows, s, heads, width), jnp.bfloat16),
+            _spec(one_chip, (layers, 1, blocks, BLOCK, width), jnp.bfloat16),
+            _spec(one_chip, (rows,), jnp.int32),
+            _spec(one_chip, (rows, cols), jnp.int32)]
+
+    def call(q, pool, pos, tables):
+        return latent_decode_attention_pallas(
+            q, pool, layers - 1, pos, tables, LatentLayout(value_width=512),
+            192 ** -0.5)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (rows, s, heads, 512)
+    # the pool is read where it lies: no temporary of a layer's size
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 def test_contiguous_kernel_compiles_for_v5e(one_chip):
     q = _spec(one_chip, (8, 1, 32, D), jnp.bfloat16)
     kv = _spec(one_chip, (8, 4096, HKV, D), jnp.bfloat16)
@@ -132,14 +160,34 @@ def test_contiguous_kernel_compiles_for_v5e(one_chip):
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # cell: expert layers at the cut depth
+JOYAI = "joyai-llm-flash-ep16.shared-doc-saturated"
 MIXED = {"mistral-7b.chat-open": 0,
          "trinity-large-ep8.longtail-saturated": 1,
          "lfm2-8b-a1b-ep2.decode-wide-saturated": 2,
-         "sdar-30b-a3b-ep8.block-decode-saturated": 2}
+         "sdar-30b-a3b-ep8.block-decode-saturated": 2,
+         JOYAI: 1}
 # the name of the rows part's attention kernel, where it is not the decode
-# rows': a block-diffusion model's rows are blocks
+# rows': a block-diffusion model's rows are blocks, a latent pool's walk has
+# its own name
 ROWS_KERNEL = {"sdar-30b-a3b-ep8.block-decode-saturated":
-               "_step_impl_block_rows_flash_decode"}
+               "_step_impl_block_rows_flash_decode",
+               JOYAI: "_step_impl_decode_rows_latent_flash_decode"}
+CHUNK_KERNEL = {JOYAI: "_step_impl_prompt_chunk_latent_flash_decode"}
+# the step and prefill programs of the five cells PR 42 found, as
+# ``lowered_step_text.py`` hashes them (kernel bodies re-printed without
+# locations): a latent pool's layout is a static parameter of the one
+# flash-decode body, and every call site that was there keeps its statics
+# and its lowered text.  A PR that means to change one of these programs
+# re-pins its line from the tool's output and says so.
+PINNED = {
+    ("mistral-7b.decode-saturated", "step"): "9caac2d72281204d",
+    ("mistral-7b.decode-saturated", "prefill"): "ecf0b26814cb2758",
+    ("mistral-7b.decode-saturated", "prefill rows=1"): "d8d687e81c01f5bc",
+    ("mistral-7b.chat-open", "step"): "20f63b0095305316",
+    ("trinity-large-ep8.longtail-saturated", "step"): "d4ccafe6ed7e5223",
+    ("lfm2-8b-a1b-ep2.decode-wide-saturated", "step"): "384cbb8aa6a57bd9",
+    ("sdar-30b-a3b-ep8.block-decode-saturated", "step"): "ce7ebe57d94d1c46",
+}
 
 
 @pytest.fixture(scope="module")
@@ -150,8 +198,21 @@ def mixed_engines():
     they lower and compile, nothing runs."""
     patch = pytest.MonkeyPatch()
     patch.setattr(_dispatch, "default_backend", lambda: "tpu")
-    yield {cell: eng for cell, eng, _ in cell_engines(ROOT) if eng.chunked}
+    yield {cell: eng for cell, eng, _ in cell_engines(ROOT)}
     patch.undo()
+
+
+@pytest.mark.parametrize("cell, program", list(PINNED),
+                         ids=[f"{c}-{p.replace(' ', '-')}" for c, p in PINNED])
+def test_older_cells_programs_lower_to_the_text_they_had(mixed_engines, cell,
+                                                         program):
+    eng = mixed_engines[cell]
+    if program == "step":
+        low = lowered(eng._step_fn.python_fn, eng._lint_args())
+    else:
+        low = lowered(eng._prefill_fn.python_fn, prefill_args(
+            eng, 256, 1 if program.endswith("rows=1") else None))
+    assert sha(low)[0] == PINNED[cell, program]
 
 
 @pytest.mark.parametrize("cell", list(MIXED))
@@ -162,9 +223,13 @@ def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell):
     reads = product_reads(low, args)
     # tables and filters no product reads: the embedding where the head is
     # not tied to it, RoPE's, a short convolution's taps
+    # (and a latent layer's up-projection, which is sliced apart first: its
+    # key half goes to the query in one product, its value half to the
+    # result in another)
     assert {k for k, n in reads.items() if n == 0} <= {
         k for k in reads if k.endswith(("rope_cos']", "rope_sin']",
-                                        "embed_tokens']", ".conv.conv']"))}
+                                        "embed_tokens']", ".conv.conv']",
+                                        ".kv_b_proj']"))}
     twice = {k: n for k, n in reads.items() if n > 1}
     assert not twice, twice
     assert sum(reads.values()) >= 5 * eng.config.num_hidden_layers
@@ -175,7 +240,7 @@ def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell):
     # attention stays a part at a time, under the part's own name
     assert {k for k in calls if "flash_decode" in k} == {
         ROWS_KERNEL.get(cell, "_step_impl_decode_rows_flash_decode"),
-        "_step_impl_prompt_chunk_flash_decode"}
+        CHUNK_KERNEL.get(cell, "_step_impl_prompt_chunk_flash_decode")}
 
 
 @pytest.mark.parametrize("cell", list(MIXED))
@@ -187,7 +252,9 @@ def test_mixed_program_compiles_for_v5e(one_chip, mixed_engines, cell):
         params, cache, *operands).compile()
     text = compiled.as_text()
     assert text.count("_step_impl_token_pass_moe_experts") >= 3 * MIXED[cell]
-    assert "_step_impl_prompt_chunk_flash_decode" in text
+    assert CHUNK_KERNEL.get(
+        cell, "_step_impl_prompt_chunk_flash_decode") in text
+    print(cell, "temporaries", compiled.memory_analysis().temp_size_in_bytes)
 
     def largest(tree):
         return max(x.size * x.dtype.itemsize
