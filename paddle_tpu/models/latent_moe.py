@@ -366,7 +366,7 @@ class LatentAttention(Layer):
                                   position_ids, part.block_tables)
         return latent_decode_attention(
             stored(q_lat, q_rope), cache, idx, pos, part.block_tables,
-            c.latent_layout, self.scale), cache
+            c.latent_layout, self.scale, shared=part.shared), cache
 
 
 class LatentMoE(Layer):

@@ -77,6 +77,12 @@ class DecodePart(NamedTuple):
     scope: Callable[[], ContextManager] = contextlib.nullcontext
     #                             opened around what runs for this part
     #                             alone (names its kernels in a trace)
+    shared: Any = None          # decode rows over a pool read through a
+    #                             prefix trie: which rows hold the same
+    #                             blocks in their leading columns
+    #                             (``ops.pallas.decode_attention
+    #                             .SharedWalk``), for a read that can walk
+    #                             them once for those rows; None: unknown
 
 
 def join_tokens(xs):

@@ -549,7 +549,7 @@ def paged_decode_attention(q, pool, layer: int, pos, block_tables,
 
 
 def latent_decode_attention(q, pool, layer: int, pos, block_tables, layout,
-                            scale: float):
+                            scale: float, shared=None):
     """Latent attention in its ABSORBED form over a latent paged pool
     (``(L, 1, num_blocks, block_len, W)``: one entry a position that is key
     and value at once, shared by every head) → ``(B, s, H,
@@ -566,7 +566,15 @@ def latent_decode_attention(q, pool, layer: int, pos, block_tables, layout,
     runs with the latent layout as its static parameter; elsewhere, and in
     a bare mesh-sharded trace, the XLA twin
     (:func:`latent_decode_attention_reference`).  Counted under
-    ``ops.kernel_path{op="decode_attention", cache="latent"}``."""
+    ``ops.kernel_path{op="decode_attention", cache="latent"}``.
+
+    ``shared`` (a :class:`~paddle_tpu.ops.pallas.decode_attention
+    .SharedWalk`, decode rows only): which rows hold the same blocks in
+    their leading columns, and so have them walked once a q tile of
+    stacked rows instead of once a row; the same result (the kernel's
+    docstring has the two parts; the twin reads those columns through the
+    tile leader's table row as the kernel does, so a grouping that names
+    the wrong rows shows in both)."""
     path, reason = "pallas_decode", None
     if not _dispatch.use_pallas():
         path, reason = "xla_math", FallbackReason(
@@ -583,23 +591,32 @@ def latent_decode_attention(q, pool, layer: int, pos, block_tables, layout,
         from .pallas.decode_attention import latent_decode_attention_pallas
         return latent_decode_attention_pallas(
             q, pool, layer, pos, block_tables, layout, scale,
-            interpret=_dispatch.pallas_interpret())
+            interpret=_dispatch.pallas_interpret(), shared=shared)
 
     return _run_decode_path(
         path, reason, None, pallas,
         lambda: latent_decode_attention_reference(
-            q, pool, layer, pos, block_tables, layout.value_width, scale))
+            q, pool, layer, pos, block_tables, layout.value_width, scale,
+            shared=shared))
 
 
 def latent_decode_attention_reference(q, pool, layer: int, pos, block_tables,
-                                      value_width: int, scale: float):
+                                      value_width: int, scale: float,
+                                      shared=None):
     """The XLA math path of :func:`latent_decode_attention` (and its
     oracle): one gather takes each row's blocks out of ``pool[layer, 0]``,
     then a masked softmax over the whole table's positions, bf16 operands
-    and float32 accumulation as in the kernel."""
+    and float32 accumulation as in the kernel.  Under a ``shared`` walk a
+    row's leading ``shared.n`` columns are taken from its tile leader's
+    table row, where the kernel's tiles read them."""
     b, s, _, w = q.shape
     bl = pool.shape[-2]
     mb = block_tables.shape[1]
+    if shared is not None:
+        n, at, tile_rows, _ = (jnp.asarray(x, jnp.int32) for x in shared)
+        leader = tile_rows[:, 0][at // tile_rows.shape[1]]
+        block_tables = jnp.where(jnp.arange(mb)[None] < n[:, None],
+                                 block_tables[leader], block_tables)
     bt = jnp.clip(block_tables, 0, pool.shape[2] - 1)
     entry = pool[layer, 0, bt].reshape(b, mb * bl, w)
     scores = jnp.einsum("bshw,blw->bhsl", q, entry,

@@ -102,13 +102,25 @@ handles partial blocks — column indices are logical).  The contiguous
 layout runs the same kernel under the identity table
 ``table[bi, ki] = bi·chunks + ki`` over its reshaped
 ``(B·chunks, bk, Hkv·D)`` cache.
+
+**A shared prefix walked once** (:class:`SharedWalk`,
+:func:`latent_decode_attention_pallas`; the latent layout's decode rows):
+rows that adopted one prefix through the trie hold the same block ids in
+their leading table columns, and walking them a row at a time copies those
+blocks once a row at a q tile of one row's heads.  Told which rows share
+which columns, the walk runs in two parts under the one kernel name: the
+body over q TILES of stacked rows, each walking its group's shared columns
+once and handing on ``(acc, m, l)``, then the body over the rows from
+their first own column on, each started from what its tile left.  The
+copies fall by the rows a tile, the MXU's fill rises by them, and the
+merge is the group boundary's rescale every walk already makes.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -192,6 +204,34 @@ def _tiling(latent: Optional[LatentLayout]) -> Tuple[int, int]:
             else (_MAX_Q_ROWS, GROUP_KEYS))
 
 
+class SharedWalk(NamedTuple):
+    """Which decode rows of a call walk a run of leading table columns
+    TOGETHER (:func:`latent_decode_attention_pallas`): rows whose tables
+    hold the same physical blocks in columns ``[0, n)`` are stacked into q
+    tiles of :func:`tile_members` rows each, a tile walks those columns
+    once for its members, and each member's own walk starts at column ``n``
+    from what the tile left.  Fixed shapes, so one program serves every
+    grouping; the live tiles come first."""
+
+    n: Any                      # int (rows,): the leading columns row i
+    #                             does not walk itself; 0: it walks alone
+    at: Any                     # int (rows,): ``tile · members + place``
+    #                             of a row with ``n > 0`` (any other row:
+    #                             whatever saves a copy, it is not read)
+    tile_rows: Any              # int (tiles, members): a tile's rows, the
+    #                             first its LEADER, through whose table row
+    #                             the shared columns are read; a place no
+    #                             row holds names any row
+    tile_n: Any                 # int (tiles,): the columns a tile walks;
+    #                             0: an empty tile
+
+
+def tile_members(latent: LatentLayout, heads: int) -> int:
+    """The decode rows one shared tile stacks: a q tile's MXU rows over
+    the heads a row brings."""
+    return max(1, latent.q_rows // int(heads))
+
+
 def group_blocks(bk: int, group_keys: int = GROUP_KEYS) -> int:
     """Blocks of ``bk`` positions one copy group holds (the kernel's G)."""
     return max(1, int(group_keys) // int(bk))
@@ -205,17 +245,22 @@ def q_tiles(s: int, g: int, max_rows: int = _MAX_Q_ROWS) -> Tuple[int, int]:
     return bq, -(-s // bq)
 
 
-def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, xp=jnp):
+def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, first=None,
+                     xp=jnp):
     """``(first, last)``: the table columns holding a key that some query
     of q tile ``qi`` (offsets ``qi·bq .. min((qi+1)·bq, s) - 1``) of a row
     at position ``pos`` may see — the columns the kernel walks, and the
     only ones it dereferences.  ``last`` holds the tile's last query's own
     key; ``first`` is 0, or with a sliding ``window`` the block of the
-    oldest key the tile's FIRST query still sees.  Both stay inside the
-    table whatever ``pos`` (an idle row parked past the cache included).
-    Scalars in the kernel (``xp=jnp``), arrays on the host (``xp=np``)."""
+    oldest key the tile's FIRST query still sees, or the row's ``first``
+    where a shared walk already read the columns before it
+    (:class:`SharedWalk`).  Both stay inside the table whatever ``pos`` (an
+    idle row parked past the cache included).  Scalars in the kernel
+    (``xp=jnp``), arrays on the host (``xp=np``)."""
     last = xp.minimum((pos + xp.minimum((qi + 1) * bq, s) - 1) // bk,
                       n_cols - 1)
+    if first is not None:
+        return xp.minimum(first, last), last
     if window is None:
         return xp.zeros_like(last), last
     return xp.minimum(xp.maximum(pos + qi * bq - window + 1, 0) // bk,
@@ -224,19 +269,23 @@ def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, xp=jnp):
 
 def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
                 window: Optional[int] = None,
-                latent: Optional[LatentLayout] = None) -> Tuple[int, int]:
+                latent: Optional[LatentLayout] = None,
+                first=None) -> Tuple[int, int]:
     """``(kv_blocks, kv_walk)`` of one kernel call on the host, from the
     bounds the kernel itself uses: the blocks its rows' q tiles need
     (``last - first + 1`` each) and the block slots it walks for them
     (whole groups of :func:`group_blocks`).  ``pos``: the call's per-row
     positions; ``s``, ``g``: its q length and GQA group size; ``latent``:
-    the latent pool's layout, whose tiles and groups are its own."""
+    the latent pool's layout, whose tiles and groups are its own;
+    ``first``: per row, the column its own walk starts at behind a shared
+    one (:class:`SharedWalk`)."""
     max_rows, group_keys = _tiling(latent)
     bq, nq = q_tiles(int(s), int(g), max_rows)
     first, last = live_block_range(
         np.asarray(pos, np.int64).reshape(-1, 1), np.arange(nq)[None],
         s=int(s), bq=bq, bk=int(bk), n_cols=int(n_cols), window=window,
-        xp=np)
+        first=(None if first is None
+               else np.asarray(first, np.int64).reshape(-1, 1)), xp=np)
     need = last - first + 1
     gb = group_blocks(bk, group_keys)
     return int(need.sum()), int((-(-need // gb) * gb).sum())
@@ -244,7 +293,7 @@ def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
 
 def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
             tile_p, bk, gb, n_cols, quantized, paged, window, block=1,
-            latent=0):
+            latent=0, walk=None):
     if quantized:
         # int8 cache: the per-block-per-kv-head scales ride as two more
         # SCALAR-PREFETCH operands — flat f32 (B·n_cols·hkv,) SMEM tables
@@ -252,7 +301,24 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
         # scalar per (row, block, head) and nothing scale-sized is ever
         # blocked through VMEM (a (1, hkv) block does not tile on a TPU)
         ks_ref, vs_ref, *refs = refs
-    if latent:
+    first_ref = resume = None
+    if walk == "shared":
+        # the tiles' part of a two-part walk (``SharedWalk``): a "row" is
+        # a q tile of stacked decode rows at the last position its members
+        # share, read through its leader's table row; it hands on the
+        # running (acc, m, l) unnormalised.  One more scalar, the live
+        # tiles' count, steers the block index maps alone
+        _, q_ref, k_hbm, *o_ref, k_buf, sems, slot_sc, acc_sc, m_sc, l_sc \
+            = refs
+        v_hbm = v_buf = None
+    elif walk == "own":
+        # the rows' part: row i walks from column ``first_ref[i]`` on,
+        # starting from what its tile left (``resume``: acc, m, l blocks
+        # picked by the row's place, the second scalar)
+        (first_ref, _, q_ref, k_hbm, *resume, o_ref,
+         k_buf, sems, slot_sc, acc_sc, m_sc, l_sc) = refs
+        v_hbm = v_buf = None
+    elif latent:
         # a latent pool (``LatentLayout``): the block that came in as K is
         # V too — its first ``latent`` lanes — so there is no V operand, no
         # V buffer and one copy a block
@@ -265,10 +331,15 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
     bi = pl.program_id(0)
     qi = pl.program_id(1)
     n_rows = pl.num_programs(0)
-    bounds = functools.partial(live_block_range, s=s, bq=bq, bk=bk,
-                               n_cols=n_cols, window=window)
+    span = functools.partial(live_block_range, s=s, bq=bq, bk=bk,
+                             n_cols=n_cols, window=window)
+
+    def bounds(pos, tile, row):
+        if first_ref is None:
+            return span(pos, tile)
+        return span(pos, tile, first=first_ref[row])
     pos_b = pos_ref[bi]
-    first, last = bounds(pos_b, qi)
+    first, last = bounds(pos_b, qi, bi)
     n_groups = (last - first + gb) // gb
     gk = gb * bk                                  # keys a group
 
@@ -286,7 +357,10 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
         out = []
         for i in range(gb):
             col = lo + j * gb + i
-            blk = bt_ref[row, jnp.minimum(col, hi)]
+            at_col = jnp.minimum(col, hi)
+            if walk == "shared":        # an empty tile's ``hi`` is -1
+                at_col = jnp.maximum(at_col, 0)
+            blk = bt_ref[row, at_col]
             at = pl.ds(i * bk, bk)
             out.append((col <= hi, [
                 pltpu.make_async_copy(block_at(ref, which, blk),
@@ -316,11 +390,16 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
     step_q = jnp.where(qi + 1 < nq, qi + 1, 0)
     step_b = jnp.minimum(jnp.where(qi + 1 < nq, bi, bi + 1), n_rows - 1)
     has_next = (qi + 1 < nq) | (bi + 1 < n_rows)
-    next_first, next_last = bounds(pos_ref[step_b], step_q)
+    next_first, next_last = bounds(pos_ref[step_b], step_q, step_b)
 
     acc_sc[...] = jnp.zeros_like(acc_sc)
     m_sc[...] = jnp.full_like(m_sc, NEG_INF)
     l_sc[...] = jnp.zeros_like(l_sc)
+    if resume:
+        @pl.when(first_ref[bi] > 0)
+        def _resume():
+            for sc, ref in zip((acc_sc, m_sc, l_sc), resume):
+                sc[:, pl.ds(0, bq * g)] = ref[0]
 
     def group(j, carry):
         slot = (slot0 + j) % 2
@@ -417,6 +496,14 @@ def _kernel(pos_ref, bt_ref, layer_ref, *refs, scale, s, g, hkv, d, bq, nq,
 
     jax.lax.fori_loop(0, n_groups, group, 0)
     slot_sc[0] = (slot0 + n_groups) % 2
+    if walk == "shared":
+        # an empty tile shares its output blocks with the last live one
+        # (the index maps): it writes nothing
+        @pl.when(n_groups > 0)
+        def _hand_on():
+            for ref, sc in zip(o_ref, (acc_sc, m_sc, l_sc)):
+                ref[0] = sc[...]
+        return
     for h in range(hkv):
         l = l_sc[h][:, :1]
         o_ref[0, h] = (acc_sc[h] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
@@ -532,7 +619,8 @@ def paged_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
 def latent_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
                                    layout: LatentLayout,
                                    scale: float,
-                                   interpret: bool = False):
+                                   interpret: bool = False,
+                                   shared: Optional[SharedWalk] = None):
     """Flash-decode of layer ``layer`` over a LATENT paged pool →
     ``(B, s, H, layout.value_width)``: latent attention in its absorbed
     form, where every head's key and value are one shared cached entry.
@@ -546,7 +634,21 @@ def latent_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
     decides it, not ``W``), the value the entry's first
     ``layout.value_width`` lanes.  The walk, the mask, ``pos`` and
     ``block_tables`` are :func:`paged_decode_attention_pallas`'s; there is
-    no window, no block mask and no int8 form of this layout."""
+    no window, no block mask and no int8 form of this layout.
+
+    ``shared`` (:class:`SharedWalk`; decode rows, ``s == 1``): the walk in
+    TWO parts, each a call of the one body under the one name.  The tiles'
+    part stacks the queries of up to :func:`tile_members` rows that hold
+    the same blocks in their leading ``n`` columns into one q tile and
+    walks those columns ONCE for them, through the leader's table row;
+    every such position lies a whole block behind every member's own, so
+    the mask hides the last copy group's padding and nothing else.  It
+    hands on the running ``(acc, m, l)`` a (row, head).  The rows' part is
+    the walk above from column ``n`` on, started from what the row's tile
+    left instead of from zeros — the
+    running-softmax algebra of every other group boundary.  Every position
+    of every row is scored once, as without it; a row with ``n == 0``
+    takes the walk above whole."""
     b, s, hq, w = q.shape
     if pool.ndim != 5 or pool.shape[1] != 1 or pool.shape[-1] != w:
         raise NotImplementedError(
@@ -562,10 +664,30 @@ def latent_decode_attention_pallas(q, pool, layer: int, pos, block_tables,
         raise NotImplementedError(
             f"latent layout: {hq} heads > a q tile of {layout.q_rows} "
             f"rows, or q_len {s} > {_MAX_Q_LEN}")
-    return _flash_decode(
-        q, pool, None, pos, jnp.asarray(block_tables, jnp.int32),
-        scale=scale, interpret=interpret, layer=int(layer), scales=None,
-        latent=layout)
+    bt = jnp.asarray(block_tables, jnp.int32)
+    walk = functools.partial(_flash_decode, scale=scale, interpret=interpret,
+                             layer=int(layer), scales=None, latent=layout)
+    if shared is None:
+        return walk(q, pool, None, pos, bt)
+    share = SharedWalk(*(jnp.asarray(x, jnp.int32) for x in shared))
+    tiles, members = share.tile_rows.shape
+    if s != 1 or members != tile_members(layout, hq):
+        raise NotImplementedError(
+            f"latent layout: a shared walk stacks {tile_members(layout, hq)}"
+            f" decode rows of q_len 1 a tile, not {members} of q_len {s}")
+    # the tiles' part: a tile's members' queries laid head-minor below one
+    # another, at the last position they share, through the leader's table
+    # row (gathers; nothing is scattered back: a row finds what its tile
+    # left through ``share.at``, in the rows' part's block index map)
+    left = walk(q[:, 0][share.tile_rows].reshape(tiles, 1, members * hq, w),
+                pool, None, share.tile_n * bk - 1,
+                bt[share.tile_rows[:, 0]], part="shared",
+                part_scalars=(jnp.sum(share.tile_n > 0, dtype=jnp.int32)
+                              .reshape(1),))
+    return walk(q, pool, None, pos, bt, part="own",
+                part_scalars=(share.n, share.at),
+                resume=tuple(x.reshape(tiles * members, 1, hq, x.shape[-1])
+                             for x in left))
 
 
 def _check_q(q, hkv: int) -> None:
@@ -587,7 +709,8 @@ def _check_q(q, hkv: int) -> None:
 
 
 def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
-                  scales, window=None, block=1, latent=None):
+                  scales, window=None, block=1, latent=None, part=None,
+                  part_scalars=(), resume=()):
     """Both layouts' way into the one ``pallas_call``.  ``k_arr``/``v_arr``
     are the operands as they lie in HBM (the kernel leaves them there):
     the paged pool twice with its ``layer``, or the contiguous cache's K
@@ -596,7 +719,12 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
     (B, columns, Hkv) K and V scale tables, or None; ``window`` the static
     sliding window, or None; ``block`` the static length of the
     block-causal mask's blocks (1: causal); ``latent`` the
-    :class:`LatentLayout` of a latent pool (``v_arr`` None), or None."""
+    :class:`LatentLayout` of a latent pool (``v_arr`` None), or None;
+    ``part`` the part of a two-part walk this call is (``"shared"`` |
+    ``"own"``, :func:`latent_decode_attention_pallas`), its scalars
+    (``part_scalars``: the live tiles' count | each row's first column and
+    its place among the tiles' results) and, for the rows' part, those
+    results (``resume``)."""
     b, s, hq, d = q.shape
     hkv = k_arr.shape[-1] // d
     block = int(block)
@@ -637,26 +765,33 @@ def _flash_decode(q, k_arr, v_arr, pos, bt, *, scale, interpret, layer,
     if quantized:
         scalars += tuple(jnp.asarray(t, jnp.float32).reshape(-1)
                          for t in scales)
+    two_part = {"part": part, "resume": resume} if part else {}
     return _flash_call(
-        scalars, q, k_arr, v_arr,
+        scalars + tuple(part_scalars), q, k_arr, v_arr,
         scale=float(d ** -0.5 if scale is None else scale), paged=paged,
         window=None if window is None else int(window), block=block,
         interpret=interpret, latent=latent, name=_disp.kernel_name(
-            "latent_flash_decode" if latent else "flash_decode"))
+            "latent_flash_decode" if latent else "flash_decode"),
+        **two_part)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "paged", "window",
                                              "block", "interpret", "name",
-                                             "latent"))
+                                             "latent", "part"))
 def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
-                name, block=1, latent=None):
+                name, block=1, latent=None, part=None, resume=()):
     """The ``pallas_call`` with the q layout round it, jitted on its own:
     a model's layers differ only in the VALUE of the layer scalar, so they
     share one trace of the kernel body and one lowering of it in every
     program that calls them (``scalars``: positions, block table, layer,
     then the int8 cache's K and V scale tables).  ``latent``: the pool is
     a latent one (``LatentLayout``; ``v_arr`` None): one operand, one
-    buffer, the output ``value_width`` wide."""
+    buffer, the output ``value_width`` wide.  ``part``: one part of a
+    two-part walk (:func:`latent_decode_attention_pallas`), whose scalars
+    end ``scalars``: ``"shared"`` returns the tiles' float32 ``(acc, m,
+    l)`` as the body holds them, ``(tiles, 1, tile rows, value_width |
+    lanes)``; ``"own"`` starts row ``i`` from block ``at[i]`` of each of
+    ``resume``."""
     b, s, hq, d = q.shape
     bk, hd = k_arr.shape[-2:]
     hkv = hd // d
@@ -684,11 +819,29 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
     kernel = functools.partial(
         _kernel, scale=scale, s=s, g=g, hkv=hkv, d=d, bq=bq, nq=nq,
         tile_p=tile_p, bk=bk, gb=gb, n_cols=scalars[1].shape[1],
-        quantized=len(scalars) > 3, paged=paged, window=window, block=block,
-        **({"latent": dv} if latent else {}))
+        quantized=not part and len(scalars) > 3, paged=paged, window=window,
+        block=block, **({"latent": dv} if latent else {}),
+        **({"walk": part} if part else {}))
 
-    def q_idx(bi, qi, *_):
+    def q_idx(bi, qi, *scalars):
+        if part == "shared":
+            # an empty tile (they come last) stays on the last live
+            # tile's blocks: nothing is fetched for it, nothing written
+            bi = jnp.minimum(bi, jnp.maximum(scalars[3][0] - 1, 0))
         return (bi, 0, qi, 0)
+
+    def left_idx(bi, qi, *scalars):
+        return (scalars[4][bi], 0, 0, 0)        # the row's place: ``at``
+
+    outs = [(dv, q.dtype)]
+    if part == "shared":        # (acc, m, l) as the scratch holds them
+        outs = [(dv, jnp.float32), (_LANES, jnp.float32),
+                (_LANES, jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((b, hkv, nq * tile_p, n), dtype)
+                 for n, dtype in outs]
+    out_specs = [pl.BlockSpec((1, hkv, tile_p, n), q_idx) for n, _ in outs]
+    if part != "shared":
+        (out_shape,), (out_specs,) = out_shape, out_specs
 
     out = pl.pallas_call(
         kernel,
@@ -696,8 +849,9 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
             num_scalar_prefetch=len(scalars),
             grid=(b, nq),
             in_specs=[pl.BlockSpec((1, hkv, tile_p, d), q_idx)]
-            + [pl.BlockSpec(memory_space=pl.ANY)] * len(kv),
-            out_specs=pl.BlockSpec((1, hkv, tile_p, dv), q_idx),
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(kv)
+            + [pl.BlockSpec((1, *x.shape[1:]), left_idx) for x in resume],
+            out_specs=out_specs,
             scratch_shapes=[
                 # two buffers of one group of K and of V blocks (a latent
                 # pool: of its one array), a DMA semaphore a copy, and the
@@ -710,13 +864,15 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
                 pltpu.VMEM((hkv, tile_p, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * tile_p, dv), q.dtype),
+        out_shape=out_shape,
         # sequential: a step issues the next step's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(*scalars, qg, *kv)
+    )(*scalars, qg, *kv, *resume)
+    if part == "shared":
+        return out
     out = out.reshape(b, hkv, nq, tile_p, dv)[:, :, :, :bq * g]
     out = out.reshape(b, hkv, nq * bq * g, dv)[:, :, :rows]
     out = out.reshape(b, hkv, s, g, dv).transpose(0, 2, 1, 3, 4)

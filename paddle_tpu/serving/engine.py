@@ -295,6 +295,14 @@ _ENGINE_IDS = itertools.count()
 _ROW_FILLS_CHIP = 256
 
 
+# The rows from which a run of leading table columns is walked ONCE for the
+# rows that hold it (``ServingEngine._regroup``): a q tile of stacked rows
+# costs the MXU a full tile whatever its fill — about what this many rows'
+# own walks cost at one row's heads a tile (PERF.md section 6, PR 43: the
+# chip's reading) — so fewer rows on a prefix walk it alone, as before.
+_SHARE_FROM = 3
+
+
 class _Operand(NamedTuple):
     """One operand of a device program after ``(params, cache)``: a row of
     the engine's operand tables (``ServingEngine._operand_tables``), as
@@ -695,9 +703,11 @@ class ServingEngine:
         # that is not K and V rows (``kv_pool_entry``, a
         # ``models.parts.PoolEntry``: a latent model's one entry that is key
         # and value at once — the pool's second axis and width, its bytes,
-        # the pre-flight and the spans' walk counts follow it).  A model
-        # that keeps a decode state of
-        # its own and declares none of it is refused here, by name.
+        # the pre-flight and the spans' walk counts follow it, and under a
+        # prefix cache a layout that can walk a shared prefix once for the
+        # rows on it is told which rows those are: ``_init_shared_walk``).
+        # A model that keeps a decode state of its own and declares none of
+        # it is refused here, by name.
         self._pool_entry = getattr(self._bind, "kv_pool_entry", None)
         self._diffusion = getattr(self._bind, "block_diffusion", None)
         self._block = int(self._diffusion.length) if self._diffusion else 0
@@ -1002,7 +1012,27 @@ class ServingEngine:
             kv_dtype=self.kv_dtype,
             host_blocks=self._host_blocks)
         self._tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
+        self._init_shared_walk()
         return nb, bl
+
+    def _init_shared_walk(self):
+        """The mirrors of a two-part walk (``ops.pallas.decode_attention
+        .SharedWalk``: four more rows of the step program's operand table),
+        for a pool whose declared layout has one, read through a prefix
+        trie: without the trie no two rows hold one block.  As many tiles
+        as the slots can ever fill from ``_SHARE_FROM`` rows on."""
+        from ..ops.pallas.decode_attention import (LatentLayout, SharedWalk,
+                                                   tile_members)
+        layout = getattr(self._pool_entry, "layout", None)
+        if not (self.kv.prefix_cache and isinstance(layout, LatentLayout)):
+            return
+        tiles = max(1, self.num_slots // _SHARE_FROM)
+        members = tile_members(layout, self._pool_entry.group)
+        self._share = SharedWalk(*(
+            np.zeros(shape, np.int32) for shape in (
+                (self.num_slots,), (self.num_slots,), (tiles, members),
+                (tiles,))))
+        self._regroup()
 
     def _init_scheduler_state(self):
         """The scheduler's host state, empty (the simulator's too)."""
@@ -1024,6 +1054,11 @@ class ServingEngine:
             # per delivered token, the forward-in-block that unmasked it
             self._unmasked_at: Dict[int, List[int]] = {}
 
+        # which decoding rows walk which leading columns together, where
+        # the pool's layout has such a walk (``_init_shared_walk``), and
+        # whether the rows changed since it was worked out (``_regroup``)
+        self._share = None
+        self._share_stale = False
         self._slots: List[Optional[_Slot]] = [None] * s
         self._prefill: Optional[_Prefill] = None   # chunked-mode cursor
         self._queue: Deque[Request] = deque()
@@ -1633,6 +1668,10 @@ class ServingEngine:
                    else self._positions)]
         if self.paged:
             step.append(op("tables", (s, mb), i32, self._tables))
+        if self._share is not None:
+            # which rows walk which leading columns together (``_regroup``)
+            step += [op("share_" + f, x.shape, i32, x)
+                     for f, x in zip(self._share._fields, self._share)]
         step.append(op("slot_mask", (s,), bool, self._active))
         if self.spec:
             step += [op("draft_ok", (s, k), bool, "draft_ok"),
@@ -1909,7 +1948,9 @@ class ServingEngine:
             a["positions"], a.get("tables"), valid=real,
             slots=(0, self.num_slots) if self._slot_leaves else None,
             scope=(verify_rows if spec else functools.partial(
-                part, "block_rows" if self._block else "decode_rows")))]
+                part, "block_rows" if self._block else "decode_rows")),
+            shared=self._share and type(self._share)(
+                *(a["share_" + f] for f in self._share._fields)))]
         if self.chunked:
             cids, clen, cdst = a["cids"], a["clen"], a["cdst"]
             parts.append(DecodePart(
@@ -2705,6 +2746,54 @@ class ServingEngine:
                 walk += n * kw
         return {"kv_blocks": blocks, "kv_walk": walk}
 
+    def _regroup(self):
+        """Work out which decoding rows walk which leading columns
+        together (``self._share``, uploaded as it stands): rows whose
+        tables hold the same block ids in the same leading columns — a
+        prefix adopted through the trie, and the row that wrote it — are
+        cut into tiles of the walk's member rows, each tile read through
+        its first row's table.  Only whole blocks BEHIND a row's current
+        one count, so what a row shares is fixed from its first decode
+        tick to its retirement, and this runs when the set of decoding
+        rows changed (``_seat``, ``_clear_slot``), not every tick.  From
+        the deepest row down: the rows that share at least half its
+        columns with it form its group, walked together over the columns
+        they ALL share; fewer than ``_SHARE_FROM`` rows (a group, or its
+        last tile) walk alone."""
+        from ..ops.pallas.decode_attention import group_blocks
+        self._share_stale = False
+        share = self._share
+        for mirror in share:
+            mirror[...] = 0
+        members = share.tile_rows.shape[1]
+        depth = self._positions // self.block_len * self._active
+        left = np.flatnonzero(depth)
+        left = left[np.argsort(-depth[left], kind="stable")]
+        t = 0
+        while left.size >= _SHARE_FROM:
+            d = depth[left[0]]
+            same = self._tables[left, :d] == self._tables[left[0], :d]
+            run = np.minimum(same.cumprod(axis=1).sum(axis=1), depth[left])
+            pick = run >= (d + 1) // 2
+            rows, n = left[pick], run[pick].min()
+            left = left[~pick]
+            for i in range(0, rows.size - _SHARE_FROM + 1, members):
+                cut = rows[i:i + members]
+                share.tile_rows[t] = cut[0]
+                share.tile_rows[t, :cut.size] = cut
+                share.tile_n[t] = share.n[cut] = n
+                share.at[cut] = t * members + np.arange(cut.size)
+                t += 1
+        # a row outside every tile resumes from nothing: it stays on the
+        # blocks the row before it fetched (``_flash_call``'s index map)
+        grouped = share.n > 0
+        share.at[...] = share.at[np.maximum.accumulate(
+            np.where(grouped, np.arange(grouped.size), 0))]
+        gb = group_blocks(self.block_len, self._pool_entry.layout.group_keys)
+        self._share_counts = (
+            int(share.tile_n.sum()), int((-(-share.tile_n // gb) * gb).sum()),
+            {"rows_grouped": int(grouped.sum()), "shared_tiles": t})
+
     def _kv_walk_shared(self, *calls) -> Dict[str, int]:
         """:meth:`_kv_walk` for a model that declares its pool's entry
         (``kv_pool_entry``): the same two counts by the layout's own tiles
@@ -2713,14 +2802,22 @@ class ServingEngine:
         row's query sees), ``rows_blocks`` the blocks their walks read,
         ``rows_distinct`` how many different physical blocks those are and
         ``rows_positions`` the different positions they hold — rows that
-        adopted one prefix walk the same blocks, each for itself."""
+        adopted one prefix hold the same blocks.  Under a two-part walk
+        (``_regroup``) the counts are of what it reads: a tile's shared
+        columns once, then each row's own from its first column on; and
+        ``rows_grouped`` the live rows that sit in a tile, ``shared_tiles``
+        the tiles."""
         from ..ops.pallas.decode_attention import walk_counts
         g, bk, cols, ((_, layers),) = self._kv_walk_geom
         layout = self._pool_entry.layout
-        blocks = walk = 0
-        for pos, s in calls:
-            kb, kw = walk_counts(pos, s, g, bk=bk, n_cols=cols,
-                                 latent=layout)
+        share = self._share
+        tile_blocks, tile_walk, grouping = (
+            self._share_counts if share else (0, 0, {}))
+        blocks, walk = layers * tile_blocks, layers * tile_walk
+        for i, (pos, s) in enumerate(calls):
+            kb, kw = walk_counts(
+                pos, s, g, bk=bk, n_cols=cols, latent=layout,
+                first=share.n if share and not i else None)
             blocks += layers * kb
             walk += layers * kw
         live = np.flatnonzero(self._active)
@@ -2728,12 +2825,13 @@ class ServingEngine:
         last = (depth - 1) // bk            # a row's last block is its own
         full = [self._tables[i, :n] for i, n in zip(live, last)]
         distinct = len(np.unique(np.concatenate(full))) if full else 0
+        own = last + 1 - (share.n[live] if share else 0)
         return {"kv_blocks": blocks, "kv_walk": walk,
                 "rows_depth": int(depth.sum()),
-                "rows_blocks": int((last + 1).sum()),
+                "rows_blocks": tile_blocks + int(own.sum()),
                 "rows_distinct": distinct + len(live),
                 "rows_positions": distinct * bk
-                + int((depth - last * bk).sum())}
+                + int((depth - last * bk).sum()), **grouping}
 
     def _note_sample_path(self, *knobs) -> str:
         """Name and count the way this tick's sampling epilogue goes:
@@ -2796,6 +2894,8 @@ class ServingEngine:
                 own["draft_ok"] = draft_ok
         t0 = self._clock()
         with span(_BUILD):
+            if self._share and self._share_stale:
+                self._regroup()
             rows_pos = self._positions
             if chunked and not paged:
                 # non-decoding rows (idle or mid-prefill) write at
@@ -4320,6 +4420,7 @@ class ServingEngine:
                                     else sampling.unmask_threshold)
         if self.paged:
             self._tables[si] = self.kv.table_row(si, self.max_blocks)
+        self._share_stale = True
 
     def _note_resumed(self, req: Request, mode: str, si: int):
         self._rlog.event(req.uid, "resumed", engine=self._eid, mode=mode,
@@ -4352,6 +4453,7 @@ class ServingEngine:
         ``kv.release`` for normal retirement."""
         if self.paged:
             self._tables[i] = 0
+        self._share_stale = True
         self._drafter_reset(i)
         self._slots[i] = None
         self._active[i] = False

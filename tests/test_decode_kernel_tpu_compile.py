@@ -27,8 +27,8 @@ from lowered_step_text import (cell_engines, kernel_calls, lowered,
 
 from paddle_tpu.ops import _dispatch
 from paddle_tpu.ops.pallas.decode_attention import (
-    LatentLayout, decode_attention_pallas, latent_decode_attention_pallas,
-    paged_decode_attention_pallas)
+    LatentLayout, SharedWalk, decode_attention_pallas,
+    latent_decode_attention_pallas, paged_decode_attention_pallas)
 
 HKV, D, BLOCK = 8, 128, 128
 
@@ -121,27 +121,35 @@ def test_block_masked_kernel_compiles_for_v5e(one_chip, rows, s):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
-@pytest.mark.parametrize("rows, s", [(96, 1), (1, 256)],
-                         ids=["latent-rows", "latent-chunk"])
-def test_latent_kernel_compiles_for_v5e(one_chip, rows, s):
+@pytest.mark.parametrize("rows, s, tiles", [(96, 1, 0), (1, 256, 0),
+                                            (96, 1, 32)],
+                         ids=["latent-rows", "latent-chunk",
+                              "latent-rows-shared"])
+def test_latent_kernel_compiles_for_v5e(one_chip, rows, s, tiles):
     """The body with the latent layout as its static parameter at the
     JoyAI cell's geometry: ONE array a layer of 640 stored lanes a position
     (576 values), 32 heads one query group (a rows tile of 32 MXU rows, a
     chunk's of 256: 8 tokens), the value the entry's first 512 lanes, copy
-    groups of 1,024 keys, tables of 96 columns, all 40 layers."""
+    groups of 1,024 keys, tables of 96 columns, all 40 layers.  Shared: the
+    two-part walk as the cell's engine builds it — 96 rows on four
+    documents, tiles of 8 rows (256 MXU rows) for as many as the slots can
+    fill — both parts one Mosaic body each under the one name."""
     heads, width, layers, blocks, cols = 32, 640, 40, 700, 96
     args = [_spec(one_chip, (rows, s, heads, width), jnp.bfloat16),
             _spec(one_chip, (layers, 1, blocks, BLOCK, width), jnp.bfloat16),
             _spec(one_chip, (rows,), jnp.int32),
             _spec(one_chip, (rows, cols), jnp.int32)]
+    if tiles:
+        args += [_spec(one_chip, shape, jnp.int32) for shape in (
+            (rows,), (rows,), (tiles, 256 // heads), (tiles,))]
 
-    def call(q, pool, pos, tables):
+    def call(q, pool, pos, tables, *shared):
         return latent_decode_attention_pallas(
             q, pool, layers - 1, pos, tables, LatentLayout(value_width=512),
-            192 ** -0.5)
+            192 ** -0.5, shared=SharedWalk(*shared) if shared else None)
 
     compiled = jax.jit(call).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") >= (2 if tiles else 1)
     out, = jax.tree_util.tree_leaves(compiled.out_info)
     assert out.shape == (rows, s, heads, 512)
     # the pool is read where it lies: no temporary of a layer's size

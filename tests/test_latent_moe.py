@@ -15,6 +15,7 @@ model computes with bf16 operands (3.7e-3 at these widths, asserted in
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +32,7 @@ from paddle_tpu.models.parts import DecodePart
 from paddle_tpu.ops.attention import (latent_decode_attention,
                                       latent_decode_attention_reference)
 from paddle_tpu.ops.pallas.decode_attention import (
-    LatentLayout, latent_decode_attention_pallas, walk_counts)
+    LatentLayout, SharedWalk, latent_decode_attention_pallas, walk_counts)
 from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.kv_cache import init_paged_kv_cache
 
@@ -217,7 +218,7 @@ def test_engine_serves_what_the_reference_puts_first(seeded):
 @pytest.fixture(scope="module")
 def latent_pool():
     rng = np.random.default_rng(0)
-    pool = rng.normal(size=(2, 1, 14, 128, 256)).astype(np.float32)
+    pool = rng.normal(size=(2, 1, 40, 128, 256)).astype(np.float32)
     pool[..., 160:] = 0.0
     return jnp.asarray(pool, jnp.bfloat16)
 
@@ -250,6 +251,120 @@ def test_latent_kernel_matches_its_xla_twin(latent_pool, name, s, pos,
                                              128, 0.1)
     assert got.shape == (len(pos), s, 4, 128)
     # bf16 outputs: an ulp of the output's rounding (2^-8 of its size)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2 ** -7, atol=2 ** -8)
+
+
+def _share(rows, groups, tiles=4, members=4):
+    """The :class:`SharedWalk` of ``groups`` ([(rows, columns)]) as the
+    engine lays it out: tiles of ``members`` rows, a tile's first row its
+    leader, the live tiles first, a place no row holds naming the leader."""
+    n, at = np.zeros(rows, np.int32), np.zeros(rows, np.int32)
+    tile_rows = np.zeros((tiles, members), np.int32)
+    tile_n = np.zeros(tiles, np.int32)
+    t = 0
+    for mem, cols in groups:
+        for i in range(0, len(mem), members):
+            cut = mem[i:i + members]
+            tile_rows[t] = cut[0]
+            tile_rows[t, :len(cut)] = cut
+            tile_n[t] = n[cut] = cols
+            at[cut] = t * members + np.arange(len(cut))
+            t += 1
+    return SharedWalk(*(jnp.asarray(x) for x in (n, at, tile_rows, tile_n)))
+
+
+DOC, OTHER, NAN_BLOCK, COLS = [1, 2, 3], [4, 5], 39, 8
+
+
+def _row(shared, own):
+    return (shared + own + [0] * COLS)[:COLS]
+
+
+# name: (positions, tables, [(a group's rows, its shared columns)]) at 4
+# heads, tiles of 4 rows (16 MXU rows) and copy groups of 2 blocks
+TWO_PART = {
+    "a-group-of-2": ([400, 500], [_row(DOC, [10]), _row(DOC, [11])],
+                     [([0, 1], 3)]),
+    # three tiles (4 + 4 + 3 rows) on one document
+    "a-group-of-11": ([384 + 11 * i for i in range(11)],
+                      [_row(DOC, [10 + i]) for i in range(11)],
+                      [(list(range(11)), 3)]),
+    "two-groups-of-different-depth": (
+        [400, 500, 300, 290, 370],
+        [_row(DOC, [10]), _row(DOC, [11]), _row(OTHER, [12]),
+         _row(OTHER, [13]), _row(OTHER, [14])],
+        [([0, 1], 3), ([2, 3, 4], 2)]),
+    "a-row-in-no-group-among-grouped": (
+        [400, 777, 500], [_row(DOC, [10]), _row([20, 21, 22, 23, 24, 25, 26],
+                                                []), _row(DOC, [11])],
+        [([0, 2], 3)]),
+    # the idle row's position lies past the table: its walk is the null
+    # block's, as without a shared part
+    "an-idle-row-parked-past-the-cache": (
+        [400, COLS * 128 + 5, 500], [_row(DOC, [10]), [0] * COLS,
+                                     _row(DOC, [11])], [([0, 2], 3)]),
+    "an-own-part-of-the-current-block-alone": (
+        [384, 389, 511], [_row(DOC, [10]), _row(DOC, [11]),
+                          _row(DOC, [12])], [([0, 1, 2], 3)]),
+    # one shared block, then 5 and 6 blocks of a row's own: three copy
+    # groups of 2
+    "an-own-part-over-two-copy-groups": (
+        [700, 800], [_row([1], [10, 11, 12, 13, 14]),
+                     _row([1], [15, 16, 17, 18, 19, 20])], [([0, 1], 1)]),
+}
+
+
+@pytest.mark.parametrize("name", list(TWO_PART))
+def test_two_part_walk_matches_the_twin_and_the_one_part_walk(latent_pool,
+                                                              name):
+    """A run of leading columns walked once for the rows that share it,
+    then each row's own columns from what its tile left: the XLA twin's
+    numbers, and the one-part walk's."""
+    layout = LatentLayout(value_width=128, q_rows=16, group_keys=256)
+    pos, tables, groups = TWO_PART[name]
+    q = _q((len(pos), 1, 4, 256), seed=len(name))
+    pos = jnp.asarray(pos, jnp.int32)
+    tables = jnp.asarray(tables, jnp.int32)
+    shared = _share(len(pos), groups)
+    walk = functools.partial(latent_decode_attention_pallas, q, latent_pool,
+                             1, pos, tables, layout, 0.1, interpret=True)
+    two, one = walk(shared=shared), walk()
+    want = latent_decode_attention_reference(q, latent_pool, 1, pos, tables,
+                                             128, 0.1)
+    twin = latent_decode_attention_reference(q, latent_pool, 1, pos, tables,
+                                             128, 0.1, shared=shared)
+    assert two.shape == (len(pos), 1, 4, 128)
+    for other in (want, one, twin):
+        np.testing.assert_allclose(np.asarray(two, np.float32),
+                                   np.asarray(other, np.float32),
+                                   rtol=2 ** -7, atol=2 ** -8)
+
+
+def test_two_part_walk_reads_no_column_outside_its_two_ranges(latent_pool):
+    """A member's own table is never read in the columns its tile walks
+    (the leader's row is), no row's past its last block, an empty tile's
+    not at all: those columns name a block of NaN here."""
+    layout = LatentLayout(value_width=128, q_rows=16, group_keys=256)
+    pool = latent_pool.at[:, :, NAN_BLOCK].set(jnp.nan)
+    pos = jnp.asarray([400, 500, 640, 130], jnp.int32)
+    real = [_row(DOC, [10]), _row(DOC, [11]), _row(DOC, [12, 13, 14]),
+            _row([20, 21], [])]
+    seen = np.full((4, COLS), NAN_BLOCK, np.int32)
+    seen[0, :4] = real[0][:4]           # the leader: shared columns and own
+    seen[1, 3] = real[1][3]             # members: their own columns alone
+    seen[2, 3:6] = real[2][3:6]
+    seen[3, :2] = real[3][:2]           # a row alone: its whole walk
+    shared = _share(4, [([0, 1, 2], 3)])
+    shared = shared._replace(tile_rows=shared.tile_rows.at[1:].set(1))
+    q = _q((4, 1, 4, 256), seed=7)
+    got = latent_decode_attention_pallas(
+        q, pool, 1, pos, jnp.asarray(seen), layout, 0.1, interpret=True,
+        shared=shared)
+    want = latent_decode_attention_reference(
+        q, pool, 1, pos, jnp.asarray(real, jnp.int32), 128, 0.1)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=2 ** -7, atol=2 ** -8)
@@ -300,6 +415,15 @@ def test_walk_counts_follow_the_layout():
     assert (blocks, walk) == (2 + 5 * 3, 2 + 5 * 4)
     assert walk_counts([600, 0], 1, 4, bk=128, n_cols=6, latent=lay) == (
         5 + 1, 6 + 2)
+    # behind a shared walk of 3 columns the first row walks 2 of its own;
+    # a tile at the last shared position walks the 3, once for its rows
+    assert walk_counts([600, 0], 1, 4, bk=128, n_cols=6, latent=lay,
+                       first=[3, 0]) == (2 + 1, 2 + 2)
+    assert walk_counts([3 * 128 - 1], 1, 16, bk=128, n_cols=6,
+                       latent=lay) == (3, 4)
+    # a first column past a row's last block is its last block
+    assert walk_counts([130], 1, 4, bk=128, n_cols=6, latent=lay,
+                       first=[5]) == (1, 2)
 
 
 # -- (e) the prefix trie over a latent pool ----------------------------------
@@ -334,35 +458,86 @@ def test_shared_prefix_through_the_trie(seeded):
         assert _served_gap(made, p, toks).max() < LOGIT_TOL
 
 
+def test_rows_on_one_prefix_walk_it_together(seeded):
+    """Four requests decoding AT ONCE on one document and one on another:
+    the four are one tile that reads the document through its leader's
+    table row (the XLA twin does what the kernel does), and give what each
+    gives alone; the leader retires first and the rest regroup."""
+    model, made = seeded
+    doc, other = _ids(32, seed=100), _ids(32, seed=101)
+    prompts = [np.concatenate([doc, _ids(7, seed=1)]),
+               np.concatenate([doc, _ids(5, seed=2)]),
+               np.concatenate([doc, _ids(3, seed=4)]),
+               np.concatenate([doc, _ids(6, seed=5)]),
+               np.concatenate([other, _ids(6, seed=3)])]
+    new = [6, 30, 30, 30, 30]           # the first, deepest row goes first
+    alone = []
+    for p, n in zip(prompts, new):
+        eng = _engine(model)
+        rid = eng.submit(p, max_new_tokens=n)
+        eng.drain()
+        alone.append(eng.result(rid))
+    eng = _engine(model, num_slots=6)
+    assert [x.shape for x in eng._share] == [(6,), (6,), (2, 64), (2,)]
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new)]
+    grouped, leaders = [], []
+    while eng.queue_depth or eng.num_pending or eng.num_active:
+        eng.step()
+        grouped.append(int((eng._share.n > 0).sum()))
+        leaders.append(int(eng._share.tile_rows[0, 0]))
+        # what a row shares lies whole blocks behind its current one
+        live = np.flatnonzero(eng._active)
+        assert (eng._share.n[live] <= eng._positions[live] // BLOCK).all()
+    assert eng.step_traces == 1
+    assert int(eng.kv.stats["prefix_hit_tokens"]) == 3 * 32
+    assert [eng.result(r) for r in rids] == alone
+    for p, rid in zip(prompts, rids):
+        assert _served_gap(made, p, eng.result(rid)).max() < LOGIT_TOL
+    # three rows on the document make a tile, the fourth joins it, the
+    # leader retires and the other three go on under another
+    assert 4 in grouped and grouped[grouped.index(4):].count(3) > 5
+    at4 = grouped.index(4)
+    assert leaders[at4] != leaders[at4 + grouped[at4:].index(3)]
+    assert (eng._share.tile_n[:1] == 4).all() or not eng._share.n.any()
+    spans = [ev["args"] for ev in obs.get_tracer().events()
+             if ev["name"] == "serving.decode"]
+    assert {a["rows_grouped"] for a in spans} >= {0, 3, 4}
+    assert {a["shared_tiles"] for a in spans} == {0, 1}
+
+
 def test_spans_count_shared_blocks_once(seeded):
-    """Two rows decoding on one adopted prefix: the rows span says how many
-    of the blocks their walks read are distinct."""
+    """Three rows decoding on one adopted prefix: the rows span says how
+    many of the blocks their walks read are distinct, and the walk reads
+    the document's blocks once for the three."""
     model, _ = seeded
     eng = _engine(model)
     doc = _ids(32, seed=100)
-    first = eng.submit(np.concatenate([doc, _ids(3, seed=1)]),
-                       max_new_tokens=20)
-    while not eng.result(first):
-        eng.step()
-    second = eng.submit(np.concatenate([doc, _ids(2, seed=2)]),
-                        max_new_tokens=20)
-    while not eng.result(second):
-        eng.step()
+    for n in (3, 2, 4):
+        rid = eng.submit(np.concatenate([doc, _ids(n, seed=n)]),
+                         max_new_tokens=20)
+        while not eng.result(rid):
+            eng.step()
     eng.step()
     spans = [ev for ev in obs.get_tracer().events()
              if ev["name"] == "serving.decode"
-             and ev.get("args", {}).get("slots") == 2]
+             and ev.get("args", {}).get("slots") == 3]
     a = spans[-1]["args"]
     depth = [int(p) + 1 for p in eng._positions[eng._active]]
     # the span was written before the tick advanced the rows by one
-    assert a["rows_depth"] == sum(depth) - 2
+    assert a["rows_depth"] == sum(depth) - 3
     per_row = [-(-(d - 1) // BLOCK) for d in depth]
-    assert a["rows_blocks"] == sum(per_row)
     # four blocks of the document, once; each row's own blocks after them
     assert a["rows_distinct"] == 4 + sum(n - 4 for n in per_row)
     assert a["rows_positions"] == 32 + sum(d - 1 - 32 for d in depth)
-    assert a["kv_blocks"] >= 3 * a["rows_blocks"] and a["kv_walk"] >= \
-        a["kv_blocks"]
+    # the walk reads what is distinct: the one tile's four columns, then
+    # each row's own
+    assert (a["rows_grouped"], a["shared_tiles"]) == (3, 1)
+    assert a["rows_blocks"] == a["rows_distinct"] < sum(per_row)
+    # by the layout's tiles and groups, three layers: the tile's 4 blocks
+    # + the live rows' own + the idle row's one + the chunk part's one
+    own = sum(n - 4 for n in per_row)
+    assert a["kv_blocks"] == 3 * (4 + own + 1 + 1)
+    assert a["kv_walk"] >= a["kv_blocks"]
     snap = obs.snapshot()["kv_cache.position_bytes"]["series"]
     mine = [r for r in snap if r["labels"].get("engine") == eng._eid]
     assert mine[0]["value"] == 3 * 256 * 4      # layers x stored lanes x f32
