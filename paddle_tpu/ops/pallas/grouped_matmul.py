@@ -89,9 +89,6 @@ def grouped_matmul_pallas(xs, w, group_sizes, *, tiling=None,
     tile (``tiling[0]``, default :data:`TILE_ROWS`); ``tiling`` is
     ``(tm, tk, tn)`` with ``tk | K`` and ``tn | N``.  The result has
     ``xs``'s dtype; rows of no group hold whatever the buffer held."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import \
-        make_group_metadata
-
     from .. import _dispatch as _disp
 
     m, k = xs.shape
@@ -104,6 +101,24 @@ def grouped_matmul_pallas(xs, w, group_sizes, *, tiling=None,
         raise NotImplementedError(
             f"grouped matmul ({m}, {k}) x ({k}, {n}) does not tile by "
             f"({tm}, {tk}, {tn})")
+    return _gmm_call(xs, w, group_sizes, tiles=(tm, tk, tn),
+                     interpret=interpret,
+                     name=_disp.kernel_name("moe_experts"))
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret", "name"))
+def _gmm_call(xs, w, group_sizes, *, tiles, interpret, name):
+    """The group metadata and the ``pallas_call``, jitted on their own (as
+    ``decode_attention._flash_call`` is): a model's expert layers differ
+    only in their weights' VALUES, so they share one trace of the kernel
+    body and one lowering of it in every program that calls them.  Traced
+    a call site (3 an expert layer, in each of the mixed step program's two
+    passes) they were half of that program's tracing and lowering: 6.8 ->
+    3.7 s for 8 of SDAR's 48 layers on a CPU host (PR 44)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import \
+        make_group_metadata
+    (m, k), (e, _, n) = xs.shape, w.shape
+    tm, tk, tn = tiles
     tiles_k, tiles_n = k // tk, n // tn
     meta, num_active = make_group_metadata(
         group_sizes=group_sizes.astype(jnp.int32), m=m, tm=tm,
@@ -132,5 +147,5 @@ def grouped_matmul_pallas(xs, w, group_sizes, *, tiling=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_limits.GMM_VMEM_LIMIT),
-        interpret=interpret, name=_disp.kernel_name("moe_experts"),
+        interpret=interpret, name=name,
     )(meta, xs, w)

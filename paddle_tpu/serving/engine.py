@@ -97,8 +97,10 @@ replaces the wave with a **token-budget scheduler**:
     so TPOT degrades by a bounded, chunk-sized amount instead of a
     whole-prompt stall, and TTFT pipelines across ticks;
   * the mixed step is jitted ONCE (chunk size static, budget-1
-    ``track_retraces`` site ``serving.step``); chunk-free ticks ride the
-    same program with a dummy chunk whose writes are steered harmless;
+    ``track_retraces`` site ``serving.step``); a chunk-free tick runs a
+    second program, the same pass over the decode rows and a stub of 8 of
+    the chunk's ``prefill_chunk`` positions, jitted once too (site
+    ``serving.step_rows``) and chosen on the host by what the tick holds;
   * ``chunk_policy`` trades the two SLOs: ``"prefill"`` (default) runs a
     pending chunk every tick, ``"decode"`` interleaves chunks with
     chunk-free ticks while decodes are active;
@@ -274,6 +276,8 @@ _UPLOAD, _ACCOUNT = TICK_COSTS
 # function it sat in: the benchmark's accepted readers find the step
 # programs' kernels by ``_\w*step_impl\w*`` on that name.
 _STEP, _PREFILL = "_step_impl", "_prefill_impl"
+# what a cursor engine's rows-alone step program keeps of the chunk part
+_STUB_CHUNK = 8
 
 # engine instances share the default registry; the ``engine`` label keeps
 # their series (and retrace budgets) independent
@@ -756,7 +760,7 @@ class ServingEngine:
                     f"of {self._block}")
             if self._slot_leaves:
                 # one row a slot and a null row, which a chunk-free tick's
-                # chunk part addresses
+                # stub of a chunk part addresses
                 cache = self._bind.init_serving_cache(self.num_slots + 1,
                                                       nb, bl)
                 (pool,) = (v for k, v in cache.items()
@@ -953,13 +957,19 @@ class ServingEngine:
                     self._program_arity(table), len(outputs)))
             return _obs.track_retraces(self._under_mesh(body), site,
                                        budget=budget, labels=lbl, **kwargs)
-        # ONE step program serves every tick.  The budget of 1 IS the
-        # scheduler's contract: admission, chunk progress, drafts and
-        # retirement all move through traced inputs.  The cursor engine
-        # has no other program; a wave engine has its prefill program,
-        # compiled once per bucket.
+        # ONE step program serves every tick with the same parts.  The
+        # budget of 1 IS the scheduler's contract: admission, chunk
+        # progress, drafts and retirement all move through traced inputs.
+        # A cursor engine has one more for the ticks with no chunk, the
+        # same body with the chunk part cut to a stub, under the same
+        # contract; a wave engine has its prefill program, compiled once
+        # per bucket.
         self._step_fn = program(self._step_program(), self._step_table,
                                 self._step_outputs, "serving.step", 1)
+        self._rows_fn = program(
+            self._step_program(rows_alone=True), self._step_table,
+            self._step_outputs, "serving.step_rows", 1) \
+            if self.chunked else None
         # Its budget is no knob: one program a bucket ``max_length`` allows.
         self._prefill_fn = None if self.chunked else program(
             self._prefill_program(), self._prefill_table,
@@ -1115,15 +1125,18 @@ class ServingEngine:
         return _cm.TickAttribution(model, engine_id=self._eid)
 
     def _perf_tick(self, measured_ms: float, occ: int,
-                   chunk_tokens: int = 0) -> None:
+                   chunk_tokens: int = 0, compiled: bool = False) -> None:
         """Stamp one measured tick with the model's prediction at the
         tick's ACTUAL occupancy / live depths / chunk state (positions
         are still pre-advance here — the depths the step just read).
+        A tick that ``compiled`` the rows-alone program is left out: it
+        comes after the detectors' skip of an engine's first ticks, and
+        its seconds of compiling would calibrate their band.
         Host↔HBM bytes any swap/demotion moved since the last dispatch
         ride along — the roofline's swap term (costmodel.py) bounds the
         tick by host-link bandwidth when they dominate."""
         swap_bytes, self._tick_swap_bytes = self._tick_swap_bytes, 0
-        if self._perf is None:
+        if self._perf is None or compiled:
             return
         live = int(self._positions[self._active].sum()) if occ else 0
         self._perf.on_tick(
@@ -1516,6 +1529,13 @@ class ServingEngine:
             "jit.traces", "").labels(site="serving.step", **lbl)
         self._m_prefill_traces = ctr(
             "jit.traces", "").labels(site="serving.prefill", **lbl)
+        self._m_rows_traces = ctr(
+            "jit.traces", "").labels(site="serving.step_rows", **lbl)
+        self._m_rows_only = ctr(
+            "serving.rows_only_ticks",
+            "ticks of a cursor engine with no prompt chunk: the rows-alone "
+            "step program ran, a pass over the rows part and a stub of the "
+            "chunk part").labels(**lbl)
         # preemptive scheduling + host KV tier (ISSUE 16; BASELINE.md
         # "Preemption accounting conventions": swap bytes are pool
         # traffic, NEVER streamed-KV bytes)
@@ -1763,14 +1783,16 @@ class ServingEngine:
                 if o.dtype is np.float32 else x)
         return a
 
-    def _step_program(self):
+    def _step_program(self, rows_alone=False):
         """The Python body of THE step program, composed for this engine's
         layout and compiled exactly once: ONE pass of the model's weights
         (``decode_parts``) over a rows part and, when ``chunked``, a chunk
         part, over the cache addressed through block tables when ``paged``
         and by slot row when not.  Named by its layout,
         ``_[spec_][mixed_]step_impl[_paged]``: the device trace's module
-        line and the benchmark's readers go by that name.
+        line and the benchmark's readers go by that name.  ``rows_alone``
+        gives a cursor engine's second program, for its chunk-free ticks
+        (``_[spec_]rows_step_impl[_paged]``, below).
 
         A part (``models.parts.DecodePart``) is a run of tokens with its
         own way into the per-request state: ids, positions, its block
@@ -1833,9 +1855,7 @@ class ServingEngine:
         over the ``cdst`` cache row, each layer's cut out with a dynamic
         slice and put back.  Pad-tail writes past the prompt land where
         decode overwrites them before the mask can read them (the
-        wave-prefill padding argument); a chunk-free tick rides the same
-        program with an all-null table, or ``cpos = max_length`` (every
-        write drops, the row round-trips bit-identical).  Its logits are
+        wave-prefill padding argument).  Its logits are
         taken at ``clen - 1`` alone: the sampled chunk token is the
         request's FIRST token when this chunk completes the prompt; the
         host discards it otherwise (always, for a block-diffusion model:
@@ -1843,6 +1863,31 @@ class ServingEngine:
         token comes with the first block's delivery).  A prefilling slot is
         inactive until its cursor completes, so the two parts never touch
         the same row.
+
+        A chunk-free tick runs the ``rows_alone`` program instead, which
+        the host chooses by what the tick holds (``_device_step``): the
+        same body with the chunk part cut to a stub of its first
+        ``_stub_chunk`` (8) positions — ``num_slots·(k+1) + 8`` token rows
+        in the pass where the mixed program has ``prefill_chunk`` more —
+        the same operand table and packed layout, the same outputs (the
+        chunk's token is junk, which the host does not read).  On such a
+        tick ``clen`` is 0, so the stub holds no real token: its table is
+        all null, or ``cpos = max_length`` (every write drops, the row
+        round-trips bit-identical), its state row the null row.  It is
+        compiled once, at the engine's first chunk-free tick, under a
+        budget of 1 of its own (site ``serving.step_rows``).
+
+        Why a stub and not the rows part alone: what XLA:TPU rounds to
+        bfloat16 follows from what it fuses (``xla_allow_excess_precision``),
+        and what it fuses from the parts' cuts.  With a chunk part beside
+        the rows part a projection's result is materialised in bfloat16
+        where the parts take their cuts of it; with the rows part alone it
+        stays float32 through the q/k norm and RoPE (SDAR, PR 44: the K the
+        rows part wrote differed from the mixed program's in a fifth of its
+        elements in the first layer, and the served tokens lay twice as far
+        from the reference's as the parent's did on the same requests).  A
+        stub keeps the cuts and with them the mixed program's rounding: a
+        live row computes what it computes in the mixed program.
 
         Per-slot state (a model's ``slot_state`` leaves): the rows part
         addresses the slots' rows (the null row stays out), the chunk part
@@ -1858,7 +1903,7 @@ class ServingEngine:
             a = self._unpack(self._step_table, packed, own)
             prep = self._prepare(params)
             mask, key = a["slot_mask"], a["key"]
-            parts = self._step_parts(a)
+            parts = self._step_parts(a, stub=rows_alone)
             tokens = parts[0].input_ids
             if chunked:
                 whole = _disp.program_part(_STEP, "token_pass")
@@ -1905,7 +1950,8 @@ class ServingEngine:
             return (*outs, *([jnp.stack(load)[None]] if load else ()), cache)
 
         step.__name__ = step.__qualname__ = (
-            "_" + "spec_" * spec + "mixed_" * chunked + "step_impl"
+            "_" + "spec_" * spec
+            + ("rows_" if rows_alone else "mixed_" * chunked) + "step_impl"
             + "_paged" * self.paged)
         return step
 
@@ -1917,14 +1963,22 @@ class ServingEngine:
                 + self.prefill_chunk * self.chunked)
 
     @property
+    def _stub_chunk(self) -> int:
+        """The positions the rows-alone program keeps of the chunk part:
+        one sublane tile of them (``_step_program``)."""
+        return min(_STUB_CHUNK, self.prefill_chunk)
+
+    @property
     def _row_tokens(self) -> int:
         """The positions one row of the rows part holds: the verify window,
         a block-diffusion model's block, or one token."""
         return self.spec_k + 1 if self.spec else self._block or 1
 
-    def _step_parts(self, a):
+    def _step_parts(self, a, stub=False):
         """The step program's parts (``_step_program``), from its operands
-        by name: the rows part, then the chunk part when ``chunked``."""
+        by name: the rows part, then the chunk part when ``chunked`` —
+        ``stub``: cut to the first ``_stub_chunk`` of its positions, the
+        rows-alone program's."""
         spec, paged = self.spec, self.paged
         part = functools.partial(_disp.program_part, _STEP)
 
@@ -1953,6 +2007,8 @@ class ServingEngine:
                 *(a["share_" + f] for f in self._share._fields)))]
         if self.chunked:
             cids, clen, cdst = a["cids"], a["clen"], a["cdst"]
+            if stub:
+                cids = cids[:, :self._stub_chunk]
             parts.append(DecodePart(
                 cids, a["cpos"][None], cdst if paged else None,
                 valid=((jnp.arange(cids.shape[1]) < clen)[None]
@@ -2874,11 +2930,13 @@ class ServingEngine:
             clen = min(self.prefill_chunk, pf.end - pf.cursor)
             cpos, cslot = pf.cursor, pf.slot
         elif chunked:
-            # chunk-free tick, same compiled program: contiguous writes
-            # drop past max_length, paged writes land in the null block,
-            # and no position is a real token (``clen`` 0: none reaches an
-            # expert or advances a state)
+            # chunk-free tick: the rows-alone program runs, whose chunk
+            # part is a stub of ``_stub_chunk`` null positions: contiguous
+            # writes drop past max_length, paged writes land in the null
+            # block, and no position is a real token (``clen`` 0: none
+            # reaches an expert or advances a state)
             cslot, cpos = 0, 0 if paged else self.max_length
+            self._m_rows_only.inc()
         if spec:
             # the draft builds the verify window, and growth below needs its
             # real span: an input-building phase of its own, before the grow.
@@ -2907,7 +2965,7 @@ class ServingEngine:
             state = {}
             if self._slot_leaves:
                 # the state row the chunk part addresses: the cursor's
-                # slot, or the null row (a chunk-free tick's junk lands
+                # slot, or the null row (a chunk-free tick's stub lands
                 # there); the rows part advances the decoding rows alone
                 own["cslot"] = cslot if do_chunk else self.num_slots
                 state = {"state": "carried" if cpos else "fresh"}
@@ -2922,8 +2980,9 @@ class ServingEngine:
                     knobs.append((np.float32(sp.temperature),
                                   np.int32(sp.top_k), np.float32(sp.top_p)))
                 walks = [(rows_pos, self._row_tokens)]
-                if chunked:  # the chunk part runs every tick, real or not
-                    walks.append(([cpos], self.prefill_chunk))
+                if chunked:     # the chunk part, or the stub it is cut to
+                    walks.append(([cpos], self.prefill_chunk if do_chunk
+                                  else self._stub_chunk))
                 facts = dict(sample_path=self._note_sample_path(*knobs),
                              **self._kv_walk(*walks))
                 if self._slot_leaves:
@@ -2945,10 +3004,15 @@ class ServingEngine:
         rows_span = span(
             "serving.verify" if spec else "serving.decode", slots=occ,
             **facts,
-            # the program's one ``decode_parts`` call: how often the tick
-            # streams the token-wise weights, over how many padded token
-            # rows, how many of them real (live rows' tokens + the chunk's)
-            weight_passes=1, pass_rows=self._pass_rows,
+            # the ``decode_parts`` call of the program this tick runs: how
+            # often it streams the token-wise weights, over how many parts
+            # that hold tokens and how many padded token rows (a chunk-free
+            # tick's stub of a chunk part among them), how many of them real
+            # (live rows' tokens + the chunk's)
+            weight_passes=1, parts=1 + do_chunk,
+            pass_rows=(self.num_slots * self._row_tokens + (
+                self.prefill_chunk if do_chunk
+                else self._stub_chunk * chunked)),
             pass_tokens=occ * (self._block or 1) + drafted + clen,
             **({"drafted": int(draft_ok.sum())} if spec else {}),
             **diffusion)
@@ -2984,7 +3048,10 @@ class ServingEngine:
             if chunked:
                 chunk = (pf if do_chunk else None, cpos, clen,
                          cdst if paged else cslot)
+            traced = self.rows_step_traces
             out = iter(self._device_step(own, chunk))
+            # a cursor engine's first chunk-free tick compiles its program
+            compiled = self.rows_step_traces > traced
             toks = next(out)
             if self._block:
                 # what the forward did, on the tick's span: positions
@@ -3006,7 +3073,8 @@ class ServingEngine:
                 # (positions are still pre-advance: the depths it read)
                 self._m_step_ms.observe((now - t0) * 1e3)
                 self._perf_tick((now - t0) * 1e3, occ,
-                                chunk_tokens=clen if do_chunk else 0)
+                                chunk_tokens=clen if do_chunk else 0,
+                                compiled=compiled)
                 if self._model_counters:
                     self._note_model_counters(list(out))
                 if self._block:
@@ -3090,9 +3158,10 @@ class ServingEngine:
                     ctopk=np.full((1,), sp.top_k, np.int32),
                     ctopp=np.full((1,), sp.top_p, np.float32))
             args = self._upload(self._step_table, own)
+        # a cursor engine's tick with no chunk runs the rows-alone program
+        fn = self._step_fn if own.get("clen", 1) else self._rows_fn
         with span(_DISPATCH, leaves=self._program_leaves):
-            *out, self._cache = self._step_fn(self._params, self._cache,
-                                              *args)
+            *out, self._cache = fn(self._params, self._cache, *args)
         with span(_READBACK):
             return jax.device_get(out)
 
@@ -3853,6 +3922,19 @@ class ServingEngine:
         return int(self._m_step_traces.value())
 
     @property
+    def rows_step_traces(self) -> int:
+        """Compilations of a cursor engine's rows-alone step program (its
+        own ``jit.traces`` site, budget 1: 0 until the first chunk-free
+        tick, then 1)."""
+        return int(self._m_rows_traces.value())
+
+    @property
+    def rows_only_ticks(self) -> int:
+        """Ticks on which a cursor engine ran its rows-alone step program:
+        no prompt chunk, so the pass held the rows part and a stub."""
+        return int(self._m_rows_only.value())
+
+    @property
     def prefill_traces(self) -> int:
         """Compilations of the prefill function (one per padded bucket
         length actually seen)."""
@@ -3973,6 +4055,8 @@ class ServingEngine:
                "tokens_generated": int(self._m_tokens.value()),
                "prefill_waves": int(self._m_waves.value()),
                "step_traces": self.step_traces,
+               "rows_step_traces": self.rows_step_traces,
+               "serving_rows_only_ticks": self.rows_only_ticks,
                "prefill_traces": self.prefill_traces,
                "slo_violations": {
                    str(c.labels["kind"]): int(c.value())

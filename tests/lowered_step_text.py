@@ -14,7 +14,9 @@ the engine left the llama step programs as they were, PR 29 that one composed
 step program lowers to the text of the eight hand-written ones, PR 41 that
 a wave engine's step program and its prefill program at ``prefill_batch`` rows
 stayed as they were beside the one-row program (the ``prefill rows=1``
-lines, which an older checkout does not print)."""
+lines, which an older checkout does not print), PR 44 that every step
+program stayed as it was beside a cursor engine's rows-alone program (the
+``rows step`` lines)."""
 import base64
 import hashlib
 import json
@@ -176,6 +178,11 @@ def programs(label, eng, bucket, vocab=None):
     if vocab:
         print("   sorts over the vocabulary (shape, in a branch):",
               sorts_over(low, vocab))
+    # a cursor engine's program for its chunk-free ticks (PR 44)
+    if getattr(eng, "_rows_fn", None) is not None:
+        rows = eng._rows_fn.python_fn
+        print(label, "rows step", rows.__name__,
+              *sha(lowered(rows, eng._lint_args())))
     if eng._prefill_fn is not None:
         prefill = eng._prefill_fn.python_fn
         print(label, "prefill", prefill.__name__,

@@ -534,9 +534,10 @@ def test_spans_count_shared_blocks_once(seeded):
     assert (a["rows_grouped"], a["shared_tiles"]) == (3, 1)
     assert a["rows_blocks"] == a["rows_distinct"] < sum(per_row)
     # by the layout's tiles and groups, three layers: the tile's 4 blocks
-    # + the live rows' own + the idle row's one + the chunk part's one
+    # + the live rows' own + the idle row's one + the one of the stub the
+    # rows-alone program keeps of the chunk part (no chunk this tick)
     own = sum(n - 4 for n in per_row)
-    assert a["kv_blocks"] == 3 * (4 + own + 1 + 1)
+    assert (a["parts"], a["kv_blocks"]) == (1, 3 * (4 + own + 1 + 1))
     assert a["kv_walk"] >= a["kv_blocks"]
     snap = obs.snapshot()["kv_cache.position_bytes"]["series"]
     mine = [r for r in snap if r["labels"].get("engine") == eng._eid]

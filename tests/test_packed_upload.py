@@ -215,6 +215,8 @@ def test_a_programs_text_does_not_hold_the_engines_seed(models, layout):
     for seed in (0, 1, 2**31 + 7):
         eng = _engine(models, layout, seed=seed)
         programs = [(eng._step_fn, eng._lint_args())]
+        if eng._rows_fn is not None:
+            programs.append((eng._rows_fn, eng._lint_args()))
         if eng._prefill_fn is not None:
             programs.append((eng._prefill_fn, eng._lint_args(16)))
         texts.add(tuple(jax.jit(fn.python_fn).lower(*args).as_text()
@@ -301,6 +303,8 @@ def test_a_program_call_makes_the_transfers_its_span_states(
             return fn(*args)
         return call
     eng._step_fn = spy(eng._step_fn)
+    if eng._rows_fn is not None:    # a cursor engine's chunk-free ticks
+        eng._rows_fn = spy(eng._rows_fn)
     if eng._prefill_fn is not None:
         eng._prefill_fn = spy(eng._prefill_fn)
     obs.get_tracer().clear()

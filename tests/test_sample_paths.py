@@ -379,6 +379,8 @@ def test_engine_counts_each_ticks_path_as_the_device_decides_it(lm, layout):
     eng._linted = True      # the first tick's self-lint would trace the spy
     step_fn, prefill_fn = eng._step_fn, eng._prefill_fn
     eng._step_fn = spy(step_fn, "step", eng._step_table)
+    if eng._rows_fn is not None:    # a cursor engine's chunk-free ticks
+        eng._rows_fn = spy(eng._rows_fn, "step", eng._step_table)
     if prefill_fn is not None:
         eng._prefill_fn = spy(prefill_fn, "prefill", eng._prefill_table)
 

@@ -452,11 +452,16 @@ def test_the_block_ticks_spans_and_counters_add_up(seeded):
             "serving.advance"} <= names and "serving.verify" not in names
     for a in rows:
         assert a["block"] == B and a["weight_passes"] == 1
-        assert a["pass_rows"] == 4 * B + CHUNK
+        # the program the tick ran: the block rows, and the chunk's
+        # positions where a chunk ran beside them (the stub of them the
+        # rows-alone program keeps where none did)
+        assert a["pass_rows"] == 4 * B + (
+            CHUNK if a["parts"] == 2 else eng._stub_chunk)
         assert 0 <= a["unmasked"] <= a["masked_in"] <= a["slots"] * B
         assert a["commits"] <= a["slots"]
         assert a["kv_walk"] >= a["kv_blocks"] > 0
         assert a["sample_path"] == "greedy"
+    assert sum(a["parts"] - 1 for a in rows) == len(chunks) < len(rows)
     # a tick's real tokens: its live rows' blocks and its chunk's tokens
     by_tick = {}
     for e in chunks:

@@ -61,6 +61,10 @@ def test_a_real_tick_runs_the_program_the_table_describes(
         return call
     eng._linted = True      # the first tick's self-lint would trace the spy
     eng._step_fn = spy(step, "step")
+    if chunked:     # a chunk-free tick's program: the same table and name
+        assert eng._rows_fn.python_fn.__name__ == \
+            step.python_fn.__name__.replace("mixed_", "rows_")
+        eng._rows_fn = spy(eng._rows_fn, "step")
     if prefill is not None:
         eng._prefill_fn = spy(prefill, "prefill")
     rs = np.random.RandomState(3)

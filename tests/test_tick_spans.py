@@ -115,6 +115,8 @@ def _spy_programs(eng):
         return call
     eng._linted = True              # or the first tick's lint traces the spy
     eng._step_fn = spy(eng._step_fn, eng._step_table)
+    if eng._rows_fn is not None:    # a cursor engine's chunk-free ticks
+        eng._rows_fn = spy(eng._rows_fn, eng._step_table)
     if eng._prefill_fn is not None:
         eng._prefill_fn = spy(eng._prefill_fn, eng._prefill_table)
     return calls
@@ -295,7 +297,8 @@ def test_tick_spans_count_the_kernels_block_walk(lm, mode):
     """``kv_blocks=`` / ``kv_walk=`` on the tick's rows span: the flash-decode
     kernel's own bounds (``walk_counts``) over the positions the tick
     UPLOADS — the rows' (a verify window's q length with them) and, on a
-    cursor engine, the chunk part's, real or not — times the layers."""
+    cursor engine, the chunk part's (a chunk-free tick's program keeps a stub
+    of it) — times the layers."""
     from paddle_tpu.ops.pallas.decode_attention import walk_counts
 
     kw = dict({"paged": True, "block_len": 8}, **WALK_MODES[mode])
@@ -303,14 +306,18 @@ def test_tick_spans_count_the_kernels_block_walk(lm, mode):
     for i, n in enumerate(PROMPTS):
         eng.submit(_prompt(n, i + 1), max_new_tokens=6)
     eng._linted = True              # or the first tick's lint traces the spy
-    handed, step_fn = [], eng._step_fn
+    handed = []
 
-    def spy(params, cache, packed, *own):
-        a = eng._unpack(eng._step_table, packed, own)
-        handed.append({n: np.asarray(a[n]) for n in ("positions", "cpos")
-                       if n in a})
-        return step_fn(params, cache, packed, *own)
-    eng._step_fn = spy
+    def spy(fn):
+        def call(params, cache, packed, *own):
+            a = eng._unpack(eng._step_table, packed, own)
+            handed.append({n: np.asarray(a[n])
+                           for n in ("positions", "cpos", "clen") if n in a})
+            return fn(params, cache, packed, *own)
+        return call
+    eng._step_fn = spy(eng._step_fn)
+    if eng._rows_fn is not None:    # a cursor engine's chunk-free ticks
+        eng._rows_fn = spy(eng._rows_fn)
     c = lm.config
     g = c.num_attention_heads // c.num_key_value_heads
     bk, cols = (8, MAXLEN // 8) if eng.paged else (MAXLEN, 1)
@@ -326,8 +333,9 @@ def test_tick_spans_count_the_kernels_block_walk(lm, mode):
             continue
         (ops,) = handed[before:]
         calls = [(ops["positions"], eng.spec_k + 1 if eng.spec else 1)]
-        if eng.chunked:
-            calls.append(([int(ops["cpos"])], eng.prefill_chunk))
+        if eng.chunked:     # a chunk-free tick's program keeps a stub
+            calls.append(([int(ops["cpos"])], eng.prefill_chunk
+                          if ops["clen"] else eng._stub_chunk))
         need, walk = (sum(x) for x in zip(*(
             walk_counts(p, s, g, bk=bk, n_cols=cols) for p, s in calls)))
         assert 0 < need <= walk
@@ -345,9 +353,10 @@ PASS_MODES = dict(MODES, spec_chunked={
 def test_tick_spans_say_what_the_one_weight_pass_ran_over(lm, mode):
     """``weight_passes=`` / ``pass_rows=`` / ``pass_tokens=`` on the tick's
     rows span: the step program streams the token-wise weights once, over
-    ``num_slots·(k+1) + prefill_chunk`` token rows, of which the live rows'
-    tokens (a verify window's real drafts with them) and the chunk's real
-    tokens are real — none of a chunk-free tick's chunk part."""
+    ``num_slots·(k+1)`` token rows and ``prefill_chunk`` more on a tick with
+    a chunk, the rows-alone program's stub of them on a cursor engine's tick
+    without (``parts=`` 1 or 2: the program the tick runs), of which the live rows' tokens (a verify window's real drafts with them)
+    and the chunk's real tokens are real."""
     eng = ServingEngine(lm, num_slots=3, max_length=MAXLEN, paged=True,
                         block_len=8, **PASS_MODES[mode])
     from paddle_tpu.serving.drafter import Drafter
@@ -358,8 +367,7 @@ def test_tick_spans_say_what_the_one_weight_pass_ran_over(lm, mode):
     for i, n in enumerate((13, 7)):
         eng.submit(_prompt(n, i + 1), max_new_tokens=8,
                    drafter=TwoTokens() if eng.spec else None)
-    rows_of = eng.num_slots * (eng.spec_k + 1 if eng.spec else 1) \
-        + eng.prefill_chunk * eng.chunked
+    rows_of = eng.num_slots * (eng.spec_k + 1 if eng.spec else 1)
     kinds = set()
     while eng.queue_depth or eng.last_occupancy or not kinds:
         obs.get_tracer().clear()
@@ -371,10 +379,13 @@ def test_tick_spans_say_what_the_one_weight_pass_ran_over(lm, mode):
         if not rows:
             continue
         (a,) = rows
-        assert (a["weight_passes"], a["pass_rows"]) == (1, rows_of)
+        ran = rows_of + (eng.prefill_chunk if chunk
+                         else eng._stub_chunk * eng.chunked)
+        assert (a["weight_passes"], a["parts"], a["pass_rows"]) == (
+            1, 1 + len(chunk), ran)
         real = a["slots"] + a.get("drafted", 0) + sum(
             c["tokens"] for c in chunk)
-        assert a["pass_tokens"] == real <= rows_of
+        assert a["pass_tokens"] == real <= ran
         kinds.add((a["slots"] > 0, bool(chunk), a.get("drafted", 0) > 0))
     # live rows with and (on a cursor engine) without a chunk beside them,
     # a chunk with no live row; a verify window with real drafts
