@@ -65,7 +65,7 @@ from .fleet.mp_layers import constrain
 
 __all__ = ["Gate", "SwitchGate", "GShardGate", "MoELayer",
            "SigmoidTopKGate", "SoftmaxTopKGate", "HeldExpertsMoE",
-           "expert_load"]
+           "held_experts_kernel_specs", "expert_load"]
 
 EP_AXES = ("dp", "sharding")  # expert dim rides the combined dp×sharding axes
 
@@ -534,3 +534,20 @@ class HeldExpertsMoE(Layer):
             out = jnp.where(held.T[..., None], back.astype(jnp.float32)
                             * w.T[..., None], 0.0).sum(0)
         return out.astype(x.dtype).reshape(shape)
+
+
+def held_experts_kernel_specs(config, token_rows):
+    """Pre-flight specs of the kernels only a :class:`HeldExpertsMoE`
+    model's step programs build: the grouped products (in and out
+    projection), per pass of the weights over ``token_rows`` tokens.
+    ``config``: any with ``experts_held``, ``num_experts_per_tok``,
+    ``hidden_size`` and ``moe_intermediate_size``."""
+    from ..static_analysis import moe_experts_spec
+    c = config
+    lo, hi = c.experts_held
+    h, fm = c.hidden_size, c.moe_intermediate_size
+    return [moe_experts_spec(rows * c.num_experts_per_tok, hi - lo, k, n,
+                             variant=f"tokens={rows},{k}x{n}")
+            for rows in token_rows
+            for k, n in sorted({(h, fm), (fm, h)})
+            if grouped_kernel_takes(k, n)]

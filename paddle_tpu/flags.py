@@ -147,10 +147,6 @@ DEFINE("decode_attention_block_kv", 512,
        "flash-decode KV chunk size (cap; the kernel picks the largest "
        "128-aligned divisor of max_length at or below it)")
 # paged KV cache (serving/kv_cache.py): the serving engine's block pool
-DEFINE("serving_paged_kv", False,
-       "ServingEngine default cache layout: False = contiguous per-slot "
-       "rows, True = paged block pool with prefix caching (engine "
-       "constructor arg overrides)")
 DEFINE("kv_cache_block_len", 128,
        "paged KV cache block length in tokens.  128 keeps one block == "
        "one 128-aligned flash-decode KV chunk so the Pallas kernel can "
@@ -161,9 +157,6 @@ DEFINE("kv_cache_num_blocks", 0,
        "derives num_slots * max_length / block_len — the contiguous "
        "cache's footprint, now shareable across slots; set lower to "
        "serve more slots than worst-case memory would allow")
-DEFINE("serving_prefix_cache", True,
-       "register full prompt blocks in the paged cache's prefix trie and "
-       "serve later prompts that share them without recompute")
 # quantized KV cache (serving/kv_cache.py + models/llama.py + the
 # flash-decode kernel): int8 blocks with per-block-per-kv-head scales
 # halve both resident-session HBM and the per-step cache stream — the
@@ -187,54 +180,10 @@ DEFINE("serving_int8_weights", False,
 DEFINE("serving_chunked_prefill", False,
        "ServingEngine default admission mode: False = wave prefill "
        "(separate bucketed prefill programs), True = chunked prefill "
-       "(prompts split into FLAGS_serving_prefill_chunk-token chunks "
+       "(prompts split into prefill_chunk-token chunks "
        "folded into the once-jitted mixed decode step, so in-flight "
        "decodes never stall behind a long prompt; engine constructor "
        "arg overrides)")
-DEFINE("serving_prefill_chunk", 256,
-       "chunked-prefill token budget per scheduler tick: each mixed "
-       "step carries num_slots decode tokens plus one prompt chunk of "
-       "at most this many tokens.  Larger chunks finish prompts (TTFT) "
-       "faster; smaller chunks bound the per-tick latency bump in-flight "
-       "decodes see (TPOT).  Static — part of the compiled step shape")
-DEFINE("serving_chunk_policy", "prefill",
-       "mixed-step scheduling policy: 'prefill' schedules a pending "
-       "prompt chunk on every tick (fastest TTFT); 'decode' interleaves "
-       "— while any slot is decoding, chunks run on alternate ticks "
-       "only, halving prefill bandwidth to protect TPOT further")
-# speculative decoding (serving/engine.py + serving/drafter.py): at b=1
-# decode sits AT the bf16 weight-stream floor (BENCH_DECODE.json), so the
-# only way faster is amortising each weight pass over several tokens —
-# score a host-drafted window through the q-tiled flash-decode path in
-# ONE step and keep the longest verified prefix
-DEFINE("serving_spec_decode", False,
-       "ServingEngine default decode mode: True = speculative decoding "
-       "(a host-side n-gram self-drafter proposes up to "
-       "FLAGS_serving_spec_k tokens per slot per tick; one mixed verify "
-       "step scores them all and greedy rows accept the longest matching "
-       "prefix, 1..k+1 tokens per step).  Greedy outputs stay "
-       "token-identical to plain decode; sampled rows fall back to one "
-       "token per step.  Engine constructor arg overrides")
-DEFINE("serving_spec_k", 4,
-       "speculative draft window: max draft tokens proposed per slot per "
-       "verify step.  Static — the verify step is compiled for q-depth "
-       "k+1, so every tick runs the same program whether drafts hit or "
-       "not (no-draft rows ride along as effective depth-1 decode).  "
-       "Larger k amortises the weight stream further when drafts hit but "
-       "wastes verify compute (and, paged, block churn) when they miss")
-DEFINE("serving_spec_ngram", 3,
-       "longest n-gram the prompt-lookup self-drafter matches against "
-       "each slot's prompt+generated history when proposing drafts "
-       "(it backs off to shorter n-grams, floor 1, before giving up)")
-DEFINE("serving_spec_drafter", "ngram",
-       "ServingEngine default drafter kind: 'ngram' = the free host-side "
-       "prompt-lookup proposer (serving/drafter.py NgramDrafter); "
-       "'model' = a draft MODEL sharing the engine (its own param set, "
-       "tiny contiguous KV cache and once-jitted draft step at q-depth "
-       "k), which drafts novel text the n-gram matcher cannot and "
-       "emits the proposal distribution the rejection-sampling "
-       "acceptance needs.  Engine constructor arg and per-request "
-       "submit(drafter=...) override")
 # mesh-sharded serving (serving/engine.py mesh=... + serving/router.py):
 # the tensor-parallel engine step and the data-parallel replica router —
 # ROADMAP item 1's multi-chip execution path
@@ -246,19 +195,6 @@ DEFINE("serving_mesh", "",
        "placed per models.generation.decode_mesh_specs and the "
        "once-jitted step runs under declared in_shardings with the "
        "cache operand still donated")
-DEFINE("serving_dp_replicas", 1,
-       "ReplicaRouter default replica count: data-parallel ServingEngine "
-       "replicas behind one submit() (serving/router.py); each replica "
-       "owns its KV cache/block pool while the model params are shared "
-       "host-side.  1 = a trivial single-replica router")
-DEFINE("serving_router_policy", "prefix",
-       "ReplicaRouter placement policy: 'prefix' hashes the longest "
-       "trie-matched prompt prefix to the replica holding the warm "
-       "blocks (falling back to least-loaded when no replica has a "
-       "full-block match), 'least_loaded' ranks replicas by queue depth "
-       "+ pending chunks + busy slots, 'round_robin' rotates.  Session "
-       "affinity overrides every policy: a session's requests never "
-       "migrate off their replica")
 # graph lint (paddle_tpu/static_analysis): jaxpr static analysis of the
 # serving hot path — donation, dtype widening, constant capture,
 # host-sync, retrace hazards — one abstract trace, before any device run
@@ -344,30 +280,6 @@ DEFINE("serving_slo_tpot_ms", 0.0,
        "retired request whose mean time-per-output-token exceeds this "
        "misses SLO, attributed to decode.  0 disables the TPOT "
        "deadline")
-# preemptive scheduling + HBM->host KV tiering (serving/engine.py +
-# serving/kv_cache.py HostTier): when paged admission would block on a
-# full pool, a victim selector preempts a running slot instead of
-# waiting for retirement
-DEFINE("serving_preempt", "off",
-       "ServingEngine default preemption mode when paged admission "
-       "blocks on pool_full: 'off' (FIFO-blocking, the historical "
-       "behavior), 'swap' (victim's private blocks move to the pinned "
-       "host pool and the request resumes with its exact KV restored), "
-       "or 'recompute' (victim's blocks are freed and the request "
-       "re-prefills through the prefix trie on resume).  Engine "
-       "constructor arg overrides")
-DEFINE("serving_host_blocks", 0,
-       "capacity of the host-RAM KV tier in blocks (same geometry as "
-       "the device pool).  >0 arms HBM->host demotion of cold prefix-"
-       "trie blocks (re-promoted on a prefix hit) and is required for "
-       "preempt mode 'swap' (pinned swap buffers share this pool; "
-       "pinned records always win over demoted trie entries).  0 "
-       "disables the tier")
-DEFINE("serving_preempt_after", 2,
-       "admission must have blocked for this many consecutive ticks "
-       "before a waiter may preempt a SAME-priority victim (strictly "
-       "lower-priority victims are preempted immediately); guards "
-       "against churn under transient pressure")
 # cost model + perf sentinel (paddle_tpu/observability/costmodel.py,
 # regression.py): per-tick analytical roofline, measured-vs-predicted
 # attribution, and EWMA anomaly/drift detection (BASELINE.md "Cost-model

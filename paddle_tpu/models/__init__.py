@@ -5,26 +5,11 @@ in-tree here because they are the benchmark workloads the framework is
 measured on (BASELINE.md) and they double as integration tests of the hybrid
 parallel stack.
 
-What the serving engine (``serving.ServingEngine``) takes: ``llama`` in every
-layout; ``afmoe`` (Trinity) on the paged pool, wave or chunked, with or
-without a prefix cache; ``lfm2`` (LFM2-MoE: short-convolution state a slot
-beside the paged pool) as ``paged=True, chunked=True, prefix_cache=False``
-and nothing else — its ``check_serving_layout`` names what it refuses (a
-prefix cache, preemption and the host tier, export/import, the contiguous
-cache, wave prefill, int8 KV, speculation, a mesh, int8 weights); ``sdar``
-(SDAR-MoE: generation by diffusion over blocks, declared as
-``block_diffusion`` — the engine's rows part is then a block of positions a
-row, tokens leave it a block at a time) as ``paged=True, chunked=True,
-prefix_cache=False`` too, refusing the same list by name;
-``latent_moe`` (latent attention, MLA, over a sigmoid-routed MoE: the paged
-pool holds ONE entry a position that is key and value at once, declared as
-``kv_pool_entry``) as ``paged=True, chunked=True`` WITH or without a prefix
-cache, refusing by name the contiguous cache, wave prefill, int8 KV,
-preemption and the host tier, export/import, a mesh, speculation and int8
-weights.  A model
-that keeps a decode state of its own (``init_decode_state``: ``mamba``,
-``rwkv``) and does not declare it as serving state (``slot_state`` +
-``init_serving_cache``) is refused at construction.
+What the serving engine (``serving.ServingEngine``) takes is told once, in
+:class:`~paddle_tpu.models.parts.ServingTraits`: ``llama`` in every layout,
+and ``afmoe`` (Trinity), ``lfm2`` (LFM2-MoE), ``sdar`` (SDAR-MoE) and
+``latent_moe`` (latent attention, MLA) as each one's ``serving_traits``
+declare — what a family refuses, and why, is its ``unsupported``.
 """
 
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, tiny_afmoe_config

@@ -28,10 +28,10 @@ exchange that supplies it is not part of this model — and that partial
 result goes on to the next layer; the shared expert is computed whole.
 
 The cache row is plain K and V of ``num_key_value_heads · head_dim``, so
-the model decodes over the stacked caches llama uses: the paged pool
-(``serving/kv_cache.py``) as it is, written through llama's
-:func:`~paddle_tpu.models.llama.paged_kv_write`, and for ``generate()`` the
-contiguous cache.  Window layers pass their window to the cached-attention
+the model decodes over the stacked caches llama uses
+(:func:`~paddle_tpu.models.parts.kv_attention`): the paged pool
+(``serving/kv_cache.py``) as it is and, for ``generate()``, the contiguous
+cache.  Window layers pass their window to the cached-attention
 ops, whose kernel neither reads nor scores blocks behind it; the allocator
 frees nothing behind a window yet (ROADMAP R5).
 """
@@ -39,6 +39,7 @@ frees nothing behind a window yet (ROADMAP R5).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -46,20 +47,21 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
-from ..distributed.moe import HeldExpertsMoE, SigmoidTopKGate
+from ..distributed.fleet.mp_layers import vocab_parallel_lookup
+from ..distributed.moe import (HeldExpertsMoE, SigmoidTopKGate,
+                               held_experts_kernel_specs)
 from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
-from .llama import LlamaMLP, paged_kv_write, part_site
-from .parts import (DecodePart, head_tokens, join_tokens, join_valid,
-                    part_by_part, split_tokens)
+from .llama import swiglu_mlp
+from .parts import (CausalLMDecode, ServingTraits, band_mask, join_valid,
+                    kv_attention)
 
 __all__ = ["AfmoeConfig", "AfmoeAttention", "AfmoeMoE",
            "AfmoeDecoderLayer", "AfmoeModel", "AfmoeForCausalLM",
-           "tiny_afmoe_config", "held_experts_kernel_specs"]
+           "tiny_afmoe_config"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -201,95 +203,24 @@ class AfmoeAttention(Layer):
             gate = jax.nn.sigmoid(matmul(x, self.gate_proj))
             return matmul(attn.reshape(b, s, -1) * gate, self.o_proj)
 
-    def _band(self, s: int):
-        """(1, 1, s, s) bool: key j inside query i's window."""
-        i = jnp.arange(s)
-        return (i[:, None] - i[None, :] < self.window)[None, None]
-
     def forward(self, x, rope_cache, position_ids=None):
         with jax.named_scope(self.scope):
             q, k, v = self._qkv(x, rope_cache, position_ids)
-            mask = None if self.window is None else self._band(x.shape[1])
+            mask = (None if self.window is None
+                    else band_mask(x.shape[1], self.window))
             out = flash_attention(q, k, v, causal=True, attn_mask=mask)
             return self._out(x, out)
 
     def decode(self, x, rope_cache, parts, cache, idx: int):
-        """Decode over the stacked cache, as ``LlamaAttention.decode``:
-        the projections, the gate and the output projection once over the
-        tokens of all ``parts``; each part's RoPE, K/V write and read at
-        its own positions — with ``block_tables`` through the paged pool
-        (per-row ``pos``), without them over the contiguous cache at a
-        scalar ``pos`` (``generate()``).  Returns (out, cache)."""
-        if isinstance(cache, dict):
-            raise NotImplementedError(
-                "AfmoeAttention.decode: the int8 KV cache is not supported")
+        """Decode over the stacked cache
+        (:func:`~paddle_tpu.models.parts.kv_attention`); the gate and the
+        output projection once over the tokens of all ``parts``.  Returns
+        (out, cache)."""
         with jax.named_scope(self.scope):
-            sites = [self._site(p, rope_cache) for p in parts]
-            out, cache = part_by_part(
-                parts, self._proj(x), cache,
-                lambda i, p, cache, q, k, v: self._attend(
-                    q, k, v, rope_cache, p, sites[i], cache, idx))
+            out, cache = kv_attention(
+                "AfmoeAttention", x, self._proj, parts, rope_cache, cache,
+                idx, rope=self._rope, window=self.window)
             return self._out(x, out), cache
-
-    @staticmethod
-    def _site(part, rope_cache):
-        if part.block_tables is None and getattr(part.pos, "ndim", 0) != 0:
-            raise NotImplementedError(
-                "AfmoeAttention.decode: per-row positions need the "
-                "paged pool (block_tables); the contiguous cache is "
-                "decoded at one scalar position")
-        return part_site(part, rope_cache)
-
-    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
-        """One part's RoPE, write and read against layer ``idx``."""
-        from ..ops.attention import (cached_decode_attention,
-                                     paged_decode_attention)
-        pos, position_ids, rope_ids = site
-        s = q.shape[1]
-        win = {} if self.window is None else {"window": self.window}
-        q, k = self._rope(q, k, rope_cache, rope_ids)
-        if part.block_tables is not None:
-            cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
-                                           part.block_tables)
-            return paged_decode_attention(q, kvp, idx, pos,
-                                          part.block_tables, **win), cache
-        cache = jax.lax.dynamic_update_slice(
-            cache, k.astype(cache.dtype)[None, None],
-            (idx, 0, 0, pos, 0, 0))
-        cache = jax.lax.dynamic_update_slice(
-            cache, v.astype(cache.dtype)[None, None],
-            (idx, 1, 0, pos, 0, 0))
-        if isinstance(pos, int) and pos == 0 and s > 1:
-            mask = None if self.window is None else self._band(s)
-            return flash_attention(q, k, v, causal=True,
-                                   attn_mask=mask), cache
-        return cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
-                                       pos, **win), cache
-
-
-def held_experts_kernel_specs(config, token_rows):
-    """Pre-flight specs of the kernels only a held-experts model's step
-    programs build: the grouped products (in and out projection), per
-    pass of the weights over ``token_rows`` tokens.  ``config``: any with
-    ``experts_held``, ``num_experts_per_tok``, ``hidden_size`` and
-    ``moe_intermediate_size``."""
-    from ..distributed.moe import grouped_kernel_takes
-    from ..static_analysis import moe_experts_spec
-    c = config
-    lo, hi = c.experts_held
-    h, fm = c.hidden_size, c.moe_intermediate_size
-    return [moe_experts_spec(rows * c.num_experts_per_tok, hi - lo, k, n,
-                             variant=f"tokens={rows},{k}x{n}")
-            for rows in token_rows
-            for k, n in sorted({(h, fm), (fm, h)})
-            if grouped_kernel_takes(k, n)]
-
-
-def swiglu_mlp(config: AfmoeConfig, width: int) -> LlamaMLP:
-    """SwiGLU MLP of a given width — the dense layers' and the shared
-    expert's: llama's, which reads ``hidden_size``, ``intermediate_size``,
-    ``initializer_range`` and ``dtype`` of whatever config it is given."""
-    return LlamaMLP(dataclasses.replace(config, intermediate_size=width))
 
 
 class AfmoeMoE(Layer):
@@ -389,26 +320,25 @@ class AfmoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.norm(x)
 
-    def decode(self, parts, cache):
-        """Cache-carrying decode pass of ``parts``
-        (:mod:`~paddle_tpu.models.parts`) over the stacked cache
-        (contiguous from ``init_kv_cache`` or, for parts with
-        ``block_tables``, the paged pool).  Returns (the normed hidden
-        states the head is taken of, their per-part (rows, positions),
-        cache)."""
-        x = constrain(
-            self._embed(join_tokens([p.input_ids for p in parts])),
-            ("dp", "sharding"), None, None)
-        rope = (self.rope_cos, self.rope_sin)
-        for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, parts, cache, i)
-        x, shapes = head_tokens(x, parts)
-        return self.norm(x), shapes, cache
+
+# the engine layouts this model cannot run, and why
+# (``models.parts.ServingTraits.unsupported``)
+_UNSUPPORTED = {
+    "contiguous_cache":
+        "its decode takes per-row positions over the paged pool only",
+    "kv_cache_dtype": "the windowed attention path has no int8 pool",
+    "mesh": "the held-experts layer has no exchange and the grouped "
+            "product no sharded form",
+    "spec_decode": "the model drafter keeps a contiguous cache and "
+                   "draft_model_from truncates a llama",
+    "int8_weights": "quantize_for_decode knows no stacked expert weights",
+}
 
 
-class AfmoeForCausalLM(Layer):
-    """Causal LM over :class:`AfmoeModel`; the serving engine's contract
-    is ``config`` + ``decode_parts`` over the stacked cache."""
+class AfmoeForCausalLM(CausalLMDecode, Layer):
+    """Causal LM over :class:`AfmoeModel`, served over the stacked cache
+    (:class:`~paddle_tpu.models.parts.CausalLMDecode`; the embedding's μP
+    scaling stays this model's)."""
 
     def __init__(self, config: AfmoeConfig):
         super().__init__()
@@ -428,65 +358,15 @@ class AfmoeForCausalLM(Layer):
     def forward(self, input_ids, position_ids=None):
         return self.logits(self.model(input_ids, position_ids))
 
-    def decode_parts(self, parts, cache):
-        """([logits a part], cache): ONE pass of the weights over the
-        tokens of every part, as ``LlamaForCausalLM.decode_parts``: one
-        routing, one sort and three grouped products an expert layer over
-        all of them.  A part's ``valid`` marks its real tokens; the routed
-        experts leave padding out (``HeldExpertsMoE.forward``)."""
-        hidden, shapes, cache = self.model.decode(parts, cache)
-        with jax.named_scope("lm_head"):
-            return split_tokens(self.logits(hidden), shapes), cache
-
-    def decode_step(self, input_ids, cache, pos, block_tables=None,
-                    valid=None):
-        """(logits, cache): one cache-carrying decode step, as
-        ``LlamaForCausalLM.decode_step``: the pass over one part."""
-        (logits,), cache = self.decode_parts(
-            [DecodePart(input_ids, pos, block_tables, valid)], cache)
-        return logits, cache
-
-    def generate(self, input_ids, max_new_tokens: int = 32, **kw):
-        from .generation import greedy_generate
-        return greedy_generate(self, input_ids, max_new_tokens, **kw)
-
-    # -- what the serving engine asks a model -------------------------------
+    def _embed(self, input_ids):
+        return self.model._embed(input_ids)
 
     @property
-    def expert_layers(self) -> int:
-        """Expert layers: the step programs hand ``decode_step`` the real
-        tokens (``valid=``) and return the layers' load beside the sampled
-        tokens (``distributed.moe.expert_load``)."""
-        return self.config.num_expert_layers
-
-    @property
-    def attention_windows(self) -> Tuple[Optional[int], ...]:
-        """Per layer, the sliding window its attention reads, or None."""
-        return tuple(block.self_attn.window for block in self.model.layers)
-
-    def serving_kernel_specs(self, token_rows):
-        return held_experts_kernel_specs(self.config, token_rows)
-
-    def check_serving_layout(self, *, paged, kv_cache_dtype, mesh,
-                             spec_decode, int8_weights, **_):
-        """Refuse, by name, the engine layouts this model cannot run (of
-        the arguments the engine states, those this model has a say on)."""
-        def no(what, why):
-            raise NotImplementedError(
-                f"AfmoeForCausalLM cannot be served with {what}: {why}")
-        if not paged:
-            no("the contiguous cache (paged=False)",
-               "its decode takes per-row positions over the paged pool only")
-        if kv_cache_dtype != "bf16":
-            no(f"kv_cache_dtype={kv_cache_dtype!r}",
-               "the windowed attention path has no int8 pool")
-        if mesh is not None:
-            no("a mesh", "the held-experts layer has no exchange and the "
-               "grouped product no sharded form")
-        if spec_decode:
-            no("speculative decoding",
-               "the model drafter keeps a contiguous cache and "
-               "draft_model_from truncates a llama")
-        if int8_weights:
-            no("int8_weights", "quantize_for_decode knows no stacked "
-               "expert weights")
+    def serving_traits(self) -> ServingTraits:
+        c = self.config
+        return ServingTraits(
+            expert_layers=c.num_expert_layers,
+            attention_windows=tuple(block.self_attn.window
+                                    for block in self.model.layers),
+            kernel_specs=functools.partial(held_experts_kernel_specs, c),
+            unsupported=_UNSUPPORTED)

@@ -40,11 +40,12 @@ value of every head at once.
 the same mathematics with the up-projection moved from every cached
 position to the query and the result: the pool holds ``c + r`` values a
 position a layer instead of ``H (n + r + v)``.  bf16 operands, float32
-accumulation and softmax.  The pool (``kv_pool_entry``) is ``(L, 1,
-blocks, block_len, W)`` with ``W`` the entry padded with zero lanes to a
-multiple of 128 (the TPU's tiled HBM layout pads a minor axis so anyway);
-the read is :func:`~paddle_tpu.ops.attention.latent_decode_attention`, the
-flash-decode walk with that layout as a static parameter.
+accumulation and softmax.  The pool (``serving_traits.pool_entry``) is
+``(L, 1, blocks, block_len, W)`` with ``W`` the entry padded with zero
+lanes to a multiple of 128 (the TPU's tiled HBM layout pads a minor axis so
+anyway); the read is
+:func:`~paddle_tpu.ops.attention.latent_decode_attention`, the flash-decode
+walk with that layout as a static parameter.
 
 **FFN.**  Layers below ``first_k_dense_replace``: a SwiGLU of
 ``intermediate_size``.  The others: ``s = sigmoid(N_2(h') W_r)`` in float32
@@ -67,6 +68,7 @@ built.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -74,18 +76,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
-from ..distributed.moe import HeldExpertsMoE, SigmoidTopKGate
+from ..distributed.fleet.mp_layers import vocab_parallel_lookup
+from ..distributed.moe import (HeldExpertsMoE, SigmoidTopKGate,
+                               held_experts_kernel_specs)
 from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache
 from ..ops.pallas.decode_attention import LatentLayout
 from ..tensor.math import matmul
-from .afmoe import held_experts_kernel_specs, swiglu_mlp
-from .llama import paged_write_site, part_site
-from .parts import (DecodePart, PoolEntry, head_tokens, join_tokens,
-                    join_valid, part_by_part, split_tokens)
+from .llama import paged_write_site, swiglu_mlp
+from .parts import (CausalLMDecode, PoolEntry, ServingTraits, join_valid,
+                    part_by_part, part_site)
 
 __all__ = ["LatentMoeConfig", "LatentMoeForCausalLM",
            "tiny_latent_moe_config", "rope_pairs"]
@@ -452,27 +454,33 @@ class LatentMoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.norm(x)
 
-    def decode(self, parts, cache):
-        """Cache-carrying decode pass of ``parts``
-        (:mod:`~paddle_tpu.models.parts`) over the latent paged pool.
-        Returns (the normed hidden states the head is taken of, their
-        per-part (rows, positions), cache)."""
-        x = constrain(
-            vocab_parallel_lookup(
-                self.embed_tokens,
-                join_tokens([p.input_ids for p in parts])),
-            ("dp", "sharding"), None, None)
-        rope = (self.rope_cos, self.rope_sin)
-        for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, parts, cache, i)
-        x, shapes = head_tokens(x, parts)
-        return self.norm(x), shapes, cache
+
+# the engine layouts this model cannot run, and why
+# (``models.parts.ServingTraits.unsupported``).  A prefix cache is fine: the
+# trie addresses blocks, not layouts
+_UNSUPPORTED = {
+    "contiguous_cache": "the absorbed read walks the latent paged pool only",
+    "wave_prefill": "the wave's prefill program writes K and V rows; a "
+                    "prompt here goes through the chunk part's absorbed "
+                    "read",
+    "kv_cache_dtype": "the latent pool has no int8 form (one scale a block "
+                      "would cover the latent and the RoPE key alike) and "
+                      "no demotion",
+    "preemption": "no test shows the block movers exact on a latent pool",
+    "mesh": "the latent walk has no sharded form, the held-experts layer no "
+            "exchange",
+    "spec_decode": "the model drafter keeps a contiguous K/V cache and "
+                   "draft_model_from truncates a llama; the draft module "
+                   "(num_nextn_predict_layers) is not built",
+    "int8_weights": "quantize_for_decode knows no stacked expert weights "
+                    "and no low-rank projections",
+}
 
 
-class LatentMoeForCausalLM(Layer):
-    """Causal LM over :class:`LatentMoeModel`; the serving engine's contract
-    is ``config`` + ``decode_parts`` over the latent paged pool +
-    ``kv_pool_entry``."""
+class LatentMoeForCausalLM(CausalLMDecode, Layer):
+    """Causal LM over :class:`LatentMoeModel`, served
+    (:class:`~paddle_tpu.models.parts.CausalLMDecode`) over the latent
+    paged pool."""
 
     def __init__(self, config: LatentMoeConfig):
         super().__init__()
@@ -490,71 +498,16 @@ class LatentMoeForCausalLM(Layer):
         """Logits (B, T, V) of whole sequences: the plain form."""
         return self.logits(self.model(input_ids, position_ids))
 
-    def decode_parts(self, parts, cache):
-        """([logits a part], cache): ONE pass of the weights over the
-        tokens of every part, as ``AfmoeForCausalLM.decode_parts``.  A
-        part's ``valid`` marks its real tokens; the routed experts leave
-        padding out."""
-        hidden, shapes, cache = self.model.decode(parts, cache)
-        with jax.named_scope("lm_head"):
-            return split_tokens(self.logits(hidden), shapes), cache
-
-    def decode_step(self, input_ids, cache, pos, block_tables=None,
-                    valid=None):
-        """(logits, cache): the pass over one part."""
-        (logits,), cache = self.decode_parts(
-            [DecodePart(input_ids, pos, block_tables, valid)], cache)
-        return logits, cache
-
-    # -- what the serving engine asks a model -------------------------------
-
     @property
-    def kv_pool_entry(self) -> PoolEntry:
-        """The paged pool holds ONE array a layer of one entry a position:
-        the normed latent and the rotated RoPE key, key and value of every
-        head at once, padded to whole lane tiles."""
+    def serving_traits(self) -> ServingTraits:
         c = self.config
-        return PoolEntry(arrays=1, width=c.entry_width,
-                         group=c.num_attention_heads,
-                         layout=c.latent_layout)
-
-    @property
-    def expert_layers(self) -> int:
-        return self.config.num_expert_layers
-
-    def serving_kernel_specs(self, token_rows):
-        return held_experts_kernel_specs(self.config, token_rows)
-
-    def check_serving_layout(self, *, paged, chunked, prefix_cache,
-                             kv_cache_dtype, mesh, spec_decode, int8_weights,
-                             preempt, host_blocks):
-        """Refuse, by name, the engine layouts this model cannot run.  A
-        prefix cache is fine: the trie addresses blocks, not layouts."""
-        def no(what, why):
-            raise NotImplementedError(
-                f"LatentMoeForCausalLM cannot be served with {what}: {why}")
-        if not paged:
-            no("the contiguous cache (paged=False)",
-               "the absorbed read walks the latent paged pool only")
-        if not chunked:
-            no("wave prefill (chunked=False)",
-               "the wave's prefill program writes K and V rows; a prompt "
-               "here goes through the chunk part's absorbed read")
-        if kv_cache_dtype != "bf16":
-            no(f"kv_cache_dtype={kv_cache_dtype!r}",
-               "the latent pool has no int8 form (one scale a block would "
-               "cover the latent and the RoPE key alike) and no demotion")
-        if preempt != "off" or host_blocks:
-            no(f"preempt={preempt!r} / host_blocks={host_blocks}",
-               "no test shows the block movers exact on a latent pool")
-        if mesh is not None:
-            no("a mesh", "the latent walk has no sharded form, the "
-               "held-experts layer no exchange")
-        if spec_decode:
-            no("speculative decoding",
-               "the model drafter keeps a contiguous K/V cache and "
-               "draft_model_from truncates a llama; the draft module "
-               "(num_nextn_predict_layers) is not built")
-        if int8_weights:
-            no("int8_weights", "quantize_for_decode knows no stacked "
-               "expert weights and no low-rank projections")
+        return ServingTraits(
+            # ONE array a layer of one entry a position: the normed latent
+            # and the rotated RoPE key, key and value of every head at
+            # once, padded to whole lane tiles
+            pool_entry=PoolEntry(arrays=1, width=c.entry_width,
+                                 group=c.num_attention_heads,
+                                 layout=c.latent_layout),
+            expert_layers=c.num_expert_layers,
+            kernel_specs=functools.partial(held_experts_kernel_specs, c),
+            unsupported=_UNSUPPORTED)

@@ -36,29 +36,30 @@ the real tokens and advances a row's state only by them: the state it hands
 back is the state as of the row's last valid token (a row with none keeps
 what it had), and a row at position 0 starts from zeros whatever the state
 holds — a slot is reused without a reset.  The engine's side of this is
-``slot_state`` / ``init_serving_cache`` below.
+``serving_traits``' ``slot_state`` / ``init_serving_cache`` below.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
-from ..distributed.moe import HeldExpertsMoE, SigmoidTopKGate
+from ..distributed.fleet.mp_layers import vocab_parallel_lookup
+from ..distributed.moe import (HeldExpertsMoE, SigmoidTopKGate,
+                               held_experts_kernel_specs)
 from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
-from .afmoe import held_experts_kernel_specs
-from .llama import LlamaMLP, paged_kv_write, part_site
-from .parts import (DecodePart, head_tokens, join_tokens, join_valid,
-                    part_by_part, slot_rows, slot_rows_back, split_tokens)
+from .llama import LlamaMLP
+from .parts import (CausalLMDecode, ServingTraits, join_valid, kv_attention,
+                    part_by_part, slot_rows, slot_rows_back)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2ShortConv", "Lfm2Attention",
            "Lfm2MoeForCausalLM", "tiny_lfm2_config"]
@@ -281,52 +282,14 @@ class Lfm2Attention(Layer):
             return matmul(out.reshape(*x.shape[:2], -1), self.out_proj)
 
     def decode(self, x, rope_cache, parts, cache, idx: int):
-        """Decode over the attention layers' stacked cache, as
-        ``AfmoeAttention.decode``: the projections once over the tokens
-        of all ``parts``; each part's RoPE, write and read at its own
-        positions — with ``block_tables`` through the paged pool (per-row
-        ``pos``), without them over the contiguous cache at a scalar
-        ``pos`` (``generate()``).  Returns (out, cache)."""
+        """Decode over the attention layers' stacked cache
+        (:func:`~paddle_tpu.models.parts.kv_attention`).  Returns (out,
+        cache)."""
         with jax.named_scope("attn.global"):
-            sites = [self._site(p, rope_cache) for p in parts]
-            out, cache = part_by_part(
-                parts, self._proj(x), cache,
-                lambda i, p, cache, q, k, v: self._attend(
-                    q, k, v, rope_cache, p, sites[i], cache, idx))
+            out, cache = kv_attention("Lfm2Attention", x, self._proj, parts,
+                                      rope_cache, cache, idx)
             return matmul(out.reshape(*out.shape[:2], -1),
                           self.out_proj), cache
-
-    @staticmethod
-    def _site(part, rope_cache):
-        if part.block_tables is None and getattr(part.pos, "ndim", 0) != 0:
-            raise NotImplementedError(
-                "Lfm2Attention.decode: per-row positions need the "
-                "paged pool (block_tables); the contiguous cache is "
-                "decoded at one scalar position")
-        return part_site(part, rope_cache)
-
-    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
-        """One part's RoPE, write and read against KV layer ``idx``."""
-        from ..ops.attention import (cached_decode_attention,
-                                     paged_decode_attention)
-        pos, position_ids, rope_ids = site
-        s = q.shape[1]
-        q, k = fused_rope(q, k, *rope_cache, rope_ids)
-        if part.block_tables is not None:
-            cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
-                                           part.block_tables)
-            return paged_decode_attention(q, kvp, idx, pos,
-                                          part.block_tables), cache
-        cache = jax.lax.dynamic_update_slice(
-            cache, k.astype(cache.dtype)[None, None],
-            (idx, 0, 0, pos, 0, 0))
-        cache = jax.lax.dynamic_update_slice(
-            cache, v.astype(cache.dtype)[None, None],
-            (idx, 1, 0, pos, 0, 0))
-        if isinstance(pos, int) and pos == 0 and s > 1:
-            return flash_attention(q, k, v, causal=True), cache
-        return cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
-                                       pos), cache
 
 
 class Lfm2MoE(Layer):
@@ -383,10 +346,12 @@ class Lfm2DecoderLayer(Layer):
               if self.kind == FULL else self.conv(y))
         return self._ffn(x + op)
 
-    def decode(self, x, rope_cache, parts, cache):
-        """One layer against the two-leaf state ``cache``: an attention
-        layer writes and reads its layer of ``"attn"``, a convolution layer
-        advances its layer of ``"conv"`` (each part its own rows)."""
+    def decode(self, x, rope_cache, parts, cache, index: int):
+        """One layer (the model's ``index``-th, which nothing here needs)
+        against the two-leaf state ``cache``: an attention layer writes and
+        reads its layer of ``"attn"``, a convolution layer advances its
+        layer of ``"conv"`` (each part its own rows), at the layer's place
+        among its kind."""
         y, i = self.operator_norm(x), self.state_index
         if self.kind == FULL:
             with jax.named_scope("attn"):
@@ -425,26 +390,35 @@ class Lfm2MoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.embedding_norm(x)
 
-    def decode(self, parts, cache):
-        """Cache-carrying decode pass of ``parts``
-        (:mod:`~paddle_tpu.models.parts`) over the two-leaf state.
-        Returns (the normed hidden states the head is taken of, their
-        per-part (rows, positions), cache)."""
-        x = constrain(
-            vocab_parallel_lookup(
-                self.embed_tokens,
-                join_tokens([p.input_ids for p in parts])),
-            ("dp", "sharding"), None, None)
-        rope = (self.rope_cos, self.rope_sin)
-        for block in self.layers:
-            x, cache = block.decode(x, rope, parts, cache)
-        x, shapes = head_tokens(x, parts)
-        return self.embedding_norm(x), shapes, cache
+
+# the engine layouts this model cannot run, and why
+# (``models.parts.ServingTraits.unsupported``)
+_UNSUPPORTED = {
+    "contiguous_cache": "its attention layers decode per-row positions over "
+                        "the paged pool only",
+    "wave_prefill": "the prefill program addresses block tables, not the "
+                    "slots whose convolution state a prompt must leave "
+                    "behind",
+    "prefix_cache": "a hit skips the positions whose convolution state the "
+                    "request needs; no state is checkpointed at block "
+                    "boundaries",
+    "preemption": "swap, recompute and the host tier move KV blocks only "
+                  "and would lose a slot's convolution state",
+    "kv_cache_dtype": "the two-leaf cache has no int8 pool",
+    "mesh": "the held-experts layer has no exchange and the convolution "
+            "state no declared sharding",
+    "spec_decode": "a rejected draft would have to roll the convolution "
+                   "state back; only K/V rolls back by position",
+    "int8_weights": "quantize_for_decode knows no stacked expert weights",
+}
 
 
-class Lfm2MoeForCausalLM(Layer):
-    """Causal LM over :class:`Lfm2MoeModel`; the serving engine's contract
-    is ``config`` + ``decode_parts`` + the declarations at the end."""
+class Lfm2MoeForCausalLM(CausalLMDecode, Layer):
+    """Causal LM over :class:`Lfm2MoeModel`, served
+    (:class:`~paddle_tpu.models.parts.CausalLMDecode`) over ``cache =
+    {"attn", "conv"}``: a part's ``slots`` are its rows of ``"conv"``, and
+    ``decode_step`` is the pass over one part that addresses every row of
+    it."""
 
     def __init__(self, config: Lfm2MoeConfig):
         super().__init__()
@@ -464,24 +438,8 @@ class Lfm2MoeForCausalLM(Layer):
     def forward(self, input_ids, position_ids=None):
         return self.logits(self.model(input_ids, position_ids))
 
-    def decode_parts(self, parts, cache):
-        """([logits a part], cache): ONE pass of the weights over the
-        tokens of every part, as ``LlamaForCausalLM.decode_parts``, over
-        ``cache = {"attn", "conv"}``.  A part's ``valid`` marks its real
-        tokens: the routed experts leave padding out and the convolution
-        state advances by the real tokens only; its ``slots`` are its rows
-        of ``"conv"``."""
-        hidden, shapes, cache = self.model.decode(parts, cache)
-        with jax.named_scope("lm_head"):
-            return split_tokens(self.logits(hidden), shapes), cache
-
-    def decode_step(self, input_ids, cache, pos, block_tables=None,
-                    valid=None):
-        """(logits, cache): one cache-carrying decode step: the pass over
-        one part that addresses every row of ``"conv"``."""
-        (logits,), cache = self.decode_parts(
-            [DecodePart(input_ids, pos, block_tables, valid)], cache)
-        return logits, cache
+    def _final_norm(self, x):
+        return self.model.embedding_norm(x)
 
     def _conv_state(self, rows: int):
         c = self.config
@@ -497,16 +455,6 @@ class Lfm2MoeForCausalLM(Layer):
              c.num_key_value_heads, c.head_dim), c.dtype),
             "conv": self._conv_state(batch_size)}
 
-    def generate(self, input_ids, max_new_tokens: int = 32, **kw):
-        from .generation import greedy_generate
-        return greedy_generate(self, input_ids, max_new_tokens, **kw)
-
-    # -- what the serving engine asks a model -------------------------------
-
-    #: the leaves of the serving cache that are fixed-size per slot (slot
-    #: axis 1); every other leaf is the paged pool's (block axis 2)
-    slot_state = ("conv",)
-
     def init_serving_cache(self, num_slots: int, num_blocks: int,
                            block_len: int):
         """The serving engine's cache for ``num_slots`` state rows and a
@@ -519,45 +467,11 @@ class Lfm2MoeForCausalLM(Layer):
             "conv": self._conv_state(num_slots)}
 
     @property
-    def expert_layers(self) -> int:
-        return self.config.num_expert_layers
-
-    def serving_kernel_specs(self, token_rows):
-        return held_experts_kernel_specs(self.config, token_rows)
-
-    def check_serving_layout(self, *, paged, chunked, prefix_cache,
-                             kv_cache_dtype, mesh, spec_decode, int8_weights,
-                             preempt, host_blocks):
-        """Refuse, by name, the engine layouts this model cannot run."""
-        def no(what, why):
-            raise NotImplementedError(
-                f"Lfm2MoeForCausalLM cannot be served with {what}: {why}")
-        if not paged:
-            no("the contiguous cache (paged=False)",
-               "its attention layers decode per-row positions over the "
-               "paged pool only")
-        if not chunked:
-            no("wave prefill (chunked=False)",
-               "the prefill program addresses block tables, not the slots "
-               "whose convolution state a prompt must leave behind")
-        if prefix_cache:
-            no("a prefix cache (prefix_cache=True)",
-               "a hit skips the positions whose convolution state the "
-               "request needs; no state is checkpointed at block boundaries")
-        if preempt != "off" or host_blocks:
-            no(f"preempt={preempt!r} / host_blocks={host_blocks}",
-               "swap, recompute and the host tier move KV blocks only and "
-               "would lose a slot's convolution state")
-        if kv_cache_dtype != "bf16":
-            no(f"kv_cache_dtype={kv_cache_dtype!r}",
-               "the two-leaf cache has no int8 pool")
-        if mesh is not None:
-            no("a mesh", "the held-experts layer has no exchange and the "
-               "convolution state no declared sharding")
-        if spec_decode:
-            no("speculative decoding",
-               "a rejected draft would have to roll the convolution state "
-               "back; only K/V rolls back by position")
-        if int8_weights:
-            no("int8_weights", "quantize_for_decode knows no stacked "
-               "expert weights")
+    def serving_traits(self) -> ServingTraits:
+        c = self.config
+        return ServingTraits(
+            slot_state=("conv",),
+            init_serving_cache=self.init_serving_cache,
+            expert_layers=c.num_expert_layers,
+            kernel_specs=functools.partial(held_experts_kernel_specs, c),
+            unsupported=_UNSUPPORTED)

@@ -38,8 +38,7 @@ from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer
 from ..ops import build_rope_cache, flash_attention, fused_rope
-from .parts import (DecodePart, head_tokens, join_tokens, part_by_part,
-                    split_tokens)
+from .parts import CausalLMDecode, part_by_part, part_site
 
 __all__ = ["LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
            "LlamaModel", "LlamaForCausalLM", "llama3_8b_config",
@@ -239,30 +238,6 @@ def paged_write_site(position_ids, block_tables, bl: int):
         block_tables[rows, jnp.minimum(lb, max_blocks - 1)],
         jnp.int32(0))                      # out-of-table pads -> null block
     return phys, position_ids % bl
-
-
-def part_site(part, rope_cache):
-    """Where one part's tokens sit: (pos — per row over the paged pool —,
-    (B, s) position ids, the ids RoPE rotates by).  Shared by every
-    attention layer that decodes over the stacked caches."""
-    b, s = part.input_ids.shape
-    pos = part.pos
-    paged = part.block_tables is not None
-    per_row = getattr(pos, "ndim", 0) == 1
-    if paged and not per_row:
-        pos = jnp.full((b,), pos, jnp.int32)
-        per_row = True
-    if per_row:
-        position_ids = pos[:, None] + jnp.arange(s)[None, :]      # (B, s)
-    else:
-        position_ids = pos + jnp.arange(s)[None, :]
-    if paged:
-        # prompt-pad positions may run past the RoPE table; clamp for
-        # the rotation only (pad rows' outputs are never consumed)
-        rope_ids = jnp.minimum(position_ids, rope_cache[0].shape[0] - 1)
-    else:
-        rope_ids = position_ids
-    return pos, position_ids, rope_ids
 
 
 def _layer_slots(leaf, idx: int, slots):
@@ -533,6 +508,14 @@ class LlamaMLP(Layer):
                       self.down_proj)
 
 
+def swiglu_mlp(config, width: int) -> LlamaMLP:
+    """SwiGLU MLP of a given width — an expert model's dense layers' and
+    its shared expert's: :class:`LlamaMLP`, which reads ``hidden_size``,
+    ``intermediate_size``, ``initializer_range`` and ``dtype`` of whatever
+    config it is given."""
+    return LlamaMLP(dataclasses.replace(config, intermediate_size=width))
+
+
 class LlamaDecoderLayer(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -602,29 +585,6 @@ class LlamaModel(Layer):
                 x = block(x, rope, position_ids, segment_ids)
         return self.norm(x)
 
-    def decode(self, parts, cache):
-        """Cache-carrying decode pass over ``parts``
-        (:mod:`~paddle_tpu.models.parts`).  ``cache``: the stacked
-        (L, 2, B, max_len, Hkv, D) array from
-        :func:`paddle_tpu.models.generation.init_kv_cache` — or, for parts
-        with ``block_tables``, the pooled paged cache from
-        :func:`paddle_tpu.serving.kv_cache.init_paged_kv_cache`; a part's
-        ``pos`` is the number of tokens already in the cache.  Returns
-        (the normed hidden states the head is taken of, their per-part
-        (rows, positions), cache)."""
-        x = vocab_parallel_lookup(
-            self.embed_tokens, join_tokens([p.input_ids for p in parts]))
-        # constrain the gathered activations (batch over dp×sharding) so
-        # the SPMD partitioner shards the lookup output instead of falling
-        # back to rematerialising the full embedding table per device
-        # (the gather-on-sharded-dim cliff recorded in MULTICHIP_r02)
-        x = constrain(x, ("dp", "sharding"), None, None)
-        rope = (self.rope_cos, self.rope_sin)
-        for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, parts, cache, i)
-        x, shapes = head_tokens(x, parts)
-        return self.norm(x), shapes, cache
-
 
 def mask_boundary_labels(labels, segment_ids):
     """Drop labels at packed-document boundaries: the position whose next
@@ -649,8 +609,14 @@ def causal_lm_loss(logits, labels):
     return jnp.sum(loss * valid) / jnp.maximum(jnp.sum(valid), 1.0)
 
 
-class LlamaForCausalLM(Layer):
-    """Causal LM head + loss (the train-step entry the benchmarks drive)."""
+class LlamaForCausalLM(CausalLMDecode, Layer):
+    """Causal LM head + loss (the train-step entry the benchmarks drive);
+    ``decode_parts`` / ``decode_step`` / ``generate`` are
+    :class:`~paddle_tpu.models.parts.CausalLMDecode`'s, over the stacked
+    (L, 2, B, max_len, Hkv, D) cache of
+    :func:`paddle_tpu.models.generation.init_kv_cache` or, for parts with
+    ``block_tables``, the pooled paged cache of
+    :func:`paddle_tpu.serving.kv_cache.init_paged_kv_cache`."""
 
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -679,32 +645,6 @@ class LlamaForCausalLM(Layer):
             labels = mask_boundary_labels(labels, segment_ids)
         return causal_lm_loss(
             self.forward(input_ids, position_ids, segment_ids), labels)
-
-    def decode_parts(self, parts, cache):
-        """([logits a part], cache): ONE pass of the weights over the
-        tokens of every :class:`~paddle_tpu.models.parts.DecodePart`, each
-        addressing its own piece of ``cache``; a part's logits are
-        (rows, positions, vocab), or (rows, 1, vocab) at its ``last``."""
-        hidden, shapes, cache = self.model.decode(parts, cache)
-        with jax.named_scope("lm_head"):
-            return split_tokens(self.logits(hidden), shapes), cache
-
-    def decode_step(self, input_ids, cache, pos, block_tables=None):
-        """(logits, cache): one cache-carrying decode step (prefill when
-        ``input_ids`` is the whole prompt at pos=0, incremental when it is
-        the last token): the pass over one part.  See models/generation.py
-        for the cache layout, serving/kv_cache.py for the paged layout
-        ``block_tables`` selects."""
-        (logits,), cache = self.decode_parts(
-            [DecodePart(input_ids, pos, block_tables)], cache)
-        return logits, cache
-
-    def generate(self, input_ids, max_new_tokens: int = 32, **kw):
-        """Greedy/sampled generation with the pre-allocated KV cache
-        (parity: PaddleNLP ``model.generate``; see
-        :func:`paddle_tpu.models.generation.greedy_generate`)."""
-        from .generation import greedy_generate
-        return greedy_generate(self, input_ids, max_new_tokens, **kw)
 
 
 def draft_model_from(model, params=None, num_layers: int = 1):

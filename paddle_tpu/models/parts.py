@@ -27,19 +27,25 @@ With one part nothing is reshaped: the activations keep the part's own
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Callable, ContextManager, NamedTuple, Optional, Tuple
+import dataclasses
+from typing import (Any, Callable, ContextManager, Mapping, NamedTuple,
+                    Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["DecodePart", "PoolEntry", "join_tokens", "split_tokens",
-           "join_valid", "part_by_part", "head_tokens", "slot_rows",
-           "slot_rows_back"]
+from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
+from ..ops import flash_attention, fused_rope
+
+__all__ = ["DecodePart", "PoolEntry", "ServingTraits", "CausalLMDecode",
+           "join_tokens", "split_tokens", "join_valid", "part_by_part",
+           "head_tokens", "slot_rows", "slot_rows_back", "part_site",
+           "kv_attention", "band_mask"]
 
 
 class PoolEntry(NamedTuple):
-    """What a model declares (``kv_pool_entry``) whose paged pool holds
-    something else a position than K and V rows of
+    """What a model declares (``ServingTraits.pool_entry``) whose paged pool
+    holds something else a position than K and V rows of
     ``num_key_value_heads · head_dim``: the serving engine builds the pool,
     its bytes a block, the cost model's bytes a token and the block-walk
     counts of its spans from this, once, at construction."""
@@ -54,6 +60,58 @@ class PoolEntry(NamedTuple):
     #                             (``ops.pallas.decode_attention
     #                             .LatentLayout``), whose tiles and copy
     #                             groups the counts follow
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingTraits:
+    """THE CONTRACT between a model and ``serving.ServingEngine``, told here
+    and nowhere else.
+
+    Every served model has ``config``, ``state_dict`` and
+    ``decode_parts(parts, cache)``: ONE pass of its weights over the tokens
+    of every :class:`DecodePart` (module docstring;
+    :class:`CausalLMDecode` is the one copy of it).  Whatever else the
+    engine has to know it reads, once, at construction, from the model's
+    ONE attribute ``serving_traits``: this record.  A model without the
+    attribute has the defaults, which are llama's — K and V rows in every
+    layout, nothing refused.  A model that keeps a decode state of its own
+    (``init_decode_state``) and declares no ``slot_state`` is refused at
+    construction, by name."""
+
+    slot_state: Tuple[str, ...] = ()
+    #   the leaves of the serving cache (a dict) that are fixed-size per
+    #   slot (slot axis 1); the one other leaf is the paged pool's
+    init_serving_cache: Optional[Callable[[int, int, int], Any]] = None
+    #   (state rows, pool blocks, block length) -> that dict; a model with
+    #   ``slot_state`` makes its whole serving cache itself
+    pool_entry: Optional["PoolEntry"] = None
+    #   what the paged pool holds a position where that is not K and V rows
+    #   of ``num_key_value_heads · head_dim``: the pool's second axis and
+    #   width, its bytes, the pre-flight and the spans' walk counts follow
+    #   it, and under a prefix cache a layout that can walk a shared prefix
+    #   once is told which rows share one (``DecodePart.shared``)
+    block_diffusion: Any = None
+    #   ``models.generation.BlockDiffusion``: generation by diffusion over
+    #   blocks.  The engine's rows part is then a block of ``length``
+    #   positions a row, its epilogue the unmasking rule, its chunk part
+    #   stops at the prompt's last whole block
+    expert_layers: int = 0
+    #   routed-expert layers: the step programs hand the parts their real
+    #   tokens (``valid``) and return the layers' load beside the sampled
+    #   tokens (``distributed.moe.expert_load``)
+    attention_windows: Tuple[Optional[int], ...] = ()
+    #   per K/V layer, the sliding window its attention reads; None: the
+    #   whole prefix.  Empty: every layer reads the whole prefix
+    kernel_specs: Optional[Callable[[Any], list]] = None
+    #   (token rows a pass) -> the pre-flight specs of the kernels only
+    #   this model's step programs build
+    unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    #   the engine layouts this model cannot run: layout -> the model's
+    #   reason.  The engine words the refusal and asks in this mapping's
+    #   order, the first that applies wins.  The keys: "contiguous_cache",
+    #   "wave_prefill", "prefix_cache", "preemption" (``preempt`` or a host
+    #   tier), "kv_cache_dtype" (any but bf16), "mesh", "spec_decode",
+    #   "int8_weights"; any other is an error at the engine's construction
 
 
 class DecodePart(NamedTuple):
@@ -156,3 +214,152 @@ def slot_rows_back(leaf, rows, slots, axis: int):
         return rows
     return jax.lax.dynamic_update_slice_in_dim(leaf, rows, slots[0],
                                                axis=axis)
+
+
+def part_site(part, rope_cache):
+    """Where one part's tokens sit: (pos — per row over the paged pool —,
+    (B, s) position ids, the ids RoPE rotates by).  Shared by every
+    attention layer that decodes over the stacked caches."""
+    b, s = part.input_ids.shape
+    pos = part.pos
+    paged = part.block_tables is not None
+    per_row = getattr(pos, "ndim", 0) == 1
+    if paged and not per_row:
+        pos = jnp.full((b,), pos, jnp.int32)
+        per_row = True
+    if per_row:
+        position_ids = pos[:, None] + jnp.arange(s)[None, :]      # (B, s)
+    else:
+        position_ids = pos + jnp.arange(s)[None, :]
+    if paged:
+        # prompt-pad positions may run past the RoPE table; clamp for
+        # the rotation only (pad rows' outputs are never consumed)
+        rope_ids = jnp.minimum(position_ids, rope_cache[0].shape[0] - 1)
+    else:
+        rope_ids = position_ids
+    return pos, position_ids, rope_ids
+
+
+def band_mask(s: int, window: int):
+    """(1, 1, s, s) bool: key j inside query i's sliding window."""
+    i = jnp.arange(s)
+    return (i[:, None] - i[None, :] < window)[None, None]
+
+
+def kv_attention(who: str, x, project, parts, rope_cache, cache, idx: int, *,
+                 rope=None, window: Optional[int] = None, block: int = 1):
+    """Attention of the tokens ``x`` of all ``parts`` over a stacked cache
+    of plain K and V rows, for layer ``who`` (named in a refusal): the
+    projections (``project(x) -> q, k, v`` split into heads, normed as the
+    model norms them) once over all tokens; each part's RoPE
+    (``rope(q, k, rope_cache, ids)``; None: rotate-half), K/V write and
+    read at its own positions — with ``block_tables`` through the paged
+    pool (per-row ``pos``), without them over the contiguous cache at one
+    scalar ``pos`` (``generate()``).  ``window``: the sliding window the
+    read is restricted to; ``block`` > 1: the block-causal mask, over the
+    paged pool alone.  Returns (attention (rows, positions, heads, D) as
+    :func:`join_tokens` lays it, cache).
+
+    llama's attention is not this: it alone carries the int8 pool, per-row
+    contiguous writes and the mesh constraints."""
+    from ..ops.attention import (cached_decode_attention,
+                                 paged_decode_attention)
+    from .llama import paged_kv_write
+    if isinstance(cache, dict):
+        raise NotImplementedError(
+            f"{who}.decode: the int8 KV cache is not supported")
+    for p in parts:
+        if p.block_tables is not None:
+            continue
+        if block > 1:
+            raise NotImplementedError(
+                f"{who}.decode: the block-causal read runs over the paged "
+                f"pool (block_tables) only")
+        if getattr(p.pos, "ndim", 0) != 0:
+            raise NotImplementedError(
+                f"{who}.decode: per-row positions need the paged pool "
+                f"(block_tables); the contiguous cache is decoded at one "
+                f"scalar position")
+    sites = [part_site(p, rope_cache) for p in parts]
+
+    def attend(i, part, cache, q, k, v):
+        # the part's K/V land before the read (a block sees itself whole)
+        pos, position_ids, rope_ids = sites[i]
+        s = q.shape[1]
+        q, k = (fused_rope(q, k, *rope_cache, rope_ids) if rope is None
+                else rope(q, k, rope_cache, rope_ids))
+        if part.block_tables is not None:
+            cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
+                                           part.block_tables)
+            return paged_decode_attention(
+                q, kvp, idx, pos, part.block_tables, window=window,
+                block=block), cache
+        cache = jax.lax.dynamic_update_slice(
+            cache, k.astype(cache.dtype)[None, None],
+            (idx, 0, 0, pos, 0, 0))
+        cache = jax.lax.dynamic_update_slice(
+            cache, v.astype(cache.dtype)[None, None],
+            (idx, 1, 0, pos, 0, 0))
+        if isinstance(pos, int) and pos == 0 and s > 1:
+            mask = None if window is None else band_mask(s, window)
+            return flash_attention(q, k, v, causal=True,
+                                   attn_mask=mask), cache
+        return cached_decode_attention(q, cache[idx, 0], cache[idx, 1],
+                                       pos, window=window), cache
+    return part_by_part(parts, project(x), cache, attend)
+
+
+class CausalLMDecode:
+    """The served half of a causal LM, mixed into a ``Layer`` that has
+    ``logits(hidden)`` and a ``model`` with ``embed_tokens``, ``layers``,
+    the RoPE buffers and a final ``norm``; a model that embeds or norms
+    otherwise overrides ``_embed`` / ``_final_norm``."""
+
+    def _embed(self, input_ids):
+        return vocab_parallel_lookup(self.model.embed_tokens, input_ids)
+
+    def _final_norm(self, x):
+        return self.model.norm(x)
+
+    def decode_parts(self, parts, cache):
+        """([logits a part], cache): ONE pass of the weights over the
+        tokens of every :class:`DecodePart`, each addressing its own piece
+        of ``cache`` (whatever the layers' ``decode(x, rope_cache, parts,
+        cache, index)`` address: a stacked contiguous cache, a paged pool,
+        a dict of leaves); a part's logits are (rows, positions, vocab), or
+        (rows, 1, vocab) at its ``last``.  A part's ``valid`` marks its
+        real tokens: routed experts leave padding out and a per-slot state
+        advances by the real tokens only."""
+        m = self.model
+        # constrain the gathered activations (batch over dp×sharding) so
+        # the SPMD partitioner shards the lookup output instead of falling
+        # back to rematerialising the full embedding table per device (the
+        # gather-on-sharded-dim cliff recorded in MULTICHIP_r02)
+        x = constrain(
+            self._embed(join_tokens([p.input_ids for p in parts])),
+            ("dp", "sharding"), None, None)
+        rope = (m.rope_cos, m.rope_sin)
+        for i, block in enumerate(m.layers):
+            x, cache = block.decode(x, rope, parts, cache, i)
+        x, shapes = head_tokens(x, parts)
+        hidden = self._final_norm(x)
+        with jax.named_scope("lm_head"):
+            return split_tokens(self.logits(hidden), shapes), cache
+
+    def decode_step(self, input_ids, cache, pos, block_tables=None,
+                    valid=None):
+        """(logits, cache): one cache-carrying decode step (prefill when
+        ``input_ids`` is the whole prompt at pos=0, incremental when it is
+        the last token): the pass over one part.  See models/generation.py
+        for the cache layout, serving/kv_cache.py for the paged layout
+        ``block_tables`` selects."""
+        (logits,), cache = self.decode_parts(
+            [DecodePart(input_ids, pos, block_tables, valid)], cache)
+        return logits, cache
+
+    def generate(self, input_ids, max_new_tokens: int = 32, **kw):
+        """Greedy/sampled generation with the pre-allocated KV cache
+        (parity: PaddleNLP ``model.generate``; see
+        :func:`paddle_tpu.models.generation.greedy_generate`)."""
+        from .generation import greedy_generate
+        return greedy_generate(self, input_ids, max_new_tokens, **kw)

@@ -32,36 +32,36 @@ block's ``B`` positions against the committed K/V of everything before it
 and the block's own K/V and unmasks some of the still-masked positions by
 confidence; a block that goes in mask-free is committed — the K/V that
 forward writes are the ones later blocks read.  The serving engine drives
-it (``ServingEngine``, "Block diffusion"): this model declares
-``block_diffusion`` and the engine composes a block rows part for it.
+it (``ServingEngine``, "Block diffusion"): this model's ``serving_traits``
+declare ``block_diffusion`` and the engine composes a block rows part for
+it.
 
 The cache row is plain K and V of ``num_key_value_heads · head_dim``, so
-the model decodes over the paged pool llama uses, written through llama's
-:func:`~paddle_tpu.models.llama.paged_kv_write`; the cached-attention ops
-take the mask's block length (``block=``).
+the model decodes over the paged pool llama uses
+(:func:`~paddle_tpu.models.parts.kv_attention`, which takes the mask's
+block length as ``block=``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.fleet.mp_layers import constrain, vocab_parallel_lookup
-from ..distributed.moe import HeldExpertsMoE, SoftmaxTopKGate
+from ..distributed.fleet.mp_layers import vocab_parallel_lookup
+from ..distributed.moe import (HeldExpertsMoE, SoftmaxTopKGate,
+                               held_experts_kernel_specs)
 from ..nn import initializer as I
 from ..nn.common import RMSNorm
 from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
-from .afmoe import held_experts_kernel_specs
 from .generation import BlockDiffusion
-from .llama import paged_kv_write, part_site
-from .parts import (DecodePart, head_tokens, join_tokens, join_valid,
-                    part_by_part, split_tokens)
+from .parts import CausalLMDecode, ServingTraits, join_valid, kv_attention
 
 __all__ = ["SdarMoeConfig", "SdarMoeForCausalLM", "tiny_sdar_config",
            "block_causal_mask"]
@@ -203,38 +203,14 @@ class SdarAttention(Layer):
             return self._out(flash_attention(q, k, v, attn_mask=mask))
 
     def decode(self, x, rope_cache, parts, cache, idx: int):
-        """Decode over the paged pool, as ``AfmoeAttention.decode``: the
-        projections and the output projection once over the tokens of all
-        ``parts``; each part's RoPE, K/V write and read at its own
-        positions through its block table, under the block mask.  Returns
-        (out, cache)."""
-        if isinstance(cache, dict):
-            raise NotImplementedError(
-                "SdarAttention.decode: the int8 KV cache is not supported")
+        """Decode over the paged pool
+        (:func:`~paddle_tpu.models.parts.kv_attention`) under the block
+        mask.  Returns (out, cache)."""
         with jax.named_scope("attn.block"):
-            for p in parts:
-                if p.block_tables is None:
-                    raise NotImplementedError(
-                        "SdarAttention.decode: the block-causal read runs "
-                        "over the paged pool (block_tables) only")
-            sites = [part_site(p, rope_cache) for p in parts]
-            out, cache = part_by_part(
-                parts, self._proj(x), cache,
-                lambda i, p, cache, q, k, v: self._attend(
-                    q, k, v, rope_cache, p, sites[i], cache, idx))
+            out, cache = kv_attention(
+                "SdarAttention", x, self._proj, parts, rope_cache, cache,
+                idx, block=self.config.block_length)
             return self._out(out), cache
-
-    def _attend(self, q, k, v, rope_cache, part, site, cache, idx: int):
-        """One part's RoPE, write and read against layer ``idx``: the
-        part's K/V land before the read (a block sees itself whole)."""
-        from ..ops.attention import paged_decode_attention
-        pos, position_ids, rope_ids = site
-        q, k = fused_rope(q, k, *rope_cache, rope_ids)
-        cache, kvp, _ = paged_kv_write(cache, idx, k, v, position_ids,
-                                       part.block_tables)
-        return paged_decode_attention(
-            q, kvp, idx, pos, part.block_tables,
-            block=self.config.block_length), cache
 
 
 class SdarMoE(Layer):
@@ -308,27 +284,31 @@ class SdarMoeModel(Layer):
             x = block(x, rope, position_ids)
         return self.norm(x)
 
-    def decode(self, parts, cache):
-        """Cache-carrying decode pass of ``parts``
-        (:mod:`~paddle_tpu.models.parts`) over the paged pool.  Returns
-        (the normed hidden states the head is taken of, their per-part
-        (rows, positions), cache)."""
-        x = constrain(
-            vocab_parallel_lookup(
-                self.embed_tokens,
-                join_tokens([p.input_ids for p in parts])),
-            ("dp", "sharding"), None, None)
-        rope = (self.rope_cos, self.rope_sin)
-        for i, block in enumerate(self.layers):
-            x, cache = block.decode(x, rope, parts, cache, i)
-        x, shapes = head_tokens(x, parts)
-        return self.norm(x), shapes, cache
+
+# the engine layouts this model cannot run, and why
+# (``models.parts.ServingTraits.unsupported``)
+_UNSUPPORTED = {
+    "contiguous_cache":
+        "its decode takes per-row positions over the paged pool only",
+    "wave_prefill": "the prefill program masks causally and samples a first "
+                    "token; a prompt here commits whole blocks under the "
+                    "block mask and yields none",
+    "prefix_cache": "no test shows a hit exact under the block mask",
+    "preemption": "a victim mid-block would have to carry its block and the "
+                  "forwards it has had; swap and recompute move K/V only",
+    "kv_cache_dtype": "the block-masked attention path has no int8 pool",
+    "mesh": "the held-experts layer has no exchange and the grouped "
+            "product no sharded form",
+    "spec_decode": "a row's tick is already a block of positions; drafts "
+                   "have no place in it",
+    "int8_weights": "quantize_for_decode knows no stacked expert weights",
+}
 
 
-class SdarMoeForCausalLM(Layer):
-    """Block-diffusion LM over :class:`SdarMoeModel`; the serving engine's
-    contract is ``config`` + ``decode_parts`` over the paged pool +
-    ``block_diffusion``."""
+class SdarMoeForCausalLM(CausalLMDecode, Layer):
+    """Block-diffusion LM over :class:`SdarMoeModel`, served
+    (:class:`~paddle_tpu.models.parts.CausalLMDecode`) over the paged
+    pool."""
 
     def __init__(self, config: SdarMoeConfig):
         super().__init__()
@@ -347,70 +327,11 @@ class SdarMoeForCausalLM(Layer):
         mask: position i's logits predict the token AT i."""
         return self.logits(self.model(input_ids, position_ids))
 
-    def decode_parts(self, parts, cache):
-        """([logits a part], cache): ONE pass of the weights over the
-        tokens of every part, as ``AfmoeForCausalLM.decode_parts``.  A
-        part's ``valid`` marks its real tokens; the routed experts leave
-        padding out."""
-        hidden, shapes, cache = self.model.decode(parts, cache)
-        with jax.named_scope("lm_head"):
-            return split_tokens(self.logits(hidden), shapes), cache
-
-    def decode_step(self, input_ids, cache, pos, block_tables=None,
-                    valid=None):
-        """(logits, cache): the pass over one part."""
-        (logits,), cache = self.decode_parts(
-            [DecodePart(input_ids, pos, block_tables, valid)], cache)
-        return logits, cache
-
-    # -- what the serving engine asks a model -------------------------------
-
     @property
-    def block_diffusion(self) -> BlockDiffusion:
-        """Generation by diffusion over blocks: the engine's rows part is a
-        block of ``length`` positions a row, its epilogue the unmasking
-        rule, its chunk part stops at the prompt's last whole block."""
-        return self.config.block_diffusion
-
-    @property
-    def expert_layers(self) -> int:
-        return self.config.num_hidden_layers
-
-    def serving_kernel_specs(self, token_rows):
-        return held_experts_kernel_specs(self.config, token_rows)
-
-    def check_serving_layout(self, *, paged, chunked, prefix_cache,
-                             kv_cache_dtype, mesh, spec_decode, int8_weights,
-                             preempt, host_blocks):
-        """Refuse, by name, the engine layouts this model cannot run."""
-        def no(what, why):
-            raise NotImplementedError(
-                f"SdarMoeForCausalLM cannot be served with {what}: {why}")
-        if not paged:
-            no("the contiguous cache (paged=False)",
-               "its decode takes per-row positions over the paged pool only")
-        if not chunked:
-            no("wave prefill (chunked=False)",
-               "the prefill program masks causally and samples a first "
-               "token; a prompt here commits whole blocks under the block "
-               "mask and yields none")
-        if prefix_cache:
-            no("a prefix cache (prefix_cache=True)",
-               "no test shows a hit exact under the block mask")
-        if preempt != "off" or host_blocks:
-            no(f"preempt={preempt!r} / host_blocks={host_blocks}",
-               "a victim mid-block would have to carry its block and the "
-               "forwards it has had; swap and recompute move K/V only")
-        if kv_cache_dtype != "bf16":
-            no(f"kv_cache_dtype={kv_cache_dtype!r}",
-               "the block-masked attention path has no int8 pool")
-        if mesh is not None:
-            no("a mesh", "the held-experts layer has no exchange and the "
-               "grouped product no sharded form")
-        if spec_decode:
-            no("speculative decoding",
-               "a row's tick is already a block of positions; drafts have "
-               "no place in it")
-        if int8_weights:
-            no("int8_weights", "quantize_for_decode knows no stacked "
-               "expert weights")
+    def serving_traits(self) -> ServingTraits:
+        c = self.config
+        return ServingTraits(
+            block_diffusion=c.block_diffusion,
+            expert_layers=c.num_hidden_layers,
+            kernel_specs=functools.partial(held_experts_kernel_specs, c),
+            unsupported=_UNSUPPORTED)

@@ -56,8 +56,8 @@ token-identical to ``greedy_generate`` (tests/test_serving.py asserts
 this across admission orders).  ``generate()`` remains the right tool for
 offline parity/eval batches; the engine is the right tool for traffic.
 
-**Paged mode** (``paged=True`` / FLAGS_serving_paged_kv): the per-slot
-cache rows are replaced by the kv_cache.py block pool — one
+**Paged mode** (``paged=True``): the per-slot cache rows are replaced by
+the kv_cache.py block pool — one
 ``(L, 2, num_blocks, block_len, Hkv·D)`` array plus a host-side
 :class:`~paddle_tpu.serving.kv_cache.BlockManager`.  What changes and
 what doesn't:
@@ -113,7 +113,7 @@ Greedy outputs remain token-identical to the wave engine (and therefore
 to ``greedy_generate``) — tests/test_serving.py staggered traces with a
 long prompt arriving mid-decode assert it for both cache layouts.
 
-**Speculative decoding** (``spec_decode=True`` / FLAGS_serving_spec_decode):
+**Speculative decoding** (``spec_decode=True``):
 at b=1 the decode step already sits AT the bf16 weight-stream floor
 (BENCH_DECODE.json, 1.0–1.07x of bound), so no kernel tuning helps — the
 only lever left is amortising each pass of the weights over MORE than one
@@ -121,8 +121,8 @@ token.  Spec mode does that without a second model:
 
   * a host-side **self-drafter** (drafter.py: prompt-lookup / n-gram
     match over each slot's prompt+generated history, the vLLM ``ngram``
-    speculator scheme) proposes up to ``spec_k`` (FLAGS_serving_spec_k)
-    tokens per greedy slot per tick;
+    speculator scheme) proposes up to ``spec_k`` tokens per greedy slot
+    per tick;
   * ONE once-jitted **verify step** feeds every row its (k+1)-token
     window ``[current, d_1..d_k]`` at its own depth — exactly the
     q-tiled mode the flash-decode kernel grew for chunked prefill, with
@@ -212,7 +212,7 @@ from ..models.generation import (SAMPLE_PATHS, _place_on_mesh,
                                  accept_draft_tokens, decode_mesh_specs,
                                  init_kv_cache, sample_path, sample_tokens,
                                  unmask_block)
-from ..models.parts import DecodePart
+from ..models.parts import DecodePart, ServingTraits
 from ..nn.layer import bind_params
 from ..ops import _dispatch as _disp
 from .drafter import DraftModelDrafter, NgramDrafter
@@ -305,6 +305,12 @@ _ROW_FILLS_CHIP = 256
 # own walks cost at one row's heads a tile (PERF.md section 6, PR 43: the
 # chip's reading) — so fewer rows on a prefix walk it alone, as before.
 _SHARE_FROM = 3
+
+
+# Admission must have blocked this many consecutive ticks before a waiter
+# may preempt a SAME-priority victim (a strictly lower-priority one goes at
+# once): guards against churn under transient pressure.
+_PREEMPT_AFTER = 2
 
 
 class _Operand(NamedTuple):
@@ -517,66 +523,59 @@ class ServingEngine:
     request is finished and returns outputs in arrival order.
     """
 
-    # a block-diffusion model's ``block_diffusion`` and its block length
-    # (0: every row's tick is one token); set once, at construction
-    _diffusion = None
-    _block = 0
     # the bucket from which a prompt is prefilled alone (``_wave_rows``)
     _lone_from = _ROW_FILLS_CHIP
 
     def __init__(self, model, num_slots: int = 8, max_length: int = 1024,
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
                  prefill_batch: int = 4, seed: int = 0,
-                 paged: Optional[bool] = None,
+                 paged: bool = False,
                  block_len: Optional[int] = None,
                  num_blocks: Optional[int] = None,
-                 prefix_cache: Optional[bool] = None,
+                 prefix_cache: bool = True,
                  chunked: Optional[bool] = None,
-                 prefill_chunk: Optional[int] = None,
-                 chunk_policy: Optional[str] = None,
-                 spec_decode: Optional[bool] = None,
-                 spec_k: Optional[int] = None,
+                 prefill_chunk: int = 256,
+                 chunk_policy: str = "prefill",
+                 spec_decode: bool = False,
+                 spec_k: int = 4,
                  kv_cache_dtype: Optional[str] = None,
                  int8_weights: Optional[bool] = None,
                  mesh=None,
-                 preempt: Optional[str] = None,
-                 host_blocks: Optional[int] = None,
-                 drafter=None,
+                 preempt: str = "off",
+                 host_blocks: int = 0,
+                 drafter="ngram",
                  draft_model=None):
         """``prefill_batch``: the most requests a prefill wave admits, and
         the rows a wave of short prompts is padded to; a prompt whose bucket
         is ``_ROW_FILLS_CHIP`` positions or more is prefilled alone, in a
         one-row program, whatever this says.
 
-        ``paged`` (default FLAGS_serving_paged_kv) selects the paged
-        block-pool cache; ``block_len`` (FLAGS_kv_cache_block_len) and
-        ``num_blocks`` (FLAGS_kv_cache_num_blocks; 0 derives the
-        contiguous cache's footprint, num_slots·max_length/block_len,
-        plus the null block) size it; ``prefix_cache``
-        (FLAGS_serving_prefix_cache) toggles prompt-prefix sharing.
+        ``paged`` selects the paged block-pool cache; ``block_len``
+        (FLAGS_kv_cache_block_len) and ``num_blocks``
+        (FLAGS_kv_cache_num_blocks; 0 derives the contiguous cache's
+        footprint, num_slots·max_length/block_len, plus the null block)
+        size it; ``prefix_cache`` toggles prompt-prefix sharing.
 
         ``chunked`` (default FLAGS_serving_chunked_prefill) selects
         chunked-prefill admission: prompts are split into
-        ``prefill_chunk``-token chunks (FLAGS_serving_prefill_chunk)
-        folded into the ONE mixed decode step, so a long prompt never
-        stalls in-flight decodes for a whole-prompt prefill;
-        ``chunk_policy`` (FLAGS_serving_chunk_policy): 'prefill' runs a
+        ``prefill_chunk``-token chunks folded into the ONE mixed decode
+        step, so a long prompt never stalls in-flight decodes for a
+        whole-prompt prefill; ``chunk_policy``: 'prefill' runs a
         pending chunk every tick, 'decode' interleaves chunks with
         chunk-free ticks while decodes are active (TPOT protection at
         half the prompt-ingest rate).
 
-        ``spec_decode`` (default FLAGS_serving_spec_decode) selects
-        speculative decoding: a drafter proposes up to ``spec_k``
-        (FLAGS_serving_spec_k) tokens per slot per tick and one verify
+        ``spec_decode`` selects speculative decoding: a drafter proposes
+        up to ``spec_k`` tokens per slot per tick and one verify
         step commits the longest accepted prefix — greedy outputs
         token-identical to plain decode, sampled rows exact under
         rejection sampling, 1..k+1 tokens per step.  Composes with
         every cache layout and with chunked prefill (the verify window
         replaces the mixed step's decode half).
 
-        ``drafter`` (default FLAGS_serving_spec_drafter) picks the
-        proposer: ``'ngram'`` (host-side prompt lookup), ``'model'``
-        (a draft model sharing the engine — see ``draft_model``), or a
+        ``drafter`` picks the proposer: ``'ngram'`` (host-side prompt
+        lookup), ``'model'`` (a draft model sharing the engine — see
+        ``draft_model``), or a
         :class:`~paddle_tpu.serving.drafter.Drafter` instance.
         ``draft_model``: the draft model for kind ``'model'`` — a
         ``(model, params)`` pair, a bare model (its own state_dict is
@@ -632,8 +631,7 @@ class ServingEngine:
         self.eos_token_id = eos_token_id
         self.pad_token_id = int(pad_token_id)
         self.prefill_batch = int(prefill_batch)
-        self.paged = bool(_flags.flag("serving_paged_kv")
-                          if paged is None else paged)
+        self.paged = bool(paged)
         self.kv_dtype = str(kv_cache_dtype
                             or _flags.flag("serving_kv_cache_dtype"))
         if self.kv_dtype not in ("bf16", "int8", "mixed"):
@@ -651,10 +649,8 @@ class ServingEngine:
         self.quantized = self.kv_dtype == "int8"
         self.chunked = bool(_flags.flag("serving_chunked_prefill")
                             if chunked is None else chunked)
-        self.prefill_chunk = int(prefill_chunk
-                                 or _flags.flag("serving_prefill_chunk"))
-        self._chunk_policy = str(chunk_policy
-                                 or _flags.flag("serving_chunk_policy"))
+        self.prefill_chunk = int(prefill_chunk)
+        self._chunk_policy = str(chunk_policy)
         if self._chunk_policy not in ("prefill", "decode"):
             raise ValueError(
                 f"chunk_policy must be 'prefill' or 'decode', got "
@@ -662,9 +658,8 @@ class ServingEngine:
         if self.chunked and self.prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1, got {self.prefill_chunk}")
-        self.spec = bool(_flags.flag("serving_spec_decode")
-                         if spec_decode is None else spec_decode)
-        self.spec_k = int(spec_k or _flags.flag("serving_spec_k"))
+        self.spec = bool(spec_decode)
+        self.spec_k = int(spec_k)
         if self.spec and self.spec_k < 1:
             raise ValueError(
                 f"spec_k must be >= 1, got {self.spec_k}")
@@ -684,38 +679,9 @@ class ServingEngine:
         self.mesh = self._resolve_mesh(mesh)
         # quantized-decode hooks, exactly as models/generation.py binds
         self._bind = getattr(model, "unwrapped", model)
-        # THE CONTRACT.  Every model: ``decode_parts(parts, cache)``, ONE
-        # pass of its weights over the tokens of every part
-        # (``models.parts.DecodePart``: a run of tokens — ids, positions —
-        # with its own way into the per-request state: a block table or its
-        # slot rows of the cache, the mask of its real tokens, its rows of
-        # a per-slot state, the position its logits are wanted at, the
-        # scope its kernels are named by) — everything token-wise once over
-        # all of them, what addresses the state a part at a time in list
-        # order; ``decode_step`` is the pass over one part.
-        # A model that is not llama also says itself which
-        # leaves of its serving cache are fixed-size per slot beside the
-        # paged pool (``slot_state``: their names; slot axis 1) and makes
-        # the whole cache for N slots (``init_serving_cache``), which of the
-        # engine's layouts it cannot run (``check_serving_layout``), whether
-        # its step programs return a routed-expert load beside the tokens
-        # (``expert_layers``), and which of its layers read a sliding window
-        # only (``attention_windows``), and whether it generates by diffusion
-        # over blocks (``block_diffusion``: the block length and mask token,
-        # by which the rows part becomes a block rows part, and the unmasking
-        # rule's defaults), and what its paged pool holds a position where
-        # that is not K and V rows (``kv_pool_entry``, a
-        # ``models.parts.PoolEntry``: a latent model's one entry that is key
-        # and value at once — the pool's second axis and width, its bytes,
-        # the pre-flight and the spans' walk counts follow it, and under a
-        # prefix cache a layout that can walk a shared prefix once for the
-        # rows on it is told which rows those are: ``_init_shared_walk``).
-        # A model that keeps a decode state of its own and declares none of
-        # it is refused here, by name.
-        self._pool_entry = getattr(self._bind, "kv_pool_entry", None)
-        self._diffusion = getattr(self._bind, "block_diffusion", None)
-        self._block = int(self._diffusion.length) if self._diffusion else 0
-        self._slot_leaves = tuple(getattr(self._bind, "slot_state", ()))
+        # the contract: ``models.parts.ServingTraits``
+        self._bind_traits(getattr(self._bind, "serving_traits",
+                                  ServingTraits()))
         if (hasattr(self._bind, "init_decode_state")
                 and not self._slot_leaves):
             raise NotImplementedError(
@@ -724,26 +690,13 @@ class ServingEngine:
                 f"declare it as serving state — slot_state (the leaves that "
                 f"are fixed-size per slot) and init_serving_cache (the "
                 f"paged pool and those leaves for N slots)")
-        if prefix_cache is None:
-            prefix_cache = bool(_flags.flag("serving_prefix_cache"))
-        check_layout = getattr(self._bind, "check_serving_layout", None)
-        if check_layout is not None:
-            check_layout(paged=self.paged, chunked=self.chunked,
-                         prefix_cache=self.paged and bool(prefix_cache),
-                         kv_cache_dtype=self.kv_dtype, mesh=self.mesh,
-                         spec_decode=self.spec,
-                         int8_weights=self._int8_weights,
-                         preempt=self.preempt, host_blocks=self._host_blocks)
+        self._refuse_unsupported(self.paged and bool(prefix_cache))
         if self._block and (self.prefill_chunk % self._block
                             or self.max_length % self._block):
             raise ValueError(
                 f"prefill_chunk {self.prefill_chunk} and max_length "
                 f"{self.max_length} must be multiples of the model's block "
                 f"of {self._block}: a chunk and a cache hold whole blocks")
-        self._expert_layers = int(getattr(self._bind, "expert_layers", 0))
-        self._windows = tuple(
-            int(w) for w in getattr(self._bind, "attention_windows", ())
-            if w is not None)
         self._init_metrics()
         self._init_scheduler_state()
 
@@ -761,8 +714,8 @@ class ServingEngine:
             if self._slot_leaves:
                 # one row a slot and a null row, which a chunk-free tick's
                 # stub of a chunk part addresses
-                cache = self._bind.init_serving_cache(self.num_slots + 1,
-                                                      nb, bl)
+                cache = self._traits.init_serving_cache(self.num_slots + 1,
+                                                        nb, bl)
                 (pool,) = (v for k, v in cache.items()
                            if k not in self._slot_leaves)
                 self._kv_layers = int(pool.shape[0])
@@ -804,9 +757,7 @@ class ServingEngine:
             self._m_state_bytes.set(float(sum(
                 cache[k].nbytes for k in self._slot_leaves)))
         if self.spec:
-            sel = (self._drafter_arg if self._drafter_arg is not None
-                   else str(_flags.flag("serving_spec_drafter")))
-            self._drafter = self._make_drafter(sel)
+            self._drafter = self._make_drafter(self._drafter_arg)
             self._drafters[getattr(self._drafter, "kind", "custom")] = \
                 self._drafter
         def pool_program(impl, site, n_args):
@@ -982,10 +933,52 @@ class ServingEngine:
         self._perf = (self._build_perf_model()
                       if _flags.flag("perf_model") == "on" else None)
 
-    def _init_preempt(self, preempt: Optional[str],
-                      host_blocks: Optional[int]):
-        self.preempt = str(_flags.flag("serving_preempt")
-                           if preempt is None else preempt)
+    def _bind_traits(self, traits: ServingTraits):
+        """What the model declares (the simulator, which has none: the
+        defaults), under the names the scheduler reads it by."""
+        self._traits = traits
+        self._pool_entry = traits.pool_entry
+        self._diffusion = traits.block_diffusion
+        # the block length (0: every row's tick is one token)
+        self._block = int(self._diffusion.length) if self._diffusion else 0
+        self._slot_leaves = tuple(traits.slot_state)
+        self._expert_layers = int(traits.expert_layers)
+        self._windows = tuple(int(w) for w in traits.attention_windows
+                              if w is not None)
+
+    def _refuse_unsupported(self, prefix_cache: bool):
+        """Refuse, by name, the layout this engine was asked for where the
+        model lists it as ``unsupported``: the first in the model's order."""
+        asked = {
+            "contiguous_cache": (not self.paged,
+                                 "the contiguous cache (paged=False)"),
+            "wave_prefill": (not self.chunked,
+                             "wave prefill (chunked=False)"),
+            "prefix_cache": (prefix_cache,
+                             "a prefix cache (prefix_cache=True)"),
+            "preemption": (self.preempt != "off" or self._host_blocks,
+                           f"preempt={self.preempt!r} / "
+                           f"host_blocks={self._host_blocks}"),
+            "kv_cache_dtype": (self.kv_dtype != "bf16",
+                               f"kv_cache_dtype={self.kv_dtype!r}"),
+            "mesh": (self.mesh is not None, "a mesh"),
+            "spec_decode": (self.spec, "speculative decoding"),
+            "int8_weights": (self._int8_weights, "int8_weights"),
+        }
+        who = type(self._bind).__name__
+        unknown = set(self._traits.unsupported) - set(asked)
+        if unknown:
+            raise ValueError(
+                f"{who}.serving_traits.unsupported names no layout of the "
+                f"engine's: {sorted(unknown)} (known: {sorted(asked)})")
+        for layout, why in self._traits.unsupported.items():
+            refused, what = asked[layout]
+            if refused:
+                raise NotImplementedError(
+                    f"{who} cannot be served with {what}: {why}")
+
+    def _init_preempt(self, preempt: str, host_blocks: int):
+        self.preempt = str(preempt)
         if self.preempt not in ("off", "swap", "recompute"):
             raise ValueError(
                 f"preempt must be off|swap|recompute, got "
@@ -994,13 +987,10 @@ class ServingEngine:
             raise ValueError(
                 "preemption requires the paged cache: victim block free "
                 "and swap/recompute resume are BlockManager operations")
-        self._preempt_after = int(_flags.flag("serving_preempt_after"))
-        hb = int(_flags.flag("serving_host_blocks")
-                 if host_blocks is None else host_blocks)
+        hb = int(host_blocks)
         if self.preempt == "swap" and hb < 1:
             raise ValueError(
-                "preempt='swap' needs a host tier: pass host_blocks "
-                "(or FLAGS_serving_host_blocks) >= 1")
+                "preempt='swap' needs a host tier: pass host_blocks >= 1")
         self._host_blocks = hb if self.paged else 0
 
     def _init_pool(self, block_len, num_blocks, prefix_cache):
@@ -1017,8 +1007,7 @@ class ServingEngine:
                  or self.num_slots * self.max_blocks + 1)
         self.kv = BlockManager(
             nb, bl,
-            prefix_cache=bool(_flags.flag("serving_prefix_cache")
-                              if prefix_cache is None else prefix_cache),
+            prefix_cache=bool(prefix_cache),
             kv_dtype=self.kv_dtype,
             host_blocks=self._host_blocks)
         self._tables = np.zeros((self.num_slots, self.max_blocks), np.int32)
@@ -1282,9 +1271,7 @@ class ServingEngine:
         if not isinstance(sel, str):
             return sel
         if sel == "ngram":
-            return NgramDrafter(
-                self.spec_k,
-                max_ngram=int(_flags.flag("serving_spec_ngram")))
+            return NgramDrafter(self.spec_k)
         if sel == "model":
             src = self._draft_model_arg
             if src is None:
@@ -2347,7 +2334,7 @@ class ServingEngine:
 
           * a strictly-lower-priority victim is preempted immediately;
           * a same-priority victim only after the waiter has been
-            blocked ``FLAGS_serving_preempt_after`` consecutive ticks,
+            blocked ``_PREEMPT_AFTER`` consecutive ticks,
             and never one that was itself already preempted once —
             together these stop two equal-priority requests from
             swapping each other forever.
@@ -2364,7 +2351,7 @@ class ServingEngine:
             if vr.priority < priority:
                 pass                       # strictly lower: immediate
             elif (vr.priority == priority
-                  and blocked_ticks >= self._preempt_after
+                  and blocked_ticks >= _PREEMPT_AFTER
                   and vr.preempt_count == 0):
                 pass                       # FIFO fairness gate passed
             else:
@@ -2763,7 +2750,7 @@ class ServingEngine:
             except NotImplementedError:     # no kernel at this length
                 bk = self.max_length
             cols = self.max_length // bk
-        windows = (getattr(self._bind, "attention_windows", None)
+        windows = (self._traits.attention_windows
                    or (None,) * self._kv_layers)
         if self._pool_entry is not None:
             # the declared entry's walk: every head one query group over
@@ -2852,7 +2839,7 @@ class ServingEngine:
 
     def _kv_walk_shared(self, *calls) -> Dict[str, int]:
         """:meth:`_kv_walk` for a model that declares its pool's entry
-        (``kv_pool_entry``): the same two counts by the layout's own tiles
+        (``pool_entry``): the same two counts by the layout's own tiles
         and groups, and of the ROWS part alone (the first call), a layer:
         ``rows_depth`` the live rows' summed depths (the positions each
         row's query sees), ``rows_blocks`` the blocks their walks read,
@@ -3637,7 +3624,7 @@ class ServingEngine:
         the program each device actually compiles; whole-model heads
         would overstate VMEM by mp×)."""
         from .. import static_analysis as _sa
-        extra = getattr(self._bind, "serving_kernel_specs", None)
+        extra = self._traits.kernel_specs
         if self._pool_entry is not None:
             # the registry's decode-attention spec models K and V rows of
             # Hkv·D; a declared entry's walk has no spec there yet

@@ -57,6 +57,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from .. import flags as _flags
+from ..models.parts import ServingTraits
 from ..observability import costmodel as _cm
 from ..observability import tracing as _obs
 from . import loadgen as _loadgen
@@ -124,9 +125,9 @@ class SimEngine(ServingEngine):
                  max_length: int = 1024, prefill_batch: int = 4,
                  seed: int = 0, block_len: Optional[int] = None,
                  num_blocks: Optional[int] = None,
-                 prefix_cache: Optional[bool] = None,
-                 preempt: Optional[str] = None,
-                 host_blocks: Optional[int] = None,
+                 prefix_cache: bool = True,
+                 preempt: str = "off",
+                 host_blocks: int = 0,
                  eos_token_id: Optional[int] = None,
                  pad_token_id: int = 0,
                  profile: Optional[_cm.HardwareProfile] = None):
@@ -142,8 +143,8 @@ class SimEngine(ServingEngine):
         # no model: no expert layers' load, no window layers' dead
         # positions, no per-slot state, no kernel whose block walk the
         # spans would count
-        self._expert_layers, self._windows, self._slot_leaves = 0, (), ()
-        self._kv_walk_geom = self._pool_entry = None
+        self._bind_traits(ServingTraits())
+        self._kv_walk_geom = None
         # the simulator is paged-only: the BlockManager IS the part of
         # the memory system worth simulating (admission blocking,
         # prefix hits, preemption, the host tier)
@@ -154,15 +155,12 @@ class SimEngine(ServingEngine):
             raise NotImplementedError(
                 "SimEngine does not model chunked prefill (the mixed "
                 "step's chunk cursor is a dispatch-structure feature)")
-        if bool(_flags.flag("serving_spec_decode")):
-            raise NotImplementedError(
-                "SimEngine does not model speculative decoding (accept "
-                "rates depend on real logits)")
+        # (nor speculative decoding: accept rates depend on real logits)
         self.chunked = False
-        self.prefill_chunk = int(_flags.flag("serving_prefill_chunk"))
+        self.prefill_chunk = 256
         self._chunk_policy = "prefill"
         self.spec = False
-        self.spec_k = int(_flags.flag("serving_spec_k"))
+        self.spec_k = 4
         self._init_preempt(preempt, host_blocks)
         self.mesh = None
         self._init_metrics()
@@ -282,7 +280,7 @@ class FleetSim:
 
     def __init__(self, num_replicas: int = 16,
                  spec: Optional[SimSpec] = None, *,
-                 policy: Optional[str] = None, seed: int = 0,
+                 policy: str = "prefix", seed: int = 0,
                  **engine_kwargs: Any):
         self.spec = spec or SimSpec.default()
         self.engines = [SimEngine(self.spec, seed=seed + i,
@@ -410,14 +408,13 @@ def run_fleet(*, requests: int = 100_000, replicas: int = 16,
     restored on exit."""
     saved = {k: _flags.flag(k) for k in
              ("serving_admission", "perf_model", "request_log_max_requests",
-              "serving_chunked_prefill", "serving_spec_decode")}
+              "serving_chunked_prefill")}
     # keep the scale run's memory bounded: the rolling request-log
     # window covers the trace tail, plenty for the structural signature
     _flags.set_flags({
         "serving_admission": admission,
         "perf_model": "on",
         "serving_chunked_prefill": False,
-        "serving_spec_decode": False,
         "request_log_max_requests": min(8192, max(4096, requests // 8))})
     tracer = _obs.get_tracer()
     saved_trace = tracer.enabled

@@ -186,7 +186,7 @@ def init_paged_kv_cache(config, num_blocks: int, block_len: int, dtype=None,
     The pytree threads through the engine's jitted step exactly like the
     plain array (same argnum, donated wholesale).
 
-    ``entry`` (a model's ``kv_pool_entry``, ``models.parts.PoolEntry``):
+    ``entry`` (a model's declared ``models.parts.PoolEntry``):
     the pool holds ``entry.arrays`` arrays a layer of ``entry.width`` lanes
     a position instead — a latent model's ONE entry that is key and value
     at once, ``(L, 1, num_blocks, block_len, width)``.  Blocks, tables, the

@@ -9,7 +9,7 @@ them.  Aggregate tok/s is the sum of per-replica committed tokens
 (BASELINE.md multi-replica accounting), and the placement policy is
 what keeps that sum high:
 
-  * **prefix-affinity** (default, FLAGS_serving_router_policy): paged
+  * **prefix-affinity** (default, ``policy="prefix"``): paged
     replicas expose a READ-ONLY trie probe
     (:meth:`~paddle_tpu.serving.kv_cache.BlockManager.prefix_probe`);
     the router sends a prompt to the replica holding its longest
@@ -95,11 +95,10 @@ class ReplicaRouter:
     instead to route over pre-built, possibly heterogeneous engines.
     """
 
-    def __init__(self, model=None, num_replicas: Optional[int] = None,
+    def __init__(self, model=None, num_replicas: int = 1,
                  *, engines: Optional[List[ServingEngine]] = None,
-                 policy: Optional[str] = None, **engine_kwargs):
-        self.policy = str(policy
-                          or _flags.flag("serving_router_policy"))
+                 policy: str = "prefix", **engine_kwargs):
+        self.policy = str(policy)
         if self.policy not in ("prefix", "least_loaded", "round_robin"):
             raise ValueError(
                 f"policy must be 'prefix', 'least_loaded' or "
@@ -114,8 +113,7 @@ class ReplicaRouter:
         else:
             if model is None:
                 raise ValueError("a model (or engines=[...]) is required")
-            n = int(num_replicas
-                    or _flags.flag("serving_dp_replicas"))
+            n = int(num_replicas)
             if n < 1:
                 raise ValueError(f"num_replicas must be >= 1, got {n}")
             self._factory = lambda: ServingEngine(model, **engine_kwargs)

@@ -147,7 +147,7 @@ def _through_the_pool(model, ids, prompt_len):
     cfg = model.config
     n_blocks = -(-len(ids) // BLOCK)
     cache = init_paged_kv_cache(cfg, 2 * n_blocks + 1, BLOCK,
-                                entry=model.kv_pool_entry)
+                                entry=model.serving_traits.pool_entry)
     table = jnp.asarray([list(range(2 * n_blocks, n_blocks, -1))], jnp.int32)
     out = []
     at = 0
@@ -178,7 +178,7 @@ def test_chunks_then_decode_through_the_pool_match_the_reference(seeded):
     assert np.abs(got - want).max() < LOGIT_TOL
     # the pool: one array a layer, the entry padded to whole lane tiles,
     # zeros in the lanes the entry pads and in blocks no table names
-    e = model.kv_pool_entry
+    e = model.serving_traits.pool_entry
     assert cache.shape[:2] == (3, 1) and cache.shape[-1] == e.width == 256
     values = model.config.entry_values
     assert values == 160 and not np.asarray(cache[..., values:]).any()
