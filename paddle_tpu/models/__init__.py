@@ -7,9 +7,9 @@ parallel stack.
 
 What the serving engine (``serving.ServingEngine``) takes is told once, in
 :class:`~paddle_tpu.models.parts.ServingTraits`: ``llama`` in every layout,
-and ``afmoe`` (Trinity), ``lfm2`` (LFM2-MoE), ``sdar`` (SDAR-MoE) and
-``latent_moe`` (latent attention, MLA) as each one's ``serving_traits``
-declare — what a family refuses, and why, is its ``unsupported``.
+and ``afmoe`` (Trinity), ``lfm2`` (LFM2-MoE), ``sdar`` (SDAR-MoE),
+``latent_moe`` (latent attention, MLA) and ``olmo_hybrid`` (Gated DeltaNet
+layers beside attention) as each one's ``serving_traits`` declare — what a family refuses, and why, is its ``unsupported``.
 """
 
 from .afmoe import AfmoeConfig, AfmoeForCausalLM, tiny_afmoe_config
@@ -18,6 +18,8 @@ from .generation import (DecodeStep, accept_draft_tokens, greedy_generate,
 from .latent_moe import (LatentMoeConfig, LatentMoeForCausalLM,
                          tiny_latent_moe_config)
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM, tiny_lfm2_config
+from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
+                          tiny_olmo_hybrid_config)
 from .sdar import SdarMoeConfig, SdarMoeForCausalLM, tiny_sdar_config
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,
                     causal_lm_loss, draft_model_from, llama3_8b_config,
@@ -32,4 +34,5 @@ __all__ = [
     "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "tiny_lfm2_config",
     "SdarMoeConfig", "SdarMoeForCausalLM", "tiny_sdar_config",
     "LatentMoeConfig", "LatentMoeForCausalLM", "tiny_latent_moe_config",
+    "OlmoHybridConfig", "OlmoHybridForCausalLM", "tiny_olmo_hybrid_config",
 ]

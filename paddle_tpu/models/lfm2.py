@@ -58,8 +58,8 @@ from ..nn.layer import Layer, LayerList
 from ..ops import build_rope_cache, flash_attention, fused_rope
 from ..tensor.math import matmul
 from .llama import LlamaMLP
-from .parts import (CausalLMDecode, ServingTraits, join_valid, kv_attention,
-                    part_by_part, slot_rows, slot_rows_back)
+from .parts import (CausalLMDecode, ServingTraits, carried_window,
+                    join_valid, kv_attention, part_by_part)
 
 __all__ = ["Lfm2MoeConfig", "Lfm2ShortConv", "Lfm2Attention",
            "Lfm2MoeForCausalLM", "tiny_lfm2_config"]
@@ -211,24 +211,10 @@ class Lfm2ShortConv(Layer):
         prefix of each row; None: all) marks the real tokens, a row
         without one keeps its state, and a row at position 0 starts from
         zeros."""
-        keep = self.taps - 1
-
         def filtered(_, p, state, u):
-            b, s, _ = u.shape
-            rows = slot_rows(state, p.slots, 0)
-            fresh = jnp.broadcast_to(jnp.asarray(p.pos) == 0, (b,))
-            prev = jnp.where(fresh[:, None, None], 0, rows).astype(u.dtype)
-            ext = jnp.concatenate([prev, u], axis=1)
-            y = self._filter(ext, s).astype(x.dtype)
-            if p.valid is None:
-                rows = ext[:, s:].astype(state.dtype)
-            else:
-                n = jnp.asarray(p.valid).sum(axis=1, dtype=jnp.int32)  # (B,)
-                last = jax.vmap(lambda e, i: jax.lax.dynamic_slice_in_dim(
-                    e, i, keep, axis=0))(ext, n)
-                rows = jnp.where((n > 0)[:, None, None],
-                                 last.astype(state.dtype), rows)
-            return y, slot_rows_back(state, rows, p.slots, 0)
+            return carried_window(
+                p, state, u,
+                lambda ext, s: self._filter(ext, s).astype(x.dtype))
         with jax.named_scope("conv"):
             c, u = self._gated(x)
             y, state = part_by_part(parts, (u,), state, filtered)

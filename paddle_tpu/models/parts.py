@@ -39,8 +39,8 @@ from ..ops import flash_attention, fused_rope
 
 __all__ = ["DecodePart", "PoolEntry", "ServingTraits", "CausalLMDecode",
            "join_tokens", "split_tokens", "join_valid", "part_by_part",
-           "head_tokens", "slot_rows", "slot_rows_back", "part_site",
-           "kv_attention", "band_mask"]
+           "head_tokens", "slot_rows", "slot_rows_back", "carried_window",
+           "part_site", "kv_attention", "band_mask"]
 
 
 class PoolEntry(NamedTuple):
@@ -216,9 +216,37 @@ def slot_rows_back(leaf, rows, slots, axis: int):
                                                axis=axis)
 
 
+def carried_window(part, state, u, filt):
+    """A causal convolution's carried window, for one part: the part's
+    tokens ``u`` (B, s, C) behind the last ``keep`` inputs of ITS rows of
+    ``state`` (rows, keep, C) — zeros for a row at position 0, whatever
+    the state holds: a slot is reused without a reset — filtered by
+    ``filt(ext (B, keep + s, C), s) -> (B, s, C)``.  Returns (the filtered
+    tokens, ``state`` with the part's rows as of each row's last VALID
+    token): ``part.valid`` marks the real tokens, a prefix of each row, and
+    a row without one keeps its window."""
+    b, s, _ = u.shape
+    keep = state.shape[1]
+    rows = slot_rows(state, part.slots, 0)
+    fresh = jnp.broadcast_to(jnp.asarray(part.pos) == 0, (b,))
+    prev = jnp.where(fresh[:, None, None], 0, rows).astype(u.dtype)
+    ext = jnp.concatenate([prev, u], axis=1)
+    y = filt(ext, s)
+    if part.valid is None:
+        rows = ext[:, s:].astype(state.dtype)
+    else:
+        n = jnp.asarray(part.valid).sum(axis=1, dtype=jnp.int32)     # (B,)
+        last = jax.vmap(lambda e, i: jax.lax.dynamic_slice_in_dim(
+            e, i, keep, axis=0))(ext, n)
+        rows = jnp.where((n > 0)[:, None, None],
+                         last.astype(state.dtype), rows)
+    return y, slot_rows_back(state, rows, part.slots, 0)
+
+
 def part_site(part, rope_cache):
     """Where one part's tokens sit: (pos — per row over the paged pool —,
-    (B, s) position ids, the ids RoPE rotates by).  Shared by every
+    (B, s) position ids, the ids RoPE rotates by; ``rope_cache`` None: a
+    model without rotary embedding, the ids as they are).  Shared by every
     attention layer that decodes over the stacked caches."""
     b, s = part.input_ids.shape
     pos = part.pos
@@ -231,7 +259,7 @@ def part_site(part, rope_cache):
         position_ids = pos[:, None] + jnp.arange(s)[None, :]      # (B, s)
     else:
         position_ids = pos + jnp.arange(s)[None, :]
-    if paged:
+    if paged and rope_cache is not None:
         # prompt-pad positions may run past the RoPE table; clamp for
         # the rotation only (pad rows' outputs are never consumed)
         rope_ids = jnp.minimum(position_ids, rope_cache[0].shape[0] - 1)
@@ -312,11 +340,15 @@ def kv_attention(who: str, x, project, parts, rope_cache, cache, idx: int, *,
 class CausalLMDecode:
     """The served half of a causal LM, mixed into a ``Layer`` that has
     ``logits(hidden)`` and a ``model`` with ``embed_tokens``, ``layers``,
-    the RoPE buffers and a final ``norm``; a model that embeds or norms
-    otherwise overrides ``_embed`` / ``_final_norm``."""
+    the RoPE buffers and a final ``norm``; a model that embeds, norms or
+    encodes position otherwise overrides ``_embed`` / ``_final_norm`` /
+    ``_rope_cache``."""
 
     def _embed(self, input_ids):
         return vocab_parallel_lookup(self.model.embed_tokens, input_ids)
+
+    def _rope_cache(self):
+        return self.model.rope_cos, self.model.rope_sin
 
     def _final_norm(self, x):
         return self.model.norm(x)
@@ -338,7 +370,7 @@ class CausalLMDecode:
         x = constrain(
             self._embed(join_tokens([p.input_ids for p in parts])),
             ("dp", "sharding"), None, None)
-        rope = (m.rope_cos, m.rope_sin)
+        rope = self._rope_cache()
         for i, block in enumerate(m.layers):
             x, cache = block.decode(x, rope, parts, cache, i)
         x, shapes = head_tokens(x, parts)

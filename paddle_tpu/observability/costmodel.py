@@ -186,12 +186,16 @@ class CostModel:
     def __init__(self, profile: HardwareProfile, *,
                  weight_bytes: int, n_params: int,
                  kv_token_bytes: float, num_slots: int,
-                 comm_bytes_fn: Optional[Callable[[], int]] = None) -> None:
+                 comm_bytes_fn: Optional[Callable[[], int]] = None,
+                 state_row_bytes: float = 0.0) -> None:
         self.profile = profile
         self.weight_bytes = int(weight_bytes)
         self.n_params = int(n_params)
         self.kv_token_bytes = float(kv_token_bytes)
         self.num_slots = int(num_slots)
+        # what a decoding row reads AND writes of its fixed-size per-slot
+        # state a tick (a model's ``slot_state`` leaves; 0: it has none)
+        self.state_row_bytes = float(state_row_bytes)
         self._comm_bytes_fn = comm_bytes_fn
         self._comm_bytes: Optional[int] = None
         self._memo: Dict[Tuple[int, int, int, int, int],
@@ -228,6 +232,8 @@ class CostModel:
         # kv_token_bytes (int8 KV shrinks it by the committed ratio).
         weight_ms = self.weight_bytes / p.hbm_bps * 1e3
         kv_ms = key[1] * self.kv_token_bytes / p.hbm_bps * 1e3
+        # fixed-size per-slot state: every occupied row's, whole, both ways
+        state_ms = key[0] * self.state_row_bytes / p.hbm_bps * 1e3
         # compute: dense decode GEMMs run over all num_slots rows
         # (masked, not skipped — static shapes), 2*N FLOPs per token
         # position; the chunk adds its prompt tokens on top.
@@ -238,10 +244,12 @@ class CostModel:
         # serialized against the tick's dispatch (the engine moves them
         # between dispatches), so they bound the tick when they dominate
         swap_ms = int(swap_bytes) / p.host_bps * 1e3
-        hbm_ms = weight_ms + kv_ms
+        hbm_ms = weight_ms + kv_ms + state_ms
         predicted = max(hbm_ms, compute_ms, comm_ms, swap_ms)
         if predicted == hbm_ms:
-            bound = "weight-stream" if weight_ms >= kv_ms else "kv-stream"
+            bound = ("weight-stream" if weight_ms >= max(kv_ms, state_ms)
+                     else "kv-stream" if kv_ms >= state_ms
+                     else "state-stream")
         elif predicted == compute_ms:
             bound = "compute"
         elif predicted == comm_ms:
@@ -249,6 +257,7 @@ class CostModel:
         else:
             bound = "swap"
         out = {"weight_stream_ms": weight_ms, "kv_stream_ms": kv_ms,
+               "state_stream_ms": state_ms,
                "compute_ms": compute_ms, "comm_ms": comm_ms,
                "swap_ms": swap_ms,
                "predicted_ms": predicted, "bound": bound,
@@ -320,6 +329,7 @@ class TickAttribution:
             self._measured_ms = 0.0
             self._bounds: Dict[str, Dict[str, float]] = {}
             self._terms = {"weight_stream_ms": 0.0, "kv_stream_ms": 0.0,
+                           "state_stream_ms": 0.0,
                            "compute_ms": 0.0, "comm_ms": 0.0,
                            "swap_ms": 0.0, "predicted_ms": 0.0}
             self._ratios: List[float] = []

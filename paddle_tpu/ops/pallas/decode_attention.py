@@ -144,6 +144,11 @@ _MAX_Q_LEN = _limits.MAX_Q_LEN  # beyond this: whole-prefill, flash territory
 # Chosen on the chip (PERF.md §6, PR 30); kernel.decode_walk_live_pct is
 # what another choice is judged by.
 GROUP_KEYS = 512
+# The VMEM a group's buffers (K and V, each twice) may take: at Hkv·D =
+# 3840 (30 K/V heads of 128, no GQA; PR 46) a group of 512 keys would be
+# 15.7 MB of the 16 a kernel may use, so a group is halved until it fits —
+# to 256 keys there; every narrower pool keeps its 512
+GROUP_VMEM = 8 * 1024 * 1024
 
 
 def _pick_block_kv(kv_len: int, cap: int) -> int:
@@ -232,9 +237,26 @@ def tile_members(latent: LatentLayout, heads: int) -> int:
     return max(1, latent.q_rows // int(heads))
 
 
-def group_blocks(bk: int, group_keys: int = GROUP_KEYS) -> int:
-    """Blocks of ``bk`` positions one copy group holds (the kernel's G)."""
-    return max(1, int(group_keys) // int(bk))
+def stored_key_bytes(width: int, arrays: int, dtype) -> int:
+    """What one key takes in a pool as stored: ``arrays`` arrays (K and V,
+    or a latent pool's one) of ``width`` elements of ``dtype``.  The ONE
+    reckoning of :func:`group_blocks`' input: the kernel, the engine's
+    walk counts and the pre-flight spec all call it."""
+    return int(arrays) * int(width) * jnp.dtype(dtype).itemsize
+
+
+def group_blocks(bk: int, group_keys: int = GROUP_KEYS,
+                 key_bytes: int = 0) -> int:
+    """Blocks of ``bk`` positions one copy group holds (the kernel's G):
+    ``group_keys`` keys' worth, halved while the group's double buffers —
+    :func:`stored_key_bytes` a key — pass ``GROUP_VMEM``.  Left out,
+    ``key_bytes`` halves nothing: right for a pool of at most 8 KiB a key
+    (every accepted cell's), which is what the accepted benchmark's own
+    test of the walk counts relies on (it may not be edited here)."""
+    gb = max(1, int(group_keys) // int(bk))
+    while gb > 1 and 2 * gb * int(bk) * int(key_bytes) > GROUP_VMEM:
+        gb //= 2
+    return gb
 
 
 def q_tiles(s: int, g: int, max_rows: int = _MAX_Q_ROWS) -> Tuple[int, int]:
@@ -270,7 +292,7 @@ def live_block_range(pos, qi, *, s, bq, bk, n_cols, window=None, first=None,
 def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
                 window: Optional[int] = None,
                 latent: Optional[LatentLayout] = None,
-                first=None) -> Tuple[int, int]:
+                first=None, key_bytes: int = 0) -> Tuple[int, int]:
     """``(kv_blocks, kv_walk)`` of one kernel call on the host, from the
     bounds the kernel itself uses: the blocks its rows' q tiles need
     (``last - first + 1`` each) and the block slots it walks for them
@@ -278,7 +300,8 @@ def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
     positions; ``s``, ``g``: its q length and GQA group size; ``latent``:
     the latent pool's layout, whose tiles and groups are its own;
     ``first``: per row, the column its own walk starts at behind a shared
-    one (:class:`SharedWalk`)."""
+    one (:class:`SharedWalk`); ``key_bytes``: what a key takes over the
+    pool's arrays (:func:`group_blocks`)."""
     max_rows, group_keys = _tiling(latent)
     bq, nq = q_tiles(int(s), int(g), max_rows)
     first, last = live_block_range(
@@ -287,7 +310,7 @@ def walk_counts(pos, s: int, g: int, *, bk: int, n_cols: int,
         first=(None if first is None
                else np.asarray(first, np.int64).reshape(-1, 1)), xp=np)
     need = last - first + 1
-    gb = group_blocks(bk, group_keys)
+    gb = group_blocks(bk, group_keys, key_bytes)
     return int(need.sum()), int((-(-need // gb) * gb).sum())
 
 
@@ -799,9 +822,10 @@ def _flash_call(scalars, q, k_arr, v_arr, *, scale, paged, window, interpret,
     rows = s * g
     max_rows, group_keys = _tiling(latent)
     bq, nq = q_tiles(s, g, max_rows)
-    gb = group_blocks(bk, group_keys)
     dv = latent.value_width if latent else d      # the output's width
     kv = (k_arr,) if latent else (k_arr, v_arr)
+    gb = group_blocks(bk, group_keys,
+                      stored_key_bytes(hd, len(kv), k_arr.dtype))
     tile_p = max(8, -(-(bq * g) // 8) * 8)  # sublane-pad each q tile
     # grouped-GQA q layout: (B, Hkv, s·G, D), row r = si·g + gi — then cut
     # into nq tiles of bq·g rows, each sublane-padded to tile_p, so one

@@ -1107,10 +1107,15 @@ class ServingEngine:
             def comm_fn():
                 comm = self.mesh_preflight()["comm"]
                 return int(comm.get("total_bytes_per_step", 0))
+        # a decoding row reads and writes its row of every slot leaf
+        state_row = 2.0 * sum(
+            self._cache[k].nbytes for k in self._slot_leaves) / (
+                self.num_slots + 1)
         model = _cm.CostModel(
             _cm.resolve_profile(), weight_bytes=weight_bytes,
             n_params=n_params, kv_token_bytes=kv_tok,
-            num_slots=self.num_slots, comm_bytes_fn=comm_fn)
+            num_slots=self.num_slots, comm_bytes_fn=comm_fn,
+            state_row_bytes=state_row)
         return _cm.TickAttribution(model, engine_id=self._eid)
 
     def _perf_tick(self, measured_ms: float, occ: int,
@@ -2768,6 +2773,19 @@ class ServingEngine:
             int(bk), int(cols),
             tuple((w, windows.count(w)) for w in set(windows)))
 
+    @functools.cached_property
+    def _kv_key_bytes(self) -> int:
+        """What a key takes over the pool's arrays, as stored: a wide
+        pool's copy groups are smaller
+        (``ops.pallas.decode_attention.group_blocks``)."""
+        from ..ops.pallas.decode_attention import stored_key_bytes
+        c, entry = self.config, self._pool_entry
+        width, arrays = (
+            (entry.width, entry.arrays) if entry is not None
+            else (int(c.num_key_value_heads) * int(c.head_dim), 2))
+        return stored_key_bytes(
+            width, arrays, "int8" if self.quantized else c.dtype)
+
     def _kv_walk(self, *calls) -> Dict[str, int]:
         """``kv_blocks=`` and ``kv_walk=`` of a tick's or a wave's span:
         over the program's flash-decode calls (``calls``: the positions
@@ -2784,7 +2802,8 @@ class ServingEngine:
         for pos, s in calls:
             for window, n in layers:
                 kb, kw = walk_counts(pos, s, g, bk=bk, n_cols=cols,
-                                     window=window)
+                                     window=window,
+                                     key_bytes=self._kv_key_bytes)
                 blocks += n * kb
                 walk += n * kw
         return {"kv_blocks": blocks, "kv_walk": walk}
@@ -2832,7 +2851,8 @@ class ServingEngine:
         grouped = share.n > 0
         share.at[...] = share.at[np.maximum.accumulate(
             np.where(grouped, np.arange(grouped.size), 0))]
-        gb = group_blocks(self.block_len, self._pool_entry.layout.group_keys)
+        gb = group_blocks(self.block_len, self._pool_entry.layout.group_keys,
+                          self._kv_key_bytes)
         self._share_counts = (
             int(share.tile_n.sum()), int((-(-share.tile_n // gb) * gb).sum()),
             {"rows_grouped": int(grouped.sum()), "shared_tiles": t})
@@ -2860,7 +2880,8 @@ class ServingEngine:
         for i, (pos, s) in enumerate(calls):
             kb, kw = walk_counts(
                 pos, s, g, bk=bk, n_cols=cols, latent=layout,
-                first=share.n if share and not i else None)
+                first=share.n if share and not i else None,
+                key_bytes=self._kv_key_bytes)
             blocks += layers * kb
             walk += layers * kw
         live = np.flatnonzero(self._active)

@@ -66,8 +66,8 @@ from .core import (Finding, GraphLintError, GraphLintWarning,
 from .kernel_registry import (KernelSpec, KernelSpecError,
                               decode_attention_spec, flash_attention_spec,
                               int8_matmul_spec, kv_streamed_bytes,
-                              moe_experts_spec, rms_norm_spec,
-                              registered_kernel_specs,
+                              gated_delta_specs, moe_experts_spec,
+                              rms_norm_spec, registered_kernel_specs,
                               streamed_bytes, vmem_footprint)
 from .kernel_rules import (KernelRule, KernelVmemRule, KernelBoundsRule,
                            KernelAlignRule, KernelScaleGranuleRule,
@@ -93,7 +93,7 @@ __all__ = [
     # kernel pre-flight (ISSUE 14)
     "KernelSpec", "KernelSpecError", "decode_attention_spec",
     "flash_attention_spec", "int8_matmul_spec", "rms_norm_spec",
-    "moe_experts_spec",
+    "moe_experts_spec", "gated_delta_specs",
     "registered_kernel_specs", "vmem_footprint", "streamed_bytes",
     "kv_streamed_bytes",
     "KernelRule", "KernelVmemRule", "KernelBoundsRule",

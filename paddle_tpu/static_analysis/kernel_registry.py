@@ -433,11 +433,12 @@ def decode_attention_spec(b: int, s: int, hq: int, hkv: int, d: int, *,
 
     # the kernel's own tiling arithmetic and walk bounds, imported
     from ..ops.pallas.decode_attention import (group_blocks,
-                                               live_block_range, q_tiles)
+                                               live_block_range, q_tiles,
+                                               stored_key_bytes)
     bq, nq = q_tiles(s, g)
-    gb = group_blocks(bk)
     tile_p = max(8, -(-(bq * g) // 8) * 8)
     kv_dtype = "int8" if quantized else q_dtype
+    gb = group_blocks(bk, key_bytes=stored_key_bytes(hkv * d, 2, kv_dtype))
 
     pos_hi = max(0, kv_len - s)
     scalars = (
@@ -741,6 +742,93 @@ def rms_norm_spec(rows: int, d: int, *, dtype: str = "bfloat16",
     return KernelSpec(
         op="rms_norm", variant=variant or f"rows={rows},d={d}",
         grid=(max(1, rows // br),), operands=tuple(operands), dims=dims)
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule's kernels (ops/pallas/gated_delta.py)
+# ---------------------------------------------------------------------------
+
+def gated_delta_specs(layers: int, heads: int, dk: int, dv: int, *,
+                      rows: int, chunk: int) -> List[KernelSpec]:
+    """KernelSpecs of the two kernels over the serving leaf ``(layers, rows
+    + 1, d_k, H·d_v)`` float32.  The STEP: one grid step a decode row, the
+    row's whole ``S`` in and out (aliased), the row picked through the
+    ``order`` table of the live rows first — a step past them keeps the
+    last block, so the worst case is every row fetched once.  The CHUNK:
+    grid (lane groups, sub-chunks), a group's block of ``S`` resident while
+    its sub-chunks' stacked operands stream by."""
+    from ..ops.gated_delta import sub_chunk
+    from ..ops.pallas.gated_delta import lane_group
+    hv, hg = heads * dv, lane_group(heads, dv)
+    groups, gw, c = heads // hg, hg * dv, sub_chunk(chunk)
+    nc = -(-chunk // c)
+    leaf = (layers, rows + 1, dk, hv)
+    f32 = "float32"
+
+    def step_leaf(grid_ivs, sc):
+        (r,) = grid_ivs
+        return (Iv.const(layers - 1),
+                sc.lookup("first") + sc.lookup("order", r),
+                Iv.const(0), Iv.const(0))
+
+    def step_row(grid_ivs, sc):
+        (r,) = grid_ivs
+        return (sc.lookup("order", r), Iv.const(0), Iv.const(0))
+
+    step = KernelSpec(
+        op="gated_delta_step", variant=f"rows={rows},H={heads},{dk}x{dv}",
+        grid=(rows,),
+        operands=(
+            BlockOperand("S", (1, 1, dk, hv), leaf, f32, step_leaf),
+            BlockOperand("q", (1, dk, heads), (rows, dk, heads), f32,
+                         step_row, sublane_padded=True),
+            BlockOperand("k", (1, dk, heads), (rows, dk, heads), f32,
+                         step_row, sublane_padded=True),
+            BlockOperand("v_alpha_beta", (1, 3, hv), (rows, 3, hv), f32,
+                         step_row, sublane_padded=True),
+            BlockOperand("o", (1, 1, hv), (rows, 1, hv), f32, step_row,
+                         sublane_padded=True),
+            BlockOperand("S_out", (1, 1, dk, hv), leaf, f32, step_leaf)),
+        scalars=(ScalarOperand("order", (rows,), 0, rows - 1),
+                 ScalarOperand("first", (), 0, 1)),
+        dims={"rows": rows, "heads": heads, "dk": dk, "dv": dv,
+              "lanes_128": (("H·dv", hv), ("group", gw))})
+
+    def chunk_leaf(grid_ivs, sc):
+        p, n = grid_ivs
+        return (Iv.const(layers - 1), sc.lookup("row"), Iv.const(0), p)
+
+    def head_rows(grid_ivs, sc):
+        p, n = grid_ivs
+        return (n, p, Iv.const(0), Iv.const(0))
+
+    def lane_rows(grid_ivs, sc):
+        p, n = grid_ivs
+        return (n, Iv.const(0), p)
+
+    def stacked(name, r, cols):
+        return BlockOperand(name, (1, 1, hg * r, cols),
+                            (nc, groups, hg * r, cols), f32, head_rows,
+                            sublane_padded=True)
+    walk = KernelSpec(
+        op="gated_delta_chunk",
+        variant=f"chunk={chunk},c={c},H={heads},{dk}x{dv}",
+        grid=(groups, nc),
+        operands=(
+            BlockOperand("S", (1, 1, dk, gw), leaf, f32, chunk_leaf,
+                         fetches=groups),
+            stacked("w", c, dk), stacked("qd", c, dk), stacked("p", c, c),
+            stacked("kdT", dk, c),
+            BlockOperand("u", (1, c, gw), (nc, c, hv), f32, lane_rows),
+            BlockOperand("d", (1, 1, gw), (nc, 1, hv), f32, lane_rows,
+                         sublane_padded=True),
+            BlockOperand("o", (1, c, gw), (nc, c, hv), f32, lane_rows),
+            BlockOperand("S_out", (1, 1, dk, gw), leaf, f32, chunk_leaf,
+                         fetches=groups)),
+        scalars=(ScalarOperand("row", (), 0, rows),),
+        dims={"chunk": chunk, "c": c, "heads": heads, "dk": dk, "dv": dv,
+              "lanes_128": (("group", gw),)})
+    return [step, walk]
 
 
 # ---------------------------------------------------------------------------

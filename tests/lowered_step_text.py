@@ -16,7 +16,8 @@ a wave engine's step program and its prefill program at ``prefill_batch`` rows
 stayed as they were beside the one-row program (the ``prefill rows=1``
 lines, which an older checkout does not print), PR 44 that every step
 program stayed as it was beside a cursor engine's rows-alone program (the
-``rows step`` lines)."""
+``rows step`` lines), PR 46 that a seventh model with two slot leaves left
+the six cells' programs as they were."""
 import base64
 import hashlib
 import json
@@ -299,6 +300,26 @@ def cell_engines(root):
     with nn.abstract_parameters():
         model = LatentMoeForCausalLM(
             serve_mla.program_config(cfg, kw["max_length"]))
+    model.eval()
+    pt.flags.set_flags({"perf_model": "off"})
+    yield cell, ServingEngine(model, seed=0, **kw), None
+    pt.flags.set_flags({"perf_model": "on"})
+    del model
+
+    # the seventh cell, on a checkout that has it: a Gated DeltaNet layer
+    # and an attention layer (the published period has three of the first)
+    cell = "olmo-hybrid-7b.decode-state-saturated"
+    if not os.path.isfile(os.path.join(root, "benchmark", "workloads",
+                                       cell + ".json")):
+        return
+    from benchmark.harness import serve_olmo_hybrid
+    from paddle_tpu.models.olmo_hybrid import OlmoHybridForCausalLM
+    kw = dict(cell_file("workloads", cell)["engine"], num_blocks=129)
+    cfg = cell_file("configs", "olmo-hybrid-7b")
+    cfg = dict(cfg, num_hidden_layers=2, layer_types=cfg["layer_types"][2:4])
+    with nn.abstract_parameters():
+        model = OlmoHybridForCausalLM(
+            serve_olmo_hybrid.program_config(cfg, kw["max_length"]))
     model.eval()
     pt.flags.set_flags({"perf_model": "off"})
     yield cell, ServingEngine(model, seed=0, **kw), None

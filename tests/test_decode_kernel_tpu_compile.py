@@ -166,16 +166,72 @@ def test_contiguous_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# -- the Olmo-Hybrid cell's kernels -------------------------------------------
+
+@pytest.mark.parametrize("rows, s", [(80, 1), (1, 256), (1, 8)],
+                         ids=["olmo-rows-g1", "olmo-chunk-g1", "olmo-stub-g1"])
+def test_flash_decode_at_a_query_group_of_one_compiles_for_v5e(one_chip,
+                                                               rows, s):
+    """The flash-decode body at plain multi-head attention, 30 K/V heads of
+    128 and a query group of ONE (every older cell has a group of 4 to 32):
+    a key is 15 KiB over K and V, so the copy group is 256 keys and not 512
+    (``group_blocks``), or its double buffers alone would fill the 16 MB a
+    kernel may use."""
+    hkv, layers, blocks, cols = 30, 4, 471, 32
+    args = [_spec(one_chip, (rows, s, hkv, D), jnp.bfloat16),
+            _spec(one_chip, (layers, 2, blocks, BLOCK, hkv * D),
+                  jnp.bfloat16),
+            _spec(one_chip, (rows,), jnp.int32),
+            _spec(one_chip, (rows, cols), jnp.int32)]
+
+    def call(q, pool, pos, tables):
+        return paged_decode_attention_pallas(q, pool, layers - 1, pos,
+                                             tables)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 21)
+
+
+@pytest.mark.parametrize("rows, s", [(80, 1), (1, 256), (1, 8)],
+                         ids=["step-80-rows", "chunk-256", "stub-8"])
+def test_gated_delta_kernels_compile_for_v5e(one_chip, rows, s):
+    """The gated delta rule's step and chunk kernels at the Olmo-Hybrid
+    cell's shapes: 12 linear layers x 81 state rows of 96 x (30 x 192)
+    float32 (2.15 GB), aliased: the compiled call holds the leaf once."""
+    from paddle_tpu.ops.pallas.gated_delta import (gated_delta_chunk_pallas,
+                                                   gated_delta_step_pallas)
+    layers, state_rows, h, dk, dv = 12, 81, 30, 96, 192
+    f32 = jnp.float32
+    leaf = _spec(one_chip, (layers, state_rows, dk, h * dv), f32)
+    args = [leaf, _spec(one_chip, (), jnp.int32),
+            *(_spec(one_chip, (rows, s, h, w), f32) for w in (dk, dk, dv)),
+            _spec(one_chip, (rows, s, h), f32),
+            _spec(one_chip, (rows, s, h), f32),
+            _spec(one_chip, (rows,), jnp.bool_),
+            _spec(one_chip, (rows,), jnp.bool_)]
+    fn = gated_delta_step_pallas if s == 1 else gated_delta_chunk_pallas
+
+    def call(leaf, first, q, k, v, g, beta, live, fresh):
+        return fn(leaf, layers - 1, first, q, k, v, g, beta, live, fresh)
+    compiled = jax.jit(call, donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    nbytes = layers * state_rows * dk * h * dv * 4
+    assert mem.alias_size_in_bytes >= nbytes          # updated in place
+    assert mem.temp_size_in_bytes < (1 << 26)         # no second copy
+
+
 # -- the mixed step programs, whole -------------------------------------------
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # cell: expert layers at the cut depth
 JOYAI = "joyai-llm-flash-ep16.shared-doc-saturated"
+OLMO = "olmo-hybrid-7b.decode-state-saturated"
 MIXED = {"mistral-7b.chat-open": 0,
          "trinity-large-ep8.longtail-saturated": 1,
          "lfm2-8b-a1b-ep2.decode-wide-saturated": 2,
          "sdar-30b-a3b-ep8.block-decode-saturated": 2,
-         JOYAI: 1}
+         JOYAI: 1, OLMO: 0}
 # the name of the rows part's attention kernel, where it is not the decode
 # rows': a block-diffusion model's rows are blocks, a latent pool's walk has
 # its own name
@@ -259,7 +315,7 @@ def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell,
     assert {k for k, n in reads.items() if n == 0} <= {
         k for k in reads if k.endswith(("rope_cos']", "rope_sin']",
                                         "embed_tokens']", ".conv.conv']",
-                                        ".kv_b_proj']"))}
+                                        ".mixer.conv']", ".kv_b_proj']"))}
     twice = {k: n for k, n in reads.items() if n > 1}
     assert not twice, twice
     assert sum(reads.values()) >= 5 * eng.config.num_hidden_layers
@@ -272,6 +328,21 @@ def test_mixed_program_reads_each_weight_in_one_product(mixed_engines, cell,
     assert {k for k in calls if "flash_decode" in k} == {
         ROWS_KERNEL.get(cell, "_step_impl_decode_rows_flash_decode"),
         CHUNK_KERNEL.get(cell, "_step_impl_prompt_chunk_flash_decode")}
+
+
+@pytest.mark.parametrize("rows_alone", [False, True],
+                         ids=["mixed", "rows_alone"])
+def test_olmo_programs_call_the_two_state_kernels_a_linear_layer(
+        mixed_engines, rows_alone):
+    """One linear layer at the cut depth: the decode rows' step and the
+    chunk part's walk (the rows-alone program's stub of it too) once each a
+    layer, under their program part's names."""
+    eng = mixed_engines[OLMO]
+    fn = eng._rows_fn if rows_alone else eng._step_fn
+    calls = kernel_calls(lowered(fn.python_fn, eng._lint_args()))
+    assert {k: n for k, n in calls.items() if "gated_delta" in k} == {
+        "_step_impl_decode_rows_gated_delta_step": 1,
+        "_step_impl_prompt_chunk_gated_delta_chunk": 1}
 
 
 @pytest.mark.parametrize("rows_alone", [False, True],

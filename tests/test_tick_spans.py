@@ -532,6 +532,7 @@ def test_spans_off_silences_the_two_costs_too(lm, monkeypatch, mode):
 _PALLAS_DIR = os.path.join(os.path.dirname(pt.__file__), "ops", "pallas")
 _CALL_SITES = [("decode_attention.py", 0), ("flash_attention.py", 0),
                ("flash_attention.py", 1), ("flash_attention.py", 2),
+               ("gated_delta.py", 0), ("gated_delta.py", 1),
                ("grouped_matmul.py", 0), ("int8_matmul.py", 0),
                ("rms_norm.py", 0)]
 
@@ -544,7 +545,7 @@ def _pallas_calls(filename):
             and n.func.attr == "pallas_call"]
 
 
-def test_the_call_sites_are_the_seven():
+def test_the_call_sites_are_the_nine():
     found = [(os.path.basename(p), i)
              for p in sorted(glob.glob(os.path.join(_PALLAS_DIR, "*.py")))
              for i in range(len(_pallas_calls(os.path.basename(p))))]
@@ -557,11 +558,16 @@ def test_every_pallas_call_has_a_name(filename, index):
     (name,) = [k.value for k in call.keywords if k.arg == "name"]
     if isinstance(name, ast.Name):
         # handed down as the static ``name=`` of the jitted function round
-        # the call (decode_attention._flash_call): read it where it is made
+        # the call (decode_attention._flash_call, gated_delta._step_call /
+        # _chunk_call): read it where it is made, the file's index-th
         with open(os.path.join(_PALLAS_DIR, filename)) as f:
-            (name,) = [k.value for n in ast.walk(ast.parse(f.read()))
-                       if isinstance(n, ast.Call) for k in n.keywords
-                       if k.arg == "name" and isinstance(k.value, ast.Call)]
+            made = sorted(
+                (k.value for n in ast.walk(ast.parse(f.read()))
+                 if isinstance(n, ast.Call) for k in n.keywords
+                 if k.arg == "name" and isinstance(k.value, ast.Call)),
+                key=lambda v: v.lineno)
+        assert len(made) == len(_pallas_calls(filename))
+        name = made[index]
     # built by ops._dispatch.kernel_name, so a program part can lead it
     assert isinstance(name, ast.Call) and "kernel_name" in ast.dump(name.func)
     # (one constant, or one of two by the pool's layout: the latent walk
